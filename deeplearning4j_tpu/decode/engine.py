@@ -603,7 +603,10 @@ class DecodeEngine:
         runtime. Single-chip engines skip both."""
         if self.mesh is None:
             return self._timed(fn, label, bucket, *args)
-        with self.mesh.run_lock:
+        # set_mesh: the decode kernels run per shard of the ambient mesh
+        # (kernels/flash_attention._per_shard); the cost plane's shadow
+        # lower inside _timed sees the same mesh
+        with self.mesh.run_lock, jax.set_mesh(self.mesh.mesh):
             out = self._timed(fn, label, bucket, *args)
             jax.block_until_ready(out)
             return out
@@ -625,13 +628,9 @@ class DecodeEngine:
                 cr.observe_dispatch(label, (monotonic_s() - t0) * 1000.0)
                 return out
             return fn(*args)
-        abs_args = None
         if cr is not None:
-            try:
-                from ..telemetry.cost import abstractify
-                abs_args = abstractify(args)
-            except Exception:
-                abs_args = None
+            from ..telemetry.cost import abstractify
+            abs_args = abstractify(args)
         t0 = monotonic_s()
         out = fn(*args)
         jax.block_until_ready(out[1])
@@ -640,7 +639,7 @@ class DecodeEngine:
         record_jit_compile(label, ms, registry=self.registry)
         if self.compile_tracker is not None:
             self.compile_tracker.record(ms, bucket=bucket, phase="decode")
-        if cr is not None and abs_args is not None:
+        if cr is not None:
             cr.capture(label, fn, abs_args, family="decode",
                        samples=self._cost_samples(label))
             cr.dispatch_due(label)

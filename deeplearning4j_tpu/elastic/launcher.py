@@ -7,9 +7,16 @@ bounded). Two implementations:
 
 - `InProcessLauncher` — replicas are ServingServer instances on threads in
   this process, sharing a `scan_dir` of model zips. The deterministic
-  choice for tests and the ManualClock autoscale smoke.
+  choice for tests and the ManualClock autoscale smoke, and the way to run
+  replicas ON AN ACCELERATOR: a chip belongs to one process at a time, and
+  the process that has touched JAX holds it, so replicas that share a chip
+  (or a host's chips) are threads of that one process. Every replica gets
+  JAX's default device — on a four-chip host all of them sit on the first
+  chip; one replica per device is the router cell's work (ROADMAP R5).
 - `SubprocessLauncher` — each replica is a real OS process (its own Python,
-  its own XLA client), for smoke runs that want process-grade isolation.
+  its own XLA client), for process-grade isolation. The child runs on
+  whatever platform ITS environment gives it; the launcher refuses to start
+  a child that needs an accelerator this process already holds.
 
 Warm-up contract: a launcher replays the newest registry deploy event
 through the `RegistrySubscriber` path (`subscriber.apply`, the same code
@@ -199,8 +206,6 @@ class InProcessLauncher(ReplicaLauncher):
 
 _SUBPROCESS_SCRIPT = r"""
 import sys, json
-import jax
-jax.config.update("jax_platforms", "cpu")
 from deeplearning4j_tpu.serving.server import ServingServer
 opts = json.loads(sys.argv[1])
 server = ServingServer(**opts).start()
@@ -217,14 +222,23 @@ class SubprocessLauncher(ReplicaLauncher):
     (POST /deploy) since the subscriber lives in the child. Bounded by
     `max_replicas` like every launcher.
 
+    One process for each chip: the child takes its platform from its
+    environment — this process's, with `env` laid over it (a value of None
+    removes a variable) — and nothing in code pins it. A child that is not
+    pinned to the CPU (`JAX_PLATFORMS=cpu`) needs an accelerator of its own;
+    if this process runs JAX on one, the child would fail or hang at
+    start-up, so `launch` raises instead. On an accelerator use
+    `InProcessLauncher`, or launch from a process that runs JAX on the CPU
+    and hand the children an `env` without the pin.
+
     Mesh groups: `server_opts["mesh"]` is normalized to its JSON dict form
-    so it survives the argv hand-off; the child inherits the parent's env,
-    so set XLA_FLAGS=--xla_force_host_platform_device_count=N in the
-    parent when smoke-testing a CPU mesh."""
+    so it survives the argv hand-off; to smoke-test a CPU mesh give the
+    children XLA_FLAGS=--xla_force_host_platform_device_count=N."""
 
     def __init__(self, scan_dir, server_opts=None, max_replicas=4,
-                 deploy_event=None, start_timeout_s=60.0):
+                 deploy_event=None, start_timeout_s=60.0, env=None):
         self.scan_dir = str(scan_dir)
+        self.env = dict(env or {})
         self.server_opts = dict(server_opts or {})
         mesh = self.server_opts.get("mesh")
         if mesh is not None and hasattr(mesh, "to_dict"):
@@ -255,12 +269,45 @@ class SubprocessLauncher(ReplicaLauncher):
                 self._record_fan_error(name, e)
         return fanned
 
+    def child_env(self):
+        """The environment a replica process starts with."""
+        import os
+        env = dict(os.environ)
+        for key, value in self.env.items():
+            if value is None:
+                env.pop(key, None)
+            else:
+                env[key] = str(value)
+        return env
+
+    @staticmethod
+    def check_chip_is_free(env):
+        """Raise unless a child started with `env` can get the platform it
+        will ask for: either it is pinned to the CPU, or this process does
+        not run JAX on an accelerator (asking initialises this process's
+        backend, which a process that serves models does anyway)."""
+        if env.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+            return
+        import jax
+        backend = jax.default_backend()
+        if backend != "cpu":
+            raise RuntimeError(
+                f"SubprocessLauncher: this process runs JAX on {backend!r} "
+                "and holds that accelerator; a replica process that is not "
+                "pinned to the CPU needs one of its own and would fail or "
+                "hang at start-up. Use InProcessLauncher (replicas as "
+                "threads of the process that holds the chip), or launch "
+                "from a process that runs JAX on the CPU, or pin the "
+                "replicas with env={'JAX_PLATFORMS': 'cpu'}.")
+
     def launch(self, name):
         import json as _json
         import subprocess
         import sys
         from ..util.http import post_json
         name = str(name)
+        env = self.child_env()
+        self.check_chip_is_free(env)
         with self._lock:
             if name in self._replicas:
                 raise ValueError(f"replica {name!r} already running")
@@ -275,7 +322,7 @@ class SubprocessLauncher(ReplicaLauncher):
                 [sys.executable, "-c", _SUBPROCESS_SCRIPT,
                  _json.dumps(opts)],
                 stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
-                text=True)
+                text=True, env=env)
             line = self._read_port_line(proc, self.start_timeout_s)
             if not line.startswith("PORT="):
                 raise RuntimeError(f"replica {name} failed to start: "

@@ -34,8 +34,9 @@ on each visiting K/V shard (parallel/ring_attention.py merges the per-shard
 (out, lse) partials by log-sum-exp). The LSE cotangent folds into the
 backward for free: ds = p·(dp − Δ) with Δ = rowsum(dO·O) − g_lse.
 
-Falls back transparently (see `flash_attention`) when shapes don't tile or
-Pallas is unavailable, so callers can use it unconditionally.
+Gives way to a pure-JAX path (see `flash_attention`) when shapes don't tile,
+so callers can use it unconditionally; each such call is counted in
+`pallas_fallback_total` and logged once per shape (`_note_fallback`).
 """
 from __future__ import annotations
 
@@ -51,12 +52,64 @@ NEG_INF = -1e30
 LANES = 128  # lse/delta residuals are stored broadcast over one lane tile
 
 
-def _compiler_params(pltpu, **kw):
-    """jax renamed TPUCompilerParams -> CompilerParams across the versions
-    this repo spans; resolve whichever this install has."""
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kw)
+def _interpret_default():
+    """The one place that decides whether a kernel is compiled or
+    interpreted: compiled on a TPU, interpreted on the CPU (tests and
+    rehearsals), and an error on any other backend — a platform that is
+    neither must not run the Pallas interpreter on an accelerator unseen."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels run compiled on 'tpu' and interpreted on 'cpu'; "
+        f"the default backend is {backend!r}. Pass interpret= explicitly.")
+
+
+def _per_shard(fn, arrays, B, H):
+    """Run `fn(*arrays)` — a Pallas call on [batch, time, heads, head_dim]
+    tensors (plus [batch, ...] masks/lengths) — once per shard of the
+    ambient mesh. GSPMD cannot partition a Mosaic kernel ("wrap the call in
+    a shard_map"), and attention is independent across batch rows and
+    heads, so under a mesh set with `jax.set_mesh` (the serving mesh does
+    that around its dispatches) the batch splits over the data axis and the
+    heads over the model axis, each only where it divides; no collective is
+    needed. With no ambient mesh this is a plain call."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return fn(*arrays)
+    from jax.sharding import PartitionSpec as P
+    from ..parallel.sharding import DATA_AXIS, MODEL_AXIS
+
+    def axis(name, n):
+        size = mesh.shape.get(name, 1)
+        return name if size > 1 and n % size == 0 else None
+    b, h = axis(DATA_AXIS, B), axis(MODEL_AXIS, H)
+    tensor = P(b, None, h, None)
+    specs = tuple(tensor if a.ndim == 4 else P(b, *[None] * (a.ndim - 1))
+                  for a in arrays)
+    return jax.shard_map(fn, in_specs=specs, out_specs=tensor,
+                         check_vma=False)(*arrays)
+
+
+def _note_fallback(kernel, path, **shape):
+    """`use_pallas` was asked for and these shapes do not tile, so the call
+    gives way to `path`. Callers rely on that; it is counted in
+    `pallas_fallback_total{kernel,path,shape}` (at trace time, so once per
+    compiled program) and logged the first time each shape is seen."""
+    from ..telemetry.logging import get_logger
+    from ..telemetry.registry import get_registry
+    counter = get_registry().counter(
+        "pallas_fallback_total",
+        "Pallas kernel calls that gave way to a pure-JAX path because the "
+        "shapes do not tile")
+    label = ",".join(f"{k}={v}" for k, v in shape.items())
+    first = not counter.get(kernel=kernel, path=path, shape=label)
+    counter.inc(1, kernel=kernel, path=path, shape=label)
+    if first:
+        get_logger().warning("pallas_fallback", kernel=kernel, path=path,
+                             **shape)
 
 
 def _mask_fold(s, km_ref):
@@ -214,8 +267,8 @@ def _flash_forward(q, k, v, km, offs, scale, causal, block_q, block_k,
             pltpu.VMEM((block_q, LANES), jnp.float32),   # running max
             pltpu.VMEM((block_q, LANES), jnp.float32),   # running sum
         ],
-        compiler_params=_compiler_params(
-            pltpu, dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*args)
     out = res[0]
@@ -379,8 +432,8 @@ def _flash_backward(q, k, v, out, lse, g, km, offs, scale, causal, block_q,
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, qi, ki: (b, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=_compiler_params(
-            pltpu, dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf, dof, lse, delta, *extra_args)
 
@@ -408,8 +461,8 @@ def _flash_backward(q, k, v, out, lse, g, km, offs, scale, causal, block_q,
         ],
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                         pltpu.VMEM((block_k, D), jnp.float32)],
-        compiler_params=_compiler_params(
-            pltpu, dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf, dof, lse, delta, *extra_args)
 
@@ -533,7 +586,7 @@ def flash_attention(q, k, v, *, causal=False, scale=None, key_mask=None,
     if scale is None:
         scale = float(1.0 / (D ** 0.5))
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _interpret_default()
     plan = _plan(Tq, Tk, D, block_q, block_k, interpret)
     if plan is None:
         # prefer the O(T_block)-memory blockwise scan over the materializing
@@ -542,14 +595,20 @@ def flash_attention(q, k, v, *, causal=False, scale=None, key_mask=None,
         from ..parallel.ring_attention import (attention_reference,
                                                blockwise_attention)
         blk = _fit_block(Tk, min(block_k, Tk), 1)
-        if blk is not None and blk >= 8:
+        blockwise = blk is not None and blk >= 8
+        _note_fallback("flash_attention",
+                       "blockwise" if blockwise else "reference",
+                       Tq=Tq, Tk=Tk, D=D, interpret=interpret)
+        if blockwise:
             return blockwise_attention(q, k, v, block_size=blk, causal=causal,
                                        scale=scale, key_mask=key_mask)
         return attention_reference(q, k, v, causal=causal, scale=scale,
                                    key_mask=key_mask)
-    km = None if key_mask is None else _prep_mask(key_mask, B, Tk)
-    return _flash(q, k, v, km, None, scale, causal, plan[0], plan[1],
-                  interpret)
+    masks = () if key_mask is None else (_prep_mask(key_mask, B, Tk),)
+    return _per_shard(
+        lambda q, k, v, km=None: _flash(q, k, v, km, None, scale, causal,
+                                        plan[0], plan[1], interpret),
+        (q, k, v) + masks, B, H)
 
 
 def flash_attention_lse(q, k, v, *, causal=False, scale=None, key_mask=None,
@@ -568,7 +627,7 @@ def flash_attention_lse(q, k, v, *, causal=False, scale=None, key_mask=None,
     if scale is None:
         scale = float(1.0 / (D ** 0.5))
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _interpret_default()
     plan = _plan(Tq, Tk, D, block_q, block_k, interpret)
     if plan is None:
         raise ValueError(
@@ -629,19 +688,23 @@ def flash_decode(q, k, v, lengths, *, scale=None, use_pallas=True,
     if scale is None:
         scale = float(1.0 / (D ** 0.5))
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _interpret_default()
     lengths = jnp.asarray(lengths, jnp.int32)
     if not use_pallas:
         return _decode_reference(q, k, v, lengths, scale)
     tq = 1 if interpret else 8          # Mosaic sublane floor when compiled
     plan = _plan(tq, C, D, tq, block_k, interpret)
     if plan is None:
+        _note_fallback("flash_decode", "reference", C=C, D=D,
+                       interpret=interpret)
         return _decode_reference(q, k, v, lengths, scale)
     km = (jax.lax.broadcasted_iota(jnp.int32, (S, C), 1)
           < lengths[:, None]).astype(jnp.float32)[:, None, :]   # [S, 1, C]
     qq = q if tq == 1 else jnp.broadcast_to(q, (S, tq, H, D))
-    out = _flash(qq, k, v, km, None, scale, False, plan[0], plan[1],
-                 interpret)
+    out = _per_shard(
+        lambda q, k, v, km: _flash(q, k, v, km, None, scale, False, plan[0],
+                                   plan[1], interpret),
+        (qq, k, v, km), S, H)
     return out[:, :1]
 
 
@@ -686,5 +749,5 @@ def can_flash(Tq, Tk, D, *, block_q=256, block_k=1024, interpret=None):
     """True when the Pallas kernel can run these shapes (compiled-mode tile
     alignment on TPU; any divisor in interpret mode)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _interpret_default()
     return _plan(Tq, Tk, D, block_q, block_k, interpret) is not None

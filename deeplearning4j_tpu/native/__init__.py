@@ -4,9 +4,10 @@ Reference analog: SURVEY.md §2.9 — the reference's data/runtime path is
 native (libnd4j + DataVec behind JavaCPP); this module is the TPU build's
 equivalent seam. The C++ side (src/dl4jtpu_io.cpp) implements the host hot
 loops — CSV parse, IDX decode, threaded batch gather, pixel normalize,
-one-hot — and the Python data pipeline uses them when the library is present,
-falling back to pure Python otherwise (`load()` returns None when no
-toolchain/lib exists, so the framework never hard-requires the build step).
+one-hot — and the Python data pipeline uses them when the library can be
+built, and pure Python otherwise (`load()` returns None when there is no
+toolchain, so the framework never hard-requires the build step). Which of the
+two a process ended up with is logged once (`native_io_runtime`).
 """
 from __future__ import annotations
 
@@ -29,17 +30,27 @@ def load(build_if_missing=True):
         return _lib
     _tried = True
     from .build import LIB, build
-    path = LIB if os.path.exists(LIB) else None
-    if path is None and build_if_missing:
+    from ..telemetry.logging import get_logger
+    log = get_logger()
+    path = None
+    if build_if_missing:
         try:
-            path = build()
+            path = build()      # a no-op when LIB was built from this source
         except RuntimeError as e:
             import warnings
             warnings.warn(f"native IO build failed; using Python fallbacks "
                           f"({e})", stacklevel=2)
+            log.warning("native_io_runtime", runtime="python",
+                        reason="build failed")
             return None
-    if path is None or not os.path.exists(path):
+    elif os.path.exists(LIB):
+        path = LIB
+    if path is None:
+        log.info("native_io_runtime", runtime="python",
+                 reason="no C++ toolchain" if build_if_missing
+                 else "library not built")
         return None
+    log.info("native_io_runtime", runtime="native", library=path)
     lib = ctypes.CDLL(path)
     lib.dl4j_csv_parse.restype = ctypes.c_int
     lib.dl4j_csv_parse.argtypes = [

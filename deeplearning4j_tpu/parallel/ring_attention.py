@@ -15,11 +15,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map            # jax >= 0.8
-except ImportError:                      # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 from .sharding import SEQ_AXIS
 

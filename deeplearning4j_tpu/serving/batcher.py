@@ -333,7 +333,9 @@ class DynamicBatcher:
             self.cost_registry.capture(label, fn, args, family="serve",
                                        samples=bucket, version=version)
         except Exception:
-            pass    # attribution is observability, never a dispatch failure
+            # attribution is observability, never a dispatch failure — but a
+            # seam without a cost row is counted, not silent
+            self.cost_registry.capture_errors.inc(1, executable=label)
 
     def reset_observed(self):
         """Forget recorded (signature, bucket) pairs — used when the serving

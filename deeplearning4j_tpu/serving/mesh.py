@@ -302,7 +302,10 @@ class MeshDispatcher:
             # run_lock + block: one partitioned wave in flight per mesh
             # (see MeshContext.run_lock — concurrent launches deadlock the
             # CPU collectives, and on real chips they'd serialize anyway)
-            with ctx.run_lock:
+            # set_mesh: the Pallas kernels read the ambient mesh and run
+            # per shard (kernels/flash_attention._per_shard) — GSPMD cannot
+            # partition a Mosaic kernel
+            with ctx.run_lock, jax.set_mesh(ctx.mesh):
                 out = self.mesh_inner.output(xb, **kw)
                 jax.block_until_ready(out)
         if sampled:

@@ -48,13 +48,31 @@ import warnings as _pywarnings
 
 from .registry import get_registry
 from .trace import get_tracer
+from ..util.time_source import monotonic_s
 
-# Same nominal v5e numbers bench.py anchors its roofline on: the matmul leg
-# is meant to be overridden with the measured MXU ceiling (bench probes it);
-# the HBM leg stays nominal because cost_analysis byte counts are an upper
-# bound (see bench.py's roofline_note).
-V5E_PEAK_FLOPS = 197e12          # bf16 dense nominal, TPU v5e (FLOP/s)
-V5E_PEAK_HBM = 820e9             # bytes/s nominal, TPU v5e
+# Published peaks of one chip, keyed by `jax.devices()[0].device_kind` — the
+# one table bench.py and the live plane both read. The matmul leg may be
+# overridden with a measured MXU ceiling (bench probes it); the HBM leg stays
+# nominal because cost_analysis byte counts are an upper bound (see bench.py's
+# roofline_note). A device that is not listed has no peaks: its rows carry no
+# roofline legs, binding or util (None) — never another chip's numbers.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {
+        "flops": 197e12,         # bf16 dense, FLOP/s
+        "hbm_bps": 819e9,        # bytes/s
+        "source": "Google Cloud documentation, 'TPU v5e': 197 TFLOP/s "
+                  "bf16, 16 GB HBM at 819 GB/s per chip"},
+}
+
+
+def device_peaks(device_kind=None):
+    """The `DEVICE_PEAKS` row of `device_kind` (default: the first device
+    JAX reports), or None for a device the table does not list."""
+    if device_kind is None:
+        import jax
+        device_kind = jax.devices()[0].device_kind
+    return DEVICE_PEAKS.get(device_kind)
+
 
 _COST_KEYS = (("flops", "flops"), ("bytes accessed", "hbm_bytes"))
 _MEM_KEYS = (("temp_size_in_bytes", "temp_bytes"),
@@ -80,15 +98,12 @@ def abstractify(tree):
 def compiled_costs(compiled):
     """Normalize `Compiled.cost_analysis()` + `memory_analysis()` into one
     flat dict: {flops, hbm_bytes, temp_bytes, argument_bytes, output_bytes,
-    code_bytes}. cost_analysis returns a dict on some jax versions and a
-    list-of-dict (one per partition) on others; missing keys and backends
-    that report nothing degrade to 0.0, never raise."""
+    code_bytes}. Missing keys and backends that report nothing degrade to
+    0.0, never raise."""
     out = {name: 0.0 for _, name in _COST_KEYS}
     out.update({name: 0.0 for _, name in _MEM_KEYS})
     try:
         ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
         for key, name in _COST_KEYS:
             v = ca.get(key)
             if v is not None:
@@ -106,6 +121,16 @@ def compiled_costs(compiled):
     return out
 
 
+def _pallas_kernel_count(compiled):
+    """Pallas (Mosaic) custom calls in a compiled program's text: 0 on the
+    CPU, where interpret mode runs the kernel body as plain XLA ops; None
+    for a stand-in that has no text to read."""
+    try:
+        return compiled.as_text().count("tpu_custom_call")
+    except Exception:
+        return None
+
+
 def classify(flops, hbm_bytes, tflops_ceiling=None, hbm_bps_ceiling=None,
              measured_ms=None):
     """The roofline arithmetic bench.py's headline block uses, shared:
@@ -113,11 +138,13 @@ def classify(flops, hbm_bytes, tflops_ceiling=None, hbm_bps_ceiling=None,
     ceiling; binding is whichever leg is longer; util (when a measured wall
     time is supplied) is the longer leg over the measured time — util ≈ 1.0
     means the executable already runs as fast as its binding wall allows.
-    Ceilings are FLOP/s and bytes/s; default to the v5e nominals."""
-    tf = float(tflops_ceiling or V5E_PEAK_FLOPS)
-    bw = float(hbm_bps_ceiling or V5E_PEAK_HBM)
-    t_mm_ms = float(flops) / tf * 1e3
-    t_bw_ms = float(hbm_bytes) / bw * 1e3
+    Ceilings are FLOP/s and bytes/s; without both (a device `DEVICE_PEAKS`
+    does not list) every field is None."""
+    if not tflops_ceiling or not hbm_bps_ceiling:
+        return {"roofline_compute_ms": None, "roofline_hbm_ms": None,
+                "roofline_binding": None, "roofline_util": None}
+    t_mm_ms = float(flops) / float(tflops_ceiling) * 1e3
+    t_bw_ms = float(hbm_bytes) / float(hbm_bps_ceiling) * 1e3
     out = {"roofline_compute_ms": t_mm_ms,
            "roofline_hbm_ms": t_bw_ms,
            "roofline_binding": "hbm" if t_bw_ms > t_mm_ms else "matmul"}
@@ -147,11 +174,15 @@ class ExecutableCostRegistry:
                  hbm_gbps_ceiling=None, sample_every=16):
         self.registry = registry if registry is not None else get_registry()
         # Ceilings arrive in the bench-report units (TFLOP/s, GB/s) and are
-        # held in base units (FLOP/s, bytes/s) like bench's internals.
+        # held in base units (FLOP/s, bytes/s) like bench's internals; left
+        # out, they are this device's published peaks, or None when
+        # DEVICE_PEAKS does not list it.
+        peaks = {} if matmul_tflops_ceiling and hbm_gbps_ceiling \
+            else device_peaks() or {}
         self.tf_ceiling = (float(matmul_tflops_ceiling) * 1e12
-                           if matmul_tflops_ceiling else V5E_PEAK_FLOPS)
+                           if matmul_tflops_ceiling else peaks.get("flops"))
         self.bw_ceiling = (float(hbm_gbps_ceiling) * 1e9
-                           if hbm_gbps_ceiling else V5E_PEAK_HBM)
+                           if hbm_gbps_ceiling else peaks.get("hbm_bps"))
         self.sample_every = max(1, int(sample_every))
         self._lock = threading.Lock()
         self._records = {}            # label -> row dict
@@ -175,7 +206,8 @@ class ExecutableCostRegistry:
             "labeled by executable")
         self.binding_gauge = r.gauge(
             "roofline_binding",
-            "Roofline binding per executable: 1 = hbm-bound, 0 = matmul-bound")
+            "Roofline binding per executable: 1 = hbm-bound, 0 = matmul-bound "
+            "(no series for a device without published peaks)")
         self.util_gauge = r.gauge(
             "roofline_util",
             "Live roofline utilization estimate per executable "
@@ -213,20 +245,34 @@ class ExecutableCostRegistry:
             # (donation-unusable on sharded caches) that the real compile
             # did not — silence them here so the diagnostic lower never
             # pollutes donation watches or test warning nets.
+            t0 = monotonic_s()
             with _pywarnings.catch_warnings():
                 _pywarnings.simplefilter("ignore")
                 comp = target.lower(*args, **(kwargs or {})).compile()
-        except Exception:
+            capture_ms = (monotonic_s() - t0) * 1e3
+        except Exception as e:
+            # counted AND said: a seam that cannot lower on this device must
+            # not look fine because only a counter moved
             self.capture_errors.inc(1, executable=str(label))
+            from .logging import get_logger
+            (getattr(self.registry, "logger", None) or get_logger()).warning(
+                "cost_capture_failed", executable=str(label),
+                error=f"{type(e).__name__}: {e}")
             return None
         return self.capture_compiled(label, comp, family=family,
-                                     samples=samples, version=version)
+                                     samples=samples, version=version,
+                                     capture_ms=capture_ms)
 
     def capture_compiled(self, label, compiled, family=None, samples=1,
-                         version=None):
+                         version=None, capture_ms=None):
         """Record costs for an already-compiled executable (bench.py's AOT
         path). Returns the stored row (also the live-vs-offline agreement
-        surface bench asserts against)."""
+        surface bench asserts against). `capture_ms` is what the shadow
+        lower + compile cost — with the persistent compilation cache on
+        (util/compile_cache.py) a re-trace and a cache read, not a second
+        compile. `pallas_kernels` counts the Pallas custom calls in the
+        compiled program: 0 under `use_pallas=True` means every attention
+        call gave way to a pure-JAX path (see `pallas_fallback_total`)."""
         label = str(label)
         family = str(family) if family else label.split(":", 1)[0]
         samples = max(1, int(samples))
@@ -241,7 +287,9 @@ class ExecutableCostRegistry:
                    roofline_compute_ms=cls["roofline_compute_ms"],
                    roofline_hbm_ms=cls["roofline_hbm_ms"],
                    roofline_binding=cls["roofline_binding"],
-                   roofline_util=None, dispatch_ms_p50=None, dispatches=0)
+                   roofline_util=None, dispatch_ms_p50=None, dispatches=0,
+                   capture_ms=capture_ms,
+                   pallas_kernels=_pallas_kernel_count(compiled))
         with self._lock:
             prev = self._records.get(label)
             self._records[label] = row
@@ -250,9 +298,10 @@ class ExecutableCostRegistry:
         self.captures.inc(1, executable=label, family=family)
         self.flops_gauge.set(row["flops_per_sample"], executable=label)
         self.bytes_gauge.set(row["hbm_bytes_per_sample"], executable=label)
-        self.binding_gauge.set(
-            1.0 if row["roofline_binding"] == "hbm" else 0.0,
-            executable=label)
+        if row["roofline_binding"] is not None:
+            self.binding_gauge.set(
+                1.0 if row["roofline_binding"] == "hbm" else 0.0,
+                executable=label)
         return row
 
     def _update_deploy_ratio_locked(self, family, label, row, prev):
@@ -297,12 +346,13 @@ class ExecutableCostRegistry:
         with self._lock:
             row = self._records.get(label)
         if row is not None and ms and ms > 0:
-            util = max(row["roofline_compute_ms"],
-                       row["roofline_hbm_ms"]) / float(ms)
-            row["roofline_util"] = util
             row["dispatch_ms_p50"] = self.dispatch_hist.percentile(
                 0.50, executable=label)
-            self.util_gauge.set(util, executable=label)
+            if row["roofline_binding"] is not None:
+                util = max(row["roofline_compute_ms"],
+                           row["roofline_hbm_ms"]) / float(ms)
+                row["roofline_util"] = util
+                self.util_gauge.set(util, executable=label)
 
     def record_dispatch(self, label, ms):
         """Called on EVERY dispatch where the wall time is already measured
@@ -340,8 +390,11 @@ class ExecutableCostRegistry:
         return rows
 
     def to_dict(self, sort="hbm_bytes_per_sample", family=None):
-        return {"ceilings": {"matmul_tflops_ceiling": self.tf_ceiling / 1e12,
-                             "hbm_gbps_ceiling": self.bw_ceiling / 1e9},
+        return {"ceilings": {
+                    "matmul_tflops_ceiling":
+                        self.tf_ceiling and self.tf_ceiling / 1e12,
+                    "hbm_gbps_ceiling":
+                        self.bw_ceiling and self.bw_ceiling / 1e9},
                 "sample_every": self.sample_every,
                 "executables": self.table(sort=sort, family=family)}
 

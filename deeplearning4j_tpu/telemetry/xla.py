@@ -92,11 +92,8 @@ class _TimedFirstCall:
             from .cost import abstractify, get_cost_registry
             cost = get_cost_registry()
             if cost is not None:
-                try:
-                    abs_args = abstractify(args)
-                    abs_kwargs = abstractify(kwargs)
-                except Exception:
-                    cost = None
+                abs_args = abstractify(args)
+                abs_kwargs = abstractify(kwargs)
             t0 = monotonic_s()
             out = self.__wrapped__(*args, **kwargs)
             record_jit_compile(self._label, (monotonic_s() - t0) * 1000.0,

@@ -4,24 +4,26 @@ Trains a deep MLP split into 4 pipeline stages (each stage's parameters on
 its own device, microbatches streamed through the interleaved
 one-forward-one-backward schedule as compiled per-stage XLA executables),
 then gathers the model onto one device for inference and writes/restores a
-sharded checkpoint. Runs on the 8-device virtual CPU mesh; the same code
-drives real multi-chip TPU slices.
+sharded checkpoint. Needs 4 devices: 8 virtual ones on the CPU, or the chips
+of a multi-chip TPU host.
 
-Run: python examples/06_pipeline_parallelism.py
+Run: JAX_PLATFORMS=cpu python examples/06_pipeline_parallelism.py
 """
 import os
 import sys
 
-# the demo needs SEVERAL devices: force the 8-device virtual CPU mesh (on a
-# real multi-chip TPU slice, drop these two lines and the stages land on
-# real chips)
+# the demo needs SEVERAL devices. The platform comes from the environment,
+# as in the other examples: on the CPU (JAX_PLATFORMS=cpu) this flag gives
+# it 8 virtual devices; on a multi-chip TPU host the flag does nothing and
+# the stages land on the chips
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=8")
-import jax
-
-jax.config.update("jax_platforms", "cpu")
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+from deeplearning4j_tpu.util.compile_cache import enable_compile_cache  # noqa: E402
 
+enable_compile_cache()
+
+import jax
 import numpy as np
 
 from deeplearning4j_tpu import (DataSet, DenseLayer, InputType,

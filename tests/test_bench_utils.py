@@ -1,6 +1,6 @@
 """Unit tests for bench.py's measurement self-defense (pure logic only —
 no device): the interleaved min-difference timer must cancel a bimodal
-per-call floor and survive relay outages via its resample self-check, and
+per-call floor and survive stalls via its resample self-check, and
 the regression detector must compare against the best prior BENCH_r*.json
 with the renamed-metric mapping applied."""
 import importlib.util
@@ -43,8 +43,8 @@ def test_diff_time_cancels_bimodal_floor(bench):
 
 def test_diff_time_raises_when_all_rounds_invert(bench):
     # a 2K-deep run can never legitimately be faster than a K-deep one;
-    # persistent inversion means outages corrupted every round
-    with pytest.raises(RuntimeError, match="outages"):
+    # persistent inversion means stalls corrupted every round
+    with pytest.raises(RuntimeError, match="stalls"):
         bench._diff_time(lambda: 0.5, lambda: 0.4, trials=3)
 
 
@@ -81,8 +81,8 @@ def test_regressions_empty_without_priors(bench, tmp_path, monkeypatch):
     assert bench._regressions_vs_prior({"metric": "m", "value": 1.0}) == []
 
 
-def test_diff_time_resamples_through_relay_outage(bench):
-    """A multi-second outage covering one sample group makes the round
+def test_diff_time_resamples_through_a_stall(bench):
+    """A multi-second stall covering one sample group makes the round
     violate the diff <= 0.55*min(t_2K) invariant — the estimator must
     detect it and resample instead of publishing a 27x-off number (the
     observed failure this guard exists for)."""
@@ -96,7 +96,7 @@ def test_diff_time_resamples_through_relay_outage(bench):
 
     def run_2k():
         state["i"] += 1
-        if state["i"] <= 10:          # every 2K-sample of round 1: outage
+        if state["i"] <= 10:          # every 2K-sample of round 1: stalled
             return 2 * sig + floor + 11.0
         return 2 * sig + floor
 

@@ -140,8 +140,29 @@ def test_capture_normalizes_per_sample_and_labels_gauges():
     assert row["hbm_bytes_per_sample"] == pytest.approx(200.0)
     assert reg.get("executable_flops_per_sample").get(
         executable="serve:b8") == pytest.approx(100.0)
-    assert reg.get("roofline_binding").get(executable="serve:b8") in (0.0, 1.0)
     assert cost.to_dict()["executables"][0]["executable"] == "serve:b8"
+    # the CPU has no published peaks: no legs, no binding, no util — never
+    # another chip's numbers
+    assert row["roofline_binding"] is None and row["roofline_util"] is None
+    assert reg.get("roofline_binding").get(executable="serve:b8") is None
+    assert cost.to_dict()["ceilings"] == {"matmul_tflops_ceiling": None,
+                                          "hbm_gbps_ceiling": None}
+    # with ceilings given (bench's measured ones) the row is classified
+    given = ExecutableCostRegistry(reg, matmul_tflops_ceiling=100.0,
+                                   hbm_gbps_ceiling=800.0)
+    row = given.capture_compiled("serve:b8", StubCompiled(800.0, 1600.0),
+                                 samples=8, version="v1")
+    assert row["roofline_binding"] == "hbm"
+    assert reg.get("roofline_binding").get(executable="serve:b8") == 1.0
+
+
+def test_device_peaks_table_is_keyed_by_device_kind():
+    from deeplearning4j_tpu.telemetry.cost import DEVICE_PEAKS, device_peaks
+    assert device_peaks() is None                    # tests run on the CPU
+    v5e = device_peaks("TPU v5 lite")
+    assert v5e is DEVICE_PEAKS["TPU v5 lite"]
+    assert v5e["flops"] == 197e12 and v5e["hbm_bps"] == 819e9
+    assert "source" in v5e
 
 
 def test_capture_error_counts_not_raises():
@@ -223,7 +244,9 @@ def test_decode_family_capture_step_and_prefill():
     net = transformer_lm(vocab_size=24, d_model=32, n_layers=1, n_heads=2,
                          seed=1).init()
     reg = MetricsRegistry()
-    cost = ExecutableCostRegistry(reg, sample_every=1)
+    cost = ExecutableCostRegistry(reg, sample_every=1,
+                                  matmul_tflops_ceiling=1.0,
+                                  hbm_gbps_ceiling=10.0)
     eng = DecodeEngine(net, slots=2, max_len=32, cost_registry=cost)
     eng.generate([1, 2, 3], 4)
     labels = cost.labels()
@@ -314,11 +337,11 @@ def test_profile_cost_and_trace_http_contract_serving():
         server.predict(x, wait_s=30)
         status, body = _get(server.url + "/profile/cost")
         assert status == 200
-        assert body["ceilings"]["hbm_gbps_ceiling"] > 0
+        assert body["ceilings"]["hbm_gbps_ceiling"] is None   # CPU: no peaks
         rows = body["executables"]
         assert any(r["executable"].startswith("serve:") for r in rows)
         for r in rows:
-            assert r["roofline_binding"] in ("hbm", "matmul")
+            assert r["flops"] > 0 and r["roofline_binding"] is None
         # unknown sort / family filters degrade, never 500
         assert _get_status(server.url + "/profile/cost?sort=bogus") == 200
         status, body = _get(server.url + "/profile/cost?family=nope")
@@ -419,7 +442,7 @@ def test_char_rnn_tbptt_scan_has_zero_donation_warnings():
 def test_smoke_profile_tool():
     """Fast variant of tools/smoke_profile.py: deploy, push traffic, scrape
     /profile/cost, and hold the full attribution contract — every active
-    executable attributed with a roofline binding, zero steady-state
+    executable attributed (no roofline binding on the CPU), zero steady-state
     recompiles/re-captures, and sampled-histogram overhead < 1% of
     steady-state dispatch time."""
     import tools.smoke_profile as smoke
@@ -427,7 +450,7 @@ def test_smoke_profile_tool():
     assert out["executables"] >= 1
     assert out["captures"] == out["executables"]
     assert out["dispatches"] > out["executables"]
-    assert out["binding"] in ("hbm", "matmul")
+    assert out["binding"] is None                    # CPU: no peaks
     assert out["sampling_overhead_pct"] < 1.0
 
 
@@ -459,3 +482,24 @@ def test_fleet_profile_merges_cost_tables_across_instances():
         assert keys == sorted(keys), "rows not ranked by bytes/sample"
     finally:
         server.stop()
+
+
+# ------------------------------------------------------- compile cache
+
+def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path):
+    """`enable_compile_cache`: where JAX_COMPILATION_CACHE_DIR is set JAX
+    has read it and nothing is set in code; where it is not, the fixed
+    `<checkout>/.jax_cache`. (jax.config.update is stubbed: the tests never
+    turn the cache on.)"""
+    from pathlib import Path
+    from deeplearning4j_tpu.util import compile_cache as cc
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda key, value: calls.append((key, value)))
+    monkeypatch.setenv(cc.ENV_VAR, str(tmp_path))
+    assert cc.enable_compile_cache() == str(tmp_path)
+    assert calls == []
+    monkeypatch.delenv(cc.ENV_VAR)
+    root = Path(__file__).resolve().parents[1]
+    assert cc.enable_compile_cache() == str(root / ".jax_cache")
+    assert calls == [("jax_compilation_cache_dir", str(root / ".jax_cache"))]

@@ -251,9 +251,13 @@ def test_scheduler_shed_expiry_and_stop_token():
             f.result(timeout=60)
         assert mreg.get("decode_expired_total").get() == 1
         # stop token retires a slot early, mid-batch
+        # (the stop id must FIRST occur past index 0 — a stream that opens
+        # with it rightly stops after one token)
         full = net.generate([3, 1, 4], 6)
-        res = sched.generate([3, 1, 4], max_new_tokens=6, stop_id=full[1])
-        assert res["tokens"] == full[:2] and res["finish_reason"] == "stop"
+        cut = next(i for i in range(1, len(full)) if full[i] not in full[:i])
+        res = sched.generate([3, 1, 4], max_new_tokens=6, stop_id=full[cut])
+        assert res["tokens"] == full[:cut + 1] \
+            and res["finish_reason"] == "stop"
         # unservable size: a clear client error, not a shed
         with pytest.raises(ValueError):
             sched.submit(list(range(10)), max_new_tokens=1000)

@@ -143,10 +143,7 @@ def test_self_attention_respects_mask():
 # ------------------------------------------------------------ collectives
 
 def test_collectives_smoke():
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     mesh = make_mesh(n_data=8)
 

@@ -569,6 +569,34 @@ def test_smoke_elastic_tool(tmp_path):
     assert out["preemptions"] == {"preempt-as1": 1}
 
 
+def test_subprocess_launcher_one_process_for_each_chip(tmp_path,
+                                                        monkeypatch):
+    """The child takes its platform from its environment (nothing in code
+    pins it), and a child that needs an accelerator this process holds is
+    refused with a clear error instead of failing or hanging at start-up."""
+    import jax
+    from deeplearning4j_tpu.elastic import SubprocessLauncher
+    from deeplearning4j_tpu.elastic import launcher as launcher_mod
+    assert "jax_platforms" not in launcher_mod._SUBPROCESS_SCRIPT
+    assert "JAX_PLATFORMS" not in launcher_mod._SUBPROCESS_SCRIPT
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    monkeypatch.setenv("DROPPED", "1")
+    lau = SubprocessLauncher(str(tmp_path), env={"DROPPED": None, "K": 3})
+    env = lau.child_env()
+    assert env["JAX_PLATFORMS"] == "cpu" and env["K"] == "3"
+    assert "DROPPED" not in env
+    # this process on the CPU holds no chip: any child may start
+    SubprocessLauncher.check_chip_is_free({})
+    # this process on an accelerator: only CPU-pinned children may
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    SubprocessLauncher.check_chip_is_free({"JAX_PLATFORMS": "cpu"})
+    unpinned = SubprocessLauncher(str(tmp_path),
+                                  env={"JAX_PLATFORMS": None})
+    with pytest.raises(RuntimeError, match="InProcessLauncher"):
+        unpinned.launch("p0")
+    assert not unpinned.alive("p0") and unpinned.names() == []
+
+
 @pytest.mark.slow
 def test_subprocess_launcher_real_process_replica(tmp_path):
     """SubprocessLauncher: one OS process per replica — launch, warm

@@ -393,13 +393,22 @@ def test_smoke_ckpt_tool(tmp_path):
 def test_trainer_probe_visible_through_fleet_healthz(tmp_path):
     """The probe lands on the PROCESS monitor by default, which UIServer
     /healthz aggregates and FleetCollector scrapes — a training run shows
-    up on /fleet/healthz with its iteration/heartbeat detail."""
+    up on /fleet/healthz with its iteration/heartbeat detail.
+
+    The process monitor keeps every probe any earlier test of this worker
+    left registered (a tripped trainer, a pipeline with a dead worker), and
+    the host's status is the worst of them — so which files shared the
+    worker decided this test. It gets a process monitor of its own."""
     from deeplearning4j_tpu.telemetry.fleet import FleetServer
+    from deeplearning4j_tpu.telemetry.health import (HealthMonitor,
+                                                     get_monitor, set_monitor)
     from deeplearning4j_tpu.ui.server import UIServer
     from deeplearning4j_tpu.util.http import get_json
 
     X, Y = _data(n=40)
     it = ListDataSetIterator(DataSet(X, Y), batch_size=8)
+    shared = get_monitor()
+    set_monitor(HealthMonitor())
     trainer = FaultTolerantTrainer(_factory(),
                                    CheckpointConfig(tmp_path / "ck",
                                                     frequency=0))
@@ -420,3 +429,4 @@ def test_trainer_probe_visible_through_fleet_healthz(tmp_path):
             ui.stop()
     finally:
         trainer.unregister_probe()
+        set_monitor(shared)

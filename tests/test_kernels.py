@@ -112,7 +112,7 @@ def test_flash_attention_ragged_seq_shrinks_block():
 
 def test_flash_attention_fallback_on_narrow_head():
     # D=6 violates the kernel's lane contract (D % 8) in every mode ->
-    # silently uses the reference path (only the default-scale rounding
+    # gives way to the reference path (only the default-scale rounding
     # differs: f64 Python float here vs f32 jnp.sqrt inside the reference)
     rng = np.random.default_rng(8)
     q = jnp.asarray(rng.normal(size=(1, 32, 1, 6)).astype(np.float32))
@@ -354,3 +354,81 @@ def test_self_attention_layer_attention_dropout():
     # rate 0.5 actually perturbs training
     assert not np.allclose(plain.get_flat_params(), dropped.get_flat_params(),
                            rtol=1e-4, atol=1e-5)
+
+
+def test_fallback_is_counted_and_logged_once_per_shape():
+    """use_pallas asked for, shapes that do not tile: the call still gives
+    way to the pure-JAX path (callers rely on it), but no longer in
+    silence — `pallas_fallback_total` counts every such trace and the
+    structured log says so once per shape."""
+    from deeplearning4j_tpu.kernels import flash_decode
+    from deeplearning4j_tpu.telemetry.logging import get_logger
+    from deeplearning4j_tpu.telemetry.registry import get_registry
+    q, k, v = _qkv(t=16, d=12, seed=2)               # D % 8 != 0: no plan
+    labels = dict(kernel="flash_attention", path="blockwise",
+                  shape="Tq=16,Tk=16,D=12,interpret=True")
+    before = (get_registry().get("pallas_fallback_total").get(**labels)
+              if get_registry().get("pallas_fallback_total") else 0)
+    logged = lambda: sum(1 for r in get_logger().buffer.records()
+                         if r["message"] == "pallas_fallback"
+                         and r["fields"].get("D") == 12)
+    logs0 = logged()
+    flash_attention(q, k, v, causal=True)
+    flash_attention(q, k, v, causal=True)
+    counter = get_registry().get("pallas_fallback_total")
+    assert counter.get(**labels) == before + 2
+    assert logged() - logs0 == (1 if before == 0 else 0)
+    # the compiled plan of a 64-entry decode cache: the serving default
+    # max_len a `use_pallas=True` server used to fall through unseen
+    n0 = counter.get(kernel="flash_decode", path="reference",
+                     shape="C=64,D=16,interpret=False")
+    qd = jnp.zeros((2, 1, 2, 16), jnp.float32)
+    kv = jnp.zeros((2, 64, 2, 16), jnp.float32)
+    flash_decode(qd, kv, kv, jnp.array([3, 5]), interpret=False)
+    assert counter.get(kernel="flash_decode", path="reference",
+                       shape="C=64,D=16,interpret=False") == n0 + 1
+
+
+def test_interpret_decision_has_one_home(monkeypatch):
+    """Compiled on 'tpu', interpreted on 'cpu', an error anywhere else."""
+    import importlib
+    fa = importlib.import_module("deeplearning4j_tpu.kernels.flash_attention")
+    assert fa._interpret_default() is True           # the tests' CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert fa._interpret_default() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        fa._interpret_default()
+    with pytest.raises(RuntimeError):
+        fa.can_flash(128, 128, 64)
+
+
+def test_kernels_run_per_shard_under_an_ambient_mesh():
+    """GSPMD cannot partition a Mosaic kernel, so under `jax.set_mesh` (the
+    serving mesh's dispatch seams) the kernels run inside a shard_map —
+    batch over the data axis, heads over the model axis — and answer what
+    the unsharded call answers."""
+    from deeplearning4j_tpu.kernels import flash_decode
+    from deeplearning4j_tpu.parallel.sharding import make_mesh
+    mesh = make_mesh(n_data=2, n_model=4)
+    q, k, v = _qkv(b=4, t=32, h=4, d=8, seed=11)
+    mask = jnp.asarray(np.arange(32)[None, :] < np.array([[32], [20], [7],
+                                                          [32]]))
+    attend = lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                             key_mask=mask)
+    lengths = jnp.array([5, 32, 1, 17])
+    decode = lambda q, k, v: flash_decode(q, k, v, lengths)
+    want = attend(q, k, v), decode(q[:, :1], k, v)
+    with jax.set_mesh(mesh):
+        assert "shard_map" in str(jax.make_jaxpr(attend)(q, k, v))
+        got = jax.jit(attend)(q, k, v), jax.jit(decode)(q[:, :1], k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=2e-5, atol=2e-6)
+    # 3 heads do not divide the model axis of 4: heads stay whole
+    q3, k3, v3 = _qkv(b=2, t=16, h=3, d=8, seed=12)
+    with jax.set_mesh(mesh):
+        got3 = jax.jit(lambda q, k, v: flash_attention(q, k, v))(q3, k3, v3)
+    np.testing.assert_allclose(np.asarray(got3),
+                               np.asarray(flash_attention(q3, k3, v3)),
+                               rtol=2e-5, atol=2e-6)

@@ -133,3 +133,24 @@ def test_csv_int_looking_fields_take_fast_path_as_floats():
     m = native.csv_parse(b"1,2,3\n")
     assert m is not None and m.tolist() == [[1.0, 2.0, 3.0]]
     assert [_coerce(v) for v in "1,2,3".split(",")] == [1.0, 2.0, 3.0]
+
+
+def test_staleness_is_decided_by_a_source_hash_not_by_file_times():
+    """A checkout makes up every mtime, so the library is current exactly
+    when the stamp beside it holds the hash of the source it was built
+    from — whatever the clocks say."""
+    import os
+    from deeplearning4j_tpu.native import build as b
+    assert b.build() == b.LIB
+    digest = b._source_hash()
+    with open(b.STAMP) as f:
+        assert f.read().strip() == digest
+    before = os.stat(b.LIB)
+    try:
+        os.utime(b.LIB, (0, 0))              # "older than the source"
+        assert b._built_from(digest)         # still current: same source
+        assert b.build() == b.LIB            # and not rebuilt
+        assert os.stat(b.LIB).st_mtime == 0
+    finally:
+        os.utime(b.LIB, (before.st_atime, before.st_mtime))
+    assert not b._built_from("0" * 64)       # another source: stale

@@ -1,0 +1,238 @@
+"""The main path's kernels and steps, compiled for a v5e that is described and
+not attached (on-chip-measurement guide, section 2.3) at the widths
+`chip_smoke.py` runs: what the chip's compiler would refuse — a block that
+does not tile, too much VMEM, a program that does not fit 16 GB — fails here,
+at no chip time. A compile that passes is not a chip run.
+
+Everything that touches the topology lives in module-scoped fixtures of this
+one file: only the worker that is handed this file loads the TPU compiler,
+and it compiles in its own process. The code under test picks interpret mode
+from `jax.default_backend()`, which is the CPU here, so the tests pass
+`interpret=False` (kernels) or steer `_interpret_default` (whole steps).
+"""
+import importlib
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from deeplearning4j_tpu.kernels.flash_attention import (flash_attention,
+                                                        flash_attention_lse,
+                                                        flash_decode,
+                                                        flash_decode_paged)
+
+# the module, not the function of the same name the package re-exports
+fa = importlib.import_module("deeplearning4j_tpu.kernels.flash_attention")
+KERNEL = "tpu_custom_call"
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def chip_config():
+    """The process as it is on the chip machine, for this module only: no
+    x64 (tests/conftest.py turns it on for the CPU's gradient checks; under
+    it the kernels' Python constants trace as f64, which Mosaic refuses and
+    no TPU run ever sees), and no persistent compilation cache (a compile for
+    a described chip is written to it but cannot be read back without the
+    chip)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    x64 = jax.config.jax_enable_x64
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_x64", False)
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_x64", x64)
+    jax.config.update("jax_enable_compilation_cache", cache)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def on_chip(one_chip, chip_config):
+    """shape, dtype -> a ShapeDtypeStruct placed on the described chip."""
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+
+
+def compiled_text(fn, *args):
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def graded(attend):
+    def loss(q, k, v, *rest):
+        out = attend(q, k, v, *rest)
+        out = out[0] if isinstance(out, tuple) else out
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+# [batch, time, heads, head_dim]: the transformer_lm train step of
+# chip_smoke.py / bench_transformer_lm, its kernel check, and the widest
+# shape the issue's author compiled
+SHAPES = [(16, 512, 4, 64), (4, 4096, 8, 64), (2, 4096, 16, 128)]
+DTYPES = [jnp.bfloat16, jnp.float32]
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=lambda d: jnp.dtype(d).name)
+@pytest.mark.parametrize("shape", SHAPES, ids=str)
+def test_flash_forward_compiles(on_chip, shape, dtype):
+    q = on_chip(shape, dtype)
+    text = compiled_text(
+        lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                        interpret=False), q, q, q)
+    assert text.count(KERNEL) == 1
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=lambda d: jnp.dtype(d).name)
+@pytest.mark.parametrize("shape", SHAPES, ids=str)
+def test_flash_forward_backward_causal_compiles(on_chip, shape, dtype):
+    q = on_chip(shape, dtype)
+    text = compiled_text(graded(
+        lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                        interpret=False)), q, q, q)
+    assert text.count(KERNEL) == 3          # forward, dQ, dK/dV
+
+
+@pytest.mark.parametrize("shape", SHAPES[:2], ids=str)
+def test_flash_forward_backward_masked_compiles(on_chip, shape):
+    q = on_chip(shape, jnp.bfloat16)
+    mask = on_chip(shape[:2], jnp.float32)
+    text = compiled_text(graded(
+        lambda q, k, v, m: flash_attention(q, k, v, causal=True, key_mask=m,
+                                           interpret=False)), q, q, q, mask)
+    assert text.count(KERNEL) == 3
+
+
+@pytest.mark.parametrize("shape", SHAPES[:2], ids=str)
+def test_flash_lse_offsets_forward_backward_compiles(on_chip, shape):
+    """The ring-attention variant: (out, lse) primal pair with dynamic
+    global q/k offsets riding in SMEM."""
+    q = on_chip(shape, jnp.bfloat16)
+    off = on_chip((), jnp.int32)
+
+    def attend(q, k, v, qo, ko):
+        return flash_attention_lse(q, k, v, causal=True, q_offset=qo,
+                                   k_offset=ko, interpret=False)
+    text = compiled_text(graded(attend), q, q, q, off, off)
+    assert text.count(KERNEL) == 3
+
+
+# the decode shapes of chip_smoke.py's server: 8 slots, capacity 256,
+# 4 heads of 64; and a long cache
+@pytest.mark.parametrize("capacity", [256, 4096])
+def test_flash_decode_compiles(on_chip, capacity):
+    q = on_chip((8, 1, 4, 64), jnp.bfloat16)
+    kv = on_chip((8, capacity, 4, 64), jnp.bfloat16)
+    lengths = on_chip((8,), jnp.int32)
+    text = compiled_text(
+        lambda q, k, v, n: flash_decode(q, k, v, n, interpret=False),
+        q, kv, kv, lengths)
+    assert text.count(KERNEL) == 1
+
+
+def test_flash_decode_paged_compiles(on_chip):
+    slots, block, blocks_per_slot = 8, 16, 16        # capacity 256
+    q = on_chip((slots, 1, 4, 64), jnp.bfloat16)
+    pool = on_chip((slots * blocks_per_slot + 1, block, 4, 64), jnp.bfloat16)
+    table = on_chip((slots, blocks_per_slot), jnp.int32)
+    lengths = on_chip((slots,), jnp.int32)
+    text = compiled_text(
+        lambda q, k, v, t, n: flash_decode_paged(q, k, v, t, n,
+                                                 interpret=False),
+        q, pool, pool, table, lengths)
+    assert text.count(KERNEL) == 1
+
+
+def test_untileable_shape_has_no_compiled_plan():
+    """Why chip_smoke.py asks for prompts of 128 tokens and more: compiled,
+    the key block must be a multiple of 128, so a 64-token prefill bucket or
+    a 64-entry cache has no plan and gives way to the pure-JAX path."""
+    assert fa._plan(64, 64, 64, 256, 1024, interpret=False) is None
+    assert fa._plan(8, 64, 64, 8, 1024, interpret=False) is None
+    assert fa._plan(128, 128, 64, 256, 1024, interpret=False) == (128, 128)
+    assert fa._plan(64, 64, 64, 256, 1024, interpret=True) == (64, 64)
+
+
+# ------------------------------------------------ whole steps of the models
+def _abstract(tree, sharding):
+    def leaf(a):
+        if hasattr(a, "shape") and hasattr(a, "dtype"):
+            return jax.ShapeDtypeStruct(
+                a.shape, jax.dtypes.canonicalize_dtype(a.dtype),
+                sharding=sharding)
+        return a
+    return jax.tree_util.tree_map(leaf, tree)
+
+
+@pytest.fixture(scope="module")
+def lm_engine(chip_config):
+    """chip_smoke.py's decoder: the 256-wide transformer_lm behind an
+    8-slot, 256-token decode engine (built on the CPU; only its shapes go to
+    the compiler)."""
+    from deeplearning4j_tpu.decode.engine import DecodeEngine
+    from deeplearning4j_tpu.zoo.models import transformer_lm
+    net = transformer_lm(vocab_size=256, d_model=256, n_layers=4, n_heads=4,
+                         use_pallas=True, compute_dtype="bfloat16").init()
+    return DecodeEngine(net, slots=8, max_len=256)
+
+
+def test_decode_step_compiles_with_kernel(lm_engine, one_chip,
+                                          chip_config, monkeypatch):
+    monkeypatch.setattr(fa, "_interpret_default", lambda: False)
+    eng = lm_engine
+    args = _abstract((eng.model.params, eng.model.states, eng.init_cache(),
+                      np.zeros((eng.slots,), np.int32),
+                      eng._greedy_step_ops), one_chip)
+    text = eng._build_step().lower(*args, None).compile().as_text()
+    assert text.count(KERNEL) == 4          # one flash_decode per layer
+
+
+@pytest.mark.parametrize("bucket", [128, 256])
+def test_prefill_bucket_compiles_with_kernel(lm_engine, one_chip,
+                                             chip_config, monkeypatch,
+                                             bucket):
+    monkeypatch.setattr(fa, "_interpret_default", lambda: False)
+    eng = lm_engine
+    args = _abstract((eng.model.params, eng.model.states, eng.init_cache(),
+                      np.int32(0), np.zeros((bucket,), np.int32),
+                      np.int32(bucket - 1), eng._greedy_slot_ops), one_chip)
+    text = eng._build_prefill(bucket).lower(*args, None).compile().as_text()
+    assert text.count(KERNEL) == 4          # one masked flash per layer
+
+
+@pytest.mark.slow
+def test_resnet50_train_step_fits_one_chip(one_chip, chip_config):
+    """chip_smoke.py's train phase: ResNet-50, batch 256, 224 x 224, bf16,
+    uint8 pixels + int32 ids with the ingest fused into the step."""
+    from deeplearning4j_tpu.etl.device_transform import DeviceIngest
+    from deeplearning4j_tpu.nn.updaters import Nesterovs
+    from deeplearning4j_tpu.zoo.models import resnet50
+    net = resnet50(num_classes=1000, image_size=224,
+                   updater=Nesterovs(learning_rate=0.05, momentum=0.9),
+                   compute_dtype="bfloat16").init()
+    net.set_ingest(DeviceIngest(one_hot_labels=1000))
+    args = _abstract((net.params, net.opt_state, net.states, net._rng,
+                      [np.zeros((256, 224, 224, 3), np.uint8)],
+                      [np.zeros((256,), np.int32)]), one_chip)
+    comp = net._make_train_step().lower(*args, None, None, None).compile()
+    mem = comp.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9

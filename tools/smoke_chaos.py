@@ -14,7 +14,7 @@ degradation paths with a FaultPlan installed into util.http:
    auto-rolls b back to v1 — with zero 5xx reaching front-end clients
    (each failed canary attempt failed over to the stable cohort).
 
-Usage:
+Usage (on the CPU; without JAX_PLATFORMS it runs on the default platform):
     JAX_PLATFORMS=cpu python tools/smoke_chaos.py [-n 8]
 """
 from __future__ import annotations
@@ -115,6 +115,8 @@ def run(n_requests=6, nin=6, seed=0):
 
 
 def main(argv=None):
+    from deeplearning4j_tpu.util.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("-n", "--n-requests", type=int, default=6)
     args = ap.parse_args(argv)

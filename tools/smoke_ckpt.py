@@ -168,6 +168,8 @@ def run(root):
 
 
 def main():
+    from deeplearning4j_tpu.util.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import json
     import tempfile
     with tempfile.TemporaryDirectory() as d:

@@ -17,7 +17,7 @@ populated with exemplar-ready observations; (d) token-for-token parity:
 every concurrent request's output equals the model's own isolated
 net.generate run (per-request independence from co-batched neighbors).
 
-Usage:
+Usage (on the CPU; without JAX_PLATFORMS it runs on the default platform):
     JAX_PLATFORMS=cpu python tools/smoke_decode.py [-n 8] [-t 6]
 """
 from __future__ import annotations
@@ -140,6 +140,8 @@ def run(n_requests=8, max_new_tokens=6, slots=3, max_len=64):
 
 
 def main():
+    from deeplearning4j_tpu.util.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("-n", "--requests", type=int, default=8)
     ap.add_argument("-t", "--max-new-tokens", type=int, default=6)

@@ -23,7 +23,7 @@ speculative verify, end to end — three arcs over one serving stack:
    pass, and the greedy speculative stream is token-for-token identical
    to target-only decoding, with executable cache sizes of exactly 1.
 
-Usage:
+Usage (on the CPU; without JAX_PLATFORMS it runs on the default platform):
     JAX_PLATFORMS=cpu python tools/smoke_decode_v2.py [-n 8]
 """
 from __future__ import annotations
@@ -258,6 +258,8 @@ def run(n_requests=8):
 
 
 def main():
+    from deeplearning4j_tpu.util.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("-n", "--requests", type=int, default=8)
     args = ap.parse_args()

@@ -25,7 +25,7 @@ Every transition lands in the frontend registry
 trace-correlated structured logs, and — scraped over a FleetServer —
 /fleet/metrics //fleet/healthz.
 
-Usage:
+Usage (on the CPU; without JAX_PLATFORMS it runs on the default platform):
     JAX_PLATFORMS=cpu python tools/smoke_elastic.py
 """
 from __future__ import annotations
@@ -194,6 +194,8 @@ def run(burst_rate=2000.0, burst_s=0.05, nin=6, seed=0, scan_dir=None):
 
 
 def main(argv=None):
+    from deeplearning4j_tpu.util.compile_cache import enable_compile_cache
+    enable_compile_cache()
     with tempfile.TemporaryDirectory() as d:
         out = run(scan_dir=d)
     print("elastic smoke OK:", json.dumps(out))

@@ -12,7 +12,7 @@ state trains with ZERO recompiles after the first epoch (jit_compiles_total
 stable), and (c) the telemetry layer saw the pipeline (etl_batches_total,
 etl_consumer_wait_ms populated).
 
-Usage:
+Usage (on the CPU; without JAX_PLATFORMS it runs on the default platform):
     JAX_PLATFORMS=cpu python tools/smoke_etl.py [-n 512] [-w 4] [-e 8]
 """
 from __future__ import annotations
@@ -123,6 +123,8 @@ def run(n_rows=512, workers=4, epochs=8, batch_size=32, seed=0):
 
 
 def main(argv=None):
+    from deeplearning4j_tpu.util.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("-n", "--n-rows", type=int, default=512)
     ap.add_argument("-w", "--workers", type=int, default=4)

@@ -12,7 +12,7 @@ Boots TWO ServingServers plus a FleetServer over both, then:
    totals), `/fleet/healthz` (worst-status aggregation), and `/fleet/trace`
    (one pid lane per host, process_name metadata).
 
-Usage:
+Usage (on the CPU; without JAX_PLATFORMS it runs on the default platform):
     JAX_PLATFORMS=cpu python tools/smoke_fleet.py [-n 8]
 """
 from __future__ import annotations
@@ -101,6 +101,8 @@ def run(n_requests=8, nin=6, seed=0):
 
 
 def main(argv=None):
+    from deeplearning4j_tpu.util.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("-n", "--n-requests", type=int, default=8)
     args = ap.parse_args(argv)

@@ -14,7 +14,7 @@ Boots a ServingServer with a registered model, then:
    records at `GET /logs` carry trace ids matching the training iteration
    spans (the /logs <-> /trace join).
 
-Usage:
+Usage (on the CPU; without JAX_PLATFORMS it runs on the default platform):
     JAX_PLATFORMS=cpu python tools/smoke_health.py
 """
 from __future__ import annotations
@@ -126,6 +126,8 @@ def run(nin=6, n_batches=4, seed=0):
 
 
 def main(argv=None):
+    from deeplearning4j_tpu.util.compile_cache import enable_compile_cache
+    enable_compile_cache()
     out = run()
     print("health smoke OK:", json.dumps(out))
     return 0

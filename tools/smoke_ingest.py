@@ -20,7 +20,7 @@ warning this PR fixed), (d) the h2d byte counter saw narrow bytes (uint8
 ids, packed features — not widened float32), and (e) device/host parity on
 a held-out batch.
 
-Usage:
+Usage (on the CPU; without JAX_PLATFORMS it runs on the default platform):
     JAX_PLATFORMS=cpu python tools/smoke_ingest.py [-n 384] [-e 6]
 """
 from __future__ import annotations
@@ -187,6 +187,8 @@ def run(n_rows=384, epochs=6, batch_size=32, seed=0):
 
 
 def main(argv=None):
+    from deeplearning4j_tpu.util.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("-n", "--n-rows", type=int, default=384)
     ap.add_argument("-e", "--epochs", type=int, default=6)

@@ -21,9 +21,9 @@ server over the SAME ModelSerializer zip, then:
    unit — one cohort member, the whole group back to stable, zero client
    5xx throughout.
 
-Usage:
-    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-        python tools/smoke_mesh.py [-n 12] [-g 4]
+Usage (needs 8 devices: 8 virtual CPU devices unless the environment names
+another platform, e.g. JAX_PLATFORMS=tpu on a host with 8 chips):
+    python tools/smoke_mesh.py [-n 12] [-g 4]
 """
 from __future__ import annotations
 
@@ -247,9 +247,12 @@ def _drive(mesh_srv, ref_srv, fe, prompts, pred_xs, max_new_tokens,
 
 
 def main(argv=None):
+    # before JAX is imported: with nothing said, rehearse on the CPU
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     os.environ.setdefault("XLA_FLAGS",
                           "--xla_force_host_platform_device_count=8")
+    from deeplearning4j_tpu.util.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("-n", "--predict-requests", type=int, default=12)
     ap.add_argument("-g", "--generate-requests", type=int, default=4)

@@ -18,7 +18,7 @@ serving pays ZERO recompiles after the deploy warm-up (compiles_total flat
 across repeated /predict waves AND the output executable's XLA cache stays
 at one entry), and (e) NO XLA donation warning fires anywhere in the run.
 
-Usage:
+Usage (on the CPU; without JAX_PLATFORMS it runs on the default platform):
     JAX_PLATFORMS=cpu python tools/smoke_quant.py [-e 30]
 """
 from __future__ import annotations
@@ -148,6 +148,8 @@ def run(steps=30):
 
 
 def main(argv=None):
+    from deeplearning4j_tpu.util.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("-e", "--steps", type=int, default=30)
     args = ap.parse_args(argv)

@@ -7,7 +7,7 @@ pool, and asserts zero errors plus a p99 latency budget. The default run
 (200 requests) is the heavy variant invoked by the `slow`-marked test;
 tier-1 runs a lighter request count through `run()`.
 
-Usage:
+Usage (on the CPU; without JAX_PLATFORMS it runs on the default platform):
     JAX_PLATFORMS=cpu python tools/smoke_serving.py [-n 200] [-c 16]
 """
 from __future__ import annotations
@@ -115,6 +115,8 @@ def run(n_requests=200, concurrency=16, max_rows=4, p99_budget_ms=10000.0,
 
 
 def main(argv=None):
+    from deeplearning4j_tpu.util.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("-n", "--n-requests", type=int, default=200)
     ap.add_argument("-c", "--concurrency", type=int, default=16)

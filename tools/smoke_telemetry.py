@@ -9,7 +9,7 @@ injected by util.http.post_json -> handler server span -> trace context
 propagated through the admission queue -> batcher batch/dispatch spans +
 span links -> compile accounting -> registry -> exposition.
 
-Usage:
+Usage (on the CPU; without JAX_PLATFORMS it runs on the default platform):
     JAX_PLATFORMS=cpu python tools/smoke_telemetry.py [-n 32] [-c 8]
 """
 from __future__ import annotations
@@ -110,6 +110,8 @@ def run(n_requests=32, concurrency=8, nin=6, seed=0):
 
 
 def main(argv=None):
+    from deeplearning4j_tpu.util.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("-n", "--n-requests", type=int, default=32)
     ap.add_argument("-c", "--concurrency", type=int, default=8)
