@@ -67,6 +67,7 @@ from ..nn.layers.feedforward import (DenseLayerModule, EmbeddingLayerModule,
 from ..nn.layers.misc import ActivationLayerModule, DropoutLayerModule
 from ..nn.layers.recurrent import (GravesBidirectionalLSTMModule,
                                    SelfAttentionLayerModule, _BaseLSTMModule)
+from ..telemetry.trace import get_tracer
 from ..telemetry.xla import record_jit_compile
 from ..util.time_source import monotonic_s
 from . import sampling as _sampling
@@ -187,7 +188,7 @@ def build_plan(model):
 class DecodeEngine:
     def __init__(self, model, *, slots=4, max_len=128, compile_tracker=None,
                  registry=None, paged=False, block_size=16, num_blocks=None,
-                 cost_registry=None):
+                 cost_registry=None, tracer=None):
         self.model = model
         self.slots = int(slots)
         self.capacity = int(max_len)
@@ -232,6 +233,23 @@ class DecodeEngine:
         # its wall time sampled every Nth dispatch (the sync is paid only on
         # sampled dispatches — decode steps are otherwise async)
         self.cost_registry = cost_registry
+        # the step's three host phases (Tracer.phase): profiler annotation
+        # + histogram (with a registry) + a `<phase>_ms` attribute folded
+        # into the scheduler's decode_wave span
+        self.tracer = tracer if tracer is not None else get_tracer()
+        self._m_dispatch = self._m_sync = self._m_probs_read = None
+        if registry is not None:
+            self._m_dispatch = registry.histogram(
+                "decode_step_dispatch_ms", "The jitted decode step call "
+                "until it returns (enqueue cost; on a mesh it waits for "
+                "the device inside the run lock), ms")
+            self._m_sync = registry.histogram(
+                "decode_step_sync_ms", "Host read of the step's next ids: "
+                "the wait for the device, ms")
+            self._m_probs_read = registry.histogram(
+                "decode_probs_read_ms", "Host read of the step's "
+                "[slots, vocab] probabilities, ms")
+        self.last_step_s = 0.0      # dispatch + sync + probs_read of step()
         # mesh-sharded decode (serving/mesh.py): a wrapped model carries the
         # serving MeshContext; the KV cache partitions its head axis over
         # the mesh model axis and the step/prefill executables pin the
@@ -314,6 +332,14 @@ class DecodeEngine:
         return total
 
     # ------------------------------------------------------------ walks
+    def _node_scope(self, node):
+        """jax.named_scope of one walked node: its name on its operations
+        in the lowered text and in a device trace, the output node's under
+        `lm_head`. Within an attention layer the cache write is `kv_append`
+        and the read `attention`."""
+        return jax.named_scope(node.name if node.name != self.output_name
+                               else "lm_head/" + node.name)
+
     def _paged_append_seq(self, entry, t, row):
         """Scatter a [L, H, Dh] token sequence into the pool along `row`
         (the slot's table row): the L positions reshape into L/bs chunks of
@@ -341,50 +367,59 @@ class DecodeEngine:
         for node in self.nodes:
             if node.kind == "input":
                 continue
-            if node.kind == "vertex":
-                acts[node.name] = node.vertex.apply(
-                    [acts[i] for i in node.inputs])
-                continue
-            m = node.module
-            p, s = params[node.name], states[node.name]
-            x = acts[node.inputs[0]]
-            if isinstance(m, SelfAttentionLayerModule):
-                q, k, v = m.project_qkv(p, x)             # [1, L, H, Dh]
-                out = m.attend(q, k, v, mask)
-                y = m.finish(p, out, mask)
-                entry = layers[node.name]
-                if table is not None:
+            with self._node_scope(node):
+                if node.kind == "vertex":
+                    acts[node.name] = node.vertex.apply(
+                        [acts[i] for i in node.inputs])
+                    continue
+                m = node.module
+                p, s = params[node.name], states[node.name]
+                x = acts[node.inputs[0]]
+                if isinstance(m, SelfAttentionLayerModule):
+                    q, k, v = m.project_qkv(p, x)             # [1, L, H, Dh]
+                    with jax.named_scope("attention"):
+                        out = m.attend(q, k, v, mask)
+                    y = m.finish(p, out, mask)
+                    entry = layers[node.name]
+                    with jax.named_scope("kv_append"):
+                        if table is not None:
+                            layers[node.name] = {
+                                "k": self._paged_append_seq(entry["k"], k[0],
+                                                            row),
+                                "v": self._paged_append_seq(entry["v"], v[0],
+                                                            row)}
+                        else:
+                            # match the traced slot's index dtype under x64
+                            z = jnp.zeros((), slot.dtype)
+                            layers[node.name] = {
+                                "k": lax.dynamic_update_slice(
+                                    entry["k"], k.astype(entry["k"].dtype),
+                                    (slot, z, z, z)),
+                                "v": lax.dynamic_update_slice(
+                                    entry["v"], v.astype(entry["v"].dtype),
+                                    (slot, z, z, z))}
+                elif isinstance(m, _BaseLSTMModule):
+                    n_out = int(m.conf.n_out)
+                    zeros = (jnp.zeros((1, n_out), self._dtype),
+                             jnp.zeros((1, n_out), self._dtype))
+                    # masked steps carry state through (the scan's contract),
+                    # so the final carry equals the state after `length` real
+                    # steps
+                    y, _, _, (hf, cf) = m.forward(p, s, x, mask=mask,
+                                                  initial_state=zeros,
+                                                  return_state=True)
+                    entry = layers[node.name]
+                    z = jnp.zeros((), slot.dtype)
                     layers[node.name] = {
-                        "k": self._paged_append_seq(entry["k"], k[0], row),
-                        "v": self._paged_append_seq(entry["v"], v[0], row)}
+                        "h": lax.dynamic_update_slice(
+                            entry["h"], hf.astype(entry["h"].dtype),
+                            (slot, z)),
+                        "c": lax.dynamic_update_slice(
+                            entry["c"], cf.astype(entry["c"].dtype),
+                            (slot, z))}
                 else:
-                    z = jnp.zeros((), slot.dtype)  # match the traced slot's
-                    layers[node.name] = {          # index dtype under x64
-                        "k": lax.dynamic_update_slice(
-                            entry["k"], k.astype(entry["k"].dtype),
-                            (slot, z, z, z)),
-                        "v": lax.dynamic_update_slice(
-                            entry["v"], v.astype(entry["v"].dtype),
-                            (slot, z, z, z))}
-            elif isinstance(m, _BaseLSTMModule):
-                n_out = int(m.conf.n_out)
-                zeros = (jnp.zeros((1, n_out), self._dtype),
-                         jnp.zeros((1, n_out), self._dtype))
-                # masked steps carry state through (the scan's contract), so
-                # the final carry equals the state after `length` real steps
-                y, _, _, (hf, cf) = m.forward(p, s, x, mask=mask,
-                                              initial_state=zeros,
-                                              return_state=True)
-                entry = layers[node.name]
-                z = jnp.zeros((), slot.dtype)
-                layers[node.name] = {
-                    "h": lax.dynamic_update_slice(
-                        entry["h"], hf.astype(entry["h"].dtype), (slot, z)),
-                    "c": lax.dynamic_update_slice(
-                        entry["c"], cf.astype(entry["c"].dtype), (slot, z))}
-            else:
-                y = m.forward(p, s, x, train=False, rng=None, mask=mask)[0]
-            acts[node.name] = y
+                    y = m.forward(p, s, x, train=False, rng=None, mask=mask)[0]
+                acts[node.name] = y
         return acts[self.output_name], layers
 
     def _walk_step(self, params, states, x0, cache, pos, kv_valid,
@@ -406,48 +441,53 @@ class DecodeEngine:
         for node in self.nodes:
             if node.kind == "input":
                 continue
-            if node.kind == "vertex":
-                acts[node.name] = node.vertex.apply(
-                    [acts[i] for i in node.inputs])
-                continue
-            m = node.module
-            p, s = params[node.name], states[node.name]
-            x = acts[node.inputs[0]]
-            if isinstance(m, SelfAttentionLayerModule):
-                q, kt, vt = m.project_qkv(p, x)           # [S, 1, H, Dh]
-                entry = layers[node.name]
-                if table is not None:
-                    nk = entry["k"].at[blk, off].set(
-                        kt[:, 0].astype(entry["k"].dtype))
-                    nv = entry["v"].at[blk, off].set(
-                        vt[:, 0].astype(entry["v"].dtype))
+            with self._node_scope(node):
+                if node.kind == "vertex":
+                    acts[node.name] = node.vertex.apply(
+                        [acts[i] for i in node.inputs])
+                    continue
+                m = node.module
+                p, s = params[node.name], states[node.name]
+                x = acts[node.inputs[0]]
+                if isinstance(m, SelfAttentionLayerModule):
+                    q, kt, vt = m.project_qkv(p, x)           # [S, 1, H, Dh]
+                    entry = layers[node.name]
+                    use_pallas = getattr(m.conf, "use_pallas", False)
+                    if table is not None:
+                        with jax.named_scope("kv_append"):
+                            nk = entry["k"].at[blk, off].set(
+                                kt[:, 0].astype(entry["k"].dtype))
+                            nv = entry["v"].at[blk, off].set(
+                                vt[:, 0].astype(entry["v"].dtype))
+                        with jax.named_scope("attention"):
+                            out = flash_decode_paged(q, nk, nv, table,
+                                                     kv_valid,
+                                                     use_pallas=use_pallas)
+                    else:
+                        append = jax.vmap(
+                            lambda row, t, at: lax.dynamic_update_slice(
+                                row, t, (at, jnp.zeros((), at.dtype),
+                                         jnp.zeros((), at.dtype))))
+                        with jax.named_scope("kv_append"):
+                            nk = append(entry["k"],
+                                        kt.astype(entry["k"].dtype), pos)
+                            nv = append(entry["v"],
+                                        vt.astype(entry["v"].dtype), pos)
+                        with jax.named_scope("attention"):
+                            out = flash_decode(q, nk, nv, kv_valid,
+                                               use_pallas=use_pallas)
                     layers[node.name] = {"k": nk, "v": nv}
-                    out = flash_decode_paged(
-                        q, nk, nv, table, kv_valid,
-                        use_pallas=getattr(m.conf, "use_pallas", False))
+                    y = m.finish(p, out.astype(x.dtype), None)
+                elif isinstance(m, _BaseLSTMModule):
+                    entry = layers[node.name]
+                    y, _, _, (hf, cf) = m.forward(
+                        p, s, x, initial_state=(entry["h"], entry["c"]),
+                        return_state=True)
+                    layers[node.name] = {"h": hf.astype(entry["h"].dtype),
+                                         "c": cf.astype(entry["c"].dtype)}
                 else:
-                    append = jax.vmap(
-                        lambda row, t, at: lax.dynamic_update_slice(
-                            row, t, (at, jnp.zeros((), at.dtype),
-                                     jnp.zeros((), at.dtype))))
-                    nk = append(entry["k"], kt.astype(entry["k"].dtype), pos)
-                    nv = append(entry["v"], vt.astype(entry["v"].dtype), pos)
-                    layers[node.name] = {"k": nk, "v": nv}
-                    out = flash_decode(q, nk, nv, kv_valid,
-                                       use_pallas=getattr(m.conf,
-                                                          "use_pallas",
-                                                          False))
-                y = m.finish(p, out.astype(x.dtype), None)
-            elif isinstance(m, _BaseLSTMModule):
-                entry = layers[node.name]
-                y, _, _, (hf, cf) = m.forward(
-                    p, s, x, initial_state=(entry["h"], entry["c"]),
-                    return_state=True)
-                layers[node.name] = {"h": hf.astype(entry["h"].dtype),
-                                     "c": cf.astype(entry["c"].dtype)}
-            else:
-                y = m.forward(p, s, x, train=False, rng=None)[0]
-            acts[node.name] = y
+                    y = m.forward(p, s, x, train=False, rng=None)[0]
+                acts[node.name] = y
         return acts[self.output_name], layers
 
     @staticmethod
@@ -482,30 +522,33 @@ class DecodeEngine:
         for node in self.nodes:
             if node.kind == "input":
                 continue
-            if node.kind == "vertex":
-                acts[node.name] = node.vertex.apply(
-                    [acts[i] for i in node.inputs])
-                continue
-            m = node.module
-            p, s = params[node.name], states[node.name]
-            x = acts[node.inputs[0]]
-            if isinstance(m, SelfAttentionLayerModule):
-                q, k, v = m.project_qkv(p, x)             # [1, W, H, Dh]
-                entry = layers[node.name]
-                z = jnp.zeros((), slot.dtype)
-                st = jnp.asarray(start, slot.dtype)
-                nk = lax.dynamic_update_slice(
-                    entry["k"], k.astype(entry["k"].dtype), (slot, st, z, z))
-                nv = lax.dynamic_update_slice(
-                    entry["v"], v.astype(entry["v"].dtype), (slot, st, z, z))
-                layers[node.name] = {"k": nk, "v": nv}
-                krow = lax.dynamic_index_in_dim(nk, slot, 0, keepdims=True)
-                vrow = lax.dynamic_index_in_dim(nv, slot, 0, keepdims=True)
-                out = self._verify_attend(q, krow, vrow, start)
-                y = m.finish(p, out.astype(x.dtype), None)
-            else:
-                y = m.forward(p, s, x, train=False, rng=None)[0]
-            acts[node.name] = y
+            with self._node_scope(node):
+                if node.kind == "vertex":
+                    acts[node.name] = node.vertex.apply(
+                        [acts[i] for i in node.inputs])
+                    continue
+                m = node.module
+                p, s = params[node.name], states[node.name]
+                x = acts[node.inputs[0]]
+                if isinstance(m, SelfAttentionLayerModule):
+                    q, k, v = m.project_qkv(p, x)             # [1, W, H, Dh]
+                    entry = layers[node.name]
+                    z = jnp.zeros((), slot.dtype)
+                    st = jnp.asarray(start, slot.dtype)
+                    nk = lax.dynamic_update_slice(
+                        entry["k"], k.astype(entry["k"].dtype),
+                        (slot, st, z, z))
+                    nv = lax.dynamic_update_slice(
+                        entry["v"], v.astype(entry["v"].dtype),
+                        (slot, st, z, z))
+                    layers[node.name] = {"k": nk, "v": nv}
+                    krow = lax.dynamic_index_in_dim(nk, slot, 0, keepdims=True)
+                    vrow = lax.dynamic_index_in_dim(nv, slot, 0, keepdims=True)
+                    out = self._verify_attend(q, krow, vrow, start)
+                    y = m.finish(p, out.astype(x.dtype), None)
+                else:
+                    y = m.forward(p, s, x, train=False, rng=None)[0]
+                acts[node.name] = y
         return acts[self.output_name], layers
 
     # ------------------------------------------------------- executables
@@ -529,7 +572,9 @@ class DecodeEngine:
             probs = y[:, -1].astype(jnp.float32)          # [S, V]
             new_cache = {"lengths": jnp.minimum(lengths + 1, C),
                          "layers": layers}
-            return new_cache, _sampling.sample_tokens(probs, samp), probs
+            with jax.named_scope("sample"):
+                nxt = _sampling.sample_tokens(probs, samp)
+            return new_cache, nxt, probs
 
         return jax.jit(step_fn, donate_argnums=(2,), **self._jit_sharding())
 
@@ -551,8 +596,9 @@ class DecodeEngine:
             probs = probs.astype(jnp.float32)
             new_cache = {"lengths": cache["lengths"].at[slot].set(length),
                          "layers": layers}
-            return new_cache, _sampling.sample_tokens(probs[None],
-                                                      samp)[0], probs
+            with jax.named_scope("sample"):
+                nid = _sampling.sample_tokens(probs[None], samp)[0]
+            return new_cache, nid, probs
 
         return jax.jit(prefill_fn, donate_argnums=(2,),
                        **self._jit_sharding())
@@ -594,7 +640,7 @@ class DecodeEngine:
         if placer is not None:
             placer()
 
-    def _run(self, fn, label, bucket, *args):
+    def _run(self, fn, label, bucket, *args, sample=True):
         """Invoke a decode executable. On a mesh, the call takes the
         context's run_lock and blocks until ready inside it: one
         partitioned wave in flight per mesh, or concurrently-launched
@@ -602,26 +648,27 @@ class DecodeEngine:
         interleave their rendezvous participants and deadlock XLA's CPU
         runtime. Single-chip engines skip both."""
         if self.mesh is None:
-            return self._timed(fn, label, bucket, *args)
+            return self._timed(fn, label, bucket, *args, sample=sample)
         # set_mesh: the decode kernels run per shard of the ambient mesh
         # (kernels/flash_attention._per_shard); the cost plane's shadow
         # lower inside _timed sees the same mesh
         with self.mesh.run_lock, jax.set_mesh(self.mesh.mesh):
-            out = self._timed(fn, label, bucket, *args)
+            out = self._timed(fn, label, bucket, *args, sample=sample)
             jax.block_until_ready(out)
             return out
 
-    def _timed(self, fn, label, bucket, *args):
+    def _timed(self, fn, label, bucket, *args, sample=True):
         """Invoke a decode executable; the first call per label is the XLA
         compile and is timed into the compile accounting (CompileTracker
         phase="decode" + jit_compiles_total), same discipline as the
         batcher's observed buckets. With a cost registry attached, the first
         call also captures the executable's XLA costs (from an abstract-arg
         snapshot taken BEFORE the donating call) and every Nth later call is
-        wall-timed into the sampled dispatch_ms histogram."""
+        wall-timed into the sampled dispatch_ms histogram (`sample=False`:
+        the caller times the call itself, as step() does)."""
         cr = self.cost_registry
         if label in self._compiled:
-            if cr is not None and cr.dispatch_due(label):
+            if sample and cr is not None and cr.dispatch_due(label):
                 t0 = monotonic_s()
                 out = fn(*args)
                 jax.block_until_ready(out[1])
@@ -762,11 +809,31 @@ class DecodeEngine:
             if self._step_fn is None:
                 self._step_fn = self._build_step()
             fn = self._step_fn
-        cache, nxt, probs = self._run(
-            fn, "decode_step", "step", self.model.params, self.model.states,
-            cache, ids, self._step_operands(sampling),
-            table if self.paged else None)
-        return cache, np.asarray(nxt), np.asarray(probs)
+        label = "decode_step"
+        warm = label in self._compiled
+        cr = self.cost_registry
+        phase = self.tracer.phase
+        with phase("decode_step_dispatch", histogram=self._m_dispatch,
+                   fold=True) as dispatch:
+            cache, nxt, probs = self._run(
+                fn, label, "step", self.model.params, self.model.states,
+                cache, ids, self._step_operands(sampling),
+                table if self.paged else None, sample=False)
+            if not warm:            # the compile: _timed has accounted it
+                dispatch.cancel()
+        with phase("decode_step_sync", histogram=self._m_sync,
+                   fold=True) as sync:
+            nxt = np.asarray(nxt)
+        with phase("decode_probs_read", histogram=self._m_probs_read,
+                   fold=True) as read:
+            probs = np.asarray(probs)
+        step_ms = dispatch.duration_ms + sync.duration_ms
+        self.last_step_s = (step_ms + read.duration_ms) / 1000.0
+        if warm and cr is not None and cr.dispatch_due(label):
+            # every Nth step's wall (call + wait for the ids) is the cost
+            # plane's dispatch sample: the wait above is the sync it needs
+            cr.observe_dispatch(label, step_ms)
+        return cache, nxt, probs
 
     def has_recurrent(self):
         return any(node.kind == "layer"

@@ -16,6 +16,16 @@ Every loop iteration:
    spent — a deadline mid-generation returns the PARTIAL result with
    finish_reason="deadline", not an error).
 
+Every pass is one `decode_wave` phase (telemetry/trace.py `Tracer.phase`:
+profiler annotation "dl4j:<name>" + histogram `<name>_ms` + ring span) whose
+parts fold into it: `decode_admit` (a pass that admitted something; holds
+the per-request `decode_queue_wait` and `decode_prefill`),
+`decode_step_build` (host work before the dispatch), the engine's
+`decode_step_dispatch` / `decode_step_sync` / `decode_probs_read`, and
+`decode_emit` (tokens appended, retirements, futures completed). The ring
+gets one span a pass with its parts' durations as attributes;
+`decode_itl_ms` is the sum of the engine's three, from the same clock reads.
+
 Requests therefore join and leave the in-flight batch per token with zero
 steady-state recompiles: after the step executable and a prompt-length
 bucket have compiled once, no request mix recompiles anything
@@ -69,7 +79,8 @@ from .sampling import batch_operands
 class GenerateRequest:
     __slots__ = ("prompt", "max_new_tokens", "stop_id", "future", "deadline",
                  "enqueued_at", "trace_ctx", "tokens", "slot", "version",
-                 "ttft_ms", "finish_reason", "sampler", "admit_seq")
+                 "ttft_ms", "queue_wait_ms", "finish_reason", "sampler",
+                 "admit_seq")
 
     def __init__(self, prompt, max_new_tokens, stop_id=None, deadline=None,
                  sampler=None):
@@ -84,6 +95,7 @@ class GenerateRequest:
         self.slot = None
         self.version = None
         self.ttft_ms = None
+        self.queue_wait_ms = None         # enqueue -> popped with a slot
         self.finish_reason = None
         self.sampler = sampler            # SamplerConfig or None (greedy)
         self.admit_seq = None             # admission order; youngest preempts
@@ -98,6 +110,7 @@ class GenerateRequest:
             "n_prompt": len(self.prompt),
             "version": self.version,
             "ttft_ms": self.ttft_ms,
+            "queue_wait_ms": self.queue_wait_ms,
             "finish_reason": self.finish_reason,
         })
 
@@ -174,6 +187,25 @@ class DecodeScheduler:
             "decode_itl_ms", "Inter-token latency (one decode step), ms")
         self.m_tps = reg.gauge("decode_tokens_per_sec",
                                "Decode throughput over the last step wave")
+        # one unlabelled histogram per phase of the loop (module docstring);
+        # the engine registers its three on the same registry
+        self.m_wave = reg.histogram(
+            "decode_wave_ms", "One scheduler pass (admit + step wave) that "
+            "prefilled or stepped, ms")
+        self.m_admit = reg.histogram(
+            "decode_admit_ms", "Admission of a pass that admitted something "
+            "(queue pops, slot and block bookkeeping, prefills), ms")
+        self.m_queue_wait = reg.histogram(
+            "decode_queue_wait_ms", "Enqueue to popped with a free slot, "
+            "per request, ms")
+        self.m_prefill = reg.histogram(
+            "decode_prefill_ms", "One synchronous engine.prefill call, ms")
+        self.m_step_build = reg.histogram(
+            "decode_step_build_ms", "Host work of a step wave before the "
+            "dispatch (paged growth, ids, sampling operands), ms")
+        self.m_emit = reg.histogram(
+            "decode_emit_ms", "Host work after a step's result (tokens "
+            "appended, retirements, futures completed), ms")
         reg.gauge("decode_active_slots", "In-flight generate requests",
                   fn=lambda: float(self.active_count()))
         reg.gauge("decode_queue_depth", "Generate requests awaiting a slot",
@@ -374,7 +406,8 @@ class DecodeScheduler:
                 return hit[1]
         eng = DecodeEngine(model, slots=self.slots, max_len=self.max_len,
                            compile_tracker=self.compile_tracker,
-                           registry=self.metrics_registry, paged=self.paged,
+                           registry=self.metrics_registry,
+                           tracer=self.tracer, paged=self.paged,
                            block_size=self.block_size,
                            num_blocks=self.pool_blocks,
                            cost_registry=self.cost_registry)
@@ -401,11 +434,16 @@ class DecodeScheduler:
                     self._work.wait(self.idle_wait_s)
                 if self._closed and not self._queue and not self._active:
                     return
-            try:
-                self._admit()
-                self._step_wave()
-            except Exception as e:          # last resort: the loop survives
-                self._fail_all(e)
+            with self.tracer.phase("decode_wave",
+                                   histogram=self.m_wave) as wave:
+                try:
+                    admitted = self._admit()
+                    stepped = self._step_wave()
+                except Exception as e:      # last resort: the loop survives
+                    self._fail_all(e)
+                    admitted = stepped = True
+                if not (admitted or stepped):
+                    wave.cancel()
 
     def _fail_all(self, exc):
         self.m_errors.add(len(self._active))
@@ -428,8 +466,20 @@ class DecodeScheduler:
             return None
 
     def _admit(self):
+        """Fill free slots from the queue; returns how many requests got a
+        slot (and a prefill). The call is one `decode_admit` phase when it
+        admitted something."""
         if not self._free:
-            return
+            return 0
+        seq0 = self._admit_seq
+        with self.tracer.phase("decode_admit", histogram=self.m_admit,
+                               fold=True) as ph:
+            self._fill_free_slots()
+            if self._admit_seq == seq0:
+                ph.cancel()
+        return self._admit_seq - seq0
+
+    def _fill_free_slots(self):
         # pin ONE (version, model) per cache generation; on a hot-swap,
         # drain in-flight work before re-pinning (a step never mixes
         # versions)
@@ -516,6 +566,12 @@ class DecodeScheduler:
             r.slot, r.version = slot, self._version
             r.admit_seq = self._admit_seq
             self._admit_seq += 1
+            if r.ttft_ms is None:       # first admission: `now` is the pop
+                r.queue_wait_ms = (now - r.enqueued_at) * 1000.0
+                self.tracer.record_span(
+                    "decode_queue_wait", r.enqueued_at, now,
+                    parent=r.trace_ctx, histogram=self.m_queue_wait,
+                    slot=slot)
             if self.paged:
                 blks = self._pool.alloc(need)
                 self._slot_blocks[slot] = blks
@@ -524,9 +580,10 @@ class DecodeScheduler:
             bucket = self._engine.prefill_bucket(len(ctx))
             with self._lock:
                 self._observed_buckets.add(bucket)
-            with self.tracer.span("decode_prefill", parent=r.trace_ctx,
-                                  slot=slot, bucket=bucket,
-                                  n_prompt=len(ctx)):
+            with self.tracer.phase("decode_prefill",
+                                   histogram=self.m_prefill,
+                                   parent=r.trace_ctx, slot=slot,
+                                   bucket=bucket, n_prompt=len(ctx)):
                 try:
                     self._cache, nid, _ = self._engine.prefill(
                         self._cache, slot, ctx, sampling=r.sampler,
@@ -620,45 +677,54 @@ class DecodeScheduler:
 
     # ------------------------------------------------------------ stepping
     def _step_wave(self):
+        """One decode step for every active slot; returns whether it
+        stepped."""
         if not self._active:
-            return
+            return False
         import numpy as np
-        if self.paged:
-            # oldest-first: seniority keeps its blocks, the youngest pays
-            for slot in sorted(self._active,
-                               key=lambda s: self._active[s].admit_seq):
-                if slot in self._active:    # not preempted as a victim
-                    self._grow(slot)
-            if not self._active:
-                return
-        ids = np.zeros((self.slots,), np.int32)
-        any_sampled = False
-        for slot, r in self._active.items():
-            ids[slot] = r.tokens[-1]
-            any_sampled = any_sampled or r.sampler is not None
-        samp = None
-        if any_sampled:
-            # per-slot sampling params + fold_in step indexes as ARRAY
-            # operands — swinging every request never recompiles (GL016)
-            samp = batch_operands(
-                self.slots,
-                {s: r.sampler for s, r in self._active.items()},
-                {s: len(r.tokens) for s, r in self._active.items()})
-        t0 = monotonic_s()
+        with self.tracer.phase("decode_step_build",
+                               histogram=self.m_step_build, fold=True):
+            if self.paged:
+                # oldest-first: seniority keeps its blocks, the youngest
+                # pays
+                for slot in sorted(self._active,
+                                   key=lambda s: self._active[s].admit_seq):
+                    if slot in self._active:    # not preempted as a victim
+                        self._grow(slot)
+                if not self._active:
+                    return False
+            ids = np.zeros((self.slots,), np.int32)
+            any_sampled = False
+            for slot, r in self._active.items():
+                ids[slot] = r.tokens[-1]
+                any_sampled = any_sampled or r.sampler is not None
+            samp = None
+            if any_sampled:
+                # per-slot sampling params + fold_in step indexes as ARRAY
+                # operands — swinging every request never recompiles (GL016)
+                samp = batch_operands(
+                    self.slots,
+                    {s: r.sampler for s, r in self._active.items()},
+                    {s: len(r.tokens) for s, r in self._active.items()})
         self._cache, nxt, _ = self._engine.step(
             self._cache, ids, sampling=samp,
             table=self._table if self.paged else None)
-        wall = monotonic_s() - t0
-        n_active = len(self._active)
-        self.m_tps.set(n_active / max(wall, 1e-9))
-        now = monotonic_s()
-        for slot, r in list(self._active.items()):
-            r.tokens.append(int(nxt[slot]))
-            self.m_tokens.add(1)
-            self.m_itl.observe(wall * 1000.0,
-                               trace_id=getattr(r.trace_ctx, "trace_id",
-                                                None))
-            self._maybe_retire(slot, now)
+        # the engine's own dispatch + sync + probs_read clock reads: the
+        # old histogram and the phases' cannot drift apart
+        wall = self._engine.last_step_s
+        with self.tracer.phase("decode_emit", histogram=self.m_emit,
+                               fold=True):
+            n_active = len(self._active)
+            self.m_tps.set(n_active / max(wall, 1e-9))
+            now = monotonic_s()
+            for slot, r in list(self._active.items()):
+                r.tokens.append(int(nxt[slot]))
+                self.m_tokens.add(1)
+                self.m_itl.observe(wall * 1000.0,
+                                   trace_id=getattr(r.trace_ctx, "trace_id",
+                                                    None))
+                self._maybe_retire(slot, now)
+        return True
 
     # ----------------------------------------------------------- retiring
     def _release_slot(self, slot):

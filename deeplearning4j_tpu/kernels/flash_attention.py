@@ -221,8 +221,13 @@ def _offs_smem_spec():
 
 
 def _flash_forward(q, k, v, km, offs, scale, causal, block_q, block_k,
-                   interpret, need_lse=False):
+                   interpret, need_lse=False, name="flash_fwd"):
     """Returns (out [B,Tq,H,D], lse [BH,Tq,LANES] f32 | None).
+
+    `name` is the kernel's name in the jaxpr and in a device trace; the
+    public entries pass their own (`flash_fwd`, `flash_decode`,
+    `flash_decode_paged`), the backward kernels are `flash_bwd_dq` and
+    `flash_bwd_dkv`.
 
     km: optional [B, 1, Tk] f32 key-validity mask; offs: optional int32 [2]
     (global q, k position offsets for the causal mask — the ring path).
@@ -270,6 +275,7 @@ def _flash_forward(q, k, v, km, offs, scale, causal, block_q, block_k,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name=name,
     )(*args)
     out = res[0]
     lse = res[1] if need_lse else None
@@ -435,6 +441,7 @@ def _flash_backward(q, k, v, out, lse, g, km, offs, scale, causal, block_q,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qf, kf, vf, dof, lse, delta, *extra_args)
 
     qlane = pl.BlockSpec((1, block_q, LANES), lambda b, ki, qi: (b, qi, 0))
@@ -464,6 +471,7 @@ def _flash_backward(q, k, v, out, lse, g, km, offs, scale, causal, block_q,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qf, kf, vf, dof, lse, delta, *extra_args)
 
     unfold = lambda x, T: jnp.swapaxes(x.reshape(B, H, T, D), 1, 2)
@@ -480,19 +488,21 @@ def _zero_cotangents(km, offs):
 
 
 # --------------------------------------------------------------------- plain
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
-def _flash(q, k, v, km, offs, scale, causal, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+def _flash(q, k, v, km, offs, scale, causal, block_q, block_k, interpret,
+           name="flash_fwd"):
     return _flash_forward(q, k, v, km, offs, scale, causal, block_q, block_k,
-                          interpret)[0]
+                          interpret, name=name)[0]
 
 
-def _flash_fwd(q, k, v, km, offs, scale, causal, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, km, offs, scale, causal, block_q, block_k, interpret,
+               name):
     out, lse = _flash_forward(q, k, v, km, offs, scale, causal, block_q,
-                              block_k, interpret, need_lse=True)
+                              block_k, interpret, need_lse=True, name=name)
     return out, (q, k, v, km, offs, out, lse[..., 0])
 
 
-def _flash_bwd(scale, causal, block_q, block_k, interpret, res, g):
+def _flash_bwd(scale, causal, block_q, block_k, interpret, name, res, g):
     q, k, v, km, offs, out, lse = res
     dq, dk, dv = _flash_backward(q, k, v, out, lse, g, km, offs, scale,
                                  causal, block_q, block_k, interpret)
@@ -661,7 +671,7 @@ def _decode_reference(q, k, v, lengths, scale):
 
 
 def flash_decode(q, k, v, lengths, *, scale=None, use_pallas=True,
-                 block_k=1024, interpret=None):
+                 block_k=1024, interpret=None, _name="flash_decode"):
     """Decode-mode flash attention: ONE new query per cache slot against a
     fixed-shape slot-per-request KV cache.
 
@@ -703,7 +713,7 @@ def flash_decode(q, k, v, lengths, *, scale=None, use_pallas=True,
     qq = q if tq == 1 else jnp.broadcast_to(q, (S, tq, H, D))
     out = _per_shard(
         lambda q, k, v, km: _flash(q, k, v, km, None, scale, False, plan[0],
-                                   plan[1], interpret),
+                                   plan[1], interpret, _name),
         (qq, k, v, km), S, H)
     return out[:, :1]
 
@@ -742,7 +752,8 @@ def flash_decode_paged(q, k_pool, v_pool, block_table, lengths, *,
     k = jnp.take(k_pool, table, axis=0).reshape(S, nb * bs, H, D)
     v = jnp.take(v_pool, table, axis=0).reshape(S, nb * bs, H, D)
     return flash_decode(q, k, v, lengths, scale=scale, use_pallas=use_pallas,
-                        block_k=block_k, interpret=interpret)
+                        block_k=block_k, interpret=interpret,
+                        _name="flash_decode_paged")
 
 
 def can_flash(Tq, Tk, D, *, block_q=256, block_k=1024, interpret=None):

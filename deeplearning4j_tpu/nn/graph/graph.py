@@ -132,37 +132,40 @@ class ComputationGraph(MultiStepTrainable):
                 continue
             xs = [acts[i] for i in spec.inputs]
             ms = [out_masks.get(i) for i in spec.inputs]
-            if spec.kind == "layer":
-                x, m = xs[0], ms[0]
-                if rng is not None:
-                    rng, pre_rng, sub = jax.random.split(rng, 3)
+            # the vertex's name on its operations, in the lowered text and
+            # in a device trace (backward: transpose(jvp(<name>)))
+            with jax.named_scope(name):
+                if spec.kind == "layer":
+                    x, m = xs[0], ms[0]
+                    if rng is not None:
+                        rng, pre_rng, sub = jax.random.split(rng, 3)
+                    else:
+                        pre_rng = sub = None
+                    if spec.preprocessor is not None:
+                        x = spec.preprocessor(x, m, rng=pre_rng)
+                        m = spec.preprocessor.feed_forward_mask(m) if m is not None else None
+                    kwargs = {}
+                    if initial_carries is not None and name in initial_carries:
+                        kwargs = {"initial_state": initial_carries[name], "return_state": True}
+                    out = self.layers[name].forward(params[name], states[name], x,
+                                                    train=train, rng=sub, mask=m, **kwargs)
+                    if len(out) == 4:
+                        y, s, m, fin = out
+                        carries[name] = fin
+                    else:
+                        y, s, m = out
+                    new_states[name] = s
+                    acts[name] = y
+                    out_masks[name] = m
                 else:
-                    pre_rng = sub = None
-                if spec.preprocessor is not None:
-                    x = spec.preprocessor(x, m, rng=pre_rng)
-                    m = spec.preprocessor.feed_forward_mask(m) if m is not None else None
-                kwargs = {}
-                if initial_carries is not None and name in initial_carries:
-                    kwargs = {"initial_state": initial_carries[name], "return_state": True}
-                out = self.layers[name].forward(params[name], states[name], x,
-                                                train=train, rng=sub, mask=m, **kwargs)
-                if len(out) == 4:
-                    y, s, m, fin = out
-                    carries[name] = fin
-                else:
-                    y, s, m = out
-                new_states[name] = s
-                acts[name] = y
-                out_masks[name] = m
-            else:
-                vc = spec.vertex_conf
-                if isinstance(vc, DuplicateToTimeSeriesVertex):
-                    ref = vc.reference_input
-                    t = acts[ref].shape[1] if ref in acts and acts[ref].ndim == 3 else timesteps
-                    acts[name] = vc.apply(xs, ms, timesteps=t)
-                else:
-                    acts[name] = vc.apply(xs, ms)
-                out_masks[name] = vc.output_mask(ms)
+                    vc = spec.vertex_conf
+                    if isinstance(vc, DuplicateToTimeSeriesVertex):
+                        ref = vc.reference_input
+                        t = acts[ref].shape[1] if ref in acts and acts[ref].ndim == 3 else timesteps
+                        acts[name] = vc.apply(xs, ms, timesteps=t)
+                    else:
+                        acts[name] = vc.apply(xs, ms)
+                    out_masks[name] = vc.output_mask(ms)
         return acts, new_states, out_masks, carries
 
     # ------------------------------------------------------- mixed precision
@@ -218,23 +221,29 @@ class ComputationGraph(MultiStepTrainable):
             layer = self.layers[out_name]
             if not layer.is_output_layer():
                 raise ValueError(f"Network output '{out_name}' is not an output layer")
-            feats = acts[spec.inputs[0]]
-            if spec.preprocessor is not None:
-                if rng is not None:
-                    rng, pre_rng = jax.random.split(rng)
+            with jax.named_scope(out_name):        # as _forward names it
+                feats = acts[spec.inputs[0]]
+                if spec.preprocessor is not None:
+                    if rng is not None:
+                        rng, pre_rng = jax.random.split(rng)
+                    else:
+                        pre_rng = None
+                    feats = spec.preprocessor(
+                        feats, out_masks.get(spec.inputs[0]), rng=pre_rng)
+                if self._compute_dtype() is not None:
+                    # loss math in full precision
+                    feats = feats.astype(self._dtype)
+                mask = mlab if mlab is not None \
+                    else out_masks.get(spec.inputs[0])
+                if isinstance(layer, feedforward.CenterLossOutputLayerModule):
+                    total = total + layer.score(
+                        params[out_name], feats, y, mask, train, rng,
+                        state=states[out_name])
+                    new_states[out_name] = layer.update_centers(
+                        states[out_name], feats, y)
                 else:
-                    pre_rng = None
-                feats = spec.preprocessor(feats, out_masks.get(spec.inputs[0]),
-                                          rng=pre_rng)
-            if self._compute_dtype() is not None:
-                feats = feats.astype(self._dtype)  # loss math in full precision
-            mask = mlab if mlab is not None else out_masks.get(spec.inputs[0])
-            if isinstance(layer, feedforward.CenterLossOutputLayerModule):
-                total = total + layer.score(params[out_name], feats, y, mask, train,
-                                            rng, state=states[out_name])
-                new_states[out_name] = layer.update_centers(states[out_name], feats, y)
-            else:
-                total = total + layer.score(params[out_name], feats, y, mask, train, rng)
+                    total = total + layer.score(params[out_name], feats, y,
+                                                mask, train, rng)
         total = total + self._reg_score(params)
         return total, (new_states, carries)
 
@@ -313,8 +322,9 @@ class ComputationGraph(MultiStepTrainable):
             (score, (new_states, out_carries)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params)
             grads = self._normalize_grads(grads)
-            updates, opt_state = tx.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = tx.update(grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
             return params, opt_state, new_states, score, out_carries
 
         # tbptt donates the recurrent carries too (arg 8): out_carries

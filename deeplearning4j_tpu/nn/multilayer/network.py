@@ -40,6 +40,10 @@ class MultiLayerNetwork(MultiStepTrainable):
     def __init__(self, conf: MultiLayerConfiguration):
         self.conf = conf
         self.layers = [create_layer(lc) for lc in conf.layers]
+        # jax.named_scope of each layer in the traced forward: the names a
+        # device trace and the lowered text show for its operations
+        self._scopes = [lc.name or f"layer{i}"
+                        for i, lc in enumerate(conf.layers)]
         self.params = None          # {"0": {...}, "1": {...}}
         self.states = None          # non-trainable per-layer state
         self.opt_state = None
@@ -135,12 +139,15 @@ class MultiLayerNetwork(MultiStepTrainable):
                 rng, pre_rng, sub = jax.random.split(rng, 3)
             else:
                 pre_rng = sub = None
-            x, cur_mask = self._apply_preprocessor(i, x, cur_mask, rng=pre_rng)
             kwargs = {}
             if initial_carries is not None and str(i) in initial_carries:
                 kwargs = {"initial_state": initial_carries[str(i)], "return_state": True}
-            out = layer.forward(params[str(i)], states[str(i)], x, train=train,
-                                rng=sub, mask=cur_mask, **kwargs)
+            with jax.named_scope(self._scopes[i]):
+                x, cur_mask = self._apply_preprocessor(i, x, cur_mask,
+                                                       rng=pre_rng)
+                out = layer.forward(params[str(i)], states[str(i)], x,
+                                    train=train, rng=sub, mask=cur_mask,
+                                    **kwargs)
             if len(out) == 4:
                 x, new_s, cur_mask, final = out
                 carries[str(i)] = final
@@ -206,19 +213,24 @@ class MultiLayerNetwork(MultiStepTrainable):
         feats, new_states, cur_mask, carries, _ = fwd_fn(
             params, states, x, fwd_rng, mask, initial_carries)
         out_layer = self.layers[out_idx]
-        feats, cur_mask = self._apply_preprocessor(out_idx, feats, cur_mask,
-                                                   rng=pre_rng)
-        if self._compute_dtype() is not None:
-            feats = feats.astype(self._dtype)  # loss math in full precision
         if not out_layer.is_output_layer():
             raise ValueError("Last layer is not an output/loss layer")
-        lm = label_mask if label_mask is not None else cur_mask
-        if isinstance(out_layer, feedforward.CenterLossOutputLayerModule):
-            score = out_layer.score(params[str(out_idx)], feats, y, lm, train, rng,
-                                    state=states[str(out_idx)])
-            new_states[str(out_idx)] = out_layer.update_centers(states[str(out_idx)], feats, y)
-        else:
-            score = out_layer.score(params[str(out_idx)], feats, y, lm, train, rng)
+        with jax.named_scope(self._scopes[out_idx]):   # as _forward names it
+            feats, cur_mask = self._apply_preprocessor(out_idx, feats,
+                                                       cur_mask, rng=pre_rng)
+            if self._compute_dtype() is not None:
+                # loss math in full precision
+                feats = feats.astype(self._dtype)
+            lm = label_mask if label_mask is not None else cur_mask
+            if isinstance(out_layer, feedforward.CenterLossOutputLayerModule):
+                score = out_layer.score(
+                    params[str(out_idx)], feats, y, lm, train, rng,
+                    state=states[str(out_idx)])
+                new_states[str(out_idx)] = out_layer.update_centers(
+                    states[str(out_idx)], feats, y)
+            else:
+                score = out_layer.score(params[str(out_idx)], feats, y, lm,
+                                        train, rng)
         score = score + self._reg_score(params)
         return score, (new_states, carries)
 
@@ -302,8 +314,9 @@ class MultiLayerNetwork(MultiStepTrainable):
             (score, (new_states, out_carries)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params)
             grads = self._normalize_grads(grads)
-            updates, opt_state = tx.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = tx.update(grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
             return params, opt_state, new_states, score, out_carries, grads
 
         # tbptt also donates the LSTM carries (arg 8): out_carries aliases
@@ -490,8 +503,10 @@ class MultiLayerNetwork(MultiStepTrainable):
                     (score, (new_states, new_carries)), grads = \
                         jax.value_and_grad(loss_fn, has_aux=True)(params)
                     grads = self._normalize_grads(grads)
-                    updates, opt_state = tx.update(grads, opt_state, params)
-                    params = optax.apply_updates(params, updates)
+                    with jax.named_scope("optimizer"):
+                        updates, opt_state = tx.update(grads, opt_state,
+                                                       params)
+                        params = optax.apply_updates(params, updates)
                     return (params, opt_state, new_states, new_carries), score
 
                 # final carries ARE an output: the donated carry buffers can

@@ -28,12 +28,30 @@ Each class provides:
 Listeners fire once per execution with the advanced iteration count — a
 well-defined K-step cadence; per-step scores stay available on device as
 `last_scores`.
+
+Each execution after an epoch's first is one `fit_execution` phase
+(telemetry/trace.py `Tracer.phase`) whose parts fold into it, each with a
+histogram on the default registry: `fit_prepare` (stacking the K batches
+into a plan), `fit_dispatch` (the jitted call until it RETURNS — where a
+host that runs ahead of the device gets held; the compiling call is left
+out) and `fit_listeners` (the per-execution callbacks).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 import optax
+
+from ..telemetry.registry import get_registry
+from ..telemetry.trace import get_tracer
+
+
+def _fit_phase(name, help):
+    """A folded phase of fit(steps_per_execution=K), with its `<name>_ms`
+    histogram on the default registry (where etl_consumer_wait_ms lives)."""
+    return get_tracer().phase(
+        name, histogram=get_registry().histogram(name + "_ms", help),
+        fold=True)
 
 
 class MultiStepTrainable:
@@ -146,8 +164,9 @@ class MultiStepTrainable:
                     self._scan_loss, has_aux=True)(
                         params, states, x, y, step_rng, mask, lmask)
                 grads = self._normalize_grads(grads)
-                updates, opt_state = tx.update(grads, opt_state, params)
-                params = optax.apply_updates(params, updates)
+                with jax.named_scope("optimizer"):
+                    updates, opt_state = tx.update(grads, opt_state, params)
+                    params = optax.apply_updates(params, updates)
                 return (params, opt_state, new_states, rng), score
 
             (params, opt_state, states, rng), scores = jax.lax.scan(
@@ -189,23 +208,35 @@ class MultiStepTrainable:
         plan."""
         mode, stacked, K = prepared
         if mode == "std":
-            if "multi" not in self._jit_cache:
+            args = (self.params, self.opt_state, self.states, self._rng,
+                    stacked)
+            fn = self._jit_cache.get("multi")
+            if fn is None:
+                # the compiling call stays with timed_first_call's
+                # accounting and OUTSIDE any phase: see _fit_grouped
                 from ..telemetry.xla import timed_first_call
-                self._jit_cache["multi"] = timed_first_call(
+                fn = self._jit_cache["multi"] = timed_first_call(
                     self._make_multi_step(), "multi_step:std")
+                out = fn(*args)
+            else:
+                with _fit_phase("fit_dispatch", "The multi-step "
+                                "executable's call until it returns (not "
+                                "until ready), ms"):
+                    out = fn(*args)
             (self.params, self.opt_state, self.states, self._rng,
-             scores) = self._jit_cache["multi"](
-                self.params, self.opt_state, self.states, self._rng, stacked)
+             scores) = out
         else:
             scores = self._run_prepared_tbptt(stacked, K)
         self.last_scores = scores          # [K] device array
         self.score_value = scores[-1]      # device scalar; syncs lazily
         self.iteration_count += int(K)
         B = jax.tree_util.tree_leaves(stacked)[0].shape[1]
-        for listener in self.listeners:
-            if hasattr(listener, "record_batch_size"):
-                listener.record_batch_size(int(K) * int(B))
-            listener.iteration_done(self, self.iteration_count)
+        with _fit_phase("fit_listeners", "Listener callbacks of one "
+                        "multi-step execution, ms"):
+            for listener in self.listeners:
+                if hasattr(listener, "record_batch_size"):
+                    listener.record_batch_size(int(K) * int(B))
+                listener.iteration_done(self, self.iteration_count)
         return self
 
     def _fit_grouped(self, it, K, prepare=None, run=None, fallback=None):
@@ -218,12 +249,37 @@ class MultiStepTrainable:
         run = run or (lambda prepared, group: self.fit_prepared(prepared))
         fallback = fallback or self.fit_batch
         group = []
+        # An epoch's first execution may trace, lower and compile, and it
+        # runs outside the fit_execution phase: on the v5e host a jitted
+        # call that lowers from inside a `with` block took 3-4 s longer per
+        # enclosing block (jaxpr -> MLIR conversion of the 5-step ResNet-50
+        # program: 11.3 s outside, 14.3 s under one block, 18.6-20 s under
+        # two; my chip runs, PR 24; cause not found, not seen on a CPU).
+        warm = False
 
-        def flush(group):
-            prepared = prepare(group) if len(group) == K else None
+        def execute(group):
+            with _fit_phase("fit_prepare", "Stacking one group of K device "
+                            "batches into an execution plan, ms") as prep:
+                prepared = prepare(group)
+                if prepared is None:
+                    prep.cancel()
             if prepared is not None:
                 run(prepared, group)
-            else:
+            return prepared
+
+        def flush(group):
+            nonlocal warm
+            prepared = None
+            if len(group) == K:
+                if warm:
+                    with get_tracer().phase("fit_execution", steps=K) as ex:
+                        prepared = execute(group)
+                        if prepared is None:
+                            ex.cancel()
+                else:
+                    prepared = execute(group)
+                warm = prepared is not None
+            if prepared is None:
                 for ds in group:
                     fallback(ds)
 

@@ -188,6 +188,9 @@ class ServingServer(BackgroundHttpServer):
                 pool_blocks=decode_pool_blocks,
                 cost_registry=self.cost)
             self.health.register("decode", self.decode.probe)
+            self._m_generate_front = self.metrics.registry.histogram(
+                "generate_front_ms", "The /generate handler's own time per "
+                "request: its wall less the wait on the scheduler, ms")
 
     # ---- health probes -----------------------------------------------------
     def _probe_admission(self):
@@ -670,71 +673,83 @@ class ServingServer(BackgroundHttpServer):
         """POST /generate {"prompt": [token ids], "max_new_tokens"?: N,
         "timeout_ms"?: T, "stop"?: id, "temperature"?: T, "top_k"?: K,
         "top_p"?: P, "seed"?: S} -> {"tokens", "n_prompt", "version",
-        "ttft_ms", "finish_reason"}. Sampling params become array operands
-        of the shared decode step (decode/sampling.py) — any mix per
-        request, zero recompiles; omitting them decodes greedily. 404 when
-        the decode plane is off, 429 when shed, 504 when the deadline
-        passed before the first token, 503 with no model. A deadline hit
-        MID-generation answers 200 with the partial tokens and
-        finish_reason="deadline" (the per-token budget semantics)."""
+        "ttft_ms", "queue_wait_ms", "server_ms", "finish_reason"}. Sampling
+        params become array operands of the shared decode step
+        (decode/sampling.py) — any mix per request, zero recompiles;
+        omitting them decodes greedily. 404 when the decode plane is off,
+        429 when shed, 504 when the deadline passed before the first token,
+        503 with no model. A deadline hit MID-generation answers 200 with
+        the partial tokens and finish_reason="deadline" (the per-token
+        budget semantics).
+
+        Times in the answer, all on the server's clock: `queue_wait_ms`
+        (enqueue -> a decode slot), `ttft_ms` (enqueue -> first token),
+        `server_ms` (this handler's wall up to serialising the body), so
+        client latency - server_ms is the wire's and the client's. The
+        handler's own time — its wall less the wait on the scheduler's
+        future — is the `generate_front` phase (`generate_front_ms`)."""
         if self.decode is None:
             handler.send_json(
                 404, {"error": "decode plane disabled; start the server "
                                "with decode=True"})
             return
-        d = json.loads(handler.body() or b"{}")
+        with self.tracer.span("generate") as root, \
+                self.tracer.phase("generate_front",
+                                  histogram=self._m_generate_front,
+                                  parent=root) as front:
+            status, body = self._generate(handler.body(), root, front)
+            root.set_attribute("status", status)
+            if status == 200:
+                body["server_ms"] = \
+                    (monotonic_s() - front.start_mono) * 1000.0
+            handler.send_json(status, body)
+
+    def _generate(self, raw, root, front):
+        """(status, body) of one /generate request; a shed request raises
+        RejectedError through to the handler's 429."""
+        d = json.loads(raw or b"{}")
         prompt = d.get("prompt")
         if not isinstance(prompt, list) or not prompt:
-            handler.send_json(400, {"error": "prompt must be a non-empty "
-                                             "list of token ids"})
-            return
+            return 400, {"error": "prompt must be a non-empty list of "
+                                  "token ids"}
+        root.set_attribute("n_prompt", len(prompt))
         from ..decode.sampling import SamplerConfig
         try:
             sampler = SamplerConfig.from_request(d)
         except (TypeError, ValueError) as e:
-            handler.send_json(400, {"error": f"bad sampling params: {e}"})
-            return
+            return 400, {"error": f"bad sampling params: {e}"}
         timeout_ms = d.get("timeout_ms", self.default_timeout_ms)
-        with self.tracer.span("generate", n_prompt=len(prompt)) as root:
+        try:
+            fut = self.decode.submit(
+                prompt, max_new_tokens=d.get("max_new_tokens"),
+                timeout_ms=timeout_ms, stop_id=d.get("stop"),
+                sampler=sampler)
+            wait_s = 120.0 if timeout_ms is None \
+                else float(timeout_ms) / 1000.0 + 120.0
             try:
-                fut = self.decode.submit(
-                    prompt, max_new_tokens=d.get("max_new_tokens"),
-                    timeout_ms=timeout_ms, stop_id=d.get("stop"),
-                    sampler=sampler)
-                wait_s = 120.0 if timeout_ms is None \
-                    else float(timeout_ms) / 1000.0 + 120.0
-                try:
+                with front.paused():
                     res = fut.result(timeout=wait_s)
-                except FuturesTimeoutError:
-                    # withdraw/clamp the request: an abandoned generation
-                    # must not keep burning a decode slot (mirror of the
-                    # /predict path's _abandon)
-                    self.decode.abandon(fut)
-                    raise
-            except DeadlineExceeded as e:
-                root.set_attribute("status", 504)
-                handler.send_json(504, {"error": str(e)})
-                return
             except FuturesTimeoutError:
-                root.set_attribute("status", 503)
-                handler.send_json(503, {"error": "decode timed out"})
-                return
-            except NoModelDeployed as e:
-                root.set_attribute("status", 503)
-                handler.send_json(503, {"error": str(e)})
-                return
-            except ValueError as e:      # unservable request shape
-                root.set_attribute("status", 400)
-                handler.send_json(400, {"error": str(e)})
-                return
-            root.set_attribute("status", 200)
-            root.set_attribute("version", res["version"])
-            root.set_attribute("n_tokens", len(res["tokens"]))
-            self.logger.debug("generate_ok", n_prompt=len(prompt),
-                              n_tokens=len(res["tokens"]),
-                              finish_reason=res["finish_reason"],
-                              version=res["version"])
-        handler.send_json(200, res)
+                # withdraw/clamp the request: an abandoned generation
+                # must not keep burning a decode slot (mirror of the
+                # /predict path's _abandon)
+                self.decode.abandon(fut)
+                raise
+        except DeadlineExceeded as e:
+            return 504, {"error": str(e)}
+        except FuturesTimeoutError:
+            return 503, {"error": "decode timed out"}
+        except NoModelDeployed as e:
+            return 503, {"error": str(e)}
+        except ValueError as e:      # unservable request shape
+            return 400, {"error": str(e)}
+        root.set_attribute("version", res["version"])
+        root.set_attribute("n_tokens", len(res["tokens"]))
+        self.logger.debug("generate_ok", n_prompt=len(prompt),
+                          n_tokens=len(res["tokens"]),
+                          finish_reason=res["finish_reason"],
+                          version=res["version"])
+        return 200, res
 
     def _healthz(self):
         """Deep health: aggregate of every registered component probe plus

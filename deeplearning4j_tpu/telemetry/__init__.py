@@ -6,9 +6,16 @@ Four cooperating parts, one import surface:
 
 - `trace` — structured tracing: `Tracer` producing nested `Span`s with
   ids/attributes, a thread-local current-span context propagated through the
-  serving hot path (admission -> micro-batch coalesce -> registry dispatch
-  -> model step) and training (epoch -> iteration -> jit step), exportable
-  as Chrome-trace/Perfetto JSON.
+  serving hot path (/predict: admission -> micro-batch coalesce -> registry
+  dispatch -> model step; /generate: generate -> decode_queue_wait,
+  decode_prefill, generate_front per request, one decode_wave span per
+  scheduler pass) and training (per batch: epoch -> iteration -> jit step;
+  fit(steps_per_execution=K): one fit_execution span per execution),
+  exportable as Chrome-trace/Perfetto JSON. `Tracer.phase` is the one call
+  site for a timed phase of those loops: a `jax.profiler` annotation
+  named "dl4j:<phase>" (so a profiler session shows host phases beside the
+  device's lines, on one clock), a `<phase>_ms` histogram in the registry,
+  and the ring span.
 - `registry` — central `MetricsRegistry`: thread-safe counters, gauges, and
   bounded histograms with exact-bucket percentiles; ServingMetrics, the
   training listeners, and streaming all register here instead of keeping
@@ -77,7 +84,8 @@ from .propagation import (SpanContext, extract, extract_message,
                           parse_traceparent)
 from .registry import (Counter, Gauge, Histogram, MetricsRegistry,
                        get_registry)
-from .trace import (NOOP_SPAN, Span, Tracer, current_span, enable_tracing,
+from .trace import (NOOP_SPAN, Phase, Span, Tracer, current_span,
+                    enable_tracing,
                     get_tracer, new_span_id, new_trace_id, set_tracer)
 from .xla import (CompileTracker, record_jit_compile,
                   register_device_memory_gauges, timed_first_call)
@@ -96,7 +104,7 @@ __all__ = ["AlertEngine", "AlertRule", "LogAlertSink", "RouterAlertSink",
            "inject", "inject_message", "parse_traceparent",
            "Counter", "Gauge", "Histogram", "MetricsRegistry",
            "get_registry",
-           "NOOP_SPAN", "Span", "Tracer", "current_span", "enable_tracing",
+           "NOOP_SPAN", "Phase", "Span", "Tracer", "current_span", "enable_tracing",
            "get_tracer", "new_span_id", "new_trace_id", "set_tracer",
            "CompileTracker", "record_jit_compile",
            "register_device_memory_gauges", "timed_first_call",

@@ -29,10 +29,19 @@ Design notes:
 - Spans can also LINK to other spans (`add_link`) — the batch<->request
   association without a parent edge; links export as Chrome-trace flow
   events.
+- `Tracer.phase` is the ONE call site for a timed phase of a hot loop: the
+  same pair of clock reads feeds a `jax.profiler.TraceAnnotation` named
+  "dl4j:<name>" (so a profiler session shows the phase on a host-thread
+  line beside the device's lines, on the profiler's clock), a registry
+  histogram (what `/metrics` and the benchmark read, tracer on or off) and,
+  with the tracer enabled, a ring span. Phases of a loop that turns many
+  times a second `fold` into their enclosing phase: the ring then holds one
+  span per pass carrying `<phase>_ms` attributes, not one per phase.
 """
 from __future__ import annotations
 
 import collections
+import contextlib
 import json
 import os
 import threading
@@ -40,6 +49,23 @@ import threading
 from ..util.time_source import monotonic_s, now_s
 
 _tls = threading.local()          # .span: innermost active Span, any tracer
+                                  # .phase: innermost active Phase
+
+PROFILER_PREFIX = "dl4j:"         # every phase's name in a profiler trace
+_TraceAnnotation = None           # jax.profiler.TraceAnnotation, on first use
+
+
+def _annotate(name):
+    """An entered `jax.profiler.TraceAnnotation` for phase `name`. With no
+    profiler session running it is a flag check. jax is imported here, on
+    the first phase, so importing telemetry never pays for it."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    ann = _TraceAnnotation(PROFILER_PREFIX + name)
+    ann.__enter__()
+    return ann
 
 
 def new_trace_id() -> str:
@@ -174,6 +200,93 @@ class _NoopSpan:
 NOOP_SPAN = _NoopSpan()
 
 
+class Phase:
+    """One timed phase with three sinks (via Tracer.phase): a profiler
+    annotation "dl4j:<name>", an observation in ms into `histogram`, and a
+    ring span when the tracer is enabled — all from one pair of
+    monotonic_s() reads. The span is made at exit (as `record_span` makes
+    one), so a phase never becomes the thread-current span: children name
+    their parent, and `parent` may still be set while the phase runs.
+
+    `fold=True`: no ring span of its own; the duration adds to the
+    `<name>_ms` attribute of the nearest enclosing unfolded phase on this
+    thread. `cancel()`: the interval turned out not to be one worth
+    counting (a pass that did nothing, a call that compiled) — nothing is
+    observed and no span recorded. `paused()`: time inside it is taken off
+    the duration and left out of the annotation (a handler blocked on a
+    future); the ring span keeps the whole interval and says `paused_ms`."""
+
+    __slots__ = ("tracer", "name", "histogram", "parent", "fold",
+                 "attributes", "start_mono", "end_mono", "paused_s",
+                 "_ann", "_outer", "_cancelled")
+
+    def __init__(self, tracer, name, histogram, parent, fold, attributes):
+        self.tracer = tracer
+        self.name = str(name)
+        self.histogram = histogram
+        self.parent = parent
+        self.fold = bool(fold)
+        self.attributes = attributes
+        self.start_mono = self.end_mono = None
+        self.paused_s = 0.0
+        self._ann = self._outer = None
+        self._cancelled = False
+
+    def cancel(self):
+        self._cancelled = True
+
+    @property
+    def duration_ms(self):
+        if self.end_mono is None:
+            return None
+        return (self.end_mono - self.start_mono - self.paused_s) * 1000.0
+
+    @contextlib.contextmanager
+    def paused(self):
+        t0 = monotonic_s()
+        self._ann.__exit__(None, None, None)
+        try:
+            yield
+        finally:
+            self._ann = _annotate(self.name)
+            self.paused_s += monotonic_s() - t0
+
+    def __enter__(self):
+        self._outer = getattr(_tls, "phase", None)
+        _tls.phase = self
+        if self.parent is None and not self.fold and self.tracer.enabled:
+            self.parent = current_span()
+        self._ann = _annotate(self.name)
+        self.start_mono = monotonic_s()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.end_mono = monotonic_s()
+        self._ann.__exit__(exc_type, exc, tb)
+        _tls.phase = self._outer
+        if self._cancelled:
+            return False
+        ms = self.duration_ms
+        if self.histogram is not None:
+            self.histogram.observe(ms)
+        if self.fold:
+            host = self._outer
+            while host is not None and host.fold:
+                host = host._outer
+            if host is not None and host.tracer.enabled:
+                key = self.name + "_ms"
+                host.attributes[key] = host.attributes.get(key, 0.0) + ms
+        elif self.tracer.enabled:
+            if exc_type is not None:
+                self.attributes.setdefault("error", exc_type.__name__)
+            if self.paused_s:
+                self.attributes["paused_ms"] = self.paused_s * 1000.0
+            self.tracer.record_span(self.name, self.start_mono,
+                                    self.end_mono, parent=self.parent,
+                                    **self.attributes)
+        return False
+
+
 class Tracer:
     """Produces spans and keeps the most recent `max_spans` finished ones in
     a bounded ring buffer for export."""
@@ -208,9 +321,20 @@ class Tracer:
             parent = None
         return Span(self, name, parent=parent, attributes=attributes)
 
+    def phase(self, name, histogram=None, parent=None, fold=False,
+              **attributes):
+        """Context-manager Phase: one call site, three sinks (profiler
+        annotation "dl4j:<name>", `histogram` in ms, ring span). Only the
+        ring span depends on `enabled`."""
+        return Phase(self, name, histogram, parent, fold, attributes)
+
     def record_span(self, name, start_mono, end_mono, parent=None,
-                    **attributes):
-        """Record an already-measured interval as a finished span."""
+                    histogram=None, **attributes):
+        """Record an already-measured interval as a finished span; with a
+        `histogram`, also as an observation in ms (tracer on or off). An
+        interval in the past cannot become a profiler annotation."""
+        if histogram is not None:
+            histogram.observe((end_mono - start_mono) * 1000.0)
         if not self.enabled:
             return NOOP_SPAN
         if parent is NOOP_SPAN:

@@ -648,3 +648,355 @@ def test_streaming_broker_registers_central_metrics():
         client.close()
     finally:
         broker.stop()
+
+
+# ------------------------------------------- phases: one site, three sinks
+
+class _FakeAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: records enter/exit."""
+    log = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        _FakeAnnotation.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        _FakeAnnotation.log.append(("exit", self.name))
+        return False
+
+
+@pytest.fixture
+def fake_annotation(monkeypatch):
+    from deeplearning4j_tpu.telemetry import trace as trace_mod
+    _FakeAnnotation.log = []
+    monkeypatch.setattr(trace_mod, "_TraceAnnotation", _FakeAnnotation)
+    return _FakeAnnotation.log
+
+
+def test_phase_lands_in_three_sinks_with_one_duration(manual_clock,
+                                                      fake_annotation):
+    """Tracer.phase: the profiler annotation "dl4j:<name>", the histogram
+    and the ring span all come from ONE pair of clock reads."""
+    t = Tracer()
+    h = MetricsRegistry().histogram("work_ms", "test")
+    with t.span("request") as root:
+        with t.phase("work", histogram=h, parent=root, slot=3) as ph:
+            assert current_span() is root       # a phase is never current
+            manual_clock.advance(0.25)
+    assert fake_annotation == [("enter", "dl4j:work"), ("exit", "dl4j:work")]
+    assert h.count() == 1 and h.sum() == pytest.approx(250.0)
+    assert ph.duration_ms == pytest.approx(250.0)
+    span = next(s for s in t.finished_spans() if s.name == "work")
+    assert span.duration_ms == pytest.approx(250.0)
+    assert span.parent_id == root.span_id and span.trace_id == root.trace_id
+    assert span.attributes == {"slot": 3}
+
+
+def test_phase_disabled_tracer_observes_histogram_allocates_no_span(
+        manual_clock, fake_annotation, monkeypatch):
+    from deeplearning4j_tpu.telemetry import trace as trace_mod
+
+    def no_span(*a, **k):
+        raise AssertionError("a disabled tracer allocated a Span")
+    monkeypatch.setattr(trace_mod, "Span", no_span)
+    t = Tracer(enabled=False)
+    h = MetricsRegistry().histogram("work_ms", "test")
+    with t.phase("pass") as outer:
+        with t.phase("work", histogram=h, fold=True):
+            manual_clock.advance(0.002)
+    t.record_span("waited", 1.0, 1.5, histogram=h)
+    assert h.count() == 2 and h.sum() == pytest.approx(502.0)
+    assert outer.attributes == {} and t.finished_spans() == []
+    assert ("enter", "dl4j:work") in fake_annotation    # still annotated
+
+
+@pytest.mark.parametrize("case", ["fold", "cancel", "paused"])
+def test_phase_fold_cancel_and_pause(case, manual_clock, fake_annotation):
+    t = Tracer()
+    h = MetricsRegistry().histogram("part_ms", "test")
+    if case == "fold":
+        # parts of a hot loop: one ring span a pass, parts as attributes
+        with t.phase("pass"):
+            for _ in range(2):
+                with t.phase("part", histogram=h, fold=True):
+                    manual_clock.advance(0.01)
+        (span,) = t.finished_spans()
+        assert span.name == "pass"
+        assert span.attributes["part_ms"] == pytest.approx(20.0)
+        assert h.count() == 2
+    elif case == "cancel":
+        with t.phase("part", histogram=h) as ph:
+            manual_clock.advance(0.01)
+            ph.cancel()
+        assert h.count() == 0 and t.finished_spans() == []
+        assert ph.duration_ms == pytest.approx(10.0)    # still measured
+    else:
+        with t.phase("part", histogram=h) as ph:
+            manual_clock.advance(0.01)
+            with ph.paused():
+                manual_clock.advance(5.0)
+            manual_clock.advance(0.02)
+        assert h.sum() == pytest.approx(30.0)
+        (span,) = t.finished_spans()
+        assert span.duration_ms == pytest.approx(5030.0)
+        assert span.attributes["paused_ms"] == pytest.approx(5000.0)
+        # the annotation leaves the pause out: two segments
+        assert fake_annotation == [("enter", "dl4j:part"),
+                                   ("exit", "dl4j:part")] * 2
+
+
+def _tiny_lm(use_pallas=False, d_model=32, vocab=24):
+    from deeplearning4j_tpu.zoo.models import transformer_lm
+    return transformer_lm(vocab_size=vocab, d_model=d_model, n_layers=2,
+                          n_heads=2, seed=3, causal=True,
+                          use_pallas=use_pallas).init()
+
+
+def _tiny_scheduler(net, tracer, slots=2, max_len=64):
+    from deeplearning4j_tpu.decode.scheduler import DecodeScheduler
+    from deeplearning4j_tpu.serving.registry import ModelRegistry
+    registry = ModelRegistry()
+    registry.register("v1", net)
+    registry.deploy("v1")
+    mreg = MetricsRegistry()
+    return DecodeScheduler(registry, mreg, slots=slots, max_len=max_len,
+                           tracer=tracer), mreg
+
+
+def test_profiler_session_shows_decode_phases_on_a_host_line(tmp_path):
+    """Under a real jax.profiler session the scheduler's phases are events
+    of the profiler's own trace: dl4j:decode_wave encloses
+    dl4j:decode_step_sync on a host line."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+    sched, _ = _tiny_scheduler(_tiny_lm(), Tracer(enabled=False))
+    sched.start()
+    try:
+        sched.generate([1, 2, 3], max_new_tokens=3)     # compiles
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            sched.generate([1, 2, 3], max_new_tokens=6)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        sched.stop()
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    enclosed = 0
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                   for e in line.events if e.name.startswith("dl4j:")]
+            waves = [e for e in evs if e[0] == "dl4j:decode_wave"]
+            for name, a, b in evs:
+                if name == "dl4j:decode_step_sync":
+                    enclosed += any(w[1] <= a and b <= w[2] for w in waves)
+    # 5 steps; the last one's wave may still be open when the trace stops
+    assert enclosed >= 3
+
+
+def test_decode_phase_sums_add_up_to_the_wave():
+    """Over N warm steps the parts cover the pass: their sum is at most the
+    waves' and at least 90 % of it; decode_itl_ms is the engine's three
+    phases, from the same clock reads."""
+    # a step of a few ms, so the parts' share is the loop's, not the
+    # bookkeeping's (97-98 % here; a 0.6 ms step reads 87 %)
+    sched, mreg = _tiny_scheduler(_tiny_lm(d_model=256, vocab=2048),
+                                  Tracer(enabled=False), slots=8)
+    parts = ["decode_admit_ms", "decode_step_build_ms",
+             "decode_step_dispatch_ms", "decode_step_sync_ms",
+             "decode_probs_read_ms", "decode_emit_ms"]
+    names = parts + ["decode_wave_ms", "decode_itl_ms"]
+
+    def sums():
+        return {n: (mreg.get(n).sum(), mreg.get(n).count()) for n in names}
+    sched.start()
+    try:
+        sched.generate(list(range(1, 9)), max_new_tokens=3)     # compiles
+        before = sums()
+        sched.generate(list(range(1, 9)), max_new_tokens=40)
+        after = sums()
+    finally:
+        sched.stop()
+    d = {n: after[n][0] - before[n][0] for n in names}
+    steps = after["decode_step_sync_ms"][1] - before["decode_step_sync_ms"][1]
+    assert steps == 39          # the first token comes from the prefill
+    covered = sum(d[n] for n in parts)
+    assert covered <= d["decode_wave_ms"]
+    assert covered >= 0.9 * d["decode_wave_ms"], (covered, d)
+    engine = d["decode_step_dispatch_ms"] + d["decode_step_sync_ms"] \
+        + d["decode_probs_read_ms"]
+    assert d["decode_itl_ms"] == pytest.approx(engine, rel=1e-6)
+    for n in ("decode_queue_wait_ms", "decode_prefill_ms"):
+        assert mreg.get(n).count() == 2
+
+
+def test_generate_response_times_and_request_spans():
+    """/generate answers queue_wait_ms <= ttft_ms <= server_ms; the
+    request's trace holds generate -> {decode_queue_wait, decode_prefill,
+    generate_front}; the front's histogram leaves the wait on the scheduler
+    out."""
+    from deeplearning4j_tpu.serving import ServingServer
+    srv = ServingServer(decode=True, decode_slots=2, decode_max_len=64)
+    srv.registry.register("v1", _tiny_lm())
+    srv.deploy("v1")
+    srv.start()
+    try:
+        req = urllib.request.Request(
+            srv.url + "/generate", method="POST",
+            data=json.dumps({"prompt": [1, 2, 3],
+                             "max_new_tokens": 6}).encode(),
+            headers={"Content-Type": "application/json"})
+        body = json.loads(urllib.request.urlopen(req, timeout=120).read())
+        front = srv.metrics.registry.get("generate_front_ms")
+        spans = srv.tracer.finished_spans()
+    finally:
+        srv.stop()
+    assert len(body["tokens"]) == 6
+    assert 0.0 <= body["queue_wait_ms"] <= body["ttft_ms"] \
+        <= body["server_ms"]
+    assert front.count() == 1 and front.sum() < body["server_ms"]
+    root = next(s for s in spans if s.name == "generate")
+    kids = {s.name: s for s in spans if s.parent_id == root.span_id}
+    assert {"decode_queue_wait", "decode_prefill",
+            "generate_front"} <= set(kids)
+    assert kids["generate_front"].attributes["paused_ms"] > 0
+    waves = [s for s in spans if s.name == "decode_wave"]
+    assert waves and all("decode_step_sync_ms" in s.attributes
+                         for s in waves)
+    assert not any(s.name == "decode_step_sync" for s in spans)
+
+
+def test_fit_steps_per_execution_counts_one_phase_each_per_execution():
+    from deeplearning4j_tpu import (Adam, ComputationGraph, DataSet,
+                                    DenseLayer, InputType,
+                                    ListDataSetIterator,
+                                    NeuralNetConfiguration, OutputLayer)
+    from deeplearning4j_tpu.telemetry import enable_tracing, get_tracer
+    conf = (NeuralNetConfiguration.builder().seed(9).updater(Adam(1e-2))
+            .graph_builder().add_inputs("in")
+            .add_layer("d", DenseLayer(n_out=16, activation="relu"), "in")
+            .add_layer("out", OutputLayer(n_out=3, activation="softmax",
+                                          loss="MCXENT"), "d")
+            .set_outputs("out")
+            .set_input_types(InputType.feed_forward(8)).build())
+    net = ComputationGraph(conf).init()
+    rng = np.random.default_rng(0)
+    sets = [DataSet(rng.normal(size=(16, 8)).astype(np.float32),
+                    np.eye(3, dtype=np.float32)[rng.integers(0, 3, 16)])
+            for _ in range(7)]          # 3 executions of 2 + a ragged tail
+    reg = get_registry()
+    names = ("fit_prepare_ms", "fit_dispatch_ms", "fit_listeners_ms")
+
+    def counts():
+        return [reg.get(n).count() if reg.get(n) else 0 for n in names]
+    tracer = get_tracer()
+    was = tracer.enabled
+    enable_tracing()
+    try:
+        tracer.clear()
+        before = counts()
+        net.fit(ListDataSetIterator(sets), steps_per_execution=2)
+        spans = [s for s in tracer.finished_spans()
+                 if s.name == "fit_execution"]
+    finally:
+        tracer.enabled = was
+    # the first execution compiles: its dispatch stays with the compile
+    # accounting (jit_compiles_total), not in fit_dispatch_ms, and like
+    # every epoch's first it runs outside the fit_execution span
+    assert [a - b for a, b in zip(counts(), before)] == [3, 2, 3]
+    assert len(spans) == 2 and spans[0].attributes["steps"] == 2
+    assert all({"fit_prepare_ms", "fit_dispatch_ms", "fit_listeners_ms"}
+               <= set(s.attributes) for s in spans)
+
+
+def _pallas_names(jaxpr, out):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(eqn.params["name"])
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _pallas_names(inner, out)
+    return out
+
+
+@pytest.mark.parametrize("what,expect", [
+    ("attention_fwd", ["flash_fwd"]),
+    ("attention_fwd_bwd", ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]),
+    ("decode_step", ["flash_decode"] * 2),
+    ("decode_step_paged", ["flash_decode_paged"] * 2),
+])
+def test_pallas_calls_carry_their_names(what, expect):
+    """make_jaxpr of both attention passes and of the decode step: every
+    pallas_call says which kernel it is (what a device trace shows)."""
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.kernels import flash_attention
+    if what.startswith("attention"):
+        q = jnp.ones((1, 128, 2, 64), jnp.float32)
+
+        def loss(q, k, v):
+            return flash_attention(q, k, v, causal=True).sum()
+        fn = loss if what == "attention_fwd" \
+            else jax.grad(loss, argnums=(0, 1, 2))
+        jaxpr = jax.make_jaxpr(fn)(q, q, q)
+    else:
+        from deeplearning4j_tpu.decode import DecodeEngine
+        paged = what.endswith("paged")
+        net = _tiny_lm(use_pallas=True)
+        eng = DecodeEngine(net, slots=2, max_len=32, paged=paged)
+        jaxpr = jax.make_jaxpr(eng._build_step())(
+            net.params, net.states, eng.init_cache(),
+            np.zeros((2,), np.int32), eng._greedy_step_ops,
+            eng.full_table() if paged else None)
+    assert _pallas_names(jaxpr.jaxpr, []) == expect
+
+
+@pytest.mark.parametrize("kind", ["multilayer", "graph", "multi_step"])
+def test_train_step_lowering_names_layers_and_optimizer(kind):
+    """The lowered text of a two-layer net's train step carries its layer
+    names (forward jvp(<layer>), backward transpose(jvp(<layer>))) and
+    `optimizer` as scopes."""
+    import jax.numpy as jnp
+    from deeplearning4j_tpu import (Adam, ComputationGraph, DenseLayer,
+                                    InputType, MultiLayerNetwork,
+                                    NeuralNetConfiguration, OutputLayer)
+    b = NeuralNetConfiguration.builder().seed(1).updater(Adam(1e-2))
+    hidden = DenseLayer(n_out=16, activation="tanh", name="hidden")
+    out = OutputLayer(n_out=3, activation="softmax", loss="MCXENT")
+    x, y = jnp.ones((4, 8)), jnp.ones((4, 3))
+    if kind == "graph":
+        conf = (b.graph_builder().add_inputs("in")
+                .add_layer("hidden", hidden, "in")
+                .add_layer("head", out, "hidden").set_outputs("head")
+                .set_input_types(InputType.feed_forward(8)).build())
+        net = ComputationGraph(conf).init()
+        lowered = net._make_train_step().lower(
+            net.params, net.opt_state, net.states, net._rng, [x], [y],
+            None, None, None)
+        layers = ["hidden", "head"]
+    else:
+        conf = b.list().layer(hidden).layer(out) \
+            .input_type(InputType.feed_forward(8)).build()
+        net = MultiLayerNetwork(conf).init()
+        layers = ["hidden", "layer1"]       # an unnamed layer: layer<i>
+        if kind == "multilayer":
+            lowered = net._make_train_step().lower(
+                net.params, net.opt_state, net.states, net._rng, x, y,
+                None, None, None)
+        else:
+            stacked = (jnp.stack([x, x]), jnp.stack([y, y]), None, None)
+            lowered = net._make_multi_step().lower(
+                net.params, net.opt_state, net.states, net._rng, stacked)
+    text = lowered.as_text(debug_info=True)
+    for name in layers:
+        assert f'jvp({name})/' in text, name
+        assert f'transpose(jvp({name}))/' in text, name
+    assert '"optimizer/' in text or '/optimizer/' in text
