@@ -6,8 +6,14 @@ One engine serves one model with three executable families:
   [slots] next ids out — that advances EVERY in-flight request by one token.
   Attention layers append the token's k/v into their [slots, capacity, H,
   Dh] cache rows with a per-slot `lax.dynamic_update_slice` (vmapped over
-  the slot axis) and attend against the cache masked by the per-slot length
-  vector (kernels.flash_attention.flash_decode); recurrent layers carry
+  the slot axis) and attend against the cache with the decode kernel
+  (kernels.flash_attention.flash_decode): its grid walks (slot, key block),
+  a tile is all heads of one slot's key block read from the cache buffer in
+  the layout the device stores it in (for head_dim < 128 the TPU keeps the
+  positions minor-most, and the kernel's operand is a bitcast of the
+  buffer: no instruction of the step copies or transposes a K or V slab —
+  tests/test_tpu_compile.py), and the per-slot length vector rides to the
+  kernel as scalars that mask the scores there; recurrent layers carry
   their (h, c) state in [slots, n_out] cache rows. Because every shape is a
   function of (slots, capacity) only — never of how many tokens any request
   has generated — steady-state decoding NEVER recompiles, no matter how
@@ -48,7 +54,8 @@ scheduler's allocator backs — token-for-token equal to the slab layout
 head-sharding.
 
 Decode runs in the model's param dtype (no mixed-precision cast): decode is
-bound by streaming cache bytes, not MXU throughput, and greedy parity with
+bound by streaming cache bytes, not MXU throughput (the decode kernel does
+its one-row products on the VPU, in float32), and greedy parity with
 ``model.output`` is the contract the tests pin.
 """
 from __future__ import annotations

@@ -34,6 +34,15 @@ on each visiting K/V shard (parallel/ring_attention.py merges the per-shard
 (out, lse) partials by log-sum-exp). The LSE cotangent folds into the
 backward for free: ds = p·(dp − Δ) with Δ = rowsum(dO·O) − g_lse.
 
+Decode (`flash_decode`: one query per cache slot against a [slots,
+capacity, H, D] KV cache) is a kernel of its own, `_decode_kernel`, and
+shares no grid with the above: grid (slots, key blocks), a tile is all heads
+of one slot's key block as the cache buffer holds it ([H, D, positions], the
+positions on the lanes), the per-head products run on the VPU in float32,
+and the validity mask is an iota against the slot's length in SMEM. What
+selects it is the entry point; nothing of the cache is folded, copied or
+masked in HBM on the way.
+
 Gives way to a pure-JAX path (see `flash_attention`) when shapes don't tile,
 so callers can use it unconditionally; each such call is counted in
 `pallas_fallback_total` and logged once per shape (`_note_fallback`).
@@ -221,13 +230,12 @@ def _offs_smem_spec():
 
 
 def _flash_forward(q, k, v, km, offs, scale, causal, block_q, block_k,
-                   interpret, need_lse=False, name="flash_fwd"):
+                   interpret, need_lse=False):
     """Returns (out [B,Tq,H,D], lse [BH,Tq,LANES] f32 | None).
 
-    `name` is the kernel's name in the jaxpr and in a device trace; the
-    public entries pass their own (`flash_fwd`, `flash_decode`,
-    `flash_decode_paged`), the backward kernels are `flash_bwd_dq` and
-    `flash_bwd_dkv`.
+    The kernel's name in the jaxpr and in a device trace is `flash_fwd`; the
+    backward kernels are `flash_bwd_dq` and `flash_bwd_dkv`, the decode
+    kernel `flash_decode` / `flash_decode_paged`.
 
     km: optional [B, 1, Tk] f32 key-validity mask; offs: optional int32 [2]
     (global q, k position offsets for the causal mask — the ring path).
@@ -275,7 +283,7 @@ def _flash_forward(q, k, v, km, offs, scale, causal, block_q, block_k,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name=name,
+        name="flash_fwd",
     )(*args)
     out = res[0]
     lse = res[1] if need_lse else None
@@ -488,21 +496,19 @@ def _zero_cotangents(km, offs):
 
 
 # --------------------------------------------------------------------- plain
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
-def _flash(q, k, v, km, offs, scale, causal, block_q, block_k, interpret,
-           name="flash_fwd"):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _flash(q, k, v, km, offs, scale, causal, block_q, block_k, interpret):
     return _flash_forward(q, k, v, km, offs, scale, causal, block_q, block_k,
-                          interpret, name=name)[0]
+                          interpret)[0]
 
 
-def _flash_fwd(q, k, v, km, offs, scale, causal, block_q, block_k, interpret,
-               name):
+def _flash_fwd(q, k, v, km, offs, scale, causal, block_q, block_k, interpret):
     out, lse = _flash_forward(q, k, v, km, offs, scale, causal, block_q,
-                              block_k, interpret, need_lse=True, name=name)
+                              block_k, interpret, need_lse=True)
     return out, (q, k, v, km, offs, out, lse[..., 0])
 
 
-def _flash_bwd(scale, causal, block_q, block_k, interpret, name, res, g):
+def _flash_bwd(scale, causal, block_q, block_k, interpret, res, g):
     q, k, v, km, offs, out, lse = res
     dq, dk, dv = _flash_backward(q, k, v, out, lse, g, km, offs, scale,
                                  causal, block_q, block_k, interpret)
@@ -670,6 +676,131 @@ def _decode_reference(q, k, v, lengths, scale):
     return out.astype(q.dtype)
 
 
+# the decode kernel's K and V tiles: all heads of one slot's key block. Four
+# of them (K and V, double-buffered) stay inside the default scoped VMEM.
+_DECODE_TILE_BYTES = 2 << 20
+
+
+def _decode_block(C, H, D, itemsize, block_k, interpret):
+    """Key-block length of the decode kernel — the largest divisor of the
+    capacity whose [H, D, block] tile fits `_DECODE_TILE_BYTES` (and
+    `block_k`) — or None => fall back. Compiled, positions lie on the lanes
+    (a multiple of 128) and head_dim on the sublanes (a multiple of 8 for
+    float32, of 16 for a packed bfloat16); interpret mode takes any divisor."""
+    c_align, d_align = (1, 1) if interpret else (128, 32 // itemsize)
+    if D % d_align:
+        return None
+    target = min(block_k, C, max(c_align,
+                                 _DECODE_TILE_BYTES // (H * D * itemsize)))
+    return _fit_block(C, target, c_align)
+
+
+def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, qc_ref, acc_ref,
+                   m_ref, l_ref, *, scale, block_c, nk):
+    """One (slot, key block) step of decode attention on the cache as it
+    lies in HBM: k_ref/v_ref are [1, H, D, block_c] tiles — head_dim on the
+    sublanes, cache positions on the lanes. With one query row per head the
+    work is a matrix-vector product, so it runs on the VPU in float32: the
+    scores are a sublane reduction of k * q (q as a [D, 1] column), the
+    output a lane reduction of v * p, both per head, carried across key
+    blocks by the online softmax in (m, l, acc). The validity mask is an
+    iota against the slot's length, a scalar in SMEM."""
+    from jax.experimental import pallas as pl
+    si, ki = pl.program_id(0), pl.program_id(1)
+    H, D = k_ref.shape[1], k_ref.shape[2]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (D, D), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (D, D), 1))
+
+    @pl.when(ki == 0)
+    def _init():
+        def column(h, carry):
+            # the head's query row [1, D] turned onto the sublanes: [D, 1]
+            row = q_ref[0, h].astype(jnp.float32)
+            qc_ref[h] = jnp.sum(jnp.where(eye, row, 0.0), axis=1,
+                                keepdims=True)
+            return carry
+        jax.lax.fori_loop(0, H, column, None, unroll=True)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    kpos = ki * block_c + jax.lax.broadcasted_iota(jnp.int32, (1, block_c), 1)
+    valid = kpos < len_ref[si]                        # [1, block_c]
+
+    def head(h, carry):
+        k = k_ref[0, h].astype(jnp.float32)           # [D, block_c]
+        s = jnp.sum(k * qc_ref[h], axis=0, keepdims=True) * scale
+        s = jnp.where(valid, s, NEG_INF)              # [1, block_c]
+        m_prev = m_ref[h]                             # [1, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        v = v_ref[0, h].astype(jnp.float32)           # [D, block_c]
+        acc_ref[h] = acc_ref[h] * corr + jnp.sum(v * p, axis=1, keepdims=True)
+        l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[h] = m_new
+        return carry
+    # unrolled: the scheduler overlaps one head's reductions with the next
+    # head's loads (0.74 -> 0.58 ms a call at 48 x 1024 x 16 x 64 float32)
+    jax.lax.fori_loop(0, H, head, None, unroll=True)
+
+    @pl.when(ki == nk - 1)
+    def _finish():
+        def row(h, carry):
+            # l >= 1 always: a fully masked slot sums exp(0) per position
+            col = acc_ref[h] / l_ref[h]               # [D, 1]
+            o_ref[0, h] = jnp.sum(jnp.where(eye, col, 0.0), axis=0,
+                                  keepdims=True).astype(o_ref.dtype)
+            return carry
+        jax.lax.fori_loop(0, H, row, None, unroll=True)
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
+def _decode_call(q, k, v, lengths, scale, block_c, interpret, name):
+    """q [S, 1, H, D], k/v [S, C, H, D], lengths [S] -> [S, 1, H, D].
+
+    Jitted, so the layers of one step program share ONE trace and one
+    lowering of the kernel. With the head loop unrolled, tracing it layer by
+    layer cost the opt350m server 4 s of every set-up (24 layers x 16
+    heads), a warm compile cache or not."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    S, _, H, D = q.shape
+    C = k.shape[1]
+    nk = C // block_c
+    # The kernel's operand is [S, H, D, C]. For head_dim < 128 that IS the
+    # cache buffer: the TPU lays a [S, C, H, D] array out with the positions
+    # minor-most (minor-to-major {1,3,2,0}: a 64-wide minor axis would pad
+    # every tile to 128 lanes), so this transpose compiles to a bitcast
+    # (tests/test_tpu_compile.py holds it to that). For head_dim >= 128 the
+    # buffer is row-major and the transpose is a copy, as the fold of heads
+    # was before.
+    kt, vt = (jnp.transpose(x, (0, 2, 3, 1)) for x in (k, v))
+    tile = pl.BlockSpec((1, H, D, block_c), lambda s, ki, lens: (s, 0, 0, ki))
+    row = pl.BlockSpec((1, H, 1, D), lambda s, ki, lens: (s, 0, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, scale=scale, block_c=block_c,
+                          nk=nk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(S, nk),
+            in_specs=[row, tile, tile],
+            out_specs=row,
+            scratch_shapes=[
+                pltpu.VMEM((H, D, 1), jnp.float32),      # q columns
+                pltpu.VMEM((H, D, 1), jnp.float32),      # acc
+                pltpu.VMEM((H, 1, 1), jnp.float32),      # running max
+                pltpu.VMEM((H, 1, 1), jnp.float32),      # running sum
+            ]),
+        out_shape=jax.ShapeDtypeStruct((S, H, 1, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name=name,
+    )(lengths, q.reshape(S, H, 1, D), kt, vt)
+    return out.reshape(S, 1, H, D)
+
+
 def flash_decode(q, k, v, lengths, *, scale=None, use_pallas=True,
                  block_k=1024, interpret=None, _name="flash_decode"):
     """Decode-mode flash attention: ONE new query per cache slot against a
@@ -681,17 +812,20 @@ def flash_decode(q, k, v, lengths, *, scale=None, use_pallas=True,
     lengths: [slots] int32 — valid entries per slot (including the current
     token). Returns [slots, 1, heads, head_dim].
 
-    The per-slot validity mask (iota < lengths) folds into the score tiles
-    exactly like the key mask of the training kernel — this is the same
-    in-kernel masking discipline, driven by the cache's length vector, so
-    every decode step runs ONE executable regardless of how many tokens
-    each co-batched request has generated (the zero-recompile contract of
-    the decode engine). A [1, D] query doesn't meet Mosaic's 8-sublane
-    floor when compiled, so the query row is broadcast to 8 sublanes and
-    row 0 of the output kept: decode attention is bound by streaming the
-    K/V cache bytes from HBM, and the 7 redundant MXU rows ride along for
-    free. Falls back to the masked reference row when shapes don't tile or
-    `use_pallas=False` (the two paths agree to f32 rounding)."""
+    A kernel of its own (`_decode_kernel`), not the training forward: the
+    grid walks (slot, key block) and each tile is all heads of one slot's
+    key block, read from the cache buffer in the layout it is stored in —
+    no fold of heads, no copy of the cache (`_decode_call`). The per-slot
+    validity mask is an iota compared with the slot's length, which rides
+    to the kernel as a scalar (SMEM), so every decode step runs ONE
+    executable regardless of how many tokens each co-batched request has
+    generated (the zero-recompile contract of the decode engine). Every
+    key block is read and computed, those past a slot's length too.
+    Multiplies and accumulates in float32 whatever the cache's dtype. The
+    key block follows from heads x head_dim, the dtype and the VMEM budget
+    (`_decode_block`); `block_k` caps it. Falls back to the masked
+    reference row when shapes don't tile or `use_pallas=False` (the two
+    paths agree to f32 rounding)."""
     S, Tq, H, D = q.shape
     assert Tq == 1, f"flash_decode takes one query per slot, got Tq={Tq}"
     C = k.shape[1]
@@ -702,20 +836,15 @@ def flash_decode(q, k, v, lengths, *, scale=None, use_pallas=True,
     lengths = jnp.asarray(lengths, jnp.int32)
     if not use_pallas:
         return _decode_reference(q, k, v, lengths, scale)
-    tq = 1 if interpret else 8          # Mosaic sublane floor when compiled
-    plan = _plan(tq, C, D, tq, block_k, interpret)
-    if plan is None:
+    block_c = _decode_block(C, H, D, k.dtype.itemsize, block_k, interpret)
+    if block_c is None:
         _note_fallback("flash_decode", "reference", C=C, D=D,
                        interpret=interpret)
         return _decode_reference(q, k, v, lengths, scale)
-    km = (jax.lax.broadcasted_iota(jnp.int32, (S, C), 1)
-          < lengths[:, None]).astype(jnp.float32)[:, None, :]   # [S, 1, C]
-    qq = q if tq == 1 else jnp.broadcast_to(q, (S, tq, H, D))
-    out = _per_shard(
-        lambda q, k, v, km: _flash(q, k, v, km, None, scale, False, plan[0],
-                                   plan[1], interpret, _name),
-        (qq, k, v, km), S, H)
-    return out[:, :1]
+    return _per_shard(
+        lambda q, k, v, lengths: _decode_call(q, k, v, lengths, scale,
+                                              block_c, interpret, _name),
+        (q, k, v, lengths), S, H)
 
 
 def flash_decode_paged(q, k_pool, v_pool, block_table, lengths, *,

@@ -814,7 +814,18 @@ def test_decode_phase_sums_add_up_to_the_wave():
     names = parts + ["decode_wave_ms", "decode_itl_ms"]
 
     def sums():
-        return {n: (mreg.get(n).sum(), mreg.get(n).count()) for n in names}
+        # generate() returns from inside the pass that emitted the last
+        # token: read once that pass has closed and the loop stands idle
+        read = lambda: {n: (mreg.get(n).sum(), mreg.get(n).count())
+                        for n in names}
+        seen = read()
+        for _ in range(100):
+            threading.Event().wait(0.05)
+            again = read()
+            if again == seen:
+                return seen
+            seen = again
+        raise AssertionError("the scheduler's histograms never settled")
     sched.start()
     try:
         sched.generate(list(range(1, 9)), max_new_tokens=3)     # compiles

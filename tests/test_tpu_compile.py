@@ -12,6 +12,7 @@ from `jax.default_backend()`, which is the CPU here, so the tests pass
 """
 import importlib
 import os
+import re
 
 import numpy as np
 import pytest
@@ -75,6 +76,39 @@ def on_chip(one_chip, chip_config):
 
 def compiled_text(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
+
+
+_RESULT = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* "
+                     r"([\w\-]+)\(")
+_CALLS = re.compile(r"calls=%?([\w.\-]+)")
+_COMPUTATION = re.compile(r"^%?([\w.\-]+) \(.*\{\s*$")
+
+
+def relayouts(text, elements):
+    """Names of the instructions of an optimized HLO text that rewrite an
+    array of at least `elements` elements into another layout: a `copy`, a
+    `transpose`, or a fusion whose root is one. (A bitcast moves nothing, an
+    in-place `dynamic-update-slice` is the cache's own append, and a
+    `copy-start` / `copy-done` pair changes the memory space, not the
+    layout.)"""
+    roots, rows = {}, []
+    computation = None
+    for line in text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            computation = head.group(1)
+        m = _RESULT.match(line)
+        if not m:
+            continue
+        name, dims, op = m.groups()
+        if line.lstrip().startswith("ROOT"):
+            roots[computation] = op
+        rows.append((name, int(np.prod([int(d) for d in dims.split(",")
+                                        if d])), op, _CALLS.search(line)))
+    moved = ("copy", "transpose")
+    return [name for name, n, op, calls in rows if n >= elements and (
+        op in moved or (op == "fusion" and calls
+                        and roots.get(calls.group(1)) in moved))]
 
 
 def graded(attend):
@@ -147,6 +181,62 @@ def test_flash_decode_compiles(on_chip, capacity):
         lambda q, k, v, n: flash_decode(q, k, v, n, interpret=False),
         q, kv, kv, lengths)
     assert text.count(KERNEL) == 1
+    assert relayouts(text, 8 * capacity * 4 * 64) == []
+
+
+def test_flash_decode_reads_the_cache_where_it_lies(on_chip):
+    """The decode attention of the `opt350m_batch_decode` cell: 48 slots of
+    1024 positions, 16 heads of 64, float32. The TPU stores such a buffer
+    with the positions minor-most, which is the kernel's operand layout: the
+    compiled call is the kernel and bitcasts, and no instruction copies or
+    transposes an array the size of a K or V slab. (Through the training
+    forward this was two transposing copies of 201 MB a layer: 46 ms of a
+    96 ms decode step on the chip.)"""
+    S, C, H, D = 48, 1024, 16, 64
+    q = on_chip((S, 1, H, D), jnp.float32)
+    kv = on_chip((S, C, H, D), jnp.float32)
+    comp = jax.jit(lambda q, k, v, n: flash_decode(q, k, v, n,
+                                                   interpret=False)).lower(
+        q, kv, kv, on_chip((S,), jnp.int32)).compile()
+    text = comp.as_text()
+    assert text.count(KERNEL) == 1
+    assert relayouts(text, S * C * H * D) == []
+    assert comp.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_flash_decode_runs_per_shard_on_a_mesh(topo, chip_config):
+    """`ServingServer(mesh=4)`: the cache head-sharded over four chips. The
+    kernel runs per shard inside `_per_shard`'s shard_map on 4 of the 16
+    heads, still on the buffer as it lies: one kernel, no slab rewritten,
+    no collective."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from deeplearning4j_tpu.parallel.sharding import DATA_AXIS, MODEL_AXIS
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), (DATA_AXIS, MODEL_AXIS))
+    S, C, H, D = 48, 1024, 16, 64
+    heads = NamedSharding(mesh, P(None, None, MODEL_AXIS, None))
+    q = jax.ShapeDtypeStruct((S, 1, H, D), jnp.float32, sharding=heads)
+    kv = jax.ShapeDtypeStruct((S, C, H, D), jnp.float32, sharding=heads)
+    lengths = jax.ShapeDtypeStruct((S,), jnp.int32,
+                                   sharding=NamedSharding(mesh, P()))
+    with jax.set_mesh(mesh):
+        text = compiled_text(
+            lambda q, k, v, n: flash_decode(q, k, v, n, interpret=False),
+            q, kv, kv, lengths)
+    assert text.count(KERNEL) == 1
+    assert relayouts(text, S * C * H * D // 4) == []
+    assert not re.search(r"all-gather|all-reduce|all-to-all|"
+                         r"collective-permute", text)
+
+
+def test_relayouts_sees_a_transposed_cache(on_chip):
+    """The guard has teeth: the same cache read through the training
+    forward, which folds the heads into the batch in front of its kernel,
+    shows the K and the V slab rewritten."""
+    q = on_chip((48, 8, 16, 64), jnp.float32)
+    kv = on_chip((48, 1024, 16, 64), jnp.float32)
+    text = compiled_text(
+        lambda q, k, v: flash_attention(q, k, v, interpret=False), q, kv, kv)
+    assert len(relayouts(text, 48 * 1024 * 16 * 64)) >= 2
 
 
 def test_flash_decode_paged_compiles(on_chip):
@@ -204,6 +294,9 @@ def test_decode_step_compiles_with_kernel(lm_engine, one_chip,
                       eng._greedy_step_ops), one_chip)
     text = eng._build_step().lower(*args, None).compile().as_text()
     assert text.count(KERNEL) == 4          # one flash_decode per layer
+    # and nothing in the step rewrites a K or V slab: the append updates
+    # the cache in place, the kernel reads it where it lies
+    assert relayouts(text, eng.slots * eng.capacity * 256) == []
 
 
 @pytest.mark.parametrize("bucket", [128, 256])
