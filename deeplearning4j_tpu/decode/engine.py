@@ -5,15 +5,21 @@ One engine serves one model with three executable families:
 - ``step``: ONE compiled function of fixed shape — [slots] token ids in,
   [slots] next ids out — that advances EVERY in-flight request by one token.
   Attention layers append the token's k/v into their [slots, capacity, H,
-  Dh] cache rows with a per-slot `lax.dynamic_update_slice` (vmapped over
-  the slot axis) and attend against the cache with the decode kernel
+  Dh] cache rows with ONE in-place kernel a layer for all slots and for K
+  and V (kernels.flash_attention.kv_append: of each slot only the 128
+  positions around its append position are read and written back, the
+  output aliased onto the donated cache; where the shapes do not tile that
+  way — head_dim >= 128, a capacity off the lanes, `use_pallas=False` — a
+  per-slot `lax.dynamic_update_slice` vmapped over the slot axis writes the
+  same bytes) and attend against the cache with the decode kernel
   (kernels.flash_attention.flash_decode): its grid walks (slot, key block),
-  a tile is all heads of one slot's key block read from the cache buffer in
-  the layout the device stores it in (for head_dim < 128 the TPU keeps the
-  positions minor-most, and the kernel's operand is a bitcast of the
-  buffer: no instruction of the step copies or transposes a K or V slab —
-  tests/test_tpu_compile.py), and the per-slot length vector rides to the
-  kernel as scalars that mask the scores there; recurrent layers carry
+  a tile is all heads of one slot's key block. Both kernels work on the
+  cache buffer in the layout the device stores it in (for head_dim < 128
+  the TPU keeps the positions minor-most, and the kernels' operand is a
+  bitcast of the buffer: no instruction of the step copies or transposes a
+  K or V slab, and none loops over the slots — tests/test_tpu_compile.py),
+  and the per-slot length vector rides to the decode kernel as scalars
+  that mask the scores there; recurrent layers carry
   their (h, c) state in [slots, n_out] cache rows. Because every shape is a
   function of (slots, capacity) only — never of how many tokens any request
   has generated — steady-state decoding NEVER recompiles, no matter how
@@ -434,7 +440,7 @@ class DecodeEngine:
         """[slots, 1, f] single-token forward against the cache. `pos` is
         the per-slot append position (clamped), `kv_valid` the number of
         valid cache entries including the appended token."""
-        from ..kernels import flash_decode, flash_decode_paged
+        from ..kernels import flash_decode, flash_decode_paged, kv_append
         acts = {self.input_name: x0}
         layers = dict(cache["layers"])
         if table is not None:
@@ -471,15 +477,12 @@ class DecodeEngine:
                                                      kv_valid,
                                                      use_pallas=use_pallas)
                     else:
-                        append = jax.vmap(
-                            lambda row, t, at: lax.dynamic_update_slice(
-                                row, t, (at, jnp.zeros((), at.dtype),
-                                         jnp.zeros((), at.dtype))))
                         with jax.named_scope("kv_append"):
-                            nk = append(entry["k"],
-                                        kt.astype(entry["k"].dtype), pos)
-                            nv = append(entry["v"],
-                                        vt.astype(entry["v"].dtype), pos)
+                            nk, nv = kv_append(
+                                entry["k"], entry["v"],
+                                kt.astype(entry["k"].dtype),
+                                vt.astype(entry["v"].dtype), pos,
+                                use_pallas=use_pallas)
                         with jax.named_scope("attention"):
                             out = flash_decode(q, nk, nv, kv_valid,
                                                use_pallas=use_pallas)

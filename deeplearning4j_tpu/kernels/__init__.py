@@ -8,6 +8,7 @@ path. Kernels run in interpret mode on CPU (tests) and compile via Mosaic on
 TPU.
 """
 from .flash_attention import (flash_attention, flash_decode,
-                              flash_decode_paged)
+                              flash_decode_paged, kv_append)
 
-__all__ = ["flash_attention", "flash_decode", "flash_decode_paged"]
+__all__ = ["flash_attention", "flash_decode", "flash_decode_paged",
+           "kv_append"]

@@ -41,7 +41,10 @@ of one slot's key block as the cache buffer holds it ([H, D, positions], the
 positions on the lanes), the per-head products run on the VPU in float32,
 and the validity mask is an iota against the slot's length in SMEM. What
 selects it is the entry point; nothing of the cache is folded, copied or
-masked in HBM on the way.
+masked in HBM on the way. The step's new K and V reach the cache through
+`kv_append`, a kernel on the same view of the same buffer with its output
+aliased onto it: grid (slots), of each slot the one block of 128 positions
+that holds its append position goes through VMEM, nothing else is touched.
 
 Gives way to a pure-JAX path (see `flash_attention`) when shapes don't tile,
 so callers can use it unconditionally; each such call is counted in
@@ -235,7 +238,8 @@ def _flash_forward(q, k, v, km, offs, scale, causal, block_q, block_k,
 
     The kernel's name in the jaxpr and in a device trace is `flash_fwd`; the
     backward kernels are `flash_bwd_dq` and `flash_bwd_dkv`, the decode
-    kernel `flash_decode` / `flash_decode_paged`.
+    kernel `flash_decode` / `flash_decode_paged`, the cache append
+    `kv_append`.
 
     km: optional [B, 1, Tk] f32 key-validity mask; offs: optional int32 [2]
     (global q, k position offsets for the causal mask — the ring path).
@@ -806,8 +810,9 @@ def flash_decode(q, k, v, lengths, *, scale=None, use_pallas=True,
     """Decode-mode flash attention: ONE new query per cache slot against a
     fixed-shape slot-per-request KV cache.
 
-    q: [slots, 1, heads, head_dim] — the current token's query (its k/v
-    already appended to the cache at position lengths-1);
+    q: [slots, 1, heads, head_dim] — the current token's query (the step
+    has put its k/v into the cache at position lengths-1 with `kv_append`
+    before this call; this kernel only reads the cache);
     k, v: [slots, capacity, heads, head_dim] — the cache;
     lengths: [slots] int32 — valid entries per slot (including the current
     token). Returns [slots, 1, heads, head_dim].
@@ -845,6 +850,139 @@ def flash_decode(q, k, v, lengths, *, scale=None, use_pallas=True,
         lambda q, k, v, lengths: _decode_call(q, k, v, lengths, scale,
                                               block_c, interpret, _name),
         (q, k, v, lengths), S, H)
+
+
+def _append_reference(k, v, k_new, v_new, pos):
+    """The append as XLA writes it: a per-slot `lax.dynamic_update_slice`
+    vmapped over the slot axis — the semantics `kv_append` must match bit
+    for bit, and its fallback. In place on a donated cache, but on the TPU a
+    serial loop over the slots (48 strided updates of ~8 us each per buffer
+    at 48 x 1024 x 16 x 64)."""
+    def one(row, t, at):
+        z = jnp.zeros((), at.dtype)
+        return jax.lax.dynamic_update_slice(row, t, (at, z, z))
+    append = jax.vmap(one)
+    return append(k, k_new, pos), append(v, v_new, pos)
+
+
+def _append_block(C, D, itemsize, interpret):
+    """Lane block of the append kernel — the run of cache positions that
+    holds a slot's append position — or None => fall back. The kernel writes
+    the cache as the TPU stores it for head_dim < 128: positions on the
+    lanes (a block of 128 that divides the capacity), head_dim on the
+    sublanes (a multiple of 8 for float32, of 16 for a packed bfloat16).
+    For head_dim >= 128 the buffer is row-major, a token's [H, D] row is
+    contiguous and XLA's update is the right one. Interpret mode takes the
+    largest divisor of the capacity up to 128."""
+    if D >= LANES:
+        return None
+    if interpret:
+        return _fit_block(C, LANES, 1)
+    if C % LANES or D % (32 // itemsize):
+        return None
+    return LANES
+
+
+def _append_kernel(pos_ref, kn_ref, vn_ref, k_ref, v_ref, ko_ref, vo_ref, *,
+                   block_c):
+    """One slot's append: k_ref/v_ref are the [1, H, D, block_c] tile of the
+    cache that holds position pos[slot] (the BlockSpec's index map chose it
+    from the prefetched scalar), ko_ref/vo_ref the same tile of the same
+    buffer (aliased). Lane pos % block_c is replaced by the new token's
+    values, every other lane is written back as read. The token's [1, D] row
+    per head turns onto the sublanes as `_decode_kernel` turns q — but by a
+    max over -inf, which hands every value through bit for bit (a sum would
+    turn -0.0 into 0.0)."""
+    from jax.experimental import pallas as pl
+    H, D = k_ref.shape[1], k_ref.shape[2]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (D, D), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (D, D), 1))
+    hit = (jax.lax.broadcasted_iota(jnp.int32, (1, block_c), 1)
+           == pos_ref[pl.program_id(0)] % block_c)
+
+    def head(h, carry):
+        for new_ref, old_ref, out_ref in ((kn_ref, k_ref, ko_ref),
+                                          (vn_ref, v_ref, vo_ref)):
+            row = new_ref[0, h].astype(jnp.float32)           # [1, D]
+            col = jnp.max(jnp.where(eye, row, -jnp.inf), axis=1,
+                          keepdims=True)                      # [D, 1]
+            old = old_ref[0, h].astype(jnp.float32)           # [D, block_c]
+            out_ref[0, h] = jnp.where(hit, col, old).astype(out_ref.dtype)
+        return carry
+    jax.lax.fori_loop(0, H, head, None, unroll=True)
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6))
+def _append_call(k, v, k_new, v_new, pos, block_c, interpret):
+    """k/v [S, C, H, D], k_new/v_new [S, 1, H, D], pos [S] -> (k, v) with
+    k[s, pos[s]] = k_new[s, 0]. Jitted for the reason `_decode_call` is:
+    the layers of a step share one trace and one lowering.
+
+    The operand is the same [S, H, D, C] view of the cache `_decode_call`
+    reads (a bitcast of a buffer stored positions-minor), the output is
+    aliased onto it, and the only block of a slot that moves is the one
+    its position lies in: 2 x H x D x 128 elements read and written a slot,
+    whatever the capacity."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    S, C, H, D = k.shape
+    kt, vt = (jnp.transpose(x, (0, 2, 3, 1)) for x in (k, v))
+    tile = pl.BlockSpec((1, H, D, block_c),
+                        lambda s, pos: (s, 0, 0, pos[s] // block_c))
+    row = pl.BlockSpec((1, H, 1, D), lambda s, pos: (s, 0, 0, 0))
+    slab = jax.ShapeDtypeStruct((S, H, D, C), k.dtype)
+    nk, nv = pl.pallas_call(
+        functools.partial(_append_kernel, block_c=block_c),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(S,),
+            in_specs=[row, row, tile, tile],
+            out_specs=[tile, tile]),
+        out_shape=[slab, slab],
+        # operands count from the prefetched scalar: 3 and 4 are the slabs
+        input_output_aliases={3: 0, 4: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="kv_append",
+    )(pos, k_new.reshape(S, H, 1, D), v_new.reshape(S, H, 1, D), kt, vt)
+    return tuple(jnp.transpose(x, (0, 3, 1, 2)) for x in (nk, nv))
+
+
+def kv_append(k, v, k_new, v_new, pos, *, use_pallas=True, interpret=None):
+    """Append one token's K and V per slot to a slot-per-request KV cache,
+    in place when the cache is donated.
+
+    k, v: [slots, capacity, heads, head_dim] — the cache;
+    k_new, v_new: [slots, 1, heads, head_dim] — the step's new token, in
+    the cache's dtype; pos: [slots] int32 — where each slot appends, inside
+    [0, capacity). Returns (k, v) with k[s, pos[s]] = k_new[s, 0] and
+    v[s, pos[s]] = v_new[s, 0], every other element as it was.
+
+    ONE kernel (`kv_append`) for all slots and for K and V, so a layer of
+    the decode step costs one launch: the grid walks the slots, a slot's
+    block index follows from its position (a prefetched scalar), and the
+    output is aliased onto the input, so of each slot only the 128-position
+    block that holds the position is read and written back — on the buffer
+    in the layout the TPU stores it in, as `flash_decode` reads it. Gives
+    way to the vmapped `dynamic_update_slice` (`_append_reference`, the
+    same bytes) when the shapes do not tile that way (`_append_block`) or
+    `use_pallas=False`."""
+    S, C, H, D = k.shape
+    if interpret is None:
+        interpret = _interpret_default()
+    pos = jnp.asarray(pos, jnp.int32)
+    if not use_pallas:
+        return _append_reference(k, v, k_new, v_new, pos)
+    block_c = _append_block(C, D, k.dtype.itemsize, interpret)
+    if block_c is None:
+        _note_fallback("kv_append", "dynamic_update_slice", C=C, D=D,
+                       interpret=interpret)
+        return _append_reference(k, v, k_new, v_new, pos)
+    return _per_shard(
+        lambda k, v, k_new, v_new, pos: _append_call(k, v, k_new, v_new, pos,
+                                                     block_c, interpret),
+        (k, v, k_new, v_new, pos), S, H)
 
 
 def flash_decode_paged(q, k_pool, v_pool, block_table, lengths, *,
