@@ -8,12 +8,10 @@ ingest established:
 
 - `DecodeEngine` compiles a fixed-shape decode step (every token, every
   mix of co-batched requests), one prefill per power-of-two prompt-length
-  bucket, and one speculative-verify pass per window size. The KV cache is
-  a fixed [slots, capacity, heads, head_dim] tensor per attention layer
-  (plus a [slots, n_out] carry pair per recurrent layer) with a per-slot
-  length vector; appends are `lax.dynamic_update_slice` writes, and the
-  attention step masks against the length vector inside the flash kernel
-  (`kernels.flash_attention.flash_decode`).
+  bucket, and one speculative-verify pass per window size, over a cache
+  pytree with a per-slot length vector. What a layer keeps there (an
+  attention layer's K/V, a recurrent layer's carry) and how each leg
+  advances it is the layer's decode contract (nn/layers/base.py).
 - `sampling.SamplerConfig` carries a request's temperature / top-k /
   top-p / seed; they enter the step executable as BATCH-SHAPED ARRAY
   OPERANDS (never jit keys — graftlint GL016), with per-slot

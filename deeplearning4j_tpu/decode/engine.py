@@ -1,44 +1,29 @@
-"""DecodeEngine: fixed-shape KV-cache decode executables for the nn types.
+"""DecodeEngine: fixed-shape decode executables over the nn types' layers.
 
-One engine serves one model with three executable families:
+The engine owns the plan (a MultiLayerNetwork or a one-in/one-out
+ComputationGraph flattened to nodes), the cache pytree and three executable
+families; what a layer keeps per decode slot and how each leg advances it is
+the layer's own business — the decode contract of nn/layers/base.py
+(`decode_unsupported`, `decode_entry`, `decode_prefill` / `decode_step` /
+`decode_verify`, `decode_rewindable`; nn/layers/recurrent.py has attention's
+K/V cache and the LSTM carry). No layer class is named here.
 
 - ``step``: ONE compiled function of fixed shape — [slots] token ids in,
   [slots] next ids out — that advances EVERY in-flight request by one token.
-  Attention layers append the token's k/v into their [slots, capacity, H,
-  Dh] cache rows with ONE in-place kernel a layer for all slots and for K
-  and V (kernels.flash_attention.kv_append: of each slot only the 128
-  positions around its append position are read and written back, the
-  output aliased onto the donated cache; where the shapes do not tile that
-  way — head_dim >= 128, a capacity off the lanes, `use_pallas=False` — a
-  per-slot `lax.dynamic_update_slice` vmapped over the slot axis writes the
-  same bytes) and attend against the cache with the decode kernel
-  (kernels.flash_attention.flash_decode): its grid walks (slot, key block),
-  a tile is all heads of one slot's key block. Both kernels work on the
-  cache buffer in the layout the device stores it in (for head_dim < 128
-  the TPU keeps the positions minor-most, and the kernels' operand is a
-  bitcast of the buffer: no instruction of the step copies or transposes a
-  K or V slab, and none loops over the slots — tests/test_tpu_compile.py),
-  and the per-slot length vector rides to the decode kernel as scalars
-  that mask the scores there; recurrent layers carry
-  their (h, c) state in [slots, n_out] cache rows. Because every shape is a
-  function of (slots, capacity) only — never of how many tokens any request
-  has generated — steady-state decoding NEVER recompiles, no matter how
-  requests join and leave the batch.
+  Because every shape is a function of (slots, capacity) only — never of how
+  many tokens any request has generated — steady-state decoding NEVER
+  recompiles, no matter how requests join and leave the batch.
 - ``prefill``: one compiled function per power-of-two prompt-length bucket.
-  The prompt runs as a normal full-sequence forward (causal attention via
-  the masked flash kernel — the same padded+masked length-bucket discipline
-  the serving batcher applies to /predict), each attention layer's K/V
-  projections land in the slot's cache rows in one dynamic_update_slice,
-  and the recurrent final carries land in the slot's carry rows. Pad
-  positions write garbage K/V beyond `length`; the length mask keeps every
-  later step from ever attending to them.
+  The prompt runs as a normal full-sequence forward under the same
+  padded+masked length-bucket discipline the serving batcher applies to
+  /predict, and every stateful layer writes the slot's state.
 - ``verify`` (speculative decoding, decode/speculative.py): one compiled
   function per window size W — appends a W-token window at a dynamic
   `start` offset of one slot and returns ALL W next-token distributions in
   one batched pass (prefill-shaped work: it spends the compute the
   HBM-bound step leaves idle). Rollback after the accept decision is a
-  host-side length reset — which is why verify requires rewind-free state
-  (attention-only models; LSTM carries cannot rewind).
+  host-side length reset — which is why verify requires every layer's state
+  to be rewindable.
 
 Both legs emit SAMPLED token ids (decode/sampling.py): temperature /
 top-k / top-p / seed arrive as batch-shaped ARRAY OPERANDS, with
@@ -49,37 +34,27 @@ params never become recompile keys (graftlint GL016).
 The cache is a plain pytree ``{"lengths": int32[slots], "layers": {name:
 entry}}`` threaded functionally through the executables and DONATED, so
 steady state re-uses the cache buffers in place instead of allocating a
-fresh multi-MB cache per token. With ``paged=True`` the attention entries
-become a shared BLOCK POOL ``[num_blocks, block_size, H, Dh]`` addressed
-through a ``[slots, max_blocks]`` int32 block-table operand
-(decode/paged.py): appends scatter into (table[pos//bs], pos%bs), the
-attention gathers the slot's blocks back into contiguous rows
-(kernels.flash_attention.flash_decode_paged), and capacity is whatever the
-scheduler's allocator backs — token-for-token equal to the slab layout
-(parity-tested), with the table replicated on a mesh while the pool keeps
-head-sharding.
+fresh multi-MB cache per token. With ``paged=True`` the layers are handed a
+``[slots, max_blocks]`` int32 block-table operand (decode/paged.py) and
+capacity is whatever the scheduler's allocator backs; the table replicates
+on a mesh while every entry keeps the sharding its layer declared.
 
 Decode runs in the model's param dtype (no mixed-precision cast): decode is
-bound by streaming cache bytes, not MXU throughput (the decode kernel does
-its one-row products on the VPU, in float32), and greedy parity with
+bound by streaming cache bytes, not MXU throughput, and greedy parity with
 ``model.output`` is the contract the tests pin.
 """
 from __future__ import annotations
 
+import math
 import threading
+from types import SimpleNamespace
+from typing import Any, NamedTuple
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..nn.layers.convolution import LayerNormalizationModule
-from ..nn.layers.feedforward import (DenseLayerModule, EmbeddingLayerModule,
-                                     LossLayerModule, OutputLayerModule,
-                                     RnnOutputLayerModule)
-from ..nn.layers.misc import ActivationLayerModule, DropoutLayerModule
-from ..nn.layers.recurrent import (GravesBidirectionalLSTMModule,
-                                   SelfAttentionLayerModule, _BaseLSTMModule)
 from ..telemetry.trace import get_tracer
 from ..telemetry.xla import record_jit_compile
 from ..util.time_source import monotonic_s
@@ -90,15 +65,6 @@ class DecodeUnsupported(TypeError):
     """The model contains a construct with no token-streaming semantics
     (bidirectional recurrence, non-causal attention, temporal pooling...)."""
 
-
-# layers whose forward is a pure per-position map ([b,t,f] -> [b,t,g] with
-# position i depending only on position i): safe in both decode legs
-_POSITIONWISE = (DenseLayerModule, EmbeddingLayerModule, RnnOutputLayerModule,
-                 OutputLayerModule, LossLayerModule, ActivationLayerModule,
-                 DropoutLayerModule, LayerNormalizationModule)
-
-# graph vertices that are per-position maps over their inputs
-_POSITIONWISE_VERTICES = ("ElementWiseVertex", "MergeVertex")
 
 MIN_PREFILL_BUCKET = 16   # floor the prompt buckets: bounds the executable
                           # set at log2(capacity/16)+1 without measurable
@@ -125,22 +91,26 @@ class _Node:
         self.vertex = vertex
 
 
-def _check_layer(name, module):
-    if isinstance(module, GravesBidirectionalLSTMModule):
-        raise DecodeUnsupported(
-            f"layer {name!r}: bidirectional recurrence needs future tokens "
-            "and cannot stream")
-    if isinstance(module, SelfAttentionLayerModule):
-        if not getattr(module.conf, "causal", False):
-            raise DecodeUnsupported(
-                f"layer {name!r}: non-causal attention attends to future "
-                "positions and cannot decode incrementally")
-        return
-    if isinstance(module, (_BaseLSTMModule,) + _POSITIONWISE):
-        return
-    raise DecodeUnsupported(
-        f"layer {name!r} ({type(module).__name__}) has no per-token decode "
-        "semantics")
+class _Ctx(NamedTuple):
+    """The operands of one leg that a layer's `decode_<leg>` reads (the
+    contract's docstring, nn/layers/base.py, says which leg sets which)."""
+    mask: Any = None
+    slot: Any = None
+    length: Any = None
+    pos: Any = None
+    kv_valid: Any = None
+    start: Any = None
+    table: Any = None
+    row: Any = None
+    blk: Any = None
+    off: Any = None
+
+
+def _layer_node(name, inputs, module):
+    reason = module.decode_unsupported()
+    if reason is not None:
+        raise DecodeUnsupported(f"layer {name!r}: {reason}")
+    return _Node(name, "layer", inputs, module=module)
 
 
 def build_plan(model):
@@ -163,8 +133,7 @@ def build_plan(model):
         nodes = [_Node("__in__", "input")]
         prev = "__in__"
         for i, module in enumerate(model.layers):
-            _check_layer(str(i), module)
-            nodes.append(_Node(str(i), "layer", (prev,), module=module))
+            nodes.append(_layer_node(str(i), (prev,), module))
             prev = str(i)
         return nodes, "__in__", prev, vocab
     if isinstance(model, ComputationGraph):
@@ -184,12 +153,11 @@ def build_plan(model):
                     raise DecodeUnsupported(
                         f"vertex {name!r}: preprocessors have no per-token "
                         "semantics")
-                module = model.layers[name]
-                _check_layer(name, module)
-                nodes.append(_Node(name, "layer", spec.inputs, module=module))
+                nodes.append(_layer_node(name, spec.inputs,
+                                         model.layers[name]))
             else:
                 vc = spec.vertex_conf
-                if type(vc).__name__ not in _POSITIONWISE_VERTICES:
+                if not vc.positionwise:
                     raise DecodeUnsupported(
                         f"vertex {name!r} ({type(vc).__name__}) is not a "
                         "per-position map")
@@ -233,12 +201,21 @@ class DecodeEngine:
         if model.params is None:
             model.init()
         self._dtype = model._dtype
-        # recurrent carries accumulate in f32 for sub-32-bit param dtypes
-        # (mirrors nn/layers/recurrent._lstm_scan's acc_dt choice)
-        self._acc_dtype = (jnp.float32
-                           if jnp.issubdtype(self._dtype, jnp.floating)
-                           and jnp.finfo(self._dtype).bits < 32
-                           else self._dtype)
+        # what each stateful layer declared it keeps: {name: {leaf: (shape,
+        # dtype, model axis)}}; the entries a length reset cannot rewind
+        # are the carries
+        geom = SimpleNamespace(
+            slots=self.slots, capacity=self.capacity, dtype=self._dtype,
+            paged=self.paged, block_size=self.block_size,
+            num_blocks=self.num_blocks)
+        self._entries = {}
+        self._carries = set()
+        for node in self.nodes:
+            entry = node.kind == "layer" and node.module.decode_entry(geom)
+            if entry:
+                self._entries[node.name] = entry
+                if not node.module.decode_rewindable:
+                    self._carries.add(node.name)
         self.compile_tracker = compile_tracker
         self.registry = registry            # MetricsRegistry for jit counters
         # live cost attribution (telemetry/cost.py): each decode executable
@@ -280,37 +257,22 @@ class DecodeEngine:
         self._greedy_slot_ops = _sampling.slot_operands(None, 0)
 
     # ------------------------------------------------------------ cache
+    def _map_cache(self, fn):
+        """The cache pytree with fn(shape, dtype, model_axis) at every leaf
+        the layers declared."""
+        # `lengths` is allocated BEFORE the entries: after them (PR 28's order)
+        # the same step program served ~2 % fewer tokens/s (PERF.md §6, PR 29)
+        return {"lengths": fn((self.slots,), jnp.int32, None),
+                "layers": {name: {k: fn(*leaf) for k, leaf in entry.items()}
+                           for name, entry in self._entries.items()}}
+
     def _cache_zeros(self):
-        """Abstract cache construction (shapes/dtypes only — placement is
-        `init_cache`'s job, so `cache_bytes` can eval_shape this)."""
-        layers = {}
-        for node in self.nodes:
-            if node.kind != "layer":
-                continue
-            m = node.module
-            if isinstance(m, SelfAttentionLayerModule):
-                H = int(m.conf.n_heads)
-                Dh = int(m.conf.n_out) // H
-                # paged: one shared pool per layer instead of per-slot rows;
-                # [N, bs, H, Dh] keeps the head axis at index 2, so the mesh
-                # cache_sharding rule (4-D -> shard axis 2) head-shards the
-                # pool exactly as it does the slab
-                shape = ((self.num_blocks, self.block_size, H, Dh)
-                         if self.paged
-                         else (self.slots, self.capacity, H, Dh))
-                layers[node.name] = {"k": jnp.zeros(shape, self._dtype),
-                                     "v": jnp.zeros(shape, self._dtype)}
-            elif isinstance(m, _BaseLSTMModule):
-                n_out = int(m.conf.n_out)
-                layers[node.name] = {
-                    "h": jnp.zeros((self.slots, n_out), self._acc_dtype),
-                    "c": jnp.zeros((self.slots, n_out), self._acc_dtype)}
-        return {"lengths": jnp.zeros((self.slots,), jnp.int32),
-                "layers": layers}
+        """Abstract cache construction (placement is `init_cache`'s job)."""
+        return self._map_cache(lambda shape, dtype, _: jnp.zeros(shape, dtype))
 
     def init_cache(self):
         """Fresh all-zero cache pytree (slot lengths all 0); on a serving
-        mesh every entry is placed under its head-sharded NamedSharding."""
+        mesh every entry is placed under its declared NamedSharding."""
         cache = self._cache_zeros()
         if self.mesh is None:
             return cache
@@ -319,32 +281,29 @@ class DecodeEngine:
             self.cache_shardings())
 
     def cache_shardings(self):
-        """NamedSharding pytree matching the cache (mesh only): attention
-        K/V [slots, capacity, H, Dh] shard heads over the model axis,
-        recurrent carries shard features, lengths replicate."""
+        """NamedSharding pytree matching the cache (mesh only): each leaf
+        split over the model axis on the axis its layer declared (attention
+        K/V: heads; recurrent carries: features), lengths replicated."""
         if self._cache_shardings is None:
-            shapes = jax.eval_shape(self._cache_zeros)
-            self._cache_shardings = jax.tree_util.tree_map(
-                lambda leaf: self.mesh.cache_sharding(leaf.shape), shapes)
+            self._cache_shardings = self._map_cache(
+                lambda shape, _, axis: self.mesh.cache_sharding(shape, axis))
         return self._cache_shardings
 
     def cache_bytes(self, per_shard=False):
-        # eval_shape: sizes from the abstract pytree, no device allocation
-        shapes = jax.eval_shape(self._cache_zeros)
-        if not per_shard or self.mesh is None:
-            return sum(int(x.size * x.dtype.itemsize)
-                       for x in jax.tree_util.tree_leaves(shapes))
-        # per-shard: what ONE chip holds resident — the honest capacity
-        # number for admission and gauges on a mesh (a head-sharded entry
-        # puts 1/n_model of its bytes on each chip; uneven entries stay
-        # replicated and count whole)
-        total = 0
-        for x in jax.tree_util.tree_leaves(shapes):
-            nbytes = int(x.size * x.dtype.itemsize)
-            total += nbytes // self.mesh.cache_shard_count(x.shape)
-        return total
+        """Bytes of the cache, no device allocation. per_shard: what ONE
+        chip holds resident — the honest capacity number for admission and
+        gauges on a mesh (a head-sharded entry puts 1/n_model of its bytes
+        on each chip; uneven entries stay replicated and count whole)."""
+        split = per_shard and self.mesh is not None
 
-    # ------------------------------------------------------------ walks
+        def nbytes(shape, dtype, axis):
+            if split:
+                shape = self.mesh.cache_sharding(shape, axis) \
+                    .shard_shape(shape)
+            return math.prod(shape) * jnp.dtype(dtype).itemsize
+        return sum(jax.tree_util.tree_leaves(self._map_cache(nbytes)))
+
+    # ------------------------------------------------------------- walk
     def _node_scope(self, node):
         """jax.named_scope of one walked node: its name on its operations
         in the lowered text and in a device trace, the output node's under
@@ -353,180 +312,11 @@ class DecodeEngine:
         return jax.named_scope(node.name if node.name != self.output_name
                                else "lm_head/" + node.name)
 
-    def _paged_append_seq(self, entry, t, row):
-        """Scatter a [L, H, Dh] token sequence into the pool along `row`
-        (the slot's table row): the L positions reshape into L/bs chunks of
-        one block each, landing at the row's physical block ids. Pad chunks
-        of a prefill bucket address block 0 (scratch) — over-length writes
-        land where nobody reads instead of needing in-trace bounds checks."""
-        bs = self.block_size
-        L = t.shape[0]
-        chunks = -(-L // bs)
-        pad = chunks * bs - L
-        if pad:
-            t = jnp.pad(t, ((0, pad), (0, 0), (0, 0)))
-        tc = t.reshape(chunks, bs, t.shape[1], t.shape[2])
-        return entry.at[row[:chunks]].set(tc.astype(entry.dtype))
-
-    def _walk_prefill(self, params, states, x0, mask, cache, slot, length,
-                      table=None):
-        """Full-sequence forward over the plan, capturing each stateful
-        layer's K/V (resp. final carry) into `slot`'s cache rows — in paged
-        mode, into the pool blocks of `slot`'s table row."""
-        acts = {self.input_name: x0}
-        layers = dict(cache["layers"])
-        if table is not None:
-            row = lax.dynamic_index_in_dim(table, slot, 0, keepdims=False)
-        for node in self.nodes:
-            if node.kind == "input":
-                continue
-            with self._node_scope(node):
-                if node.kind == "vertex":
-                    acts[node.name] = node.vertex.apply(
-                        [acts[i] for i in node.inputs])
-                    continue
-                m = node.module
-                p, s = params[node.name], states[node.name]
-                x = acts[node.inputs[0]]
-                if isinstance(m, SelfAttentionLayerModule):
-                    q, k, v = m.project_qkv(p, x)             # [1, L, H, Dh]
-                    with jax.named_scope("attention"):
-                        out = m.attend(q, k, v, mask)
-                    y = m.finish(p, out, mask)
-                    entry = layers[node.name]
-                    with jax.named_scope("kv_append"):
-                        if table is not None:
-                            layers[node.name] = {
-                                "k": self._paged_append_seq(entry["k"], k[0],
-                                                            row),
-                                "v": self._paged_append_seq(entry["v"], v[0],
-                                                            row)}
-                        else:
-                            # match the traced slot's index dtype under x64
-                            z = jnp.zeros((), slot.dtype)
-                            layers[node.name] = {
-                                "k": lax.dynamic_update_slice(
-                                    entry["k"], k.astype(entry["k"].dtype),
-                                    (slot, z, z, z)),
-                                "v": lax.dynamic_update_slice(
-                                    entry["v"], v.astype(entry["v"].dtype),
-                                    (slot, z, z, z))}
-                elif isinstance(m, _BaseLSTMModule):
-                    n_out = int(m.conf.n_out)
-                    zeros = (jnp.zeros((1, n_out), self._dtype),
-                             jnp.zeros((1, n_out), self._dtype))
-                    # masked steps carry state through (the scan's contract),
-                    # so the final carry equals the state after `length` real
-                    # steps
-                    y, _, _, (hf, cf) = m.forward(p, s, x, mask=mask,
-                                                  initial_state=zeros,
-                                                  return_state=True)
-                    entry = layers[node.name]
-                    z = jnp.zeros((), slot.dtype)
-                    layers[node.name] = {
-                        "h": lax.dynamic_update_slice(
-                            entry["h"], hf.astype(entry["h"].dtype),
-                            (slot, z)),
-                        "c": lax.dynamic_update_slice(
-                            entry["c"], cf.astype(entry["c"].dtype),
-                            (slot, z))}
-                else:
-                    y = m.forward(p, s, x, train=False, rng=None, mask=mask)[0]
-                acts[node.name] = y
-        return acts[self.output_name], layers
-
-    def _walk_step(self, params, states, x0, cache, pos, kv_valid,
-                   table=None):
-        """[slots, 1, f] single-token forward against the cache. `pos` is
-        the per-slot append position (clamped), `kv_valid` the number of
-        valid cache entries including the appended token."""
-        from ..kernels import flash_decode, flash_decode_paged, kv_append
-        acts = {self.input_name: x0}
-        layers = dict(cache["layers"])
-        if table is not None:
-            bs = self.block_size
-            # physical (block, offset) of each slot's append position; an
-            # unallocated logical block maps to 0 = scratch, so a slot the
-            # scheduler hasn't backed writes where nobody reads
-            blk = jnp.take_along_axis(table, (pos // bs)[:, None],
-                                      axis=1)[:, 0]
-            off = pos % bs
-        for node in self.nodes:
-            if node.kind == "input":
-                continue
-            with self._node_scope(node):
-                if node.kind == "vertex":
-                    acts[node.name] = node.vertex.apply(
-                        [acts[i] for i in node.inputs])
-                    continue
-                m = node.module
-                p, s = params[node.name], states[node.name]
-                x = acts[node.inputs[0]]
-                if isinstance(m, SelfAttentionLayerModule):
-                    q, kt, vt = m.project_qkv(p, x)           # [S, 1, H, Dh]
-                    entry = layers[node.name]
-                    use_pallas = getattr(m.conf, "use_pallas", False)
-                    if table is not None:
-                        with jax.named_scope("kv_append"):
-                            nk = entry["k"].at[blk, off].set(
-                                kt[:, 0].astype(entry["k"].dtype))
-                            nv = entry["v"].at[blk, off].set(
-                                vt[:, 0].astype(entry["v"].dtype))
-                        with jax.named_scope("attention"):
-                            out = flash_decode_paged(q, nk, nv, table,
-                                                     kv_valid,
-                                                     use_pallas=use_pallas)
-                    else:
-                        with jax.named_scope("kv_append"):
-                            nk, nv = kv_append(
-                                entry["k"], entry["v"],
-                                kt.astype(entry["k"].dtype),
-                                vt.astype(entry["v"].dtype), pos,
-                                use_pallas=use_pallas)
-                        with jax.named_scope("attention"):
-                            out = flash_decode(q, nk, nv, kv_valid,
-                                               use_pallas=use_pallas)
-                    layers[node.name] = {"k": nk, "v": nv}
-                    y = m.finish(p, out.astype(x.dtype), None)
-                elif isinstance(m, _BaseLSTMModule):
-                    entry = layers[node.name]
-                    y, _, _, (hf, cf) = m.forward(
-                        p, s, x, initial_state=(entry["h"], entry["c"]),
-                        return_state=True)
-                    layers[node.name] = {"h": hf.astype(entry["h"].dtype),
-                                         "c": cf.astype(entry["c"].dtype)}
-                else:
-                    y = m.forward(p, s, x, train=False, rng=None)[0]
-                acts[node.name] = y
-        return acts[self.output_name], layers
-
-    @staticmethod
-    def _verify_attend(q, k, v, start):
-        """[1, W, H, Dh] window queries vs one slot's full [1, C, H, Dh]
-        cache row, causal against GLOBAL positions: query i (at position
-        start+i) sees keys [0, start+i]. Cache entries beyond start+W hold
-        stale garbage from longer rolled-back windows — causally masked, so
-        rollback never has to zero them. W is tiny (K+1 draft tokens), so
-        the [H, W, C] score tile is reference-einsum territory; a Mosaic
-        flash variant with a query offset is the rig follow-up."""
-        W, C = q.shape[1], k.shape[1]
-        scale = 1.0 / float(np.sqrt(q.shape[-1]))
-        qpos = start + jnp.arange(W, dtype=jnp.int32)
-        kpos = jnp.arange(C, dtype=jnp.int32)
-        mask = kpos[None, :] <= qpos[:, None]                # [W, C]
-        s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                       k.astype(jnp.float32)) * scale
-        s = jnp.where(mask[None, None], s, -1e30)
-        p = jax.nn.softmax(s, axis=-1)
-        out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
-        return out.astype(q.dtype)
-
-    def _walk_verify(self, params, states, x0, cache, slot, start):
-        """[1, W, f] window forward for speculative verify: each attention
-        layer appends the window's K/V at `slot` row offset `start` and
-        attends the window against the whole row. Attention-only by
-        construction — `verify()` rejects recurrent plans, because rollback
-        is a host-side length reset and carries cannot rewind."""
+    def _walk(self, leg, params, states, x0, cache, ctx):
+        """One forward over the plan for one leg — "prefill": a [1, L, f]
+        prompt into `ctx.slot`'s state; "step": [slots, 1, f], one token a
+        slot against the cache; "verify": a [1, W, f] window at `ctx.start`.
+        Each layer's `decode_<leg>` advances its own cache entry."""
         acts = {self.input_name: x0}
         layers = dict(cache["layers"])
         for node in self.nodes:
@@ -537,28 +327,12 @@ class DecodeEngine:
                     acts[node.name] = node.vertex.apply(
                         [acts[i] for i in node.inputs])
                     continue
-                m = node.module
-                p, s = params[node.name], states[node.name]
-                x = acts[node.inputs[0]]
-                if isinstance(m, SelfAttentionLayerModule):
-                    q, k, v = m.project_qkv(p, x)             # [1, W, H, Dh]
-                    entry = layers[node.name]
-                    z = jnp.zeros((), slot.dtype)
-                    st = jnp.asarray(start, slot.dtype)
-                    nk = lax.dynamic_update_slice(
-                        entry["k"], k.astype(entry["k"].dtype),
-                        (slot, st, z, z))
-                    nv = lax.dynamic_update_slice(
-                        entry["v"], v.astype(entry["v"].dtype),
-                        (slot, st, z, z))
-                    layers[node.name] = {"k": nk, "v": nv}
-                    krow = lax.dynamic_index_in_dim(nk, slot, 0, keepdims=True)
-                    vrow = lax.dynamic_index_in_dim(nv, slot, 0, keepdims=True)
-                    out = self._verify_attend(q, krow, vrow, start)
-                    y = m.finish(p, out.astype(x.dtype), None)
-                else:
-                    y = m.forward(p, s, x, train=False, rng=None)[0]
-                acts[node.name] = y
+                name = node.name
+                acts[name], entry = getattr(node.module, "decode_" + leg)(
+                    params[name], states[name], acts[node.inputs[0]],
+                    layers.get(name), ctx)
+                if name in layers:
+                    layers[name] = entry
         return acts[self.output_name], layers
 
     # ------------------------------------------------------- executables
@@ -576,9 +350,17 @@ class DecodeEngine:
             lengths = cache["lengths"]
             pos = jnp.clip(lengths, 0, C - 1)
             x0 = self._one_hot(ids[:, None])              # [S, 1, V]
-            y, layers = self._walk_step(params, states, x0, cache,
-                                        pos, pos + 1,
-                                        table=table if paged else None)
+            # slot s appends at pos[s] and then holds pos[s] + 1 tokens
+            ctx = _Ctx(pos=pos, kv_valid=pos + 1)
+            if paged:
+                # physical (block, offset) of each slot's append position;
+                # an unallocated logical block maps to 0 = scratch, so a
+                # slot the scheduler hasn't backed writes where nobody reads
+                bs = self.block_size
+                blk = jnp.take_along_axis(table, (pos // bs)[:, None],
+                                          axis=1)[:, 0]
+                ctx = ctx._replace(table=table, blk=blk, off=pos % bs)
+            y, layers = self._walk("step", params, states, x0, cache, ctx)
             probs = y[:, -1].astype(jnp.float32)          # [S, V]
             new_cache = {"lengths": jnp.minimum(lengths + 1, C),
                          "layers": layers}
@@ -597,9 +379,11 @@ class DecodeEngine:
             x0 = self._one_hot(ids[None, :])              # [1, L, V]
             valid = (jnp.arange(L, dtype=jnp.int32)
                      < length).astype(self._dtype)[None]  # [1, L]
-            y, layers = self._walk_prefill(params, states, x0, valid,
-                                           cache, slot, length,
-                                           table=table if paged else None)
+            ctx = _Ctx(mask=valid, slot=slot, length=length)
+            if paged:
+                ctx = ctx._replace(table=table, row=lax.dynamic_index_in_dim(
+                    table, slot, 0, keepdims=False))
+            y, layers = self._walk("prefill", params, states, x0, cache, ctx)
             z = jnp.zeros((), length.dtype)
             probs = lax.dynamic_slice(
                 y, (z, length - 1, z), (1, 1, self.vocab))[0, 0]
@@ -617,8 +401,8 @@ class DecodeEngine:
         def verify_fn(params, states, cache, slot, ids, start):
             params = self.model._dequant_params(params)
             x0 = self._one_hot(ids[None, :])              # [1, W, V]
-            y, layers = self._walk_verify(params, states, x0, cache,
-                                          slot, start)
+            y, layers = self._walk("verify", params, states, x0, cache,
+                                   _Ctx(slot=slot, start=start))
             probs = y[0].astype(jnp.float32)              # [W, V]
             # lengths unchanged: the accept decision is host-side, and the
             # host commits the accepted length via set_length afterwards
@@ -846,9 +630,8 @@ class DecodeEngine:
         return cache, nxt, probs
 
     def has_recurrent(self):
-        return any(node.kind == "layer"
-                   and isinstance(node.module, _BaseLSTMModule)
-                   for node in self.nodes)
+        """Some layer keeps a carry: state a length reset cannot rewind."""
+        return bool(self._carries)
 
     def verify(self, cache, slot, tokens, start):
         """Speculative verify: append the W-token window `tokens` at row
@@ -856,7 +639,7 @@ class DecodeEngine:
         next-token distribution AFTER each window position, all W in ONE
         batched pass. The caller owns the accept decision and commits the
         surviving length via `set_length` (rollback = not advancing it).
-        One executable per W; attention-only, slab-layout only."""
+        One executable per W; rewindable state only, slab-layout only."""
         if self.paged:
             raise DecodeUnsupported(
                 "speculative verify runs on the slab layout (the paged "
@@ -898,35 +681,25 @@ class DecodeEngine:
         return out
 
     def carry_snapshot(self, cache):
-        """Host copy of the recurrent carries + lengths — tiny ([slots,
-        n_out] per LSTM layer, no K/V. The speculative engine snapshots a
-        recurrent DRAFT before proposing and restores on rollback; attention
-        entries don't need it (rollback is a length reset)."""
-        snap = {"lengths": np.asarray(cache["lengths"]).copy(), "layers": {}}
-        for name, entry in cache["layers"].items():
-            if "h" in entry:
-                snap["layers"][name] = {k: np.asarray(v).copy()
-                                        for k, v in entry.items()}
-        return snap
+        """Host copy of the carries + lengths — tiny ([slots, n_out] per
+        LSTM layer, no K/V). The speculative engine snapshots a recurrent
+        DRAFT before proposing and restores on rollback; rewindable entries
+        don't need it (rollback is a length reset)."""
+        return {"lengths": np.asarray(cache["lengths"]).copy(),
+                "layers": {name: {k: np.asarray(v).copy()
+                                  for k, v in cache["layers"][name].items()}
+                           for name in self._carries}}
 
     def carry_restore(self, cache, snap):
-        """Rewind the recurrent carries (and lengths) to a snapshot."""
-        layers = dict(cache["layers"])
-        shardings = self.cache_shardings() if self.mesh is not None else None
-        for name, entry in snap["layers"].items():
-            if shardings is not None:
-                layers[name] = {
-                    k: jax.device_put(jnp.asarray(v),
-                                      shardings["layers"][name][k])
-                    for k, v in entry.items()}
-            else:
-                layers[name] = {k: jnp.asarray(v)
-                                for k, v in entry.items()}
-        out = {"lengths": jnp.asarray(snap["lengths"]), "layers": layers}
-        if shardings is not None:
-            out["lengths"] = jax.device_put(jnp.asarray(snap["lengths"]),
-                                            shardings["lengths"])
-        return out
+        """Rewind the carries (and lengths) to a snapshot; every other
+        entry stays the array it is (placed where it is placed already)."""
+        out = {"lengths": jnp.asarray(snap["lengths"]),
+               "layers": {**cache["layers"], **jax.tree_util.tree_map(
+                   jnp.asarray, snap["layers"])}}
+        if self.mesh is None:
+            return out
+        return jax.tree_util.tree_map(jax.device_put, out,
+                                      self.cache_shardings())
 
     def warmup(self, buckets=()):
         """Compile the step and the given prefill buckets on a scratch cache

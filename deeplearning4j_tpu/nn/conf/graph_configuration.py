@@ -40,6 +40,9 @@ def vertex_from_dict(d):
 class BaseVertexConf:
     """Non-layer DAG node (reference: nn/conf/graph/GraphVertex.java)."""
 
+    #: a per-position map over its inputs: the decode engine may stream it
+    positionwise = False
+
     def n_params(self):
         return 0
 
@@ -65,6 +68,7 @@ class BaseVertexConf:
 class ElementWiseVertex(BaseVertexConf):
     """Add/Subtract/Product/Average/Max of equal-shaped inputs
     (reference: nn/conf/graph/ElementWiseVertex.java)."""
+    positionwise = True
 
     def __init__(self, op="add"):
         self.op = op
@@ -100,6 +104,7 @@ class ElementWiseVertex(BaseVertexConf):
 class MergeVertex(BaseVertexConf):
     """Concatenate along the feature/channel (last) axis
     (reference: nn/conf/graph/MergeVertex.java)."""
+    positionwise = True
 
     def __init__(self):
         pass

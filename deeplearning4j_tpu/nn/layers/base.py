@@ -15,6 +15,8 @@ variable-length sequences (reference: Layer.feedForwardMaskArray).
 """
 from __future__ import annotations
 
+from typing import Any, NamedTuple
+
 import jax
 import jax.numpy as jnp
 
@@ -47,8 +49,32 @@ def apply_dropout(x, rate, train, rng):
     return jnp.where(mask, x / keep, 0.0)
 
 
+class CacheLeaf(NamedTuple):
+    """One array of a layer's decode-cache entry: its shape, its dtype, and
+    the axis a serving mesh splits over its model axis (None: replicated)."""
+    shape: tuple
+    dtype: Any
+    model_axis: int | None = None
+
+
 class BaseLayerModule:
-    """One instantiated layer: shape-aware param init + pure forward."""
+    """One instantiated layer: shape-aware param init + pure forward.
+
+    The decode contract (walked by decode/engine.py, which names no layer
+    class) is four answers: may the layer stream (`decode_unsupported`),
+    what it keeps per decode slot (`decode_entry`), how a prompt fills that
+    and one token or a verify window advances it (`decode_prefill`,
+    `decode_step`, `decode_verify`), and whether a length reset rewinds it
+    (`decode_rewindable`). SelfAttentionLayerModule and _BaseLSTMModule
+    (recurrent.py) are the two worked examples."""
+
+    #: forward is a per-position map ([b,t,f] -> [b,t,g], position i from
+    #: position i alone): it streams with no state of its own
+    positionwise = False
+    #: rolling a slot back is a reset of its length: nothing the layer keeps
+    #: has to be restored (False: verify refuses the plan, and the
+    #: speculative draft snapshots the entry instead)
+    decode_rewindable = True
 
     def __init__(self, conf):
         self.conf = conf
@@ -62,6 +88,34 @@ class BaseLayerModule:
     def forward(self, params, state, x, *, train=False, rng=None, mask=None):
         """Returns (activations, new_state, out_mask)."""
         raise NotImplementedError
+
+    # -- decode contract ----------------------------------------------------
+    def decode_unsupported(self):
+        """None when the layer can decode token by token, else the reason."""
+        if self.positionwise:
+            return None
+        return f"{type(self).__name__} has no per-token decode semantics"
+
+    def decode_entry(self, geom):
+        """{leaf name: CacheLeaf} the layer keeps in the decode cache, from
+        the engine's geometry: `slots`, `capacity`, `dtype`, and `paged`
+        with `block_size` / `num_blocks` for the block-pool layout. Empty:
+        no state."""
+        return {}
+
+    def decode_prefill(self, params, state, x, entry, ctx):
+        """(y, entry) of one leg. prefill: x [1, L, f] is a padded prompt
+        for cache slot `ctx.slot`, `ctx.mask` [1, L] marks its `ctx.length`
+        real tokens (paged: `ctx.row` is the slot's block-table row).
+        step: x [slots, 1, f], slot s appends at `ctx.pos[s]` and then
+        holds `ctx.kv_valid[s]` tokens (paged: at block `ctx.blk[s]`, offset
+        `ctx.off[s]` of the pool behind `ctx.table`). verify: x [1, W, f] is
+        a window appended at position `ctx.start` of slot `ctx.slot`; only
+        asked of rewindable layers. Stateless default: the forward."""
+        return self.forward(params, state, x, train=False, rng=None,
+                            mask=ctx.mask)[0], entry
+
+    decode_step = decode_verify = decode_prefill
 
     # -- optional: output-layer protocol -------------------------------------
     def is_output_layer(self):

@@ -133,6 +133,7 @@ class LayerNormalizationModule(BaseLayerModule):
     """Layer norm over the last axis (stateless; NEW — the reference's 2017
     layer set has no LayerNormalization). Per-position mean/variance keep
     transformer activations stable regardless of batch composition."""
+    positionwise = True
 
     def init(self, rng, input_type, dtype=jnp.float32):
         c = self.conf

@@ -54,13 +54,14 @@ class _DenseCore(BaseLayerModule):
 
 @register_impl("DenseLayer")
 class DenseLayerModule(_DenseCore):
-    pass
+    positionwise = True
 
 
 @register_impl("EmbeddingLayer")
 class EmbeddingLayerModule(BaseLayerModule):
     """Index lookup: mathematically a one-hot matmul, implemented as a gather
     (reference: feedforward/embedding/EmbeddingLayer.java)."""
+    positionwise = True
 
     def init(self, rng, input_type, dtype=jnp.float32):
         c = self.conf
@@ -99,13 +100,14 @@ class BaseOutputLayerModule(_DenseCore):
 
 @register_impl("OutputLayer")
 class OutputLayerModule(BaseOutputLayerModule):
-    pass
+    positionwise = True
 
 
 @register_impl("RnnOutputLayer")
 class RnnOutputLayerModule(BaseOutputLayerModule):
     """Applies the dense projection per timestep on [b,t,f]
     (reference: nn/layers/recurrent/RnnOutputLayer.java)."""
+    positionwise = True
 
     def preoutput(self, params, x):
         return x @ params["W"] + params["b"]
@@ -126,6 +128,7 @@ class RnnOutputLayerModule(BaseOutputLayerModule):
 @register_impl("LossLayer")
 class LossLayerModule(BaseLayerModule):
     """Parameterless loss on incoming activations (reference: LossLayer.java)."""
+    positionwise = True
 
     def init(self, rng, input_type, dtype=jnp.float32):
         return {}, {}, input_type

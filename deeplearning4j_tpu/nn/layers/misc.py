@@ -9,6 +9,8 @@ from .base import BaseLayerModule, register_impl, apply_dropout
 
 @register_impl("ActivationLayer")
 class ActivationLayerModule(BaseLayerModule):
+    positionwise = True
+
     def init(self, rng, input_type, dtype=jnp.float32):
         return {}, {}, input_type
 
@@ -18,6 +20,8 @@ class ActivationLayerModule(BaseLayerModule):
 
 @register_impl("DropoutLayer")
 class DropoutLayerModule(BaseLayerModule):
+    positionwise = True
+
     def init(self, rng, input_type, dtype=jnp.float32):
         return {}, {}, input_type
 

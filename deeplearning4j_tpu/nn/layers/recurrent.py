@@ -16,11 +16,12 @@ supported via explicit carry in/out: forward(..., initial_state=..., return_stat
 """
 from __future__ import annotations
 
+import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .base import BaseLayerModule, register_impl, apply_dropout
+from .base import BaseLayerModule, CacheLeaf, register_impl, apply_dropout
 from ..activations import get_activation
 from ..weights import init_weights
 from ..conf.inputs import InputType
@@ -47,6 +48,14 @@ def _init_lstm_params(rng, n_in, n_out, conf, dtype, peephole):
     return params
 
 
+def _acc_dtype(dt):
+    """Any sub-32-bit float compute (bf16, and f16 with its 65504 max) gets
+    f32 accumulation: the scan's gate arithmetic and cell state, and the
+    carry rows a decode cache keeps."""
+    return (jnp.float32 if jnp.issubdtype(dt, jnp.floating)
+            and jnp.finfo(dt).bits < 32 else dt)
+
+
 def _lstm_scan(params, x, h0, c0, gate_act, cell_act, peephole, mask=None, reverse=False):
     """x: [b,t,n_in] -> outputs [b,t,n_out], final (h,c).
 
@@ -66,10 +75,7 @@ def _lstm_scan(params, x, h0, c0, gate_act, cell_act, peephole, mask=None, rever
     W, RW, b = params["W"], params["RW"], params["b"]
     P = params.get("P")
     out_dt = x.dtype
-    # any sub-32-bit float compute (bf16, and f16 with its 65504 max) gets
-    # the f32 accumulation treatment
-    acc_dt = (jnp.float32 if jnp.issubdtype(out_dt, jnp.floating)
-              and jnp.finfo(out_dt).bits < 32 else out_dt)
+    acc_dt = _acc_dtype(out_dt)
     if P is not None:
         P = P.astype(acc_dt)
 
@@ -137,6 +143,38 @@ class _BaseLSTMModule(BaseLayerModule):
             return outs, state, mask, final
         return outs, state, mask
 
+    # -- decode: each slot's (h, c) carry in a [slots, n_out] row. A carry
+    # cannot be rolled back by a length reset, so verify refuses the plan
+    # and a speculative draft snapshots the rows instead
+    decode_rewindable = False
+
+    def decode_unsupported(self):
+        return None
+
+    def decode_entry(self, geom):
+        leaf = CacheLeaf((geom.slots, int(self.conf.n_out)),
+                         _acc_dtype(geom.dtype), 1)
+        return {"h": leaf, "c": leaf}
+
+    def decode_prefill(self, params, state, x, entry, ctx):
+        # masked steps carry state through (the scan's contract), so the
+        # final carry equals the state after `length` real steps
+        y, _, _, (hf, cf) = self.forward(params, state, x, mask=ctx.mask,
+                                         return_state=True)
+        at = (ctx.slot, jnp.zeros((), ctx.slot.dtype))
+        return y, {
+            "h": lax.dynamic_update_slice(
+                entry["h"], hf.astype(entry["h"].dtype), at),
+            "c": lax.dynamic_update_slice(
+                entry["c"], cf.astype(entry["c"].dtype), at)}
+
+    def decode_step(self, params, state, x, entry, ctx):
+        y, _, _, (hf, cf) = self.forward(
+            params, state, x, initial_state=(entry["h"], entry["c"]),
+            return_state=True)
+        return y, {"h": hf.astype(entry["h"].dtype),
+                   "c": cf.astype(entry["c"].dtype)}
+
 
 @register_impl("GravesLSTM")
 class GravesLSTMModule(_BaseLSTMModule):
@@ -175,6 +213,48 @@ class GravesBidirectionalLSTMModule(BaseLayerModule):
                               c.activation, True, mask, reverse=True)
         return out_f + out_b, state, mask
 
+    def decode_unsupported(self):
+        return ("bidirectional recurrence needs future tokens and cannot "
+                "stream")
+
+
+def _paged_append_seq(pool, t, row):
+    """Scatter a [L, H, Dh] token sequence into the [N, bs, H, Dh] block
+    pool along `row` (the slot's table row): the L positions reshape into
+    L/bs chunks of one block each, landing at the row's physical block ids.
+    Pad chunks of a prefill bucket address block 0 (scratch) — over-length
+    writes land where nobody reads instead of needing in-trace bounds
+    checks."""
+    bs = pool.shape[1]
+    L = t.shape[0]
+    chunks = -(-L // bs)
+    pad = chunks * bs - L
+    if pad:
+        t = jnp.pad(t, ((0, pad), (0, 0), (0, 0)))
+    tc = t.reshape(chunks, bs, t.shape[1], t.shape[2])
+    return pool.at[row[:chunks]].set(tc.astype(pool.dtype))
+
+
+def _verify_attend(q, k, v, start):
+    """[1, W, H, Dh] window queries vs one slot's full [1, C, H, Dh] cache
+    row, causal against GLOBAL positions: query i (at position start+i)
+    sees keys [0, start+i]. Cache entries beyond start+W hold stale garbage
+    from longer rolled-back windows — causally masked, so rollback never has
+    to zero them. W is tiny (K+1 draft tokens), so the [H, W, C] score tile
+    is reference-einsum territory; a Mosaic flash variant with a query
+    offset is the rig follow-up."""
+    W, C = q.shape[1], k.shape[1]
+    scale = 1.0 / float(np.sqrt(q.shape[-1]))
+    qpos = start + jnp.arange(W, dtype=jnp.int32)
+    kpos = jnp.arange(C, dtype=jnp.int32)
+    mask = kpos[None, :] <= qpos[:, None]                # [W, C]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
+                   k.astype(jnp.float32)) * scale
+    s = jnp.where(mask[None, None], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
+    return out.astype(q.dtype)
+
 
 @register_impl("SelfAttentionLayer")
 class SelfAttentionLayerModule(BaseLayerModule):
@@ -183,7 +263,22 @@ class SelfAttentionLayerModule(BaseLayerModule):
     blockwise attention; a key mask folds the sequence mask into the scores
     and zeroes masked outputs (same convention as the LSTM scan). For
     sequence-parallel long-context attention call
-    parallel.ring_attention.ring_attention on the projections directly."""
+    parallel.ring_attention.ring_attention on the projections directly.
+
+    Decode state (causal layers only): K and V of every token so far, as a
+    slab `[slots, capacity, H, Dh]` or, paged, a block pool `[num_blocks,
+    block_size, H, Dh]` the slots share through the engine's block table
+    (decode/paged.py); the head axis splits over a serving mesh's model
+    axis. Prefill writes the prompt's K/V into the slot's rows in one
+    update; pad positions write garbage beyond `length` that the length
+    mask hides from every later step. A step appends every slot's token
+    with ONE in-place kernel a layer (kernels.flash_attention.kv_append) and
+    attends with the decode kernel (flash_decode), both on the cache buffer
+    in the layout the device stores it in: no instruction of the step
+    copies a slab or loops over the slots (tests/test_tpu_compile.py). The
+    paged step scatters into (table[pos // bs], pos % bs) and gathers the
+    slot's blocks back (flash_decode_paged), token for token the slab.
+    Rolling back is a length reset: stale rows are causally masked."""
 
     def init(self, rng, input_type, dtype=jnp.float32):
         c = self.conf
@@ -202,8 +297,8 @@ class SelfAttentionLayerModule(BaseLayerModule):
 
     def project_qkv(self, params, x):
         """[b,t,f] -> (q, k, v) each [b,t,H,Dh]. Split out of forward so the
-        decode engine (decode/engine.py) can run the SAME projections when
-        it appends one token's k/v to a KV-cache slot."""
+        decode legs run the SAME projections when they append a token's k/v
+        to a KV-cache slot."""
         c = self.conf
         B, T, _ = x.shape
         H = int(c.n_heads)
@@ -244,6 +339,81 @@ class SelfAttentionLayerModule(BaseLayerModule):
         if mask is not None:
             out = out * mask[:, :, None]  # zero masked steps like the LSTM scan
         return out
+
+    # -- decode ---------------------------------------------------------------
+    def decode_unsupported(self):
+        if not getattr(self.conf, "causal", False):
+            return ("non-causal attention attends to future positions and "
+                    "cannot decode incrementally")
+        return None
+
+    def decode_entry(self, geom):
+        H = int(self.conf.n_heads)
+        Dh = int(self.conf.n_out) // H
+        shape = ((geom.num_blocks, geom.block_size, H, Dh) if geom.paged
+                 else (geom.slots, geom.capacity, H, Dh))
+        leaf = CacheLeaf(shape, geom.dtype, 2)
+        return {"k": leaf, "v": leaf}
+
+    def decode_prefill(self, params, state, x, entry, ctx):
+        q, k, v = self.project_qkv(params, x)                 # [1, L, H, Dh]
+        with jax.named_scope("attention"):
+            out = self.attend(q, k, v, ctx.mask)
+        y = self.finish(params, out, ctx.mask)
+        with jax.named_scope("kv_append"):
+            if ctx.table is not None:
+                return y, {
+                    "k": _paged_append_seq(entry["k"], k[0], ctx.row),
+                    "v": _paged_append_seq(entry["v"], v[0], ctx.row)}
+            # match the traced slot's index dtype under x64
+            z = jnp.zeros((), ctx.slot.dtype)
+            at = (ctx.slot, z, z, z)
+            return y, {
+                "k": lax.dynamic_update_slice(
+                    entry["k"], k.astype(entry["k"].dtype), at),
+                "v": lax.dynamic_update_slice(
+                    entry["v"], v.astype(entry["v"].dtype), at)}
+
+    def decode_step(self, params, state, x, entry, ctx):
+        from ...kernels import flash_decode, flash_decode_paged, kv_append
+        q, kt, vt = self.project_qkv(params, x)               # [S, 1, H, Dh]
+        use_pallas = getattr(self.conf, "use_pallas", False)
+        if ctx.table is not None:
+            with jax.named_scope("kv_append"):
+                nk = entry["k"].at[ctx.blk, ctx.off].set(
+                    kt[:, 0].astype(entry["k"].dtype))
+                nv = entry["v"].at[ctx.blk, ctx.off].set(
+                    vt[:, 0].astype(entry["v"].dtype))
+            with jax.named_scope("attention"):
+                out = flash_decode_paged(q, nk, nv, ctx.table, ctx.kv_valid,
+                                         use_pallas=use_pallas)
+        else:
+            with jax.named_scope("kv_append"):
+                nk, nv = kv_append(entry["k"], entry["v"],
+                                   kt.astype(entry["k"].dtype),
+                                   vt.astype(entry["v"].dtype), ctx.pos,
+                                   use_pallas=use_pallas)
+            with jax.named_scope("attention"):
+                out = flash_decode(q, nk, nv, ctx.kv_valid,
+                                   use_pallas=use_pallas)
+        return self.finish(params, out.astype(x.dtype), None), \
+            {"k": nk, "v": nv}
+
+    def decode_verify(self, params, state, x, entry, ctx):
+        """Slab layout only (the engine's verify() refuses the paged one)."""
+        q, k, v = self.project_qkv(params, x)                 # [1, W, H, Dh]
+        slot = ctx.slot
+        z = jnp.zeros((), slot.dtype)
+        at = (slot, jnp.asarray(ctx.start, slot.dtype), z, z)
+        nk = lax.dynamic_update_slice(entry["k"],
+                                      k.astype(entry["k"].dtype), at)
+        nv = lax.dynamic_update_slice(entry["v"],
+                                      v.astype(entry["v"].dtype), at)
+        krow = lax.dynamic_index_in_dim(nk, slot, 0, keepdims=True)
+        vrow = lax.dynamic_index_in_dim(nv, slot, 0, keepdims=True)
+        out = _verify_attend(q, krow, vrow, ctx.start)
+        return self.finish(params, out.astype(x.dtype), None), \
+            {"k": nk, "v": nv}
 
     def forward(self, params, state, x, *, train=False, rng=None, mask=None):
         c = self.conf

@@ -187,24 +187,15 @@ class MeshContext:
                 model.states, even_sharding(self.mesh, P(), ()))
         return model.params
 
-    def cache_sharding(self, shape):
-        """Decode-cache entry sharding: 4-D attention K/V [slots, capacity,
-        H, Dh] partition the HEAD axis over the model axis; 2-D recurrent
-        carries [slots, n_out] partition the feature axis; 1-D lengths
-        replicate. Uneven dims degrade to replicated (even_sharding)."""
-        if len(shape) == 4:
-            spec = P(None, None, MODEL_AXIS, None)
-        elif len(shape) == 2:
-            spec = P(None, MODEL_AXIS)
-        else:
-            spec = P()
+    def cache_sharding(self, shape, model_axis=None):
+        """Decode-cache leaf sharding: `model_axis`, the axis the layer that
+        keeps the leaf declared (attention K/V: heads; recurrent carries:
+        features), partitions over the model axis; None replicates. An
+        uneven dim degrades to replicated (even_sharding)."""
+        spec = P() if model_axis is None else P(
+            *[MODEL_AXIS if i == model_axis else None
+              for i in range(len(shape))])
         return even_sharding(self.mesh, spec, shape)
-
-    def cache_shard_count(self, shape):
-        """How many pieces a cache entry of `shape` is split into — the
-        denominator for per-shard cache accounting (satellite: capacity
-        admission and gauges must report per-chip bytes on a mesh)."""
-        return spec_shards(self.mesh, self.cache_sharding(shape).spec)
 
     # ---- wrapping ----------------------------------------------------------
     def wrap(self, model):

@@ -1,0 +1,242 @@
+"""The decode contract lives on the layer (nn/layers/base.py), not in decode/:
+
+- a stateful layer defined HERE — a causal running mean over time, one
+  `[slots, n_out]` row of running sums per slot — decodes through
+  DecodeEngine on the slab and the paged layout with no edit under decode/;
+  declared non-rewindable, verify() refuses it and carry_snapshot holds it.
+- a cache leaf is placed on a serving mesh by the axis its layer declared,
+  whatever its rank (a 3-D latent-style leaf has no head axis to guess).
+- decode/engine.py names no layer class.
+"""
+import ast
+import pathlib
+from dataclasses import dataclass
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.sharding import PartitionSpec as P
+
+from deeplearning4j_tpu.decode import DecodeEngine, DecodeUnsupported
+from deeplearning4j_tpu.decode import engine as engine_module
+from deeplearning4j_tpu.nn.conf.configuration import NeuralNetConfiguration
+from deeplearning4j_tpu.nn.conf.inputs import InputType
+from deeplearning4j_tpu.nn.conf.layers import (BaseRecurrentConf,
+                                               BatchNormalization, DenseLayer,
+                                               RnnOutputLayer,
+                                               register_layer_conf)
+from deeplearning4j_tpu.nn.layers.base import (BaseLayerModule, CacheLeaf,
+                                               register_impl)
+from deeplearning4j_tpu.nn.multilayer.network import MultiLayerNetwork
+from deeplearning4j_tpu.serving.mesh import MeshContext
+
+V = 12   # vocab
+F = 8    # hidden width
+
+
+@register_layer_conf
+@dataclass
+class RunningMeanLayer(BaseRecurrentConf):
+    """y[t] = mean of x[0..t] over the real (unmasked) positions."""
+
+
+@register_impl("RunningMeanLayer")
+class RunningMeanLayerModule(BaseLayerModule):
+    decode_rewindable = False       # a running sum cannot be un-added
+
+    def init(self, rng, input_type, dtype=jnp.float32):
+        return {}, {}, input_type
+
+    def forward(self, params, state, x, *, train=False, rng=None, mask=None):
+        m = jnp.ones(x.shape[:2], x.dtype) if mask is None \
+            else mask.astype(x.dtype)
+        sums = jnp.cumsum(x * m[:, :, None], axis=1)
+        count = jnp.maximum(jnp.cumsum(m, axis=1), 1)
+        return sums / count[:, :, None], state, mask
+
+    def decode_unsupported(self):
+        return None
+
+    def decode_entry(self, geom):
+        return {"sum": CacheLeaf((geom.slots, int(self.conf.n_out)),
+                                 geom.dtype)}
+
+    def decode_prefill(self, params, state, x, entry, ctx):
+        y = self.forward(params, state, x, mask=ctx.mask)[0]
+        total = jnp.sum(x * ctx.mask[:, :, None], axis=1)        # [1, F]
+        at = (ctx.slot, jnp.zeros((), ctx.slot.dtype))
+        return y, {"sum": lax.dynamic_update_slice(
+            entry["sum"], total.astype(entry["sum"].dtype), at)}
+
+    def decode_step(self, params, state, x, entry, ctx):
+        total = entry["sum"] + x[:, 0]
+        y = total / ctx.kv_valid[:, None].astype(total.dtype)
+        return y[:, None], {"sum": total}
+
+
+@register_layer_conf
+@dataclass
+class LatentLikeLayer(BaseRecurrentConf):
+    """Identity that declares a 3-D `[slots, capacity, n_out]` cache leaf
+    (the shape of a latent cache: no head axis) and never touches it."""
+    model_axis: int | None = None
+
+
+@register_impl("LatentLikeLayer")
+class LatentLikeLayerModule(BaseLayerModule):
+    positionwise = True
+
+    def init(self, rng, input_type, dtype=jnp.float32):
+        return {}, {}, input_type
+
+    def forward(self, params, state, x, *, train=False, rng=None, mask=None):
+        return x, state, mask
+
+    def decode_entry(self, geom):
+        return {"latent": CacheLeaf(
+            (geom.slots, geom.capacity, int(self.conf.n_out)), geom.dtype,
+            self.conf.model_axis)}
+
+
+def _net(*middle, seed=3):
+    b = NeuralNetConfiguration.builder().seed(seed).list()
+    b.layer(DenseLayer(n_out=F, activation="tanh"))
+    for conf in middle:
+        b.layer(conf)
+    b.layer(RnnOutputLayer(n_out=V, activation="softmax", loss="MCXENT"))
+    return MultiLayerNetwork(
+        b.input_type(InputType.recurrent(V)).build()).init()
+
+
+def _naive_greedy(net, prompt, n):
+    """(ids, last-position probability rows) of re-running the full forward
+    on the growing sequence."""
+    ids, out, rows = list(prompt), [], []
+    for _ in range(n):
+        x = np.eye(V, dtype=np.float32)[np.asarray(ids)][None]
+        rows.append(np.asarray(net.output(x))[0, -1])
+        out.append(int(rows[-1].argmax()))
+        ids.append(out[-1])
+    return out, np.stack(rows)
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["slab", "paged"])
+def test_a_layer_defined_outside_decode_streams_token_for_token(paged):
+    """Two slots joined at different steps: each request's tokens (and
+    probability rows) equal net.output(...) on its own growing sequence."""
+    net = _net(RunningMeanLayer(n_out=F),
+               DenseLayer(n_out=F, activation="tanh"))
+    prompts, n = {1: [3, 1, 4, 1, 5], 0: [9, 2, 6]}, 7
+    want = {s: _naive_greedy(net, p, n) for s, p in prompts.items()}
+    eng = DecodeEngine(net, slots=2, max_len=32, paged=paged, block_size=4)
+    cache = eng.init_cache()
+    assert cache["layers"]["1"]["sum"].shape == (2, F)
+    got, rows = {1: [], 0: []}, {1: [], 0: []}
+    ids = np.zeros((2,), np.int32)
+    cache, nid, probs = eng.prefill(cache, 1, prompts[1])
+    got[1].append(nid)
+    rows[1].append(probs)
+    step = 0
+    while any(len(g) < n for g in got.values()):
+        step += 1
+        if step == 4:                       # slot 0 joins three tokens later
+            cache, nid, probs = eng.prefill(cache, 0, prompts[0])
+            got[0].append(nid)
+            rows[0].append(probs)
+            continue
+        for s in got:
+            if got[s]:
+                ids[s] = got[s][-1]
+        cache, nxt, probs = eng.step(cache, ids)
+        for s in got:
+            if got[s] and len(got[s]) < n:
+                got[s].append(int(nxt[s]))
+                rows[s].append(probs[s])
+    for s in prompts:
+        assert got[s] == want[s][0]
+        np.testing.assert_allclose(np.stack(rows[s]), want[s][1],
+                                   rtol=1e-4, atol=1e-6)
+    assert eng.executable_counts()["decode_step"] == 1
+
+
+def test_a_non_rewindable_entry_is_refused_by_verify_and_snapshotted():
+    net = _net(RunningMeanLayer(n_out=F))
+    eng = DecodeEngine(net, slots=2, max_len=16)
+    assert eng.has_recurrent()
+    cache, _, _ = eng.prefill(eng.init_cache(), 1, [1, 2, 3])
+    with pytest.raises(DecodeUnsupported):
+        eng.verify(cache, 1, [4, 5], 3)
+    snap = eng.carry_snapshot(cache)
+    assert set(snap["layers"]) == {"1"}
+    np.testing.assert_array_equal(snap["layers"]["1"]["sum"],
+                                  np.asarray(cache["layers"]["1"]["sum"]))
+    stepped, _, _ = eng.step(cache, np.array([0, 7], np.int32))
+    back = eng.carry_restore(stepped, snap)
+    np.testing.assert_array_equal(np.asarray(back["layers"]["1"]["sum"]),
+                                  snap["layers"]["1"]["sum"])
+    np.testing.assert_array_equal(np.asarray(back["lengths"]), [0, 3])
+
+
+def test_a_layer_that_answers_nothing_is_refused_by_name():
+    with pytest.raises(DecodeUnsupported, match="BatchNormalizationModule"):
+        DecodeEngine(_net(BatchNormalization()), slots=1, max_len=16)
+
+
+@pytest.mark.parametrize("axis,spec", [(None, P()),
+                                       (2, P(None, None, "model"))])
+def test_a_cache_leaf_is_placed_by_its_declared_axis_not_its_rank(axis, spec):
+    """1 x 4 mesh: a 3-D leaf declared with no model axis replicates (the
+    rank rule knew 4-D and 2-D only), declared with one it splits there."""
+    ctx = MeshContext({"n_data": 1, "n_model": 4}, devices=jax.devices()[:4])
+    net = ctx.wrap(_net(LatentLikeLayer(n_out=F, model_axis=axis),
+                        RunningMeanLayer(n_out=F)))
+    eng = DecodeEngine(net, slots=2, max_len=16)
+    cache = eng.init_cache()
+    latent = cache["layers"]["1"]["latent"]
+    assert latent.shape == (2, 16, F)
+    assert latent.sharding.spec == spec
+    # the 2-D running sums declared no axis either: replicated, where the
+    # rank rule split every 2-D leaf on axis 1
+    assert cache["layers"]["2"]["sum"].sharding.spec == P()
+    assert eng.cache_shardings()["lengths"].spec == P()
+    whole = eng.cache_bytes()
+    split = 2 * 16 * F * 4 * (3 if axis is not None else 0) // 4
+    assert eng.cache_bytes(per_shard=True) == whole - split
+    # and it decodes under the mesh as it does on one device
+    assert eng.generate([5, 1, 2], 4) == \
+        _naive_greedy(net.mesh_inner, [5, 1, 2], 4)[0]
+
+
+@pytest.mark.parametrize("shape,axis", [((4, 8), 1), ((4, 16, 8), 1),
+                                        ((4, 16, 8, 4), 2),
+                                        ((4, 16, 6, 4), 2)])
+def test_mesh_cache_sharding_takes_the_axis(shape, axis):
+    ctx = MeshContext({"n_data": 1, "n_model": 4}, devices=jax.devices()[:4])
+    got = ctx.cache_sharding(shape, axis).spec
+    if shape[axis] % 4:            # uneven: degrades to replicated
+        assert got == P()
+    else:
+        assert got == P(*[None] * axis, "model", *[None] * (len(shape)
+                                                           - axis - 1))
+    assert ctx.cache_sharding(shape).spec == P()
+
+
+def test_the_engine_names_no_layer_class():
+    tree = ast.parse(pathlib.Path(engine_module.__file__).read_text())
+    for node in ast.walk(tree):
+        if isinstance(node, ast.ImportFrom):
+            assert "nn.layers" not in (node.module or ""), node.module
+        if isinstance(node, ast.Import):
+            assert all("nn.layers" not in a.name for a in node.names)
+        if isinstance(node, ast.Call) \
+                and getattr(node.func, "id", None) == "isinstance":
+            # the plan tells the two network classes apart; nothing asks a
+            # node's module (or a cache entry) what it is
+            assert ast.unparse(node.args[0]) == "model", ast.unparse(node)
+    src = ast.unparse(tree)
+    for gone in ("_POSITIONWISE", "_check_layer", "_walk_step",
+                 "_walk_prefill", "_walk_verify", "'h' in entry"):
+        assert gone not in src, gone
