@@ -208,6 +208,11 @@ class DecodeScheduler:
             "appended, retirements, futures completed), ms")
         reg.gauge("decode_active_slots", "In-flight generate requests",
                   fn=lambda: float(self.active_count()))
+        reg.gauge("decode_kv_live_pct",
+                  "Cache positions the active requests have filled (prompt "
+                  "+ tokens emitted), % of slots x capacity: what the next "
+                  "step's attention has to read",
+                  fn=self.kv_live_pct)
         reg.gauge("decode_queue_depth", "Generate requests awaiting a slot",
                   fn=lambda: float(self.depth()))
         # PER-SHARD cache bytes: on a mesh the KV cache partitions its head
@@ -234,6 +239,13 @@ class DecodeScheduler:
     def active_count(self):
         # loop-thread-written dict; len() is atomic enough for a gauge
         return len(self._active)
+
+    def kv_live_pct(self):
+        # from what the loop thread already holds on the host, no device
+        # read; list() of the loop-thread-written dict is atomic enough
+        live = sum(len(r.prompt) + len(r.tokens)
+                   for r in list(self._active.values()))
+        return 100.0 * live / (self.slots * self.max_len)
 
     def submit(self, prompt_ids, max_new_tokens=None, timeout_ms=None,
                stop_id=None, sampler=None):

@@ -36,15 +36,20 @@ backward for free: ds = p·(dp − Δ) with Δ = rowsum(dO·O) − g_lse.
 
 Decode (`flash_decode`: one query per cache slot against a [slots,
 capacity, H, D] KV cache) is a kernel of its own, `_decode_kernel`, and
-shares no grid with the above: grid (slots, key blocks), a tile is all heads
-of one slot's key block as the cache buffer holds it ([H, D, positions], the
-positions on the lanes), the per-head products run on the VPU in float32,
-and the validity mask is an iota against the slot's length in SMEM. What
-selects it is the entry point; nothing of the cache is folded, copied or
-masked in HBM on the way. The step's new K and V reach the cache through
-`kv_append`, a kernel on the same view of the same buffer with its output
-aliased onto it: grid (slots), of each slot the one block of 128 positions
-that holds its append position goes through VMEM, nothing else is touched.
+shares no grid with the above: grid (slots), the cache left in HBM, and per
+slot a loop over the key blocks the slot has FILLED, each copied into VMEM
+by the kernel itself (double-buffered, the next slot's first block under
+this slot's last). A tile is all heads of one slot's key block as the cache
+buffer holds it ([H, D, positions], the positions on the lanes), the
+per-head products run on the VPU in float32, and the validity mask is an
+iota against the slot's length in SMEM. A block wholly past the slot's
+length is neither copied nor computed on, so a step's HBM traffic follows
+the lengths, not the capacity. What selects the kernel is the entry point;
+nothing of the cache is folded, copied or masked in HBM on the way. The
+step's new K and V reach the cache through `kv_append`, a kernel on the
+same view of the same buffer with its output aliased onto it: grid (slots),
+of each slot the one block of 128 positions that holds its append position
+goes through VMEM, nothing else is touched.
 
 Gives way to a pure-JAX path (see `flash_attention`) when shapes don't tile,
 so callers can use it unconditionally; each such call is counted in
@@ -122,6 +127,19 @@ def _note_fallback(kernel, path, **shape):
     if first:
         get_logger().warning("pallas_fallback", kernel=kernel, path=path,
                              **shape)
+
+
+def _note_decode_block(block_c, **shape):
+    """The key block `_decode_block` chose for these shapes, as the gauge
+    `flash_decode_block{C,H,D,itemsize}` beside `pallas_fallback_total`: set
+    at trace time, so once per compiled program. With the server's
+    `decode_kv_live_pct` it says what share of the cache a step reads."""
+    from ..telemetry.registry import get_registry
+    get_registry().gauge(
+        "flash_decode_block",
+        "Key-block length (cache positions) of the flash_decode kernel: a "
+        "slot's blocks wholly past its length are not read").set(
+            block_c, **shape)
 
 
 def _mask_fold(s, km_ref):
@@ -699,64 +717,121 @@ def _decode_block(C, H, D, itemsize, block_k, interpret):
     return _fit_block(C, target, c_align)
 
 
-def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, qc_ref, acc_ref,
-                   m_ref, l_ref, *, scale, block_c, nk):
-    """One (slot, key block) step of decode attention on the cache as it
-    lies in HBM: k_ref/v_ref are [1, H, D, block_c] tiles — head_dim on the
-    sublanes, cache positions on the lanes. With one query row per head the
-    work is a matrix-vector product, so it runs on the VPU in float32: the
-    scores are a sublane reduction of k * q (q as a [D, 1] column), the
-    output a lane reduction of v * p, both per head, carried across key
-    blocks by the online softmax in (m, l, acc). The validity mask is an
-    iota against the slot's length, a scalar in SMEM."""
+def _live_blocks(length, block_c, nk):
+    """How many of a slot's nk key blocks hold a valid position:
+    ceil(length / block_c). `_decode_kernel` neither copies nor computes on
+    any block after them. A slot of length 0 keeps every block live: its
+    documented result is the uniform average over the whole cache
+    (`_decode_reference`)."""
+    return jnp.where(length > 0, (length + block_c - 1) // block_c, nk)
+
+
+def _decode_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
+                   buf_ref, qc_ref, acc_ref, m_ref, l_ref, *, scale, block_c,
+                   nk, slots):
+    """One slot of decode attention on the cache as it lies in HBM. k_hbm /
+    v_hbm are the whole [S, H, D, C] buffers, left in HBM; the kernel walks
+    the slot's LIVE key blocks only (`_live_blocks` of its length, a
+    scalar in SMEM) and copies each [H, D, block_c] tile — head_dim on the
+    sublanes, cache positions on the lanes — into one of two VMEM buffers
+    itself, so a block past the slot's length costs neither HBM traffic nor
+    arithmetic. (A grid over the key blocks with an index map clamped to the
+    last live block still fetches every block: Mosaic's pipeline re-copies
+    an operand whose index map computes the same block again.)
+
+    The copies are double-buffered across the whole call: each block's copy
+    is started while the block before it is computed on, the first block of
+    the NEXT slot under this slot's last, and always before the current
+    block is waited for, so the DMA queue never runs dry. Which buffer the
+    slot starts in crosses the grid step in `buf_ref` (SMEM), hence the
+    grid's "arbitrary" semantics.
+
+    With one query row per head the work is a matrix-vector product, so it
+    runs on the VPU in float32: the scores are a sublane reduction of k * q
+    (q as a [D, 1] column), the output a lane reduction of v * p, both per
+    head, carried across key blocks by the online softmax in (m, l, acc).
+    The validity mask is an iota against the slot's length."""
     from jax.experimental import pallas as pl
-    si, ki = pl.program_id(0), pl.program_id(1)
-    H, D = k_ref.shape[1], k_ref.shape[2]
+    from jax.experimental.pallas import tpu as pltpu
+    si = pl.program_id(0)
+    H, D = k_buf.shape[1], k_buf.shape[2]
     eye = (jax.lax.broadcasted_iota(jnp.int32, (D, D), 0)
            == jax.lax.broadcasted_iota(jnp.int32, (D, D), 1))
+    length = len_ref[si]
+    live = _live_blocks(length, block_c, nk)
 
-    @pl.when(ki == 0)
-    def _init():
-        def column(h, carry):
-            # the head's query row [1, D] turned onto the sublanes: [D, 1]
-            row = q_ref[0, h].astype(jnp.float32)
-            qc_ref[h] = jnp.sum(jnp.where(eye, row, 0.0), axis=1,
-                                keepdims=True)
-            return carry
-        jax.lax.fori_loop(0, H, column, None, unroll=True)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def copies(slot, block, buf):
+        at = pl.ds(pl.multiple_of(block * block_c, block_c), block_c)
+        return [pltpu.make_async_copy(hbm.at[slot, :, :, at], vmem.at[buf],
+                                      sem.at[i, buf])
+                for i, (hbm, vmem) in enumerate(((k_hbm, k_buf),
+                                                 (v_hbm, v_buf)))]
 
-    kpos = ki * block_c + jax.lax.broadcasted_iota(jnp.int32, (1, block_c), 1)
-    valid = kpos < len_ref[si]                        # [1, block_c]
+    @pl.when(si == 0)
+    def _first():
+        buf_ref[0] = 0
+        for copy in copies(0, 0, 0):
+            copy.start()
 
-    def head(h, carry):
-        k = k_ref[0, h].astype(jnp.float32)           # [D, block_c]
-        s = jnp.sum(k * qc_ref[h], axis=0, keepdims=True) * scale
-        s = jnp.where(valid, s, NEG_INF)              # [1, block_c]
-        m_prev = m_ref[h]                             # [1, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        v = v_ref[0, h].astype(jnp.float32)           # [D, block_c]
-        acc_ref[h] = acc_ref[h] * corr + jnp.sum(v * p, axis=1, keepdims=True)
-        l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[h] = m_new
+    def column(h, carry):
+        # the head's query row [1, D] turned onto the sublanes: [D, 1]
+        row = q_ref[0, h].astype(jnp.float32)
+        qc_ref[h] = jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
         return carry
-    # unrolled: the scheduler overlaps one head's reductions with the next
-    # head's loads (0.74 -> 0.58 ms a call at 48 x 1024 x 16 x 64 float32)
-    jax.lax.fori_loop(0, H, head, None, unroll=True)
+    jax.lax.fori_loop(0, H, column, None, unroll=True)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(ki == nk - 1)
-    def _finish():
-        def row(h, carry):
-            # l >= 1 always: a fully masked slot sums exp(0) per position
-            col = acc_ref[h] / l_ref[h]               # [D, 1]
-            o_ref[0, h] = jnp.sum(jnp.where(eye, col, 0.0), axis=0,
-                                  keepdims=True).astype(o_ref.dtype)
+    def block(j, buf):
+        # what follows block j: the slot's next live block or the next
+        # slot's first; after the last block of the last slot a copy nobody
+        # reads (`_drain` waits for it), which keeps this one basic block
+        more = j + 1 < live
+        for copy in copies(jnp.where(more, si, jnp.minimum(si + 1, slots - 1)),
+                           jnp.where(more, j + 1, 0), 1 - buf):
+            copy.start()
+        for copy in copies(si, j, buf):
+            copy.wait()
+        kpos = j * block_c + jax.lax.broadcasted_iota(jnp.int32,
+                                                      (1, block_c), 1)
+        valid = kpos < length                         # [1, block_c]
+
+        def head(h, carry):
+            k = k_buf[buf, h].astype(jnp.float32)     # [D, block_c]
+            s = jnp.sum(k * qc_ref[h], axis=0, keepdims=True) * scale
+            s = jnp.where(valid, s, NEG_INF)          # [1, block_c]
+            m_prev = m_ref[h]                         # [1, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            v = v_buf[buf, h].astype(jnp.float32)     # [D, block_c]
+            acc_ref[h] = acc_ref[h] * corr + jnp.sum(v * p, axis=1,
+                                                     keepdims=True)
+            l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=1, keepdims=True)
+            m_ref[h] = m_new
             return carry
-        jax.lax.fori_loop(0, H, row, None, unroll=True)
+        # unrolled: the scheduler overlaps one head's reductions with the
+        # next head's loads (0.74 -> 0.58 ms a call at 48 x 1024 x 16 x 64
+        # float32)
+        jax.lax.fori_loop(0, H, head, None, unroll=True)
+        return 1 - buf
+
+    buf = jax.lax.fori_loop(0, live, block, buf_ref[0])
+    buf_ref[0] = buf
+
+    @pl.when(si == slots - 1)
+    def _drain():
+        for copy in copies(0, 0, buf):
+            copy.wait()
+
+    def row(h, carry):
+        # l >= 1 always: a fully masked slot sums exp(0) per position
+        col = acc_ref[h] / l_ref[h]                   # [D, 1]
+        o_ref[0, h] = jnp.sum(jnp.where(eye, col, 0.0), axis=0,
+                              keepdims=True).astype(o_ref.dtype)
+        return carry
+    jax.lax.fori_loop(0, H, row, None, unroll=True)
 
 
 @functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
@@ -771,7 +846,6 @@ def _decode_call(q, k, v, lengths, scale, block_c, interpret, name):
     from jax.experimental.pallas import tpu as pltpu
     S, _, H, D = q.shape
     C = k.shape[1]
-    nk = C // block_c
     # The kernel's operand is [S, H, D, C]. For head_dim < 128 that IS the
     # cache buffer: the TPU lays a [S, C, H, D] array out with the positions
     # minor-most (minor-to-major {1,3,2,0}: a 64-wide minor axis would pad
@@ -780,17 +854,21 @@ def _decode_call(q, k, v, lengths, scale, block_c, interpret, name):
     # buffer is row-major and the transpose is a copy, as the fold of heads
     # was before.
     kt, vt = (jnp.transpose(x, (0, 2, 3, 1)) for x in (k, v))
-    tile = pl.BlockSpec((1, H, D, block_c), lambda s, ki, lens: (s, 0, 0, ki))
-    row = pl.BlockSpec((1, H, 1, D), lambda s, ki, lens: (s, 0, 0, 0))
+    row = pl.BlockSpec((1, H, 1, D), lambda s, lens: (s, 0, 0, 0))
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale, block_c=block_c,
-                          nk=nk),
+                          nk=C // block_c, slots=S),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(S, nk),
-            in_specs=[row, tile, tile],
+            grid=(S,),
+            in_specs=[row, in_hbm, in_hbm],
             out_specs=row,
             scratch_shapes=[
+                pltpu.VMEM((2, H, D, block_c), k.dtype),  # K tiles
+                pltpu.VMEM((2, H, D, block_c), v.dtype),  # V tiles
+                pltpu.SemaphoreType.DMA((2, 2)),         # (K | V, buffer)
+                pltpu.SMEM((1,), jnp.int32),             # next buffer
                 pltpu.VMEM((H, D, 1), jnp.float32),      # q columns
                 pltpu.VMEM((H, D, 1), jnp.float32),      # acc
                 pltpu.VMEM((H, 1, 1), jnp.float32),      # running max
@@ -798,7 +876,7 @@ def _decode_call(q, k, v, lengths, scale, block_c, interpret, name):
             ]),
         out_shape=jax.ShapeDtypeStruct((S, H, 1, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name=name,
     )(lengths, q.reshape(S, H, 1, D), kt, vt)
@@ -818,14 +896,18 @@ def flash_decode(q, k, v, lengths, *, scale=None, use_pallas=True,
     token). Returns [slots, 1, heads, head_dim].
 
     A kernel of its own (`_decode_kernel`), not the training forward: the
-    grid walks (slot, key block) and each tile is all heads of one slot's
-    key block, read from the cache buffer in the layout it is stored in —
-    no fold of heads, no copy of the cache (`_decode_call`). The per-slot
+    grid walks the slots, the kernel each slot's key blocks, and a tile is
+    all heads of one key block, copied from the cache buffer in the layout
+    it is stored in — no fold of heads, no copy of the cache in HBM
+    (`_decode_call`). The per-slot
     validity mask is an iota compared with the slot's length, which rides
     to the kernel as a scalar (SMEM), so every decode step runs ONE
     executable regardless of how many tokens each co-batched request has
-    generated (the zero-recompile contract of the decode engine). Every
-    key block is read and computed, those past a slot's length too.
+    generated (the zero-recompile contract of the decode engine). A key
+    block wholly past a slot's length is neither read from HBM nor computed
+    on (`_live_blocks`); for every length >= 1 the result is bit for
+    bit what reading every block gives, and a slot of length 0 reads them
+    all (the uniform average, which callers never read).
     Multiplies and accumulates in float32 whatever the cache's dtype. The
     key block follows from heads x head_dim, the dtype and the VMEM budget
     (`_decode_block`); `block_k` caps it. Falls back to the masked
@@ -846,6 +928,7 @@ def flash_decode(q, k, v, lengths, *, scale=None, use_pallas=True,
         _note_fallback("flash_decode", "reference", C=C, D=D,
                        interpret=interpret)
         return _decode_reference(q, k, v, lengths, scale)
+    _note_decode_block(block_c, C=C, H=H, D=D, itemsize=k.dtype.itemsize)
     return _per_shard(
         lambda q, k, v, lengths: _decode_call(q, k, v, lengths, scale,
                                               block_c, interpret, _name),

@@ -24,7 +24,8 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.decode import (DecodeEngine, DecodeScheduler,
                                        DecodeUnsupported)
 from deeplearning4j_tpu.kernels import flash_decode
-from deeplearning4j_tpu.kernels.flash_attention import _decode_reference
+from deeplearning4j_tpu.kernels.flash_attention import (_decode_block,
+                                                        _decode_reference)
 from deeplearning4j_tpu.serving.admission import (DeadlineExceeded,
                                                   RejectedError)
 from deeplearning4j_tpu.serving.registry import ModelRegistry
@@ -263,6 +264,35 @@ def test_scheduler_shed_expiry_and_stop_token():
             sched.submit(list(range(10)), max_new_tokens=1000)
     finally:
         sched.stop()
+
+
+def test_kv_live_gauge_and_key_block_record():
+    """`decode_kv_live_pct`: the positions the active requests have filled
+    (prompt + tokens emitted, what the next step's attention reads) over
+    slots x capacity, from the host's own bookkeeping; 0 again once they
+    finish. `flash_decode_block{C,H,D,itemsize}`: the key block the kernel
+    chose for the engine's shapes. Driven synchronously (no loop thread)."""
+    from deeplearning4j_tpu.telemetry.registry import get_registry
+    net = _tlm(seed=12, use_pallas=True)
+    slots, cap = 3, 64
+    sched, _, mreg = _scheduler(net, slots=slots, max_len=cap)
+    live = mreg.get("decode_kv_live_pct")
+    assert live.get() == 0.0
+    f1 = sched.submit([3, 1, 4, 1, 5], max_new_tokens=6)
+    f2 = sched.submit([2, 7], max_new_tokens=3)
+    sched._admit()              # each prefill emits its request's first token
+    assert live.get() == pytest.approx(100.0 * (6 + 3) / (slots * cap))
+    sched._step_wave()
+    assert live.get() == pytest.approx(100.0 * (7 + 4) / (slots * cap))
+    sched._step_wave()          # the second request is done: its slot is idle
+    assert f2.done() and live.get() == pytest.approx(100.0 * 8 / (slots * cap))
+    while not f1.done():
+        sched._step_wave()
+    assert live.get() == 0.0 and sched.active_count() == 0
+    # 2 heads of 16, float32, as `_tlm` builds them
+    want = _decode_block(cap, 2, 16, 4, 1024, interpret=True)
+    assert get_registry().get("flash_decode_block").get(
+        C=cap, H=2, D=16, itemsize=4) == want
 
 
 def test_hot_swap_drains_then_swaps_and_warm_engine_stays_warm():

@@ -3,7 +3,10 @@ CPU) against the masked reference row `_decode_reference`: dtypes, heads x
 head_dim, a capacity of one and of several key blocks, and the lengths a
 decode batch meets — a slot at its first token, a full slot, a ragged batch,
 and an empty slot (no valid key: the uniform average over the cache, which
-callers never read). The compiled kernel is in tests/test_tpu_compile.py."""
+callers never read). A key block wholly past a slot's length is stepped
+over — not copied from HBM, not computed on — and the result is, bit for bit,
+that of the kernel with every block live. The compiled kernel is in
+tests/test_tpu_compile.py."""
 import importlib
 
 import numpy as np
@@ -47,6 +50,71 @@ def test_decode_kernel_matches_reference(dtype, heads, dim, blocks, lengths):
                                rtol=tol, atol=tol)
     np.testing.assert_array_equal(np.asarray(jax.jit(decode)(q, k, v, lens)),
                                   np.asarray(got))
+
+
+def _qkv(rng, dtype, C, heads=4, dim=16, slots=SLOTS):
+    return [jnp.asarray(rng.normal(size=shape), dtype) for shape in
+            [(slots, 1, heads, dim)] + [(slots, C, heads, dim)] * 2]
+
+
+@pytest.mark.parametrize("blocks", [3, 8], ids=lambda n: f"{n}blk")
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=lambda d: jnp.dtype(d).name)
+def test_dead_key_blocks_are_not_computed_on(dtype, blocks):
+    """Every key block wholly past a slot's length is NaN in K and in V. A
+    kernel that computed on it (as the one before did: v * 0 of a NaN) would
+    return NaN; this one returns what it returns on the clean cache, which
+    is the reference row's result."""
+    C = BLOCK * blocks
+    q, k, v = _qkv(np.random.default_rng(blocks), dtype, C)
+    lengths = [1, BLOCK, BLOCK + 1, C - BLOCK]
+    dead = np.zeros((SLOTS, C, 1, 1), bool)
+    for s, n in enumerate(lengths):
+        dead[s, -(-n // BLOCK) * BLOCK:] = True
+    assert dead.any(axis=1).all()
+    poisoned = [jnp.where(dead, jnp.nan, x) for x in (k, v)]
+    lens = jnp.asarray(lengths, jnp.int32)
+    decode = lambda k, v: fa.flash_decode(q, k, v, lens, block_k=BLOCK)
+    got = np.asarray(decode(*poisoned), np.float32)
+    np.testing.assert_array_equal(got, np.asarray(decode(k, v), np.float32))
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(
+        got, np.asarray(fa._decode_reference(q, k, v, lens, 16 ** -0.5),
+                        np.float32), rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("blocks", [1, 3, 8], ids=lambda n: f"{n}blk")
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=lambda d: jnp.dtype(d).name)
+def test_skipping_dead_blocks_changes_no_bit(monkeypatch, dtype, blocks):
+    """For every length >= 1 — the first token, a block's edge, one past
+    it, a full slot — the kernel's output is bit for bit that of the same
+    kernel with every block live (what it was before it stepped over any)."""
+    C = BLOCK * blocks
+    q, k, v = _qkv(np.random.default_rng(10 + blocks), dtype, C, slots=6)
+    lens = jnp.asarray([1, BLOCK, min(BLOCK + 1, C), C, max(1, C - 1),
+                        max(1, C // 2)], jnp.int32)
+    call = lambda name: np.asarray(fa._decode_call(
+        q, k, v, lens, 0.25, BLOCK, True, name), np.float32)
+    got = call("flash_decode")
+    # `_decode_call` is jitted: another kernel name is another trace, which
+    # sees the patched rule (and leaves the real one's trace alone)
+    monkeypatch.setattr(fa, "_live_blocks", lambda length, block_c, nk: nk)
+    np.testing.assert_array_equal(got, call("flash_decode_all_live"))
+
+
+def test_key_blocks_a_slot_reads():
+    """The kernel's loop bound as a pure function of the length: the blocks
+    up to the one that holds the last valid position and no other, all of
+    them for a full slot, and all of them for length 0 (the uniform
+    average)."""
+    B, nk = 128, 8
+    live = lambda n: int(fa._live_blocks(jnp.int32(n), B, nk))
+    assert [live(n) for n in (1, 127, 128, 129, 3 * B, 3 * B + 1)] == \
+        [1, 1, 1, 2, 3, 4]
+    assert [live(n) for n in (nk * B - B + 1, nk * B, 0)] == [nk] * 3
+    for n in range(1, nk * B + 1, 37):
+        assert (live(n) - 1) * B < n <= live(n) * B
 
 
 def test_key_block_follows_heads_dim_and_dtype():
