@@ -9,6 +9,7 @@ TPU.
 """
 from .flash_attention import (flash_attention, flash_decode,
                               flash_decode_paged, kv_append)
+from .ssm_step import ssm_step
 
 __all__ = ["flash_attention", "flash_decode", "flash_decode_paged",
-           "kv_append"]
+           "kv_append", "ssm_step"]

@@ -688,6 +688,9 @@ def _decode_reference(q, k, v, lengths, scale):
     same contract as the main kernel's fully-masked-row behavior; callers
     never read those slots."""
     S, C = k.shape[0], k.shape[1]
+    G = q.shape[2] // k.shape[2]
+    if G > 1:           # grouped heads: K/V head j serves query heads j*G..
+        k, v = jnp.repeat(k, G, axis=2), jnp.repeat(v, G, axis=2)
     valid = jax.lax.broadcasted_iota(jnp.int32, (S, C), 1) \
         < jnp.asarray(lengths, jnp.int32)[:, None]
     s = jnp.einsum("sqhd,schd->shqc", q.astype(jnp.float32),
@@ -706,9 +709,13 @@ _DECODE_TILE_BYTES = 2 << 20
 def _decode_block(C, H, D, itemsize, block_k, interpret):
     """Key-block length of the decode kernel — the largest divisor of the
     capacity whose [H, D, block] tile fits `_DECODE_TILE_BYTES` (and
-    `block_k`) — or None => fall back. Compiled, positions lie on the lanes
-    (a multiple of 128) and head_dim on the sublanes (a multiple of 8 for
-    float32, of 16 for a packed bfloat16); interpret mode takes any divisor."""
+    `block_k`) — or None => fall back. H counts the QUERY heads: with
+    grouped heads the tile holds fewer K/V heads, but a block's cost is its
+    arithmetic (PERF.md section 6, PR 30), which follows the query heads,
+    and a shorter block lets more of a slot's capacity go unread. Compiled,
+    positions lie on the lanes (a multiple of 128) and head_dim on the
+    sublanes (a multiple of 8 for float32, of 16 for a packed bfloat16);
+    interpret mode takes any divisor."""
     c_align, d_align = (1, 1) if interpret else (128, 32 // itemsize)
     if D % d_align:
         return None
@@ -750,11 +757,17 @@ def _decode_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
     runs on the VPU in float32: the scores are a sublane reduction of k * q
     (q as a [D, 1] column), the output a lane reduction of v * p, both per
     head, carried across key blocks by the online softmax in (m, l, acc).
-    The validity mask is an iota against the slot's length."""
+    The validity mask is an iota against the slot's length.
+
+    Grouped heads: q_ref holds Hq = G * H query heads against the tile's H
+    K/V heads; K/V head h is loaded once and serves query heads h*G .. h*G +
+    G - 1, each with its own (m, l, acc)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     si = pl.program_id(0)
     H, D = k_buf.shape[1], k_buf.shape[2]
+    Hq = q_ref.shape[1]
+    G = Hq // H
     eye = (jax.lax.broadcasted_iota(jnp.int32, (D, D), 0)
            == jax.lax.broadcasted_iota(jnp.int32, (D, D), 1))
     length = len_ref[si]
@@ -778,7 +791,7 @@ def _decode_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
         row = q_ref[0, h].astype(jnp.float32)
         qc_ref[h] = jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
         return carry
-    jax.lax.fori_loop(0, H, column, None, unroll=True)
+    jax.lax.fori_loop(0, Hq, column, None, unroll=True)
     acc_ref[...] = jnp.zeros_like(acc_ref)
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
@@ -799,17 +812,23 @@ def _decode_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
 
         def head(h, carry):
             k = k_buf[buf, h].astype(jnp.float32)     # [D, block_c]
-            s = jnp.sum(k * qc_ref[h], axis=0, keepdims=True) * scale
-            s = jnp.where(valid, s, NEG_INF)          # [1, block_c]
-            m_prev = m_ref[h]                         # [1, 1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            corr = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            v = v_buf[buf, h].astype(jnp.float32)     # [D, block_c]
-            acc_ref[h] = acc_ref[h] * corr + jnp.sum(v * p, axis=1,
-                                                     keepdims=True)
-            l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=1, keepdims=True)
-            m_ref[h] = m_new
+            v = None        # loaded after the first p, where it always was:
+            for g in range(G):  # equal heads trace to the program they did
+                qh = h if G == 1 else h * G + g       # the query head
+                s = jnp.sum(k * qc_ref[qh], axis=0, keepdims=True) * scale
+                s = jnp.where(valid, s, NEG_INF)      # [1, block_c]
+                m_prev = m_ref[qh]                    # [1, 1]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=1, keepdims=True))
+                corr = jnp.exp(m_prev - m_new)
+                p = jnp.exp(s - m_new)
+                if v is None:
+                    v = v_buf[buf, h].astype(jnp.float32)  # [D, block_c]
+                acc_ref[qh] = acc_ref[qh] * corr + jnp.sum(v * p, axis=1,
+                                                           keepdims=True)
+                l_ref[qh] = l_ref[qh] * corr + jnp.sum(p, axis=1,
+                                                       keepdims=True)
+                m_ref[qh] = m_new
             return carry
         # unrolled: the scheduler overlaps one head's reductions with the
         # next head's loads (0.74 -> 0.58 ms a call at 48 x 1024 x 16 x 64
@@ -831,12 +850,13 @@ def _decode_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
         o_ref[0, h] = jnp.sum(jnp.where(eye, col, 0.0), axis=0,
                               keepdims=True).astype(o_ref.dtype)
         return carry
-    jax.lax.fori_loop(0, H, row, None, unroll=True)
+    jax.lax.fori_loop(0, Hq, row, None, unroll=True)
 
 
 @functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
 def _decode_call(q, k, v, lengths, scale, block_c, interpret, name):
-    """q [S, 1, H, D], k/v [S, C, H, D], lengths [S] -> [S, 1, H, D].
+    """q [S, 1, Hq, D], k/v [S, C, H, D], lengths [S] -> [S, 1, Hq, D]
+    (Hq a multiple of H: grouped heads).
 
     Jitted, so the layers of one step program share ONE trace and one
     lowering of the kernel. With the head loop unrolled, tracing it layer by
@@ -844,8 +864,8 @@ def _decode_call(q, k, v, lengths, scale, block_c, interpret, name):
     heads), a warm compile cache or not."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    S, _, H, D = q.shape
-    C = k.shape[1]
+    S, _, Hq, D = q.shape
+    C, H = k.shape[1], k.shape[2]
     # The kernel's operand is [S, H, D, C]. For head_dim < 128 that IS the
     # cache buffer: the TPU lays a [S, C, H, D] array out with the positions
     # minor-most (minor-to-major {1,3,2,0}: a 64-wide minor axis would pad
@@ -854,7 +874,7 @@ def _decode_call(q, k, v, lengths, scale, block_c, interpret, name):
     # buffer is row-major and the transpose is a copy, as the fold of heads
     # was before.
     kt, vt = (jnp.transpose(x, (0, 2, 3, 1)) for x in (k, v))
-    row = pl.BlockSpec((1, H, 1, D), lambda s, lens: (s, 0, 0, 0))
+    row = pl.BlockSpec((1, Hq, 1, D), lambda s, lens: (s, 0, 0, 0))
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale, block_c=block_c,
@@ -869,18 +889,18 @@ def _decode_call(q, k, v, lengths, scale, block_c, interpret, name):
                 pltpu.VMEM((2, H, D, block_c), v.dtype),  # V tiles
                 pltpu.SemaphoreType.DMA((2, 2)),         # (K | V, buffer)
                 pltpu.SMEM((1,), jnp.int32),             # next buffer
-                pltpu.VMEM((H, D, 1), jnp.float32),      # q columns
-                pltpu.VMEM((H, D, 1), jnp.float32),      # acc
-                pltpu.VMEM((H, 1, 1), jnp.float32),      # running max
-                pltpu.VMEM((H, 1, 1), jnp.float32),      # running sum
+                pltpu.VMEM((Hq, D, 1), jnp.float32),     # q columns
+                pltpu.VMEM((Hq, D, 1), jnp.float32),     # acc
+                pltpu.VMEM((Hq, 1, 1), jnp.float32),     # running max
+                pltpu.VMEM((Hq, 1, 1), jnp.float32),     # running sum
             ]),
-        out_shape=jax.ShapeDtypeStruct((S, H, 1, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, Hq, 1, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name=name,
-    )(lengths, q.reshape(S, H, 1, D), kt, vt)
-    return out.reshape(S, 1, H, D)
+    )(lengths, q.reshape(S, Hq, 1, D), kt, vt)
+    return out.reshape(S, 1, Hq, D)
 
 
 def flash_decode(q, k, v, lengths, *, scale=None, use_pallas=True,
@@ -891,7 +911,9 @@ def flash_decode(q, k, v, lengths, *, scale=None, use_pallas=True,
     q: [slots, 1, heads, head_dim] — the current token's query (the step
     has put its k/v into the cache at position lengths-1 with `kv_append`
     before this call; this kernel only reads the cache);
-    k, v: [slots, capacity, heads, head_dim] — the cache;
+    k, v: [slots, capacity, kv_heads, head_dim] — the cache; `heads` is a
+    multiple of `kv_heads` (grouped-query attention: K/V head j is read
+    once and serves query heads j*G .. j*G + G - 1);
     lengths: [slots] int32 — valid entries per slot (including the current
     token). Returns [slots, 1, heads, head_dim].
 
@@ -913,9 +935,10 @@ def flash_decode(q, k, v, lengths, *, scale=None, use_pallas=True,
     (`_decode_block`); `block_k` caps it. Falls back to the masked
     reference row when shapes don't tile or `use_pallas=False` (the two
     paths agree to f32 rounding)."""
-    S, Tq, H, D = q.shape
+    S, Tq, Hq, D = q.shape
     assert Tq == 1, f"flash_decode takes one query per slot, got Tq={Tq}"
-    C = k.shape[1]
+    C, H = k.shape[1], k.shape[2]
+    assert Hq % H == 0, f"{Hq} query heads over {H} K/V heads"
     if scale is None:
         scale = float(1.0 / (D ** 0.5))
     if interpret is None:
@@ -923,7 +946,7 @@ def flash_decode(q, k, v, lengths, *, scale=None, use_pallas=True,
     lengths = jnp.asarray(lengths, jnp.int32)
     if not use_pallas:
         return _decode_reference(q, k, v, lengths, scale)
-    block_c = _decode_block(C, H, D, k.dtype.itemsize, block_k, interpret)
+    block_c = _decode_block(C, Hq, D, k.dtype.itemsize, block_k, interpret)
     if block_c is None:
         _note_fallback("flash_decode", "reference", C=C, D=D,
                        interpret=interpret)

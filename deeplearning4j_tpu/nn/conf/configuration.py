@@ -45,9 +45,9 @@ def expected_input_kind(conf):
         return "recurrent"
     if isinstance(conf, (L.ActivationLayer, L.DropoutLayer, L.LossLayer,
                          L.GlobalPoolingLayer, L.BatchNormalization,
-                         L.LayerNormalization)):
+                         L.LayerNormalization, L.RMSNormalization)):
         return "any"
-    if type(conf) is L.DenseLayer:
+    if type(conf) in (L.DenseLayer, L.GatedDenseLayer):
         # Dense is time-distributed on [b, t, f] (no RnnToFeedForward needed)
         # and self-flattens rank-4 CNN input; only cnn_flat still reshapes
         return "any"

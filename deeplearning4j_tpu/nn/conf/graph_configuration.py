@@ -178,7 +178,9 @@ class UnstackVertex(BaseVertexConf):
 
 @register_vertex
 class ScaleVertex(BaseVertexConf):
-    """Multiply by a fixed scalar (reference: nn/conf/graph/ScaleVertex.java)."""
+    """Multiply by a fixed scalar (reference: nn/conf/graph/ScaleVertex.java):
+    an embedding or residual multiplier in a decoder block."""
+    positionwise = True
 
     def __init__(self, scale_factor=1.0):
         self.scale_factor = float(scale_factor)

@@ -145,6 +145,41 @@ class RnnOutputLayer(FeedForwardLayerConf):
 
 @register_layer_conf
 @dataclass
+class LMHeadLayer(RnnOutputLayer):
+    """Per-timestep output layer of a language model whose head is the
+    embedding: probabilities = softmax(x W^T / logits_scaling), no bias. W is
+    stored [n_out, n_in] — the layout of the [vocab, d_model] embedding
+    matrix, so a tied model places ONE buffer under both leaves (the graph
+    keeps a leaf per layer; training them tied is not wired). The logits and
+    the softmax are float32 whatever the parameters' dtype: among 100k
+    near-flat probabilities a bfloat16 tie would decide the greedy pick."""
+    logits_scaling: float = 1.0
+
+
+@register_layer_conf
+@dataclass
+class GatedDenseLayer(FeedForwardLayerConf):
+    """Gated feed-forward block (GLU family; SwiGLU with the default
+    activation): (g, u) = split(x W_in), out = (activation(g) * u) W_out,
+    no biases. n_hidden is the width of g and of u, so W_in is [n_in,
+    2 * n_hidden] and W_out [n_hidden, n_out]. Time-distributed on
+    [b, t, f] like DenseLayer."""
+    n_hidden: int | None = None
+
+    def apply_global_defaults(self, g):
+        explicit = self.activation
+        super().apply_global_defaults(g)
+        if explicit is None:
+            self.activation = "swish"
+
+    def get_output_type(self, input_type):
+        if isinstance(input_type, RecurrentInputType):
+            return InputType.recurrent(self.n_out)
+        return InputType.feed_forward(self.n_out)
+
+
+@register_layer_conf
+@dataclass
 class LossLayer(BaseLayerConf):
     """Parameterless loss layer (reference: nn/conf/layers/LossLayer.java)."""
     loss: str = "MSE"
@@ -263,6 +298,23 @@ class LayerNormalization(BaseLayerConf):
 
 @register_layer_conf
 @dataclass
+class RMSNormalization(_NoActivationConf):
+    """Root-mean-square norm over the feature (last) axis, x * rsqrt(mean(x^2)
+    + eps) * gamma: no mean, no bias (Zhang & Sennrich 2019) — the norm of
+    the pre-norm decoder blocks (zoo.granite_hybrid_lm). The statistics are
+    taken in float32 whatever the activations' dtype."""
+    n_in: int | None = None
+    n_out: int | None = None
+    eps: float = 1e-5
+
+    set_n_in = _norm_set_n_in
+
+    def get_output_type(self, input_type):
+        return input_type
+
+
+@register_layer_conf
+@dataclass
 class BatchNormalization(BaseLayerConf):
     """Batch norm over feature/channel axis (reference:
     nn/conf/layers/BatchNormalization.java, runtime
@@ -323,6 +375,29 @@ class SelfAttentionLayer(BaseRecurrentConf):
     # dropout on the attention OUTPUT (post-softmax·V, pre-Wo) — the layer's
     # inherited `dropout` drops the INPUT like every reference layer
     attention_dropout: float = 0.0
+    # grouped-query attention: K/V heads (None: as many as query heads);
+    # each serves n_heads // n_kv_heads query heads, and the decode cache
+    # holds only these
+    n_kv_heads: int | None = None
+    # what the scores are multiplied by (None: 1 / sqrt(head_dim))
+    score_scale: float | None = None
+
+
+@register_layer_conf
+@dataclass
+class Mamba2Layer(_NoActivationConf, BaseRecurrentConf):
+    """Mamba-2 mixer (Dao & Gu 2024, arXiv:2405.21060): a selective
+    state-space layer with a scalar decay a head, [b,t,f] -> [b,t,n_out].
+    d_inner = n_heads * head_dim; one group (B and C are shared by all
+    heads). Runtime: nn/layers/mamba.py — chunked SSD for sequences, a
+    per-token recurrence on a fixed-size state for decode."""
+    n_heads: int = 8
+    head_dim: int = 64
+    d_state: int = 128
+    d_conv: int = 4
+    chunk_size: int = 256
+    eps: float = 1e-5
+    use_pallas: bool = False
 
 
 @register_layer_conf

@@ -19,7 +19,8 @@ from ..conf.graph_configuration import (ComputationGraphConfiguration,
                                         DuplicateToTimeSeriesVertex)
 from ..conf.configuration import BackpropType
 from ..layers.base import create_layer
-from ..layers import feedforward, convolution, recurrent, misc, variational  # noqa: F401
+from ..layers import (feedforward, convolution, recurrent, mamba, misc,  # noqa: F401
+                      variational)
 from ..multistep import MultiStepTrainable
 from ...telemetry.xla import timed_first_call
 from ..updaters import apply_gradient_normalization

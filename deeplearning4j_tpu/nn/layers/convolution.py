@@ -151,6 +151,29 @@ class LayerNormalizationModule(BaseLayerModule):
         return self.activation_fn()(y), state, mask
 
 
+@register_impl("RMSNormalization")
+class RMSNormalizationModule(BaseLayerModule):
+    """x * rsqrt(mean(x^2) + eps) * gamma over the last axis; the mean of
+    squares in float32, the result in the activations' dtype."""
+    positionwise = True
+
+    def init(self, rng, input_type, dtype=jnp.float32):
+        return {"gamma": jnp.ones((int(self.conf.n_in),), dtype)}, {}, \
+            input_type
+
+    def forward(self, params, state, x, *, train=False, rng=None, mask=None):
+        y = rms_norm(x, params["gamma"], self.conf.eps)
+        return self.activation_fn()(y), state, mask
+
+
+def rms_norm(x, gamma, eps):
+    """The RMS norm itself, shared with the Mamba-2 mixer's gated norm."""
+    acc = jnp.promote_types(x.dtype, jnp.float32)
+    xf = x.astype(acc)
+    y = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
+    return (y * gamma.astype(acc)).astype(x.dtype)
+
+
 @register_impl("BatchNormalization")
 class BatchNormalizationModule(BaseLayerModule):
     """Batch normalization over the channel (last) axis for NHWC or the feature

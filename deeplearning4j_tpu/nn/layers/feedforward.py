@@ -125,6 +125,53 @@ class RnnOutputLayerModule(BaseOutputLayerModule):
         return self.loss_fn()(lab2, z2, self.conf.activation, m2)
 
 
+@register_impl("LMHeadLayer")
+class LMHeadLayerModule(RnnOutputLayerModule):
+    """softmax(x W^T / logits_scaling) with W [n_out, n_in] (the embedding's
+    layout) and no bias; logits and softmax in float32."""
+
+    def init(self, rng, input_type, dtype=jnp.float32):
+        c = self.conf
+        n_in, n_out = int(c.n_in), int(c.n_out)
+        params = {"W": init_weights(rng, (n_out, n_in), c.weight_init,
+                                    fan_in=n_in, fan_out=n_out,
+                                    distribution=c.dist, dtype=dtype)}
+        return params, {}, InputType.recurrent(n_out)
+
+    def preoutput(self, params, x):
+        acc = jnp.promote_types(x.dtype, jnp.float32)
+        z = jnp.einsum("btf,vf->btv", x, params["W"],
+                       preferred_element_type=acc)
+        return z / self.conf.logits_scaling
+
+
+@register_impl("GatedDenseLayer")
+class GatedDenseLayerModule(BaseLayerModule):
+    """(activation(g) * u) W_out with (g, u) = split(x W_in): one gemm in,
+    one out, no biases."""
+    positionwise = True
+
+    def init(self, rng, input_type, dtype=jnp.float32):
+        c = self.conf
+        n_in, n_hidden, n_out = int(c.n_in), int(c.n_hidden), int(c.n_out)
+        k1, k2 = jax.random.split(rng)
+        mk = lambda k, i, o: init_weights(k, (i, o), c.weight_init, fan_in=i,
+                                          fan_out=o, distribution=c.dist,
+                                          dtype=dtype)
+        params = {"W_in": mk(k1, n_in, 2 * n_hidden),
+                  "W_out": mk(k2, n_hidden, n_out)}
+        from ..conf.inputs import RecurrentInputType
+        out_t = (InputType.recurrent(n_out)
+                 if isinstance(input_type, RecurrentInputType)
+                 else InputType.feed_forward(n_out))
+        return params, {}, out_t
+
+    def forward(self, params, state, x, *, train=False, rng=None, mask=None):
+        x = apply_dropout(x, self.conf.dropout, train, rng)
+        g, u = jnp.split(x @ params["W_in"], 2, axis=-1)
+        return (self.activation_fn()(g) * u) @ params["W_out"], state, mask
+
+
 @register_impl("LossLayer")
 class LossLayerModule(BaseLayerModule):
     """Parameterless loss on incoming activations (reference: LossLayer.java)."""

@@ -21,7 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .base import BaseLayerModule, CacheLeaf, register_impl, apply_dropout
+from .base import (BaseLayerModule, CacheLeaf, apply_dropout,
+                   note_cache_entry, register_impl)
 from ..activations import get_activation
 from ..weights import init_weights
 from ..conf.inputs import InputType
@@ -154,7 +155,7 @@ class _BaseLSTMModule(BaseLayerModule):
     def decode_entry(self, geom):
         leaf = CacheLeaf((geom.slots, int(self.conf.n_out)),
                          _acc_dtype(geom.dtype), 1)
-        return {"h": leaf, "c": leaf}
+        return note_cache_entry(geom, "state", {"h": leaf, "c": leaf})
 
     def decode_prefill(self, params, state, x, entry, ctx):
         # masked steps carry state through (the scan's contract), so the
@@ -235,6 +236,15 @@ def _paged_append_seq(pool, t, row):
     return pool.at[row[:chunks]].set(tc.astype(pool.dtype))
 
 
+def _repeat_kv(q, k, v):
+    """k, v [b, t, Hkv, Dh] repeated to q's heads (K/V head j serves query
+    heads j*G .. j*G + G - 1); as they are when the counts agree."""
+    G = q.shape[2] // k.shape[2]
+    if G == 1:
+        return k, v
+    return jnp.repeat(k, G, axis=2), jnp.repeat(v, G, axis=2)
+
+
 def _verify_attend(q, k, v, start):
     """[1, W, H, Dh] window queries vs one slot's full [1, C, H, Dh] cache
     row, causal against GLOBAL positions: query i (at position start+i)
@@ -244,6 +254,7 @@ def _verify_attend(q, k, v, start):
     is reference-einsum territory; a Mosaic flash variant with a query
     offset is the rig follow-up."""
     W, C = q.shape[1], k.shape[1]
+    k, v = _repeat_kv(q, k, v)
     scale = 1.0 / float(np.sqrt(q.shape[-1]))
     qpos = start + jnp.arange(W, dtype=jnp.int32)
     kpos = jnp.arange(C, dtype=jnp.int32)
@@ -284,36 +295,53 @@ class SelfAttentionLayerModule(BaseLayerModule):
         c = self.conf
         n_in, n_out, H = int(c.n_in), int(c.n_out), int(c.n_heads)
         assert n_out % H == 0, "n_heads must evenly divide n_out"
+        assert H % self.kv_heads == 0, "n_kv_heads must evenly divide n_heads"
+        n_kv = n_out // H * self.kv_heads
         k1, k2, k3, k4 = jax.random.split(rng, 4)
         mk = lambda k, i, o: init_weights(k, (i, o), c.weight_init, fan_in=i,
                                           fan_out=o, distribution=c.dist,
                                           dtype=dtype)
         params = {
-            "Wq": mk(k1, n_in, n_out), "Wk": mk(k2, n_in, n_out),
-            "Wv": mk(k3, n_in, n_out), "Wo": mk(k4, n_out, n_out),
+            "Wq": mk(k1, n_in, n_out), "Wk": mk(k2, n_in, n_kv),
+            "Wv": mk(k3, n_in, n_kv), "Wo": mk(k4, n_out, n_out),
             "b": jnp.full((n_out,), c.bias_init or 0.0, dtype),
         }
         return params, {}, InputType.recurrent(n_out)
 
+    @property
+    def kv_heads(self):
+        """K/V heads: the query heads unless the conf groups them."""
+        c = self.conf
+        return int(getattr(c, "n_kv_heads", None) or c.n_heads)
+
     def project_qkv(self, params, x):
-        """[b,t,f] -> (q, k, v) each [b,t,H,Dh]. Split out of forward so the
-        decode legs run the SAME projections when they append a token's k/v
-        to a KV-cache slot."""
+        """[b,t,f] -> q [b,t,H,Dh] and k, v [b,t,Hkv,Dh]. Split out of
+        forward so the decode legs run the SAME projections when they append
+        a token's k/v to a KV-cache slot. An explicit `score_scale` rides on
+        q (q * score_scale * sqrt(Dh)), so every kernel behind keeps its
+        1 / sqrt(Dh)."""
         c = self.conf
         B, T, _ = x.shape
-        H = int(c.n_heads)
+        H, Hkv = int(c.n_heads), self.kv_heads
         Dh = int(c.n_out) // H
         q = (x @ params["Wq"]).reshape(B, T, H, Dh)
-        k = (x @ params["Wk"]).reshape(B, T, H, Dh)
-        v = (x @ params["Wv"]).reshape(B, T, H, Dh)
+        k = (x @ params["Wk"]).reshape(B, T, Hkv, Dh)
+        v = (x @ params["Wv"]).reshape(B, T, Hkv, Dh)
+        scale = getattr(c, "score_scale", None)
+        if scale is not None:
+            q = q * jnp.asarray(float(scale) * float(np.sqrt(Dh)), q.dtype)
         return q, k, v
 
     def attend(self, q, k, v, mask):
-        """The kernel dispatch (shared by forward and the decode prefill)."""
+        """The kernel dispatch (shared by forward and the decode prefill).
+        Grouped K/V heads are repeated to the query heads here: a sequence's
+        K and V are small beside the cache, where the decode kernel reads
+        each K/V head once for its group."""
         from ...parallel.ring_attention import attention_reference, \
             blockwise_attention
         c = self.conf
         T = q.shape[1]
+        k, v = _repeat_kv(q, k, v)
         if getattr(c, "use_pallas", False):
             from ...kernels import flash_attention
             # block_size tunes the QUERY tile only; the key tile keeps the
@@ -348,12 +376,12 @@ class SelfAttentionLayerModule(BaseLayerModule):
         return None
 
     def decode_entry(self, geom):
-        H = int(self.conf.n_heads)
-        Dh = int(self.conf.n_out) // H
+        H = self.kv_heads
+        Dh = int(self.conf.n_out) // int(self.conf.n_heads)
         shape = ((geom.num_blocks, geom.block_size, H, Dh) if geom.paged
                  else (geom.slots, geom.capacity, H, Dh))
         leaf = CacheLeaf(shape, geom.dtype, 2)
-        return {"k": leaf, "v": leaf}
+        return note_cache_entry(geom, "kv", {"k": leaf, "v": leaf})
 
     def decode_prefill(self, params, state, x, entry, ctx):
         q, k, v = self.project_qkv(params, x)                 # [1, L, H, Dh]

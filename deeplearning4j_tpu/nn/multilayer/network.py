@@ -24,7 +24,8 @@ import optax
 
 from ..conf.configuration import MultiLayerConfiguration, BackpropType
 from ..layers.base import create_layer
-from ..layers import feedforward, convolution, recurrent, misc, variational  # noqa: F401 (register impls)
+from ..layers import (feedforward, convolution, recurrent, mamba, misc,  # noqa: F401 (register impls)
+                      variational)
 from ..multistep import MultiStepTrainable
 from ..updaters import apply_gradient_normalization
 from ...optimize.listeners import resolve_listeners

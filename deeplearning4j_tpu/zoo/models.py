@@ -13,8 +13,8 @@ from ..nn.conf.layers import (DenseLayer, OutputLayer, RnnOutputLayer,
                               ConvolutionLayer, SubsamplingLayer,
                               BatchNormalization, ActivationLayer, GravesLSTM,
                               GlobalPoolingLayer)
-from ..nn.conf.graph_configuration import ElementWiseVertex
-from ..nn.updaters import Adam, Nesterovs
+from ..nn.conf.graph_configuration import ElementWiseVertex, ScaleVertex
+from ..nn.updaters import Adam, Nesterovs, Sgd
 from ..nn.multilayer.network import MultiLayerNetwork
 from ..nn.graph.graph import ComputationGraph
 
@@ -222,6 +222,77 @@ def transformer_lm(vocab_size=256, d_model=256, n_layers=4, n_heads=4,
         prev = f"b{i}_ln2"
     gb.add_layer("out", RnnOutputLayer(n_out=vocab_size, activation="softmax",
                                        loss="MCXENT"), prev)
+    gb.set_outputs("out")
+    gb.set_input_types(InputType.recurrent(vocab_size))
+    return ComputationGraph(gb.build())
+
+
+def granite_hybrid_lm(vocab_size=256, d_model=64, n_layers=10, n_heads=4,
+                      ffn_mult=4, n_kv_heads=None, attention_layers=(5,),
+                      mamba_n_heads=None, mamba_d_head=16, mamba_d_state=16,
+                      mamba_d_conv=4, mamba_chunk_size=256,
+                      embedding_multiplier=1.0, attention_multiplier=None,
+                      residual_multiplier=1.0, logits_scaling=1.0,
+                      rms_norm_eps=1e-5, dtype="float32", seed=12345,
+                      use_pallas=False, updater=None):
+    """Hybrid state-space / attention decoder of the `granitemoehybrid`
+    shape with no routed experts (ibm-granite/granite-4.0-h-*): pre-norm
+    blocks h += r * mixer(RMSNorm(h)); h += r * mlp(RMSNorm(h)), the mixer a
+    Mamba2Layer except at `attention_layers` (0-based), where it is causal
+    grouped-query attention without positional encoding, scores times
+    `attention_multiplier`; the mlp a gated SiLU feed-forward of width
+    d_model * ffn_mult; h_0 = embedding_multiplier * E[ids]; probabilities =
+    softmax(RMSNorm(h) E^T / logits_scaling). Input one-hot [b, t, vocab].
+
+    `dtype` is the parameters' and activations' dtype (the decode engine
+    decodes in it). The head is tied by value, not by leaf: `out/W` has the
+    embedding's [vocab, d_model] layout, so whoever places weights gives
+    both leaves ONE buffer; init() draws them apart. The default updater is
+    plain SGD: it keeps no state beside the parameters."""
+    from ..nn.conf.layers import (GatedDenseLayer, LMHeadLayer, Mamba2Layer,
+                                  RMSNormalization, SelfAttentionLayer)
+    if mamba_n_heads is None:
+        mamba_n_heads = 2 * d_model // mamba_d_head
+    gb = (NeuralNetConfiguration.builder()
+          .seed(seed).updater(updater or Sgd(learning_rate=1e-3))
+          .weight_init("xavier").dtype(dtype)
+          .graph_builder()
+          .add_inputs("tokens"))
+    norm = lambda: RMSNormalization(eps=rms_norm_eps)
+
+    def residual(name, prev, branch):
+        gb.add_vertex(name + "x", ScaleVertex(residual_multiplier), branch)
+        gb.add_vertex(name, ElementWiseVertex("add"), prev, name + "x")
+        return name
+
+    gb.add_layer("embed", DenseLayer(n_out=d_model, activation="identity"),
+                 "tokens")
+    gb.add_vertex("embed_x", ScaleVertex(embedding_multiplier), "embed")
+    prev = "embed_x"
+    for i in range(n_layers):
+        gb.add_layer(f"b{i}_norm1", norm(), prev)
+        if i in attention_layers:
+            mixer = f"b{i}_attn"
+            gb.add_layer(mixer, SelfAttentionLayer(
+                n_out=d_model, n_heads=n_heads, n_kv_heads=n_kv_heads,
+                score_scale=attention_multiplier, causal=True,
+                use_pallas=use_pallas, activation="identity"), f"b{i}_norm1")
+        else:
+            mixer = f"b{i}_mamba"
+            gb.add_layer(mixer, Mamba2Layer(
+                n_out=d_model, n_heads=mamba_n_heads, head_dim=mamba_d_head,
+                d_state=mamba_d_state, d_conv=mamba_d_conv,
+                chunk_size=mamba_chunk_size, eps=rms_norm_eps,
+                use_pallas=use_pallas), f"b{i}_norm1")
+        prev = residual(f"b{i}_res1", prev, mixer)
+        gb.add_layer(f"b{i}_norm2", norm(), prev)
+        gb.add_layer(f"b{i}_mlp", GatedDenseLayer(
+            n_out=d_model, n_hidden=d_model * ffn_mult), f"b{i}_norm2")
+        prev = residual(f"b{i}_res2", prev, f"b{i}_mlp")
+    gb.add_layer("norm", norm(), prev)
+    gb.add_layer("out", LMHeadLayer(n_out=vocab_size, activation="softmax",
+                                    loss="MCXENT",
+                                    logits_scaling=logits_scaling), "norm")
     gb.set_outputs("out")
     gb.set_input_types(InputType.recurrent(vocab_size))
     return ComputationGraph(gb.build())

@@ -224,6 +224,67 @@ def test_mesh_cache_sharding_takes_the_axis(shape, axis):
     assert ctx.cache_sharding(shape).spec == P()
 
 
+@pytest.mark.parametrize("paged", [False, True], ids=["slab", "paged"])
+def test_a_model_of_both_entry_kinds_decodes_with_decode_untouched(paged):
+    """What PR 31 proves of the contract: the hybrid decoder (Mamba-2 layers
+    keeping a fixed-size state and a conv tail, grouped-query attention
+    keeping K/V rows) came as layer files, and decodes — two slots joined at
+    different steps, token for token the full forward — through an engine,
+    a scheduler and a serving plane that name none of it. No answer had to
+    be added to BaseLayerModule."""
+    from deeplearning4j_tpu.zoo.models import granite_hybrid_lm
+    vocab = 40
+    net = granite_hybrid_lm(
+        vocab_size=vocab, d_model=32, n_layers=3, n_heads=4, n_kv_heads=2,
+        attention_layers=(1,), mamba_d_head=8, mamba_d_state=16,
+        mamba_chunk_size=8, embedding_multiplier=12, residual_multiplier=0.22,
+        attention_multiplier=0.125, logits_scaling=8, seed=11).init()
+    eye = np.eye(vocab, dtype=np.float32)
+
+    def naive(prompt, n):
+        ids, out = list(prompt), []
+        for _ in range(n):
+            out.append(int(np.asarray(net.output(eye[ids][None]))[0, -1]
+                           .argmax()))
+            ids.append(out[-1])
+        return out
+
+    prompts, n = {1: [3, 1, 4, 1, 5, 9, 2, 6, 5, 3], 0: [9, 2, 6]}, 6
+    eng = DecodeEngine(net, slots=2, max_len=32, paged=paged, block_size=8)
+    cache = eng.init_cache()
+    assert {frozenset(e) for e in cache["layers"].values()} \
+        == {frozenset({"ssm", "conv"}), frozenset({"k", "v"})}
+    got = {1: [], 0: []}
+    ids = np.zeros((2,), np.int32)
+    cache, nid, _ = eng.prefill(cache, 1, prompts[1])
+    got[1].append(nid)
+    for step in range(1, 2 * n + 2):
+        if step == 3:                   # slot 0 joins two tokens later
+            cache, nid, _ = eng.prefill(cache, 0, prompts[0])
+            got[0].append(nid)
+            continue
+        for s in got:
+            if got[s]:
+                ids[s] = got[s][-1]
+        cache, nxt, _ = eng.step(cache, ids)
+        for s in got:
+            if got[s] and len(got[s]) < n:
+                got[s].append(int(nxt[s]))
+    assert got == {s: naive(p, n) for s, p in prompts.items()}
+    with pytest.raises(DecodeUnsupported):      # a state cannot rewind
+        eng.verify(cache, 1, [4, 5], 3)
+    assert set(eng.carry_snapshot(cache)["layers"]) == {"b0_mamba",
+                                                        "b2_mamba"}
+    # nothing under decode/ or serving/ knows the new layers by name
+    pkg = pathlib.Path(engine_module.__file__).parents[1]
+    for path in [*(pkg / "decode").glob("*.py"),
+                 *(pkg / "serving").glob("*.py")]:
+        text = path.read_text().lower()
+        for word in ("mamba", "ssm_", "rmsnorm", "gateddense", "granite",
+                     "lmhead", "n_kv_heads"):
+            assert word not in text, (path.name, word)
+
+
 def test_the_engine_names_no_layer_class():
     tree = ast.parse(pathlib.Path(engine_module.__file__).read_text())
     for node in ast.walk(tree):

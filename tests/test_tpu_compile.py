@@ -337,6 +337,44 @@ def test_flash_decode_paged_compiles(on_chip):
     assert text.count(KERNEL) == 1
 
 
+def test_flash_decode_grouped_heads_compiles_on_the_cache_where_it_lies(
+        on_chip):
+    """granite4_h_micro's attention layers: 32 query heads over a bfloat16
+    cache of 8 K/V heads; the key block follows the QUERY heads (512, where
+    the 8 K/V heads alone would take the whole capacity), and the cache is
+    still read through a bitcast."""
+    S, C, Hq, H, D = 64, 1024, 32, 8, 64
+    assert fa._decode_block(C, Hq, D, 2, 1024, False) == 512
+    text = compiled_text(
+        lambda q, k, v, n: flash_decode(q, k, v, n, interpret=False),
+        on_chip((S, 1, Hq, D), jnp.bfloat16),
+        on_chip((S, C, H, D), jnp.bfloat16),
+        on_chip((S, C, H, D), jnp.bfloat16), on_chip((S,), jnp.int32))
+    assert text.count(KERNEL) == 1
+    assert relayouts(text, S * C * H * D) == []
+
+
+def test_ssm_step_compiles_in_place(on_chip):
+    """The Mamba-2 state update at the cell's size, 64 slots x [128, 4096]
+    float32: one kernel, the donated state aliased onto its output (no
+    second 134 MB buffer, no copy of it)."""
+    from deeplearning4j_tpu.kernels import ssm_step
+    S, N, C = 64, 128, 4096
+    comp = jax.jit(
+        lambda st, a, x, b, c: ssm_step(st, a, x, b, c, interpret=False),
+        donate_argnums=(0,)).lower(
+            on_chip((S, N, C), jnp.float32), on_chip((S, C), jnp.float32),
+            on_chip((S, C), jnp.float32), on_chip((S, N), jnp.float32),
+            on_chip((S, N), jnp.float32)).compile()
+    text = comp.as_text()
+    assert text.count(KERNEL) == 1
+    assert len(re.findall(r"%ssm_step[.\d]* = ", text)) == 1
+    assert relayouts(text, S * N * C) == []
+    mem = comp.memory_analysis()
+    assert mem.alias_size_in_bytes == S * N * C * 4
+    assert mem.temp_size_in_bytes < 1 << 20
+
+
 def test_untileable_shape_has_no_compiled_plan():
     """Why chip_smoke.py asks for prompts of 128 tokens and more: compiled,
     the key block must be a multiple of 128, so a 64-token prefill bucket or
@@ -401,6 +439,43 @@ def test_prefill_bucket_compiles_with_kernel(lm_engine, one_chip,
                       np.int32(bucket - 1), eng._greedy_slot_ops), one_chip)
     text = eng._build_prefill(bucket).lower(*args, None).compile().as_text()
     assert text.count(KERNEL) == 4          # one masked flash per layer
+
+
+def test_hybrid_decode_step_compiles_with_both_kinds_of_kernel(
+        one_chip, chip_config, monkeypatch):
+    """granite4_h_micro's two kinds of block (Mamba-2, attention, Mamba-2) at
+    a quarter of its widths, bfloat16, 16 slots of 256: per Mamba-2 layer
+    one `ssm_step`, for the attention layer one `kv_append` and one
+    `flash_decode`; no loop over the slots, no copy of a state or of a K/V
+    slab. (The whole 40-layer step at the cell's size compiles here in 31 s
+    with 12.2 GB of arguments and 0.116 GB of temporaries: PERF.md §4.)"""
+    from deeplearning4j_tpu.decode.engine import DecodeEngine
+    from deeplearning4j_tpu.zoo.models import granite_hybrid_lm
+    monkeypatch.setattr(fa, "_interpret_default", lambda: False)
+    monkeypatch.setattr(
+        importlib.import_module("deeplearning4j_tpu.kernels.ssm_step"),
+        "_interpret_default", lambda: False)
+    net = granite_hybrid_lm(
+        vocab_size=512, d_model=512, n_layers=3, n_heads=8, n_kv_heads=2,
+        attention_layers=(1,), mamba_d_head=64, mamba_d_state=128,
+        embedding_multiplier=12, attention_multiplier=0.015625,
+        residual_multiplier=0.22, logits_scaling=8, dtype="bfloat16",
+        use_pallas=True).init()
+    eng = DecodeEngine(net, slots=16, max_len=256)
+    args = _abstract((net.params, net.states, eng.init_cache(),
+                      np.zeros((eng.slots,), np.int32),
+                      eng._greedy_step_ops), one_chip)
+    text = eng._build_step().lower(*args, None).compile().as_text()
+    assert text.count(KERNEL) == 4
+    assert len(re.findall(r"%ssm_step[.\d]* = ", text)) == 2
+    assert len(re.findall(r"%kv_append[.\d]* = ", text)) == 1
+    assert relayouts(text, 16 * 128 * 1024) == []
+    assert loops(text) == []
+    text = eng._build_prefill(128).lower(*_abstract(
+        (net.params, net.states, eng.init_cache(), np.int32(0),
+         np.zeros((128,), np.int32), np.int32(100), eng._greedy_slot_ops),
+        one_chip), None).compile().as_text()
+    assert text.count(KERNEL) == 1          # the attention layer's flash
 
 
 @pytest.mark.slow
