@@ -29,7 +29,13 @@ Both legs emit SAMPLED token ids (decode/sampling.py): temperature /
 top-k / top-p / seed arrive as batch-shaped ARRAY OPERANDS, with
 temperature <= 0 short-circuiting to argmax in-trace, so greedy and
 creative requests co-batch in the same executable and per-request sampling
-params never become recompile keys (graftlint GL016).
+params never become recompile keys (graftlint GL016). The sampling leg does,
+and hands the host, only what those operands ask for: a batch with no
+sampled slot runs an argmax (the sort and the draw sit behind a traced
+conditional; `decode_steps_total{sampler}` counts which way each step went)
+and the host reads [slots] int32 ids. The [slots, vocab] distribution comes
+back as the device array the program produced: `read_probs` is the host
+copy, for a caller that uses rows of it.
 
 The cache is a plain pytree ``{"lengths": int32[slots], "layers": {name:
 entry}}`` threaded functionally through the executables and DONATED, so
@@ -228,6 +234,7 @@ class DecodeEngine:
         # into the scheduler's decode_wave span
         self.tracer = tracer if tracer is not None else get_tracer()
         self._m_dispatch = self._m_sync = self._m_probs_read = None
+        self._m_steps = None        # decode_steps_total{sampler}
         if registry is not None:
             self._m_dispatch = registry.histogram(
                 "decode_step_dispatch_ms", "The jitted decode step call "
@@ -237,9 +244,14 @@ class DecodeEngine:
                 "decode_step_sync_ms", "Host read of the step's next ids: "
                 "the wait for the device, ms")
             self._m_probs_read = registry.histogram(
-                "decode_probs_read_ms", "Host read of the step's "
-                "[slots, vocab] probabilities, ms")
-        self.last_step_s = 0.0      # dispatch + sync + probs_read of step()
+                "decode_probs_read_ms", "Host read of rows of a step's "
+                "[slots, vocab] probabilities (read_probs: only a caller "
+                "that uses them pays it), ms")
+            self._m_steps = registry.counter(
+                "decode_steps_total", "Decode steps by what their sampling "
+                "operands asked for: sampler=\"greedy\" (no slot with a "
+                "positive temperature: argmax only) or \"sampled\"")
+        self.last_step_s = 0.0      # dispatch + sync of step()
         # mesh-sharded decode (serving/mesh.py): a wrapped model carries the
         # serving MeshContext; the KV cache partitions its head axis over
         # the mesh model axis and the step/prefill executables pin the
@@ -554,7 +566,8 @@ class DecodeEngine:
     def prefill(self, cache, slot, prompt_ids, sampling=None, step_index=0,
                 table=None):
         """Run `prompt_ids` (python ints / 1-D array) into cache slot `slot`;
-        returns (cache, first generated id, last-position probs [vocab]).
+        returns (cache, first generated id, last-position probs [vocab] as
+        the device array the program produced: `read_probs` for a host copy).
 
         `sampling`: a SamplerConfig (greedy when None); `step_index` is the
         fold_in counter of the emitted token — 0 on a fresh admission,
@@ -586,7 +599,7 @@ class DecodeEngine:
             fn, f"decode_prefill:{L}", L, self.model.params,
             self.model.states, cache, np.int32(slot), padded, np.int32(n),
             samp, table if self.paged else None)
-        return cache, int(nid), np.asarray(probs)
+        return cache, int(nid), probs
 
     def step(self, cache, last_ids, sampling=None, table=None):
         """Advance every slot one token. `last_ids`: [slots] int token ids
@@ -594,7 +607,8 @@ class DecodeEngine:
         cache rows are reset by the next prefill). `sampling`: the operand
         dict from sampling.batch_operands (greedy when None — per-request
         sampling params are ARRAY operands here, never jit keys). Returns
-        (cache, next_ids [slots] np.int32, probs [slots, vocab])."""
+        (cache, next_ids [slots] np.int32, probs [slots, vocab] still on
+        the device: `read_probs` for a host copy of the rows a caller uses)."""
         ids = np.asarray(last_ids, np.int32).reshape(self.slots)
         self._ensure_placed()
         if self.paged and table is None:
@@ -607,27 +621,38 @@ class DecodeEngine:
         warm = label in self._compiled
         cr = self.cost_registry
         phase = self.tracer.phase
+        samp = self._step_operands(sampling)
         with phase("decode_step_dispatch", histogram=self._m_dispatch,
                    fold=True) as dispatch:
             cache, nxt, probs = self._run(
                 fn, label, "step", self.model.params, self.model.states,
-                cache, ids, self._step_operands(sampling),
+                cache, ids, samp,
                 table if self.paged else None, sample=False)
             if not warm:            # the compile: _timed has accounted it
                 dispatch.cancel()
         with phase("decode_step_sync", histogram=self._m_sync,
                    fold=True) as sync:
             nxt = np.asarray(nxt)
-        with phase("decode_probs_read", histogram=self._m_probs_read,
-                   fold=True) as read:
-            probs = np.asarray(probs)
+        if self._m_steps is not None:
+            # the same question the traced conditional asks of the operand
+            self._m_steps.inc(1, sampler="sampled" if np.any(
+                samp["temperature"] > 0) else "greedy")
         step_ms = dispatch.duration_ms + sync.duration_ms
-        self.last_step_s = (step_ms + read.duration_ms) / 1000.0
+        self.last_step_s = step_ms / 1000.0
         if warm and cr is not None and cr.dispatch_due(label):
             # every Nth step's wall (call + wait for the ids) is the cost
             # plane's dispatch sample: the wait above is the sync it needs
             cr.observe_dispatch(label, step_ms)
         return cache, nxt, probs
+
+    def read_probs(self, probs):
+        """Host copy of a distribution `step` / `prefill` returned, or of
+        the rows of it a caller indexed out on the device: the phase
+        `decode_probs_read` and its histogram, whose count is how often some
+        caller moved probabilities to the host at all."""
+        with self.tracer.phase("decode_probs_read",
+                               histogram=self._m_probs_read, fold=True):
+            return np.asarray(probs)
 
     def has_recurrent(self):
         """Some layer keeps a carry: state a length reset cannot rewind."""

@@ -19,6 +19,13 @@ so one executable serves every mix of greedy and sampled slots, and the
 graftlint GL016 rule (`sampling-recompile-key`) flags any hot-path code
 that demotes these back to static args or dict-key components.
 
+What a batch pays follows what its operands ask for: the filter and the
+draw below (a sort, a cumulative sum and a Gumbel draw, each over
+[slots, vocab]) sit in one branch of a `lax.cond` whose predicate is
+`any(temperature > 0)` — a traced value of the operand, nothing static.
+A batch of greedy slots runs the argmax alone; a batch with one sampled
+slot runs what it always ran and draws the same tokens.
+
 Determinism: slot s draws token t from
 ``jax.random.categorical(fold_in(PRNGKey(seed[s]), step[s]), ...)`` — a
 pure function of (seed, token index). The sequence therefore reproduces
@@ -44,6 +51,7 @@ from __future__ import annotations
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 NEG_INF = -1e30
 
@@ -167,21 +175,27 @@ def sample_tokens(probs, operands):
     Greedy slots (temperature <= 0) take the argmax; sampled slots draw
     from categorical(logits/T) with the top-k/top-p mask applied at the
     LOGIT level (NEG_INF) and a per-slot key
-    fold_in(PRNGKey(seed), step)."""
+    fold_in(PRNGKey(seed), step). The filter and the draw are one branch
+    of a `lax.cond` on "some slot samples" (module docstring)."""
     temperature = operands["temperature"]
     greedy_ids = jnp.argmax(probs, axis=-1).astype(jnp.int32)
-    keep = keep_mask(probs, operands["top_k"], operands["top_p"])
-    t = jnp.maximum(temperature, 1e-6)[:, None]
-    logits = jnp.log(jnp.clip(probs, 1e-30, None)) / t
-    logits = jnp.where(keep, logits, NEG_INF)
 
-    def draw(seed, step, row):
-        key = jax.random.fold_in(jax.random.PRNGKey(seed), step)
-        return jax.random.categorical(key, row)
+    def filter_and_draw():
+        keep = keep_mask(probs, operands["top_k"], operands["top_p"])
+        t = jnp.maximum(temperature, 1e-6)[:, None]
+        logits = jnp.log(jnp.clip(probs, 1e-30, None)) / t
+        logits = jnp.where(keep, logits, NEG_INF)
 
-    sampled = jax.vmap(draw)(operands["seed"].astype(jnp.uint32),
-                             operands["step"], logits).astype(jnp.int32)
-    return jnp.where(temperature > 0, sampled, greedy_ids)
+        def draw(seed, step, row):
+            key = jax.random.fold_in(jax.random.PRNGKey(seed), step)
+            return jax.random.categorical(key, row)
+
+        sampled = jax.vmap(draw)(operands["seed"].astype(jnp.uint32),
+                                 operands["step"], logits).astype(jnp.int32)
+        return jnp.where(temperature > 0, sampled, greedy_ids)
+
+    return lax.cond(jnp.any(temperature > 0), filter_and_draw,
+                    lambda: greedy_ids)
 
 
 def filter_probs_np(probs, config):

@@ -21,10 +21,11 @@ profiler annotation "dl4j:<name>" + histogram `<name>_ms` + ring span) whose
 parts fold into it: `decode_admit` (a pass that admitted something; holds
 the per-request `decode_queue_wait` and `decode_prefill`),
 `decode_step_build` (host work before the dispatch), the engine's
-`decode_step_dispatch` / `decode_step_sync` / `decode_probs_read`, and
-`decode_emit` (tokens appended, retirements, futures completed). The ring
-gets one span a pass with its parts' durations as attributes;
-`decode_itl_ms` is the sum of the engine's three, from the same clock reads.
+`decode_step_dispatch` / `decode_step_sync`, and `decode_emit` (tokens
+appended, retirements, futures completed). The ring gets one span a pass
+with its parts' durations as attributes; `decode_itl_ms` is the sum of the
+engine's two, from the same clock reads. (The step's probabilities stay on
+the device: this loop never reads them.)
 
 Requests therefore join and leave the in-flight batch per token with zero
 steady-state recompiles: after the step executable and a prompt-length
@@ -721,8 +722,8 @@ class DecodeScheduler:
         self._cache, nxt, _ = self._engine.step(
             self._cache, ids, sampling=samp,
             table=self._table if self.paged else None)
-        # the engine's own dispatch + sync + probs_read clock reads: the
-        # old histogram and the phases' cannot drift apart
+        # the engine's own dispatch + sync clock reads: the old histogram
+        # and the phases' cannot drift apart
         wall = self._engine.last_step_s
         with self.tracer.phase("decode_emit", histogram=self.m_emit,
                                fold=True):

@@ -173,7 +173,8 @@ class SpeculativeEngine:
                 if greedy:
                     nxt = int(step_nxt[0])
                 else:
-                    dist = filter_probs_np(dp[0], sampler)
+                    dist = filter_probs_np(self.draft.read_probs(dp[0]),
+                                           sampler)
                     draft_dists.append(dist)
                     nxt = int(rng.choice(dist.shape[0], p=dist))
                 drafts.append(nxt)

@@ -4,7 +4,10 @@
   cases and against its numpy mirror (filter_probs_np), seeded streams
   reproducible / seed-sensitive, greedy short-circuit, and compile-flat
   executable counts while every sampling parameter swings per request
-  (the GL016 invariant, asserted on XLA cache sizes).
+  (the GL016 invariant, asserted on XLA cache sizes); `sample_tokens`
+  behind its conditional against the unconditional formulation (equal ids
+  over greedy, sampled and mixed batches), the distribution left on the
+  device, `decode_steps_total{sampler}` and the probabilities-read count.
 - paged KV: BlockPool unit behavior (all-or-nothing alloc, double-free,
   defrag, high-water), flash_decode_paged == flash_decode on the gathered
   layout, and paged greedy/sampled decode == slab decode token-for-token
@@ -31,8 +34,9 @@ from deeplearning4j_tpu.decode import (BlockPool, DecodeEngine,
                                        DecodeScheduler, DecodeUnsupported,
                                        PoolExhausted, SamplerConfig,
                                        SpeculativeEngine, blocks_for)
-from deeplearning4j_tpu.decode.sampling import (batch_operands,
-                                                filter_probs_np, keep_mask)
+from deeplearning4j_tpu.decode.sampling import (NEG_INF, batch_operands,
+                                                filter_probs_np, keep_mask,
+                                                sample_tokens)
 from deeplearning4j_tpu.kernels import flash_decode, flash_decode_paged
 from deeplearning4j_tpu.serving.registry import ModelRegistry
 from deeplearning4j_tpu.telemetry.registry import MetricsRegistry
@@ -155,6 +159,143 @@ def test_sampling_params_swing_compile_flat():
     counts = eng.executable_counts()
     assert all(v == 1 for v in counts.values()), counts
     assert len(outs) > 1      # the params actually changed the streams
+
+
+def _sample_tokens_unconditional(probs, operands):
+    """`sample_tokens` as it was before the conditional (PR 31's body): the
+    filter and the draw run whatever the operands say. The reference the
+    conditional form must equal id for id."""
+    temperature = operands["temperature"]
+    greedy_ids = jnp.argmax(probs, axis=-1).astype(jnp.int32)
+    keep = keep_mask(probs, operands["top_k"], operands["top_p"])
+    t = jnp.maximum(temperature, 1e-6)[:, None]
+    logits = jnp.log(jnp.clip(probs, 1e-30, None)) / t
+    logits = jnp.where(keep, logits, NEG_INF)
+
+    def draw(seed, step, row):
+        key = jax.random.fold_in(jax.random.PRNGKey(seed), step)
+        return jax.random.categorical(key, row)
+
+    sampled = jax.vmap(draw)(operands["seed"].astype(jnp.uint32),
+                             operands["step"], logits).astype(jnp.int32)
+    return jnp.where(temperature > 0, sampled, greedy_ids)
+
+
+_SAMPLERS = {
+    "temperature": dict(temperature=0.9),
+    "top_k": dict(temperature=1.3, top_k=5),
+    "top_p": dict(temperature=0.7, top_p=0.6),
+    "both": dict(temperature=1.1, top_k=9, top_p=0.85),
+}
+# which of the 6 slots sample
+_BATCHES = {"all_greedy": (), "all_sampled": range(6), "mixed": (1, 4)}
+
+
+@pytest.mark.parametrize("seed,step", [(0, 0), (7, 3), (2**32 - 1, 511)])
+@pytest.mark.parametrize("sampler", _SAMPLERS)
+@pytest.mark.parametrize("batch", _BATCHES)
+def test_sample_tokens_equals_the_unconditional_formulation(batch, sampler,
+                                                            seed, step):
+    """Behind the conditional a mixed batch draws exactly the tokens of the
+    unconditional code and a greedy batch emits exactly its argmax: equal,
+    not close, as jitted programs (how the step runs them)."""
+    S = 6
+    rng = np.random.default_rng(seed % 1000 + step)
+    probs = jnp.asarray(rng.dirichlet(np.full(V, 0.3), size=S), jnp.float32)
+    sampled = set(_BATCHES[batch])
+    ops = batch_operands(
+        S, {s: SamplerConfig(seed=(seed + s) & 0xFFFFFFFF,
+                             **(_SAMPLERS[sampler] if s in sampled
+                                else {"top_k": 3, "top_p": 0.5}))
+            for s in range(S)},
+        {s: step + s for s in range(S)})
+    got = np.asarray(jax.jit(sample_tokens)(probs, ops))
+    want = np.asarray(jax.jit(_sample_tokens_unconditional)(probs, ops))
+    assert got.dtype == np.int32 and got.shape == (S,)
+    assert got.tolist() == want.tolist()
+    greedy = np.argmax(np.asarray(probs), axis=-1)
+    for s in range(S):
+        if s not in sampled:
+            assert got[s] == greedy[s]
+
+
+def test_step_and_prefill_leave_the_distribution_on_the_device():
+    """The ids come back as host values, the distribution as the device
+    array the program produced; `read_probs` is the one host read, and its
+    histogram counts it."""
+    reg = MetricsRegistry()
+    eng = DecodeEngine(_tlm(seed=6), slots=3, max_len=32, registry=reg)
+    cache, nid, probs = eng.prefill(eng.init_cache(), 1, [4, 2, 9])
+    assert isinstance(nid, int)
+    assert isinstance(probs, jax.Array) and probs.shape == (V,)
+    cache, nxt, probs = eng.step(cache, np.array([0, nid, 0], np.int32))
+    assert isinstance(nxt, np.ndarray) and nxt.dtype == np.int32 \
+        and nxt.shape == (3,)
+    assert isinstance(probs, jax.Array) and probs.shape == (3, V)
+    read = reg.get("decode_probs_read_ms")
+    assert read.count() == 0
+    row = eng.read_probs(probs[1])
+    assert isinstance(row, np.ndarray) and row.shape == (V,)
+    assert int(np.argmax(row)) == nxt[1]
+    assert read.count() == 1
+
+
+def test_greedy_sampled_greedy_steps_share_one_executable():
+    """The conditional's predicate is a traced value of an array operand:
+    one engine stepped greedy -> sampled -> greedy compiles nothing new,
+    and `decode_steps_total` says which way each step went."""
+    reg = MetricsRegistry()
+    eng = DecodeEngine(_tlm(seed=5), slots=2, max_len=32, registry=reg)
+    cache, nid, _ = eng.prefill(eng.init_cache(), 0, [2, 7, 1])
+    ids = np.array([nid, 0], np.int32)
+    cache, _, _ = eng.step(cache, ids)
+    assert eng.executable_counts() == {"decode_step": 1,
+                                       "decode_prefill:16": 1}
+    samp = batch_operands(2, {0: SamplerConfig(temperature=5.0, seed=3)},
+                          {0: 2})
+    cache, _, _ = eng.step(cache, ids, sampling=samp)
+    # operands given, no positive temperature: still the greedy way
+    cache, _, _ = eng.step(cache, ids, sampling=batch_operands(
+        2, {0: SamplerConfig(top_k=3, seed=3)}))
+    cache, _, _ = eng.step(cache, ids)
+    cache, nid, _ = eng.prefill(cache, 1, [5, 5],
+                                sampling=SamplerConfig(temperature=0.8))
+    assert eng.executable_counts() == {"decode_step": 1,
+                                       "decode_prefill:16": 1}
+    steps = reg.get("decode_steps_total")
+    assert steps.get(sampler="greedy") == 3
+    assert steps.get(sampler="sampled") == 1
+    assert steps.get() == reg.get("decode_step_sync_ms").count()
+
+
+def test_scheduler_counts_sampled_steps_only_while_a_sampled_request_runs():
+    """An all-greedy scheduler run never reads probabilities and counts
+    every step greedy; a sampled request counts its steps `sampled` while
+    it is active, and the greedy run after it is greedy again."""
+    registry = ModelRegistry()
+    registry.register("v1", _tlm(seed=9))
+    registry.deploy("v1")
+    mreg = MetricsRegistry()
+    sched = DecodeScheduler(registry, mreg, slots=2, max_len=48)
+    steps = lambda which: mreg.get("decode_steps_total").get(sampler=which)
+    sync = lambda: mreg.get("decode_step_sync_ms").count()
+    sched.start()
+    try:
+        greedy = sched.generate([3, 1, 4], max_new_tokens=8)["tokens"]
+        assert steps("sampled") == 0 and steps("greedy") == sync() >= 7
+        before = steps("greedy")
+        cfg = SamplerConfig(temperature=0.9, top_k=8, seed=11)
+        drawn = sched.generate([3, 1, 4], max_new_tokens=8,
+                               sampler=cfg)["tokens"]
+        assert steps("sampled") == 7 and steps("greedy") == before
+        assert sched.generate([3, 1, 4],
+                              max_new_tokens=8)["tokens"] == greedy
+        assert steps("sampled") == 7
+        assert steps("greedy") + steps("sampled") == sync()
+    finally:
+        sched.stop()
+    assert drawn == _tlm(seed=9).generate([3, 1, 4], 8, sampler=cfg)
+    assert mreg.get("decode_probs_read_ms").count() == 0
 
 
 # ---------------------------------------------------------------- paged KV
@@ -290,6 +431,22 @@ def test_speculative_stop_id_and_sampled_determinism():
     s1 = spec.generate([4, 4, 1], 10, sampler=cfg)
     s2 = spec.generate([4, 4, 1], 10, sampler=cfg)
     assert s1 == s2
+
+
+def test_speculative_draft_reads_one_row_and_only_when_sampling():
+    """The draft's distribution stays on the device in greedy mode (the
+    ids suffice); sampled mode reads one [vocab] row a proposed token
+    through `read_probs`, counted in `decode_probs_read_ms`."""
+    reg = MetricsRegistry()
+    spec = SpeculativeEngine(_tlm(seed=16), _tlm(seed=8, layers=2), k=3,
+                             max_len=64, registry=reg)
+    read = reg.get("decode_probs_read_ms")
+    spec.generate([4, 4, 1], 10)
+    assert read.count() == 0
+    proposed = spec.proposed
+    spec.generate([4, 4, 1], 10,
+                  sampler=SamplerConfig(temperature=0.9, top_p=0.9, seed=5))
+    assert read.count() == spec.proposed - proposed > 0
 
 
 def test_speculative_guards():

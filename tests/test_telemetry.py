@@ -802,16 +802,18 @@ def test_profiler_session_shows_decode_phases_on_a_host_line(tmp_path):
 
 def test_decode_phase_sums_add_up_to_the_wave():
     """Over N warm steps the parts cover the pass: their sum is at most the
-    waves' and at least 90 % of it; decode_itl_ms is the engine's three
-    phases, from the same clock reads."""
+    waves' and at least 90 % of it; decode_itl_ms is the engine's two
+    phases (dispatch + sync), from the same clock reads; no pass of a greedy
+    run reads probabilities to the host, and every step counts as greedy."""
     # a step of a few ms, so the parts' share is the loop's, not the
     # bookkeeping's (97-98 % here; a 0.6 ms step reads 87 %)
     sched, mreg = _tiny_scheduler(_tiny_lm(d_model=256, vocab=2048),
                                   Tracer(enabled=False), slots=8)
     parts = ["decode_admit_ms", "decode_step_build_ms",
              "decode_step_dispatch_ms", "decode_step_sync_ms",
-             "decode_probs_read_ms", "decode_emit_ms"]
-    names = parts + ["decode_wave_ms", "decode_itl_ms"]
+             "decode_emit_ms"]
+    names = parts + ["decode_wave_ms", "decode_itl_ms",
+                     "decode_probs_read_ms"]
 
     def sums():
         # generate() returns from inside the pass that emitted the last
@@ -840,9 +842,11 @@ def test_decode_phase_sums_add_up_to_the_wave():
     covered = sum(d[n] for n in parts)
     assert covered <= d["decode_wave_ms"]
     assert covered >= 0.9 * d["decode_wave_ms"], (covered, d)
-    engine = d["decode_step_dispatch_ms"] + d["decode_step_sync_ms"] \
-        + d["decode_probs_read_ms"]
+    engine = d["decode_step_dispatch_ms"] + d["decode_step_sync_ms"]
     assert d["decode_itl_ms"] == pytest.approx(engine, rel=1e-6)
+    assert after["decode_probs_read_ms"] == (0, 0)
+    assert mreg.get("decode_steps_total").series() == [
+        ({"sampler": "greedy"}, after["decode_step_sync_ms"][1])]
     for n in ("decode_queue_wait_ms", "decode_prefill_ms"):
         assert mreg.get(n).count() == 2
 

@@ -82,7 +82,7 @@ def compiled_text(fn, *args):
 _RESULT = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* "
                      r"([\w\-]+)\(")
 _CALLS = re.compile(r"calls=%?([\w.\-]+)")
-_COMPUTATION = re.compile(r"^%?([\w.\-]+) \(.*\{\s*$")
+_COMPUTATION = re.compile(r"^(ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
 
 
 def relayouts(text, elements):
@@ -97,7 +97,7 @@ def relayouts(text, elements):
     for line in text.splitlines():
         head = _COMPUTATION.match(line)
         if head:
-            computation = head.group(1)
+            computation = head.group(2)
         m = _RESULT.match(line)
         if not m:
             continue
@@ -120,6 +120,45 @@ def loops(text):
     """The `op_name` of every `while` loop of an optimized HLO text."""
     return [re.search(r'op_name="([^"]*)"', line).group(1)
             for line in text.splitlines() if re.search(r" while\(", line)]
+
+
+_CALLEE = re.compile(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)")
+_SORT = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = .* sort\(")
+
+
+def unconditional_sorts(text):
+    """Names of the `sort` instructions of an optimized HLO text that run
+    whenever the program does: those in the entry computation or in one it
+    reaches by a call, a fusion, a loop or a reduction. What only a branch
+    of a `conditional` reaches (`branch_computations`, `true_computation`,
+    `false_computation`) is not followed."""
+    bodies, entry, computation = {}, None, None
+    for line in text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            computation = head.group(2)
+            bodies[computation] = []
+            if head.group(1):
+                entry = computation
+        elif computation is not None:
+            bodies[computation].append(line)
+    reached, todo = set(), [entry]
+    while todo:
+        computation = todo.pop()
+        if computation in reached:
+            continue
+        reached.add(computation)
+        for line in bodies[computation]:
+            todo.extend(_CALLEE.findall(line))
+    return [m.group(1) for computation in sorted(reached)
+            for m in map(_SORT.match, bodies[computation]) if m]
+
+
+def sorts_only_under_a_conditional(text):
+    """The sampler's sort is in the program, and only a branch of a
+    `conditional` reaches it: a greedy batch runs none."""
+    return " sort(" in text and " conditional(" in text \
+        and unconditional_sorts(text) == []
 
 
 def compiled_append(kv, new, pos, **how):
@@ -313,6 +352,22 @@ def test_loops_sees_the_per_slot_update(on_chip):
     assert relayouts(text, S * C * H * D) == []
 
 
+def test_unconditional_sorts_sees_a_sort_outside_a_conditional(on_chip):
+    """The sampler's filter as it ran before PR 32, for every batch: the
+    sort is in the entry computation. Behind `lax.cond` it is in the text
+    and not on the unconditional path."""
+    from deeplearning4j_tpu.decode.sampling import keep_mask
+    args = (on_chip((8, 512), jnp.float32), on_chip((8,), jnp.int32),
+            on_chip((8,), jnp.float32))
+    text = compiled_text(keep_mask, *args)
+    assert len(unconditional_sorts(text)) == 1
+    text = compiled_text(
+        lambda p, k, t: jax.lax.cond(
+            jnp.any(k > 0), lambda: keep_mask(p, k, t),
+            lambda: jnp.ones(p.shape, bool)), *args)
+    assert sorts_only_under_a_conditional(text)
+
+
 def test_relayouts_sees_a_transposed_cache(on_chip):
     """The guard has teeth: the same cache read through the training
     forward, which folds the heads into the batch in front of its kernel,
@@ -426,6 +481,7 @@ def test_decode_step_compiles_with_kernel(lm_engine, one_chip,
     # no loop over the slots is left (the append as XLA's per-slot update)
     assert loops(text) == []
     assert "dynamic-update-slice" not in text
+    assert sorts_only_under_a_conditional(text)
 
 
 @pytest.mark.parametrize("bucket", [128, 256])
@@ -439,6 +495,7 @@ def test_prefill_bucket_compiles_with_kernel(lm_engine, one_chip,
                       np.int32(bucket - 1), eng._greedy_slot_ops), one_chip)
     text = eng._build_prefill(bucket).lower(*args, None).compile().as_text()
     assert text.count(KERNEL) == 4          # one masked flash per layer
+    assert sorts_only_under_a_conditional(text)
 
 
 def test_hybrid_decode_step_compiles_with_both_kinds_of_kernel(
@@ -471,11 +528,13 @@ def test_hybrid_decode_step_compiles_with_both_kinds_of_kernel(
     assert len(re.findall(r"%kv_append[.\d]* = ", text)) == 1
     assert relayouts(text, 16 * 128 * 1024) == []
     assert loops(text) == []
+    assert sorts_only_under_a_conditional(text)
     text = eng._build_prefill(128).lower(*_abstract(
         (net.params, net.states, eng.init_cache(), np.int32(0),
          np.zeros((128,), np.int32), np.int32(100), eng._greedy_slot_ops),
         one_chip), None).compile().as_text()
     assert text.count(KERNEL) == 1          # the attention layer's flash
+    assert sorts_only_under_a_conditional(text)
 
 
 @pytest.mark.slow
