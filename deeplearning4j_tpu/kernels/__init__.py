@@ -7,9 +7,10 @@ analog of the reference shipping cuDNN-specific kernels next to the generic
 path. Kernels run in interpret mode on CPU (tests) and compile via Mosaic on
 TPU.
 """
+from .expert_gmm import expert_gmm
 from .flash_attention import (flash_attention, flash_decode,
                               flash_decode_paged, kv_append)
 from .ssm_step import ssm_step
 
-__all__ = ["flash_attention", "flash_decode", "flash_decode_paged",
-           "kv_append", "ssm_step"]
+__all__ = ["expert_gmm", "flash_attention", "flash_decode",
+           "flash_decode_paged", "kv_append", "ssm_step"]

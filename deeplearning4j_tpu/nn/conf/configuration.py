@@ -47,7 +47,8 @@ def expected_input_kind(conf):
                          L.GlobalPoolingLayer, L.BatchNormalization,
                          L.LayerNormalization, L.RMSNormalization)):
         return "any"
-    if type(conf) in (L.DenseLayer, L.GatedDenseLayer):
+    if type(conf) in (L.DenseLayer, L.GatedDenseLayer,
+                      L.MixtureOfExpertsLayer):
         # Dense is time-distributed on [b, t, f] (no RnnToFeedForward needed)
         # and self-flattens rank-4 CNN input; only cnn_flat still reshapes
         return "any"

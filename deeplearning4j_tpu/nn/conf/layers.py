@@ -451,18 +451,31 @@ class DropoutLayer(_NoActivationConf):
 @register_layer_conf
 @dataclass
 class MixtureOfExpertsLayer(FeedForwardLayerConf):
-    """Mixture-of-experts feed-forward block — NEW capability beyond the
-    reference (no MoE exists at v0.7.3; SURVEY.md §2.4 lists expert
-    parallelism as absent upstream). Router: softmax top-k gating over
-    n_experts; each expert is a 2-layer FFN (n_in -> hidden -> n_out).
-    Compute is dense over the expert axis (every expert runs, gates weight
-    the mix) so the whole block is one einsum chain that GSPMD partitions
-    over a mesh axis when the expert-indexed weights are sharded
-    P("model", ...) — that sharding IS expert parallelism. Works on [b, f]
-    and time-distributed [b, t, f]."""
+    """Routed mixture-of-experts feed-forward block — NEW capability beyond
+    the reference (no MoE exists at v0.7.3; SURVEY.md §2.4 lists expert
+    parallelism as absent upstream). Router: the `top_k` largest of
+    `n_experts` logits (computed in float32), gates their softmax; out =
+    sum over the chosen experts of gate * expert(x). Only the chosen (token,
+    expert) pairs are computed: rows sorted by expert through a grouped
+    product (kernels/expert_gmm.py), no capacity, no dropped pair.
+
+    An expert is a 2-layer ReLU FFN with biases of width `hidden_mult *
+    n_out` or, with `gated`, (silu(a) * b) W2 with (a, b) = split(x W1) of
+    width `n_hidden`, no biases. The layer may be ONE CHIP'S SHARE of a
+    deployment: it routes over all `n_experts`, holds experts
+    `first_expert .. first_expert + experts_held - 1` (default: all) and
+    returns their part of the sum, the gates as published (not renormalised
+    over the held). Expert weights are expert-major [held, ...]: sharding
+    axis 0 over a mesh axis is expert parallelism. Works on [b, f] and
+    time-distributed [b, t, f]."""
     n_experts: int = 4
     hidden_mult: int = 2
-    top_k: int = 2  # gates outside top-k are zeroed (renormalized)
+    top_k: int = 2
+    gated: bool = False
+    n_hidden: int | None = None     # default: hidden_mult * n_out
+    experts_held: int | None = None
+    first_expert: int = 0
+    use_pallas: bool = False        # the gated form's grouped-product kernel
 
     def get_output_type(self, input_type):
         if isinstance(input_type, RecurrentInputType):

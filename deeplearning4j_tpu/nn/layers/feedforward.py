@@ -312,58 +312,139 @@ class RBMModule(_DenseCore):
         return jnp.mean(free_energy(v0) - free_energy(vk))
 
 
+def _note_experts(routed, held, top_k, weight_bytes):
+    """What an expert layer holds, as gauges in the process registry beside
+    `flash_decode_block` (set at trace time, so once per compiled program):
+    `moe_experts{routed,held,top_k}` and `moe_expert_weight_bytes`, the
+    bytes of one layer's held experts — what a step streams when every held
+    expert gets a row."""
+    from ...telemetry.registry import get_registry
+    reg = get_registry()
+    reg.gauge("moe_experts", "Experts an expert layer holds, of `routed` "
+              "the router chooses `top_k` a token from").set(
+                  held, routed=routed, held=held, top_k=top_k)
+    reg.gauge("moe_expert_weight_bytes",
+              "Bytes of the expert matrices one expert layer holds").set(
+                  weight_bytes)
+
+
 @register_impl("MixtureOfExpertsLayer")
 class MixtureOfExpertsLayerModule(BaseLayerModule):
-    """Dense mixture-of-experts FFN (conf: nn/conf/layers.py
-    MixtureOfExpertsLayer — NEW, no reference counterpart). Expert weights
-    are expert-major [E, ...]; sharding axis 0 over a mesh "model" axis
-    yields expert parallelism (GSPMD partitions the einsums and all-reduces
-    the gated mix)."""
+    """Routed mixture-of-experts FFN (conf: nn/conf/layers.py
+    MixtureOfExpertsLayer — NEW, no reference counterpart): router in
+    float32 -> top-k -> the (token, expert) pairs of the experts held,
+    sorted by expert into row tiles -> rows gathered -> grouped products
+    (kernels/expert_gmm.py) -> times gate, summed by token. One code path
+    for `forward`, a prefill and the decode step; buffers are sized for the
+    worst case (every pair held), the group sizes are data. Expert weights
+    are expert-major [held, ...]. The dense all-experts einsum this layer
+    once was lives on in tests/test_moe.py as its oracle."""
+    positionwise = True
+
+    def _sizes(self):
+        c = self.conf
+        E = int(c.n_experts)
+        held = E if c.experts_held is None else int(c.experts_held)
+        first = int(c.first_expert)
+        if not (0 < held and 0 <= first and first + held <= E):
+            raise ValueError(f"experts {first}..{first + held - 1} of {E}")
+        hidden = int(c.n_hidden) if c.n_hidden is not None \
+            else int(c.hidden_mult) * int(c.n_out)
+        return E, held, first, hidden, min(int(c.top_k), E)
 
     def init(self, rng, input_type, dtype=jnp.float32):
         c = self.conf
         n_in, n_out = int(c.n_in), int(c.n_out)
-        E = int(c.n_experts)
-        hidden = int(c.hidden_mult) * n_out
+        E, held, _, hidden, _ = self._sizes()
         k1, k2, k3 = jax.random.split(rng, 3)
         mk = lambda k, shape, fi, fo: init_weights(
             k, shape, c.weight_init, fan_in=fi, fan_out=fo,
             distribution=c.dist, dtype=dtype)
+        up = (2 if c.gated else 1) * hidden
         params = {
             "Wg": mk(k1, (n_in, E), n_in, E),              # router
-            "W1": mk(k2, (E, n_in, hidden), n_in, hidden),  # expert up-proj
-            "b1": jnp.zeros((E, hidden), dtype),
-            "W2": mk(k3, (E, hidden, n_out), hidden, n_out),
-            "b2": jnp.zeros((E, n_out), dtype),
+            "W1": mk(k2, (held, n_in, up), n_in, hidden),   # expert up-proj
+            "W2": mk(k3, (held, hidden, n_out), hidden, n_out),
         }
+        if not c.gated:
+            params["b1"] = jnp.zeros((held, hidden), dtype)
+            params["b2"] = jnp.zeros((held, n_out), dtype)
         from ..conf.inputs import RecurrentInputType
         out_t = (InputType.recurrent(n_out)
                  if isinstance(input_type, RecurrentInputType)
                  else InputType.feed_forward(n_out))
         return params, {}, out_t
 
+    def route(self, params, xt):
+        """xt [T, f] -> (experts [T, k] int32, gates [T, k] float32): the k
+        largest router logits, in float32, and their softmax."""
+        acc = jnp.promote_types(xt.dtype, jnp.float32)
+        r = jnp.dot(xt.astype(acc), params["Wg"].astype(acc),
+                    precision=jax.lax.Precision.HIGHEST)
+        top, experts = jax.lax.top_k(r, self._sizes()[4])
+        return experts, jax.nn.softmax(top, axis=-1)
+
+    def layout(self, params, xt):
+        """xt [T, f] -> where every (token, expert) pair of the experts held
+        goes: `here`, `gates` [T, k] (is the pair's expert held; its gate),
+        `row_of_pair` [T * k] (its row in the grouped product's operand),
+        and of that operand `token`, `live` [rows] (the token a row holds;
+        is it a pair's), `group` [rows], `tile_group`, `n_tiles`
+        (kernels/expert_gmm.py `group_tiles`) and `tm`. Rows are sized for
+        the worst case, every pair held: T * k // tm + held tiles."""
+        from ...kernels.expert_gmm import group_tiles, row_tile
+        _, held, first, _, k = self._sizes()
+        P = xt.shape[0] * k
+        tm = row_tile(P, int(self.conf.n_experts), xt.dtype.itemsize)
+        experts, gates = self.route(params, xt)
+        local = experts - first
+        here = (local >= 0) & (local < held)
+        key = jnp.where(here, local, held).reshape(P)
+        order = jnp.argsort(key, stable=True)        # sorted place -> pair
+        onehot = (key[:, None] == jnp.arange(held)[None]).astype(jnp.int32)
+        counts = jnp.sum(onehot, axis=0)
+        # a pair's rank among its expert's pairs: its place in the stable sort
+        rank = jnp.sum(onehot * (jnp.cumsum(onehot, axis=0) - 1), axis=1)
+        tile_group, n_tiles, tile_start = group_tiles(counts, tm,
+                                                      P // tm + held)
+        first_row = tile_start * tm
+        row = jnp.arange(tile_group.shape[0] * tm)
+        group = tile_group[row // tm]
+        at = row - first_row[group]
+        start = jnp.cumsum(counts) - counts
+        return {"here": here, "gates": gates, "tm": tm,
+                "row_of_pair": first_row[jnp.minimum(key, held - 1)] + rank,
+                "token": order[jnp.minimum(start[group] + at, P - 1)] // k,
+                "live": (row // tm < n_tiles) & (at < counts[group]),
+                "group": group, "tile_group": tile_group, "n_tiles": n_tiles}
+
     def forward(self, params, state, x, *, train=False, rng=None, mask=None):
+        from ...kernels.expert_gmm import expert_gmm, tile_rows
         c = self.conf
         x = apply_dropout(x, c.dropout, train, rng)
-        squeeze = x.ndim == 2
-        if squeeze:
-            x = x[:, None, :]                       # [b, 1, f]
-        E = int(c.n_experts)
-        k = min(int(c.top_k), E)
-        gates = jax.nn.softmax(x @ params["Wg"], axis=-1)   # [b, t, E]
-        if k < E:
-            # zero all but the top-k gates, renormalize (standard MoE)
-            thresh = jnp.sort(gates, axis=-1)[..., E - k][..., None]
-            gates = jnp.where(gates >= thresh, gates, 0.0)
-            gates = gates / jnp.maximum(
-                jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
-        h = jnp.einsum("btf,efh->beth", x, params["W1"]) \
-            + params["b1"][None, :, None, :]
-        h = jax.nn.relu(h)
-        y = jnp.einsum("beth,eho->beto", h, params["W2"]) \
-            + params["b2"][None, :, None, :]
-        out = jnp.einsum("bte,beto->bto", gates, y)
-        out = self.activation_fn()(out)
-        if squeeze:
-            out = out[:, 0, :]
+        E, held, _, _, k = self._sizes()
+        W1, W2 = params["W1"], params["W2"]
+        _note_experts(E, held, k, (W1.size + W2.size) * W1.dtype.itemsize)
+        xt = x.reshape(-1, x.shape[-1])
+        with jax.named_scope("moe_route"):
+            at = self.layout(params, xt)
+        with jax.named_scope("moe_dispatch"):
+            rows = jnp.where(at["live"][:, None], xt[at["token"]], 0)
+        with jax.named_scope("moe_experts"):
+            if c.gated:
+                out = expert_gmm(rows, W1, W2, at["tile_group"], at["n_tiles"],
+                                 use_pallas=bool(c.use_pallas),
+                                 tag="x".join(str(d) for d in x.shape[:-1]))
+            else:
+                sizes = tile_rows(at["tile_group"], at["n_tiles"], held,
+                                  at["tm"])
+                h = jax.nn.relu(jax.lax.ragged_dot(rows, W1, sizes)
+                                + params["b1"][at["group"]])
+                out = jax.lax.ragged_dot(h, W2, sizes) \
+                    + params["b2"][at["group"]]
+        with jax.named_scope("moe_combine"):
+            picked = out[at["row_of_pair"]].reshape(xt.shape[0], k, -1)
+            out = jnp.sum(jnp.where(at["here"][:, :, None], picked, 0)
+                          * at["gates"][:, :, None], axis=1).astype(x.dtype)
+        out = self.activation_fn()(out.reshape(*x.shape[:-1], -1))
         return out, state, mask

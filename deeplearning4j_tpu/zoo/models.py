@@ -234,14 +234,20 @@ def granite_hybrid_lm(vocab_size=256, d_model=64, n_layers=10, n_heads=4,
                       embedding_multiplier=1.0, attention_multiplier=None,
                       residual_multiplier=1.0, logits_scaling=1.0,
                       rms_norm_eps=1e-5, dtype="float32", seed=12345,
-                      use_pallas=False, updater=None):
+                      use_pallas=False, updater=None, n_experts=0,
+                      experts_per_token=0, expert_hidden=None,
+                      experts_held=None, first_expert=0):
     """Hybrid state-space / attention decoder of the `granitemoehybrid`
-    shape with no routed experts (ibm-granite/granite-4.0-h-*): pre-norm
-    blocks h += r * mixer(RMSNorm(h)); h += r * mlp(RMSNorm(h)), the mixer a
-    Mamba2Layer except at `attention_layers` (0-based), where it is causal
-    grouped-query attention without positional encoding, scores times
-    `attention_multiplier`; the mlp a gated SiLU feed-forward of width
-    d_model * ffn_mult; h_0 = embedding_multiplier * E[ids]; probabilities =
+    shape (ibm-granite/granite-4.0-h-*): pre-norm blocks h += r *
+    mixer(RMSNorm(h)); h += r * ffn(RMSNorm(h)), the mixer a Mamba2Layer
+    except at `attention_layers` (0-based), where it is causal grouped-query
+    attention without positional encoding, scores times
+    `attention_multiplier`; the ffn a gated SiLU feed-forward of width
+    int(d_model * ffn_mult) and, with `n_experts` > 0, beside it on the same
+    norm and added to it, `experts_per_token` of `n_experts` routed gated
+    experts of width `expert_hidden`, of which this model holds
+    `experts_held` from `first_expert` on (default: all; the rest of the sum
+    is another chip's); h_0 = embedding_multiplier * E[ids]; probabilities =
     softmax(RMSNorm(h) E^T / logits_scaling). Input one-hot [b, t, vocab].
 
     `dtype` is the parameters' and activations' dtype (the decode engine
@@ -250,7 +256,8 @@ def granite_hybrid_lm(vocab_size=256, d_model=64, n_layers=10, n_heads=4,
     both leaves ONE buffer; init() draws them apart. The default updater is
     plain SGD: it keeps no state beside the parameters."""
     from ..nn.conf.layers import (GatedDenseLayer, LMHeadLayer, Mamba2Layer,
-                                  RMSNormalization, SelfAttentionLayer)
+                                  MixtureOfExpertsLayer, RMSNormalization,
+                                  SelfAttentionLayer)
     if mamba_n_heads is None:
         mamba_n_heads = 2 * d_model // mamba_d_head
     gb = (NeuralNetConfiguration.builder()
@@ -286,9 +293,19 @@ def granite_hybrid_lm(vocab_size=256, d_model=64, n_layers=10, n_heads=4,
                 use_pallas=use_pallas), f"b{i}_norm1")
         prev = residual(f"b{i}_res1", prev, mixer)
         gb.add_layer(f"b{i}_norm2", norm(), prev)
-        gb.add_layer(f"b{i}_mlp", GatedDenseLayer(
-            n_out=d_model, n_hidden=d_model * ffn_mult), f"b{i}_norm2")
-        prev = residual(f"b{i}_res2", prev, f"b{i}_mlp")
+        ffn = f"b{i}_mlp"
+        gb.add_layer(ffn, GatedDenseLayer(
+            n_out=d_model, n_hidden=int(d_model * ffn_mult)), f"b{i}_norm2")
+        if n_experts:
+            gb.add_layer(f"b{i}_moe", MixtureOfExpertsLayer(
+                n_out=d_model, n_experts=n_experts, top_k=experts_per_token,
+                gated=True, n_hidden=expert_hidden, experts_held=experts_held,
+                first_expert=first_expert, use_pallas=use_pallas,
+                activation="identity"), f"b{i}_norm2")
+            ffn = f"b{i}_ffn"
+            gb.add_vertex(ffn, ElementWiseVertex("add"), f"b{i}_moe",
+                          f"b{i}_mlp")
+        prev = residual(f"b{i}_res2", prev, ffn)
     gb.add_layer("norm", norm(), prev)
     gb.add_layer("out", LMHeadLayer(n_out=vocab_size, activation="softmax",
                                     loss="MCXENT",

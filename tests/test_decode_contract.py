@@ -224,21 +224,29 @@ def test_mesh_cache_sharding_takes_the_axis(shape, axis):
     assert ctx.cache_sharding(shape).spec == P()
 
 
+@pytest.mark.parametrize("experts", [0, 6], ids=["shared_mlp", "routed"])
 @pytest.mark.parametrize("paged", [False, True], ids=["slab", "paged"])
-def test_a_model_of_both_entry_kinds_decodes_with_decode_untouched(paged):
+def test_a_model_of_both_entry_kinds_decodes_with_decode_untouched(paged,
+                                                                   experts):
     """What PR 31 proves of the contract: the hybrid decoder (Mamba-2 layers
     keeping a fixed-size state and a conv tail, grouped-query attention
     keeping K/V rows) came as layer files, and decodes — two slots joined at
     different steps, token for token the full forward — through an engine,
     a scheduler and a serving plane that name none of it. No answer had to
-    be added to BaseLayerModule."""
+    be added to BaseLayerModule. And what PR 33 proves (`routed`): a routed
+    expert layer in every block, between the two kinds of cache entry, that
+    sorts each step's rows by expert, is `positionwise` and nothing else —
+    it decodes with no edit under decode/ or serving/ (that PR's `git diff`
+    there is empty) and no new answer either."""
     from deeplearning4j_tpu.zoo.models import granite_hybrid_lm
     vocab = 40
     net = granite_hybrid_lm(
         vocab_size=vocab, d_model=32, n_layers=3, n_heads=4, n_kv_heads=2,
         attention_layers=(1,), mamba_d_head=8, mamba_d_state=16,
         mamba_chunk_size=8, embedding_multiplier=12, residual_multiplier=0.22,
-        attention_multiplier=0.125, logits_scaling=8, seed=11).init()
+        attention_multiplier=0.125, logits_scaling=8, seed=11,
+        n_experts=experts, experts_per_token=2, expert_hidden=8,
+        experts_held=experts // 2 or None, first_expert=experts // 3).init()
     eye = np.eye(vocab, dtype=np.float32)
 
     def naive(prompt, n):
@@ -281,7 +289,7 @@ def test_a_model_of_both_entry_kinds_decodes_with_decode_untouched(paged):
                  *(pkg / "serving").glob("*.py")]:
         text = path.read_text().lower()
         for word in ("mamba", "ssm_", "rmsnorm", "gateddense", "granite",
-                     "lmhead", "n_kv_heads"):
+                     "lmhead", "n_kv_heads", "expert", "moe_"):
             assert word not in text, (path.name, word)
 
 

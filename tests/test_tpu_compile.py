@@ -430,6 +430,48 @@ def test_ssm_step_compiles_in_place(on_chip):
     assert mem.temp_size_in_bytes < 1 << 20
 
 
+def test_flash_decode_at_head_dim_128_compiles_with_its_copy(on_chip):
+    """granite4_h_small's attention layer: 32 query heads over a bfloat16
+    cache of 8 K/V heads of 128, 32 slots of 1024. The kernel path (key
+    block 256), no counted fallback; at head_dim >= 128 the cache is
+    row-major, so the kernel's [S, H, D, C] operand is a transposing copy
+    of K and of V (67 MB each) — priced in PERF.md, not cured here."""
+    S, C, Hq, H, D = 32, 1024, 32, 8, 128
+    assert fa._decode_block(C, Hq, D, 2, 1024, False) == 256
+    text = compiled_text(
+        lambda q, k, v, n: flash_decode(q, k, v, n, scale=0.0078125,
+                                        interpret=False),
+        on_chip((S, 1, Hq, D), jnp.bfloat16),
+        on_chip((S, C, H, D), jnp.bfloat16),
+        on_chip((S, C, H, D), jnp.bfloat16), on_chip((S,), jnp.int32))
+    assert text.count(KERNEL) == 1
+    assert len(relayouts(text, S * C * H * D)) == 2
+
+
+@pytest.mark.parametrize("tokens,tm", [(32, 16), (256, 128), (2048, 512)],
+                         ids=["step", "prefill_256", "rows_2048"])
+def test_expert_gmm_compiles_at_the_cells_widths(on_chip, tokens, tm):
+    """One layer's grouped product of granite4_h_small: 18 held experts of
+    4096 -> 2 x 768 -> 4096 in bfloat16, rows for `tokens` x 10 pairs in
+    tiles of `tm`: ONE kernel named after its caller, the matrices (340 MB)
+    arguments it reads where they lie, no copy of them."""
+    from deeplearning4j_tpu.kernels.expert_gmm import expert_gmm, row_tile
+    assert row_tile(tokens * 10, 72, 2) == tm
+    held, d, hidden = 18, 4096, 768
+    tiles = tokens * 10 // tm + held
+    comp = jax.jit(lambda r, a, b, g, n: expert_gmm(
+        r, a, b, g, n[0], interpret=False, tag=f"{tokens}x1")).lower(
+            on_chip((tiles * tm, d), jnp.bfloat16),
+            on_chip((held, d, 2 * hidden), jnp.bfloat16),
+            on_chip((held, hidden, d), jnp.bfloat16),
+            on_chip((tiles,), jnp.int32), on_chip((1,), jnp.int32)).compile()
+    text = comp.as_text()
+    assert text.count(KERNEL) == 1
+    assert len(re.findall(rf"%expert_gmm_{tokens}x1[.\d]* = ", text)) == 1
+    assert relayouts(text, held * d * hidden) == []
+    assert comp.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 def test_untileable_shape_has_no_compiled_plan():
     """Why chip_smoke.py asks for prompts of 128 tokens and more: compiled,
     the key block must be a multiple of 128, so a 64-token prefill bucket or
@@ -535,6 +577,45 @@ def test_hybrid_decode_step_compiles_with_both_kinds_of_kernel(
         one_chip), None).compile().as_text()
     assert text.count(KERNEL) == 1          # the attention layer's flash
     assert sorts_only_under_a_conditional(text)
+
+
+def test_routed_decode_step_compiles_with_one_expert_kernel_a_layer(
+        one_chip, chip_config, monkeypatch):
+    """Two blocks (Mamba-2, attention) with routed experts beside the shared
+    one, at an eighth of granite4_h_small's widths, bfloat16, 16 slots of
+    256: a block's expert layer is ONE `expert_gmm_16x1` kernel in the step
+    and one `expert_gmm_1x128` in a prefill, and routing adds no loop (its
+    tile table is a compare and a sum, not a `searchsorted`). (The whole
+    10-layer step at the cell's size compiles here in 36 s with 7.47 GB of
+    arguments and 0.17 GB of temporaries: PERF.md section 4.)"""
+    from deeplearning4j_tpu.decode.engine import DecodeEngine
+    from deeplearning4j_tpu.zoo.models import granite_hybrid_lm
+    for module in ("flash_attention", "ssm_step", "expert_gmm"):
+        monkeypatch.setattr(
+            importlib.import_module("deeplearning4j_tpu.kernels." + module),
+            "_interpret_default", lambda: False)
+    net = granite_hybrid_lm(
+        vocab_size=512, d_model=512, n_layers=2, n_heads=8, n_kv_heads=2,
+        attention_layers=(1,), mamba_d_head=64, mamba_d_state=128,
+        embedding_multiplier=12, attention_multiplier=0.015625,
+        residual_multiplier=0.22, logits_scaling=16, ffn_mult=0.375,
+        n_experts=16, experts_per_token=4, expert_hidden=128, experts_held=4,
+        first_expert=4, dtype="bfloat16", use_pallas=True).init()
+    eng = DecodeEngine(net, slots=16, max_len=256)
+    args = _abstract((net.params, net.states, eng.init_cache(),
+                      np.zeros((eng.slots,), np.int32),
+                      eng._greedy_step_ops), one_chip)
+    text = eng._build_step().lower(*args, None).compile().as_text()
+    assert text.count(KERNEL) == 5
+    assert len(re.findall(r"%expert_gmm_16x1[.\d]* = ", text)) == 2
+    assert len(re.findall(r"%ssm_step[.\d]* = ", text)) == 1
+    assert loops(text) == []
+    text = eng._build_prefill(128).lower(*_abstract(
+        (net.params, net.states, eng.init_cache(), np.int32(0),
+         np.zeros((128,), np.int32), np.int32(100), eng._greedy_slot_ops),
+        one_chip), None).compile().as_text()
+    assert len(re.findall(r"%expert_gmm_1x128[.\d]* = ", text)) == 2
+    assert loops(text) == []
 
 
 @pytest.mark.slow
