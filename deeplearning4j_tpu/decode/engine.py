@@ -37,6 +37,15 @@ and the host reads [slots] int32 ids. The [slots, vocab] distribution comes
 back as the device array the program produced: `read_probs` is the host
 copy, for a caller that uses rows of it.
 
+Each leg is two phases, and each phase a method: `dispatch_step` /
+`dispatch_prefill` enqueue the program and return what it will produce,
+still on the device; `read_ids` is the host copy of a step's next ids. The
+next ids are one [slots] int32 vector that both programs write (the step
+all of it, the prefill its slot's entry) and the step takes as its ids, so a
+caller can dispatch step N + 1 from step N's ids before it has read them
+(decode/scheduler.py keeps one step in flight that way). `step` / `prefill`
+are the two phases in a row, for callers that want the token at once.
+
 The cache is a plain pytree ``{"lengths": int32[slots], "layers": {name:
 entry}}`` threaded functionally through the executables and DONATED, so
 steady state re-uses the cache buffers in place instead of allocating a
@@ -251,7 +260,6 @@ class DecodeEngine:
                 "decode_steps_total", "Decode steps by what their sampling "
                 "operands asked for: sampler=\"greedy\" (no slot with a "
                 "positive temperature: argmax only) or \"sampled\"")
-        self.last_step_s = 0.0      # dispatch + sync of step()
         # mesh-sharded decode (serving/mesh.py): a wrapped model carries the
         # serving MeshContext; the KV cache partitions its head axis over
         # the mesh model axis and the step/prefill executables pin the
@@ -386,7 +394,7 @@ class DecodeEngine:
         paged = self.paged
 
         def prefill_fn(params, states, cache, slot, ids, length, samp,
-                       table):
+                       table, next_ids):
             params = self.model._dequant_params(params)
             x0 = self._one_hot(ids[None, :])              # [1, L, V]
             valid = (jnp.arange(L, dtype=jnp.int32)
@@ -404,10 +412,11 @@ class DecodeEngine:
                          "layers": layers}
             with jax.named_scope("sample"):
                 nid = _sampling.sample_tokens(probs[None], samp)[0]
-            return new_cache, nid, probs
+            # the slot's entry of the vector the next step takes as its ids
+            return new_cache, nid, probs, next_ids.at[slot].set(nid)
 
         return jax.jit(prefill_fn, donate_argnums=(2,),
-                       **self._jit_sharding())
+                       **self._jit_sharding(n_repl=3))
 
     def _build_verify(self, W):
         def verify_fn(params, states, cache, slot, ids, start):
@@ -471,7 +480,9 @@ class DecodeEngine:
         call also captures the executable's XLA costs (from an abstract-arg
         snapshot taken BEFORE the donating call) and every Nth later call is
         wall-timed into the sampled dispatch_ms histogram (`sample=False`:
-        the caller times the call itself, as step() does)."""
+        the call is only enqueued and whoever reads its result hands the
+        wall to `observe_wall`, as step(), prefill() and the scheduler
+        do)."""
         cr = self.cost_registry
         if label in self._compiled:
             if sample and cr is not None and cr.dispatch_due(label):
@@ -495,9 +506,19 @@ class DecodeEngine:
         if cr is not None:
             cr.capture(label, fn, abs_args, family="decode",
                        samples=self._cost_samples(label))
-            cr.dispatch_due(label)
-            cr.observe_dispatch(label, ms)
+            if sample:
+                cr.dispatch_due(label)
+                cr.observe_dispatch(label, ms)
         return out
+
+    def observe_wall(self, label, ms):
+        """The wall of one execution of `label` as its caller measured it
+        (call to result on the host, or result to result in a loop that
+        keeps the device fed): every Nth is the cost plane's dispatch
+        sample."""
+        cr = self.cost_registry
+        if cr is not None and cr.dispatch_due(label):
+            cr.observe_dispatch(label, ms)
 
     def _cost_samples(self, label):
         """Tokens one execution of this executable serves — the per-token
@@ -563,11 +584,26 @@ class DecodeEngine:
     def _step_operands(self, sampling):
         return self._greedy_step_ops if sampling is None else sampling
 
-    def prefill(self, cache, slot, prompt_ids, sampling=None, step_index=0,
-                table=None):
-        """Run `prompt_ids` (python ints / 1-D array) into cache slot `slot`;
-        returns (cache, first generated id, last-position probs [vocab] as
-        the device array the program produced: `read_probs` for a host copy).
+    def _place_ids(self, ids):
+        """`ids` as the [slots] int32 device array the executables take and
+        return: a step's or a prefill's own output passes through, a host
+        vector is placed like one (replicated on a mesh), so either way a
+        call has the one signature its executable was compiled for."""
+        if getattr(ids, "sharding", None) is not None:
+            return ids
+        return jax.device_put(
+            np.asarray(ids, np.int32).reshape(self.slots),
+            None if self.mesh is None else self.mesh.cache_sharding(()))
+
+    def dispatch_prefill(self, cache, slot, prompt_ids, sampling=None,
+                         step_index=0, table=None, next_ids=None):
+        """Enqueue the prefill of `prompt_ids` (python ints / 1-D array) into
+        cache slot `slot` and return without waiting for it: (cache, first
+        generated id, last-position probs [vocab], next ids [slots]), all
+        still on the device. `next_ids` is the vector the next step takes
+        as its ids (a step's or another prefill's output; zeros when None):
+        it comes back with `slot`'s entry set to the first id, so a caller
+        that runs ahead of its reads hands it to `dispatch_step` as it is.
 
         `sampling`: a SamplerConfig (greedy when None); `step_index` is the
         fold_in counter of the emitted token — 0 on a fresh admission,
@@ -591,25 +627,49 @@ class DecodeEngine:
             samp = _sampling.slot_operands(sampling, step_index)
         if self.paged and table is None:
             table = self.full_table()
+        if next_ids is None:
+            next_ids = np.zeros((self.slots,), np.int32)
         with self._jit_lock:
             fn = self._prefill_fns.get(L)
             if fn is None:
                 fn = self._prefill_fns[L] = self._build_prefill(L)
-        cache, nid, probs = self._run(
+        return self._run(
             fn, f"decode_prefill:{L}", L, self.model.params,
             self.model.states, cache, np.int32(slot), padded, np.int32(n),
-            samp, table if self.paged else None)
-        return cache, int(nid), probs
+            samp, table if self.paged else None, self._place_ids(next_ids),
+            sample=False)
 
-    def step(self, cache, last_ids, sampling=None, table=None):
-        """Advance every slot one token. `last_ids`: [slots] int token ids
-        (inactive slots may carry any id; their outputs are ignored and their
-        cache rows are reset by the next prefill). `sampling`: the operand
-        dict from sampling.batch_operands (greedy when None — per-request
-        sampling params are ARRAY operands here, never jit keys). Returns
-        (cache, next_ids [slots] np.int32, probs [slots, vocab] still on
-        the device: `read_probs` for a host copy of the rows a caller uses)."""
-        ids = np.asarray(last_ids, np.int32).reshape(self.slots)
+    def prefill(self, cache, slot, prompt_ids, sampling=None, step_index=0,
+                table=None):
+        """`dispatch_prefill` and the read of its first id, in a row — the
+        synchronous prefill of `generate`, the speculative decoder and the
+        warm-up. Returns (cache, first generated id as an int, last-position
+        probs [vocab] as the device array the program produced: `read_probs`
+        for a host copy)."""
+        t0 = monotonic_s()
+        cache, nid, probs, _ = self.dispatch_prefill(
+            cache, slot, prompt_ids, sampling=sampling,
+            step_index=step_index, table=table)
+        nid = int(nid)
+        self.observe_wall(
+            f"decode_prefill:{self.prefill_bucket(len(prompt_ids))}",
+            (monotonic_s() - t0) * 1000.0)
+        return cache, nid, probs
+
+    def dispatch_step(self, cache, last_ids, sampling=None, table=None):
+        """Enqueue one step (every slot advances one token) and return
+        without waiting for it: (cache, next ids [slots] int32, probs
+        [slots, vocab]), all still on the device. `last_ids`: [slots] token
+        ids, on the host or — a previous `dispatch_step`'s or
+        `dispatch_prefill`'s next ids — on the device, which is how a loop
+        dispatches step N + 1 before it has read step N (inactive slots may
+        carry any id; their outputs are ignored and their cache rows are
+        reset by the next prefill). `sampling`: the operand dict from
+        sampling.batch_operands (greedy when None — per-request sampling
+        params are ARRAY operands here, never jit keys). The ids are not
+        donated: `read_ids` gives their host copy before or after they have
+        been another call's operand. On a mesh the call waits for the
+        device inside the run lock (`_run`), so nothing is ever ahead."""
         self._ensure_placed()
         if self.paged and table is None:
             table = self.full_table()
@@ -619,30 +679,41 @@ class DecodeEngine:
             fn = self._step_fn
         label = "decode_step"
         warm = label in self._compiled
-        cr = self.cost_registry
-        phase = self.tracer.phase
         samp = self._step_operands(sampling)
-        with phase("decode_step_dispatch", histogram=self._m_dispatch,
-                   fold=True) as dispatch:
-            cache, nxt, probs = self._run(
+        with self.tracer.phase("decode_step_dispatch",
+                               histogram=self._m_dispatch,
+                               fold=True) as dispatch:
+            out = self._run(
                 fn, label, "step", self.model.params, self.model.states,
-                cache, ids, samp,
+                cache, self._place_ids(last_ids), samp,
                 table if self.paged else None, sample=False)
             if not warm:            # the compile: _timed has accounted it
                 dispatch.cancel()
-        with phase("decode_step_sync", histogram=self._m_sync,
-                   fold=True) as sync:
-            nxt = np.asarray(nxt)
         if self._m_steps is not None:
             # the same question the traced conditional asks of the operand
             self._m_steps.inc(1, sampler="sampled" if np.any(
                 samp["temperature"] > 0) else "greedy")
-        step_ms = dispatch.duration_ms + sync.duration_ms
-        self.last_step_s = step_ms / 1000.0
-        if warm and cr is not None and cr.dispatch_due(label):
-            # every Nth step's wall (call + wait for the ids) is the cost
-            # plane's dispatch sample: the wait above is the sync it needs
-            cr.observe_dispatch(label, step_ms)
+        return out
+
+    def read_ids(self, next_ids):
+        """Host copy ([slots] np.int32) of a dispatched step's next ids: the
+        one wait for the device of a step, the phase `decode_step_sync`."""
+        with self.tracer.phase("decode_step_sync", histogram=self._m_sync,
+                               fold=True):
+            return np.asarray(next_ids)
+
+    def step(self, cache, last_ids, sampling=None, table=None):
+        """`dispatch_step` and `read_ids` in a row: the synchronous step of
+        `generate`, the speculative decoder and the warm-up. Returns (cache,
+        next_ids [slots] np.int32, probs [slots, vocab] still on the device:
+        `read_probs` for a host copy of the rows a caller uses)."""
+        t0 = monotonic_s()
+        cache, nxt, probs = self.dispatch_step(cache, last_ids,
+                                               sampling=sampling, table=table)
+        nxt = self.read_ids(nxt)
+        # the wall of the call and of the wait for the ids is the cost
+        # plane's dispatch sample
+        self.observe_wall("decode_step", (monotonic_s() - t0) * 1000.0)
         return cache, nxt, probs
 
     def read_probs(self, probs):

@@ -2,30 +2,73 @@
 
 One scheduler thread owns the engine, the live cache, and the slot
 lifecycle; HTTP handler threads only touch the bounded admission queue.
-Every loop iteration:
+The loop runs ONE STEP AHEAD of what it has read. The step program returns
+the next ids as a [slots] int32 device array and takes its ids as one, and
+the prefill program sets its slot's entry of that same vector, so the ids
+of step N + 1 never pass through the host. With step N in flight, a pass
+(`_pass`) does, in this order:
 
-1. **admit** — free slots are filled from the queue (expired requests fail
-   with DeadlineExceeded instead of burning a prefill). Each admission runs
-   one prefill executable (compiled per pow2 prompt-length bucket) which
-   also emits the request's FIRST token — time-to-first-token is observed
-   on `decode_ttft_ms` with the request's trace id as exemplar.
-2. **step** — one fixed-shape decode step advances EVERY active slot one
-   token; the wall time is each active request's inter-token latency
-   (`decode_itl_ms`). Requests retire per token (max_new_tokens reached,
-   stop id emitted, cache capacity hit, or the per-token deadline budget
-   spent — a deadline mid-generation returns the PARTIAL result with
-   finish_reason="deadline", not an error).
+1. **dispatch step N + 1** — for every active slot with budget and room
+   left, from the vector step N and the prefills since have left on the
+   device. Who rides, the sampler's step indexes and, paged, the block each
+   rider appends to follow from `GenerateRequest.scheduled`, the count of
+   tokens dispatched for the request (read or not); no token value is
+   needed. The device goes from step N straight on to N + 1.
+2. **read step N's ids** — the pass's one wait for a step — and, under
+   step N + 1, append them, retire (max_new_tokens reached, stop id emitted,
+   cache capacity hit, or the per-token deadline budget spent — a deadline
+   mid-generation returns the PARTIAL result with finish_reason="deadline",
+   not an error) and complete futures. Then the first token of each prefill
+   the last pass dispatched (behind step N on the device, so read after
+   it): `decode_ttft_ms` is taken when it reaches the host, with the
+   request's trace id as exemplar.
+3. **admit** — free slots are filled from the queue (expired requests fail
+   with DeadlineExceeded instead of burning a prefill). Each admission
+   enqueues one prefill executable (compiled per pow2 prompt-length
+   bucket) behind step N + 1 — the donated cache orders them — and does
+   not wait for it: its first token joins the ids of step N + 2.
 
-Every pass is one `decode_wave` phase (telemetry/trace.py `Tracer.phase`:
-profiler annotation "dl4j:<name>" + histogram `<name>_ms` + ring span) whose
-parts fold into it: `decode_admit` (a pass that admitted something; holds
-the per-request `decode_queue_wait` and `decode_prefill`),
-`decode_step_build` (host work before the dispatch), the engine's
-`decode_step_dispatch` / `decode_step_sync`, and `decode_emit` (tokens
-appended, retirements, futures completed). The ring gets one span a pass
-with its parts' durations as attributes; `decode_itl_ms` is the sum of the
-engine's two, from the same clock reads. (The step's probabilities stay on
-the device: this loop never reads them.)
+Never more than one step is unread: step N + 2 is not dispatched before
+step N's ids are on the host. What follows from running ahead:
+
+- An end by length or by capacity is known by count before the dispatch:
+  the slot is left out of the next step, and the request that takes it
+  over joins the step after. An end that needs a token's value (stop id, a
+  deadline that has passed, `abandon` between a dispatch and its read) is
+  found out one step late: the slot was stepped once more, and that token
+  is thrown away (`decode_discarded_slot_steps_total`) — never appended,
+  never in `decode_tokens_total`, never in a response. The slot's row and
+  recurrent state are whatever the next prefill overwrites, as an idle
+  slot's are.
+- The unread step and first tokens belong to their cache generation:
+  `_fail_all`, a failed prefill and a cache re-init drop them, a hot-swap
+  reads the step (all its riders have ended) before the cache goes, and
+  the loop exits only when nothing is unread.
+- On a serving mesh the engine's dispatch waits for the device inside the
+  mesh's run lock, so nothing could run under the host's work: the pass
+  reads the step it has just dispatched, and every step counts
+  `decode_steps_ahead_total{ahead="0"}`, as does the first step after idle,
+  a hot-swap or a failure. A loop with nothing active dispatches nothing.
+
+Every pass that dispatched, read or admitted is one `decode_wave` phase
+(telemetry/trace.py `Tracer.phase`: profiler annotation "dl4j:<name>" +
+histogram `<name>_ms` + ring span) whose parts fold into it, all of them
+host time: `decode_step_build` (who rides, paged growth, sampling
+operands), the engine's `decode_step_dispatch` (step N + 1 enqueued) and
+`decode_step_sync` (the wait for step N's ids: with the device kept fed,
+what is left of step N after the host's own work), `decode_prefill_sync`
+(the wait for a first token), `decode_emit` (tokens appended, retirements,
+futures completed — after either read) and `decode_admit` (a pass that
+admitted something; holds the per-request `decode_queue_wait` and
+`decode_prefill`, which now spans the prompt's placement and the enqueue,
+not the program's run). The ring gets one span a pass with its parts'
+durations as attributes. `decode_itl_ms` is, per rider of a step, the wall
+from the previous result's arrival on the host (a step's ids or a first
+token; this step's own dispatch when that came later, as into a drained
+loop) to this step's ids: in steady state the interval between two reads,
+a prefill queued between two steps left out as it was when admission
+waited for it. (The step's probabilities stay on the device: this loop
+never reads them.)
 
 Requests therefore join and leave the in-flight batch per token with zero
 steady-state recompiles: after the step executable and a prompt-length
@@ -57,14 +100,16 @@ YOUNGEST active slot is preempted: its blocks free immediately, the
 request re-queues at the FRONT with its partial tokens, and on re-admission
 it re-prefills prompt+partial in one bucket pass whose sampling step index
 continues the seeded stream exactly (the preemption is invisible in the
-token stream). Deadline-expired and preempted slots retire through the
-same `_release_slot` path, so slot ids, pool blocks, and the active_slots
+token stream: a slot preempted with a step in flight loses that step's
+token, and the re-prefill emits it again). Deadline-expired and preempted
+slots retire through the same `_release_slot` path, so slot ids, pool blocks, and the active_slots
 gauge can never leak however a request leaves its slot.
 """
 from __future__ import annotations
 
 import collections
 import threading
+from typing import Any, NamedTuple
 
 from concurrent.futures import Future, TimeoutError as FuturesTimeoutError
 
@@ -81,7 +126,7 @@ class GenerateRequest:
     __slots__ = ("prompt", "max_new_tokens", "stop_id", "future", "deadline",
                  "enqueued_at", "trace_ctx", "tokens", "slot", "version",
                  "ttft_ms", "queue_wait_ms", "finish_reason", "sampler",
-                 "admit_seq")
+                 "admit_seq", "scheduled")
 
     def __init__(self, prompt, max_new_tokens, stop_id=None, deadline=None,
                  sampler=None):
@@ -100,6 +145,9 @@ class GenerateRequest:
         self.finish_reason = None
         self.sampler = sampler            # SamplerConfig or None (greedy)
         self.admit_seq = None             # admission order; youngest preempts
+        # tokens the device has been asked for: len(tokens) + those of the
+        # prefill or step the loop has dispatched and not read yet
+        self.scheduled = 0
 
     def expired(self, now=None):
         return self.deadline is not None and \
@@ -117,6 +165,23 @@ class GenerateRequest:
 
     def fail(self, exc):
         safe_set_exception(self.future, exc)
+
+
+class _Flight(NamedTuple):
+    """A dispatched step whose ids the host has not read."""
+    ids: Any                # [slots] int32, on the device
+    riders: list            # [(slot, request)] as of the dispatch
+    ahead: bool             # dispatched while another step was unread
+    dispatched_at: float
+
+
+class _First(NamedTuple):
+    """A dispatched prefill whose first token the host has not read."""
+    slot: int
+    request: GenerateRequest
+    nid: Any                # int32 scalar, on the device
+    bucket: int
+    dispatched_at: float
 
 
 class DecodeScheduler:
@@ -159,6 +224,14 @@ class DecodeScheduler:
         self._free = list(range(self.slots))
         self._observed_buckets = set()
         self._admit_seq = 0
+        # what the device has been handed and the host has not read (module
+        # docstring): the [slots] vector of next ids both programs write,
+        # the step in flight, the prefills whose first token is pending,
+        # and when the last result reached the host
+        self._ids = None
+        self._flight = None                         # _Flight
+        self._firsts = []                           # [_First]
+        self._last_read = 0.0
         # paged-mode allocator state (loop-thread-owned, rebuilt with the
         # cache each generation)
         self._pool = None                           # BlockPool
@@ -185,14 +258,25 @@ class DecodeScheduler:
             "decode_ttft_ms", "Time to first token (admission to first "
             "token), ms")
         self.m_itl = reg.histogram(
-            "decode_itl_ms", "Inter-token latency (one decode step), ms")
+            "decode_itl_ms", "Inter-token latency, per active slot a step: "
+            "from the previous result reaching the host (or this step's "
+            "dispatch, into a drained loop) to this step's ids, ms")
+        self.m_ahead = reg.counter(
+            "decode_steps_ahead_total", "Decode steps read, by whether they "
+            "were dispatched while another step was still unread "
+            "(ahead=\"1\") or into a drained loop (\"0\": first step after "
+            "idle, a hot-swap or a failure; on a mesh every step)")
+        self.m_discarded = reg.counter(
+            "decode_discarded_slot_steps_total", "Slot-steps whose token "
+            "was thrown away: the request had ended (stop id, deadline, "
+            "abandon) or been preempted while the step was in flight")
         self.m_tps = reg.gauge("decode_tokens_per_sec",
                                "Decode throughput over the last step wave")
         # one unlabelled histogram per phase of the loop (module docstring);
         # the engine registers its three on the same registry
         self.m_wave = reg.histogram(
-            "decode_wave_ms", "One scheduler pass (admit + step wave) that "
-            "prefilled or stepped, ms")
+            "decode_wave_ms", "One scheduler pass (a step dispatched, the "
+            "last one's results read, admission) that did any of it, ms")
         self.m_admit = reg.histogram(
             "decode_admit_ms", "Admission of a pass that admitted something "
             "(queue pops, slot and block bookkeeping, prefills), ms")
@@ -200,13 +284,17 @@ class DecodeScheduler:
             "decode_queue_wait_ms", "Enqueue to popped with a free slot, "
             "per request, ms")
         self.m_prefill = reg.histogram(
-            "decode_prefill_ms", "One synchronous engine.prefill call, ms")
+            "decode_prefill_ms", "One engine.dispatch_prefill call (the "
+            "prompt placed and the program enqueued, not run), ms")
+        self.m_prefill_sync = reg.histogram(
+            "decode_prefill_sync_ms", "Host read of a prefill's first "
+            "token, a pass after its dispatch: the wait for the device, ms")
         self.m_step_build = reg.histogram(
-            "decode_step_build_ms", "Host work of a step wave before the "
-            "dispatch (paged growth, ids, sampling operands), ms")
+            "decode_step_build_ms", "Host work of a step before the "
+            "dispatch (who rides, paged growth, sampling operands), ms")
         self.m_emit = reg.histogram(
-            "decode_emit_ms", "Host work after a step's result (tokens "
-            "appended, retirements, futures completed), ms")
+            "decode_emit_ms", "Host work after a step's or a prefill's "
+            "result (tokens appended, retirements, futures completed), ms")
         reg.gauge("decode_active_slots", "In-flight generate requests",
                   fn=lambda: float(self.active_count()))
         reg.gauge("decode_kv_live_pct",
@@ -229,7 +317,8 @@ class DecodeScheduler:
                   "the slab layout serves)",
                   fn=lambda: self.pool_utilization())
         for c in (self.m_requests, self.m_tokens, self.m_shed,
-                  self.m_expired, self.m_errors, self.m_preempted):
+                  self.m_expired, self.m_errors, self.m_preempted,
+                  self.m_discarded):
             c.inc(0)
 
     # ------------------------------------------------------------ admission
@@ -443,20 +532,56 @@ class DecodeScheduler:
         while True:
             with self._work:
                 while not self._queue and not self._active \
-                        and not self._closed:
+                        and self._flight is None and not self._closed:
                     self._work.wait(self.idle_wait_s)
-                if self._closed and not self._queue and not self._active:
+                if self._closed and not self._queue and not self._active \
+                        and self._flight is None:
                     return
             with self.tracer.phase("decode_wave",
                                    histogram=self.m_wave) as wave:
                 try:
-                    admitted = self._admit()
-                    stepped = self._step_wave()
+                    worked = self._pass()
                 except Exception as e:      # last resort: the loop survives
                     self._fail_all(e)
-                    admitted = stepped = True
-                if not (admitted or stepped):
+                    worked = True
+                if not worked:
                     wave.cancel()
+
+    def _pass(self):
+        """One turn of the loop (module docstring): dispatch the next step
+        from ids that are still on the device, then read what the last pass
+        left there, retire and admit while the device runs. Returns whether
+        anything was dispatched, read or admitted."""
+        prev = self._flight
+        cur = self._dispatch_step(ahead=prev is not None)
+        worked = prev is not None or cur is not None
+        if prev is not None:
+            self._emit_step(prev)
+        worked = self._emit_firsts() > 0 or worked
+        if cur is not None and self._engine.mesh is not None:
+            # the mesh's dispatch has waited for the device inside its run
+            # lock: there is nothing to run under, and nothing is ahead
+            self._emit_step(cur)
+            cur = None
+        self._flight = cur
+        return self._admit() > 0 or worked
+
+    def _drop_unread(self):
+        """What is unread dies with the cache it was computed from: nothing
+        reads ids of a cache that is gone."""
+        self._ids = None
+        self._flight = None
+        self._firsts = []
+
+    def _drop_cache(self):
+        """The end of a cache generation that cannot be stepped again: the
+        allocator and whatever is unread die with it, and the next admission
+        starts a fresh one."""
+        self._cache = None
+        self._drop_unread()
+        self._pool = None
+        self._table = None
+        self._slot_blocks = {}
 
     def _fail_all(self, exc):
         self.m_errors.add(len(self._active))
@@ -464,10 +589,7 @@ class DecodeScheduler:
             r.fail(exc)
             self._free.append(slot)
         self._active.clear()
-        self._cache = None                  # poisoned (possibly donated away)
-        self._pool = None                   # allocator dies with its cache
-        self._table = None
-        self._slot_blocks = {}
+        self._drop_cache()                  # poisoned (possibly donated away)
         if self.logger is not None:
             self.logger.error("decode_wave_failed",
                               error=f"{type(exc).__name__}: {exc}")
@@ -509,6 +631,12 @@ class DecodeScheduler:
                 or self._engine.model is not entry.model:
             if self._active:
                 return                      # drain first, swap next wave
+            if self._flight is not None:
+                # every rider of the step in flight has ended: its ids
+                # belong to the old engine's cache and are read (and
+                # counted as discarded) before that cache goes
+                flight, self._flight = self._flight, None
+                self._emit_step(flight)
             try:
                 self._engine = self.engine_for(entry.model)
             except Exception as e:
@@ -527,10 +655,10 @@ class DecodeScheduler:
                     self.m_errors.add(1)
                     r.fail(e)
             self._version = entry.version
-            self._cache = self._engine.init_cache()
-            self._reset_pool()
+            self._cache = None
         if self._cache is None:
             self._cache = self._engine.init_cache()
+            self._drop_unread()
             self._reset_pool()
         while self._free:
             r = self._pop_queued()
@@ -579,7 +707,7 @@ class DecodeScheduler:
             r.slot, r.version = slot, self._version
             r.admit_seq = self._admit_seq
             self._admit_seq += 1
-            if r.ttft_ms is None:       # first admission: `now` is the pop
+            if r.queue_wait_ms is None:  # first admission: `now` is the pop
                 r.queue_wait_ms = (now - r.enqueued_at) * 1000.0
                 self.tracer.record_span(
                     "decode_queue_wait", r.enqueued_at, now,
@@ -596,12 +724,16 @@ class DecodeScheduler:
             with self.tracer.phase("decode_prefill",
                                    histogram=self.m_prefill,
                                    parent=r.trace_ctx, slot=slot,
-                                   bucket=bucket, n_prompt=len(ctx)):
+                                   bucket=bucket, n_prompt=len(ctx)) as ph:
                 try:
-                    self._cache, nid, _ = self._engine.prefill(
-                        self._cache, slot, ctx, sampling=r.sampler,
-                        step_index=len(r.tokens),
-                        table=self._table if self.paged else None)
+                    # queued behind the step in flight through the donated
+                    # cache; the first token stays on the device as this
+                    # slot's entry of the next step's ids
+                    self._cache, nid, _, self._ids = \
+                        self._engine.dispatch_prefill(
+                            self._cache, slot, ctx, sampling=r.sampler,
+                            step_index=len(r.tokens),
+                            table=self._table_operand(), next_ids=self._ids)
                 except Exception as e:
                     self.m_errors.add(1)
                     r.fail(e)
@@ -620,21 +752,44 @@ class DecodeScheduler:
                             "co-batched KV cache lost to a failed prefill: "
                             f"{type(e).__name__}: {e}"))
                     else:
-                        self._cache = None
-                        self._pool = None
-                        self._table = None
-                        self._slot_blocks = {}
+                        self._drop_cache()
                     return
-            now = monotonic_s()
-            if r.ttft_ms is None:       # first admission only — a re-
-                r.ttft_ms = (now - r.enqueued_at) * 1000.0   # admission is
-                self.m_ttft.observe(r.ttft_ms,       # not a second "first
-                                    trace_id=getattr(r.trace_ctx,  # token"
-                                                     "trace_id", None))
-            r.tokens.append(int(nid))
-            self.m_tokens.add(1)
+            r.scheduled = len(r.tokens) + 1
             self._active[slot] = r
-            self._maybe_retire(slot, now)
+            self._firsts.append(_First(slot, r, nid, bucket, ph.start_mono))
+
+    def _emit_firsts(self):
+        """Read the first token of every prefill the last pass dispatched
+        (in their order on the device) and do with it what admission did
+        when it waited for it: `ttft_ms`, the token, a retirement by a
+        budget of one or a stop id. Returns how many were read."""
+        firsts, self._firsts = self._firsts, []
+        n = 0
+        for f in firsts:
+            r = f.request
+            if self._active.get(f.slot) is not r:
+                continue        # preempted before its first token was read
+            with self.tracer.phase("decode_prefill_sync",
+                                   histogram=self.m_prefill_sync,
+                                   fold=True):
+                nid = int(f.nid)
+            now = monotonic_s()
+            self._engine.observe_wall(
+                f"decode_prefill:{f.bucket}",
+                (now - max(f.dispatched_at, self._last_read)) * 1000.0)
+            self._last_read = now
+            n += 1
+            with self.tracer.phase("decode_emit", histogram=self.m_emit,
+                                   fold=True):
+                if r.ttft_ms is None:   # first admission only — a re-
+                    r.ttft_ms = (now - r.enqueued_at) * 1000.0  # admission
+                    self.m_ttft.observe(        # is not a second "first
+                        r.ttft_ms, trace_id=getattr(    # token"
+                            r.trace_ctx, "trace_id", None))
+                r.tokens.append(nid)
+                self.m_tokens.add(1)
+                self._maybe_retire(f.slot, now)
+        return n
 
     # --------------------------------------------------------- paged alloc
     def _reset_pool(self):
@@ -657,8 +812,9 @@ class DecodeScheduler:
         oversubscription watermark). Returns False when `slot` itself was
         the youngest and lost its own blocks."""
         r = self._active[slot]
-        # cache holds prompt + tokens[:-1]; the step appends tokens[-1]
-        need = blocks_for(len(r.prompt) + len(r.tokens), self.block_size)
+        # when the step runs the cache holds the prompt and all but the last
+        # of the `scheduled` tokens; the step appends that last one
+        need = blocks_for(len(r.prompt) + r.scheduled, self.block_size)
         row = self._slot_blocks[slot]
         while len(row) < need:
             try:
@@ -675,9 +831,11 @@ class DecodeScheduler:
         return True
 
     def _preempt(self, slot):
-        """Reclaim a slot's blocks mid-flight: the request keeps its tokens
-        and re-queues at the FRONT (it was admitted before anything queued
-        behind it); re-admission re-prefills prompt+partial."""
+        """Reclaim a slot's blocks mid-flight: the request keeps the tokens
+        the host has read and re-queues at the FRONT (it was admitted
+        before anything queued behind it); re-admission re-prefills
+        prompt+partial, which emits the token of the step in flight again
+        (that step's own is discarded when it is read)."""
         r = self._active.pop(slot)
         self._release_slot(slot)
         self.m_preempted.add(1)
@@ -689,55 +847,86 @@ class DecodeScheduler:
                              pool_free=self._pool.free_blocks)
 
     # ------------------------------------------------------------ stepping
-    def _step_wave(self):
-        """One decode step for every active slot; returns whether it
-        stepped."""
+    def _table_operand(self):
+        """The block table as an operand of a program that runs after this
+        call returns: a copy, because the loop goes on writing the table
+        and a dispatched program may read its host operands late."""
+        return self._table.copy() if self.paged else None
+
+    def _dispatch_step(self, ahead):
+        """Enqueue one decode step for every active slot with budget and
+        room left, its ids the vector the last step and the prefills since
+        have left on the device. Who rides, the block each rider appends to
+        and the sampler's step indexes follow from counts the host holds;
+        no token value is needed. Returns the _Flight, or None when no slot
+        rides."""
         if not self._active:
-            return False
-        import numpy as np
+            return None
         with self.tracer.phase("decode_step_build",
                                histogram=self.m_step_build, fold=True):
+            # an end by length or by capacity is known before the dispatch
+            # (the last token is in flight): that slot is stepped no more.
+            # An end that needs the token's value is found out a step late.
+            riders = [s for s, r in self._active.items()
+                      if r.scheduled < r.max_new_tokens
+                      and len(r.prompt) + r.scheduled < self.max_len]
             if self.paged:
                 # oldest-first: seniority keeps its blocks, the youngest
                 # pays
-                for slot in sorted(self._active,
-                                   key=lambda s: self._active[s].admit_seq):
+                riders.sort(key=lambda s: self._active[s].admit_seq)
+                for slot in riders:
                     if slot in self._active:    # not preempted as a victim
                         self._grow(slot)
-                if not self._active:
-                    return False
-            ids = np.zeros((self.slots,), np.int32)
-            any_sampled = False
-            for slot, r in self._active.items():
-                ids[slot] = r.tokens[-1]
-                any_sampled = any_sampled or r.sampler is not None
+                riders = [s for s in riders if s in self._active]
+            if not riders:
+                return None
             samp = None
-            if any_sampled:
+            if any(self._active[s].sampler is not None for s in riders):
                 # per-slot sampling params + fold_in step indexes as ARRAY
                 # operands — swinging every request never recompiles (GL016)
                 samp = batch_operands(
                     self.slots,
-                    {s: r.sampler for s, r in self._active.items()},
-                    {s: len(r.tokens) for s, r in self._active.items()})
-        self._cache, nxt, _ = self._engine.step(
-            self._cache, ids, sampling=samp,
-            table=self._table if self.paged else None)
-        # the engine's own dispatch + sync clock reads: the old histogram
-        # and the phases' cannot drift apart
-        wall = self._engine.last_step_s
+                    {s: self._active[s].sampler for s in riders},
+                    {s: self._active[s].scheduled for s in riders})
+            table = self._table_operand()
+        t0 = monotonic_s()
+        self._cache, self._ids, _ = self._engine.dispatch_step(
+            self._cache, self._ids, sampling=samp, table=table)
+        riders = [(slot, self._active[slot]) for slot in riders]
+        for _, r in riders:
+            r.scheduled += 1
+        return _Flight(self._ids, riders, ahead, t0)
+
+    def _emit_step(self, flight):
+        """Read a dispatched step's ids — the pass's one wait for a step —
+        and append, retire and complete with them. A rider that has left
+        its slot since the dispatch (ended by a token's value or a deadline
+        one step earlier, or preempted) has its token thrown away."""
+        nxt = self._engine.read_ids(flight.ids)
+        now = monotonic_s()
+        # from the previous result reaching the host when the device was
+        # kept fed, from this step's own dispatch otherwise: never a span
+        # in which the device ran something else for the whole of it
+        wall = now - max(flight.dispatched_at, self._last_read)
+        self._last_read = now
+        self._engine.observe_wall("decode_step", wall * 1000.0)
+        self.m_ahead.inc(1, ahead="1" if flight.ahead else "0")
         with self.tracer.phase("decode_emit", histogram=self.m_emit,
                                fold=True):
-            n_active = len(self._active)
-            self.m_tps.set(n_active / max(wall, 1e-9))
-            now = monotonic_s()
-            for slot, r in list(self._active.items()):
+            self.m_tps.set(len(flight.riders) / max(wall, 1e-9))
+            for slot, r in flight.riders:
+                # a request leaves its slot before a read and takes one
+                # again only after it (`_admit` ends the pass): the slot
+                # still holding it is the admission that was dispatched
+                if self._active.get(slot) is not r:
+                    self.m_discarded.add(1)
+                    continue
                 r.tokens.append(int(nxt[slot]))
                 self.m_tokens.add(1)
                 self.m_itl.observe(wall * 1000.0,
                                    trace_id=getattr(r.trace_ctx, "trace_id",
                                                     None))
                 self._maybe_retire(slot, now)
-        return True
 
     # ----------------------------------------------------------- retiring
     def _release_slot(self, slot):

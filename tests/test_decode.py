@@ -268,10 +268,11 @@ def test_scheduler_shed_expiry_and_stop_token():
 
 def test_kv_live_gauge_and_key_block_record():
     """`decode_kv_live_pct`: the positions the active requests have filled
-    (prompt + tokens emitted, what the next step's attention reads) over
-    slots x capacity, from the host's own bookkeeping; 0 again once they
-    finish. `flash_decode_block{C,H,D,itemsize}`: the key block the kernel
-    chose for the engine's shapes. Driven synchronously (no loop thread)."""
+    (prompt + tokens the host has read: the loop keeps one step in flight,
+    so the device is a token ahead of it) over slots x capacity, from the
+    host's own bookkeeping; 0 again once they finish.
+    `flash_decode_block{C,H,D,itemsize}`: the key block the kernel chose for
+    the engine's shapes. Driven pass by pass (no loop thread)."""
     from deeplearning4j_tpu.telemetry.registry import get_registry
     net = _tlm(seed=12, use_pallas=True)
     slots, cap = 3, 64
@@ -280,19 +281,145 @@ def test_kv_live_gauge_and_key_block_record():
     assert live.get() == 0.0
     f1 = sched.submit([3, 1, 4, 1, 5], max_new_tokens=6)
     f2 = sched.submit([2, 7], max_new_tokens=3)
-    sched._admit()              # each prefill emits its request's first token
+    sched._pass()               # both prefills dispatched, no token read yet
+    assert live.get() == pytest.approx(100.0 * (5 + 2) / (slots * cap))
+    sched._pass()               # step 1 dispatched, then the first tokens read
     assert live.get() == pytest.approx(100.0 * (6 + 3) / (slots * cap))
-    sched._step_wave()
+    sched._pass()               # step 2 dispatched, step 1 read
     assert live.get() == pytest.approx(100.0 * (7 + 4) / (slots * cap))
-    sched._step_wave()          # the second request is done: its slot is idle
+    # the second request's budget of 3 is in flight: step 3 leaves it out,
+    # and the read of step 2 retires it; its slot is idle
+    sched._pass()
     assert f2.done() and live.get() == pytest.approx(100.0 * 8 / (slots * cap))
     while not f1.done():
-        sched._step_wave()
+        sched._pass()
     assert live.get() == 0.0 and sched.active_count() == 0
+    assert not sched._pass() and sched._flight is None    # nothing is owed
+    assert mreg.get("decode_discarded_slot_steps_total").get() == 0
     # 2 heads of 16, float32, as `_tlm` builds them
     want = _decode_block(cap, 2, 16, 4, 1024, interpret=True)
     assert get_registry().get("flash_decode_block").get(
         C=cap, H=2, D=16, itemsize=4) == want
+
+
+# ------------------------------------------------- one step kept in flight
+def _drive(sched, futures, passes=200):
+    """Turn the loop by hand until every future is answered and nothing is
+    owed to the device."""
+    for _ in range(passes):
+        if all(f.done() for f in futures) and sched._flight is None:
+            return
+        sched._pass()
+    raise AssertionError("the loop never finished")
+
+
+def _ahead(mreg):
+    """`decode_steps_ahead_total` as {"0": n, "1": n}."""
+    return {l["ahead"]: n for l, n in
+            mreg.get("decode_steps_ahead_total").series()}
+
+
+def _loop_accounts(mreg, results):
+    """What holds after any mix: a token counted is a token answered, and a
+    step read is a step counted as ahead or not."""
+    assert mreg.get("decode_tokens_total").get() \
+        == sum(len(r["tokens"]) for r in results)
+    assert sum(_ahead(mreg).values()) \
+        == mreg.get("decode_step_sync_ms").count()
+
+
+def test_requests_joining_freed_slots_under_a_step_in_flight_equal_generate():
+    """Nine requests through three slots, budgets of 1 and 2 among them,
+    joining as slots free up while a step is in flight: each gets the
+    tokens `DecodeEngine.generate` gives its prompt alone. An end by length
+    is known before the dispatch: no slot-step is thrown away, and all but
+    the first step were dispatched ahead of a read."""
+    net = _tlm(seed=8, layers=2)
+    sched, _, mreg = _scheduler(net, slots=3)
+    shapes = [([3, 1, 4], 6), ([5, 2], 1), ([7, 7, 7, 7, 2, 1], 2),
+              ([1], 3), ([9, 8, 7, 6], 5), ([2, 2], 1), ([4], 2),
+              ([6, 5, 4, 3, 2, 1, 0], 7), ([8, 3], 4)]
+    futs = []
+    for i, (p, n) in enumerate(shapes):
+        futs.append(sched.submit(p, max_new_tokens=n))
+        if i >= 2:              # the rest arrive a pass apart
+            sched._pass()
+    _drive(sched, futs)
+    results = [f.result(timeout=0) for f in futs]
+    eng = DecodeEngine(net, slots=3, max_len=64)
+    for (p, n), r in zip(shapes, results):
+        assert r["tokens"] == eng.generate(p, n)
+        assert r["finish_reason"] == "length" and r["ttft_ms"] is not None
+    _loop_accounts(mreg, results)
+    assert mreg.get("decode_discarded_slot_steps_total").get() == 0
+    ahead = _ahead(mreg)
+    assert ahead["0"] == 1 and ahead["1"] >= 5
+    assert all(v == 1 for v in sched._engine.executable_counts().values())
+
+
+@pytest.mark.parametrize("end", ["stop", "abandon"])
+def test_an_end_found_a_step_late_discards_that_steps_token(end):
+    """A stop id ends a request when its token is read, and by then the next
+    step is in flight with the slot in it: the answer holds exactly the
+    tokens up to and including the stop id, the step's token for the slot
+    is counted as discarded and nowhere else. A caller who gives up with a
+    step in flight clamps the budget: that step's token is the last, the
+    next dispatch leaves the slot out and nothing is discarded. Either way
+    the neighbour in the batch and the request that takes the slot over
+    get `generate`'s tokens."""
+    net = _tlm(seed=12)
+    eng = DecodeEngine(net, slots=2, max_len=64)
+    full = eng.generate([3, 1, 4], 8)
+    cut = next(i for i in range(1, len(full)) if full[i] not in full[:i])
+    sched, _, mreg = _scheduler(net, slots=2)
+    first = sched.submit([3, 1, 4], max_new_tokens=8,
+                         stop_id=full[cut] if end == "stop" else None)
+    beside = sched.submit([2, 7], max_new_tokens=8)
+    if end == "abandon":
+        for _ in range(cut + 2):        # `cut + 1` tokens read, one in flight
+            sched._pass()
+        assert sched._flight is not None and sched.abandon(first)
+    _drive(sched, [first])
+    after = sched.submit([9, 8, 7], max_new_tokens=4)   # into the freed slot
+    _drive(sched, [beside, after])
+    got = first.result(timeout=0)
+    assert got["tokens"] == full[:cut + 1 if end == "stop" else cut + 2]
+    assert got["finish_reason"] == ("stop" if end == "stop" else "length")
+    assert beside.result(timeout=0)["tokens"] == eng.generate([2, 7], 8)
+    assert after.result(timeout=0)["tokens"] == eng.generate([9, 8, 7], 4)
+    assert mreg.get("decode_discarded_slot_steps_total").get() \
+        == (1 if end == "stop" else 0)
+    _loop_accounts(mreg, [f.result(timeout=0)
+                          for f in (first, beside, after)])
+
+
+def test_hot_swap_with_a_step_in_flight_reads_it_before_the_cache_goes():
+    """The last request of the old version ends by a stop id, so a step of
+    the old cache is still unread when admission finds the new version: it
+    is read (and its token discarded) before the engine and the cache are
+    swapped, and the new version's first step is dispatched into a drained
+    loop."""
+    net1, net2 = _tlm(seed=10), _tlm(seed=11)
+    sched, registry, mreg = _scheduler(net1, slots=2)
+    registry.register("v2", net2)
+    full = DecodeEngine(net1, slots=2, max_len=64).generate([3, 1, 4], 6)
+    cut = next(i for i in range(1, len(full)) if full[i] not in full[:i])
+    old = sched.submit([3, 1, 4], max_new_tokens=6, stop_id=full[cut])
+    while not old.done():
+        sched._pass()
+    assert sched._flight is not None            # its rider has just ended
+    registry.deploy("v2")
+    new = sched.submit([3, 1, 4], max_new_tokens=5)
+    sched._pass()       # nothing rides: the flight is read, then the swap
+    assert sched._version == "v2" and sched._flight is None
+    assert mreg.get("decode_discarded_slot_steps_total").get() == 1
+    _drive(sched, [new])
+    r_old, r_new = old.result(timeout=0), new.result(timeout=0)
+    assert (r_old["version"], r_old["tokens"]) == ("v1", full[:cut + 1])
+    assert (r_new["version"], r_new["tokens"]) == (
+        "v2", DecodeEngine(net2, slots=2, max_len=64).generate([3, 1, 4], 5))
+    _loop_accounts(mreg, [r_old, r_new])
+    assert _ahead(mreg)["0"] == 2       # one drained loop a version
 
 
 def test_hot_swap_drains_then_swaps_and_warm_engine_stays_warm():
@@ -334,12 +461,12 @@ def test_scheduler_survives_engine_error_and_serves_next():
         class Boom(Exception):
             pass
 
-        orig = sched._engine.prefill
+        orig = sched._engine.dispatch_prefill
 
         def boom(*a, **k):
-            sched._engine.prefill = orig
+            sched._engine.dispatch_prefill = orig
             raise Boom("injected")
-        sched._engine.prefill = boom
+        sched._engine.dispatch_prefill = boom
         with pytest.raises(Boom):
             sched.generate([1, 2], max_new_tokens=2)
         assert mreg.get("decode_errors_total").get() >= 1

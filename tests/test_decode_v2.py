@@ -508,25 +508,71 @@ def test_oversubscribed_scheduler_parity_with_forced_preemption():
         sched.stop()
 
 
+@pytest.mark.parametrize("pool_blocks", [None, 10],
+                         ids=["slab", "paged_pool_driven_dry"])
+def test_seeded_beside_greedy_under_a_step_in_flight_equal_generate(
+        pool_blocks):
+    """Seeded and greedy requests in one batch, turned pass by pass with a
+    step always in flight: each stream is `DecodeEngine.generate`'s for the
+    same prompt and sampler alone (the sampler's step index follows the
+    count of tokens dispatched, not of tokens read). On a pool of 9 blocks
+    of 8 for three contexts of ~45 a slot is preempted while a step that
+    carries it is in flight: that token is discarded, the re-prefill from
+    prompt + tokens emits it again, and the stream does not show it."""
+    net = _tlm(seed=9, layers=2)
+    prompts = [[3, 1, 4, 1, 5], [9, 2], [6, 6, 7, 2, 1, 8], [5]]
+    budgets = [40, 40, 40, 2]
+    cfgs = [None, SamplerConfig(temperature=0.8, seed=11),
+            SamplerConfig(temperature=1.1, top_k=6, seed=5), None]
+    eng = DecodeEngine(net, slots=3, max_len=64)
+    want = [eng.generate(p, n, sampler=c)
+            for p, n, c in zip(prompts, budgets, cfgs)]
+    paged = pool_blocks is not None
+    sched, _, mreg = _scheduler(net, slots=3, max_len=64, paged=paged,
+                                block_size=8, pool_blocks=pool_blocks)
+    futs = [sched.submit(p, max_new_tokens=n, sampler=c)
+            for p, n, c in zip(prompts, budgets, cfgs)]
+    for _ in range(400):
+        if all(f.done() for f in futs) and sched._flight is None:
+            break
+        sched._pass()
+    got = [f.result(timeout=0) for f in futs]
+    assert [r["tokens"] for r in got] == want
+    assert mreg.get("decode_tokens_total").get() == sum(budgets)
+    preempted = mreg.get("decode_preempted_total").get()
+    # a preempted slot was riding the step in flight, unless it was
+    # preempted before its first step
+    assert (preempted >= 1) == paged
+    assert 0 <= mreg.get("decode_discarded_slot_steps_total").get() \
+        <= preempted
+    assert paged == (mreg.get("decode_discarded_slot_steps_total").get() > 0)
+    assert sum(n for _, n in mreg.get("decode_steps_ahead_total").series()) \
+        == mreg.get("decode_step_sync_ms").count()
+    assert all(v == 1 for v in sched._engine.executable_counts().values())
+    if paged:
+        assert sched.snapshot()["paged"]["used_blocks"] == 0
+
+
 def test_fairness_deadline_and_preempt_share_retire_path(manual_clock):
     """ISSUE satellite: a preempted-then-requeued request whose deadline
     expires retires through the SAME path as a mid-generation deadline —
     partial tokens returned with finish_reason='deadline' (not a 504) —
     and neither preempt nor expiry leaks slots, blocks, or the
-    active_slots gauge. Driven synchronously (no loop thread) under
-    ManualClock for a deterministic preempt->requeue->expire sequence."""
+    active_slots gauge. Driven pass by pass (no loop thread) under
+    ManualClock for a deterministic preempt->requeue->expire sequence. The
+    slot is preempted with a step in flight: that step's token for it is
+    the one discarded slot-step."""
     net = _tlm(seed=10)
     sched, _, mreg = _scheduler(net, slots=2, max_len=32, paged=True,
                                 block_size=8, pool_blocks=5)
     # 4 allocatable blocks; two slots of up to 4 blocks each
     f1 = sched.submit([1, 2, 3], max_new_tokens=20)
     f2 = sched.submit([4, 5, 6], max_new_tokens=20, timeout_ms=5000.0)
-    sched._admit()
+    sched._pass()               # nothing to step yet: both are admitted
     assert sched.active_count() == 2
     preempted_at = None
     for _ in range(40):
-        sched._step_wave()
-        sched._admit()
+        sched._pass()           # a step dispatched, the last one read, admit
         if mreg.get("decode_preempted_total").get() >= 1 \
                 and preempted_at is None:
             preempted_at = True
@@ -550,24 +596,32 @@ def test_fairness_deadline_and_preempt_share_retire_path(manual_clock):
     snap = sched.snapshot()
     assert snap["paged"]["used_blocks"] == 0
     assert set(sched._free) == {0, 1}       # both slot ids back
+    assert mreg.get("decode_discarded_slot_steps_total").get() == 1
+    assert mreg.get("decode_tokens_total").get() \
+        == len(r1["tokens"]) + len(r2["tokens"])
 
 
 def test_mid_generation_deadline_returns_partial(manual_clock):
     """The budget-spent path (no preemption involved): tokens stop at the
     deadline, partial result, slot released — the baseline the fairness
-    test compares against."""
+    test compares against. The deadline is found out when a token is read,
+    with the next step already in flight: that step's token is discarded."""
     net = _tlm(seed=10)
     sched, _, mreg = _scheduler(net, slots=1, max_len=32)
     f = sched.submit([1, 2, 3], max_new_tokens=20, timeout_ms=2000.0)
-    sched._admit()
-    sched._step_wave()
+    for _ in range(3):          # admitted; first token read; step 1 read
+        sched._pass()
     manual_clock.advance(3.0)
-    sched._step_wave()
+    sched._pass()               # step 3 dispatched, step 2 read: too late
     r = f.result(timeout=0)
     assert r["finish_reason"] == "deadline"
-    assert 0 < len(r["tokens"]) < 20
+    assert r["tokens"] == DecodeEngine(net, slots=1, max_len=32).generate(
+        [1, 2, 3], 3)
     assert sched.active_count() == 0
     assert mreg.get("decode_active_slots").get() == 0
+    assert sched._pass() and not sched._pass()      # step 3 read, then idle
+    assert mreg.get("decode_discarded_slot_steps_total").get() == 1
+    assert mreg.get("decode_tokens_total").get() == 3
 
 
 # --------------------------------------------------------------- smoke tool

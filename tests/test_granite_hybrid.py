@@ -216,6 +216,60 @@ def test_a_slot_reused_after_a_longer_request_reads_as_a_fresh_one():
     np.testing.assert_array_equal(again, fresh)
 
 
+def test_a_discarded_step_then_a_prefill_leave_the_exact_recurrent_state():
+    """Through the scheduler, which keeps a step in flight: a request ends
+    by a stop id, so its slot's Mamba-2 state and K/V row are stepped once
+    more than its answer shows; the request that takes the slot over is
+    prefilled over that and serves `generate`'s tokens, and when it is done
+    the slot's conv and ssm state are to the last bit those of the same
+    request served alone on a fresh cache."""
+    from deeplearning4j_tpu.decode import DecodeScheduler
+    from deeplearning4j_tpu.serving.registry import ModelRegistry
+    from deeplearning4j_tpu.telemetry.registry import MetricsRegistry
+    net = tiny()
+    registry, mreg = ModelRegistry(), MetricsRegistry()
+    registry.register("v1", net)
+    registry.deploy("v1")
+    sched = DecodeScheduler(registry, mreg, slots=2, max_len=32)
+    eng = DecodeEngine(net, slots=2, max_len=32)
+    rng = np.random.RandomState(3)
+    first, beside, after = (list(rng.randint(0, V, n)) for n in (11, 4, 6))
+    full = eng.generate(first, 8)
+    cut = next(i for i in range(1, len(full)) if full[i] not in full[:i])
+
+    def drive(futures):
+        for _ in range(100):
+            if all(f.done() for f in futures) and sched._flight is None:
+                return [f.result(timeout=0) for f in futures]
+            sched._pass()
+        raise AssertionError("the loop never finished")
+
+    f_first = sched.submit(first, max_new_tokens=8, stop_id=full[cut])
+    f_beside = sched.submit(beside, max_new_tokens=6)
+    (r_first,) = drive([f_first])
+    assert r_first["tokens"] == full[:cut + 1]
+    slot = sched._free[-1]                      # the one it has just left
+    f_after = sched.submit(after, max_new_tokens=5)
+    r_beside, r_after = drive([f_beside, f_after])
+    assert mreg.get("decode_discarded_slot_steps_total").get() == 1
+    assert r_beside["tokens"] == eng.generate(beside, 6)
+    assert r_after["tokens"] == eng.generate(after, 5)
+
+    # the same request alone: a prefill and the 4 steps after it
+    cache, nid, _ = eng.prefill(eng.init_cache(), slot, after)
+    ids = np.zeros((2,), np.int32)
+    for _ in range(4):
+        ids[slot] = nid
+        cache, nxt, _ = eng.step(cache, ids)
+        nid = int(nxt[slot])
+    for name in sorted(eng._carries):
+        for leaf, alone in cache["layers"][name].items():
+            np.testing.assert_array_equal(
+                np.asarray(sched._cache["layers"][name][leaf])[slot],
+                np.asarray(alone)[slot], err_msg=f"{name}/{leaf}")
+    assert int(sched._cache["lengths"][slot]) == int(cache["lengths"][slot])
+
+
 # ---------------------------------------------------------------- the kernels
 @pytest.mark.parametrize("shape", [(3, 16, 64), (2, 8, 256)])
 def test_ssm_step_kernel_is_its_plain_form_to_the_last_bits(shape):
@@ -302,7 +356,7 @@ def test_attention_fields_at_their_defaults_leave_transformer_lm_programs():
         prefill = eng._build_prefill(16).lower(
             net.params, net.states, cache, np.int32(0),
             np.zeros((16,), np.int32), np.int32(9), eng._greedy_slot_ops,
-            None)
+            None, np.zeros((2,), np.int32))
         return _stripped(step), _stripped(prefill)
 
     assert programs() == programs(n_kv_heads=2)

@@ -211,6 +211,39 @@ def test_decode_scheduler_cache_gauge_reports_per_shard_mb():
         sched.stop()
 
 
+def test_decode_loop_on_a_mesh_is_never_ahead_and_serves_the_same_tokens():
+    """On a mesh the engine's dispatch waits for the device inside the run
+    lock, so the scheduler's pass reads the step it has just dispatched:
+    every step counts `ahead="0"`, a stop id is still found a step late
+    (the first token is read a pass after its prefill) and the tokens are
+    the one-device engine's."""
+    from deeplearning4j_tpu.decode.scheduler import DecodeScheduler
+    from deeplearning4j_tpu.serving.registry import ModelRegistry
+    from deeplearning4j_tpu.telemetry.registry import MetricsRegistry
+
+    ctx = MeshContext({"n_data": 4, "n_model": 2, "rules": "tensor_parallel"})
+    reg = ModelRegistry(adapter=ctx.wrap)
+    reg.register("v1", _graph_lm(seed=5))
+    reg.deploy("v1")
+    mreg = MetricsRegistry()
+    sched = DecodeScheduler(reg, mreg, slots=2, max_len=32)
+    alone = DecodeEngine(_graph_lm(seed=5), slots=2, max_len=32)
+    prompts, budgets = [[1, 2, 3], [4, 5], [6]], [6, 1, 4]
+    futs = [sched.submit(p, max_new_tokens=n)
+            for p, n in zip(prompts, budgets)]
+    for _ in range(40):
+        if all(f.done() for f in futs):
+            break
+        sched._pass()
+        assert sched._flight is None        # read in the pass it was sent
+    assert [f.result(timeout=0)["tokens"] for f in futs] \
+        == [alone.generate(p, n) for p, n in zip(prompts, budgets)]
+    assert mreg.get("decode_steps_ahead_total").series() == [
+        ({"ahead": "0"}, mreg.get("decode_step_sync_ms").count())]
+    assert mreg.get("decode_discarded_slot_steps_total").get() == 0
+    assert all(v == 1 for v in sched._engine.executable_counts().values())
+
+
 # ------------------------------------------------------------- OOM proxy
 
 def test_model_that_overflows_one_chip_serves_tp_sharded():

@@ -505,6 +505,22 @@ def lm_engine(chip_config):
     return DecodeEngine(net, slots=8, max_len=256)
 
 
+def _prefill_text(eng, bucket, sharding):
+    """The optimized HLO of `eng`'s prefill of `bucket` tokens into slot 0,
+    compiled for `sharding`'s device. Its outputs are the cache, the first
+    id, the last position's probabilities and, the one output the loop that
+    keeps a step in flight added, the [slots] vector of next ids."""
+    lowered = eng._build_prefill(bucket).lower(*_abstract(
+        (eng.model.params, eng.model.states, jax.eval_shape(eng._cache_zeros),
+         np.int32(0), np.zeros((bucket,), np.int32), np.int32(bucket - 1),
+         eng._greedy_slot_ops), sharding), None,
+        *_abstract((np.zeros((eng.slots,), np.int32),), sharding))
+    cache, nid, probs, next_ids = lowered.out_info
+    assert (nid.shape, probs.shape) == ((), (eng.vocab,))
+    assert (next_ids.shape, next_ids.dtype) == ((eng.slots,), jnp.int32)
+    return lowered.compile().as_text()
+
+
 def test_decode_step_compiles_with_kernel(lm_engine, one_chip,
                                           chip_config, monkeypatch):
     monkeypatch.setattr(fa, "_interpret_default", lambda: False)
@@ -532,12 +548,48 @@ def test_prefill_bucket_compiles_with_kernel(lm_engine, one_chip,
                                              bucket):
     monkeypatch.setattr(fa, "_interpret_default", lambda: False)
     eng = lm_engine
-    args = _abstract((eng.model.params, eng.model.states, eng.init_cache(),
-                      np.int32(0), np.zeros((bucket,), np.int32),
-                      np.int32(bucket - 1), eng._greedy_slot_ops), one_chip)
-    text = eng._build_prefill(bucket).lower(*args, None).compile().as_text()
+    text = _prefill_text(eng, bucket, one_chip)
     assert text.count(KERNEL) == 4          # one masked flash per layer
     assert sorts_only_under_a_conditional(text)
+
+
+def test_programs_of_the_loop_with_a_step_in_flight_keep_their_kernels(
+        one_chip, chip_config, monkeypatch):
+    """The two programs the decode loop queues behind one another, at
+    `opt350m_batch_decode`'s depth and cache geometry (24 layers, heads of
+    64, float32, 48 slots of 1024; a quarter of its width and a small
+    vocabulary, which no count below depends on). The step takes its ids as
+    the [slots] vector the step before it returned — abstractly the host
+    vector it took before, so one program: 24 `kv_append` + 24
+    `flash_decode`, one conditional, no loop, no copy of a slab. The
+    256-token prefill takes that vector too and returns it with its slot's
+    entry set (`_prefill_text` holds the outputs to that): 24 masked flash
+    kernels, one conditional, no loop."""
+    from deeplearning4j_tpu.decode.engine import DecodeEngine
+    from deeplearning4j_tpu.zoo.models import transformer_lm
+    monkeypatch.setattr(fa, "_interpret_default", lambda: False)
+    net = transformer_lm(vocab_size=512, d_model=256, n_layers=24, n_heads=4,
+                         use_pallas=True).init()
+    eng = DecodeEngine(net, slots=48, max_len=1024)
+    next_ids = np.zeros((eng.slots,), np.int32)     # a step's own output
+    lowered = eng._build_step().lower(*_abstract(
+        (net.params, net.states, jax.eval_shape(eng._cache_zeros), next_ids,
+         eng._greedy_step_ops), one_chip), None)
+    assert lowered.out_info[1].shape == next_ids.shape \
+        and lowered.out_info[1].dtype == next_ids.dtype
+    text = lowered.compile().as_text()
+    assert text.count(KERNEL) == 48
+    assert len(re.findall(r"%kv_append[.\d]* = ", text)) == 24
+    assert text.count(" conditional(") == 1
+    assert sorts_only_under_a_conditional(text)
+    assert loops(text) == []
+    assert relayouts(text, eng.slots * eng.capacity * 256) == []
+    text = _prefill_text(eng, 256, one_chip)
+    assert text.count(KERNEL) == 24
+    assert text.count(" conditional(") == 1
+    assert sorts_only_under_a_conditional(text)
+    assert loops(text) == []
+    assert relayouts(text, eng.slots * eng.capacity * 256) == []
 
 
 def test_hybrid_decode_step_compiles_with_both_kinds_of_kernel(
@@ -571,10 +623,7 @@ def test_hybrid_decode_step_compiles_with_both_kinds_of_kernel(
     assert relayouts(text, 16 * 128 * 1024) == []
     assert loops(text) == []
     assert sorts_only_under_a_conditional(text)
-    text = eng._build_prefill(128).lower(*_abstract(
-        (net.params, net.states, eng.init_cache(), np.int32(0),
-         np.zeros((128,), np.int32), np.int32(100), eng._greedy_slot_ops),
-        one_chip), None).compile().as_text()
+    text = _prefill_text(eng, 128, one_chip)
     assert text.count(KERNEL) == 1          # the attention layer's flash
     assert sorts_only_under_a_conditional(text)
 
@@ -610,10 +659,7 @@ def test_routed_decode_step_compiles_with_one_expert_kernel_a_layer(
     assert len(re.findall(r"%expert_gmm_16x1[.\d]* = ", text)) == 2
     assert len(re.findall(r"%ssm_step[.\d]* = ", text)) == 1
     assert loops(text) == []
-    text = eng._build_prefill(128).lower(*_abstract(
-        (net.params, net.states, eng.init_cache(), np.int32(0),
-         np.zeros((128,), np.int32), np.int32(100), eng._greedy_slot_ops),
-        one_chip), None).compile().as_text()
+    text = _prefill_text(eng, 128, one_chip)
     assert len(re.findall(r"%expert_gmm_1x128[.\d]* = ", text)) == 2
     assert loops(text) == []
 
