@@ -13,7 +13,9 @@ a quarter of a GB of host memory and most of a second of stop_trace() per
 thousand device operations (a 5-step execution has 20,600 and 8,600 copies:
 six of them took 42-45 GB and two minutes), and slows the input path to a
 batch per 0.5 s while it is on. So the slice gives the device time of the
-programs; how often they run is taken from the window.
+programs; how often they run is taken from the window, and so are the
+result's `device.busy_s` / `window_s`: the slice's device-busy time a step
+times the window's steps, over the window's seconds.
 """
 from __future__ import annotations
 
@@ -285,9 +287,17 @@ def run(run):
 
     samples = steps * batch
     chips = run.cell["chips"]
+    device = None
+    if reduced and reduced["programs"] and steps:
+        # the loop the cell times, not the slice after it: the device-busy
+        # time a step takes in the slice times the window's steps, over the
+        # window (what layer_metrics/device_idle_pct.train.py reads)
+        device = {"busy_s": reduced["busy_s"]
+                  / (reduced["programs"][0][1] * K) * steps,
+                  "window_s": elapsed}
     return {
         "attempted": steps, "failed": 0,
-        "memory_peak_bytes": peak_bytes,
+        "memory_peak_bytes": peak_bytes, "device": device,
         "end_to_end": {"setup_s": setup_s,
                        "samples_per_s_per_chip": samples / elapsed / chips},
         "counts": {"steps": steps, "samples": samples},

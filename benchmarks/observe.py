@@ -6,6 +6,7 @@ import json
 import math
 import shutil
 import threading
+import time
 
 from . import trace_reduce
 
@@ -40,7 +41,10 @@ def memory_peak_bytes(cost_rows=()):
 
 def snapshot(registry):
     """{instrument name: value} for counters and gauges, {count, sum, p50}
-    for histograms (their unlabeled series), of a telemetry registry."""
+    for histograms (their unlabeled series), of a telemetry registry. A
+    counter's labeled series are there too, as `name{key="value",...}` with
+    the keys in order (`decode_steps_ahead_total{ahead="1"}`); its bare name
+    is their sum."""
     out = {}
     for m in registry.collect():
         try:
@@ -49,6 +53,12 @@ def snapshot(registry):
                                "p50": m.percentile(0.5)}
             else:
                 out[m.name] = m.get()
+            if m.kind == "counter":
+                for labels, value in m.series():
+                    if labels:
+                        out[m.name + "{" + ",".join(
+                            f'{k}="{labels[k]}"' for k in sorted(labels))
+                            + "}"] = value
         except Exception:            # a gauge whose callback cannot answer
             continue
     return out
@@ -84,15 +94,26 @@ class TraceSlice:
         if self.enabled:
             import jax
             self._mark.__exit__(*exc)
+            t0 = time.perf_counter()
             jax.profiler.stop_trace()
+            note(stop_trace_s=time.perf_counter() - t0)
             self._done = True
 
     def reduce(self):
         if not self._done:
             return None
+        t0 = time.perf_counter()
         loaded = trace_reduce.load_xplane(self.log_dir)
         shutil.rmtree(self.log_dir, ignore_errors=True)
-        return trace_reduce.reduce(loaded)
+        reduced = trace_reduce.reduce(loaded)
+        kept = {"device": 0, "host": 0}
+        for plane in loaded["planes"]:
+            side = "device" if trace_reduce.DEVICE_PLANE.match(
+                plane["name"]) else "host"
+            kept[side] += sum(len(l["events"]) for l in plane["lines"])
+        note(trace_load_and_reduce_s=time.perf_counter() - t0,
+             trace_events_kept=kept)
+        return reduced
 
 
 class GaugePoll:

@@ -265,3 +265,18 @@ def decode_step_bytes(slots, live_tokens, vocab=100352, d_model=2048,
             "ssm_state": n_mamba * ssm_step_bytes(slots, d_model),
             "conv_tail": n_mamba * 2 * slots * (MAMBA_D_CONV - 1) * cd * 2,
             "kv": n_attn * live_tokens * 2 * kv * 2}
+
+
+def decode_macs_per_token(vocab, d_model, layers, ffn):
+    """Multiply-accumulates one generated token needs in the weights'
+    products: a Mamba-2 layer's in- and out-projection, an attention layer's
+    four projections, every block's gated MLP, and the head. The lookup
+    needs none; the recurrence, the conv and attention's scores and mix are
+    left out: a share of the peak computed from this reads low, never
+    high."""
+    _, di, _, win = mamba_dims(d_model)
+    kv = d_model // QUERY_HEADS_PER_KV
+    n_attn = sum(1 for i in ATTENTION_LAYERS if i < layers)
+    return (layers - n_attn) * (d_model * win + di * d_model) \
+        + n_attn * (2 * d_model * d_model + 2 * d_model * kv) \
+        + layers * 3 * d_model * ffn + d_model * vocab

@@ -334,3 +334,22 @@ def decode_step_bytes(slots, live_tokens, vocab=25088, d_model=4096,
             "ssm_state": n_mamba * ssm_step_bytes(slots, d_model),
             "conv_tail": n_mamba * 2 * slots * (MAMBA_D_CONV - 1) * cd * 2,
             "kv": n_attn * live_tokens * 2 * kv * 2}
+
+
+def decode_macs_per_token(vocab, d_model, layers, ffn):
+    """Multiply-accumulates one generated token needs on this chip in the
+    weights' products: a Mamba-2 layer's in- and out-projection, the
+    attention layer's four projections, every block's router, shared expert
+    and the held share of its 10 routed experts (10 * 18 / 72 pairs a token
+    at the mean), and this chip's rows of the head. The lookup needs none;
+    the recurrence, the conv and attention's scores and mix are left out: a
+    share of the peak computed from this reads low, never high."""
+    _, di, _, win = mamba_dims(d_model)
+    kv = d_model // QUERY_HEADS_PER_KV
+    n_attn = sum(1 for i in ATTENTION_LAYERS if i < layers)
+    pairs = EXPERTS_PER_TOKEN * EXPERTS_HELD / N_EXPERTS
+    return (layers - n_attn) * (d_model * win + di * d_model) \
+        + n_attn * (2 * d_model * d_model + 2 * d_model * kv) \
+        + layers * (3 * d_model * ffn + d_model * N_EXPERTS
+                    + pairs * 3 * d_model * EXPERT_HIDDEN) \
+        + d_model * vocab

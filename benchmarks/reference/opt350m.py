@@ -104,3 +104,14 @@ def logits(params, ids, *, heads, layers, dtype="float32"):
                 + f2["b"]
             x = _ln(h + f, p[f"b{i}_ln2"])
         return (mm(x, p["out"]["W"]) + p["out"]["b"]).astype(jnp.float32)
+
+
+def decode_macs_per_token(vocab, d_model, layers, ffn):
+    """Multiply-accumulates one generated token needs in the weights'
+    products: four [d, d] attention projections and the two feed-forward
+    matrices a layer, and the head. The lookup needs none (that the program
+    multiplies a one-hot row is its own doing), and attention's scores and
+    mix, which grow with the slot's length, are left out: a share of the
+    peak computed from this reads low, never high."""
+    return layers * (4 * d_model * d_model + 2 * d_model * ffn) \
+        + d_model * vocab

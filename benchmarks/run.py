@@ -13,8 +13,10 @@ metric has a reader of its own (layer_metrics/<metric>.py). Adding a cell, a
 configuration or a metric is adding files.
 
 The last line of stdout is the result: correct, attempted, failed, metrics,
-device (and breakdown when traced). With --trace 0 the metrics are the cell's
-end-to-end metrics; with --trace 1 its per-layer metrics.
+device (and breakdown when traced), then `compared`: every number `correct`
+was decided on beside its limit, which are also the last lines of stderr.
+With --trace 0 the metrics are the cell's end-to-end metrics; with --trace 1
+its per-layer metrics.
 
 `--rehearsal <file>` (CPU only, see README.md) overrides sizes so the whole
 path can be driven at a tiny size without a chip; a rehearsal prints no
@@ -39,6 +41,7 @@ HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
 sys.path.insert(0, str(ROOT))
 
+from benchmarks import trace_reduce  # noqa: E402
 from benchmarks.observe import note  # noqa: E402
 
 
@@ -202,16 +205,31 @@ def report(run, out):
         if reduced is None or not reduced["busy_s"] > 0:
             sys.exit("run.py: the traced window shows no operation on the "
                      "device")
-        device["busy_s"] = reduced["busy_s"]
-        device["window_s"] = reduced["window_s"]
-        result["breakdown"] = {"device_ops": reduced["device_ops"],
-                               "idle_gaps": reduced["idle_gaps"]}
+        # the kind's own busy time and window where the slice is not the
+        # loop it times (kinds/train.py), else the slice's
+        device.update(out.get("device") or {
+            "busy_s": reduced["busy_s"], "window_s": reduced["window_s"]})
+        note(programs=reduced["programs"],
+             program_busy=reduced["program_busy"],
+             kernels_cover=reduced["kernels_cover"],
+             kernels=reduced["kernels"][:60])
+        result["breakdown"] = {
+            "device_ops": trace_reduce.top_kernels(reduced),
+            "idle_gaps": reduced["idle_gaps"]}
         for name in cell["per_layer"]:
             reader = load_reader(name)
             value = reader.read(out["obs"])
             if value is not None:
                 result["metrics"][name] = {"value": value,
                                            "unit": reader.UNIT}
+    # every number compared beside its limit: the last lines of stderr, and
+    # the result's last key
+    result["compared"] = {r["check"]: {"value": r["value"],
+                                       "limit": r["limit"]}
+                          for r in run.check.rows}
+    for r in run.check.rows:
+        print(f"compared {r['check']}: {r['value']} limit {r['limit']} "
+              f"{'ok' if r['ok'] else 'NOT OK'}", file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

@@ -36,14 +36,9 @@ def test_sound_run_is_correct(capsys):
 def test_served_token_altered_where_it_is_produced_is_not_correct(
         monkeypatch):
     from deeplearning4j_tpu.decode.engine import DecodeEngine
-    real = DecodeEngine.step
-
-    def altered(self, cache, last_ids, sampling=None, table=None):
-        cache, nxt, probs = real(self, cache, last_ids, sampling=sampling,
-                                 table=table)
-        return cache, (np.asarray(nxt) + 1) % self.vocab, probs
-
-    monkeypatch.setattr(DecodeEngine, "step", altered)
+    real = DecodeEngine.read_ids     # where the loop reads a step's tokens
+    monkeypatch.setattr(DecodeEngine, "read_ids", lambda self, ids:
+                        (real(self, ids) + 1) % self.vocab)
     run, _ = drive(CELL, 6, seconds=2.0)
     assert not run.check.correct
     assert not rows(run)["served_token_logit_gap_max"]["ok"]
