@@ -81,8 +81,8 @@ class BlockPool:
         return self.capacity_blocks - len(self._free)
 
     def utilization(self):
-        """Allocated fraction of the allocatable pool (the
-        kv_pool_utilization gauge)."""
+        """Allocated fraction of the allocatable pool (the scheduler's
+        `stats()["paged"]["utilization"]`)."""
         return self.used_blocks / max(self.capacity_blocks, 1)
 
     def alloc(self, n):

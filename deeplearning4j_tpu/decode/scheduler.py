@@ -312,10 +312,6 @@ class DecodeScheduler:
         reg.gauge("decode_cache_mb",
                   "KV-cache bytes resident PER SHARD (MB) for the live "
                   "engine", fn=lambda: self.cache_mb())
-        reg.gauge("decode_kv_pool_utilization",
-                  "Allocated fraction of the paged KV block pool (0 when "
-                  "the slab layout serves)",
-                  fn=lambda: self.pool_utilization())
         for c in (self.m_requests, self.m_tokens, self.m_shed,
                   self.m_expired, self.m_errors, self.m_preempted,
                   self.m_discarded):

@@ -79,13 +79,7 @@ class SpeculativeEngine:
         self.accepted = 0
         self.rounds = 0
         self.emitted = 0
-        self._reg_metrics = None
         if registry is not None:
-            self._reg_metrics = (
-                registry.counter("spec_proposed_total",
-                                 "Draft tokens proposed"),
-                registry.counter("spec_accepted_total",
-                                 "Draft tokens accepted by the target"))
             registry.gauge("spec_acceptance_rate",
                            "Accepted/proposed draft tokens (lifetime)",
                            fn=lambda: self.acceptance_rate())
@@ -239,9 +233,6 @@ class SpeculativeEngine:
             self.proposed += len(drafts)
             self.accepted += accepted
             self.emitted += len(emitted)
-            if self._reg_metrics is not None:
-                self._reg_metrics[0].add(len(drafts))
-                self._reg_metrics[1].add(accepted)
         # over-emission past max_new_tokens / stop is trimmed, so output
         # length semantics match the plain decode loop
         if stop_id is not None and stop_id in out:

@@ -31,7 +31,13 @@ pieces, one import surface:
 
 Everything is instrumented through the telemetry layer: per-stage spans,
 `etl_batches_total` / `etl_records_total`, `etl_queue_depth`, and the
-`etl_consumer_wait_ms` histogram (the device-starvation signal).
+prefetcher's legs as phases on the profiler's clock (`etl_h2d`,
+`etl_device_transform`, `etl_producer_blocked`, `etl_consumer_wait`; each a
+`<leg>_ms{pipeline}` histogram). `etl_consumer_wait_ms` is the
+device-starvation signal for a consumer that waits for its step; under
+`fit(steps_per_execution=K)`, which dispatches ahead, it is the loop's
+blocked time and `fit_executions_ahead_total{ahead="0"}` is the signal
+(prefetch.py).
 """
 from .device_transform import DeviceIngest, lower_normalizer
 from .normalizer import (DataNormalizer, NormalizerMinMaxScaler,

@@ -21,9 +21,12 @@ when the consumer has stopped pulling).
 
 Telemetry (PR-2 layer): per-stage spans (etl_read / etl_transform), counters
 `etl_batches_total` / `etl_records_total`, queue-depth gauge
-`etl_queue_depth`, and the consumer wait-time histogram
-`etl_consumer_wait_ms` — the number that tells you whether the TPU is
-waiting on the host (prefetch working = wait ~0).
+`etl_queue_depth`, and the consumer's wait as the phase `etl_consumer_wait`
+(telemetry/trace.py: `dl4j:etl_consumer_wait` in a profiler session and the
+histogram `etl_consumer_wait_ms{pipeline=<name>}`) — the number that tells
+you whether the consumer is waiting on the host (prefetch working = wait
+~0). A DevicePrefetcher over this executor keeps a series of its own: its
+worker is this executor's consumer, and a read without labels sums both.
 """
 from __future__ import annotations
 
@@ -415,11 +418,10 @@ class ParallelPipelineExecutor(DataSetIterator):
     def _fill_peek(self):
         if self._done or self._peek is not None:
             return
-        t0 = monotonic_s()
-        item = self._inline_next_chunk() if self.workers <= 0 \
-            else self._out.take()
-        self._m_wait.observe((monotonic_s() - t0) * 1000.0,
-                             pipeline=self.name)
+        with self.tracer.phase("etl_consumer_wait", histogram=self._m_wait,
+                               fold=True, labels={"pipeline": self.name}):
+            item = self._inline_next_chunk() if self.workers <= 0 \
+                else self._out.take()
         self._gauge()
         if item is _END or item is None:
             self._done = True

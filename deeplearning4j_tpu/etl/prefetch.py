@@ -36,10 +36,28 @@ bounds end-to-end training):
   Plain/device placement only; sharded placement keeps whole-array puts.
 
 Telemetry: `etl_h2d_bytes_total` counts the bytes that ACTUALLY cross the
-link (post-narrowing), and every batch records an `ingest` span with
-`transfer_ms` vs `transform_ms` legs, so `/metrics` + `/trace` show where
-ingest time goes. `etl_consumer_wait_ms` / `etl_queue_depth` are shared with
-the pipeline executor (wait ~0 means the device never starves). A producer
+link (post-narrowing). Every leg of a batch is a `Tracer.phase`
+(telemetry/trace.py): a profiler annotation `dl4j:<leg>` on the thread that
+ran it, so a profiler session shows it on the device's clock, and the
+histogram `<leg>_ms{pipeline=<name>}`, tracer on or off. On the worker's
+thread: `etl_h2d` (the puts and the chunks' join up to and INCLUDING the
+fence: where it reads far over what the bytes need, the fence waited behind
+the program the device was running), `etl_device_transform` (only with a
+`device_transform`), `etl_producer_blocked` (`put` into a full queue: the
+healthy state, the worker is ahead). On the consumer's thread:
+`etl_consumer_wait` (`next()` blocked on an empty queue), the histogram and
+`etl_queue_depth` shared with the pipeline executor. With the tracer on,
+every batch also records ONE `ingest` ring span whose `transfer_ms` /
+`transform_ms` attributes are the same clock reads, so `/trace` shows where
+ingest time goes.
+
+What the wait says depends on the consumer. One that waits for its step
+before it pulls again (`fit_batch`, an evaluation loop) starves the device
+exactly while it waits: `etl_consumer_wait_ms` ~ 0 means the device never
+starves. `fit(steps_per_execution=K)` dispatches ahead of the device, so its
+loop spends its life in `next()` while the device works: there the wait is
+the loop's blocked time, and the starvation signal is
+`fit_executions_ahead_total{ahead="0"}` (nn/multistep.py). A producer
 error is re-raised exactly once, from next()/has_next() or — if the consumer
 already stopped pulling — from reset()/close().
 """
@@ -52,7 +70,6 @@ from ..datasets.dataset import DataSet, MultiDataSet
 from ..datasets.iterator.base import DataSetIterator
 from ..telemetry.registry import get_registry
 from ..telemetry.trace import get_tracer
-from ..util.time_source import monotonic_s
 
 
 class DevicePrefetcher(DataSetIterator):
@@ -70,6 +87,7 @@ class DevicePrefetcher(DataSetIterator):
         self.mesh = mesh
         self.sharding = sharding
         self.name = str(name)
+        self._labels = {"pipeline": self.name}
         self.transfer_dtype = transfer_dtype
         self.device_transform = device_transform
         self.transfer_streams = max(1, int(transfer_streams))
@@ -79,6 +97,16 @@ class DevicePrefetcher(DataSetIterator):
         self._m_wait = reg.histogram(
             "etl_consumer_wait_ms",
             "Time the consumer blocked waiting for the next ETL batch")
+        self._m_h2d = reg.histogram(
+            "etl_h2d_ms", "One batch's host->device puts, the join of "
+            "their chunks and the fence behind them, ms")
+        self._m_transform = reg.histogram(
+            "etl_device_transform_ms",
+            "One batch's device_transform until ready, ms")
+        self._m_blocked = reg.histogram(
+            "etl_producer_blocked_ms", "Time the prefetch worker held a "
+            "staged batch before the queue took it (full queue: the "
+            "worker is ahead), ms")
         self._m_depth = reg.gauge(
             "etl_queue_depth", "Chunks queued inside ETL pipelines")
         self._m_bytes = reg.counter(
@@ -124,9 +152,14 @@ class DevicePrefetcher(DataSetIterator):
         parts = [f.result() for f in futs]
         return jnp.concatenate(parts, axis=0), a.nbytes
 
+    def _leg(self, name, histogram):
+        """One leg of a batch as a phase: `dl4j:<name>` on the calling
+        thread + `histogram{pipeline}`; the ring keeps `ingest` instead."""
+        return self.tracer.phase(name, histogram=histogram, fold=True,
+                                 labels=self._labels)
+
     def _put(self, ds):
         import jax
-        t0 = monotonic_s()
         nbytes = 0
 
         def put(a, narrow=False):
@@ -136,39 +169,43 @@ class DevicePrefetcher(DataSetIterator):
             dev, n = self._transfer(a, narrow)
             nbytes += n
             return dev
-        if isinstance(ds, MultiDataSet):
-            out = MultiDataSet(
-                [put(f, narrow=True) for f in ds.features],
-                [put(l) for l in ds.labels],
-                None if ds.features_masks is None else
-                [None if m is None else put(m) for m in ds.features_masks],
-                None if ds.labels_masks is None else
-                [None if m is None else put(m) for m in ds.labels_masks])
-            feats = out.features
-        else:
-            out = DataSet(put(ds.features, narrow=True), put(ds.labels),
-                          put(ds.features_mask), put(ds.labels_mask))
-            feats = [out.features]
-        # fence before timestamping: device_put is async, and the span's
-        # transfer leg must mean "DMA done", not "DMA enqueued" (this blocks
-        # only the prefetch worker — the consumer keeps computing)
-        jax.block_until_ready([f for f in feats if f is not None])
-        t1 = monotonic_s()
+        with self._leg("etl_h2d", self._m_h2d) as h2d:
+            if isinstance(ds, MultiDataSet):
+                out = MultiDataSet(
+                    [put(f, narrow=True) for f in ds.features],
+                    [put(l) for l in ds.labels],
+                    None if ds.features_masks is None else
+                    [None if m is None else put(m)
+                     for m in ds.features_masks],
+                    None if ds.labels_masks is None else
+                    [None if m is None else put(m) for m in ds.labels_masks])
+                feats = out.features
+            else:
+                out = DataSet(put(ds.features, narrow=True), put(ds.labels),
+                              put(ds.features_mask), put(ds.labels_mask))
+                feats = [out.features]
+            # fence inside the leg: device_put is async, and the leg must
+            # mean "DMA done", not "DMA enqueued" (this blocks only the
+            # prefetch worker — the consumer keeps computing)
+            jax.block_until_ready([f for f in feats if f is not None])
+        end, transform_ms = h2d.end_mono, 0.0
         if self.device_transform is not None:
             tf = self.device_transform
-            if isinstance(out, MultiDataSet):
-                out = MultiDataSet([tf(f) for f in out.features], out.labels,
-                                   out.features_masks, out.labels_masks)
-            else:
-                out = DataSet(tf(out.features), out.labels,
-                              out.features_mask, out.labels_mask)
-            jax.block_until_ready(out.features)
-        t2 = monotonic_s()
+            with self._leg("etl_device_transform", self._m_transform) as dt:
+                if isinstance(out, MultiDataSet):
+                    out = MultiDataSet([tf(f) for f in out.features],
+                                       out.labels, out.features_masks,
+                                       out.labels_masks)
+                else:
+                    out = DataSet(tf(out.features), out.labels,
+                                  out.features_mask, out.labels_mask)
+                jax.block_until_ready(out.features)
+            end, transform_ms = dt.end_mono, dt.duration_ms
         self._m_bytes.inc(nbytes, pipeline=self.name)
         self.tracer.record_span(
-            "ingest", t0, t2, pipeline=self.name, bytes=nbytes,
-            transfer_ms=round((t1 - t0) * 1e3, 3),
-            transform_ms=round((t2 - t1) * 1e3, 3))
+            "ingest", h2d.start_mono, end, pipeline=self.name, bytes=nbytes,
+            transfer_ms=round(h2d.duration_ms, 3),
+            transform_ms=round(transform_ms, 3))
         return out
 
     # ---- worker ------------------------------------------------------------
@@ -188,12 +225,13 @@ class DevicePrefetcher(DataSetIterator):
             try:
                 while not stop.is_set() and self.underlying.has_next():
                     item = self._put(self.underlying.next())
-                    while not stop.is_set():
-                        try:
-                            q.put(item, timeout=0.1)
-                            break
-                        except queue.Full:
-                            continue
+                    with self._leg("etl_producer_blocked", self._m_blocked):
+                        while not stop.is_set():
+                            try:
+                                q.put(item, timeout=0.1)
+                                break
+                            except queue.Full:
+                                continue
             except Exception as e:
                 self._error = e
             finally:
@@ -217,10 +255,8 @@ class DevicePrefetcher(DataSetIterator):
     def _fill_peek(self):
         if self._done:
             return
-        t0 = monotonic_s()
-        v = self._queue.get()
-        self._m_wait.observe((monotonic_s() - t0) * 1000.0,
-                             pipeline=self.name)
+        with self._leg("etl_consumer_wait", self._m_wait):
+            v = self._queue.get()
         self._m_depth.set(self._queue.qsize(), pipeline=self.name)
         if v is self._SENTINEL:
             # exhausted; an error is held until the already-prefetched batch

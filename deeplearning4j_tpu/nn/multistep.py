@@ -30,11 +30,23 @@ well-defined K-step cadence; per-step scores stay available on device as
 `last_scores`.
 
 Each execution after an epoch's first is one `fit_execution` phase
-(telemetry/trace.py `Tracer.phase`) whose parts fold into it, each with a
-histogram on the default registry: `fit_prepare` (stacking the K batches
-into a plan), `fit_dispatch` (the jitted call until it RETURNS — where a
-host that runs ahead of the device gets held; the compiling call is left
-out) and `fit_listeners` (the per-execution callbacks).
+(telemetry/trace.py `Tracer.phase`), from the first pull of its group to
+the end of its listeners, whose parts fold into it and account for it (at
+least 95 % of the span), each with a histogram on the default registry:
+`fit_next_batch` (the K pulls from the iterator, whatever it is; a
+DevicePrefetcher's `etl_consumer_wait` nests inside it), `fit_prepare`
+(stacking the K batches into a plan), `fit_dispatch` (the jitted call until
+it RETURNS — where a host that runs ahead of the device gets held) and
+`fit_listeners` (the per-execution callbacks). An epoch's first execution
+has the same parts but no `fit_execution` span, and a call that compiles
+runs outside every phase. `fit_executions_ahead_total{ahead}` says, just
+before each dispatch that does not compile, whether the device was still
+fed: "1" when the execution before it has not finished (its `last_scores`
+are not ready; asked, not waited for), "0" when the device had drained —
+the twin of the decode loop's `decode_steps_ahead_total`, countable in an
+untraced run. With dispatch this far ahead of the device the loop's own
+waits (`fit_next_batch`, `etl_consumer_wait`) are time the device is busy,
+so "0" is the starvation signal, not the wait.
 """
 from __future__ import annotations
 
@@ -55,6 +67,8 @@ def _fit_phase(name, help):
 
 
 class MultiStepTrainable:
+    last_scores = None      # [K] device array of the newest execution
+
     def set_update_sharding(self, zero):
         """Install (or with None, remove) a ZeRO-1 sharded update
         (parallel.zero.ZeroUpdater): updater state and the parameter update
@@ -219,6 +233,13 @@ class MultiStepTrainable:
                     self._make_multi_step(), "multi_step:std")
                 out = fn(*args)
             else:
+                prev = self.last_scores
+                get_registry().counter(
+                    "fit_executions_ahead_total",
+                    "Multi-step executions dispatched while the one before "
+                    "was still running (ahead=1) or onto a drained device "
+                    "(ahead=0)").inc(1, ahead="1" if prev is not None
+                                     and not prev.is_ready() else "0")
                 with _fit_phase("fit_dispatch", "The multi-step "
                                 "executable's call until it returns (not "
                                 "until ready), ms"):
@@ -248,16 +269,34 @@ class MultiStepTrainable:
         prepare = prepare or self.prepare_steps
         run = run or (lambda prepared, group: self.fit_prepared(prepared))
         fallback = fallback or self.fit_batch
-        group = []
-        # An epoch's first execution may trace, lower and compile, and it
-        # runs outside the fit_execution phase: on the v5e host a jitted
-        # call that lowers from inside a `with` block took 3-4 s longer per
-        # enclosing block (jaxpr -> MLIR conversion of the 5-step ResNet-50
-        # program: 11.3 s outside, 14.3 s under one block, 18.6-20 s under
-        # two; my chip runs, PR 24; cause not found, not seen on a CPU).
+        it = iter(it)
+        # An epoch's first execution may trace, lower and compile: it keeps
+        # its compile accounting (timed_first_call) and stays out of the
+        # fit_execution span and of fit_dispatch_ms. (PR 24 kept it out of
+        # every `with` block because each one cost the lowering 3-4 s on the
+        # v5e host; the cause was where the caller's frames ended on
+        # CPython's chunked data stack, which timed_first_call now makes
+        # irrelevant: util/stack_room.py.)
         warm = False
 
-        def execute(group):
+        def pull():
+            group = []
+            with _fit_phase("fit_next_batch", "Pulling one group of K "
+                            "batches from the iterator, ms") as nb:
+                for ds in it:
+                    group.append(ds)
+                    if len(group) == K:
+                        break
+                if not group:
+                    nb.cancel()         # the iterator had ended
+            return group
+
+        def execute():
+            """One group: pull, prepare, run. (group, plan): the plan None
+            where the group is ragged or cannot scan."""
+            group = pull()
+            if len(group) < K:
+                return group, None
             with _fit_phase("fit_prepare", "Stacking one group of K device "
                             "batches into an execution plan, ms") as prep:
                 prepared = prepare(group)
@@ -265,31 +304,25 @@ class MultiStepTrainable:
                     prep.cancel()
             if prepared is not None:
                 run(prepared, group)
-            return prepared
+            return group, prepared
 
-        def flush(group):
-            nonlocal warm
-            prepared = None
-            if len(group) == K:
-                if warm:
-                    with get_tracer().phase("fit_execution", steps=K) as ex:
-                        prepared = execute(group)
-                        if prepared is None:
-                            ex.cancel()
-                else:
-                    prepared = execute(group)
-                warm = prepared is not None
+        while True:
+            if warm:
+                with get_tracer().phase("fit_execution", steps=K) as ex:
+                    group, prepared = execute()
+                    if prepared is None:
+                        ex.cancel()
+            else:
+                group, prepared = execute()
+            warm = prepared is not None
             if prepared is None:
                 for ds in group:
                     fallback(ds)
-
-        for ds in it:
-            group.append(ds)
-            if len(group) == K:
-                flush(group)
-                group = []
-        if group:
-            flush(group)
+                if len(group) < K:
+                    return
+            # the K batches and their stacked plan are let go before the
+            # next group is pulled, not when its plan replaces them
+            del group, prepared
 
     def _listeners_need_gradients(self):
         return any(getattr(l, "wants_gradients", False) for l in self.listeners)

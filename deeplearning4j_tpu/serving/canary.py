@@ -75,9 +75,6 @@ class CanaryController:
             "canary_rollbacks_total", "Canaries auto/manually rolled back")
         self.m_promotions.inc(0)
         self.m_rollbacks.inc(0)
-        reg.gauge("canary_fraction",
-                  "Traffic fraction routed to the canary cohort",
-                  fn=lambda: self.fraction)
         reg.gauge(_PROMOTE_RULE,
                   "1 when the canary has baked healthy and may promote",
                   fn=self._promote_ready)

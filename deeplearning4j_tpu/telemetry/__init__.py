@@ -10,16 +10,20 @@ Four cooperating parts, one import surface:
   dispatch -> model step; /generate: generate -> decode_queue_wait,
   decode_prefill, generate_front per request, one decode_wave span per
   scheduler pass) and training (per batch: epoch -> iteration -> jit step;
-  fit(steps_per_execution=K): one fit_execution span per execution),
-  exportable as Chrome-trace/Perfetto JSON. `Tracer.phase` is the one call
-  site for a timed phase of those loops: a `jax.profiler` annotation
-  named "dl4j:<phase>" (so a profiler session shows host phases beside the
-  device's lines, on one clock), a `<phase>_ms` histogram in the registry,
-  and the ring span.
+  fit(steps_per_execution=K): one fit_execution span per execution, from
+  the first pull of its group to its listeners; the input path: one ingest
+  span per prefetched batch), exportable as Chrome-trace/Perfetto JSON.
+  `Tracer.phase` is the one call site for a timed phase of those loops and
+  of the prefetcher's legs (etl_h2d, etl_consumer_wait, ...): a
+  `jax.profiler` annotation named "dl4j:<phase>" (so a profiler session
+  shows host phases beside the device's lines, on one clock, on the thread
+  that ran them), a `<phase>_ms` histogram in the registry (the series
+  `labels=` names), and the ring span.
 - `registry` — central `MetricsRegistry`: thread-safe counters, gauges, and
   bounded histograms with exact-bucket percentiles; ServingMetrics, the
   training listeners, and streaming all register here instead of keeping
-  private state.
+  private state. A counter or histogram read without labels is the total
+  over its label-sets.
 - `prometheus` — text exposition (`/metrics?format=prometheus` on the
   ServingServer and the UI server).
 - `xla` — compile/recompile cost accounting (`compiles_total`,

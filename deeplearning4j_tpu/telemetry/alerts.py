@@ -141,12 +141,10 @@ def _instrument_value(registry, name, percentile=None, labels=None):
         return None
     labels = labels or {}
     if m.kind == "histogram":
-        q = 0.99 if percentile is None else percentile
-        if labels:
-            return m.percentile(q, **labels)
-        # no labels named: aggregate across every label-set, so a rule like
+        # no labels named: the read covers every label-set, so a rule like
         # etl_consumer_starvation sees pipeline=<name> observations too
-        return m.percentile_merged(q)
+        return m.percentile(0.99 if percentile is None else percentile,
+                            **labels)
     v = m.get(**labels)
     if isinstance(v, dict):            # fn-gauge returning {label: value}
         return None

@@ -29,7 +29,7 @@ def _labelkey(labels):
 
 def _quantile(sorted_vals, q):
     """Exact quantile over an already-sorted list, or None when empty (the
-    one implementation behind percentile/percentiles/percentile_merged)."""
+    one implementation behind percentile/percentiles)."""
     if not sorted_vals:
         return None
     idx = min(len(sorted_vals) - 1,
@@ -233,41 +233,38 @@ class Histogram(_Instrument):
         """Recorded exemplars, oldest first: one label-set's when labels are
         given, else the union across every label-set (the alert-rule read)."""
         with self._lock:
-            if labels:
-                st = self._states.get(_labelkey(labels))
-                return [dict(e) for e in st.exemplars] if st else []
-            out = [e for st in self._states.values() for e in st.exemplars]
+            out = [dict(e) for st in self._series(labels)
+                   for e in st.exemplars]
         out.sort(key=lambda e: e["time"])
-        return [dict(e) for e in out]
+        return out
+
+    def _series(self, labels):
+        """The states a read covers, under the lock: one label-set's when
+        labels are given, else every label-set's — a bare read is the total
+        over the series, as `Counter.get()` is (a reader that names no
+        labels must not miss `pipeline=<name>` observations)."""
+        if labels:
+            st = self._states.get(_labelkey(labels))
+            return [st] if st else []
+        return list(self._states.values())
 
     def count(self, **labels):
         with self._lock:
-            st = self._states.get(_labelkey(labels))
-            return st.count if st else 0
+            return sum(st.count for st in self._series(labels))
 
     def sum(self, **labels):
         with self._lock:
-            st = self._states.get(_labelkey(labels))
-            return st.sum if st else 0.0
+            return sum((st.sum for st in self._series(labels)), 0.0)
 
     def _reservoir_copy(self, labels):
         with self._lock:
-            st = self._states.get(_labelkey(labels))
-            return list(st.reservoir) if st else []
+            return [v for st in self._series(labels) for v in st.reservoir]
 
     def percentile(self, q, **labels):
         """Exact percentile over the recent reservoir (sorted OUTSIDE the
-        lock), or None when empty."""
+        lock), or None when empty; without labels, over the union of every
+        label-set's reservoir."""
         vals = self._reservoir_copy(labels)
-        vals.sort()
-        return _quantile(vals, q)
-
-    def percentile_merged(self, q):
-        """Exact percentile over the UNION of every label-set's reservoir —
-        the read an alert rule wants when it names no labels (e.g. consumer
-        wait across all ETL pipelines, which record under pipeline=<name>)."""
-        with self._lock:
-            vals = [v for st in self._states.values() for v in st.reservoir]
         vals.sort()
         return _quantile(vals, q)
 

@@ -36,7 +36,9 @@ Design notes:
   histogram (what `/metrics` and the benchmark read, tracer on or off) and,
   with the tracer enabled, a ring span. Phases of a loop that turns many
   times a second `fold` into their enclosing phase: the ring then holds one
-  span per pass carrying `<phase>_ms` attributes, not one per phase.
+  span per pass carrying `<phase>_ms` attributes, not one per phase. A phase
+  whose histogram keeps one series per owner passes `labels=` (the input
+  path's `pipeline=<name>`); the annotation's name stays the phase's.
 """
 from __future__ import annotations
 
@@ -52,6 +54,7 @@ _tls = threading.local()          # .span: innermost active Span, any tracer
                                   # .phase: innermost active Phase
 
 PROFILER_PREFIX = "dl4j:"         # every phase's name in a profiler trace
+_NO_LABELS = {}                   # a phase that observes the unlabeled series
 _TraceAnnotation = None           # jax.profiler.TraceAnnotation, on first use
 
 
@@ -210,20 +213,24 @@ class Phase:
 
     `fold=True`: no ring span of its own; the duration adds to the
     `<name>_ms` attribute of the nearest enclosing unfolded phase on this
-    thread. `cancel()`: the interval turned out not to be one worth
+    thread (none, as on a worker thread: annotation and histogram only).
+    `labels`: the label values of the histogram's series observed.
+    `cancel()`: the interval turned out not to be one worth
     counting (a pass that did nothing, a call that compiled) — nothing is
     observed and no span recorded. `paused()`: time inside it is taken off
     the duration and left out of the annotation (a handler blocked on a
     future); the ring span keeps the whole interval and says `paused_ms`."""
 
-    __slots__ = ("tracer", "name", "histogram", "parent", "fold",
+    __slots__ = ("tracer", "name", "histogram", "labels", "parent", "fold",
                  "attributes", "start_mono", "end_mono", "paused_s",
                  "_ann", "_outer", "_cancelled")
 
-    def __init__(self, tracer, name, histogram, parent, fold, attributes):
+    def __init__(self, tracer, name, histogram, labels, parent, fold,
+                 attributes):
         self.tracer = tracer
         self.name = str(name)
         self.histogram = histogram
+        self.labels = labels or _NO_LABELS
         self.parent = parent
         self.fold = bool(fold)
         self.attributes = attributes
@@ -268,7 +275,7 @@ class Phase:
             return False
         ms = self.duration_ms
         if self.histogram is not None:
-            self.histogram.observe(ms)
+            self.histogram.observe(ms, **self.labels)
         if self.fold:
             host = self._outer
             while host is not None and host.fold:
@@ -322,11 +329,12 @@ class Tracer:
         return Span(self, name, parent=parent, attributes=attributes)
 
     def phase(self, name, histogram=None, parent=None, fold=False,
-              **attributes):
+              labels=None, **attributes):
         """Context-manager Phase: one call site, three sinks (profiler
-        annotation "dl4j:<name>", `histogram` in ms, ring span). Only the
-        ring span depends on `enabled`."""
-        return Phase(self, name, histogram, parent, fold, attributes)
+        annotation "dl4j:<name>", `histogram` in ms — the series `labels`
+        names, the unlabeled one without —, ring span). Only the ring span
+        depends on `enabled`."""
+        return Phase(self, name, histogram, labels, parent, fold, attributes)
 
     def record_span(self, name, start_mono, end_mono, parent=None,
                     histogram=None, **attributes):

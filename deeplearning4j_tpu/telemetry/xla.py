@@ -20,6 +20,7 @@ that cost first-class metrics:
 from __future__ import annotations
 
 from .registry import get_registry
+from ..util.stack_room import call_with_stack_room
 from ..util.time_source import monotonic_s
 
 
@@ -95,7 +96,10 @@ class _TimedFirstCall:
                 abs_args = abstractify(args)
                 abs_kwargs = abstractify(kwargs)
             t0 = monotonic_s()
-            out = self.__wrapped__(*args, **kwargs)
+            # tracing and lowering make millions of Python calls: with room
+            # on the data stack their cost does not depend on how deep the
+            # caller happens to be (util/stack_room.py)
+            out = call_with_stack_room(self.__wrapped__, *args, **kwargs)
             record_jit_compile(self._label, (monotonic_s() - t0) * 1000.0,
                                registry=self._registry)
             if cost is not None:

@@ -433,16 +433,10 @@ class FaultTolerantTrainer:
         fs.atomic_publish_dir(tmp, final)
 
     def _published(self, final, t0):
-        reg = get_registry()
-        reg.counter("checkpoints_total",
-                    "Durable training checkpoints written").inc(1)
-        dur_ms = (monotonic_s() - t0) * 1000.0
-        reg.counter("checkpoint_ms_total",
-                    "Wall ms spent writing checkpoints").inc(dur_ms)
-        reg.histogram(
+        get_registry().histogram(
             "ckpt_write_ms",
             "Wall ms serializing+publishing one checkpoint (writer side)"
-        ).observe(dur_ms)
+        ).observe((monotonic_s() - t0) * 1000.0)
         name = os.path.basename(final)
         if name.startswith("ckpt-"):
             self._last_good = name

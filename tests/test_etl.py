@@ -431,6 +431,63 @@ def test_device_prefetcher_batches_are_resident():
     pf.close()
 
 
+def test_device_prefetcher_legs_are_phases_labeled_by_pipeline():
+    """Every leg of a batch goes through Tracer.phase into its own
+    histogram under pipeline=<name>, once: the worker's etl_h2d and
+    etl_producer_blocked per batch (etl_device_transform only with a
+    transform), the consumer's wait per pull — not also as a bare
+    observation, which a bare read would now count twice."""
+    reg = MetricsRegistry()
+    x = np.ones((4, 3), np.float32)
+    pf = DevicePrefetcher(ListDataSetIterator([DataSet(x, x)] * 3),
+                          registry=reg, name="legs")
+    assert sum(1 for _ in pf) == 3
+    pf.close()
+    for name, n in (("etl_h2d_ms", 3), ("etl_producer_blocked_ms", 3),
+                    ("etl_device_transform_ms", 0),
+                    ("etl_consumer_wait_ms", 4)):   # 3 batches + the end
+        h = reg.histogram(name)
+        assert h.count(pipeline="legs") == n == h.count(), name
+        assert [ls for ls, _ in h.series()] == [{"pipeline": "legs"}] * (n > 0)
+
+
+def test_device_prefetcher_phases_on_the_profilers_clock(tmp_path):
+    """Under a profiler session the worker's transfer and the consumer's
+    wait are events of the profiler's own trace, on two host lines (two
+    threads): dl4j:etl_h2d / dl4j:etl_producer_blocked where the worker
+    ran them, dl4j:etl_consumer_wait where next() was called."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+    x = np.ones((8, 4), np.float32)
+
+    class Slow(ListDataSetIterator):
+        def next(self):
+            time.sleep(0.01)
+            return super().next()
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        pf = DevicePrefetcher(Slow([DataSet(x, x)] * 4),
+                              registry=MetricsRegistry(), name="prof")
+        assert sum(1 for _ in pf) == 4
+        pf.close()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    lines = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for i, line in enumerate(plane.lines):      # a line a thread
+                for e in line.events:
+                    if e.name.startswith("dl4j:etl_"):
+                        lines.setdefault(e.name, set()).add(i)
+    assert set(lines) == {"dl4j:etl_h2d", "dl4j:etl_producer_blocked",
+                          "dl4j:etl_consumer_wait"}
+    assert lines["dl4j:etl_h2d"] == lines["dl4j:etl_producer_blocked"]
+    assert not lines["dl4j:etl_h2d"] & lines["dl4j:etl_consumer_wait"]
+
+
 def test_device_prefetcher_sharded_placement():
     """Acceptance: sharded prefetch places each batch shard on its mesh
     device — asserted via .devices() / committed placement."""

@@ -278,6 +278,28 @@ def test_alert_histogram_rule_aggregates_across_label_sets(manual_clock):
     assert row["state"] == "firing" and row["value"] == 10_000.0
 
 
+def test_alert_histogram_rule_with_labels_reads_one_series(manual_clock):
+    """The registry's bare read is the merged one (no percentile_merged
+    beside it); a rule that names labels still reads that series alone."""
+    from deeplearning4j_tpu.telemetry.registry import Histogram
+    assert not hasattr(Histogram, "percentile_merged")
+    reg = MetricsRegistry()
+    h = reg.histogram("etl_consumer_wait_ms")
+    for _ in range(20):
+        h.observe(10_000.0, pipeline="starved")
+        h.observe(1.0, pipeline="fed")
+    eng = AlertEngine(registry=reg, interval_s=0)
+    for name, labels in (("any", None), ("fed", {"pipeline": "fed"})):
+        eng.add_rule(AlertRule(name, metric="etl_consumer_wait_ms",
+                               percentile=0.99, threshold=5000.0, op=">",
+                               labels=labels))
+    eng.evaluate()
+    rows = {r["name"]: r for r in eng.state()["rules"]}
+    assert rows["any"]["state"] == "firing" \
+        and rows["any"]["value"] == 10_000.0
+    assert rows["fed"]["state"] == "inactive" and rows["fed"]["value"] == 1.0
+
+
 def test_alert_rule_json_round_trip_and_validation():
     rules = default_serving_rules() + default_training_rules()
     for r in rules:
