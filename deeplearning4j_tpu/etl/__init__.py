@@ -35,8 +35,8 @@ prefetcher's legs as phases on the profiler's clock (`etl_h2d`,
 `etl_device_transform`, `etl_producer_blocked`, `etl_consumer_wait`; each a
 `<leg>_ms{pipeline}` histogram). `etl_consumer_wait_ms` is the
 device-starvation signal for a consumer that waits for its step; under
-`fit(steps_per_execution=K)`, which dispatches ahead, it is the loop's
-blocked time and `fit_executions_ahead_total{ahead="0"}` is the signal
+`fit(steps_per_execution=K)`, which keeps one execution queued behind the
+running one, `fit_executions_ahead_total{ahead="0"}` is the signal
 (prefetch.py).
 """
 from .device_transform import DeviceIngest, lower_normalizer

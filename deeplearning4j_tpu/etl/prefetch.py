@@ -32,7 +32,15 @@ bounds end-to-end training):
 - `transfer_streams=S` splits each large feature array into S row chunks
   `device_put` concurrently: where per-transfer latency (not wire
   bandwidth) bounds throughput, parallel chunked DMA raises sustained h2d.
-  Whether it does on today's machine has not been measured (ROADMAP S2).
+  On a v5e host it does (PR 39's chip run: 38.5 MB in eight chunks 7.7-8.2
+  ms against 38-42 ms whole, under a running program or not). Joining the
+  chunks takes a device program, which waits its turn behind whatever the
+  device is running (485 ms behind a 502 ms train step, PR 39): the worker
+  runs none. It hands the chunks over as they are, a `RowChunks` in the
+  array's place, and whoever uses them joins them: `jnp.asarray` /
+  `np.asarray` of one give the joined array, and
+  `fit(steps_per_execution=K)` joins a group's chunks inside the one
+  program that stacks its plan (nn/multistep.py `prepare_steps`; PR 40).
   Plain/device placement only; sharded placement keeps whole-array puts.
 
 Telemetry: `etl_h2d_bytes_total` counts the bytes that ACTUALLY cross the
@@ -40,11 +48,13 @@ link (post-narrowing). Every leg of a batch is a `Tracer.phase`
 (telemetry/trace.py): a profiler annotation `dl4j:<leg>` on the thread that
 ran it, so a profiler session shows it on the device's clock, and the
 histogram `<leg>_ms{pipeline=<name>}`, tracer on or off. On the worker's
-thread: `etl_h2d` (the puts and the chunks' join up to and INCLUDING the
-fence: where it reads far over what the bytes need, the fence waited behind
-the program the device was running), `etl_device_transform` (only with a
-`device_transform`), `etl_producer_blocked` (`put` into a full queue: the
-healthy state, the worker is ahead). On the consumer's thread:
+thread: `etl_h2d` (the puts and the fence behind them — "DMA done": a whole
+array's put, or a chunked array's parts; no device program is in it, so
+the leg reads what the bytes need whatever the device is running),
+`etl_device_transform` (only with a `device_transform`: a chunked array's
+join, the transform and their fence, which does wait for a device program
+and so behind a running step), `etl_producer_blocked` (`put` into a full queue:
+the healthy state, the worker is ahead). On the consumer's thread:
 `etl_consumer_wait` (`next()` blocked on an empty queue), the histogram and
 `etl_queue_depth` shared with the pipeline executor. With the tracer on,
 every batch also records ONE `ingest` ring span whose `transfer_ms` /
@@ -54,22 +64,60 @@ ingest time goes.
 What the wait says depends on the consumer. One that waits for its step
 before it pulls again (`fit_batch`, an evaluation loop) starves the device
 exactly while it waits: `etl_consumer_wait_ms` ~ 0 means the device never
-starves. `fit(steps_per_execution=K)` dispatches ahead of the device, so its
-loop spends its life in `next()` while the device works: there the wait is
-the loop's blocked time, and the starvation signal is
-`fit_executions_ahead_total{ahead="0"}` (nn/multistep.py). A producer
-error is re-raised exactly once, from next()/has_next() or — if the consumer
-already stopped pulling — from reset()/close().
+starves. `fit(steps_per_execution=K)` keeps one execution queued behind the
+running one and waits for the device in a phase of its own
+(`fit_execution_wait`, nn/multistep.py), with its next group already
+pulled: there too the wait in `next()` is time the worker did not keep up,
+and the starvation signal is `fit_executions_ahead_total{ahead="0"}`. A
+producer error is re-raised exactly once, from next()/has_next() or — if
+the consumer already stopped pulling — from reset()/close().
 """
 from __future__ import annotations
 
 import queue
 import threading
 
+import jax
+import numpy as np
+
 from ..datasets.dataset import DataSet, MultiDataSet
 from ..datasets.iterator.base import DataSetIterator
 from ..telemetry.registry import get_registry
 from ..telemetry.trace import get_tracer
+
+
+class RowChunks:
+    """One array on one device, as the row chunks it crossed the link in,
+    not joined yet. What `DevicePrefetcher(transfer_streams=S)` hands over
+    in a large array's place: joining takes a device program, and that is
+    the consumer's to run — `jnp.asarray` / `np.asarray` of this give the
+    joined array, and a jitted function takes it as a pytree of its parts
+    (how `prepare_steps` joins and stacks a group in one program)."""
+
+    def __init__(self, parts):
+        self.parts = tuple(parts)
+
+    @property
+    def shape(self):
+        return (sum(p.shape[0] for p in self.parts),) + self.parts[0].shape[1:]
+
+    @property
+    def dtype(self):
+        return self.parts[0].dtype
+
+    @property
+    def ndim(self):
+        return self.parts[0].ndim
+
+    def __jax_array__(self):
+        return jax.numpy.concatenate(self.parts, axis=0)
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self.__jax_array__(), dtype)
+
+
+jax.tree_util.register_pytree_node(
+    RowChunks, lambda c: (c.parts, None), lambda _, parts: RowChunks(parts))
 
 
 class DevicePrefetcher(DataSetIterator):
@@ -98,11 +146,12 @@ class DevicePrefetcher(DataSetIterator):
             "etl_consumer_wait_ms",
             "Time the consumer blocked waiting for the next ETL batch")
         self._m_h2d = reg.histogram(
-            "etl_h2d_ms", "One batch's host->device puts, the join of "
-            "their chunks and the fence behind them, ms")
+            "etl_h2d_ms", "One batch's host->device puts and the fence "
+            "behind them, ms")
         self._m_transform = reg.histogram(
             "etl_device_transform_ms",
-            "One batch's device_transform until ready, ms")
+            "One batch's device_transform (and the join of a chunked "
+            "array before it) until ready, ms")
         self._m_blocked = reg.histogram(
             "etl_producer_blocked_ms", "Time the prefetch worker held a "
             "staged batch before the queue took it (full queue: the "
@@ -129,12 +178,13 @@ class DevicePrefetcher(DataSetIterator):
         return self.device
 
     def _transfer(self, a, narrow):
-        """One host array -> device, returning (device_array, host_bytes).
-        Features narrow to `transfer_dtype` BEFORE the DMA; large plain-mode
-        arrays split into `transfer_streams` concurrent chunk puts (latency
-        hiding on links where per-transfer cost, not bandwidth, binds)."""
-        import jax
-        import numpy as np
+        """One host array -> device, returning (on_device, host_bytes):
+        a `jax.Array`, or a `RowChunks` of them. Features narrow to
+        `transfer_dtype` BEFORE the DMA; large plain-mode arrays split into
+        `transfer_streams` concurrent chunk puts (latency hiding on links
+        where per-transfer cost, not bandwidth, binds) and stay in chunks:
+        their join is a device program, which would hold this thread
+        behind whatever program the device runs."""
         a = np.asarray(a)
         if narrow and self.transfer_dtype is not None:
             a = np.asarray(a, self.transfer_dtype)
@@ -145,12 +195,10 @@ class DevicePrefetcher(DataSetIterator):
                      and a.nbytes >= (1 << 20))
         if not chunkable:
             return jax.device_put(a, placement), a.nbytes
-        import jax.numpy as jnp
         chunks = np.array_split(a, self.transfer_streams)
         futs = [self._pool.submit(jax.device_put, c, placement)
                 for c in chunks]
-        parts = [f.result() for f in futs]
-        return jnp.concatenate(parts, axis=0), a.nbytes
+        return RowChunks(f.result() for f in futs), a.nbytes
 
     def _leg(self, name, histogram):
         """One leg of a batch as a phase: `dl4j:<name>` on the calling
@@ -159,7 +207,6 @@ class DevicePrefetcher(DataSetIterator):
                                  labels=self._labels)
 
     def _put(self, ds):
-        import jax
         nbytes = 0
 
         def put(a, narrow=False):
@@ -186,11 +233,13 @@ class DevicePrefetcher(DataSetIterator):
                 feats = [out.features]
             # fence inside the leg: device_put is async, and the leg must
             # mean "DMA done", not "DMA enqueued" (this blocks only the
-            # prefetch worker — the consumer keeps computing)
+            # prefetch worker — the consumer keeps computing). A RowChunks
+            # is a pytree of its parts: the puts are all there is to fence
             jax.block_until_ready([f for f in feats if f is not None])
         end, transform_ms = h2d.end_mono, 0.0
         if self.device_transform is not None:
-            tf = self.device_transform
+            def tf(f):              # a chunked array joined, then transformed
+                return self.device_transform(jax.numpy.asarray(f))
             with self._leg("etl_device_transform", self._m_transform) as dt:
                 if isinstance(out, MultiDataSet):
                     out = MultiDataSet([tf(f) for f in out.features],

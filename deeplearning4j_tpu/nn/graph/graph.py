@@ -21,7 +21,7 @@ from ..conf.configuration import BackpropType
 from ..layers.base import create_layer
 from ..layers import (feedforward, convolution, recurrent, mamba, misc,  # noqa: F401
                       variational)
-from ..multistep import MultiStepTrainable
+from ..multistep import MultiStepTrainable, _step_leaf
 from ...telemetry.xla import timed_first_call
 from ..updaters import apply_gradient_normalization
 from ...optimize.listeners import resolve_listeners
@@ -408,7 +408,7 @@ class ComputationGraph(MultiStepTrainable):
             wrapped.close()        # stop the fit-owned prefetch thread
         return self
 
-    def _prep_batch(self, ds):
+    def _prep_batch(self, ds, keep_chunks=False):
         """(inputs, labels, masks, lmasks) lists of device arrays — the
         per-step leaves both fit_batch and the scanned path consume."""
         from ...datasets.dataset import DataSet, MultiDataSet
@@ -416,7 +416,7 @@ class ComputationGraph(MultiStepTrainable):
             ds = MultiDataSet([ds.features], [ds.labels],
                               None if ds.features_mask is None else [ds.features_mask],
                               None if ds.labels_mask is None else [ds.labels_mask])
-        inputs = [jnp.asarray(f) for f in ds.features]
+        inputs = [_step_leaf(f, keep_chunks) for f in ds.features]
         # with a fused ingest, labels ship raw/narrow (e.g. int class ids)
         # and the one-hot expansion happens inside the compiled step
         labels = [jnp.asarray(l) for l in ds.labels] if self._ingest is not None \

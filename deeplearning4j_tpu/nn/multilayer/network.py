@@ -26,7 +26,7 @@ from ..conf.configuration import MultiLayerConfiguration, BackpropType
 from ..layers.base import create_layer
 from ..layers import (feedforward, convolution, recurrent, mamba, misc,  # noqa: F401 (register impls)
                       variational)
-from ..multistep import MultiStepTrainable
+from ..multistep import MultiStepTrainable, _step_leaf
 from ..updaters import apply_gradient_normalization
 from ...optimize.listeners import resolve_listeners
 from ...telemetry.trace import get_tracer
@@ -400,19 +400,19 @@ class MultiLayerNetwork(MultiStepTrainable):
             wrapped.close()        # stop the fit-owned prefetch thread
         return self
 
-    def _prep_batch(self, ds):
+    def _prep_batch(self, ds, keep_chunks=False):
         """(x, y, mask, lmask) as device arrays — the per-step leaves both
         fit_batch and the scanned multi-step path consume. With an ingest
         fused (`set_ingest`) the arrays stay RAW/NARROW — the widening cast
         happens inside the compiled step, not here."""
         if self._ingest is not None:
-            x = jnp.asarray(ds.features)
+            x = _step_leaf(ds.features, keep_chunks)
             y = jnp.asarray(ds.labels)
             mask = None if ds.features_mask is None else jnp.asarray(ds.features_mask, self._dtype)
             lmask = None if ds.labels_mask is None else jnp.asarray(ds.labels_mask, self._dtype)
             return x, y, mask, lmask
-        x = jnp.asarray(ds.features, self._dtype) \
-            if not str(ds.features.dtype).startswith("int") else jnp.asarray(ds.features)
+        x = _step_leaf(ds.features, keep_chunks, self._dtype) \
+            if not str(ds.features.dtype).startswith("int") else _step_leaf(ds.features, keep_chunks)
         y = jnp.asarray(ds.labels, self._dtype)
         mask = None if ds.features_mask is None else jnp.asarray(ds.features_mask, self._dtype)
         lmask = None if ds.labels_mask is None else jnp.asarray(ds.labels_mask, self._dtype)

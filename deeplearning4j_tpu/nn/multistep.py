@@ -20,8 +20,8 @@ have drawn. Configs the scan can't honor (non-SGD solvers, ragged TBPTT
 windows, gradient-hungry listeners, mismatched shapes within a group) fall
 back to per-batch steps.
 
-Each class provides:
-  _prep_batch(ds)    -> per-step pytree of device arrays (masks may be None)
+Each class provides (keep_chunks: leave a prefetcher's RowChunks unjoined):
+  _prep_batch(ds, keep_chunks=False) -> per-step pytree (masks may be None)
   _scan_loss(p, states, x, y, rng, mask, lmask) -> (score, new_states)
   _multi_step_mode(prepped) -> "std" | "tbptt" | None
 
@@ -30,23 +30,23 @@ well-defined K-step cadence; per-step scores stay available on device as
 `last_scores`.
 
 Each execution after an epoch's first is one `fit_execution` phase
-(telemetry/trace.py `Tracer.phase`), from the first pull of its group to
-the end of its listeners, whose parts fold into it and account for it (at
-least 95 % of the span), each with a histogram on the default registry:
-`fit_next_batch` (the K pulls from the iterator, whatever it is; a
-DevicePrefetcher's `etl_consumer_wait` nests inside it), `fit_prepare`
-(stacking the K batches into a plan), `fit_dispatch` (the jitted call until
-it RETURNS — where a host that runs ahead of the device gets held) and
-`fit_listeners` (the per-execution callbacks). An epoch's first execution
-has the same parts but no `fit_execution` span, and a call that compiles
-runs outside every phase. `fit_executions_ahead_total{ahead}` says, just
-before each dispatch that does not compile, whether the device was still
-fed: "1" when the execution before it has not finished (its `last_scores`
-are not ready; asked, not waited for), "0" when the device had drained —
-the twin of the decode loop's `decode_steps_ahead_total`, countable in an
-untraced run. With dispatch this far ahead of the device the loop's own
-waits (`fit_next_batch`, `etl_consumer_wait`) are time the device is busy,
-so "0" is the starvation signal, not the wait.
+(telemetry/trace.py `Tracer.phase`), first pull of its group to end of its
+listeners; its folded parts account for it (>= 95 % of the span), each with
+a `<name>_ms` histogram on the default registry: `fit_next_batch` (the K
+pulls from the iterator; a DevicePrefetcher's `etl_consumer_wait` nests in
+it: time the input path did not keep up), `fit_execution_wait` (below),
+`fit_prepare` (stacking the K batches into a plan), `fit_dispatch` (the
+jitted call until it RETURNS) and `fit_listeners`. An epoch's first
+execution has the same parts but no span, and a call that compiles runs
+outside every phase. ONE EXECUTION IN FLIGHT (PR 40): before it stacks the
+plan of execution N + 1 the loop waits, in `fit_execution_wait`, until
+N - 1 has finished (`last_scores` it holds turn ready), so N + 1 is queued
+while N runs and nothing further: the running plan, the queued one and one
+pulled group are alive, whatever the host's lead. No knob: a device that
+outruns the host never makes it wait. `fit_executions_ahead_total{ahead}`,
+counted just before each dispatch that does not compile, says whether the
+device was still fed: "1" the execution before has not finished (asked, not
+waited for), "0" the device had drained: the starvation signal.
 """
 from __future__ import annotations
 
@@ -195,7 +195,9 @@ class MultiStepTrainable:
         execution plan for `fit_prepared`, or None when this group can't
         scan. The plan is reusable: its batch leaves are never donated
         (re-running a TBPTT plan replays the same rng table; the std plan
-        draws fresh rngs from the carried chain)."""
+        draws fresh rngs from the carried chain). A std plan is built by
+        ONE device program (`_stack_steps`), which also joins features
+        that a DevicePrefetcher handed over in row chunks."""
         if self.params is None:
             self.init()
         self._check_trainable()
@@ -203,17 +205,17 @@ class MultiStepTrainable:
         # host->device transfer for the whole group — an ineligible config
         # would otherwise re-prep (and re-transfer) every batch in the
         # fit_batch fallback
-        first = self._prep_batch(group[0])
+        first = self._prep_batch(group[0], keep_chunks=True)
         mode = self._multi_step_mode(first)
         if mode is None:
             return None
-        prepped = [first] + [self._prep_batch(ds) for ds in group[1:]]
+        prepped = [first] + [self._prep_batch(ds, keep_chunks=True)
+                             for ds in group[1:]]
         try:
             if mode == "std":
-                stacked = jax.tree_util.tree_map(lambda *a: jnp.stack(a),
-                                                 *prepped)
-                return "std", stacked, len(group)
-            return self._prepare_tbptt(prepped)   # MLN-only; may be None
+                return "std", _stack_steps(prepped), len(group)
+            return self._prepare_tbptt(jax.tree_util.tree_map(
+                jnp.asarray, prepped, is_leaf=_is_chunks))  # MLN-only
         except ValueError:
             return None  # shape or mask-structure mismatch within the group
 
@@ -260,12 +262,17 @@ class MultiStepTrainable:
                 listener.iteration_done(self, self.iteration_count)
         return self
 
+    # `last_scores` of the two newest executions this loop dispatched,
+    # older first: what the one-in-flight rule of _fit_grouped waits on
+    _in_flight = ()
+
     def _fit_grouped(self, it, K, prepare=None, run=None, fallback=None):
         """One epoch: full groups of K go through the compiled scan; ragged
         tails and incompatible groups fall back to per-batch steps. The
         prepare/run/fallback hooks default to this model's own methods;
         ShardedTrainer reuses the same accumulation loop with its sharded
-        prepare and mesh-scoped run."""
+        prepare and mesh-scoped run — and with it the rule that exactly one
+        execution is queued behind the running one (module docstring)."""
         prepare = prepare or self.prepare_steps
         run = run or (lambda prepared, group: self.fit_prepared(prepared))
         fallback = fallback or self.fit_batch
@@ -297,6 +304,15 @@ class MultiStepTrainable:
             group = pull()
             if len(group) < K:
                 return group, None
+            if len(self._in_flight) == 2:
+                # N - 1 and N are out: N + 1 is neither stacked nor
+                # dispatched before N - 1 has finished. Nothing else paces
+                # a host that is handed batches faster than the device
+                # runs them; a device that keeps up never waits here
+                with _fit_phase("fit_execution_wait", "Waiting, before "
+                                "execution N + 1 is stacked, until N - 1 "
+                                "has finished, ms"):
+                    jax.block_until_ready(self._in_flight[0])
             with _fit_phase("fit_prepare", "Stacking one group of K device "
                             "batches into an execution plan, ms") as prep:
                 prepared = prepare(group)
@@ -304,6 +320,8 @@ class MultiStepTrainable:
                     prep.cancel()
             if prepared is not None:
                 run(prepared, group)
+                self._in_flight = (self._in_flight
+                                   + (self.last_scores,))[-2:]
             return group, prepared
 
         while True:
@@ -329,3 +347,29 @@ class MultiStepTrainable:
 
     def _prepare_tbptt(self, prepped):
         return None  # ComputationGraph: TBPTT groups fall back to fit_batch
+
+
+def _is_chunks(a):
+    from ..etl.prefetch import RowChunks
+    return isinstance(a, RowChunks)
+
+
+def _step_leaf(a, keep_chunks, dtype=None):
+    """One array of a batch as a per-step leaf on the device. A `RowChunks`
+    (what a DevicePrefetcher with transfer_streams hands over) stays in its
+    chunks for `prepare_steps`, which joins them inside the stack's
+    program; everyone else gets the joined array."""
+    if keep_chunks and _is_chunks(a) and dtype in (None, a.dtype):
+        return a
+    return jnp.asarray(a, dtype)
+
+
+@jax.jit
+def _stack_steps(prepped):
+    """K per-step pytrees -> one with a leading [K] axis, in ONE device
+    program: leaf by leaf the stack, and where a leaf arrives as RowChunks
+    the chunks' join inside it (a join of its own is a program more a
+    batch, queued behind the running execution with a second copy of the
+    batch alive until it runs)."""
+    return jax.tree_util.tree_map(lambda *a: jnp.stack(a), *prepped,
+                                  is_leaf=_is_chunks)

@@ -259,6 +259,87 @@ def test_prefetcher_multi_stream_chunked_put_matches():
     np.testing.assert_array_equal(np.asarray(ds.features), x)
 
 
+@pytest.mark.parametrize("kind", ["dataset", "multidataset"])
+def test_prefetcher_fences_the_chunk_puts_not_their_join(kind, monkeypatch):
+    """With transfer_streams > 1 the worker's `etl_h2d` leg waits for the
+    chunks' transfers and for nothing else: joining them takes a device
+    program (it would wait its turn behind a running train step), so the
+    worker runs none and hands the chunks over as a RowChunks. What is
+    delivered still equals the host array bit for bit."""
+    import jax
+    from deeplearning4j_tpu.datasets.dataset import MultiDataSet
+    from deeplearning4j_tpu.etl.prefetch import RowChunks
+    n, d = 64, 512 * 9         # > 1 MiB of float32 so chunking engages
+    x = np.random.default_rng(1).normal(size=(n, d)).astype(np.float32)
+    y = np.ones((n, 2), np.float32)
+    item = DataSet(x, y) if kind == "dataset" else MultiDataSet([x, x[::-1]],
+                                                                [y])
+    fenced = []
+    real = jax.block_until_ready
+
+    def spy(tree):
+        fenced.extend(leaf.shape for leaf in jax.tree_util.tree_leaves(tree))
+        return real(tree)
+    monkeypatch.setattr(jax, "block_until_ready", spy)
+    pf = DevicePrefetcher(ListDataSetIterator([item]),
+                          registry=MetricsRegistry(), transfer_streams=4)
+    ds = pf.next()
+    pf.close()
+    monkeypatch.undo()
+    arrays = 1 if kind == "dataset" else 2
+    assert fenced == [(n // 4, d)] * (4 * arrays)   # never the joined (n, d)
+    if kind == "dataset":
+        assert isinstance(ds.features, RowChunks) and ds.num_examples() == n
+        assert [p.shape for p in ds.features.parts] == [(n // 4, d)] * 4
+        np.testing.assert_array_equal(np.asarray(ds.features), x)
+        np.testing.assert_array_equal(np.asarray(jnp.asarray(ds.features)), x)
+    else:
+        np.testing.assert_array_equal(np.asarray(ds.features[0]), x)
+        np.testing.assert_array_equal(np.asarray(ds.features[1]), x[::-1])
+        np.testing.assert_array_equal(np.asarray(ds.labels[0]), y)
+
+
+@pytest.mark.parametrize("kind", ["multilayer", "graph"])
+def test_chunked_batches_serve_every_consumer(kind):
+    """A RowChunks stands in for the array wherever a prefetched batch
+    goes: output, score, evaluate, fit_batch and both fit loops take it."""
+    from deeplearning4j_tpu import (Adam, ComputationGraph, DenseLayer,
+                                    InputType, NeuralNetConfiguration,
+                                    OutputLayer)
+    from deeplearning4j_tpu.etl.prefetch import RowChunks
+    n, d = 64, 4096                          # 1 MiB a batch: chunking engages
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(3 * n, d)).astype(np.float32)
+    y = np.eye(3, dtype=np.float32)[rng.integers(0, 3, 3 * n)]
+    if kind == "graph":
+        net = ComputationGraph(
+            NeuralNetConfiguration.builder().seed(1).updater(Adam(1e-2))
+            .graph_builder().add_inputs("in")
+            .add_layer("d", DenseLayer(n_out=8, activation="relu"), "in")
+            .add_layer("out", OutputLayer(n_out=3, activation="softmax",
+                                          loss="MCXENT"), "d")
+            .set_outputs("out")
+            .set_input_types(InputType.feed_forward(d)).build()).init()
+    else:
+        net = _tabular_net(d)
+    pf = DevicePrefetcher(ListDataSetIterator(DataSet(x, y).batch_by(n)),
+                          registry=MetricsRegistry(), transfer_streams=4)
+    ds = pf.next()
+    assert isinstance(ds.features, RowChunks)
+    np.testing.assert_allclose(np.asarray(net.output(ds.features)),
+                               np.asarray(net.output(x[:n])), rtol=1e-6)
+    assert np.isfinite(net.score(ds))
+    net.fit_batch(ds)
+    pf.reset()
+    assert 0.0 <= net.evaluate(pf).accuracy() <= 1.0
+    pf.reset()
+    net.fit(pf)                              # per batch
+    pf.reset()
+    net.fit(pf, steps_per_execution=3)       # one scanned execution
+    pf.close()
+    assert net.iteration_count == 7 and net.last_scores.shape == (3,)
+
+
 def test_prefetcher_sharded_mode_applies_transform_under_sharding():
     import jax
     from deeplearning4j_tpu.parallel.sharding import make_mesh

@@ -544,6 +544,28 @@ def test_device_prefetcher_error_on_close_exactly_once():
     pf.close()                               # second close: clean
 
 
+def test_device_prefetcher_error_once_behind_a_chunked_batch():
+    """A chunked batch is handed over in its chunks, the worker having
+    fenced their transfers and nothing else; the producer's error behind
+    it still reaches the consumer exactly once, after the staged batch."""
+    class Boom(ListDataSetIterator):
+        def next(self):
+            if self._i == 1:
+                raise RuntimeError("producer died")
+            return super().next()
+
+    x = np.arange(3 * 32 * 8192, dtype=np.float32).reshape(96, 8192)
+    pf = DevicePrefetcher(Boom(DataSet(x, x[:, :2]).batch_by(32)),
+                          queue_size=2, registry=MetricsRegistry(),
+                          transfer_streams=4)     # 1 MiB a batch: chunked
+    assert pf.has_next()
+    np.testing.assert_array_equal(np.asarray(pf.next().features), x[:32])
+    with pytest.raises(RuntimeError, match="producer died"):
+        pf.has_next()
+    assert not pf.has_next()                 # raised once, then just ended
+    pf.close()                               # and not again on close
+
+
 def test_fit_prefetch_knob():
     from deeplearning4j_tpu import (NeuralNetConfiguration, InputType,
                                     DenseLayer, OutputLayer,

@@ -107,7 +107,8 @@ def _tbptt_conf(T_unused=None):
             .layer(RnnOutputLayer(n_out=3, activation="softmax",
                                   loss="MCXENT"))
             .input_type(InputType.recurrent(4))
-            .backprop_type("tbptt").tbptt_fwd_length(4).tbptt_back_length(4)
+            .backprop_type("truncated_bptt").tbptt_fwd_length(4)
+            .tbptt_back_length(4)
             .build())
 
 
@@ -162,23 +163,23 @@ def test_multi_step_tbptt_ragged_windows_fall_back():
                                rtol=1e-6, atol=1e-7)
 
 
+def _mk_graph():
+    from deeplearning4j_tpu import ComputationGraph
+    conf = (NeuralNetConfiguration.builder().seed(9).updater(Adam(1e-2))
+            .graph_builder()
+            .add_inputs("in")
+            .add_layer("d", DenseLayer(n_out=16, activation="relu"), "in")
+            .add_layer("out", OutputLayer(n_out=3, activation="softmax",
+                                          loss="MCXENT"), "d")
+            .set_outputs("out")
+            .set_input_types(InputType.feed_forward(8)).build())
+    return ComputationGraph(conf).init()
+
+
 def test_multi_step_computation_graph_parity():
     """ComputationGraph shares the mixin: scanned groups == singles."""
-    from deeplearning4j_tpu import ComputationGraph
-
-    def build():
-        conf = (NeuralNetConfiguration.builder().seed(9).updater(Adam(1e-2))
-                .graph_builder()
-                .add_inputs("in")
-                .add_layer("d", DenseLayer(n_out=16, activation="relu"), "in")
-                .add_layer("out", OutputLayer(n_out=3, activation="softmax",
-                                              loss="MCXENT"), "d")
-                .set_outputs("out")
-                .set_input_types(InputType.feed_forward(8)).build())
-        return ComputationGraph(conf).init()
-
     sets = _batches(6)
-    a, b = build(), build()
+    a, b = _mk_graph(), _mk_graph()
     a.fit(ListDataSetIterator(sets))
     b.fit(ListDataSetIterator(sets), steps_per_execution=3)
     for pa, pb in zip(jax.tree_util.tree_leaves(a.params),
@@ -187,6 +188,56 @@ def test_multi_step_computation_graph_parity():
                                    rtol=1e-5, atol=1e-6)
     assert a.iteration_count == b.iteration_count == 6
     assert b.last_scores.shape == (3,)
+
+
+def _in_row_chunks(sets, n_chunks=4):
+    """The same batches as a DevicePrefetcher(transfer_streams=n_chunks)
+    hands them over: features in row chunks on the device, not joined."""
+    from deeplearning4j_tpu.etl.prefetch import RowChunks
+    return [DataSet(RowChunks(jax.device_put(c) for c in
+                              np.array_split(ds.features, n_chunks)),
+                    ds.labels) for ds in sets]
+
+
+@pytest.mark.parametrize("kind", ["multilayer", "graph", "tbptt"])
+def test_prepare_steps_joins_row_chunks_inside_the_plan(kind, monkeypatch):
+    """Features that arrive as RowChunks are joined by the program that
+    stacks the plan (no join program of their own, no second copy of the
+    batch), the plan and the training it drives equal the host arrays';
+    a TBPTT group and fit_batch, which need the array, join them first."""
+    from deeplearning4j_tpu.etl.prefetch import RowChunks
+    if kind == "tbptt":
+        build = lambda: MultiLayerNetwork(_tbptt_conf()).init()
+        sets = [DataSet(np.repeat(d.features, 4, 0), np.repeat(d.labels, 4, 0))
+                for d in _tbptt_sets(T=12)]          # 8 rows: 4 chunks of 2
+    else:
+        build, sets = (_mk_graph if kind == "graph" else _mk_net), _batches(4)
+    chunked = _in_row_chunks(sets)
+    joins = []                      # was a join traced (in a program) or run?
+    real = RowChunks.__jax_array__
+    monkeypatch.setattr(RowChunks, "__jax_array__", lambda c: (
+        joins.append(isinstance(c.parts[0], jax.core.Tracer)), real(c))[1])
+    a, b = build(), build()
+    plan_a, plan_b = a.prepare_steps(sets[:2]), b.prepare_steps(chunked[:2])
+    assert plan_a[0] == plan_b[0] == ("tbptt" if kind == "tbptt" else "std")
+    # std: both batches' chunks joined while the ONE plan program was
+    # traced; tbptt: two joins of their own before its window plan
+    assert joins == [kind != "tbptt"] * 2
+    for la, lb in zip(jax.tree_util.tree_leaves(plan_a[1]),
+                      jax.tree_util.tree_leaves(plan_b[1])):
+        np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
+    a.fit(ListDataSetIterator(sets), steps_per_execution=2)
+    b.fit(ListDataSetIterator(chunked), steps_per_execution=2)
+    for pa, pb in zip(jax.tree_util.tree_leaves(a.params),
+                      jax.tree_util.tree_leaves(b.params)):
+        np.testing.assert_array_equal(np.asarray(pa), np.asarray(pb))
+    # the per-batch path takes them too, joined on its own
+    del joins[:]
+    b.fit_batch(chunked[0])
+    a.fit_batch(sets[0])
+    assert joins == [False]
+    np.testing.assert_allclose(float(a.score_value), float(b.score_value),
+                               rtol=1e-6)
 
 
 def test_prepare_steps_reusable_executable():

@@ -936,10 +936,12 @@ def test_generate_response_times_and_request_spans():
         body = json.loads(urllib.request.urlopen(req, timeout=120).read())
         front = srv.metrics.registry.get("generate_front_ms")
         # the answer leaves from inside the pass that emitted the last
-        # token: that pass's span is recorded when the pass closes
+        # token: that pass's span is recorded when the pass closes, and the
+        # handler's own once it has written the answer
         for _ in range(200):
             spans = srv.tracer.finished_spans()
-            if sum(s.name == "decode_wave" for s in spans) >= 7:
+            if sum(s.name == "decode_wave" for s in spans) >= 7 \
+                    and any(s.name == "generate" for s in spans):
                 break
             threading.Event().wait(0.01)
     finally:
@@ -1124,6 +1126,139 @@ def test_fit_executions_ahead_counts_a_fed_device():
     # at least three find the one before still running (a loaded test
     # machine may stall the loop once)
     assert grew["0"] + grew["1"] == 5 and grew["1"] >= 3
+
+
+class _StubExecution:
+    """What `run` leaves in `last_scores` for an execution a test finishes
+    by hand: ready only once released."""
+
+    def __init__(self):
+        self._done = threading.Event()
+
+    def is_ready(self):
+        return self._done.is_set()
+
+    def block_until_ready(self):
+        assert self._done.wait(30), "the test never released this execution"
+        return self
+
+    def release(self):
+        self._done.set()
+
+
+def _stub_fit(net, n_groups, K, on_run=None):
+    """`net._fit_grouped` over n_groups * K batches on its own thread, its
+    prepare / run hooks stubs: a plan is its group's number, an execution a
+    _StubExecution. Returns (thread, log, executions): the log holds
+    ("pull" | "prepare" | "run", group) in the order the loop did them."""
+    log, executions = [], []
+
+    def batches():
+        for i in range(n_groups * K):
+            if i % K == 0:
+                log.append(("pull", i // K))
+            yield i
+
+    def prepare(group):
+        log.append(("prepare", group[0] // K))
+        return ("plan", group[0] // K)
+
+    def run(prepared, group):
+        ex = _StubExecution()
+        executions.append(ex)
+        net.last_scores = ex
+        log.append(("run", prepared[1]))
+        if on_run is not None:
+            on_run(ex)
+
+    t = threading.Thread(target=net._fit_grouped, daemon=True, args=(
+        batches(), K), kwargs=dict(prepare=prepare, run=run,
+                                   fallback=lambda ds: None))
+    t.start()
+    return t, log, executions
+
+
+def _settled(log, want, timeout=20.0):
+    """True once the log equals `want` and stays so for a moment."""
+    import time
+    deadline = time.monotonic() + timeout
+    while list(log) != want and time.monotonic() < deadline:
+        time.sleep(0.005)
+    time.sleep(0.15)                # anything further would show by now
+    return list(log) == want
+
+
+def test_fit_keeps_exactly_one_execution_queued_behind_the_running_one():
+    """The run-ahead rule of fit(steps_per_execution=K): N + 1 is dispatched
+    while N is unfinished; N + 2 may be pulled, but is neither stacked nor
+    dispatched until N has finished; never more than two plans alive."""
+    net = _fit_probe_net()
+    assert net._in_flight == ()
+    t, log, executions = _stub_fit(net, 5, 2)
+    step = lambda n: [("pull", n), ("prepare", n), ("run", n)]
+    want = step(0) + step(1) + [("pull", 2)]
+    # 0 runs, 1 is queued behind it, 2 is pulled and waits for 0
+    assert _settled(log, want), log
+    for n in range(3):
+        # plans alive: stacked and their execution not finished
+        assert len(executions) == n + 2
+        assert [e.is_ready() for e in executions] == [True] * n + [False] * 2
+        executions[n].release()
+        want += step(n + 2)[1:] + ([("pull", n + 3)] if n + 3 < 5 else [])
+        assert _settled(log, want), (n, log)
+    # the iterator has ended: the loop returns with 3 and 4 still out (its
+    # caller drains), and the next epoch's first execution waits for 3
+    t.join(20)
+    assert not t.is_alive()
+    assert net._in_flight == (executions[3], executions[4])
+    assert not executions[3].is_ready()
+
+
+def test_fit_execution_wait_is_a_folded_part_of_the_execution():
+    """The wait for execution N - 1 is a phase of fit_execution like the
+    loop's others: folded into the span, with its histogram, and with it
+    the parts still account for at least 95 % of the span."""
+    import queue
+    import time
+    from deeplearning4j_tpu.telemetry import enable_tracing, get_tracer
+    tracer, reg = get_tracer(), get_registry()
+    was = tracer.enabled
+    enable_tracing()
+    count = lambda: (reg.get("fit_execution_wait_ms").count()
+                     if reg.get("fit_execution_wait_ms") else 0)
+    try:
+        tracer.clear()
+        before = count()
+        # a "device" that takes 200 ms an execution, one after the other
+        queued = queue.Queue()
+
+        def device():
+            for ex in iter(queued.get, None):
+                time.sleep(0.2)
+                ex.release()
+        threading.Thread(target=device, daemon=True).start()
+        t, log, executions = _stub_fit(_fit_probe_net(), 5, 2,
+                                       on_run=queued.put)
+        t.join(30)
+        queued.put(None)
+        assert not t.is_alive()
+        spans = [s for s in tracer.finished_spans()
+                 if s.name == "fit_execution"]
+    finally:
+        tracer.enabled = was
+    assert len(spans) == 4 and count() - before == 3
+    parts = FIT_PARTS + ("fit_execution_wait_ms",)
+    # the epoch's first execution has no span; the second finds one
+    # execution out and does not wait; 2, 3 and 4 each wait for the one
+    # before the last: about one execution's length, the host being fast
+    assert ["fit_execution_wait_ms" in s.attributes for s in spans] \
+        == [False, True, True, True]
+    for s in spans[1:]:
+        assert s.attributes["fit_execution_wait_ms"] >= 100.0
+        total = sum(s.attributes.get(n, 0.0) for n in parts)
+        assert 0.95 * s.duration_ms <= total <= s.duration_ms
+    assert not any(s.name == "fit_execution_wait"
+                   for s in tracer.finished_spans())
 
 
 def test_fit_compiling_call_is_in_no_phase():
