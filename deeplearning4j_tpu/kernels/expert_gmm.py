@@ -54,7 +54,19 @@ def row_tile(pairs, n_experts, itemsize):
     """Rows a tile for `pairs` (token, expert) pairs spread over `n_experts`:
     the power of two that holds twice an expert's mean share, so nearly
     every expert is one tile and its matrices stream once; at least a packed
-    sublane tile (16 rows of bfloat16), at most 512."""
+    sublane tile (16 rows of bfloat16), at most 512.
+
+    The floor is what MANY SMALL GROUPS pay. `granite4_h_small`'s decode
+    step lays 80 pairs over 18 held experts (4.4 rows a group in tiles of
+    16: 3.6 rows computed a pair). `ling3_flash`'s lays 126 pairs over 64
+    held experts — one routing group of 512 under group-limited routing, 2
+    rows a group at the mean, 53 of the 64 touched — into 53 tiles of 16:
+    846 rows computed for 126 pairs, 6.8 a pair (counted over 20 seeded
+    routers at 128 rows; 2.0 a pair at 512 and 1,024 rows). The padding
+    costs arithmetic the MXU has to spare and no bytes: a tile streams its
+    expert's matrices once whatever it holds, so the step's cost is the
+    touched experts' 11.8 MB each, and an 8-row tile would save nothing a
+    bfloat16 sublane tile allows."""
     want = max(32 // itemsize, 2 * pairs // max(1, n_experts))
     return next((t for t in _ROW_TILES if t >= want), _ROW_TILES[-1])
 

@@ -402,6 +402,49 @@ class Mamba2Layer(_NoActivationConf, BaseRecurrentConf):
 
 @register_layer_conf
 @dataclass
+class KimiDeltaAttentionLayer(_NoActivationConf, BaseRecurrentConf):
+    """Kimi Delta Attention mixer (Kimi Linear, arXiv:2510.26692 section 3):
+    linear attention whose per-head state S [head_dim, head_dim] is updated
+    by a delta rule under a per-channel decay, [b,t,f] -> [b,t,n_out]; q, k
+    and v each pass a causal depthwise conv of `d_conv` taps and SiLU, q and
+    k are L2-normalised a head, the decay's log is `gate_lower_bound` *
+    sigmoid(...) (bounded, every projection full rank), the output a
+    per-head RMS norm times a sigmoid gate. Runtime: nn/layers/kda.py — the
+    chunked form for sequences, a per-token step on the fixed-size state
+    for decode."""
+    n_heads: int = 4
+    head_dim: int = 32
+    d_conv: int = 4
+    chunk_size: int = 64
+    gate_lower_bound: float = -5.0
+    eps: float = 1e-6
+    use_pallas: bool = False
+
+
+@register_layer_conf
+@dataclass
+class LatentAttentionLayer(_NoActivationConf, BaseRecurrentConf):
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434), causal:
+    keys and values are up-projections of ONE `kv_lora_rank`-wide normed
+    latent a token, beside one `qk_rope_head_dim`-wide rotary key shared by
+    all heads, so the decode cache holds kv_lora_rank + qk_rope_head_dim
+    values a token whatever the head count. Full-rank queries (no q
+    compression), rotary positions on adjacent pairs, a sigmoid gate a head
+    on the output. Runtime: nn/layers/mla.py — the plain form for sequences
+    and prefill, the absorbed form (scores against the cached row) for a
+    decode step."""
+    n_heads: int = 4
+    kv_lora_rank: int = 64
+    qk_nope_head_dim: int = 32
+    qk_rope_head_dim: int = 16
+    v_head_dim: int = 32
+    rope_theta: float = 10000.0
+    eps: float = 1e-6
+    use_pallas: bool = False
+
+
+@register_layer_conf
+@dataclass
 class GravesLSTM(BaseRecurrentConf):
     """LSTM with peephole connections (reference: nn/conf/layers/GravesLSTM.java,
     runtime nn/layers/recurrent/LSTMHelpers.java — the per-timestep Java gemm
@@ -467,7 +510,15 @@ class MixtureOfExpertsLayer(FeedForwardLayerConf):
     returns their part of the sum, the gates as published (not renormalised
     over the held). Expert weights are expert-major [held, ...]: sharding
     axis 0 over a mesh axis is expert parallelism. Works on [b, f] and
-    time-distributed [b, t, f]."""
+    time-distributed [b, t, f].
+
+    Two regimes of the grouped product meet here. Few large groups
+    (`granite4_h_small`: 18 held experts at 4.4 rows a decode step) and MANY
+    SMALL GROUPS (`ling3_flash`: 64 held of 512 under group-limited sigmoid
+    routing, 2 rows each at the mean of a 128-slot step): every non-empty
+    group still costs one whole row tile (16 rows of bfloat16), so the
+    grouped operand is mostly padding there: 6.8 rows computed a pair
+    against 3.6 (kernels/expert_gmm.py `row_tile` has the count)."""
     n_experts: int = 4
     hidden_mult: int = 2
     top_k: int = 2
@@ -476,6 +527,16 @@ class MixtureOfExpertsLayer(FeedForwardLayerConf):
     experts_held: int | None = None
     first_expert: int = 0
     use_pallas: bool = False        # the gated form's grouped-product kernel
+    # "softmax": gates are the softmax over the chosen logits. "sigmoid"
+    # (DeepSeek-V3 `noaux_tc`): scores s = sigmoid(logits); the choice is by
+    # s + b with b a selection-only bias leaf (`expert_bias`); a group's
+    # score is the sum of its two largest s + b, the `topk_groups` best of
+    # `n_groups` equal groups stay, `top_k` experts are taken inside them;
+    # gates = routed_scaling * s / sum of the chosen s (no b in the gates)
+    score_function: str = "softmax"
+    n_groups: int = 1
+    topk_groups: int | None = None  # default: all groups
+    routed_scaling: float = 1.0
 
     def get_output_type(self, input_type):
         if isinstance(input_type, RecurrentInputType):

@@ -19,7 +19,7 @@ from ..conf.graph_configuration import (ComputationGraphConfiguration,
                                         DuplicateToTimeSeriesVertex)
 from ..conf.configuration import BackpropType
 from ..layers.base import create_layer
-from ..layers import (feedforward, convolution, recurrent, mamba, misc,  # noqa: F401
+from ..layers import (feedforward, convolution, recurrent, mamba, kda, mla, misc,  # noqa: F401
                       variational)
 from ..multistep import MultiStepTrainable, _step_leaf
 from ...telemetry.xla import timed_first_call

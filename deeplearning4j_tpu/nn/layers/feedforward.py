@@ -348,6 +348,8 @@ class MixtureOfExpertsLayerModule(BaseLayerModule):
         first = int(c.first_expert)
         if not (0 < held and 0 <= first and first + held <= E):
             raise ValueError(f"experts {first}..{first + held - 1} of {E}")
+        if E % int(c.n_groups):
+            raise ValueError(f"{E} experts in {c.n_groups} equal groups")
         hidden = int(c.n_hidden) if c.n_hidden is not None \
             else int(c.hidden_mult) * int(c.n_out)
         return E, held, first, hidden, min(int(c.top_k), E)
@@ -369,6 +371,8 @@ class MixtureOfExpertsLayerModule(BaseLayerModule):
         if not c.gated:
             params["b1"] = jnp.zeros((held, hidden), dtype)
             params["b2"] = jnp.zeros((held, n_out), dtype)
+        if c.score_function == "sigmoid":
+            params["route_bias"] = jnp.zeros((E,), dtype)   # selection only
         from ..conf.inputs import RecurrentInputType
         out_t = (InputType.recurrent(n_out)
                  if isinstance(input_type, RecurrentInputType)
@@ -376,13 +380,37 @@ class MixtureOfExpertsLayerModule(BaseLayerModule):
         return params, {}, out_t
 
     def route(self, params, xt):
-        """xt [T, f] -> (experts [T, k] int32, gates [T, k] float32): the k
-        largest router logits, in float32, and their softmax."""
+        """xt [T, f] -> (experts [T, k] int32, gates [T, k] float32), the
+        router in float32. "softmax": the k largest logits and their
+        softmax. "sigmoid": s = sigmoid(logits); chosen by s + route_bias —
+        of `n_groups` equal groups the `topk_groups` whose two largest s +
+        bias sum highest, then the k largest inside them; gates =
+        routed_scaling * s / sum of the chosen s: the bias moves the choice
+        and never a gate."""
+        c = self.conf
+        E, k = int(c.n_experts), self._sizes()[4]
         acc = jnp.promote_types(xt.dtype, jnp.float32)
         r = jnp.dot(xt.astype(acc), params["Wg"].astype(acc),
                     precision=jax.lax.Precision.HIGHEST)
-        top, experts = jax.lax.top_k(r, self._sizes()[4])
-        return experts, jax.nn.softmax(top, axis=-1)
+        if c.score_function == "softmax":
+            top, experts = jax.lax.top_k(r, k)
+            return experts, jax.nn.softmax(top, axis=-1)
+        if c.score_function != "sigmoid":
+            raise ValueError(f"score_function {c.score_function!r}")
+        s = jax.nn.sigmoid(r)
+        pick = s + params["route_bias"].astype(acc)
+        G = int(c.n_groups)
+        keep = G if c.topk_groups is None else int(c.topk_groups)
+        if keep < G:
+            per = pick.reshape(-1, G, E // G)
+            best = jnp.sum(jax.lax.top_k(per, 2)[0], axis=-1)     # [T, G]
+            _, groups = jax.lax.top_k(best, keep)
+            kept = jnp.any(groups[:, :, None] == jnp.arange(G), axis=1)
+            pick = jnp.where(kept[:, :, None], per, -jnp.inf).reshape(-1, E)
+        _, experts = jax.lax.top_k(pick, k)
+        chosen = jnp.take_along_axis(s, experts, axis=1)
+        return experts, float(c.routed_scaling) * chosen \
+            / jnp.sum(chosen, axis=-1, keepdims=True)
 
     def layout(self, params, xt):
         """xt [T, f] -> where every (token, expert) pair of the experts held

@@ -1,0 +1,254 @@
+"""Kimi Delta Attention mixer (Kimi Linear, arXiv:2510.26692 section 3): linear
+attention whose state is updated by a delta rule under a per-channel decay
+(conf: nn/conf/layers.py KimiDeltaAttentionLayer — NEW, no reference
+counterpart). With x the layer's input at position t, H heads of D:
+
+    (q, k, v, f, gate) = split(x W_in)      five H x D wide; beta = sigmoid(x Wb)
+    (q, k, v) = silu(causal depthwise conv_K(q | k | v))       no bias
+    q <- q / |q|_2 * D^-1/2 ;  k <- k / |k|_2                  a head
+    g = lower_bound * sigmoid(exp(A_log) (f + dt_bias))         in [lower_bound, 0]
+    S_t = (I - beta_t k_t k_t^T) Diag(exp g_t) S_{t-1} + beta_t k_t v_t^T
+    o_t = S_t^T q_t ;   out = [RMSNorm_D(o_t) * sigmoid(gate)] Wo
+
+Mamba-2's state (nn/layers/mamba.py) decays by one scalar a head and is
+added to; this one decays by a value a channel and is CORRECTED: the rank-1
+update reads S^T k of the decayed state, so neither `ssd_chunked` nor
+`ssm_step` expresses it. Three formulations of the same recurrence:
+
+- `forward` (training, output(), and the decode prefill): `kda_chunked`, in
+  chunks of `chunk_size`, in `jax.numpy`; autodiff gives the backward. A
+  masked position gets g = 0 and beta = 0: the state passes through it
+  unchanged.
+- `decode_step`: one token a slot against the slot's state, in place
+  (kernels.kda_step); the convs are a 4-tap product with the slot's tail.
+- the sequential scan over positions is the reference's
+  (benchmarks/reference/ling3_flash.py), which both are held against.
+
+Decode state, per slot: `state` [H, D, D] (d_k major, d_v on the lanes) in
+the accumulation dtype — float32 under bfloat16: the recurrence compounds
+over every token of a session; `conv`, the last K - 1 raw inputs of the
+three convs side by side, [K - 1, 3 H D] in the cache dtype. Neither grows
+with the sequence and neither rewinds by a length reset
+(`decode_rewindable = False`). The decay, the convs, the normalisations, the
+chunked form (its contractions at `highest`) and the output norm's
+statistics run in float32; the projections in the activations' dtype.
+"""
+from __future__ import annotations
+
+import numpy as np
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from .base import (BaseLayerModule, CacheLeaf, apply_dropout,
+                   note_cache_entry, register_impl)
+from .convolution import rms_norm
+from .recurrent import _acc_dtype
+from ..weights import init_weights
+from ..conf.inputs import InputType
+
+_HIGHEST = lax.Precision.HIGHEST
+_L2_EPS = 1e-6
+
+
+def kda_chunked(q, k, v, g, beta, chunk):
+    """The delta rule over a whole sequence, chunk by chunk.
+
+    q, k, v, g [b, t, H, D] (q and k normalised; g <= 0 the decay's log, 0
+    at masked positions), beta [b, t, H] (0 at masked positions), one dtype
+    (float32 in practice) -> o [b, t, H, D] and the state after the last
+    position, [b, H, D, D]. Inside a chunk, with G_r the sum of g up to and
+    including r and S_0 the state entering it:
+
+        A_ij = beta_i sum_d k_i[d] k_j[d] exp(G_i[d] - G_j[d])     j < i
+        (I + A) U = beta * (V - (K * exp G) S_0)      unit lower-triangular
+        o_r = S_0^T (q_r * exp G_r)
+              + sum_{i <= r} (sum_d q_r[d] k_i[d] exp(G_r[d] - G_i[d])) u_i
+        S_end = Diag(exp G_C) S_0 + sum_i (k_i * exp(G_C - G_i)) u_i^T
+
+    Every exponent is formed as a difference <= 0 BEFORE it is
+    exponentiated: a chunk at the gate's bound sums to hundreds below zero,
+    where exp(G_i) / exp(G_j) is 0 / 0 in float32."""
+    b, T, H, D = q.shape
+    Q = min(int(chunk), T)
+    pad = -T % Q
+    if pad:                 # g = 0, beta = 0: padding leaves the state alone
+        q, k, v, g = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                      for a in (q, k, v, g))
+        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+    nc = (T + pad) // Q
+    chunks = lambda a: jnp.moveaxis(a.reshape((b, nc, Q) + a.shape[2:]), 1, 0)
+    upto = jnp.tril(jnp.ones((Q, Q), bool))                  # j <= i
+    eye = jnp.eye(Q, dtype=q.dtype)
+
+    def one(S0, c):
+        qc, kc, vc, gc, bc = c                               # [b, Q, H, ..]
+        G = jnp.cumsum(gc, axis=1)
+        from_start = jnp.exp(G)                              # [b, Q, H, D]
+        diff = G[:, :, None] - G[:, None, :]                 # [b, i, j, H, D]
+        E = jnp.exp(jnp.where(upto[None, :, :, None, None], diff, -jnp.inf))
+        kk = jnp.einsum("bihd,bjhd,bijhd->bhij", kc, kc, E,
+                        precision=_HIGHEST)
+        A = jnp.moveaxis(bc, 1, 2)[..., None] * kk * (1 - eye)
+        rhs = bc[..., None] * (vc - jnp.einsum(
+            "bihd,bhdv->bihv", kc * from_start, S0, precision=_HIGHEST))
+        U = jax.scipy.linalg.solve_triangular(
+            A + eye, jnp.moveaxis(rhs, 1, 2), lower=True,
+            unit_diagonal=True)                              # [b, H, Q, D]
+        qk = jnp.einsum("bihd,bjhd,bijhd->bhij", qc, kc, E,
+                        precision=_HIGHEST)
+        o = jnp.einsum("bihd,bhdv->bihv", qc * from_start, S0,
+                       precision=_HIGHEST) \
+            + jnp.einsum("bhij,bhjv->bihv", qk, U, precision=_HIGHEST)
+        to_end = jnp.exp(G[:, -1:] - G)                      # [b, Q, H, D]
+        S = from_start[:, -1][..., None] * S0 + jnp.einsum(
+            "bihd,bhiv->bhdv", kc * to_end, U, precision=_HIGHEST)
+        return S, o
+
+    last, o = lax.scan(one, jnp.zeros((b, H, D, D), q.dtype),
+                       tuple(chunks(a) for a in (q, k, v, g, beta)))
+    o = jnp.moveaxis(o, 0, 1).reshape(b, T + pad, H, D)
+    return o[:, :T], last
+
+
+@register_impl("KimiDeltaAttentionLayer")
+class KimiDeltaAttentionLayerModule(BaseLayerModule):
+    decode_rewindable = False
+
+    def dims(self):
+        """(H, D, K, H * D)."""
+        c = self.conf
+        H, D = int(c.n_heads), int(c.head_dim)
+        return H, D, int(c.d_conv), H * D
+
+    def init(self, rng, input_type, dtype=jnp.float32):
+        """A uniform in [1, 16], dt_bias 0, the norm 1, the convs uniform
+        +- 1/sqrt(K) (Mamba2Layer's conventions)."""
+        c = self.conf
+        H, D, K, HD = self.dims()
+        n_in, n_out = int(c.n_in), int(c.n_out)
+        k1, k2, k3, k4, k5 = jax.random.split(rng, 5)
+        mk = lambda k, i, o: init_weights(k, (i, o), c.weight_init, fan_in=i,
+                                          fan_out=o, distribution=c.dist,
+                                          dtype=dtype)
+        params = {
+            "W_in": mk(k1, n_in, 5 * HD),
+            "Wb": mk(k2, n_in, H),
+            "conv_W": (jax.random.uniform(k3, (K, 3 * HD), jnp.float32, -1.0,
+                                          1.0) / np.sqrt(K)).astype(dtype),
+            "dt_bias": jnp.zeros((HD,), dtype),
+            "A_log": jnp.log(jax.random.uniform(k4, (H,), jnp.float32,
+                                                1.0, 16.0)).astype(dtype),
+            "norm": jnp.ones((D,), dtype),
+            "Wo": mk(k5, HD, n_out),
+        }
+        return params, {}, InputType.recurrent(n_out)
+
+    # -- the pieces the legs share ---------------------------------------------
+    def _project(self, params, u):
+        """u [.., f] -> the convs' raw input [.., 3 H D], f and gate
+        [.., H D], beta [.., H] (float32)."""
+        HD = self.dims()[3]
+        qkv, f, gate = jnp.split(u @ params["W_in"], [3 * HD, 4 * HD],
+                                 axis=-1)
+        acc = _acc_dtype(u.dtype)
+        return qkv, f, gate, jax.nn.sigmoid((u @ params["Wb"]).astype(acc))
+
+    def _rule_inputs(self, params, conv, f):
+        """The convs' output and the raw f -> q, k (normalised), v, g
+        [.., H, D], all in the conv's dtype (float32; float64 under x64)."""
+        H, D, _, _ = self.dims()
+        acc = conv.dtype
+        heads = lambda a: a.reshape(a.shape[:-1] + (H, D))
+        q, k, v = (heads(a) for a in jnp.split(conv, 3, axis=-1))
+        l2 = lambda a: a * lax.rsqrt(jnp.sum(jnp.square(a), axis=-1,
+                                             keepdims=True) + _L2_EPS)
+        A = jnp.exp(params["A_log"].astype(acc))[:, None]
+        g = float(self.conf.gate_lower_bound) * jax.nn.sigmoid(
+            A * heads(f.astype(acc) + params["dt_bias"].astype(acc)))
+        return l2(q) * D ** -0.5, l2(k), v, g
+
+    def _finish(self, params, o, gate, out_dtype):
+        """Per-head norm, sigmoid gate, output projection."""
+        HD = self.dims()[3]
+        y = rms_norm(o, params["norm"], self.conf.eps) * jax.nn.sigmoid(
+            gate.astype(o.dtype)).reshape(o.shape)
+        y = y.reshape(y.shape[:-2] + (HD,))
+        return self.activation_fn()(y.astype(out_dtype) @ params["Wo"])
+
+    # -- forward ---------------------------------------------------------------
+    def forward(self, params, state, u, *, train=False, rng=None, mask=None,
+                return_state=False):
+        """return_state: also (the state after the last unmasked position
+        [b, H, D, D], the convs' raw inputs [b, t, 3 H D])."""
+        c = self.conf
+        K = int(c.d_conv)
+        u = apply_dropout(u, c.dropout, train, rng)
+        acc = _acc_dtype(u.dtype)
+        qkv, f, gate, beta = self._project(params, u)
+        T = u.shape[1]
+        with jax.named_scope("kda_conv"):
+            w = params["conv_W"].astype(acc)
+            xp = jnp.pad(qkv.astype(acc), ((0, 0), (K - 1, 0), (0, 0)))
+            conv = jax.nn.silu(sum(xp[:, k:k + T] * w[k] for k in range(K)))
+        with jax.named_scope("kda_chunk"):
+            q, k, v, g = self._rule_inputs(params, conv, f)
+            if mask is not None:
+                m = mask.astype(acc)[:, :, None]
+                g, beta = g * m[..., None], beta * m
+            o, last = kda_chunked(q, k, v, g, beta, c.chunk_size)
+        out = self._finish(params, o, gate, u.dtype)
+        if mask is not None:
+            out = out * mask[:, :, None].astype(out.dtype)
+        if return_state:
+            return out, state, mask, (last, qkv)
+        return out, state, mask
+
+    # -- decode ----------------------------------------------------------------
+    def decode_unsupported(self):
+        return None
+
+    def decode_entry(self, geom):
+        H, D, K, HD = self.dims()
+        return note_cache_entry(geom, "state", {
+            "state": CacheLeaf((geom.slots, H, D, D), _acc_dtype(geom.dtype),
+                               1),
+            "conv": CacheLeaf((geom.slots, K - 1, 3 * HD), geom.dtype, 2)})
+
+    def decode_prefill(self, params, state, u, entry, ctx):
+        """Both rows of the slot are overwritten whole: a reused slot
+        carries nothing over. The conv tail is the raw q | k | v at
+        positions length - K + 1 .. length - 1, zeros before position 0."""
+        K = int(self.conf.d_conv)
+        y, _, _, (last, qkv) = self.forward(params, state, u, mask=ctx.mask,
+                                            return_state=True)
+        z = jnp.zeros((), ctx.slot.dtype)
+        xp = jnp.pad(qkv, ((0, 0), (K - 1, 0), (0, 0)))
+        zl = jnp.zeros((), ctx.length.dtype)
+        tail = lax.dynamic_slice(xp, (zl, ctx.length, zl),
+                                 (1, K - 1, xp.shape[2]))
+        return y, {
+            "state": lax.dynamic_update_slice(
+                entry["state"], last.astype(entry["state"].dtype),
+                (ctx.slot, z, z, z)),
+            "conv": lax.dynamic_update_slice(
+                entry["conv"], tail.astype(entry["conv"].dtype),
+                (ctx.slot, z, z))}
+
+    def decode_step(self, params, state, u, entry, ctx):
+        from ...kernels import kda_step
+        acc = entry["state"].dtype
+        qkv, f, gate, beta = self._project(params, u[:, 0])     # [S, ..]
+        with jax.named_scope("kda_conv"):
+            window = jnp.concatenate(
+                [entry["conv"], qkv[:, None].astype(entry["conv"].dtype)],
+                axis=1)                                     # [S, K, 3 H D]
+            conv = jax.nn.silu(jnp.sum(
+                window.astype(acc) * params["conv_W"].astype(acc), axis=1))
+        with jax.named_scope("kda_step"):
+            q, k, v, g = self._rule_inputs(params, conv, f)
+            new, o = kda_step(
+                entry["state"], jnp.exp(g), k, q, beta, v,
+                use_pallas=getattr(self.conf, "use_pallas", False))
+        out = self._finish(params, o, gate, u.dtype)
+        return out[:, None], {"state": new, "conv": window[:, 1:]}

@@ -24,7 +24,7 @@ import optax
 
 from ..conf.configuration import MultiLayerConfiguration, BackpropType
 from ..layers.base import create_layer
-from ..layers import (feedforward, convolution, recurrent, mamba, misc,  # noqa: F401 (register impls)
+from ..layers import (feedforward, convolution, recurrent, mamba, kda, mla, misc,  # noqa: F401 (register impls)
                       variational)
 from ..multistep import MultiStepTrainable, _step_leaf
 from ..updaters import apply_gradient_normalization

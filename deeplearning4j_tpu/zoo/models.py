@@ -315,6 +315,105 @@ def granite_hybrid_lm(vocab_size=256, d_model=64, n_layers=10, n_heads=4,
     return ComputationGraph(gb.build())
 
 
+def ling_hybrid_lm(vocab_size=256, d_model=160, n_layers=6, n_heads=2,
+                   ffn_mult=2.4, layer_group_size=6, first_k_dense=2,
+                   kda_head_dim=128, kda_d_conv=4, kda_chunk_size=64,
+                   kda_lower_bound=-5.0, kv_lora_rank=512,
+                   qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+                   rope_theta=6000000.0, n_experts=512, n_groups=8,
+                   topk_groups=4, experts_per_token=8, routed_scaling=2.5,
+                   expert_hidden=768, shared_hidden=768, experts_held=None,
+                   first_expert=0, expert_swiglu_limits=(),
+                   shared_swiglu_limits=(), rms_norm_eps=1e-6,
+                   dtype="float32", seed=12345, use_pallas=False,
+                   updater=None):
+    """Hybrid linear-attention / latent-attention expert decoder of the
+    `bailing_hybrid` shape (inclusionAI/Ling-3.0-flash): pre-norm blocks h +=
+    mixer(RMSNorm(h)); h += ffn(RMSNorm(h)). Layer i (0-based) mixes with a
+    LatentAttentionLayer when (i + 1) % layer_group_size == 0, else with a
+    KimiDeltaAttentionLayer; its ffn is a gated SiLU feed-forward of width
+    int(d_model * ffn_mult) for i < first_k_dense, else `experts_per_token`
+    of `n_experts` routed gated experts of width `expert_hidden` — sigmoid
+    scores, a selection-only bias, the `topk_groups` best of `n_groups`
+    groups, gates renormalised and times `routed_scaling` — beside a shared
+    expert of width `shared_hidden` on the same norm; this model holds
+    `experts_held` of the routed experts from `first_expert` on (default:
+    all; the rest of the sum is another chip's). h_0 = E[ids]; probabilities
+    = softmax(RMSNorm(h) W_head^T), the head untied. Input one-hot [b, t,
+    vocab].
+
+    The published clamp on an expert's gated product
+    (`expert_swiglu_limit_list`, `share_expert_swiglu_limit_list`) is NOT
+    implemented: a non-zero limit among those given is refused here rather
+    than guessed at. The default updater is plain SGD: it keeps no state
+    beside the parameters."""
+    from ..nn.conf.layers import (GatedDenseLayer, KimiDeltaAttentionLayer,
+                                  LatentAttentionLayer, LMHeadLayer,
+                                  MixtureOfExpertsLayer, RMSNormalization)
+    if any(expert_swiglu_limits) or any(shared_swiglu_limits):
+        raise ValueError(
+            "a non-zero swiglu limit is not implemented: the source gives "
+            "the limit and not the clamp's form (expert_swiglu_limits "
+            f"{list(expert_swiglu_limits)}, shared_swiglu_limits "
+            f"{list(shared_swiglu_limits)})")
+    gb = (NeuralNetConfiguration.builder()
+          .seed(seed).updater(updater or Sgd(learning_rate=1e-3))
+          .weight_init("xavier").dtype(dtype)
+          .graph_builder()
+          .add_inputs("tokens"))
+    norm = lambda: RMSNormalization(eps=rms_norm_eps)
+
+    def residual(name, prev, branch):
+        gb.add_vertex(name, ElementWiseVertex("add"), prev, branch)
+        return name
+
+    gb.add_layer("embed", DenseLayer(n_out=d_model, activation="identity"),
+                 "tokens")
+    prev = "embed"
+    for i in range(n_layers):
+        gb.add_layer(f"b{i}_norm1", norm(), prev)
+        if (i + 1) % layer_group_size == 0:
+            mixer = f"b{i}_mla"
+            gb.add_layer(mixer, LatentAttentionLayer(
+                n_out=d_model, n_heads=n_heads, kv_lora_rank=kv_lora_rank,
+                qk_nope_head_dim=qk_nope_head_dim,
+                qk_rope_head_dim=qk_rope_head_dim, v_head_dim=v_head_dim,
+                rope_theta=rope_theta, eps=rms_norm_eps,
+                use_pallas=use_pallas), f"b{i}_norm1")
+        else:
+            mixer = f"b{i}_kda"
+            gb.add_layer(mixer, KimiDeltaAttentionLayer(
+                n_out=d_model, n_heads=n_heads, head_dim=kda_head_dim,
+                d_conv=kda_d_conv, chunk_size=kda_chunk_size,
+                gate_lower_bound=kda_lower_bound, eps=rms_norm_eps,
+                use_pallas=use_pallas), f"b{i}_norm1")
+        prev = residual(f"b{i}_res1", prev, mixer)
+        gb.add_layer(f"b{i}_norm2", norm(), prev)
+        dense = i < first_k_dense
+        ffn = f"b{i}_mlp"
+        gb.add_layer(ffn, GatedDenseLayer(
+            n_out=d_model, n_hidden=int(d_model * ffn_mult) if dense
+            else shared_hidden), f"b{i}_norm2")
+        if not dense:
+            gb.add_layer(f"b{i}_moe", MixtureOfExpertsLayer(
+                n_out=d_model, n_experts=n_experts, top_k=experts_per_token,
+                gated=True, n_hidden=expert_hidden, experts_held=experts_held,
+                first_expert=first_expert, score_function="sigmoid",
+                n_groups=n_groups, topk_groups=topk_groups,
+                routed_scaling=routed_scaling, use_pallas=use_pallas,
+                activation="identity"), f"b{i}_norm2")
+            ffn = f"b{i}_ffn"
+            gb.add_vertex(ffn, ElementWiseVertex("add"), f"b{i}_moe",
+                          f"b{i}_mlp")
+        prev = residual(f"b{i}_res2", prev, ffn)
+    gb.add_layer("norm", norm(), prev)
+    gb.add_layer("out", LMHeadLayer(n_out=vocab_size, activation="softmax",
+                                    loss="MCXENT"), "norm")
+    gb.set_outputs("out")
+    gb.set_input_types(InputType.recurrent(vocab_size))
+    return ComputationGraph(gb.build())
+
+
 def vgg16(num_classes=1000, image_size=224, seed=12345):
     """VGG16 (reference: trainedmodels/TrainedModels.java VGG16)."""
     b = (NeuralNetConfiguration.builder()

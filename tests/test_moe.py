@@ -221,36 +221,64 @@ def test_expert_gmm_blocks_and_fallback():
 
 
 # -------------------------------------------- the share and the whole layer
-def test_four_shares_and_the_shared_expert_add_up_to_the_uncut_layer():
-    """model-configs guide, section 4: the expert layer built four times
-    with first_expert 0, 1/4, 1/2, 3/4 of the experts and the same router;
-    the four routed parts, plus the shared expert counted once, are the
-    uncut reference's whole layer (every expert on every row)."""
-    d, hidden, shared, n, k = 16, 8, 12, 8, 3
+def _softmax_case():
+    """`granite4_h_small`'s: softmax over the chosen logits, 8 experts, 3 a
+    token, four shares of two."""
+    return dict(n=8, k=3, shares=4, conf={}, leaves={},
+                gates=lambda x, Wg, leaves: ref.gates_of(x, Wg, 3))
+
+
+def _sigmoid_case():
+    """`ling3_flash`'s: sigmoid scores, a selection-only bias, the 4 best of
+    8 groups, 8 experts a token, gates renormalised and x 2.5; 64 experts,
+    eight shares of one routing group each."""
+    from benchmarks.reference import ling3_flash as ling
+    bias = jnp.asarray(np.random.RandomState(1).randn(64) * 0.01)
+    return dict(
+        n=64, k=ling.EXPERTS_PER_TOKEN, shares=8,
+        conf=dict(score_function="sigmoid", n_groups=ling.N_GROUPS,
+                  topk_groups=ling.TOPK_GROUPS,
+                  routed_scaling=ling.ROUTED_SCALING),
+        leaves={"route_bias": bias},
+        gates=lambda x, Wg, leaves: ling.gates_of(x, Wg, leaves["route_bias"],
+                                                  x.dtype))
+
+
+@pytest.mark.parametrize("case", [_softmax_case, _sigmoid_case],
+                         ids=["granite4_h_small", "ling3_flash"])
+def test_the_shares_and_the_shared_expert_add_up_to_the_uncut_layer(case):
+    """model-configs guide, section 4: the expert layer built once a share
+    with first_expert 0, 1/n, 2/n .. of the experts and the same router; the
+    routed parts, plus the shared expert counted once, are the uncut
+    reference's whole layer (every expert on every row)."""
+    c = case()
+    d, hidden, shared, n, k = 16, 8, 12, c["n"], c["k"]
+    held = n // c["shares"]
     rng = np.random.RandomState(0)
     Wg, W1, W2 = (jnp.asarray(rng.randn(*s) * 0.5) for s in
                   ((d, n), (n, d, 2 * hidden), (n, hidden, d)))
     W_in, W_out = (jnp.asarray(rng.randn(*s) * 0.5) for s in
                    ((d, 2 * shared), (shared, d)))
     x = jnp.asarray(rng.randn(11, d))
-    gates = ref.gates_of(x, Wg, k)
+    gates = c["gates"](x, Wg, c["leaves"])
     # float32 gates (the router's dtype) on float64 rows
     g, u = jnp.split(x @ W_in, 2, axis=-1)
     whole = ref.expert_sum(x, gates, W1, W2, x.dtype, lambda a: a) \
         + (jax.nn.silu(g) * u) @ W_out
     parts = []
-    for first in (0, 2, 4, 6):
+    for first in range(0, n, held):
         mod = MixtureOfExpertsLayerModule(MixtureOfExpertsLayer(
             n_in=d, n_out=d, n_experts=n, top_k=k, gated=True,
-            n_hidden=hidden, experts_held=2, first_expert=first,
-            activation="identity"))
-        share = {"Wg": Wg, "W1": W1[first:first + 2],
-                 "W2": W2[first:first + 2]}
+            n_hidden=hidden, experts_held=held, first_expert=first,
+            activation="identity", **c["conf"]))
+        share = dict(c["leaves"], Wg=Wg, W1=W1[first:first + held],
+                     W2=W2[first:first + held])
         parts.append(mod.forward(share, {}, x)[0])
         # and the reference given that share is that part
         np.testing.assert_allclose(parts[-1], ref.expert_sum(
-            x, gates[:, first:first + 2], share["W1"], share["W2"], x.dtype,
-            lambda a: a), atol=1e-6)
+            x, gates[:, first:first + held], share["W1"], share["W2"],
+            x.dtype, lambda a: a), atol=1e-6)
+    assert sum(float(jnp.abs(p).max()) > 0 for p in parts) > c["shares"] // 2
     np.testing.assert_allclose(sum(parts) + (jax.nn.silu(g) * u) @ W_out,
                                whole, atol=1e-6)
 

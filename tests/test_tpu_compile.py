@@ -472,6 +472,59 @@ def test_expert_gmm_compiles_at_the_cells_widths(on_chip, tokens, tm):
     assert comp.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
+def test_kda_step_compiles_in_place(on_chip):
+    """The delta-rule state update at `ling3_flash`'s size, 128 slots x [32,
+    128, 128] float32: one kernel — its per-head columns sliced from ONE
+    packed lane tile, which Mosaic has to accept —, the donated state aliased
+    onto its output (no second 268 MB buffer, no copy of it)."""
+    from deeplearning4j_tpu.kernels import kda_step
+    S, H, D = 128, 32, 128
+    row = on_chip((S, H, D), jnp.float32)
+    comp = jax.jit(
+        lambda st, a, k, q, b, v: kda_step(st, a, k, q, b, v,
+                                           interpret=False),
+        donate_argnums=(0,)).lower(
+            on_chip((S, H, D, D), jnp.float32), row, row, row,
+            on_chip((S, H), jnp.float32), row).compile()
+    text = comp.as_text()
+    assert text.count(KERNEL) == 1
+    assert len(re.findall(r"%kda_step[.\d]* = ", text)) == 1
+    assert relayouts(text, S * H * D * D) == []
+    mem = comp.memory_analysis()
+    assert mem.alias_size_in_bytes == S * H * D * D * 4
+    assert mem.temp_size_in_bytes < 16 << 20
+
+
+def test_mla_decode_and_latent_append_compile_on_the_padded_row(on_chip):
+    """The latent cache at `ling3_flash`'s size, 128 slots x 3,072 x 640
+    bfloat16 (576 padded to five lane tiles: a 576-wide copy is refused, the
+    slice must be aligned to the tiling): the decode kernel reads the slab
+    where it lies, the append writes it in place."""
+    from deeplearning4j_tpu.kernels import latent_append, mla_decode
+    S, C, W, H, R = 128, 3072, 640, 32, 512
+    slab = on_chip((S, C, W), jnp.bfloat16)
+    text = compiled_text(
+        lambda q, lat, n: mla_decode(q, lat, n, rank=R, interpret=False),
+        on_chip((S, H, W), jnp.bfloat16), slab, on_chip((S,), jnp.int32))
+    assert text.count(KERNEL) == 1
+    assert len(re.findall(r"%mla_decode[.\d]* = ", text)) == 1
+    assert relayouts(text, S * C * W) == []
+    comp = jax.jit(
+        lambda lat, rows, pos: latent_append(lat, rows, pos, interpret=False),
+        donate_argnums=(0,)).lower(
+            slab, on_chip((S, W), jnp.bfloat16),
+            on_chip((S,), jnp.int32)).compile()
+    text = comp.as_text()
+    assert len(re.findall(r"%latent_append[.\d]* = ", text)) == 1
+    assert relayouts(text, S * C * W) == [] and loops(text) == []
+    assert comp.memory_analysis().alias_size_in_bytes == S * C * W * 2
+    with pytest.raises(Exception, match="aligned to tiling"):
+        compiled_text(
+            lambda q, lat, n: mla_decode(q, lat, n, rank=R, interpret=False),
+            on_chip((S, H, 576), jnp.bfloat16),
+            on_chip((S, C, 576), jnp.bfloat16), on_chip((S,), jnp.int32))
+
+
 def test_untileable_shape_has_no_compiled_plan():
     """Why chip_smoke.py asks for prompts of 128 tokens and more: compiled,
     the key block must be a multiple of 128, so a 64-token prefill bucket or
@@ -662,6 +715,45 @@ def test_routed_decode_step_compiles_with_one_expert_kernel_a_layer(
     text = _prefill_text(eng, 128, one_chip)
     assert len(re.findall(r"%expert_gmm_1x128[.\d]* = ", text)) == 2
     assert loops(text) == []
+
+
+def test_ling_decode_step_compiles_with_its_three_new_kernels(
+        one_chip, chip_config, monkeypatch):
+    """One period of `ling_hybrid_lm` (5 KDA + 1 MLA; two dense and four
+    routed ffns) at two of the configuration's 32 heads, its own 128-wide
+    heads, 512-wide latent and 768-wide experts, bfloat16, 16 slots of 256:
+    a KDA layer is ONE `kda_step` kernel in the step, the MLA layer one
+    `latent_append` and one `mla_decode`, an expert layer one
+    `expert_gmm_16x1`; no loop, no copy of a state or of the latent slab.
+    (The whole step at the cell's size — 128 slots of 3,072, 32 heads —
+    compiles here in 19 s with 6.05 GB of arguments and 0.02 GB of
+    temporaries, its 1,024-token prefill in 45 s with 0.26 GB: PERF.md
+    section 4.)"""
+    from deeplearning4j_tpu.decode.engine import DecodeEngine
+    from deeplearning4j_tpu.zoo.models import ling_hybrid_lm
+    for module in ("kda_step", "mla_decode", "expert_gmm"):
+        monkeypatch.setattr(
+            importlib.import_module("deeplearning4j_tpu.kernels." + module),
+            "_interpret_default", lambda: False)
+    net = ling_hybrid_lm(vocab_size=512, d_model=256, n_layers=6, n_heads=2,
+                         experts_held=64, dtype="bfloat16",
+                         use_pallas=True).init()
+    eng = DecodeEngine(net, slots=16, max_len=256)
+    args = _abstract((net.params, net.states, eng.init_cache(),
+                      np.zeros((eng.slots,), np.int32),
+                      eng._greedy_step_ops), one_chip)
+    text = eng._build_step().lower(*args, None).compile().as_text()
+    assert text.count(KERNEL) == 11
+    assert len(re.findall(r"%kda_step[.\d]* = ", text)) == 5
+    assert len(re.findall(r"%mla_decode[.\d]* = ", text)) == 1
+    assert len(re.findall(r"%latent_append[.\d]* = ", text)) == 1
+    assert len(re.findall(r"%expert_gmm_16x1[.\d]* = ", text)) == 4
+    assert loops(text) == []
+    assert relayouts(text, 16 * 2 * 128 * 128) == []
+    assert relayouts(text, 16 * 256 * 640) == []
+    text = _prefill_text(eng, 128, one_chip)
+    assert len(re.findall(r"%expert_gmm_1x128[.\d]* = ", text)) == 4
+    assert "kda_step" not in text and "mla_decode" not in text
 
 
 @pytest.mark.slow
