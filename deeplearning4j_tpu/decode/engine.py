@@ -327,8 +327,9 @@ class DecodeEngine:
     def _node_scope(self, node):
         """jax.named_scope of one walked node: its name on its operations
         in the lowered text and in a device trace, the output node's under
-        `lm_head`. Within an attention layer the cache write is `kv_append`
-        and the read `attention`."""
+        `lm_head`. Within an attention layer a step's append and read are
+        one kernel under `attention` (`kv_append` where they are two calls,
+        and for a prefill's write)."""
         return jax.named_scope(node.name if node.name != self.output_name
                                else "lm_head/" + node.name)
 

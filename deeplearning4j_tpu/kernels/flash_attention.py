@@ -46,10 +46,16 @@ iota against the slot's length in SMEM. A block wholly past the slot's
 length is neither copied nor computed on, so a step's HBM traffic follows
 the lengths, not the capacity. What selects the kernel is the entry point;
 nothing of the cache is folded, copied or masked in HBM on the way. The
-step's new K and V reach the cache through `kv_append`, a kernel on the
-same view of the same buffer with its output aliased onto it: grid (slots),
-of each slot the one block of 128 positions that holds its append position
-goes through VMEM, nothing else is touched.
+step's new K and V reach the cache through the same kernel
+(`flash_decode_append`): the cache is aliased onto two outputs, and when a
+slot's last live block — the one that holds its append position — is in
+VMEM, the token goes into its lane there and the 128 positions round it are
+copied back, so a layer of the step is one launch and reads that tile once.
+`kv_append` is the append alone, on the same view of the same buffer with
+its output aliased onto it: grid (slots), of each slot the one block of 128
+positions that holds its append position goes through VMEM, nothing else is
+touched; it is what the fused kernel is held to, and its other half where
+it gives way (head_dim >= 128).
 
 Gives way to a pure-JAX path (see `flash_attention`) when shapes don't tile,
 so callers can use it unconditionally; each such call is counted in
@@ -702,8 +708,11 @@ def _decode_reference(q, k, v, lengths, scale):
 
 
 # the decode kernel's K and V tiles: all heads of one slot's key block. Four
-# of them (K and V, double-buffered) stay inside the default scoped VMEM.
+# of them (K and V, double-buffered) stay inside the default scoped VMEM;
+# with the appending kernel's stage (at most two more, at a 128-position
+# block) they need the limit raised, so that it compiles what the other does.
 _DECODE_TILE_BYTES = 2 << 20
+_DECODE_APPEND_VMEM_BYTES = 32 << 20
 
 
 def _decode_block(C, H, D, itemsize, block_k, interpret):
@@ -735,7 +744,7 @@ def _live_blocks(length, block_c, nk):
 
 def _decode_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
                    buf_ref, qc_ref, acc_ref, m_ref, l_ref, *, scale, block_c,
-                   nk, slots):
+                   nk, slots, append=None):
     """One slot of decode attention on the cache as it lies in HBM. k_hbm /
     v_hbm are the whole [S, H, D, C] buffers, left in HBM; the kernel walks
     the slot's LIVE key blocks only (`_live_blocks` of its length, a
@@ -761,7 +770,15 @@ def _decode_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
 
     Grouped heads: q_ref holds Hq = G * H query heads against the tile's H
     K/V heads; K/V head h is loaded once and serves query heads h*G .. h*G +
-    G - 1, each with its own (m, l, acc)."""
+    G - 1, each with its own (m, l, acc).
+
+    `append` (`_decode_append_kernel`): the step's token is written by this
+    kernel too. The slot's length counts it, so its position `length - 1`
+    lies in the slot's LAST live block; that block is peeled off the loop,
+    and once it is in VMEM the token's K and V go into their lane of the
+    tiles and the lanes round it are copied back to the cache while the
+    block is computed on (`_insert_token`): what `kv_append` writes, without
+    its read of the tile and without its launch."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     si = pl.program_id(0)
@@ -796,7 +813,7 @@ def _decode_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
 
-    def block(j, buf):
+    def block(j, buf, token=None):
         # what follows block j: the slot's next live block or the next
         # slot's first; after the last block of the last slot a copy nobody
         # reads (`_drain` waits for it), which keeps this one basic block
@@ -806,6 +823,8 @@ def _decode_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
             copy.start()
         for copy in copies(si, j, buf):
             copy.wait()
+        if token is not None:   # into its lane of the tiles, and home
+            token(buf)
         kpos = j * block_c + jax.lax.broadcasted_iota(jnp.int32,
                                                       (1, block_c), 1)
         valid = kpos < length                         # [1, block_c]
@@ -836,13 +855,22 @@ def _decode_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
         jax.lax.fori_loop(0, H, head, None, unroll=True)
         return 1 - buf
 
-    buf = jax.lax.fori_loop(0, live, block, buf_ref[0])
+    if append is None:
+        buf = jax.lax.fori_loop(0, live, block, buf_ref[0])
+    else:
+        buf = jax.lax.fori_loop(0, live - 1, block, buf_ref[0])
+        buf = block(live - 1, buf, functools.partial(
+            _insert_token, append, (k_buf, v_buf), si, length - 1, block_c,
+            eye))
     buf_ref[0] = buf
 
     @pl.when(si == slots - 1)
     def _drain():
         for copy in copies(0, 0, buf):
             copy.wait()
+        if append is not None:
+            for write in _token_writes(append, si, length - 1):
+                write.wait()
 
     def row(h, carry):
         # l >= 1 always: a fully masked slot sums exp(0) per position
@@ -851,6 +879,21 @@ def _decode_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
                               keepdims=True).astype(o_ref.dtype)
         return carry
     jax.lax.fori_loop(0, Hq, row, None, unroll=True)
+
+
+def _decode_scratch(Hq, H, D, block_c, dtype):
+    """`_decode_kernel`'s scratch, in the order of its arguments."""
+    from jax.experimental.pallas import tpu as pltpu
+    return [
+        pltpu.VMEM((2, H, D, block_c), dtype),       # K tiles
+        pltpu.VMEM((2, H, D, block_c), dtype),       # V tiles
+        pltpu.SemaphoreType.DMA((2, 2)),             # (K | V, buffer)
+        pltpu.SMEM((1,), jnp.int32),                 # next buffer
+        pltpu.VMEM((Hq, D, 1), jnp.float32),         # q columns
+        pltpu.VMEM((Hq, D, 1), jnp.float32),         # acc
+        pltpu.VMEM((Hq, 1, 1), jnp.float32),         # running max
+        pltpu.VMEM((Hq, 1, 1), jnp.float32),         # running sum
+    ]
 
 
 @functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
@@ -884,16 +927,7 @@ def _decode_call(q, k, v, lengths, scale, block_c, interpret, name):
             grid=(S,),
             in_specs=[row, in_hbm, in_hbm],
             out_specs=row,
-            scratch_shapes=[
-                pltpu.VMEM((2, H, D, block_c), k.dtype),  # K tiles
-                pltpu.VMEM((2, H, D, block_c), v.dtype),  # V tiles
-                pltpu.SemaphoreType.DMA((2, 2)),         # (K | V, buffer)
-                pltpu.SMEM((1,), jnp.int32),             # next buffer
-                pltpu.VMEM((Hq, D, 1), jnp.float32),     # q columns
-                pltpu.VMEM((Hq, D, 1), jnp.float32),     # acc
-                pltpu.VMEM((Hq, 1, 1), jnp.float32),     # running max
-                pltpu.VMEM((Hq, 1, 1), jnp.float32),     # running sum
-            ]),
+            scratch_shapes=_decode_scratch(Hq, H, D, block_c, k.dtype)),
         out_shape=jax.ShapeDtypeStruct((S, Hq, 1, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
@@ -908,9 +942,10 @@ def flash_decode(q, k, v, lengths, *, scale=None, use_pallas=True,
     """Decode-mode flash attention: ONE new query per cache slot against a
     fixed-shape slot-per-request KV cache.
 
-    q: [slots, 1, heads, head_dim] — the current token's query (the step
-    has put its k/v into the cache at position lengths-1 with `kv_append`
-    before this call; this kernel only reads the cache);
+    q: [slots, 1, heads, head_dim] — the current token's query (its k/v
+    are in the cache at position lengths-1 already: this entry only reads
+    the cache; `flash_decode_append` is the one that also writes them, and
+    what a decode step calls);
     k, v: [slots, capacity, kv_heads, head_dim] — the cache; `heads` is a
     multiple of `kv_heads` (grouped-query attention: K/V head j is read
     once and serves query heads j*G .. j*G + G - 1);
@@ -989,16 +1024,23 @@ def _append_block(C, D, itemsize, interpret):
     return LANES
 
 
+def _with_token(old, row, hit, eye):
+    """The [D, lanes] tile `old` with lane `hit` replaced by a token's
+    [1, D] row. The row turns onto the sublanes as `_decode_kernel` turns q
+    — but by a max over -inf, which hands every value through bit for bit (a
+    sum would turn -0.0 into 0.0)."""
+    col = jnp.max(jnp.where(eye, row.astype(jnp.float32), -jnp.inf), axis=1,
+                  keepdims=True)                              # [D, 1]
+    return jnp.where(hit, col, old.astype(jnp.float32)).astype(old.dtype)
+
+
 def _append_kernel(pos_ref, kn_ref, vn_ref, k_ref, v_ref, ko_ref, vo_ref, *,
                    block_c):
     """One slot's append: k_ref/v_ref are the [1, H, D, block_c] tile of the
     cache that holds position pos[slot] (the BlockSpec's index map chose it
     from the prefetched scalar), ko_ref/vo_ref the same tile of the same
     buffer (aliased). Lane pos % block_c is replaced by the new token's
-    values, every other lane is written back as read. The token's [1, D] row
-    per head turns onto the sublanes as `_decode_kernel` turns q — but by a
-    max over -inf, which hands every value through bit for bit (a sum would
-    turn -0.0 into 0.0)."""
+    values (`_with_token`), every other lane is written back as read."""
     from jax.experimental import pallas as pl
     H, D = k_ref.shape[1], k_ref.shape[2]
     eye = (jax.lax.broadcasted_iota(jnp.int32, (D, D), 0)
@@ -1009,11 +1051,8 @@ def _append_kernel(pos_ref, kn_ref, vn_ref, k_ref, v_ref, ko_ref, vo_ref, *,
     def head(h, carry):
         for new_ref, old_ref, out_ref in ((kn_ref, k_ref, ko_ref),
                                           (vn_ref, v_ref, vo_ref)):
-            row = new_ref[0, h].astype(jnp.float32)           # [1, D]
-            col = jnp.max(jnp.where(eye, row, -jnp.inf), axis=1,
-                          keepdims=True)                      # [D, 1]
-            old = old_ref[0, h].astype(jnp.float32)           # [D, block_c]
-            out_ref[0, h] = jnp.where(hit, col, old).astype(out_ref.dtype)
+            out_ref[0, h] = _with_token(old_ref[0, h], new_ref[0, h], hit,
+                                        eye)
         return carry
     jax.lax.fori_loop(0, H, head, None, unroll=True)
 
@@ -1065,8 +1104,9 @@ def kv_append(k, v, k_new, v_new, pos, *, use_pallas=True, interpret=None):
     [0, capacity). Returns (k, v) with k[s, pos[s]] = k_new[s, 0] and
     v[s, pos[s]] = v_new[s, 0], every other element as it was.
 
-    ONE kernel (`kv_append`) for all slots and for K and V, so a layer of
-    the decode step costs one launch: the grid walks the slots, a slot's
+    ONE kernel (`kv_append`) for all slots and for K and V (the decode
+    step's own append where `flash_decode_append` cannot fold it into the
+    decode kernel, and the fold's oracle): the grid walks the slots, a slot's
     block index follows from its position (a prefetched scalar), and the
     output is aliased onto the input, so of each slot only the 128-position
     block that holds the position is read and written back — on the buffer
@@ -1089,6 +1129,167 @@ def kv_append(k, v, k_new, v_new, pos, *, use_pallas=True, interpret=None):
         lambda k, v, k_new, v_new, pos: _append_call(k, v, k_new, v_new, pos,
                                                      block_c, interpret),
         (k, v, k_new, v_new, pos), S, H)
+
+
+def _token_writes(append, slot, pos):
+    """The fused kernel's copies of a slot's staged tiles (K, V) to the
+    `window` cache positions round `pos`; `append` as `_decode_kernel`
+    takes it: (the token's refs, the aliased caches, semaphores, stage)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    _, out_hbms, sem, stage = append
+    window = stage.shape[-1]
+    home = pl.ds(pl.multiple_of(pos // window * window, window), window)
+    return [pltpu.make_async_copy(stage.at[i], hbm.at[slot, :, :, home],
+                                  sem.at[i])
+            for i, hbm in enumerate(out_hbms)]
+
+
+def _insert_token(append, tiles, slot, pos, block_c, eye, buf):
+    """The fused kernel's append, on the slot's last live block as it lies
+    in buffer `buf` of the K and V `tiles`: the token's rows (`new_refs`,
+    [1, H, 1, D]) go into lane pos % block_c of the tiles, where the block's
+    arithmetic then finds them as it finds every other key, and the lanes
+    round the position — all the cache can be written in: the [H, D, 128]
+    tile `kv_append` writes — go to `stage` and from there, by one copy a
+    buffer, to the aliased cache (`_token_writes`). The copies are waited
+    for when the stage is needed again, a slot later (`_drain`: the last
+    slot's), so they ride under a slot's worth of blocks."""
+    from jax.experimental import pallas as pl
+    new_refs, _, _, stage = append
+    H, window = stage.shape[1], stage.shape[-1]
+    hit = (jax.lax.broadcasted_iota(jnp.int32, (1, window), 1)
+           == pos % window)
+    lanes = pl.ds(pl.multiple_of(pos % block_c // window * window, window),
+                  window)
+    writes = _token_writes(append, slot, pos)
+
+    @pl.when(slot > 0)
+    def _staged():
+        for write in writes:
+            write.wait()
+
+    def head(h, carry):
+        for i, (new_ref, tile) in enumerate(zip(new_refs, tiles)):
+            stage[i, h] = tile[buf, h, :, lanes] = _with_token(
+                tile[buf, h, :, lanes], new_ref[0, h], hit, eye)
+        return carry
+    jax.lax.fori_loop(0, H, head, None, unroll=True)
+    for write in writes:
+        write.start()
+
+
+def _decode_append_kernel(len_ref, q_ref, kn_ref, vn_ref, k_hbm, v_hbm, o_ref,
+                          ko_hbm, vo_hbm, *scratch, **kw):
+    """`_decode_kernel` with the step's token (kn_ref / vn_ref) and the
+    cache a second time, as the outputs ko_hbm / vo_hbm aliased onto k_hbm /
+    v_hbm; the last two scratches are the write copies' semaphores and
+    their stage."""
+    _decode_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, *scratch[:-2],
+                   append=((kn_ref, vn_ref), (ko_hbm, vo_hbm), *scratch[-2:]),
+                   **kw)
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8))
+def _decode_append_call(q, k, v, k_new, v_new, lengths, scale, block_c,
+                        interpret):
+    """`_decode_call` and `_append_call` as one kernel: q [S, 1, Hq, D],
+    k/v [S, C, H, D], k_new/v_new [S, 1, H, D], lengths [S] (the appended
+    token counted) -> (out [S, 1, Hq, D], k, v) with k[s, lengths[s] - 1] =
+    k_new[s, 0]. Both slabs stay in HBM, in the [S, H, D, C] view both calls
+    use (a bitcast of a buffer stored positions-minor), aliased onto the two
+    slab outputs: the kernel reads the live blocks and writes 2 x H x D x 128
+    elements a slot. In a trace it is `flash_decode`: the decode kernel of
+    the step, whichever entry built it."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    S, _, Hq, D = q.shape
+    C, H = k.shape[1], k.shape[2]
+    # compiled, the lanes of one tile; interpreted, what tiles the block
+    window = _fit_block(block_c, LANES, 1 if interpret else LANES)
+    kt, vt = (jnp.transpose(x, (0, 2, 3, 1)) for x in (k, v))
+    row = pl.BlockSpec((1, Hq, 1, D), lambda s, lens: (s, 0, 0, 0))
+    new = pl.BlockSpec((1, H, 1, D), lambda s, lens: (s, 0, 0, 0))
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    slab = jax.ShapeDtypeStruct((S, H, D, C), k.dtype)
+    out, nk, nv = pl.pallas_call(
+        functools.partial(_decode_append_kernel, scale=scale,
+                          block_c=block_c, nk=C // block_c, slots=S),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(S,),
+            in_specs=[row, new, new, in_hbm, in_hbm],
+            out_specs=[row, in_hbm, in_hbm],
+            scratch_shapes=_decode_scratch(Hq, H, D, block_c, k.dtype) + [
+                pltpu.SemaphoreType.DMA((2,)),           # K | V written back
+                pltpu.VMEM((2, H, D, window), k.dtype),  # from this stage
+            ]),
+        out_shape=[jax.ShapeDtypeStruct((S, Hq, 1, D), q.dtype), slab, slab],
+        # operands count from the prefetched scalar: 4 and 5 are the slabs
+        input_output_aliases={4: 1, 5: 2},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_DECODE_APPEND_VMEM_BYTES),
+        interpret=interpret,
+        name="flash_decode",
+    )(lengths, q.reshape(S, Hq, 1, D), k_new.reshape(S, H, 1, D),
+      v_new.reshape(S, H, 1, D), kt, vt)
+    return (out.reshape(S, 1, Hq, D),
+            *(jnp.transpose(x, (0, 3, 1, 2)) for x in (nk, nv)))
+
+
+def flash_decode_append(q, k, v, k_new, v_new, pos, *, scale=None,
+                        use_pallas=True, block_k=1024, interpret=None):
+    """A decode step's attention layer in ONE kernel: append the step's
+    token to the cache and attend to the cache with it.
+
+    q: [slots, 1, heads, head_dim]; k, v: [slots, capacity, kv_heads,
+    head_dim] — the cache, in place when donated; k_new, v_new: [slots, 1,
+    kv_heads, head_dim] — the token, in the cache's dtype; pos: [slots]
+    int32 — where each slot appends, inside [0, capacity): the slot then
+    holds pos + 1 tokens, so `flash_decode`'s length-0 contract does not
+    exist here. Returns (out, k, v): the cache `kv_append` leaves and, on
+    it, the rows `flash_decode(q, k, v, pos + 1)` gives, both bit for bit.
+
+    The kernel is `flash_decode`'s (`_decode_kernel`, and its name in a
+    trace) with the append folded in: when a slot's last live block — the
+    one that holds `pos` — is in VMEM, the token goes into its lane there
+    and the 128 lanes round it are copied back to the cache under the
+    block's arithmetic. The cache is written exactly where `kv_append`
+    writes it; the append's own read of that tile, its launch and its grid
+    are gone. Gives way to the two calls, counted in
+    `pallas_fallback_total{kernel="flash_decode",
+    path="kv_append+flash_decode"}`, wherever either of them would not run
+    its kernel on these shapes (`_append_block`: head_dim >= 128 is stored
+    row-major and appended by XLA; `_decode_block`), and is the two
+    references under `use_pallas=False`."""
+    S, Tq, Hq, D = q.shape
+    assert Tq == 1, f"flash_decode takes one query per slot, got Tq={Tq}"
+    C, H = k.shape[1], k.shape[2]
+    assert Hq % H == 0, f"{Hq} query heads over {H} K/V heads"
+    if scale is None:
+        scale = float(1.0 / (D ** 0.5))
+    if interpret is None:
+        interpret = _interpret_default()
+    pos = jnp.asarray(pos, jnp.int32)
+    size = k.dtype.itemsize
+    block_c = None
+    if use_pallas and _append_block(C, D, size, interpret):
+        block_c = _decode_block(C, Hq, D, size, block_k, interpret)
+    if block_c is None:
+        if use_pallas:
+            _note_fallback("flash_decode", "kv_append+flash_decode", C=C,
+                           D=D, interpret=interpret)
+        with jax.named_scope("kv_append"):
+            k, v = kv_append(k, v, k_new, v_new, pos, use_pallas=use_pallas,
+                             interpret=interpret)
+        return flash_decode(q, k, v, pos + 1, scale=scale,
+                            use_pallas=use_pallas, block_k=block_k,
+                            interpret=interpret), k, v
+    _note_decode_block(block_c, C=C, H=H, D=D, itemsize=size)
+    return _per_shard(
+        lambda *a: _decode_append_call(*a, scale, block_c, interpret),
+        (q, k, v, k_new, v_new, pos + 1), S, H)
 
 
 def flash_decode_paged(q, k_pool, v_pool, block_table, lengths, *,

@@ -283,12 +283,16 @@ class SelfAttentionLayerModule(BaseLayerModule):
     axis. Prefill writes the prompt's K/V into the slot's rows in one
     update; pad positions write garbage beyond `length` that the length
     mask hides from every later step. A step appends every slot's token
-    with ONE in-place kernel a layer (kernels.flash_attention.kv_append) and
-    attends with the decode kernel (flash_decode), both on the cache buffer
-    in the layout the device stores it in: no instruction of the step
-    copies a slab or loops over the slots (tests/test_tpu_compile.py). The
-    paged step scatters into (table[pos // bs], pos % bs) and gathers the
-    slot's blocks back (flash_decode_paged), token for token the slab.
+    and attends in ONE kernel a layer
+    (kernels.flash_attention.flash_decode_append: the decode kernel puts
+    the token into the slot's last live block as it reads it and writes the
+    128 positions round it back, in place), on the cache buffer in the
+    layout the device stores it in: no instruction of the step copies a
+    slab or loops over the slots (tests/test_tpu_compile.py). Where that
+    layout is another (head_dim >= 128: row-major) it is two calls, kv_append
+    then flash_decode. The paged step scatters into (table[pos // bs],
+    pos % bs) and gathers the slot's blocks back (flash_decode_paged), token
+    for token the slab.
     Rolling back is a length reset: stale rows are causally masked."""
 
     def init(self, rng, input_type, dtype=jnp.float32):
@@ -403,7 +407,7 @@ class SelfAttentionLayerModule(BaseLayerModule):
                     entry["v"], v.astype(entry["v"].dtype), at)}
 
     def decode_step(self, params, state, x, entry, ctx):
-        from ...kernels import flash_decode, flash_decode_paged, kv_append
+        from ...kernels import flash_decode_append, flash_decode_paged
         q, kt, vt = self.project_qkv(params, x)               # [S, 1, H, Dh]
         use_pallas = getattr(self.conf, "use_pallas", False)
         if ctx.table is not None:
@@ -416,14 +420,12 @@ class SelfAttentionLayerModule(BaseLayerModule):
                 out = flash_decode_paged(q, nk, nv, ctx.table, ctx.kv_valid,
                                          use_pallas=use_pallas)
         else:
-            with jax.named_scope("kv_append"):
-                nk, nv = kv_append(entry["k"], entry["v"],
-                                   kt.astype(entry["k"].dtype),
-                                   vt.astype(entry["v"].dtype), ctx.pos,
-                                   use_pallas=use_pallas)
+            # the slot then holds ctx.kv_valid = ctx.pos + 1 tokens
             with jax.named_scope("attention"):
-                out = flash_decode(q, nk, nv, ctx.kv_valid,
-                                   use_pallas=use_pallas)
+                out, nk, nv = flash_decode_append(
+                    q, entry["k"], entry["v"], kt.astype(entry["k"].dtype),
+                    vt.astype(entry["v"].dtype), ctx.pos,
+                    use_pallas=use_pallas)
         return self.finish(params, out.astype(x.dtype), None), \
             {"k": nk, "v": nv}
 

@@ -1,0 +1,157 @@
+"""The decode kernel that appends (`flash_decode_append` of
+`kernels/flash_attention.py`, interpret mode on the CPU) against the two
+calls it folds: the slabs it leaves are `kv_append`'s and its rows are
+`flash_decode`'s on the appended cache, BIT FOR BIT — the token's lane in
+VMEM holds what the round trip through HBM would have brought back, and the
+block's arithmetic is the same. Key blocks of 256 positions in a capacity of
+1024 (four blocks a slot, two 128-position tiles a block), so the position
+falls in the first and in the last block of a slot, on lane 0 and lane 127 of
+a tile, in either tile of a block, at C - 1 (the engine's clamp), with short
+and full slots in one call. Shapes that do not tile take the two calls and
+count in `pallas_fallback_total{kernel="flash_decode"}`. The compiled kernel
+— one launch, in place — is in tests/test_tpu_compile.py."""
+import importlib
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+fa = importlib.import_module("deeplearning4j_tpu.kernels.flash_attention")
+
+SLOTS, CAPACITY, KV_HEADS, DIM, BLOCK = 4, 1024, 2, 16, 256
+C = CAPACITY
+POSITIONS = {
+    "first_block_lane_0": [0] * SLOTS,
+    "first_block_lane_127": [127] * SLOTS,
+    "first_block_second_tile": [128, 255, 129, 254],
+    "last_block_lane_0": [C - BLOCK] * SLOTS,
+    "last_block_lane_127": [C - BLOCK + 127] * SLOTS,
+    "clamped_at_capacity": [C - 1] * SLOTS,
+    "short_and_full": [0, C - 1, 300, 127],
+    "block_edges": [255, 256, 511, 512],
+}
+
+
+def bits(x):
+    x = np.asarray(x)
+    return x.view({2: np.uint16, 4: np.uint32}[x.dtype.itemsize])
+
+
+def operands(dtype, heads, seed=0, shape=(SLOTS, CAPACITY, KV_HEADS, DIM)):
+    S, _, H, D = shape
+    rng = np.random.default_rng(seed)
+    k, v = (jnp.asarray(rng.normal(size=shape), dtype) for _ in range(2))
+    k_new, v_new = (jnp.asarray(rng.normal(size=(S, 1, H, D)), dtype)
+                    for _ in range(2))
+    # a value a sum over zeros would not hand through
+    k_new = k_new.at[0, 0, 0, 0].set(-0.0)
+    q = jnp.asarray(rng.normal(size=(S, 1, heads, D)), dtype)
+    return q, k, v, k_new, v_new
+
+
+def fused(*args, **how):
+    return jax.jit(lambda *a: fa.flash_decode_append(
+        *a, block_k=BLOCK, **how))(*args)
+
+
+@pytest.mark.parametrize("positions", POSITIONS)
+@pytest.mark.parametrize("heads", [KV_HEADS, 3 * KV_HEADS],
+                         ids=["equal_heads", "grouped_heads"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=lambda d: jnp.dtype(d).name)
+def test_fused_call_matches_append_then_decode_bit_for_bit(dtype, heads,
+                                                           positions):
+    q, k, v, k_new, v_new = operands(dtype, heads)
+    pos = jnp.asarray(POSITIONS[positions], jnp.int32)
+    size = jnp.dtype(dtype).itemsize
+    assert fa._decode_block(C, heads, DIM, size, BLOCK, True) == BLOCK
+    assert fa._append_block(C, DIM, size, True) == 128
+    want_k, want_v = fa.kv_append(k, v, k_new, v_new, pos)
+    want = fa.flash_decode(q, want_k, want_v, pos + 1, block_k=BLOCK)
+    got, got_k, got_v = fused(q, k, v, k_new, v_new, pos)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    for g, w in ((got_k, want_k), (got_v, want_v), (got, want)):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_array_equal(bits(g), bits(w))
+    # and against the plain semantics: XLA's update, the masked row
+    ref_k, ref_v = fa._append_reference(k, v, k_new, v_new, pos)
+    np.testing.assert_array_equal(bits(got_k), bits(ref_k))
+    np.testing.assert_array_equal(bits(got_v), bits(ref_v))
+    ref = fa._decode_reference(q, ref_k, ref_v, pos + 1, DIM ** -0.5)
+    tol = 2e-6 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(ref, np.float32), rtol=tol,
+                               atol=tol)
+
+
+@pytest.mark.parametrize("positions", ["short_and_full", "block_edges"])
+def test_fused_call_touches_nothing_but_the_appended_position(positions):
+    """Every slot: the token at its position, K's in K and V's in V, and
+    every other element of both slabs as it was — also past the slot's
+    length, where the kernel never reads."""
+    q, k, v, k_new, v_new = operands(jnp.float32, KV_HEADS, seed=2)
+    at = np.asarray(POSITIONS[positions])
+    _, got_k, got_v = fused(q, k, v, k_new, v_new,
+                            jnp.asarray(at, jnp.int32))
+    untouched = np.ones(k.shape, bool)
+    untouched[np.arange(SLOTS), at] = False
+    for got, old, new in ((got_k, k, k_new), (got_v, v, v_new)):
+        np.testing.assert_array_equal(bits(got)[np.arange(SLOTS), at],
+                                      bits(new)[:, 0])
+        np.testing.assert_array_equal(bits(got)[untouched],
+                                      bits(old)[untouched])
+    assert not np.array_equal(bits(got_k), bits(got_v))
+
+
+def test_fused_call_is_one_kernel_named_flash_decode():
+    q, k, v, k_new, v_new = operands(jnp.float32, KV_HEADS)
+    pos = jnp.zeros((SLOTS,), jnp.int32)
+    jaxpr = str(jax.make_jaxpr(fa.flash_decode_append)(q, k, v, k_new, v_new,
+                                                       pos))
+    assert jaxpr.count("pallas_call") == 1
+    assert "flash_decode" in jaxpr and "kv_append" not in jaxpr
+    plain = str(jax.make_jaxpr(lambda *a: fa.flash_decode_append(
+        *a, use_pallas=False))(q, k, v, k_new, v_new, pos))
+    assert "pallas_call" not in plain and "scatter" in plain
+
+
+@pytest.mark.parametrize("shape,interpret,kernels", [
+    ((2, 256, 2, 128), False, None),  # head_dim 128: a row-major buffer
+    ((2, 1000, 2, 64), False, None),  # a capacity 128 does not divide
+    ((2, 64, 2, 16), False, None),    # capacity under one lane tile
+    ((2, 32, 2, 128), True, ["flash_decode"]),
+], ids=str)
+def test_shapes_that_do_not_tile_take_the_two_calls_and_count(shape,
+                                                              interpret,
+                                                              kernels):
+    """What `_append_block` or `_decode_block` refuses is today's two calls
+    (compiled shapes are only traced here: no TPU), counted once; at
+    head_dim 128 the append is XLA's update and the decode kernel still
+    runs."""
+    from deeplearning4j_tpu.telemetry.registry import get_registry
+    S, C_, H, D = shape
+    q, k, v, k_new, v_new = operands(jnp.float32, H, seed=1, shape=shape)
+    pos = jnp.asarray([C_ - 1, 3], jnp.int32)
+    labels = dict(kernel="flash_decode", path="kv_append+flash_decode",
+                  shape=f"C={C_},D={D},interpret={interpret}")
+    counter = lambda: get_registry().get("pallas_fallback_total")
+    before = counter().get(**labels) if counter() else 0
+    call = lambda **how: (lambda *a: fa.flash_decode_append(
+        *a, interpret=interpret, **how))
+    jaxpr = str(jax.make_jaxpr(call())(q, k, v, k_new, v_new, pos))
+    assert counter().get(**labels) == before + 1
+    if not interpret:       # traced only: which kernels the two calls keep
+        assert "kv_append" not in jaxpr
+        assert jaxpr.count("pallas_call") == (1 if C_ % 128 == 0 else 0)
+        return
+    assert jaxpr.count("pallas_call") == len(kernels)
+    got, got_k, got_v = call()(q, k, v, k_new, v_new, pos)
+    want_k, want_v = fa._append_reference(k, v, k_new, v_new, pos)
+    want = fa.flash_decode(q, want_k, want_v, pos + 1, interpret=True)
+    for g, w in ((got_k, want_k), (got_v, want_v), (got, want)):
+        np.testing.assert_array_equal(bits(g), bits(w))
+    # use_pallas=False is a choice, not a fallback: not counted
+    call(use_pallas=False)(q, k, v, k_new, v_new, pos)
+    assert counter().get(**labels) == before + 2

@@ -128,12 +128,13 @@ def test_shapes_that_do_not_tile_fall_back_and_count(shape, interpret):
 
 
 def test_decode_step_appends_through_the_kernel():
-    """The engine's step: with `use_pallas=True` each attention layer's
-    append is ONE `kv_append` kernel, and the cache it leaves is the cache
-    of the same net stepped through the `dynamic_update_slice`
-    (`use_pallas=False`): bit for bit in the first layer, whose projections
-    are the same, and to the rounding of the attention between them (kernel
-    against reference row) in the next."""
+    """The engine's step: with `use_pallas=True` each attention layer is
+    ONE kernel — the decode kernel, which appends the step's token as it
+    reads (`flash_decode_append`); no `kv_append` is left in the step — and
+    the cache it leaves is the cache of the same net stepped through the
+    `dynamic_update_slice` (`use_pallas=False`): bit for bit in the first
+    layer, whose projections are the same, and to the rounding of the
+    attention between them (kernel against reference row) in the next."""
     from deeplearning4j_tpu.decode.engine import DecodeEngine
     from deeplearning4j_tpu.zoo.models import transformer_lm
     caches = {}
@@ -146,8 +147,12 @@ def test_decode_step_appends_through_the_kernel():
         ids = np.asarray([1, 2, 3], np.int32)
         args = (net.params, net.states, cache, ids, eng._greedy_step_ops,
                 None)
+        # the layers share one trace of the jitted call and its kernel
         jaxpr = str(jax.make_jaxpr(eng._build_step())(*args))
-        assert jaxpr.count("kv_append") == (2 if use_pallas else 0)
+        assert jaxpr.count("name=_decode_append_call") == (
+            2 if use_pallas else 0)
+        assert jaxpr.count("name=flash_decode") == (1 if use_pallas else 0)
+        assert "kv_append" not in jaxpr
         caches[use_pallas] = eng._build_step()(*args)[0]
     np.testing.assert_array_equal(np.asarray(caches[True]["lengths"]),
                                   [1, 6, 16])

@@ -24,6 +24,7 @@ from jax.sharding import SingleDeviceSharding
 from deeplearning4j_tpu.kernels.flash_attention import (flash_attention,
                                                         flash_attention_lse,
                                                         flash_decode,
+                                                        flash_decode_append,
                                                         flash_decode_paged,
                                                         kv_append)
 
@@ -336,6 +337,81 @@ def test_kv_append_runs_per_shard_on_a_mesh(topo, chip_config):
     assert not COLLECTIVE.search(text)
 
 
+def compiled_step_layer(q, kv, new, pos, **how):
+    """`flash_decode_append` on two donated caches, compiled."""
+    return jax.jit(
+        lambda q, k, v, kn, vn, pos: flash_decode_append(q, k, v, kn, vn,
+                                                         pos, **how),
+        donate_argnums=(1, 2)).lower(q, kv, kv, new, new, pos).compile()
+
+
+def test_flash_decode_append_is_one_kernel_in_place(on_chip):
+    """An attention layer of the `opt350m_batch_decode` cell's step: 48
+    queries against two donated 48 x 1024 x 16 x 64 float32 buffers, the
+    step's token appended by the decode kernel itself. ONE kernel, named
+    `flash_decode`, on the buffers as they lie, its two slab outputs aliased
+    onto the donated inputs (operands 4 and 5, after the lengths, the
+    queries and the two new rows); no `kv_append`, no loop over the slots,
+    nothing the size of a slab copied or allocated (HBM is 92 % full in the
+    cell: one un-aliased 201 MB slab is a failed run)."""
+    S, C, H, D = 48, 1024, 16, 64
+    kv, new = on_chip((S, C, H, D), jnp.float32), on_chip((S, 1, H, D),
+                                                          jnp.float32)
+    comp = compiled_step_layer(new, kv, new, on_chip((S,), jnp.int32),
+                               interpret=False)
+    text = comp.as_text()
+    assert text.count(KERNEL) == 1
+    assert "%flash_decode" in text and "%kv_append" not in text
+    assert relayouts(text, S * C * H * D) == []
+    assert loops(text) == []
+    assert "dynamic-update-slice" not in text
+    mem = comp.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 20
+    assert mem.alias_size_in_bytes == 2 * S * C * H * D * 4
+    assert re.search(r"output_to_operand_aliasing=\{\{1\}: \(4, \{\}\), "
+                     r"\{2\}: \(5, \{\}\)\}", text)
+
+
+def test_flash_decode_append_grouped_heads_compiles_in_place(on_chip):
+    """`granite4_h_micro`'s attention layer: 32 query heads on 8 K/V heads
+    of 64, bfloat16, 64 slots of 1024 — the same kernel, in place."""
+    S, C, Hq, H, D = 64, 1024, 32, 8, 64
+    kv = on_chip((S, C, H, D), jnp.bfloat16)
+    comp = compiled_step_layer(on_chip((S, 1, Hq, D), jnp.bfloat16), kv,
+                               on_chip((S, 1, H, D), jnp.bfloat16),
+                               on_chip((S,), jnp.int32), interpret=False)
+    text = comp.as_text()
+    assert text.count(KERNEL) == 1 and "%kv_append" not in text
+    assert relayouts(text, S * C * H * D) == []
+    assert loops(text) == []
+    assert comp.memory_analysis().alias_size_in_bytes == 2 * S * C * H * D * 2
+
+
+def test_flash_decode_append_runs_per_shard_on_a_mesh(topo, chip_config):
+    """`ServingServer(mesh=4)`: per shard, 4 of the 16 heads — the three
+    outputs (the rows and the two slabs) head-sharded like the operands:
+    one kernel, in place, no collective."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from deeplearning4j_tpu.parallel.sharding import DATA_AXIS, MODEL_AXIS
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), (DATA_AXIS, MODEL_AXIS))
+    S, C, H, D = 48, 1024, 16, 64
+    heads = NamedSharding(mesh, P(None, None, MODEL_AXIS, None))
+    kv = jax.ShapeDtypeStruct((S, C, H, D), jnp.float32, sharding=heads)
+    new = jax.ShapeDtypeStruct((S, 1, H, D), jnp.float32, sharding=heads)
+    pos = jax.ShapeDtypeStruct((S,), jnp.int32,
+                               sharding=NamedSharding(mesh, P()))
+    with jax.set_mesh(mesh):
+        comp = compiled_step_layer(new, kv, new, pos, interpret=False)
+    text = comp.as_text()
+    assert text.count(KERNEL) == 1 and "%kv_append" not in text
+    assert relayouts(text, S * C * H * D // 4) == []
+    assert loops(text) == []
+    mem = comp.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 20
+    assert mem.alias_size_in_bytes == 2 * S * C * H * D * 4 // 4
+    assert not COLLECTIVE.search(text)
+
+
 def test_loops_sees_the_per_slot_update(on_chip):
     """The guard has teeth: the same append as the vmapped
     `dynamic_update_slice` (`use_pallas=False`, and the fallback) compiles
@@ -582,12 +658,12 @@ def test_decode_step_compiles_with_kernel(lm_engine, one_chip,
                       np.zeros((eng.slots,), np.int32),
                       eng._greedy_step_ops), one_chip)
     text = eng._build_step().lower(*args, None).compile().as_text()
-    # per layer one kv_append and one flash_decode
-    assert text.count(KERNEL) == 8
-    assert len(re.findall(r"%kv_append[.\d]* = ", text)) == 4
-    # and nothing in the step rewrites a K or V slab: the append kernel
-    # updates the donated cache in place, the decode kernel reads it where
-    # it lies
+    # per layer ONE kernel: the decode kernel appends the step's token
+    assert text.count(KERNEL) == 4
+    assert len(re.findall(r"%flash_decode[.\d]* = ", text)) == 4
+    assert "%kv_append" not in text
+    # and nothing in the step rewrites a K or V slab: the kernel reads the
+    # donated cache where it lies and writes the token's tile in place
     assert relayouts(text, eng.slots * eng.capacity * 256) == []
     # no loop over the slots is left (the append as XLA's per-slot update)
     assert loops(text) == []
@@ -613,8 +689,8 @@ def test_programs_of_the_loop_with_a_step_in_flight_keep_their_kernels(
     64, float32, 48 slots of 1024; a quarter of its width and a small
     vocabulary, which no count below depends on). The step takes its ids as
     the [slots] vector the step before it returned — abstractly the host
-    vector it took before, so one program: 24 `kv_append` + 24
-    `flash_decode`, one conditional, no loop, no copy of a slab. The
+    vector it took before, so one program: 24 `flash_decode` kernels that
+    append as they read, one conditional, no loop, no copy of a slab. The
     256-token prefill takes that vector too and returns it with its slot's
     entry set (`_prefill_text` holds the outputs to that): 24 masked flash
     kernels, one conditional, no loop."""
@@ -631,8 +707,9 @@ def test_programs_of_the_loop_with_a_step_in_flight_keep_their_kernels(
     assert lowered.out_info[1].shape == next_ids.shape \
         and lowered.out_info[1].dtype == next_ids.dtype
     text = lowered.compile().as_text()
-    assert text.count(KERNEL) == 48
-    assert len(re.findall(r"%kv_append[.\d]* = ", text)) == 24
+    assert text.count(KERNEL) == 24
+    assert len(re.findall(r"%flash_decode[.\d]* = ", text)) == 24
+    assert "%kv_append" not in text and "dynamic-update-slice" not in text
     assert text.count(" conditional(") == 1
     assert sorts_only_under_a_conditional(text)
     assert loops(text) == []
@@ -649,9 +726,9 @@ def test_hybrid_decode_step_compiles_with_both_kinds_of_kernel(
         one_chip, chip_config, monkeypatch):
     """granite4_h_micro's two kinds of block (Mamba-2, attention, Mamba-2) at
     a quarter of its widths, bfloat16, 16 slots of 256: per Mamba-2 layer
-    one `ssm_step`, for the attention layer one `kv_append` and one
-    `flash_decode`; no loop over the slots, no copy of a state or of a K/V
-    slab. (The whole 40-layer step at the cell's size compiles here in 31 s
+    one `ssm_step`, for the attention layer (grouped heads) one
+    `flash_decode` that appends; no loop over the slots, no copy of a state
+    or of a K/V slab. (The whole 40-layer step at the cell's size compiles here in 31 s
     with 12.2 GB of arguments and 0.116 GB of temporaries: PERF.md §4.)"""
     from deeplearning4j_tpu.decode.engine import DecodeEngine
     from deeplearning4j_tpu.zoo.models import granite_hybrid_lm
@@ -670,9 +747,10 @@ def test_hybrid_decode_step_compiles_with_both_kinds_of_kernel(
                       np.zeros((eng.slots,), np.int32),
                       eng._greedy_step_ops), one_chip)
     text = eng._build_step().lower(*args, None).compile().as_text()
-    assert text.count(KERNEL) == 4
+    assert text.count(KERNEL) == 3
     assert len(re.findall(r"%ssm_step[.\d]* = ", text)) == 2
-    assert len(re.findall(r"%kv_append[.\d]* = ", text)) == 1
+    assert len(re.findall(r"%flash_decode[.\d]* = ", text)) == 1
+    assert "%kv_append" not in text
     assert relayouts(text, 16 * 128 * 1024) == []
     assert loops(text) == []
     assert sorts_only_under_a_conditional(text)
@@ -708,7 +786,7 @@ def test_routed_decode_step_compiles_with_one_expert_kernel_a_layer(
                       np.zeros((eng.slots,), np.int32),
                       eng._greedy_step_ops), one_chip)
     text = eng._build_step().lower(*args, None).compile().as_text()
-    assert text.count(KERNEL) == 5
+    assert text.count(KERNEL) == 4      # + the attention layer's one
     assert len(re.findall(r"%expert_gmm_16x1[.\d]* = ", text)) == 2
     assert len(re.findall(r"%ssm_step[.\d]* = ", text)) == 1
     assert loops(text) == []
