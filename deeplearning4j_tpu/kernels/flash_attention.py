@@ -55,7 +55,16 @@ copied back, so a layer of the step is one launch and reads that tile once.
 its output aliased onto it: grid (slots), of each slot the one block of 128
 positions that holds its append position goes through VMEM, nothing else is
 touched; it is what the fused kernel is held to, and its other half where
-it gives way (head_dim >= 128).
+it gives way.
+
+All of that is the cache as the TPU stores it for head_dim < 128, positions
+on the lanes. A head_dim that is a multiple of 128 is stored ROW-MAJOR — a
+position's [H, head_dim] values one run of tiles — and `flash_decode_append`
+then runs `_decode_rows_kernel` (the same name in a trace): a block's
+positions x heads as the rows of one tile, both products on the MXU for all
+query heads at once, the token's rows written by one copy a buffer. The
+read-only `flash_decode` still hands such a cache to `_decode_kernel` through
+a transposing copy.
 
 Gives way to a pure-JAX path (see `flash_attention`) when shapes don't tile,
 so callers can use it unconditionally; each such call is counted in
@@ -114,6 +123,19 @@ def _per_shard(fn, arrays, B, H):
                   for a in arrays)
     return jax.shard_map(fn, in_specs=specs, out_specs=tensor,
                          check_vma=False)(*arrays)
+
+
+def _heads_per_shard(H):
+    """The heads of `H` that one shard's kernel sees under `_per_shard`: the
+    model axis of the ambient mesh splits them wherever it divides. A kernel
+    whose tiling depends on the head count is chosen by this count, not by
+    the global one."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return H
+    from ..parallel.sharding import MODEL_AXIS
+    size = mesh.shape.get(MODEL_AXIS, 1)
+    return H // size if H % size == 0 else H
 
 
 def _note_fallback(kernel, path, **shape):
@@ -914,8 +936,9 @@ def _decode_call(q, k, v, lengths, scale, block_c, interpret, name):
     # minor-most (minor-to-major {1,3,2,0}: a 64-wide minor axis would pad
     # every tile to 128 lanes), so this transpose compiles to a bitcast
     # (tests/test_tpu_compile.py holds it to that). For head_dim >= 128 the
-    # buffer is row-major and the transpose is a copy, as the fold of heads
-    # was before.
+    # buffer is row-major and the transpose is a copy: a decode step does not
+    # come here with one (`_decode_rows_call`); the paged gather and the
+    # counted fallback do.
     kt, vt = (jnp.transpose(x, (0, 2, 3, 1)) for x in (k, v))
     row = pl.BlockSpec((1, Hq, 1, D), lambda s, lens: (s, 0, 0, 0))
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
@@ -1012,9 +1035,10 @@ def _append_block(C, D, itemsize, interpret):
     the cache as the TPU stores it for head_dim < 128: positions on the
     lanes (a block of 128 that divides the capacity), head_dim on the
     sublanes (a multiple of 8 for float32, of 16 for a packed bfloat16).
-    For head_dim >= 128 the buffer is row-major, a token's [H, D] row is
-    contiguous and XLA's update is the right one. Interpret mode takes the
-    largest divisor of the capacity up to 128."""
+    For head_dim >= 128 the buffer is row-major and a token's [H, D] rows
+    are one run of tiles: the step's kernel for that layout writes them by
+    one copy (`_decode_rows_kernel`); alone, XLA's update does. Interpret
+    mode takes the largest divisor of the capacity up to 128."""
     if D >= LANES:
         return None
     if interpret:
@@ -1238,6 +1262,192 @@ def _decode_append_call(q, k, v, k_new, v_new, lengths, scale, block_c,
             *(jnp.transpose(x, (0, 3, 1, 2)) for x in (nk, nv)))
 
 
+# the row-major decode kernel's key block, in cache positions: a slot's blocks
+# past its length are not read, so a shorter block reads less; a tile is the
+# block's positions x all K/V heads, `_DECODE_TILE_BYTES` at most
+_ROWS_BLOCK_POSITIONS = 256
+
+
+def _rows_block(C, H, D, itemsize, block_k, interpret):
+    """Key-block length of the row-major decode kernel, or None => the
+    cache is not one it reads (`flash_decode_append` then takes the other
+    kernel or the two calls). head_dim has to be a multiple of the lanes:
+    the TPU then stores a [S, C, H, D] cache row-major, a position's [H, D]
+    values in whole tiles, and — compiled — H has to fill a tile's 8
+    sublanes (packed or not: a [.., 8, 128] bfloat16 array lies in (8,
+    128)(2, 1) tiles), so that the [S, C * H, D] view the kernel copies from
+    is the buffer itself; the block is then a multiple of 128 positions.
+    Interpret mode takes any H and any divisor of the capacity."""
+    if D % LANES or (not interpret and H % 8):
+        return None
+    target = min(block_k, C, _ROWS_BLOCK_POSITIONS,
+                 max(1, _DECODE_TILE_BYTES // (H * D * itemsize)))
+    return _fit_block(C, target, 1 if interpret else LANES)
+
+
+def _decode_rows_kernel(len_ref, q_ref, kx_ref, vx_ref, kn_ref, vn_ref, k_hbm,
+                        v_hbm, o_ref, ko_hbm, vo_hbm, k_buf, v_buf, sem, wsem,
+                        buf_ref, bias_ref, acc_ref, m_ref, l_ref, *, scale,
+                        block_c, slots, heads, group):
+    """One slot of decode attention on a ROW-MAJOR cache (head_dim a multiple
+    of the lanes), the step's token appended on the way. k_hbm / v_hbm are
+    the whole caches viewed [S, C * H, D] — row c * H + h is K/V head h of
+    position c, which is how a [S, C, H, D] array with H = 8 lies in HBM —
+    and stay there; a key block is `block_c` positions = `block_c * H`
+    consecutive rows, copied as they lie into one of two VMEM buffers (the
+    scheme of `_decode_kernel`: the next copy started before the current one
+    is waited for, the next slot's first block under this slot's last).
+
+    Positions x heads are on the sublanes and head_dim on the lanes, so
+    both products run on the MXU with no relayout of a tile: the scores of
+    ALL query heads against ALL rows of the block, `q [Hq, D] . rows
+    [block_c * H, D]^T`, of which a query head keeps the columns of its own
+    K/V head (`bias_ref`: 0 there, NEG_INF elsewhere, built once a call), and
+    `p [Hq, block_c * H] . rows` with p = 0 in the other heads' columns. The
+    MXU's cost is the tiles of the block it has to load as weights, the same
+    whether 8 or 64 query rows stream past them, so a K/V head's block is
+    read once and serves its whole group. Scores, softmax and accumulator
+    are float32; the products multiply in the cache's dtype.
+
+    The token: the slot holds `length` tokens, the last of them this step's,
+    whose K and V rows (`kn_ref` / `vn_ref`, [1, H, D]) are not in the cache
+    yet. They go there by one copy a buffer, straight from the operand's
+    block to rows (length - 1) * H .. of the aliased cache (`ko_hbm` /
+    `vo_hbm`), started first and waited for last. The blocks read are those
+    of the length - 1 CACHED positions (one at least: an empty slot reads a
+    block it masks whole), every column at or past the token's position
+    masked; the token itself is the softmax's first term, from `kx_ref` /
+    `vx_ref` (the token's rows repeated to the query heads, [1, Hq, D]), on
+    the VPU in float32."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    si = pl.program_id(0)
+    rows = block_c * heads
+    pos = len_ref[si] - 1
+    live = jnp.maximum((pos + block_c - 1) // block_c, 1)
+
+    def copies(slot, block, b):
+        at = pl.ds(pl.multiple_of(block * rows, rows), rows)
+        return [pltpu.make_async_copy(hbm.at[slot, at, :], vmem.at[b],
+                                      sem.at[i, b])
+                for i, (hbm, vmem) in enumerate(((k_hbm, k_buf),
+                                                 (v_hbm, v_buf)))]
+
+    home = pl.ds(pl.multiple_of(pos * heads, heads), heads)
+    writes = [pltpu.make_async_copy(new.at[0], hbm.at[si, home, :],
+                                    wsem.at[i])
+              for i, (new, hbm) in enumerate(((kn_ref, ko_hbm),
+                                              (vn_ref, vo_hbm)))]
+    for write in writes:
+        write.start()
+
+    @pl.when(si == 0)
+    def _first():
+        buf_ref[0] = 0
+        for copy in copies(0, 0, 0):
+            copy.start()
+        col = jax.lax.broadcasted_iota(jnp.int32, bias_ref.shape, 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, bias_ref.shape, 0)
+        bias_ref[...] = jnp.where(col % heads == row // group, 0.0, NEG_INF)
+
+    q = q_ref[0]                                            # [Hq, D]
+    m_ref[...] = jnp.sum(q.astype(jnp.float32)
+                         * kx_ref[0].astype(jnp.float32), axis=1,
+                         keepdims=True) * scale             # the token's score
+    l_ref[...] = jnp.ones_like(l_ref)
+    acc_ref[...] = vx_ref[0].astype(jnp.float32)
+
+    def block(j, b):
+        more = j + 1 < live
+        for copy in copies(jnp.where(more, si, jnp.minimum(si + 1, slots - 1)),
+                           jnp.where(more, j + 1, 0), 1 - b):
+            copy.start()
+        for copy in copies(si, j, b):
+            copy.wait()
+        k = k_buf[b]                                        # [rows, D]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        cached = jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1) \
+            < (pos - j * block_c) * heads
+        s = jnp.where(cached, s + bias_ref[...], NEG_INF)   # [Hq, rows]
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
+            p.astype(k.dtype), v_buf[b], preferred_element_type=jnp.float32)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[...] = m_new
+        return 1 - b
+
+    b = jax.lax.fori_loop(0, live, block, buf_ref[0])
+    buf_ref[0] = b
+
+    @pl.when(si == slots - 1)
+    def _drain():
+        for copy in copies(0, 0, b):
+            copy.wait()
+
+    o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)   # l >= 1
+    for write in writes:
+        write.wait()
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8))
+def _decode_rows_call(q, k, v, k_new, v_new, lengths, scale, block_c,
+                      interpret):
+    """`_decode_append_call` for a row-major cache: q [S, 1, Hq, D], k/v [S,
+    C, H, D], k_new/v_new [S, 1, H, D], lengths [S] (the appended token
+    counted) -> (out [S, 1, Hq, D], k, v). The kernel's view of a cache is
+    [S, C * H, D]: for H = 8 the same tiles in the same order, so the
+    reshape is a bitcast (tests/test_tpu_compile.py holds it to that) and
+    the two slab outputs are aliased onto the caches. In a trace it is
+    `flash_decode`, as the kernel it stands in for."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    S, _, Hq, D = q.shape
+    C, H = k.shape[1], k.shape[2]
+    G = Hq // H
+    rows = block_c * H
+    row = pl.BlockSpec((1, Hq, D), lambda s, lens: (s, 0, 0))
+    new = pl.BlockSpec((1, H, D), lambda s, lens: (s, 0, 0))
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    slab = jax.ShapeDtypeStruct((S, C * H, D), k.dtype)
+    kn, vn = k_new.reshape(S, H, D), v_new.reshape(S, H, D)
+    out, nk, nv = pl.pallas_call(
+        functools.partial(_decode_rows_kernel, scale=scale, block_c=block_c,
+                          slots=S, heads=H, group=G),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(S,),
+            in_specs=[row, row, row, new, new, in_hbm, in_hbm],
+            out_specs=[row, in_hbm, in_hbm],
+            scratch_shapes=[
+                pltpu.VMEM((2, rows, D), k.dtype),           # K tiles
+                pltpu.VMEM((2, rows, D), k.dtype),           # V tiles
+                pltpu.SemaphoreType.DMA((2, 2)),             # (K | V, buffer)
+                pltpu.SemaphoreType.DMA((2,)),               # K | V written
+                pltpu.SMEM((1,), jnp.int32),                 # next buffer
+                pltpu.VMEM((Hq, rows), jnp.float32),         # own-head bias
+                pltpu.VMEM((Hq, D), jnp.float32),            # acc
+                pltpu.VMEM((Hq, 1), jnp.float32),            # running max
+                pltpu.VMEM((Hq, 1), jnp.float32),            # running sum
+            ]),
+        out_shape=[jax.ShapeDtypeStruct((S, Hq, D), q.dtype), slab, slab],
+        # operands count from the prefetched scalar: 6 and 7 are the slabs
+        input_output_aliases={6: 1, 7: 2},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_DECODE_APPEND_VMEM_BYTES),
+        interpret=interpret,
+        name="flash_decode",
+    )(lengths, q.reshape(S, Hq, D).astype(k.dtype),
+      jnp.repeat(kn, G, axis=1), jnp.repeat(vn, G, axis=1), kn, vn,
+      k.reshape(S, C * H, D), v.reshape(S, C * H, D))
+    return (out.reshape(S, 1, Hq, D), nk.reshape(k.shape),
+            nv.reshape(v.shape))
+
+
 def flash_decode_append(q, k, v, k_new, v_new, pos, *, scale=None,
                         use_pallas=True, block_k=1024, interpret=None):
     """A decode step's attention layer in ONE kernel: append the step's
@@ -1257,12 +1467,16 @@ def flash_decode_append(q, k, v, k_new, v_new, pos, *, scale=None,
     and the 128 lanes round it are copied back to the cache under the
     block's arithmetic. The cache is written exactly where `kv_append`
     writes it; the append's own read of that tile, its launch and its grid
-    are gone. Gives way to the two calls, counted in
-    `pallas_fallback_total{kernel="flash_decode",
-    path="kv_append+flash_decode"}`, wherever either of them would not run
-    its kernel on these shapes (`_append_block`: head_dim >= 128 is stored
-    row-major and appended by XLA; `_decode_block`), and is the two
-    references under `use_pallas=False`."""
+    are gone. A head_dim that is a multiple of 128 is stored row-major and
+    takes the kernel that reads it so (`_decode_rows_call`, `_rows_block`:
+    the same contract, the output to float32 rounding — its products run on
+    the MXU in another order — and both slabs bit for bit). Gives way to the
+    two calls, counted in `pallas_fallback_total{kernel="flash_decode",
+    path="kv_append+flash_decode"}`, wherever neither kernel takes these
+    shapes (`_append_block`, `_decode_block`, `_rows_block` — under a mesh
+    the last is asked about the K/V heads one shard holds, so 8 heads split
+    over a model axis give way), and is the two references under
+    `use_pallas=False`."""
     S, Tq, Hq, D = q.shape
     assert Tq == 1, f"flash_decode takes one query per slot, got Tq={Tq}"
     C, H = k.shape[1], k.shape[2]
@@ -1273,9 +1487,13 @@ def flash_decode_append(q, k, v, k_new, v_new, pos, *, scale=None,
         interpret = _interpret_default()
     pos = jnp.asarray(pos, jnp.int32)
     size = k.dtype.itemsize
-    block_c = None
+    block_c, call = None, _decode_append_call
     if use_pallas and _append_block(C, D, size, interpret):
         block_c = _decode_block(C, Hq, D, size, block_k, interpret)
+    elif use_pallas:
+        # the rows kernel's view needs the SHARD's K/V heads to fill a tile
+        block_c, call = _rows_block(C, _heads_per_shard(H), D, size, block_k,
+                                    interpret), _decode_rows_call
     if block_c is None:
         if use_pallas:
             _note_fallback("flash_decode", "kv_append+flash_decode", C=C,
@@ -1288,7 +1506,7 @@ def flash_decode_append(q, k, v, k_new, v_new, pos, *, scale=None,
                             interpret=interpret), k, v
     _note_decode_block(block_c, C=C, H=H, D=D, itemsize=size)
     return _per_shard(
-        lambda *a: _decode_append_call(*a, scale, block_c, interpret),
+        lambda *a: call(*a, scale, block_c, interpret),
         (q, k, v, k_new, v_new, pos + 1), S, H)
 
 

@@ -367,7 +367,13 @@ class SelfAttentionLayer(BaseRecurrentConf):
     long-context variant is parallel.ring_attention.ring_attention, applied to
     the same Q/K/V projections. use_pallas=True routes the unmasked forward
     through the hand-tiled Pallas kernel (kernels/flash_attention.py;
-    interpret mode on CPU, Mosaic on TPU)."""
+    interpret mode on CPU, Mosaic on TPU). Grouped K/V heads (`n_kv_heads`),
+    a head width of its own (`head_dim`: H x head_dim need not be n_out) and
+    a sigmoid output gate from the layer's input (`output_gate`) are options;
+    the layer adds no positions. Causal layers decode from a K/V cache
+    [slots, capacity, kv_heads, head_dim]: one kernel a step whichever way
+    the TPU stores it (positions-minor under head_dim 128, row-major from
+    there: nn/layers/recurrent.py)."""
     n_heads: int = 4
     causal: bool = False
     block_size: int = 256
@@ -381,6 +387,13 @@ class SelfAttentionLayer(BaseRecurrentConf):
     n_kv_heads: int | None = None
     # what the scores are multiplied by (None: 1 / sqrt(head_dim))
     score_scale: float | None = None
+    # a head's width (None: n_out // n_heads); given, the projections are
+    # [n_in, n_heads * head_dim] and Wo [n_heads * head_dim, n_out]
+    head_dim: int | None = None
+    # gated attention (arXiv:2505.06708, the SDPA-output form): the context
+    # is multiplied elementwise by sigmoid(x Wgate), x the layer's input,
+    # before Wo
+    output_gate: bool = False
 
 
 @register_layer_conf
@@ -407,11 +420,24 @@ class KimiDeltaAttentionLayer(_NoActivationConf, BaseRecurrentConf):
     linear attention whose per-head state S [head_dim, head_dim] is updated
     by a delta rule under a per-channel decay, [b,t,f] -> [b,t,n_out]; q, k
     and v each pass a causal depthwise conv of `d_conv` taps and SiLU, q and
-    k are L2-normalised a head, the decay's log is `gate_lower_bound` *
-    sigmoid(...) (bounded, every projection full rank), the output a
-    per-head RMS norm times a sigmoid gate. Runtime: nn/layers/kda.py — the
-    chunked form for sequences, a per-token step on the fixed-size state
-    for decode."""
+    k are L2-normalised a head, the output a per-head RMS norm times a
+    sigmoid gate. Three published variants are options here:
+
+    - the decay's log g: `gate_form` "bounded" is `gate_lower_bound` *
+      sigmoid(exp(A_log) (f + dt_bias)) in [gate_lower_bound, 0]
+      (`ling3_flash`: `kda_safe_gate`); "softplus" is Kimi Linear's own,
+      -exp(A_log) * softplus(f + dt_bias), unbounded below (`solar_open2`);
+    - `gate_rank`: None projects the decay gate's f and the output gate
+      full rank (two [n_in, H D] blocks of `W_in`); a rank r makes each the
+      product of [n_in, r] (side by side in `W_in`, which is then [n_in,
+      3 H D + 2 r]) and its own [r, H D] (`W_fb`, `W_gb`): Kimi Linear's
+      f_a/f_b and g_a/g_b;
+    - `beta_scale`: beta = beta_scale * sigmoid(x Wb); 2 lets I - beta k
+      k^T have a negative eigenvalue (`allow_neg_eigval`).
+
+    Runtime: nn/layers/kda.py — the chunked form for sequences, a per-token
+    step on the fixed-size state for decode (kernels/kda_step.py: any head
+    count, walked in groups of 2 MB of state)."""
     n_heads: int = 4
     head_dim: int = 32
     d_conv: int = 4
@@ -419,6 +445,9 @@ class KimiDeltaAttentionLayer(_NoActivationConf, BaseRecurrentConf):
     gate_lower_bound: float = -5.0
     eps: float = 1e-6
     use_pallas: bool = False
+    gate_form: str = "bounded"          # | "softplus"
+    gate_rank: int | None = None
+    beta_scale: float = 1.0
 
 
 @register_layer_conf
@@ -512,13 +541,18 @@ class MixtureOfExpertsLayer(FeedForwardLayerConf):
     axis 0 over a mesh axis is expert parallelism. Works on [b, f] and
     time-distributed [b, t, f].
 
-    Two regimes of the grouped product meet here. Few large groups
-    (`granite4_h_small`: 18 held experts at 4.4 rows a decode step) and MANY
+    Three regimes of the grouped product meet here. Few large groups
+    (`granite4_h_small`: 18 held experts at 4.4 rows a decode step), MANY
     SMALL GROUPS (`ling3_flash`: 64 held of 512 under group-limited sigmoid
     routing, 2 rows each at the mean of a 128-slot step): every non-empty
     group still costs one whole row tile (16 rows of bfloat16), so the
     grouped operand is mostly padding there: 6.8 rows computed a pair
-    against 3.6 (kernels/expert_gmm.py `row_tile` has the count)."""
+    against 3.6 (kernels/expert_gmm.py `row_tile` has the count); and
+    between them `solar_open2`: 40 held of 320 in one group, 4.8 rows each
+    at the mean of a 192-slot step, experts of 31.5 MB that under a
+    balanced router all but always get a row (99 % touched a step; at the
+    benchmark's seeded weights the loads are uneven and 93-95 % are,
+    counted from the reference's router: PERF.md section 5)."""
     n_experts: int = 4
     hidden_mult: int = 2
     top_k: int = 2

@@ -3,12 +3,22 @@ attention whose state is updated by a delta rule under a per-channel decay
 (conf: nn/conf/layers.py KimiDeltaAttentionLayer — NEW, no reference
 counterpart). With x the layer's input at position t, H heads of D:
 
-    (q, k, v, f, gate) = split(x W_in)      five H x D wide; beta = sigmoid(x Wb)
+    (q, k, v, f, gate) = split(x W_in)      five H x D wide
     (q, k, v) = silu(causal depthwise conv_K(q | k | v))       no bias
     q <- q / |q|_2 * D^-1/2 ;  k <- k / |k|_2                  a head
     g = lower_bound * sigmoid(exp(A_log) (f + dt_bias))         in [lower_bound, 0]
+    beta = beta_scale * sigmoid(x Wb)                           a head
     S_t = (I - beta_t k_t k_t^T) Diag(exp g_t) S_{t-1} + beta_t k_t v_t^T
     o_t = S_t^T q_t ;   out = [RMSNorm_D(o_t) * sigmoid(gate)] Wo
+
+The conf's variants: `gate_form="softplus"` makes g = -exp(A_log) softplus(f +
+dt_bias), unbounded below (Kimi Linear's own; the bounded form above is
+`ling3_flash`'s); `gate_rank=r` makes f and gate low rank, (x W_fa) W_fb and
+(x W_ga) W_gb with W_in = [W_q | W_k | W_v | W_fa | W_ga] then [n_in, 3 H D
++ 2 r]; `beta_scale=2` lets beta reach 2 (a negative eigenvalue of I - beta
+k k^T). Nothing of `kda_chunked` or `kda_step` changes with them: every
+exponent is still a difference <= 0, and the triangular system's entries
+grow to 2 (tests/test_solar_hybrid.py holds both to the sequential rule).
 
 Mamba-2's state (nn/layers/mamba.py) decays by one scalar a head and is
 added to; this one decays by a value a channel and is CORRECTED: the rank-1
@@ -127,12 +137,13 @@ class KimiDeltaAttentionLayerModule(BaseLayerModule):
         c = self.conf
         H, D, K, HD = self.dims()
         n_in, n_out = int(c.n_in), int(c.n_out)
+        r = getattr(c, "gate_rank", None)
         k1, k2, k3, k4, k5 = jax.random.split(rng, 5)
         mk = lambda k, i, o: init_weights(k, (i, o), c.weight_init, fan_in=i,
                                           fan_out=o, distribution=c.dist,
                                           dtype=dtype)
         params = {
-            "W_in": mk(k1, n_in, 5 * HD),
+            "W_in": mk(k1, n_in, 3 * HD + 2 * (int(r) if r else HD)),
             "Wb": mk(k2, n_in, H),
             "conv_W": (jax.random.uniform(k3, (K, 3 * HD), jnp.float32, -1.0,
                                           1.0) / np.sqrt(K)).astype(dtype),
@@ -142,17 +153,27 @@ class KimiDeltaAttentionLayerModule(BaseLayerModule):
             "norm": jnp.ones((D,), dtype),
             "Wo": mk(k5, HD, n_out),
         }
+        if r:
+            for i, name in enumerate(("W_fb", "W_gb")):
+                params[name] = mk(jax.random.fold_in(rng, 6 + i), int(r), HD)
         return params, {}, InputType.recurrent(n_out)
 
     # -- the pieces the legs share ---------------------------------------------
     def _project(self, params, u):
         """u [.., f] -> the convs' raw input [.., 3 H D], f and gate
-        [.., H D], beta [.., H] (float32)."""
+        [.., H D] (through their second factors where they are low rank),
+        beta [.., H] (float32)."""
         HD = self.dims()[3]
-        qkv, f, gate = jnp.split(u @ params["W_in"], [3 * HD, 4 * HD],
+        r = getattr(self.conf, "gate_rank", None)
+        w = int(r) if r else HD
+        qkv, f, gate = jnp.split(u @ params["W_in"], [3 * HD, 3 * HD + w],
                                  axis=-1)
+        if r:
+            f, gate = f @ params["W_fb"], gate @ params["W_gb"]
         acc = _acc_dtype(u.dtype)
-        return qkv, f, gate, jax.nn.sigmoid((u @ params["Wb"]).astype(acc))
+        beta = jax.nn.sigmoid((u @ params["Wb"]).astype(acc))
+        return qkv, f, gate, float(getattr(self.conf, "beta_scale", 1.0)) \
+            * beta
 
     def _rule_inputs(self, params, conv, f):
         """The convs' output and the raw f -> q, k (normalised), v, g
@@ -164,8 +185,14 @@ class KimiDeltaAttentionLayerModule(BaseLayerModule):
         l2 = lambda a: a * lax.rsqrt(jnp.sum(jnp.square(a), axis=-1,
                                              keepdims=True) + _L2_EPS)
         A = jnp.exp(params["A_log"].astype(acc))[:, None]
-        g = float(self.conf.gate_lower_bound) * jax.nn.sigmoid(
-            A * heads(f.astype(acc) + params["dt_bias"].astype(acc)))
+        f = heads(f.astype(acc) + params["dt_bias"].astype(acc))
+        form = getattr(self.conf, "gate_form", "bounded")
+        if form == "softplus":
+            g = -A * jax.nn.softplus(f)
+        elif form == "bounded":
+            g = float(self.conf.gate_lower_bound) * jax.nn.sigmoid(A * f)
+        else:
+            raise ValueError(f"gate_form {form!r}: 'bounded' or 'softplus'")
         return l2(q) * D ** -0.5, l2(k), v, g
 
     def _finish(self, params, o, gate, out_dtype):

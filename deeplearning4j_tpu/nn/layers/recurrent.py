@@ -289,28 +289,43 @@ class SelfAttentionLayerModule(BaseLayerModule):
     128 positions round it back, in place), on the cache buffer in the
     layout the device stores it in: no instruction of the step copies a
     slab or loops over the slots (tests/test_tpu_compile.py). Where that
-    layout is another (head_dim >= 128: row-major) it is two calls, kv_append
-    then flash_decode. The paged step scatters into (table[pos // bs],
-    pos % bs) and gathers the slot's blocks back (flash_decode_paged), token
-    for token the slab.
-    Rolling back is a length reset: stale rows are causally masked."""
+    layout is row-major (head_dim a multiple of 128) the same entry point
+    runs the kernel that reads it so: a block's rows as they lie, all K/V
+    heads at once on the MXU, the token's row written by one copy. The paged
+    step scatters into (table[pos // bs], pos % bs) and gathers the slot's
+    blocks back (flash_decode_paged), token for token the slab.
+    Rolling back is a length reset: stale rows are causally masked.
+
+    `head_dim` sets a head's width apart from n_out / n_heads (Wq, Wgate [n_in,
+    H Dh], Wk, Wv [n_in, Hkv Dh], Wo [H Dh, n_out]). `output_gate`: the
+    context is multiplied by sigmoid(x Wgate), x the layer's input, before Wo
+    (scope `attention_gate`; the sigmoid in float32) in every leg."""
 
     def init(self, rng, input_type, dtype=jnp.float32):
         c = self.conf
         n_in, n_out, H = int(c.n_in), int(c.n_out), int(c.n_heads)
-        assert n_out % H == 0, "n_heads must evenly divide n_out"
+        assert getattr(c, "head_dim", None) or n_out % H == 0, \
+            "n_heads must evenly divide n_out"
         assert H % self.kv_heads == 0, "n_kv_heads must evenly divide n_heads"
-        n_kv = n_out // H * self.kv_heads
+        n_q, n_kv = self.head_dim * H, self.head_dim * self.kv_heads
         k1, k2, k3, k4 = jax.random.split(rng, 4)
         mk = lambda k, i, o: init_weights(k, (i, o), c.weight_init, fan_in=i,
                                           fan_out=o, distribution=c.dist,
                                           dtype=dtype)
         params = {
-            "Wq": mk(k1, n_in, n_out), "Wk": mk(k2, n_in, n_kv),
-            "Wv": mk(k3, n_in, n_kv), "Wo": mk(k4, n_out, n_out),
+            "Wq": mk(k1, n_in, n_q), "Wk": mk(k2, n_in, n_kv),
+            "Wv": mk(k3, n_in, n_kv), "Wo": mk(k4, n_q, n_out),
             "b": jnp.full((n_out,), c.bias_init or 0.0, dtype),
         }
+        if getattr(c, "output_gate", False):
+            params["Wgate"] = mk(jax.random.fold_in(rng, 5), n_in, n_q)
         return params, {}, InputType.recurrent(n_out)
+
+    @property
+    def head_dim(self):
+        c = self.conf
+        return int(getattr(c, "head_dim", None)
+                   or int(c.n_out) // int(c.n_heads))
 
     @property
     def kv_heads(self):
@@ -326,8 +341,7 @@ class SelfAttentionLayerModule(BaseLayerModule):
         1 / sqrt(Dh)."""
         c = self.conf
         B, T, _ = x.shape
-        H, Hkv = int(c.n_heads), self.kv_heads
-        Dh = int(c.n_out) // H
+        H, Hkv, Dh = int(c.n_heads), self.kv_heads, self.head_dim
         q = (x @ params["Wq"]).reshape(B, T, H, Dh)
         k = (x @ params["Wk"]).reshape(B, T, Hkv, Dh)
         v = (x @ params["Wv"]).reshape(B, T, Hkv, Dh)
@@ -361,12 +375,19 @@ class SelfAttentionLayerModule(BaseLayerModule):
                                        causal=c.causal, key_mask=mask)
         return attention_reference(q, k, v, causal=c.causal, key_mask=mask)
 
-    def finish(self, params, out, mask):
-        """Output projection + activation + mask zeroing on the attention
-        context [b,t,H,Dh] (shared by forward and both decode legs)."""
+    def finish(self, params, out, mask, x):
+        """Output gate (from the layer's input x), output projection,
+        activation and mask zeroing on the attention context [b,t,H,Dh]
+        (shared by forward and the decode legs)."""
         c = self.conf
         B, T = out.shape[0], out.shape[1]
-        out = out.reshape(B, T, int(c.n_out)) @ params["Wo"] + params["b"]
+        out = out.reshape(B, T, -1)
+        if getattr(c, "output_gate", False):
+            with jax.named_scope("attention_gate"):
+                acc = _acc_dtype(x.dtype)
+                out = (out.astype(acc) * jax.nn.sigmoid(
+                    (x @ params["Wgate"]).astype(acc))).astype(x.dtype)
+        out = out @ params["Wo"] + params["b"]
         out = self.activation_fn()(out)
         if mask is not None:
             out = out * mask[:, :, None]  # zero masked steps like the LSTM scan
@@ -380,8 +401,7 @@ class SelfAttentionLayerModule(BaseLayerModule):
         return None
 
     def decode_entry(self, geom):
-        H = self.kv_heads
-        Dh = int(self.conf.n_out) // int(self.conf.n_heads)
+        H, Dh = self.kv_heads, self.head_dim
         shape = ((geom.num_blocks, geom.block_size, H, Dh) if geom.paged
                  else (geom.slots, geom.capacity, H, Dh))
         leaf = CacheLeaf(shape, geom.dtype, 2)
@@ -391,7 +411,7 @@ class SelfAttentionLayerModule(BaseLayerModule):
         q, k, v = self.project_qkv(params, x)                 # [1, L, H, Dh]
         with jax.named_scope("attention"):
             out = self.attend(q, k, v, ctx.mask)
-        y = self.finish(params, out, ctx.mask)
+        y = self.finish(params, out, ctx.mask, x)
         with jax.named_scope("kv_append"):
             if ctx.table is not None:
                 return y, {
@@ -426,7 +446,7 @@ class SelfAttentionLayerModule(BaseLayerModule):
                     q, entry["k"], entry["v"], kt.astype(entry["k"].dtype),
                     vt.astype(entry["v"].dtype), ctx.pos,
                     use_pallas=use_pallas)
-        return self.finish(params, out.astype(x.dtype), None), \
+        return self.finish(params, out.astype(x.dtype), None, x), \
             {"k": nk, "v": nv}
 
     def decode_verify(self, params, state, x, entry, ctx):
@@ -442,7 +462,7 @@ class SelfAttentionLayerModule(BaseLayerModule):
         krow = lax.dynamic_index_in_dim(nk, slot, 0, keepdims=True)
         vrow = lax.dynamic_index_in_dim(nv, slot, 0, keepdims=True)
         out = _verify_attend(q, krow, vrow, ctx.start)
-        return self.finish(params, out.astype(x.dtype), None), \
+        return self.finish(params, out.astype(x.dtype), None, x), \
             {"k": nk, "v": nv}
 
     def forward(self, params, state, x, *, train=False, rng=None, mask=None):
@@ -455,4 +475,4 @@ class SelfAttentionLayerModule(BaseLayerModule):
         q, k, v = self.project_qkv(params, x)
         out = self.attend(q, k, v, mask)
         out = apply_dropout(out, attn_drop, train, attn_rng)
-        return self.finish(params, out, mask), state, mask
+        return self.finish(params, out, mask, x), state, mask

@@ -414,6 +414,88 @@ def ling_hybrid_lm(vocab_size=256, d_model=160, n_layers=6, n_heads=2,
     return ComputationGraph(gb.build())
 
 
+def solar_hybrid_lm(vocab_size=256, d_model=128, n_layers=4, n_heads=2,
+                    n_kv_heads=1, head_dim=128, gqa_interval=3, ffn_mult=2.5,
+                    kda_head_dim=128, kda_d_conv=4, kda_chunk_size=64,
+                    kda_gate_rank=128, n_experts=320, experts_per_token=8,
+                    routed_scaling=1.0, expert_hidden=1280,
+                    shared_hidden=1280, experts_held=None, first_expert=0,
+                    rms_norm_eps=1e-5, dtype="float32", seed=12345,
+                    use_pallas=False, updater=None):
+    """Hybrid attention / linear-attention expert decoder of the
+    `solar_open2` shape (upstage/Solar-Open2-250B): pre-norm blocks h +=
+    mixer(RMSNorm(h)); h += ffn(RMSNorm(h)). Layer i (0-based) mixes with
+    gated grouped-query attention — `n_heads` query heads on `n_kv_heads`
+    K/V heads of `head_dim`, no positions of any kind, the context times
+    sigmoid(x Wgate) before Wo — when i % (gqa_interval + 1) == 0 (the
+    period STARTS with it), else with a KimiDeltaAttentionLayer of `n_heads`
+    heads in Kimi Linear's own form: the unbounded softplus decay, decay and
+    output gates of rank `kda_gate_rank`, beta in (0, 2). Its ffn, in every
+    layer, is `experts_per_token` of `n_experts` routed gated experts of
+    width `expert_hidden` — sigmoid scores, a selection-only bias, one
+    group, gates renormalised and times `routed_scaling` — beside a shared
+    expert of width `shared_hidden` on the same norm; this model holds
+    `experts_held` of the routed experts from `first_expert` on (default:
+    all; the rest of the sum is another chip's). `ffn_mult` builds nothing:
+    it is the published `intermediate_size` of a dense layer the model does
+    not have, accepted because a configuration file's `args` carry it for
+    the benchmark's `model_dims`. h_0 = E[ids]; probabilities =
+    softmax(RMSNorm(h) W_head^T), the head untied. Input one-hot [b, t,
+    vocab]. The default updater is plain SGD: it keeps no state beside the
+    parameters."""
+    from ..nn.conf.layers import (GatedDenseLayer, KimiDeltaAttentionLayer,
+                                  LMHeadLayer, MixtureOfExpertsLayer,
+                                  RMSNormalization, SelfAttentionLayer)
+    gb = (NeuralNetConfiguration.builder()
+          .seed(seed).updater(updater or Sgd(learning_rate=1e-3))
+          .weight_init("xavier").dtype(dtype)
+          .graph_builder()
+          .add_inputs("tokens"))
+    norm = lambda: RMSNormalization(eps=rms_norm_eps)
+
+    def residual(name, prev, branch):
+        gb.add_vertex(name, ElementWiseVertex("add"), prev, branch)
+        return name
+
+    gb.add_layer("embed", DenseLayer(n_out=d_model, activation="identity"),
+                 "tokens")
+    prev = "embed"
+    for i in range(n_layers):
+        gb.add_layer(f"b{i}_norm1", norm(), prev)
+        if i % (gqa_interval + 1) == 0:
+            mixer = f"b{i}_attn"
+            gb.add_layer(mixer, SelfAttentionLayer(
+                n_out=d_model, n_heads=n_heads, n_kv_heads=n_kv_heads,
+                head_dim=head_dim, output_gate=True, causal=True,
+                use_pallas=use_pallas, activation="identity"), f"b{i}_norm1")
+        else:
+            mixer = f"b{i}_kda"
+            gb.add_layer(mixer, KimiDeltaAttentionLayer(
+                n_out=d_model, n_heads=n_heads, head_dim=kda_head_dim,
+                d_conv=kda_d_conv, chunk_size=kda_chunk_size,
+                gate_form="softplus", gate_rank=kda_gate_rank, beta_scale=2.0,
+                eps=rms_norm_eps, use_pallas=use_pallas), f"b{i}_norm1")
+        prev = residual(f"b{i}_res1", prev, mixer)
+        gb.add_layer(f"b{i}_norm2", norm(), prev)
+        gb.add_layer(f"b{i}_mlp", GatedDenseLayer(
+            n_out=d_model, n_hidden=shared_hidden), f"b{i}_norm2")
+        gb.add_layer(f"b{i}_moe", MixtureOfExpertsLayer(
+            n_out=d_model, n_experts=n_experts, top_k=experts_per_token,
+            gated=True, n_hidden=expert_hidden, experts_held=experts_held,
+            first_expert=first_expert, score_function="sigmoid",
+            n_groups=1, routed_scaling=routed_scaling,
+            use_pallas=use_pallas, activation="identity"), f"b{i}_norm2")
+        gb.add_vertex(f"b{i}_ffn", ElementWiseVertex("add"), f"b{i}_moe",
+                      f"b{i}_mlp")
+        prev = residual(f"b{i}_res2", prev, f"b{i}_ffn")
+    gb.add_layer("norm", norm(), prev)
+    gb.add_layer("out", LMHeadLayer(n_out=vocab_size, activation="softmax",
+                                    loss="MCXENT"), "norm")
+    gb.set_outputs("out")
+    gb.set_input_types(InputType.recurrent(vocab_size))
+    return ComputationGraph(gb.build())
+
+
 def vgg16(num_classes=1000, image_size=224, seed=12345):
     """VGG16 (reference: trainedmodels/TrainedModels.java VGG16)."""
     b = (NeuralNetConfiguration.builder()

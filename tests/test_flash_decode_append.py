@@ -118,18 +118,19 @@ def test_fused_call_is_one_kernel_named_flash_decode():
 
 
 @pytest.mark.parametrize("shape,interpret,kernels", [
-    ((2, 256, 2, 128), False, None),  # head_dim 128: a row-major buffer
+    ((2, 256, 2, 128), False, None),  # row-major, 2 K/V heads: no whole tile
     ((2, 1000, 2, 64), False, None),  # a capacity 128 does not divide
     ((2, 64, 2, 16), False, None),    # capacity under one lane tile
-    ((2, 32, 2, 128), True, ["flash_decode"]),
+    ((2, 32, 2, 192), True, ["flash_decode"]),  # head_dim no multiple of 128
 ], ids=str)
 def test_shapes_that_do_not_tile_take_the_two_calls_and_count(shape,
                                                               interpret,
                                                               kernels):
-    """What `_append_block` or `_decode_block` refuses is today's two calls
-    (compiled shapes are only traced here: no TPU), counted once; at
-    head_dim 128 the append is XLA's update and the decode kernel still
-    runs."""
+    """What `_append_block`, `_decode_block` and (since PR 43, for a
+    row-major cache) `_rows_block` all refuse is the two calls (compiled
+    shapes are only traced here: no TPU), counted once; at head_dim >= 128
+    the append is then XLA's update and the decode kernel still runs. (8
+    K/V heads of 128 take the row-major kernel: tests/test_solar_hybrid.py.)"""
     from deeplearning4j_tpu.telemetry.registry import get_registry
     S, C_, H, D = shape
     q, k, v, k_new, v_new = operands(jnp.float32, H, seed=1, shape=shape)

@@ -106,8 +106,8 @@ def test_kda_step_is_one_position_of_the_scan(use_pallas):
 
 def test_kda_step_falls_back_where_a_slot_is_no_tile():
     from deeplearning4j_tpu.telemetry.registry import get_registry
-    assert ks._kda_tiles(32, 128, 128, 4, False)
-    assert not ks._kda_tiles(64, 128, 128, 4, False)     # 4 MB a slot
+    assert ks._kda_tiles(32, 128, 128, 4, False) == 32   # one group a slot
+    assert ks._kda_tiles(64, 128, 128, 4, False) == 32   # 4 MB a slot: two
     assert not ks._kda_tiles(2, 8, 8, 4, False)
     counter = get_registry().counter("pallas_fallback_total", "")
     label = dict(kernel="kda_step", path="jnp",

@@ -244,8 +244,25 @@ def _sigmoid_case():
                                                   x.dtype))
 
 
-@pytest.mark.parametrize("case", [_softmax_case, _sigmoid_case],
-                         ids=["granite4_h_small", "ling3_flash"])
+def _one_group_case():
+    """`solar_open2`'s: sigmoid scores, a selection-only bias, ONE group, 8
+    of 320 experts a token, gates renormalised (x 1); eight shares of 40."""
+    from benchmarks.reference import solar_open2 as solar
+    bias = jnp.asarray(np.random.RandomState(1).randn(solar.N_EXPERTS) * 0.01)
+    return dict(
+        n=solar.N_EXPERTS, k=solar.EXPERTS_PER_TOKEN,
+        shares=solar.N_EXPERTS // solar.EXPERTS_HELD,
+        conf=dict(score_function="sigmoid", n_groups=1,
+                  routed_scaling=solar.ROUTED_SCALING),
+        leaves={"route_bias": bias},
+        gates=lambda x, Wg, leaves: solar.gates_of(
+            x, Wg, leaves["route_bias"], x.dtype))
+
+
+@pytest.mark.parametrize("case", [_softmax_case, _sigmoid_case,
+                                  _one_group_case],
+                         ids=["granite4_h_small", "ling3_flash",
+                              "solar_open2"])
 def test_the_shares_and_the_shared_expert_add_up_to_the_uncut_layer(case):
     """model-configs guide, section 4: the expert layer built once a share
     with first_expert 0, 1/n, 2/n .. of the experts and the same router; the

@@ -506,22 +506,94 @@ def test_ssm_step_compiles_in_place(on_chip):
     assert mem.temp_size_in_bytes < 1 << 20
 
 
-def test_flash_decode_at_head_dim_128_compiles_with_its_copy(on_chip):
-    """granite4_h_small's attention layer: 32 query heads over a bfloat16
-    cache of 8 K/V heads of 128, 32 slots of 1024. The kernel path (key
-    block 256), no counted fallback; at head_dim >= 128 the cache is
-    row-major, so the kernel's [S, H, D, C] operand is a transposing copy
-    of K and of V (67 MB each) — priced in PERF.md, not cured here."""
-    S, C, Hq, H, D = 32, 1024, 32, 8, 128
-    assert fa._decode_block(C, Hq, D, 2, 1024, False) == 256
-    text = compiled_text(
-        lambda q, k, v, n: flash_decode(q, k, v, n, scale=0.0078125,
-                                        interpret=False),
-        on_chip((S, 1, Hq, D), jnp.bfloat16),
-        on_chip((S, C, H, D), jnp.bfloat16),
-        on_chip((S, C, H, D), jnp.bfloat16), on_chip((S,), jnp.int32))
+@pytest.mark.parametrize("S,C,Hq", [(32, 1024, 32), (192, 4096, 64)],
+                         ids=["granite4_h_small", "solar_open2"])
+def test_flash_decode_append_at_head_dim_128_reads_the_cache_where_it_lies(
+        on_chip, S, C, Hq):
+    """An attention layer of a step over a bfloat16 cache of 8 K/V heads of
+    128 — granite4_h_small's (32 query heads, 32 slots of 1024) and
+    solar_open2's (64 query heads, 192 slots of 4096: two slabs of 1.6 GB).
+    At head_dim 128 the cache is row-major; the step's kernel reads it so
+    (its [S, C * 8, 128] view is a bitcast of the buffer) and writes the
+    token's rows itself: ONE kernel named `flash_decode`, both slabs aliased
+    onto the donated caches, no copy, transpose or scatter of a slab, no
+    loop over the slots, no counted fallback. Until PR 43 this shape paid a
+    transposing copy of K and of V every step (`flash_decode` alone, the
+    read-only entry the paged path and the fallback use, still does: two
+    relayouts, priced in PERF.md)."""
+    from deeplearning4j_tpu.telemetry.registry import get_registry
+    H, D = 8, 128
+    assert fa._rows_block(C, H, D, 2, 1024, False) == 256
+    fallbacks = get_registry().counter("pallas_fallback_total", "")
+    before = fallbacks.get()
+    kv = on_chip((S, C, H, D), jnp.bfloat16)
+    comp = compiled_step_layer(on_chip((S, 1, Hq, D), jnp.bfloat16), kv,
+                               on_chip((S, 1, H, D), jnp.bfloat16),
+                               on_chip((S,), jnp.int32), interpret=False)
+    assert fallbacks.get() == before
+    text = comp.as_text()
     assert text.count(KERNEL) == 1
-    assert len(relayouts(text, S * C * H * D)) == 2
+    assert len(re.findall(r"%flash_decode[.\d]* = ", text)) == 1
+    assert "%kv_append" not in text and "dynamic-update-slice" not in text
+    assert relayouts(text, S * C * H * D) == []
+    assert loops(text) == []
+    mem = comp.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * S * C * H * D * 2
+    assert mem.temp_size_in_bytes < 16 << 20
+    if S == 32:
+        text = compiled_text(
+            lambda q, k, v, n: flash_decode(q, k, v, n, scale=0.0078125,
+                                            interpret=False),
+            on_chip((S, 1, Hq, D), jnp.bfloat16), kv, kv,
+            on_chip((S,), jnp.int32))
+        assert text.count(KERNEL) == 1
+        assert len(relayouts(text, S * C * H * D)) == 2
+
+
+@pytest.mark.parametrize("H,shape", [(8, (1, 4)), (16, (2, 2))],
+                         ids=["2_heads_a_shard", "8_heads_a_shard"])
+def test_flash_decode_append_at_head_dim_128_chooses_by_the_shards_heads(
+        topo, chip_config, H, shape):
+    """`ServingServer(mesh=4)` over a bfloat16 cache of head_dim 128. The
+    row-major kernel's [S, C * H, D] view is the buffer only where the K/V
+    heads a SHARD holds fill a tile's 8 sublanes, so the choice is made on
+    that count: granite4_h_small's and solar_open2's 8 K/V heads split four
+    ways (2 a shard) give way, counted, to `kv_append` (at this head_dim
+    XLA's update, a loop over the slots) then `flash_decode` as they did
+    before PR 43, and compile; 16 K/V heads split two ways (8 a shard, the
+    slots over the data axis) take the one kernel per shard, in place. No
+    collective either way."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from deeplearning4j_tpu.parallel.sharding import DATA_AXIS, MODEL_AXIS
+    from deeplearning4j_tpu.telemetry.registry import get_registry
+    mesh = Mesh(np.array(topo.devices).reshape(shape), (DATA_AXIS, MODEL_AXIS))
+    S, C, D = 32, 1024, 128
+    heads = NamedSharding(mesh, P(DATA_AXIS, None, MODEL_AXIS, None))
+    q = jax.ShapeDtypeStruct((S, 1, 4 * H, D), jnp.bfloat16, sharding=heads)
+    kv = jax.ShapeDtypeStruct((S, C, H, D), jnp.bfloat16, sharding=heads)
+    new = jax.ShapeDtypeStruct((S, 1, H, D), jnp.bfloat16, sharding=heads)
+    pos = jax.ShapeDtypeStruct((S,), jnp.int32,
+                               sharding=NamedSharding(mesh, P(DATA_AXIS)))
+    fallbacks = get_registry().counter("pallas_fallback_total", "")
+    gave_way = dict(kernel="flash_decode", path="kv_append+flash_decode",
+                    shape=f"C={C},D={D},interpret=False")
+    before = fallbacks.get(**gave_way)
+    with jax.set_mesh(mesh):
+        assert fa._heads_per_shard(H) == H // shape[1]
+        comp = compiled_step_layer(q, kv, new, pos, interpret=False)
+    assert fallbacks.get(**gave_way) - before == (H // shape[1] != 8)
+    text = comp.as_text()
+    assert text.count(KERNEL) == 1
+    assert len(re.findall(r"%flash_decode[.\d]* = ", text)) == 1
+    assert not COLLECTIVE.search(text)
+    if H // shape[1] != 8:
+        assert len(loops(text)) == 2
+    else:
+        per_shard = S * C * H * D // 4
+        assert relayouts(text, per_shard) == []
+        assert loops(text) == []
+        assert "dynamic-update-slice" not in text
+        assert comp.memory_analysis().alias_size_in_bytes == 2 * per_shard * 2
 
 
 @pytest.mark.parametrize("tokens,tm", [(32, 16), (256, 128), (2048, 512)],
@@ -548,13 +620,20 @@ def test_expert_gmm_compiles_at_the_cells_widths(on_chip, tokens, tm):
     assert comp.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
-def test_kda_step_compiles_in_place(on_chip):
+@pytest.mark.parametrize("S,H", [(128, 32), (192, 64)],
+                         ids=["ling3_flash", "solar_open2"])
+def test_kda_step_compiles_in_place(on_chip, S, H):
     """The delta-rule state update at `ling3_flash`'s size, 128 slots x [32,
-    128, 128] float32: one kernel — its per-head columns sliced from ONE
-    packed lane tile, which Mosaic has to accept —, the donated state aliased
-    onto its output (no second 268 MB buffer, no copy of it)."""
+    128, 128] float32 (one head group a slot), and at `solar_open2`'s, 192
+    slots x [64, 128, 128] (4 MB a slot: two groups of 32, 805 MB of state):
+    one kernel — a group's per-head columns sliced from ONE packed lane
+    tile, which Mosaic has to accept —, the donated state aliased onto its
+    output (no second buffer, no copy of it), no counted fallback."""
     from deeplearning4j_tpu.kernels import kda_step
-    S, H, D = 128, 32, 128
+    from deeplearning4j_tpu.telemetry.registry import get_registry
+    D = 128
+    fallbacks = get_registry().counter("pallas_fallback_total", "")
+    before = fallbacks.get()
     row = on_chip((S, H, D), jnp.float32)
     comp = jax.jit(
         lambda st, a, k, q, b, v: kda_step(st, a, k, q, b, v,
@@ -562,13 +641,15 @@ def test_kda_step_compiles_in_place(on_chip):
         donate_argnums=(0,)).lower(
             on_chip((S, H, D, D), jnp.float32), row, row, row,
             on_chip((S, H), jnp.float32), row).compile()
+    assert fallbacks.get() == before
     text = comp.as_text()
     assert text.count(KERNEL) == 1
     assert len(re.findall(r"%kda_step[.\d]* = ", text)) == 1
     assert relayouts(text, S * H * D * D) == []
     mem = comp.memory_analysis()
     assert mem.alias_size_in_bytes == S * H * D * D * 4
-    assert mem.temp_size_in_bytes < 16 << 20
+    columns = S * D * 4 * H * 4     # the packed [S, 128, 4 H] float32 operand
+    assert mem.temp_size_in_bytes < max(16 << 20, columns + (1 << 20))
 
 
 def test_mla_decode_and_latent_append_compile_on_the_padded_row(on_chip):
@@ -832,6 +913,52 @@ def test_ling_decode_step_compiles_with_its_three_new_kernels(
     text = _prefill_text(eng, 128, one_chip)
     assert len(re.findall(r"%expert_gmm_1x128[.\d]* = ", text)) == 4
     assert "kda_step" not in text and "mla_decode" not in text
+
+
+def test_solar_decode_step_compiles_with_its_kernels_and_no_copy_of_a_slab(
+        one_chip, chip_config, monkeypatch):
+    """One period of `solar_hybrid_lm` (gated attention, then KDA x 3; four
+    routed ffns) at 16 of the configuration's 64 heads on its own 8 K/V
+    heads of 128, its 128 x 128 delta-rule heads and rank-128 gates,
+    bfloat16, 16 slots of 256: the attention layer is ONE `flash_decode`
+    (the row-major kernel: the token's rows written by it), a KDA layer one
+    `kda_step`, an expert layer one `expert_gmm_16x1`; no loop over the
+    slots, no copy, transpose or scatter of a K/V slab or of a state, and
+    no kernel gave way (the counted fallbacks stand still). (The whole step
+    at the cell's size — 192 slots of 4,096, 64 heads — compiles here in
+    37 s with 12.34 GB of arguments and 0.107 GB of temporaries, 8 kernels
+    and no relayout of a slab or a state, its 1,024-token prefill in 49 s:
+    PERF.md section 4.)"""
+    from deeplearning4j_tpu.decode.engine import DecodeEngine
+    from deeplearning4j_tpu.telemetry.registry import get_registry
+    from deeplearning4j_tpu.zoo.models import solar_hybrid_lm
+    for module in ("flash_attention", "kda_step", "expert_gmm"):
+        monkeypatch.setattr(
+            importlib.import_module("deeplearning4j_tpu.kernels." + module),
+            "_interpret_default", lambda: False)
+    net = solar_hybrid_lm(vocab_size=512, d_model=256, n_layers=4, n_heads=16,
+                          n_kv_heads=8, n_experts=32, experts_held=8,
+                          expert_hidden=128, shared_hidden=128,
+                          dtype="bfloat16", use_pallas=True).init()
+    eng = DecodeEngine(net, slots=16, max_len=256)
+    fallbacks = get_registry().counter("pallas_fallback_total", "")
+    before = fallbacks.get()
+    args = _abstract((net.params, net.states, eng.init_cache(),
+                      np.zeros((eng.slots,), np.int32),
+                      eng._greedy_step_ops), one_chip)
+    text = eng._build_step().lower(*args, None).compile().as_text()
+    assert fallbacks.get() == before
+    assert text.count(KERNEL) == 8
+    assert len(re.findall(r"%flash_decode[.\d]* = ", text)) == 1
+    assert len(re.findall(r"%kda_step[.\d]* = ", text)) == 3
+    assert len(re.findall(r"%expert_gmm_16x1[.\d]* = ", text)) == 4
+    assert "%kv_append" not in text
+    assert loops(text) == []
+    assert relayouts(text, 16 * 256 * 8 * 128) == []     # a K or V slab
+    assert relayouts(text, 16 * 16 * 128 * 128) == []    # a layer's state
+    text = _prefill_text(eng, 128, one_chip)
+    assert len(re.findall(r"%expert_gmm_1x128[.\d]* = ", text)) == 4
+    assert "kda_step" not in text and "%flash_decode" not in text
 
 
 @pytest.mark.slow
