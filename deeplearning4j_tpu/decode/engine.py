@@ -219,10 +219,16 @@ class DecodeEngine:
         # what each stateful layer declared it keeps: {name: {leaf: (shape,
         # dtype, model axis)}}; the entries a length reset cannot rewind
         # are the carries
+        # mesh-sharded decode (serving/mesh.py): a wrapped model carries the
+        # serving MeshContext; the KV cache partitions its head axis over
+        # the mesh model axis and the step/prefill executables pin the
+        # cache's out_shardings so donation survives partitioning
+        self.mesh = getattr(model, "mesh_context", None)
         geom = SimpleNamespace(
             slots=self.slots, capacity=self.capacity, dtype=self._dtype,
             paged=self.paged, block_size=self.block_size,
-            num_blocks=self.num_blocks)
+            num_blocks=self.num_blocks,
+            model_shards=1 if self.mesh is None else self.mesh.model_size)
         self._entries = {}
         self._carries = set()
         for node in self.nodes:
@@ -260,11 +266,6 @@ class DecodeEngine:
                 "decode_steps_total", "Decode steps by what their sampling "
                 "operands asked for: sampler=\"greedy\" (no slot with a "
                 "positive temperature: argmax only) or \"sampled\"")
-        # mesh-sharded decode (serving/mesh.py): a wrapped model carries the
-        # serving MeshContext; the KV cache partitions its head axis over
-        # the mesh model axis and the step/prefill executables pin the
-        # cache's out_shardings so donation survives partitioning
-        self.mesh = getattr(model, "mesh_context", None)
         self._cache_shardings = None        # lazily built pytree
         self._step_fn = None
         self._prefill_fns = {}              # length bucket -> jitted fn

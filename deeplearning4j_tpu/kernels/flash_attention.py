@@ -64,7 +64,11 @@ then runs `_decode_rows_kernel` (the same name in a trace): a block's
 positions x heads as the rows of one tile, both products on the MXU for all
 query heads at once, the token's rows written by one copy a buffer. The
 read-only `flash_decode` still hands such a cache to `_decode_kernel` through
-a transposing copy.
+a transposing copy. Narrower heads reach the same kernel PACKED
+(`packed_rows`): a cache declared [slots, capacity, H * D // 128, 128], 128
+// D heads side by side on a row, is stored row-major too — 16 heads of 64 in
+float32 are one (8, 128) tile a position — and a token is then two 4 KB
+copies where the positions-minor cache has it in one lane of 256 tiles.
 
 Gives way to a pure-JAX path (see `flash_attention`) when shapes don't tile,
 so callers can use it unconditionally; each such call is counted in
@@ -125,16 +129,17 @@ def _per_shard(fn, arrays, B, H):
                          check_vma=False)(*arrays)
 
 
-def _heads_per_shard(H):
+def _heads_per_shard(H, size=None):
     """The heads of `H` that one shard's kernel sees under `_per_shard`: the
-    model axis of the ambient mesh splits them wherever it divides. A kernel
-    whose tiling depends on the head count is chosen by this count, not by
-    the global one."""
-    mesh = jax.sharding.get_abstract_mesh()
-    if mesh.empty:
-        return H
-    from ..parallel.sharding import MODEL_AXIS
-    size = mesh.shape.get(MODEL_AXIS, 1)
+    model axis (of `size`; by default the ambient mesh's) splits them
+    wherever it divides. A kernel whose tiling depends on the head count is
+    chosen by this count, not by the global one."""
+    if size is None:
+        mesh = jax.sharding.get_abstract_mesh()
+        if mesh.empty:
+            return H
+        from ..parallel.sharding import MODEL_AXIS
+        size = mesh.shape.get(MODEL_AXIS, 1)
     return H // size if H % size == 0 else H
 
 
@@ -1271,13 +1276,15 @@ _ROWS_BLOCK_POSITIONS = 256
 def _rows_block(C, H, D, itemsize, block_k, interpret):
     """Key-block length of the row-major decode kernel, or None => the
     cache is not one it reads (`flash_decode_append` then takes the other
-    kernel or the two calls). head_dim has to be a multiple of the lanes:
-    the TPU then stores a [S, C, H, D] cache row-major, a position's [H, D]
-    values in whole tiles, and — compiled — H has to fill a tile's 8
-    sublanes (packed or not: a [.., 8, 128] bfloat16 array lies in (8,
-    128)(2, 1) tiles), so that the [S, C * H, D] view the kernel copies from
-    is the buffer itself; the block is then a multiple of 128 positions.
-    Interpret mode takes any H and any divisor of the capacity."""
+    kernel or the two calls). H and D are a position's ROWS as the cache
+    holds them — K/V heads of head_dim, or, packed (`packed_rows`), rows of
+    128 lanes with several heads side by side. D has to be a multiple of
+    the lanes: the TPU then stores a [S, C, H, D] cache row-major, a
+    position's [H, D] values in whole tiles, and — compiled — H has to fill
+    a tile's 8 sublanes (packed or not: a [.., 8, 128] bfloat16 array lies
+    in (8, 128)(2, 1) tiles), so that the [S, C * H, D] view the kernel
+    copies from is the buffer itself; the block is then a multiple of 128
+    positions. Interpret mode takes any H and any divisor of the capacity."""
     if D % LANES or (not interpret and H % 8):
         return None
     target = min(block_k, C, _ROWS_BLOCK_POSITIONS,
@@ -1285,29 +1292,71 @@ def _rows_block(C, H, D, itemsize, block_k, interpret):
     return _fit_block(C, target, 1 if interpret else LANES)
 
 
+def packed_rows(H, D, shards=1):
+    """Rows of 128 lanes a cache position takes when its `H` K/V heads of
+    `D` are PACKED, 128 // D of them side by side on a row — or None where
+    they are not to be: D does not divide the lanes (or fills them), or the
+    rows ONE shard of a model axis of `shards` holds (`_heads_per_shard`)
+    are not whole (8, 128) tiles. A `[S, C, H, D]` cache with D < 128 is stored positions-minor
+    on the TPU; declared `[S, C, packed_rows, 128]` the same bytes are
+    row-major, one tile a position for 16 heads of 64 in float32, which is
+    what `_decode_rows_kernel` reads and writes a token into by one 4 KB
+    copy. `[B, T, H, D] -> [B, T, H * D // 128, 128]` is the packing: a
+    plain reshape."""
+    if D >= LANES or LANES % D or \
+            _heads_per_shard(H, shards) * D % (8 * LANES):
+        return None
+    return H * D // LANES
+
+
+def _rows_dot(small, rows_ref, b, dims):
+    """`small [M, .] . rows_ref[b]` of the row-major decode kernel on the
+    MXU, float32 out; `dims` the contraction as `lax.dot_general` takes it.
+    It multiplies in the cache's dtype: bfloat16 rows in one pass, float32
+    rows at full float32 precision (Mosaic's default for them is a single
+    bfloat16 pass, 2 x 10^-3 off at 48 x 1024 x 16 x 64; at full precision
+    the kernel is as fast, bound by its copies: PERF.md section 6, PR 44)."""
+    exact = jax.lax.Precision.HIGHEST if rows_ref.dtype == jnp.float32 \
+        else None
+    return jax.lax.dot_general(small.astype(rows_ref.dtype), rows_ref[b],
+                               (dims, ((), ())), precision=exact,
+                               preferred_element_type=jnp.float32)
+
+
 def _decode_rows_kernel(len_ref, q_ref, kx_ref, vx_ref, kn_ref, vn_ref, k_hbm,
                         v_hbm, o_ref, ko_hbm, vo_hbm, k_buf, v_buf, sem, wsem,
                         buf_ref, bias_ref, acc_ref, m_ref, l_ref, *, scale,
                         block_c, slots, heads, group):
-    """One slot of decode attention on a ROW-MAJOR cache (head_dim a multiple
-    of the lanes), the step's token appended on the way. k_hbm / v_hbm are
-    the whole caches viewed [S, C * H, D] — row c * H + h is K/V head h of
-    position c, which is how a [S, C, H, D] array with H = 8 lies in HBM —
-    and stay there; a key block is `block_c` positions = `block_c * H`
-    consecutive rows, copied as they lie into one of two VMEM buffers (the
-    scheme of `_decode_kernel`: the next copy started before the current one
-    is waited for, the next slot's first block under this slot's last).
+    """One slot of decode attention on a ROW-MAJOR cache (a position's
+    values in rows of a multiple of 128 lanes), the step's token appended on
+    the way. k_hbm / v_hbm are the whole caches viewed [S, C * H, D] — row
+    c * H + h is row h of position c, which is how a [S, C, H, D] array with
+    H = 8 lies in HBM — and stay there; a key block is `block_c` positions =
+    `block_c * H` consecutive rows, copied as they lie into one of two VMEM
+    buffers (the scheme of `_decode_kernel`: the next copy started before
+    the current one is waited for, the next slot's first block under this
+    slot's last).
 
-    Positions x heads are on the sublanes and head_dim on the lanes, so
+    A row is a K/V head of head_dim D, or `pack` heads of 128 // pack side
+    by side on 128 lanes (`packed_rows`: the output's rows are then narrower
+    than the cache's); `heads` counts
+    the rows a position, `group` the query heads a row serves (the grouped
+    heads' G times `pack`). Packed, a query head enters as a 128-lane row
+    that is zero outside its own K/V head's lanes, so its product with a
+    row is its product with that head; the accumulator keeps 128 lanes a
+    query head, of which its own are picked once a slot, at the end.
+
+    Positions x rows are on the sublanes and a row's values on the lanes, so
     both products run on the MXU with no relayout of a tile: the scores of
     ALL query heads against ALL rows of the block, `q [Hq, D] . rows
     [block_c * H, D]^T`, of which a query head keeps the columns of its own
-    K/V head (`bias_ref`: 0 there, NEG_INF elsewhere, built once a call), and
-    `p [Hq, block_c * H] . rows` with p = 0 in the other heads' columns. The
+    row (`bias_ref`: 0 there, NEG_INF elsewhere, built once a call), and
+    `p [Hq, block_c * H] . rows` with p = 0 in the other rows' columns. The
     MXU's cost is the tiles of the block it has to load as weights, the same
-    whether 8 or 64 query rows stream past them, so a K/V head's block is
-    read once and serves its whole group. Scores, softmax and accumulator
-    are float32; the products multiply in the cache's dtype.
+    whether 8 or 64 query rows stream past them, so a row's block is read
+    once and serves its whole group. Scores, softmax and accumulator are
+    float32; the products multiply in the cache's dtype (`_rows_dot`: at
+    full precision on float32 rows).
 
     The token: the slot holds `length` tokens, the last of them this step's,
     whose K and V rows (`kn_ref` / `vn_ref`, [1, H, D]) are not in the cache
@@ -1364,9 +1413,7 @@ def _decode_rows_kernel(len_ref, q_ref, kx_ref, vx_ref, kn_ref, vn_ref, k_hbm,
             copy.start()
         for copy in copies(si, j, b):
             copy.wait()
-        k = k_buf[b]                                        # [rows, D]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = _rows_dot(q, k_buf, b, ((1,), (1,))) * scale
         cached = jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1) \
             < (pos - j * block_c) * heads
         s = jnp.where(cached, s + bias_ref[...], NEG_INF)   # [Hq, rows]
@@ -1374,8 +1421,8 @@ def _decode_rows_kernel(len_ref, q_ref, kx_ref, vx_ref, kn_ref, vn_ref, k_hbm,
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
-        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-            p.astype(k.dtype), v_buf[b], preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * corr + _rows_dot(
+            p, v_buf, b, ((1,), (0,)))
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
         m_ref[...] = m_new
         return 1 - b
@@ -1388,7 +1435,15 @@ def _decode_rows_kernel(len_ref, q_ref, kx_ref, vx_ref, kn_ref, vn_ref, k_hbm,
         for copy in copies(0, 0, b):
             copy.wait()
 
-    o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)   # l >= 1
+    out = acc_ref[...] / l_ref[...]                         # l >= 1
+    D = o_ref.shape[-1]
+    pack = out.shape[1] // D
+    if pack > 1:    # a query head's own lanes of its 128
+        lane = jax.lax.broadcasted_iota(jnp.int32, out.shape, 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, out.shape, 0)
+        out = jnp.where(lane // D == row % group // (group // pack), out, 0.0)
+        out = sum(out[:, i * D:(i + 1) * D] for i in range(pack))
+    o_ref[0] = out.astype(o_ref.dtype)
     for write in writes:
         write.wait()
 
@@ -1397,23 +1452,30 @@ def _decode_rows_kernel(len_ref, q_ref, kx_ref, vx_ref, kn_ref, vn_ref, k_hbm,
 def _decode_rows_call(q, k, v, k_new, v_new, lengths, scale, block_c,
                       interpret):
     """`_decode_append_call` for a row-major cache: q [S, 1, Hq, D], k/v [S,
-    C, H, D], k_new/v_new [S, 1, H, D], lengths [S] (the appended token
+    C, H, W] — W = D, or packed (`packed_rows`) H * W = K/V heads * D with W
+    = 128 —, k_new/v_new [S, 1, H, W], lengths [S] (the appended token
     counted) -> (out [S, 1, Hq, D], k, v). The kernel's view of a cache is
-    [S, C * H, D]: for H = 8 the same tiles in the same order, so the
+    [S, C * H, W]: for H = 8 the same tiles in the same order, so the
     reshape is a bitcast (tests/test_tpu_compile.py holds it to that) and
     the two slab outputs are aliased onto the caches. In a trace it is
     `flash_decode`, as the kernel it stands in for."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     S, _, Hq, D = q.shape
-    C, H = k.shape[1], k.shape[2]
-    G = Hq // H
+    C, H, W = k.shape[1:]
+    pack = W // D
+    G = Hq // H                     # query heads a row
     rows = block_c * H
-    row = pl.BlockSpec((1, Hq, D), lambda s, lens: (s, 0, 0))
-    new = pl.BlockSpec((1, H, D), lambda s, lens: (s, 0, 0))
+    row = pl.BlockSpec((1, Hq, W), lambda s, lens: (s, 0, 0))
+    new = pl.BlockSpec((1, H, W), lambda s, lens: (s, 0, 0))
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
-    slab = jax.ShapeDtypeStruct((S, C * H, D), k.dtype)
-    kn, vn = k_new.reshape(S, H, D), v_new.reshape(S, H, D)
+    slab = jax.ShapeDtypeStruct((S, C * H, W), k.dtype)
+    kn, vn = k_new.reshape(S, H, W), v_new.reshape(S, H, W)
+    q = q.reshape(S, Hq, D).astype(k.dtype)
+    if pack > 1:    # zero outside the lanes of the query head's K/V head
+        own = (jnp.arange(Hq) % G // (G // pack))[:, None] == jnp.arange(pack)
+        q = jnp.where(own[None, :, :, None], q[:, :, None, :],
+                      jnp.zeros((), q.dtype)).reshape(S, Hq, W)
     out, nk, nv = pl.pallas_call(
         functools.partial(_decode_rows_kernel, scale=scale, block_c=block_c,
                           slots=S, heads=H, group=G),
@@ -1421,15 +1483,16 @@ def _decode_rows_call(q, k, v, k_new, v_new, lengths, scale, block_c,
             num_scalar_prefetch=1,
             grid=(S,),
             in_specs=[row, row, row, new, new, in_hbm, in_hbm],
-            out_specs=[row, in_hbm, in_hbm],
+            out_specs=[pl.BlockSpec((1, Hq, D), lambda s, lens: (s, 0, 0)),
+                       in_hbm, in_hbm],
             scratch_shapes=[
-                pltpu.VMEM((2, rows, D), k.dtype),           # K tiles
-                pltpu.VMEM((2, rows, D), k.dtype),           # V tiles
+                pltpu.VMEM((2, rows, W), k.dtype),           # K tiles
+                pltpu.VMEM((2, rows, W), k.dtype),           # V tiles
                 pltpu.SemaphoreType.DMA((2, 2)),             # (K | V, buffer)
                 pltpu.SemaphoreType.DMA((2,)),               # K | V written
                 pltpu.SMEM((1,), jnp.int32),                 # next buffer
-                pltpu.VMEM((Hq, rows), jnp.float32),         # own-head bias
-                pltpu.VMEM((Hq, D), jnp.float32),            # acc
+                pltpu.VMEM((Hq, rows), jnp.float32),         # own-row bias
+                pltpu.VMEM((Hq, W), jnp.float32),            # acc
                 pltpu.VMEM((Hq, 1), jnp.float32),            # running max
                 pltpu.VMEM((Hq, 1), jnp.float32),            # running sum
             ]),
@@ -1441,9 +1504,8 @@ def _decode_rows_call(q, k, v, k_new, v_new, lengths, scale, block_c,
             vmem_limit_bytes=_DECODE_APPEND_VMEM_BYTES),
         interpret=interpret,
         name="flash_decode",
-    )(lengths, q.reshape(S, Hq, D).astype(k.dtype),
-      jnp.repeat(kn, G, axis=1), jnp.repeat(vn, G, axis=1), kn, vn,
-      k.reshape(S, C * H, D), v.reshape(S, C * H, D))
+    )(lengths, q, jnp.repeat(kn, G, axis=1), jnp.repeat(vn, G, axis=1), kn,
+      vn, k.reshape(S, C * H, W), v.reshape(S, C * H, W))
     return (out.reshape(S, 1, Hq, D), nk.reshape(k.shape),
             nv.reshape(v.shape))
 
@@ -1454,12 +1516,14 @@ def flash_decode_append(q, k, v, k_new, v_new, pos, *, scale=None,
     token to the cache and attend to the cache with it.
 
     q: [slots, 1, heads, head_dim]; k, v: [slots, capacity, kv_heads,
-    head_dim] — the cache, in place when donated; k_new, v_new: [slots, 1,
-    kv_heads, head_dim] — the token, in the cache's dtype; pos: [slots]
-    int32 — where each slot appends, inside [0, capacity): the slot then
-    holds pos + 1 tokens, so `flash_decode`'s length-0 contract does not
-    exist here. Returns (out, k, v): the cache `kv_append` leaves and, on
-    it, the rows `flash_decode(q, k, v, pos + 1)` gives, both bit for bit.
+    head_dim] — the cache, in place when donated — or PACKED, [slots,
+    capacity, kv_heads * head_dim // 128, 128] (`packed_rows`: recognised by
+    its shape against the token's); k_new, v_new: [slots, 1, kv_heads,
+    head_dim] — the token, in the cache's dtype; pos: [slots] int32 — where
+    each slot appends, inside [0, capacity): the slot then holds pos + 1
+    tokens, so `flash_decode`'s length-0 contract does not exist here.
+    Returns (out, k, v): the cache `kv_append` leaves and, on it, the rows
+    `flash_decode(q, k, v, pos + 1)` gives, both bit for bit.
 
     The kernel is `flash_decode`'s (`_decode_kernel`, and its name in a
     trace) with the append folded in: when a slot's last live block — the
@@ -1467,20 +1531,25 @@ def flash_decode_append(q, k, v, k_new, v_new, pos, *, scale=None,
     and the 128 lanes round it are copied back to the cache under the
     block's arithmetic. The cache is written exactly where `kv_append`
     writes it; the append's own read of that tile, its launch and its grid
-    are gone. A head_dim that is a multiple of 128 is stored row-major and
-    takes the kernel that reads it so (`_decode_rows_call`, `_rows_block`:
-    the same contract, the output to float32 rounding — its products run on
-    the MXU in another order — and both slabs bit for bit). Gives way to the
-    two calls, counted in `pallas_fallback_total{kernel="flash_decode",
+    are gone. A cache that is stored row-major — head_dim a multiple of 128,
+    or packed — takes the kernel that reads it so (`_decode_rows_call`,
+    `_rows_block`: the same contract, the output to float32 rounding — its
+    products run on the MXU in another order — and both slabs bit for bit).
+    Gives way to the two calls, counted in
+    `pallas_fallback_total{kernel="flash_decode",
     path="kv_append+flash_decode"}`, wherever neither kernel takes these
     shapes (`_append_block`, `_decode_block`, `_rows_block` — under a mesh
-    the last is asked about the K/V heads one shard holds, so 8 heads split
-    over a model axis give way), and is the two references under
+    the last is asked about the rows one shard holds, so 8 heads split over
+    a model axis give way; a packed cache is unpacked for them and packed
+    again, a copy of both slabs), and is the two references under
     `use_pallas=False`."""
     S, Tq, Hq, D = q.shape
     assert Tq == 1, f"flash_decode takes one query per slot, got Tq={Tq}"
-    C, H = k.shape[1], k.shape[2]
+    C, H = k.shape[1], k_new.shape[2]
     assert Hq % H == 0, f"{Hq} query heads over {H} K/V heads"
+    packed = k.shape[2:] != (H, D)
+    assert not packed or k.shape[2:] == (H * D // LANES, LANES), \
+        f"a cache of {k.shape} for {H} K/V heads of {D}"
     if scale is None:
         scale = float(1.0 / (D ** 0.5))
     if interpret is None:
@@ -1488,26 +1557,31 @@ def flash_decode_append(q, k, v, k_new, v_new, pos, *, scale=None,
     pos = jnp.asarray(pos, jnp.int32)
     size = k.dtype.itemsize
     block_c, call = None, _decode_append_call
-    if use_pallas and _append_block(C, D, size, interpret):
+    if use_pallas and not packed and _append_block(C, D, size, interpret):
         block_c = _decode_block(C, Hq, D, size, block_k, interpret)
     elif use_pallas:
-        # the rows kernel's view needs the SHARD's K/V heads to fill a tile
-        block_c, call = _rows_block(C, _heads_per_shard(H), D, size, block_k,
+        # the rows kernel's view needs the SHARD's rows to fill a tile
+        block_c, call = _rows_block(C, _heads_per_shard(k.shape[2]),
+                                    k.shape[3], size, block_k,
                                     interpret), _decode_rows_call
-    if block_c is None:
-        if use_pallas:
-            _note_fallback("flash_decode", "kv_append+flash_decode", C=C,
-                           D=D, interpret=interpret)
-        with jax.named_scope("kv_append"):
-            k, v = kv_append(k, v, k_new, v_new, pos, use_pallas=use_pallas,
-                             interpret=interpret)
-        return flash_decode(q, k, v, pos + 1, scale=scale,
-                            use_pallas=use_pallas, block_k=block_k,
-                            interpret=interpret), k, v
-    _note_decode_block(block_c, C=C, H=H, D=D, itemsize=size)
-    return _per_shard(
-        lambda *a: call(*a, scale, block_c, interpret),
-        (q, k, v, k_new, v_new, pos + 1), S, H)
+    if block_c is not None:
+        _note_decode_block(block_c, C=C, H=H, D=D, itemsize=size)
+        k_new, v_new = (x.reshape(S, 1, *k.shape[2:]) for x in (k_new, v_new))
+        # split by the cache's rows: packed, they divide where the heads do
+        return _per_shard(
+            lambda *a: call(*a, scale, block_c, interpret),
+            (q, k, v, k_new, v_new, pos + 1), S, k.shape[2])
+    if use_pallas:
+        _note_fallback("flash_decode", "kv_append+flash_decode", C=C, D=D,
+                       interpret=interpret)
+    rows = k.shape
+    k, v = (x.reshape(S, C, H, D) for x in (k, v))
+    with jax.named_scope("kv_append"):
+        k, v = kv_append(k, v, k_new, v_new, pos, use_pallas=use_pallas,
+                         interpret=interpret)
+    out = flash_decode(q, k, v, pos + 1, scale=scale, use_pallas=use_pallas,
+                       block_k=block_k, interpret=interpret)
+    return out, k.reshape(rows), v.reshape(rows)
 
 
 def flash_decode_paged(q, k_pool, v_pool, block_table, lengths, *,

@@ -119,9 +119,10 @@ class BaseLayerModule:
 
     def decode_entry(self, geom):
         """{leaf name: CacheLeaf} the layer keeps in the decode cache, from
-        the engine's geometry: `slots`, `capacity`, `dtype`, and `paged`
-        with `block_size` / `num_blocks` for the block-pool layout. Empty:
-        no state."""
+        the engine's geometry: `slots`, `capacity`, `dtype`, `paged` with
+        `block_size` / `num_blocks` for the block-pool layout, and
+        `model_shards`, the size of the serving mesh's model axis (1 with no
+        mesh). Empty: no state."""
         return {}
 
     def decode_prefill(self, params, state, x, entry, ctx):

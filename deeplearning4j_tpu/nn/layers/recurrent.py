@@ -401,11 +401,26 @@ class SelfAttentionLayerModule(BaseLayerModule):
         return None
 
     def decode_entry(self, geom):
+        from ...kernels.flash_attention import packed_rows
         H, Dh = self.kv_heads, self.head_dim
-        shape = ((geom.num_blocks, geom.block_size, H, Dh) if geom.paged
-                 else (geom.slots, geom.capacity, H, Dh))
+        if geom.paged:
+            shape = (geom.num_blocks, geom.block_size, H, Dh)
+        else:
+            # a slab the step's kernel reads: heads narrower than a lane row
+            # packed side by side where one shard's then fill whole tiles
+            rows = getattr(self.conf, "use_pallas", False) and packed_rows(
+                H, Dh, geom.model_shards)
+            shape = (geom.slots, geom.capacity,
+                     *((rows, 128) if rows else (H, Dh)))
         leaf = CacheLeaf(shape, geom.dtype, 2)
         return note_cache_entry(geom, "kv", {"k": leaf, "v": leaf})
+
+    @staticmethod
+    def _as_stored(t, leaf):
+        """A sequence's K or V [b, t, H, Dh] in the leaf's dtype and in the
+        shape of its rows (packed: `[b, t, H Dh / 128, 128]`, a plain
+        reshape)."""
+        return t.astype(leaf.dtype).reshape(*t.shape[:2], *leaf.shape[2:])
 
     def decode_prefill(self, params, state, x, entry, ctx):
         q, k, v = self.project_qkv(params, x)                 # [1, L, H, Dh]
@@ -422,9 +437,9 @@ class SelfAttentionLayerModule(BaseLayerModule):
             at = (ctx.slot, z, z, z)
             return y, {
                 "k": lax.dynamic_update_slice(
-                    entry["k"], k.astype(entry["k"].dtype), at),
+                    entry["k"], self._as_stored(k, entry["k"]), at),
                 "v": lax.dynamic_update_slice(
-                    entry["v"], v.astype(entry["v"].dtype), at)}
+                    entry["v"], self._as_stored(v, entry["v"]), at)}
 
     def decode_step(self, params, state, x, entry, ctx):
         from ...kernels import flash_decode_append, flash_decode_paged
@@ -456,11 +471,13 @@ class SelfAttentionLayerModule(BaseLayerModule):
         z = jnp.zeros((), slot.dtype)
         at = (slot, jnp.asarray(ctx.start, slot.dtype), z, z)
         nk = lax.dynamic_update_slice(entry["k"],
-                                      k.astype(entry["k"].dtype), at)
+                                      self._as_stored(k, entry["k"]), at)
         nv = lax.dynamic_update_slice(entry["v"],
-                                      v.astype(entry["v"].dtype), at)
-        krow = lax.dynamic_index_in_dim(nk, slot, 0, keepdims=True)
-        vrow = lax.dynamic_index_in_dim(nv, slot, 0, keepdims=True)
+                                      self._as_stored(v, entry["v"]), at)
+        # the one slot's row, as heads (a packed row unpacked)
+        krow, vrow = (
+            lax.dynamic_index_in_dim(n, slot, 0, keepdims=True).reshape(
+                1, n.shape[1], *k.shape[2:]) for n in (nk, nv))
         out = _verify_attend(q, krow, vrow, ctx.start)
         return self.finish(params, out.astype(x.dtype), None, x), \
             {"k": nk, "v": nv}

@@ -302,6 +302,34 @@ def test_kv_live_gauge_and_key_block_record():
         C=cap, H=2, D=16, itemsize=4) == want
 
 
+def test_key_block_record_reads_256_on_a_packed_cache():
+    """`opt350m`'s heads, 16 of 64 in float32, at its capacity of 1024: the
+    engine declares the packed leaf, the step's layer takes the row-major
+    kernel, and `flash_decode_block{C=1024,H=16,D=64,itemsize=4}` — labelled
+    by the heads, not by the rows they pack into — reads that kernel's 256
+    positions where the positions-minor kernel's block is 512; greedy tokens
+    are the full forward's, and nothing is counted as a decode fallback."""
+    from deeplearning4j_tpu.kernels.flash_attention import _rows_block
+    from deeplearning4j_tpu.telemetry.registry import get_registry
+    net = transformer_lm(vocab_size=V, d_model=1024, n_layers=1, n_heads=16,
+                         ffn_mult=1, seed=4, use_pallas=True).init()
+    eng = DecodeEngine(net, slots=2, max_len=1024)
+    cache = eng.init_cache()
+    assert cache["layers"]["b0_attn"]["k"].shape == (2, 1024, 8, 128)
+    fallbacks = get_registry().counter("pallas_fallback_total", "")
+    decode = lambda: sum(v for labels, v in fallbacks.series()
+                         if labels["kernel"] in ("flash_decode", "kv_append"))
+    before = decode()
+    prompt, n = [3, 1, 4, 1, 5], 4
+    _, got, _ = _engine_greedy(eng, cache, 1, prompt, n)
+    assert got == _naive_greedy(net, prompt, n)[0]
+    assert decode() == before
+    assert _decode_block(1024, 16, 64, 4, 1024, interpret=False) == 512
+    assert _rows_block(1024, 8, 128, 4, 1024, interpret=False) == 256
+    assert get_registry().get("flash_decode_block").get(
+        C=1024, H=16, D=64, itemsize=4) == 256
+
+
 # ------------------------------------------------- one step kept in flight
 def _drive(sched, futures, passes=200):
     """Turn the loop by hand until every future is answered and nothing is

@@ -309,3 +309,66 @@ def test_the_engine_names_no_layer_class():
     for gone in ("_POSITIONWISE", "_check_layer", "_walk_step",
                  "_walk_prefill", "_walk_verify", "'h' in entry"):
         assert gone not in src, gone
+
+
+# -- the K/V leaf is declared from what the engine observes (PR 44) ----------
+def _packing_net(use_pallas, seed=7):
+    """One causal attention layer with `opt350m`'s heads (16 of 64: 8 rows of
+    128 lanes a position) on an 8-wide stream."""
+    from deeplearning4j_tpu.nn.conf.layers import SelfAttentionLayer
+    return _net(SelfAttentionLayer(n_out=F, n_heads=16, head_dim=64,
+                                   causal=True, use_pallas=use_pallas,
+                                   activation="identity"), seed=seed)
+
+
+def _through_every_leg(eng):
+    """Tokens and probability rows of prefill, three steps, a verify window
+    over a rolled-back slot, and a step after it."""
+    rows, toks = [], []
+    cache, nid, probs = eng.prefill(eng.init_cache(), 1, [3, 1, 4, 1, 5, 9])
+    toks.append(nid), rows.append(eng.read_probs(probs))
+    ids = np.zeros((2,), np.int32)
+    for _ in range(3):
+        ids[1] = toks[-1]
+        cache, nxt, probs = eng.step(cache, ids)
+        toks.append(int(nxt[1])), rows.append(eng.read_probs(probs[1]))
+    cache = eng.set_length(cache, 1, 7)         # two tokens rolled back
+    cache, window = eng.verify(cache, 1, [2, 7, 1], 7)
+    rows.extend(window)
+    cache = eng.set_length(cache, 1, 9)         # two of the window accepted
+    ids[1] = 1
+    cache, nxt, probs = eng.step(cache, ids)
+    toks.append(int(nxt[1])), rows.append(eng.read_probs(probs[1]))
+    return toks, np.stack(rows)
+
+
+@pytest.mark.parametrize("how,leaf", [
+    ({}, (2, 32, 8, 128)),                              # packed
+    ({"mesh": 4}, (2, 32, 16, 64)),                     # 4 heads a shard
+    ({"paged": True, "block_size": 8}, (9, 8, 16, 64)),  # the pool's shape
+    ({"use_pallas": False}, (2, 32, 16, 64)),           # no kernel reads it
+], ids=["packed", "mesh_1x4", "paged", "no_kernel"])
+def test_the_kv_leaf_packs_where_one_shards_heads_fill_whole_tiles(how, leaf):
+    """16 heads of 64 are 8 rows of 128 lanes a position: the slab leaf is
+    declared `[slots, capacity, 8, 128]` where the step's kernel reads it and
+    ONE shard holds whole (8, 128) tiles — not under a 1 x 4 mesh (2 rows a
+    shard), not paged, not without the kernel. Whatever the leaf, the engine
+    gives what the plain (`use_pallas=False`) engine gives, token for token
+    and row for row, through prefill, step, rollback and `verify`."""
+    how = dict(how)
+    net = _packing_net(how.pop("use_pallas", True))
+    if "mesh" in how:
+        net = MeshContext({"n_data": 1, "n_model": how.pop("mesh")},
+                          devices=jax.devices()[:4]).wrap(net)
+    eng = DecodeEngine(net, slots=2, max_len=32, **how)
+    assert eng._entries["1"]["k"].shape == eng._entries["1"]["v"].shape == leaf
+    assert eng.init_cache()["layers"]["1"]["k"].shape == leaf
+    want = DecodeEngine(_packing_net(False), slots=2, max_len=32)
+    if eng.paged:           # no verify on the pool: the tokens of a request
+        assert eng.generate([3, 1, 4, 1, 5, 9], 8) == \
+            want.generate([3, 1, 4, 1, 5, 9], 8)
+        return
+    toks, rows = _through_every_leg(eng)
+    want_toks, want_rows = _through_every_leg(want)
+    assert toks == want_toks
+    np.testing.assert_allclose(rows, want_rows, rtol=1e-4, atol=1e-6)

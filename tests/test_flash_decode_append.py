@@ -8,8 +8,11 @@ block's arithmetic is the same. Key blocks of 256 positions in a capacity of
 falls in the first and in the last block of a slot, on lane 0 and lane 127 of
 a tile, in either tile of a block, at C - 1 (the engine's clamp), with short
 and full slots in one call. Shapes that do not tile take the two calls and
-count in `pallas_fallback_total{kernel="flash_decode"}`. The compiled kernel
-— one launch, in place — is in tests/test_tpu_compile.py."""
+count in `pallas_fallback_total{kernel="flash_decode"}`. A PACKED cache
+(`packed_rows`: heads of 64 two to a 128-lane row, since PR 44) takes the
+row-major kernel: the slabs bit for bit after unpacking, the rows to float32
+rounding. The compiled kernel — one launch, in place — is in
+tests/test_tpu_compile.py."""
 import importlib
 
 import numpy as np
@@ -122,6 +125,7 @@ def test_fused_call_is_one_kernel_named_flash_decode():
     ((2, 1000, 2, 64), False, None),  # a capacity 128 does not divide
     ((2, 64, 2, 16), False, None),    # capacity under one lane tile
     ((2, 32, 2, 192), True, ["flash_decode"]),  # head_dim no multiple of 128
+    ((2, 1000, 16, 64), False, None),   # the same capacity, the cache PACKED
 ], ids=str)
 def test_shapes_that_do_not_tile_take_the_two_calls_and_count(shape,
                                                               interpret,
@@ -134,6 +138,8 @@ def test_shapes_that_do_not_tile_take_the_two_calls_and_count(shape,
     from deeplearning4j_tpu.telemetry.registry import get_registry
     S, C_, H, D = shape
     q, k, v, k_new, v_new = operands(jnp.float32, H, seed=1, shape=shape)
+    if fa.packed_rows(H, D):    # unpacked for the two calls, packed again
+        k, v = (x.reshape(S, C_, -1, 128) for x in (k, v))
     pos = jnp.asarray([C_ - 1, 3], jnp.int32)
     labels = dict(kernel="flash_decode", path="kv_append+flash_decode",
                   shape=f"C={C_},D={D},interpret={interpret}")
@@ -156,3 +162,106 @@ def test_shapes_that_do_not_tile_take_the_two_calls_and_count(shape,
     # use_pallas=False is a choice, not a fallback: not counted
     call(use_pallas=False)(q, k, v, k_new, v_new, pos)
     assert counter().get(**labels) == before + 2
+
+
+# ---------------------------------------------------------- the packed cache
+PACKED_SHAPE = (SLOTS, CAPACITY, 16, 64)        # opt350m's heads: 8 rows of 128
+
+
+def packed(x):
+    """[S, T, H, D] -> [S, T, H * D // 128, 128]: the layer's packing."""
+    return x.reshape(*x.shape[:2], -1, 128)
+
+
+@pytest.mark.parametrize("positions", ["first_block_lane_0",
+                                       "clamped_at_capacity",
+                                       "short_and_full", "block_edges"])
+@pytest.mark.parametrize("heads", [16, 32], ids=["16_on_16", "32_on_16"])
+def test_packed_cache_matches_append_then_decode_on_the_unpacked_one(
+        heads, positions):
+    """16 K/V heads of 64 in float32, two to a row (`opt350m`'s layer, and
+    grouped 32 query heads on them): `flash_decode_append` knows the packed
+    leaf by its shape and runs the row-major kernel on it — the output to
+    float32 rounding of `kv_append` + `flash_decode` on the unpacked cache
+    (other products in another order), both slabs bit for bit after
+    unpacking, and nothing counted as a fallback."""
+    from deeplearning4j_tpu.telemetry.registry import get_registry
+    q, k, v, k_new, v_new = operands(jnp.float32, heads, seed=3,
+                                     shape=PACKED_SHAPE)
+    pos = jnp.asarray(POSITIONS[positions], jnp.int32)
+    assert fa.packed_rows(16, 64) == 8 and packed(k).shape[2:] == (8, 128)
+    assert fa._rows_block(C, 8, 128, 4, BLOCK, True) == BLOCK
+    fallbacks = get_registry().counter("pallas_fallback_total", "")
+    before = fallbacks.get()
+    want_k, want_v = fa.kv_append(k, v, k_new, v_new, pos)
+    want = fa.flash_decode(q, want_k, want_v, pos + 1, block_k=BLOCK)
+    got, got_k, got_v = fused(q, packed(k), packed(v), k_new, v_new, pos)
+    assert fallbacks.get() == before
+    assert got.shape == q.shape and got.dtype == q.dtype
+    assert got_k.shape == got_v.shape == packed(k).shape
+    np.testing.assert_array_equal(bits(got_k.reshape(k.shape)), bits(want_k))
+    np.testing.assert_array_equal(bits(got_v.reshape(v.shape)), bits(want_v))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-6,
+                               atol=2e-6)
+    # the two references on a packed cache: unpacked for them, packed again
+    ref, ref_k, ref_v = fused(q, packed(k), packed(v), k_new, v_new, pos,
+                              use_pallas=False)
+    np.testing.assert_array_equal(bits(ref_k), bits(got_k))
+    np.testing.assert_array_equal(bits(ref_v), bits(got_v))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=2e-6,
+                               atol=2e-6)
+
+
+def test_packed_call_touches_nothing_but_the_appended_position():
+    q, k, v, k_new, v_new = operands(jnp.float32, 16, seed=4,
+                                     shape=PACKED_SHAPE)
+    at = np.asarray(POSITIONS["short_and_full"])
+    _, got_k, got_v = fused(q, packed(k), packed(v), k_new, v_new,
+                            jnp.asarray(at, jnp.int32))
+    untouched = np.ones(k.shape, bool)
+    untouched[np.arange(SLOTS), at] = False
+    for got, old, new in ((got_k, k, k_new), (got_v, v, v_new)):
+        got = bits(got.reshape(old.shape))
+        np.testing.assert_array_equal(got[np.arange(SLOTS), at],
+                                      bits(new)[:, 0])
+        np.testing.assert_array_equal(got[untouched], bits(old)[untouched])
+
+
+def test_packed_call_is_one_kernel_named_flash_decode():
+    q, k, v, k_new, v_new = operands(jnp.float32, 16, shape=PACKED_SHAPE)
+    pos = jnp.zeros((SLOTS,), jnp.int32)
+    jaxpr = str(jax.make_jaxpr(fa.flash_decode_append)(
+        q, packed(k), packed(v), k_new, v_new, pos))
+    assert jaxpr.count("pallas_call") == 1
+    assert "flash_decode" in jaxpr and "kv_append" not in jaxpr
+
+
+@pytest.mark.parametrize("H,D,shards,rows", [
+    (16, 64, 1, 8), (32, 64, 2, 16), (32, 32, 1, 8), (64, 16, 1, 8),
+    (8, 64, 1, None),       # granite4_h_micro: 4 rows, half a tile
+    (16, 64, 4, None),      # opt350m on a 1 x 4 mesh: 2 rows a shard
+    (16, 64, 2, None),      # ... and on two model shards: 4
+    (16, 64, 3, 8),         # an axis that does not divide the heads: whole
+    (8, 128, 1, None),      # a row already
+    (16, 96, 1, None),      # does not divide the lanes
+], ids=str)
+def test_packed_rows_asks_for_whole_tiles_in_the_shard(H, D, shards, rows):
+    assert fa.packed_rows(H, D, shards) == rows
+
+
+def test_rows_that_do_not_fill_a_tile_take_the_kernel_they_took():
+    """8 K/V heads of 64 (`granite4_h_micro`'s layer) are 4 rows a position:
+    not packed, the positions-minor kernel as before, its block the one it
+    chose before, nothing new counted."""
+    from deeplearning4j_tpu.telemetry.registry import get_registry
+    shape = (2, 512, 8, 64)
+    q, k, v, k_new, v_new = operands(jnp.bfloat16, 32, seed=5, shape=shape)
+    pos = jnp.asarray([511, 7], jnp.int32)
+    fallbacks = get_registry().counter("pallas_fallback_total", "")
+    before = fallbacks.get()
+    jaxpr = jax.make_jaxpr(fa.flash_decode_append)(q, k, v, k_new, v_new, pos)
+    assert fallbacks.get() == before
+    assert "transpose" in str(jaxpr)        # the [S, H, D, C] view of it
+    assert get_registry().get("flash_decode_block").get(
+        C=512, H=8, D=64, itemsize=2) == fa._decode_block(512, 32, 64, 2,
+                                                          1024, True)

@@ -387,14 +387,59 @@ def test_flash_decode_append_grouped_heads_compiles_in_place(on_chip):
     assert comp.memory_analysis().alias_size_in_bytes == 2 * S * C * H * D * 2
 
 
+@pytest.mark.parametrize("Hq", [16, 32], ids=["16_on_16", "32_on_16"])
+def test_flash_decode_append_on_a_packed_cache_is_one_kernel_in_place(
+        on_chip, Hq):
+    """`opt350m_batch_decode`'s layer since PR 44: the 16 heads of 64 packed
+    two to a row, the leaf declared [48, 1024, 8, 128] float32. THAT array
+    the TPU stores row-major, one (8, 128) tile a position (the unpacked
+    [48, 1024, 16, 64] lies positions-minor, `{1,3,2,0}`), so the row-major
+    kernel's [S, C * 8, 128] view of it is a bitcast and the step's layer is
+    ONE kernel named `flash_decode`, its two slab outputs aliased onto the
+    donated caches (operands 6 and 7): no copy, transpose or update of a
+    slab, no loop over the slots, no counted fallback, a 256-position block.
+    The products compile at full float32 precision."""
+    from deeplearning4j_tpu.telemetry.registry import get_registry
+    S, C, H, D = 48, 1024, 16, 64
+    rows = fa.packed_rows(H, D)
+    assert rows == 8 and fa._rows_block(C, rows, 128, 4, 1024, False) == 256
+    fallbacks = get_registry().counter("pallas_fallback_total", "")
+    before = fallbacks.get()
+    kv = on_chip((S, C, rows, 128), jnp.float32)
+    comp = compiled_step_layer(on_chip((S, 1, Hq, D), jnp.float32), kv,
+                               on_chip((S, 1, H, D), jnp.float32),
+                               on_chip((S,), jnp.int32), interpret=False)
+    assert fallbacks.get() == before
+    assert get_registry().get("flash_decode_block").get(
+        C=C, H=H, D=D, itemsize=4) == 256
+    text = comp.as_text()
+    stored = re.findall(r"f32\[48,1024,8,128\](\{[^}]*\}) parameter\(", text)
+    assert stored == ["{3,2,1,0:T(8,128)}"] * 2
+    assert len(re.findall(r"f32\[48,8192,128\]\S* bitcast\(", text)) == 2
+    assert text.count(KERNEL) == 1
+    assert len(re.findall(r"%flash_decode[.\d]* = ", text)) == 1
+    assert "%kv_append" not in text and "dynamic-update-slice" not in text
+    assert relayouts(text, S * C * H * D) == []
+    assert loops(text) == []
+    mem = comp.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 20
+    assert mem.alias_size_in_bytes == 2 * S * C * H * D * 4
+    assert re.search(r"output_to_operand_aliasing=\{\{1\}: \(6, \{\}\), "
+                     r"\{2\}: \(7, \{\}\)\}", text)
+
+
 def test_flash_decode_append_runs_per_shard_on_a_mesh(topo, chip_config):
     """`ServingServer(mesh=4)`: per shard, 4 of the 16 heads — the three
     outputs (the rows and the two slabs) head-sharded like the operands:
-    one kernel, in place, no collective."""
+    one kernel, in place, no collective. 4 heads of 64 are 2 rows of 128
+    lanes, no whole tile: on this mesh `opt350m`'s leaf stays unpacked
+    (`packed_rows`; the engine's side of it: tests/test_decode_contract.py)
+    and the program is the positions-minor kernel's, as before PR 44."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from deeplearning4j_tpu.parallel.sharding import DATA_AXIS, MODEL_AXIS
     mesh = Mesh(np.array(topo.devices).reshape(1, 4), (DATA_AXIS, MODEL_AXIS))
     S, C, H, D = 48, 1024, 16, 64
+    assert fa.packed_rows(H, D) == 8 and fa.packed_rows(H, D, 4) is None
     heads = NamedSharding(mesh, P(None, None, MODEL_AXIS, None))
     kv = jax.ShapeDtypeStruct((S, C, H, D), jnp.float32, sharding=heads)
     new = jax.ShapeDtypeStruct((S, 1, H, D), jnp.float32, sharding=heads)
@@ -715,6 +760,17 @@ def lm_engine(chip_config):
     return DecodeEngine(net, slots=8, max_len=256)
 
 
+@pytest.fixture(scope="module")
+def packed_lm_engine(chip_config):
+    """The same decoder with `opt350m`'s heads, 16 of 64 in float32: the one
+    shape of the two whose K/V leaves pack (`packed_rows`)."""
+    from deeplearning4j_tpu.decode.engine import DecodeEngine
+    from deeplearning4j_tpu.zoo.models import transformer_lm
+    net = transformer_lm(vocab_size=256, d_model=1024, n_layers=2, n_heads=16,
+                         ffn_mult=1, use_pallas=True).init()
+    return DecodeEngine(net, slots=8, max_len=256)
+
+
 def _prefill_text(eng, bucket, sharding):
     """The optimized HLO of `eng`'s prefill of `bucket` tokens into slot 0,
     compiled for `sharding`'s device. Its outputs are the cache, the first
@@ -731,21 +787,30 @@ def _prefill_text(eng, bucket, sharding):
     return lowered.compile().as_text()
 
 
-def test_decode_step_compiles_with_kernel(lm_engine, one_chip,
+@pytest.mark.parametrize("which,leaf", [
+    ("lm_engine", (8, 256, 4, 64)), ("packed_lm_engine", (8, 256, 8, 128))],
+    ids=["4_heads_of_64", "16_heads_of_64_packed"])
+def test_decode_step_compiles_with_kernel(request, which, leaf, one_chip,
                                           chip_config, monkeypatch):
+    """At a shape whose K/V leaves stay as they were (4 heads of 64: two
+    rows, the positions-minor kernel) and at one that packs (16 heads of 64:
+    the row-major kernel on a `[8, 256, 8, 128]` leaf)."""
     monkeypatch.setattr(fa, "_interpret_default", lambda: False)
-    eng = lm_engine
-    args = _abstract((eng.model.params, eng.model.states, eng.init_cache(),
+    eng = request.getfixturevalue(which)
+    layers = len(eng._entries)
+    assert all(e["k"].shape == leaf for e in eng._entries.values())
+    args = _abstract((eng.model.params, eng.model.states,
+                      jax.eval_shape(eng._cache_zeros),
                       np.zeros((eng.slots,), np.int32),
                       eng._greedy_step_ops), one_chip)
     text = eng._build_step().lower(*args, None).compile().as_text()
     # per layer ONE kernel: the decode kernel appends the step's token
-    assert text.count(KERNEL) == 4
-    assert len(re.findall(r"%flash_decode[.\d]* = ", text)) == 4
+    assert text.count(KERNEL) == layers
+    assert len(re.findall(r"%flash_decode[.\d]* = ", text)) == layers
     assert "%kv_append" not in text
     # and nothing in the step rewrites a K or V slab: the kernel reads the
     # donated cache where it lies and writes the token's tile in place
-    assert relayouts(text, eng.slots * eng.capacity * 256) == []
+    assert relayouts(text, int(np.prod(leaf))) == []
     # no loop over the slots is left (the append as XLA's per-slot update)
     assert loops(text) == []
     assert "dynamic-update-slice" not in text
