@@ -17,8 +17,10 @@ dt_bias), unbounded below (Kimi Linear's own; the bounded form above is
 (x W_ga) W_gb with W_in = [W_q | W_k | W_v | W_fa | W_ga] then [n_in, 3 H D
 + 2 r]; `beta_scale=2` lets beta reach 2 (a negative eigenvalue of I - beta
 k k^T). Nothing of `kda_chunked` or `kda_step` changes with them: every
-exponent is still a difference <= 0, and the triangular system's entries
-grow to 2 (tests/test_solar_hybrid.py holds both to the sequential rule).
+exponent is still a difference <= 0 — in `kda_chunked` each of the two
+factors about a sub-block's boundary is one —, and the triangular system's
+entries grow to 2 (tests/test_solar_hybrid.py holds both to the sequential
+rule).
 
 Mamba-2's state (nn/layers/mamba.py) decays by one scalar a head and is
 added to; this one decays by a value a channel and is CORRECTED: the rank-1
@@ -26,9 +28,9 @@ update reads S^T k of the decayed state, so neither `ssd_chunked` nor
 `ssm_step` expresses it. Three formulations of the same recurrence:
 
 - `forward` (training, output(), and the decode prefill): `kda_chunked`, in
-  chunks of `chunk_size`, in `jax.numpy`; autodiff gives the backward. A
-  masked position gets g = 0 and beta = 0: the state passes through it
-  unchanged.
+  chunks of `chunk_size` and, inside a chunk, sub-blocks of 16 positions,
+  in `jax.numpy`; autodiff gives the backward. A masked position gets g = 0
+  and beta = 0: the state passes through it unchanged.
 - `decode_step`: one token a slot against the slot's state, in place
   (kernels.kda_step); the convs are a 4-tap product with the slot's tail.
 - the sequential scan over positions is the reference's
@@ -58,7 +60,17 @@ from ..weights import init_weights
 from ..conf.inputs import InputType
 
 _HIGHEST = lax.Precision.HIGHEST
+_SUB = 16                # positions a sub-block of a chunk's scores
 _L2_EPS = 1e-6
+
+
+def _sub_block(Q):
+    """Positions a sub-block of a chunk of Q: `_SUB`, or half of it where
+    only that divides Q; a chunk no longer than `_SUB`, or one that neither
+    divides, is one block."""
+    if Q <= _SUB:
+        return Q
+    return next((B for B in (_SUB, _SUB // 2) if Q % B == 0), Q)
 
 
 def kda_chunked(q, k, v, g, beta, chunk):
@@ -78,7 +90,26 @@ def kda_chunked(q, k, v, g, beta, chunk):
 
     Every exponent is formed as a difference <= 0 BEFORE it is
     exponentiated: a chunk at the gate's bound sums to hundreds below zero,
-    where exp(G_i) / exp(G_j) is 0 / 0 in float32."""
+    where exp(G_i) / exp(G_j) is 0 / 0 in float32.
+
+    The two score matrices (the sums over d above) are formed by sub-blocks
+    of `_sub_block(Q)` positions. A block on the diagonal is the sum as
+    written, one exponent an (i, j, d). A block below it, rows i >= r and
+    columns j < r with r the first position of its block row, is a matrix
+    product about that boundary:
+
+        exp(G_i - G_j) = exp(G_i - G_r) exp(G_r - G_j)             j < r <= i
+        sum_d = (k_i * exp(G_i - G_r)) . (k_j * exp(G_r - G_j))
+
+    BOTH factors' exponents are differences <= 0, since g <= 0 and j < r <=
+    i: neither overflows, and one underflows only where the product does. A
+    block above the diagonal is zero and is never formed. The triangular
+    system is solved over the same sub-blocks, by forward substitution: a
+    block row's right-hand side less A's blocks left of the diagonal times
+    the rows of U already found, then that block's own unit-triangular
+    solve (XLA inverts a triangular block on the TPU at a cost that grows
+    faster than its size squared: four of 16 cost a sixth of one of 64,
+    PERF.md section 6, PR 45)."""
     b, T, H, D = q.shape
     Q = min(int(chunk), T)
     pad = -T % Q
@@ -88,25 +119,61 @@ def kda_chunked(q, k, v, g, beta, chunk):
         beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
     nc = (T + pad) // Q
     chunks = lambda a: jnp.moveaxis(a.reshape((b, nc, Q) + a.shape[2:]), 1, 0)
-    upto = jnp.tril(jnp.ones((Q, Q), bool))                  # j <= i
-    eye = jnp.eye(Q, dtype=q.dtype)
+    B = _sub_block(Q)
+    n = Q // B
+    blocks = lambda a: a.reshape((b, n, B) + a.shape[2:])
+    upto = jnp.tril(jnp.ones((B, B), bool))[:, :, None, None]    # j <= i
+
+    def scores(qc, kc, G):
+        """kk, qk [b, H, Q, Q]: sum_d k_i k_j exp(G_i - G_j) and the same
+        with q_i, at j <= i; zero above the diagonal."""
+        Gb, kb = blocks(G), blocks(kc)
+        rows = jnp.stack([kb, blocks(qc)])               # [2, b, n, B, H, D]
+        diff = Gb[:, :, :, None] - Gb[:, :, None, :]     # [b, n, i, j, H, D]
+        kE = kb[:, :, None] * jnp.exp(jnp.where(upto, diff, -jnp.inf))
+        on = jnp.moveaxis(jnp.sum(rows[:, :, :, :, None] * kE, axis=-1),
+                          -1, 2)                         # [2, b, H, n, i, j]
+        rows = rows * jnp.exp(Gb - Gb[:, :, :1])         # exp(G_i - G_r)
+        out = []
+        for a in range(n):
+            r = a * B
+            row = []
+            if a:
+                row.append(jnp.einsum(
+                    "sbihd,bjhd->sbhij", rows[:, :, a],
+                    kc[:, :r] * jnp.exp(G[:, r:r + 1] - G[:, :r]),
+                    precision=_HIGHEST))
+            row.append(on[:, :, :, a])
+            if r + B < Q:
+                row.append(jnp.zeros((2, b, H, B, Q - r - B), G.dtype))
+            out.append(jnp.concatenate(row, axis=-1))
+        return jnp.concatenate(out, axis=-2)
+
+    def solve(A, rhs):
+        """U of (I + A) U = rhs, A [b, H, Q, Q] read strictly below its
+        diagonal, rhs [b, H, Q, D]: forward substitution over the block
+        rows, a B x B unit-triangular solve each."""
+        U = []
+        for a in range(n):
+            at = slice(a * B, (a + 1) * B)
+            r = rhs[:, :, at]
+            if a:
+                r = r - jnp.einsum("bhij,bhjv->bhiv", A[:, :, at, :a * B],
+                                   jnp.concatenate(U, axis=2),
+                                   precision=_HIGHEST)
+            U.append(jax.scipy.linalg.solve_triangular(
+                A[:, :, at, at], r, lower=True, unit_diagonal=True))
+        return jnp.concatenate(U, axis=2)
 
     def one(S0, c):
         qc, kc, vc, gc, bc = c                               # [b, Q, H, ..]
         G = jnp.cumsum(gc, axis=1)
         from_start = jnp.exp(G)                              # [b, Q, H, D]
-        diff = G[:, :, None] - G[:, None, :]                 # [b, i, j, H, D]
-        E = jnp.exp(jnp.where(upto[None, :, :, None, None], diff, -jnp.inf))
-        kk = jnp.einsum("bihd,bjhd,bijhd->bhij", kc, kc, E,
-                        precision=_HIGHEST)
-        A = jnp.moveaxis(bc, 1, 2)[..., None] * kk * (1 - eye)
+        kk, qk = scores(qc, kc, G)
         rhs = bc[..., None] * (vc - jnp.einsum(
             "bihd,bhdv->bihv", kc * from_start, S0, precision=_HIGHEST))
-        U = jax.scipy.linalg.solve_triangular(
-            A + eye, jnp.moveaxis(rhs, 1, 2), lower=True,
-            unit_diagonal=True)                              # [b, H, Q, D]
-        qk = jnp.einsum("bihd,bjhd,bijhd->bhij", qc, kc, E,
-                        precision=_HIGHEST)
+        U = solve(jnp.moveaxis(bc, 1, 2)[..., None] * kk,
+                  jnp.moveaxis(rhs, 1, 2))                   # [b, H, Q, D]
         o = jnp.einsum("bihd,bhdv->bihv", qc * from_start, S0,
                        precision=_HIGHEST) \
             + jnp.einsum("bhij,bhjv->bihv", qk, U, precision=_HIGHEST)

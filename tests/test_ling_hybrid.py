@@ -57,35 +57,69 @@ def rule_inputs(T, H=2, D=8, seed=0, g_all=None):
     return tuple(jnp.asarray(a) for a in (q, k, v, g, rng.rand(T, H)))
 
 
+# (T, chunk): lengths on and off the chunk's boundary at chunk 8 (one block a
+# chunk: no sub-block is entered); then chunks that ARE cut into sub-blocks —
+# 64 and 32 into blocks of 16, 24 into blocks of 8 — whole, padded, several
+LENGTHS = [(T, 8) for T in (1, 7, 8, 9, 16, 23, 64)] \
+    + [(64, 64), (100, 64), (192, 64), (100, 32), (100, 24)]
+
+
 @pytest.mark.parametrize("g_all", [None, -5.0], ids=["drawn", "at_the_bound"])
-@pytest.mark.parametrize("T", [1, 7, 8, 9, 16, 23, 64])
-def test_chunked_kda_is_the_sequential_scan(T, g_all):
-    """Lengths on and off the chunk's (8) boundary; with every g at -5 a
-    chunk's decays sum to -40 and sixty-four positions to -320, where a
-    ratio of two exponentials would be 0 / 0 in float32."""
+@pytest.mark.parametrize("T,chunk", LENGTHS)
+def test_chunked_kda_is_the_sequential_scan(T, chunk, g_all):
+    """With every g at -5, the bounded gate's lower bound, a sub-block of 16
+    sums to -80, a chunk of 8 to -40 and one of 64 to -320, where a ratio
+    of two exponentials would be 0 / 0 in float32: inside a sub-block and
+    across a sub-block boundary every exponent is still a difference."""
     q, k, v, g, beta = rule_inputs(T, g_all=g_all)
     want, S = ref.kda_scan(q, k, v, g, beta, jnp.zeros((2, 8, 8)))
-    got, last = kda_chunked(*(a[None] for a in (q, k, v, g, beta)), 8)
+    got, last = kda_chunked(*(a[None] for a in (q, k, v, g, beta)), chunk)
     np.testing.assert_allclose(got[0], want, atol=RULE, rtol=0)
     np.testing.assert_allclose(last[0], S, atol=RULE, rtol=0)
     f32 = lambda a: a.astype(jnp.float32)[None]
-    got32, last32 = kda_chunked(*(f32(a) for a in (q, k, v, g, beta)), 8)
+    got32, last32 = kda_chunked(*(f32(a) for a in (q, k, v, g, beta)), chunk)
     assert np.isfinite(np.asarray(got32)).all()
+    assert np.isfinite(np.asarray(last32)).all()
     np.testing.assert_allclose(got32[0], want, atol=2e-5, rtol=0)
 
 
-def test_masked_positions_leave_the_state_alone():
+@pytest.mark.parametrize("T,real,chunk", [(16, 11, 8), (128, 70, 64),
+                                          (128, 64, 64), (96, 41, 32)])
+def test_masked_positions_leave_the_state_alone(T, real, chunk):
     """g = 0 and beta = 0 behind the last real token, whatever k and v hold
-    there: the state is the real tokens', bucket or not."""
-    q, k, v, g, beta = rule_inputs(16, seed=1)
-    real = 11
-    m = (jnp.arange(16) < real).astype(g.dtype)
+    there: the state is the real tokens', bucket or not — the tail inside a
+    sub-block, on a chunk's boundary, and over whole sub-blocks."""
+    q, k, v, g, beta = rule_inputs(T, seed=1)
+    m = (jnp.arange(T) < real).astype(g.dtype)
     _, want = ref.kda_scan(*(a[:real] for a in (q, k, v, g, beta)),
                            jnp.zeros((2, 8, 8)))
     _, last = kda_chunked(q[None], k[None], v[None],
                           (g * m[:, None, None])[None], (beta * m[:, None])[None],
-                          8)
+                          chunk)
     np.testing.assert_allclose(last[0], want, atol=RULE, rtol=0)
+
+
+@pytest.mark.parametrize("T,chunk", [(40, 32), (70, 64), (30, 24), (20, 8)])
+def test_chunked_kda_differentiates_to_the_sequential_scans_gradient(T, chunk):
+    """Training's backward is autodiff through the chunked form: the
+    gradient of a scalar of o and of the last state, in every input, is the
+    sequential rule's."""
+    inputs = rule_inputs(T, seed=3)
+    rng = np.random.RandomState(4)
+    wo, ws = jnp.asarray(rng.randn(T, 2, 8)), jnp.asarray(rng.randn(2, 8, 8))
+
+    def scalar(rule):
+        def f(*a):
+            o, last = rule(*a)
+            return jnp.sum(o * wo) + jnp.sum(last * ws)
+        return jax.grad(f, argnums=(0, 1, 2, 3, 4))(*inputs)
+
+    want = scalar(lambda *a: ref.kda_scan(*a, jnp.zeros((2, 8, 8))))
+    got = scalar(lambda *a: tuple(
+        r[0] for r in kda_chunked(*(x[None] for x in a), chunk)))
+    for w, g_ in zip(want, got):
+        assert float(jnp.abs(w).max()) > 1e-3
+        np.testing.assert_allclose(g_, w, atol=1e-8, rtol=0)
 
 
 @pytest.mark.parametrize("use_pallas", [False, True], ids=["jnp", "kernel"])

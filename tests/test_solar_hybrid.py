@@ -70,39 +70,78 @@ def rule_inputs(T, H=2, D=8, seed=0, steep=False):
     return tuple(jnp.asarray(a) for a in (q, k, v, g, 2.0 * rng.rand(T, H)))
 
 
+# (T, chunk): chunk 8 is one block a chunk; 64 and 32 are cut into sub-blocks
+# of 16 and 24 into sub-blocks of 8 — whole, padded, several chunks
+LENGTHS = [(T, 8) for T in (1, 7, 8, 9, 16, 23, 64)] \
+    + [(64, 64), (100, 64), (192, 64), (100, 32), (100, 24)]
+
+
 @pytest.mark.parametrize("steep", [False, True], ids=["drawn", "minus_30"])
-@pytest.mark.parametrize("T", [1, 7, 8, 9, 16, 23, 64])
-def test_chunked_kda_with_beta_to_2_and_unbounded_decay_is_the_scan(T, steep):
-    """Lengths on and off the chunk's (8) boundary. beta reaches 2, so I -
-    beta k k^T reflects and the system (I + A) U = rhs has entries up to 2;
-    channels at -30 a token sum to -240 a chunk and -1,920 over 64
-    positions, where a ratio of two exponentials would be 0 / 0."""
+@pytest.mark.parametrize("T,chunk", LENGTHS)
+def test_chunked_kda_with_beta_to_2_and_unbounded_decay_is_the_scan(T, chunk,
+                                                                    steep):
+    """beta reaches 2, so I - beta k k^T reflects and the system (I + A) U =
+    rhs has entries up to 2, in the sub-blocks' forward substitution too;
+    channels at -30 a token sum to -480 inside a sub-block of 16, -240 a
+    chunk of 8 and -1,920 over 64 positions, where a ratio of two
+    exponentials would be 0 / 0 — inside a sub-block and in both factors
+    about a sub-block's boundary."""
     q, k, v, g, beta = rule_inputs(T, steep=steep)
     assert float(beta.max()) > 1.0 or T < 3
     want, S = ref.kda_scan(q, k, v, g, beta, jnp.zeros((2, 8, 8)))
-    got, last = kda_chunked(*(a[None] for a in (q, k, v, g, beta)), 8)
+    got, last = kda_chunked(*(a[None] for a in (q, k, v, g, beta)), chunk)
     np.testing.assert_allclose(got[0], want, atol=RULE, rtol=0)
     np.testing.assert_allclose(last[0], S, atol=RULE, rtol=0)
     f32 = lambda a: a.astype(jnp.float32)[None]
-    got32, last32 = kda_chunked(*(f32(a) for a in (q, k, v, g, beta)), 8)
+    got32, last32 = kda_chunked(*(f32(a) for a in (q, k, v, g, beta)), chunk)
     assert np.isfinite(np.asarray(got32)).all()
     assert np.isfinite(np.asarray(last32)).all()
     np.testing.assert_allclose(got32[0], want, atol=5e-5, rtol=0)
 
 
-def test_masked_positions_leave_the_state_alone_on_a_bucket_boundary():
+@pytest.mark.parametrize("T,reals,chunk", [(16, (8, 11, 16), 8),
+                                           (128, (64, 70, 80, 128), 64),
+                                           (72, (24, 29, 48), 24)])
+def test_masked_positions_leave_the_state_alone_on_a_bucket_boundary(
+        T, reals, chunk):
     """g = 0 and beta = 0 behind the last real token: the state is the real
-    tokens', with the prompt ending on a chunk boundary (8), inside a chunk
-    (11) and filling the bucket (16)."""
-    q, k, v, g, beta = rule_inputs(16, seed=1, steep=True)
-    for real in (8, 11, 16):
-        m = (jnp.arange(16) < real).astype(g.dtype)
+    tokens', with the prompt ending on a chunk boundary, inside a chunk (and
+    there on a sub-block's boundary or inside one) and filling the bucket."""
+    q, k, v, g, beta = rule_inputs(T, seed=1, steep=True)
+    for real in reals:
+        m = (jnp.arange(T) < real).astype(g.dtype)
         _, want = ref.kda_scan(*(a[:real] for a in (q, k, v, g, beta)),
                                jnp.zeros((2, 8, 8)))
         _, last = kda_chunked(q[None], k[None], v[None],
                               (g * m[:, None, None])[None],
-                              (beta * m[:, None])[None], 8)
+                              (beta * m[:, None])[None], chunk)
         np.testing.assert_allclose(last[0], want, atol=RULE, rtol=0)
+
+
+@pytest.mark.parametrize("steep", [False, True], ids=["drawn", "minus_30"])
+@pytest.mark.parametrize("T,chunk", [(40, 32), (70, 64), (30, 24)])
+def test_chunked_kda_with_beta_to_2_differentiates_to_the_scans_gradient(
+        T, chunk, steep):
+    """Training's backward is autodiff through the sub-blocks — the
+    boundary's two factors, the -inf the diagonal blocks mask with, the
+    block rows' solves: the gradient of a scalar of o and of the last state,
+    in every input, is the sequential rule's and finite."""
+    inputs = rule_inputs(T, seed=3, steep=steep)
+    rng = np.random.RandomState(4)
+    wo, ws = jnp.asarray(rng.randn(T, 2, 8)), jnp.asarray(rng.randn(2, 8, 8))
+
+    def scalar(rule):
+        def f(*a):
+            o, last = rule(*a)
+            return jnp.sum(o * wo) + jnp.sum(last * ws)
+        return jax.grad(f, argnums=(0, 1, 2, 3, 4))(*inputs)
+
+    want = scalar(lambda *a: ref.kda_scan(*a, jnp.zeros((2, 8, 8))))
+    got = scalar(lambda *a: tuple(
+        r[0] for r in kda_chunked(*(x[None] for x in a), chunk)))
+    for w, g_ in zip(want, got):
+        assert np.isfinite(np.asarray(g_)).all()
+        np.testing.assert_allclose(g_, w, atol=1e-8, rtol=0)
 
 
 def kda_module(**over):

@@ -727,6 +727,26 @@ def test_mla_decode_and_latent_append_compile_on_the_padded_row(on_chip):
             on_chip((S, C, 576), jnp.bfloat16), on_chip((S,), jnp.int32))
 
 
+def test_kda_chunked_forms_no_chunk_square_of_exponents(on_chip):
+    """The prefill's delta rule at `solar_open2`'s size, [1, 1024, 64, 128]
+    float32 in chunks of 64: the scores are formed by sub-blocks of 16, so
+    no instruction of the program — in a fusion or out of one — has the [64,
+    64, 64, 128] shape of a whole chunk's exponents, and a scan body (the
+    cost analysis counts the loop's body once) exponentiates under 40 % of
+    the 34.6 M elements it did when it formed that square (10.8 M: the four
+    diagonal blocks 8.4 M, the boundaries' factors and the chunk's own)."""
+    from deeplearning4j_tpu.nn.layers.kda import kda_chunked
+    T, H, D, Q = 1024, 64, 128, 64
+    x = on_chip((1, T, H, D), jnp.float32)
+    comp = jax.jit(lambda q, k, v, g, beta: kda_chunked(
+        q, k, v, g, beta, Q)).lower(x, x, x, x,
+                                    on_chip((1, T, H), jnp.float32)).compile()
+    text = comp.as_text()
+    assert f"[{Q // 16},16,16,{H},{D}]" in text      # the diagonal blocks
+    assert not re.search(rf"\[(?:\d+,)*{Q},{Q},{H},{D}\]", text)
+    assert comp.cost_analysis()["transcendentals"] < 0.4 * 34.6e6
+
+
 def test_untileable_shape_has_no_compiled_plan():
     """Why chip_smoke.py asks for prompts of 128 tokens and more: compiled,
     the key block must be a multiple of 128, so a 64-token prefill bucket or
