@@ -252,7 +252,7 @@ def timed_fit_batch(net, ds):
 
 
 def lm_train_steps(make, ds, vocab, events):
-    """Two fit_batch steps at bench_transformer_lm's shape — both backward
+    """Two fit_batch steps of a transformer_lm at batch 16 x 512 — both backward
     kernels run inside the normal train step — and then the first step of a
     second, identical net: the same seam compiled again in this process,
     which nothing in memory can answer (a new jit of a new trace), so it is

@@ -467,9 +467,9 @@ class IngestHostWideningRule(Rule):
     rationale = (
         "A host-side astype(np.float32)/np.asarray(..., np.float32) in a "
         "prefetcher/pipeline worker loop quadruples the bytes every batch "
-        "drags across the host link — BENCH_r05 measured that link as THE "
-        "end-to-end wall (e2e_binding=host_link, chip fed at 7.7% of "
-        "compute). Ship narrow bytes (uint8/int codes) and let the compiled "
+        "drags across the host link, and the worker's cast and transfer "
+        "are what the train loop waits for when the input path falls "
+        "behind. Ship narrow bytes (uint8/int codes) and let the compiled "
         "step do the widening on-device (etl.device_transform.DeviceIngest "
         "/ network.set_ingest); a deliberate host-path remainder belongs in "
         "the baseline with a note.")
@@ -634,8 +634,8 @@ class JitMissingDonationRule(Rule):
     id = "GL010"
     name = "jit-missing-donation"
     rationale = (
-        "The headline train step sits at the HBM roofline "
-        "(BENCH_r05 roofline_util~1.0): without donate_argnums the XLA "
+        "A train step reads and writes its whole state every step: "
+        "without donate_argnums the XLA "
         "executable allocates FRESH output buffers for params and updater "
         "state every step — double the state bytes resident and an extra "
         "full copy of HBM traffic, i.e. milliseconds per step. Every "
@@ -937,7 +937,7 @@ class QuantSilentWideningRule(Rule):
         "leaves STAY narrow: an `astype(np.float32)` / `jnp.float32(...)` "
         "on moment or weight-quant leaves outside nn/quant.py or "
         "parallel/zero.py silently re-materializes the f32 bytes the diet "
-        "removed (HBM reads widen again at roofline_util~1.0) AND bypasses "
+        "removed (the step reads and writes them wide again) AND bypasses "
         "the codec's exact-round-trip contract — a hand-widened moment "
         "re-quantizes through a different path and the bitwise re-shard "
         "guarantees quietly rot. Decode through the codec (MomentCodec."

@@ -175,10 +175,8 @@ def load_cifar(train=True, num_examples=None):
     return x, ys_i.astype(np.int64), None
 
 
-def real32_gate_accuracy(epochs=10, seed=3, quantized_delta=False):
-    """The real-photo 32x32 accuracy gate, shared by bench.py
-    (`real32_test_acc`) and tests/test_real_cifar.py so the benched number
-    and the tested threshold can never train on diverged recipes: small
+def real32_gate_accuracy(epochs=10, seed=3):
+    """The real-photo 32x32 accuracy gate of tests/test_real_cifar.py: small
     convnet (zoo.cifar_convnet) + horizontal-flip augmentation on the
     committed cifar_real fixture, evaluated on the spatially-split held-out
     crops. Returns accuracy, or None when only synthetic data is found."""
@@ -201,23 +199,7 @@ def real32_gate_accuracy(epochs=10, seed=3, quantized_delta=False):
     net.fit(ListDataSetIterator(sets), epochs=epochs)
     xt, yt, _ = load_cifar(train=False)
     pred = np.argmax(np.asarray(net.output(xt)), axis=1)
-    acc = float((pred == yt).mean())
-    if not quantized_delta:
-        return acc
-    # int8 serving-weight parity on the same held-out crops (bench.py's
-    # `quantized_vs_f32_accuracy_delta` on the real-photo gate)
-    acc_q = None
-    try:
-        net.quantize_weights("int8")
-        pred_q = np.argmax(np.asarray(net.output(xt)), axis=1)
-        acc_q = float((pred_q == yt).mean())
-    except Exception as e:
-        # loud: a silent None here would also silence bench.py's
-        # real32_quantized_accuracy_delta regression guard
-        import sys
-        print(f"real32 int8 eval failed: {type(e).__name__}: {e}",
-              file=sys.stderr)
-    return acc, acc_q
+    return float((pred == yt).mean())
 
 
 class CifarDataSetIterator(_ArrayIterator):

@@ -47,8 +47,8 @@ from .sampling import filter_probs_np
 class SpeculativeEngine:
     """Draft+target pair decoding one request at a time (slot 0 of two
     single-slot engines). `k` is the proposal window; telemetry
-    (acceptance rate, per-round token yield) feeds the bench's
-    spec_acceptance_rate / spec_speedup_x numbers."""
+    (acceptance rate, per-round token yield) is in `stats()`, and the
+    `spec_acceptance_rate` gauge when a registry is given."""
 
     def __init__(self, draft_model, target_model, *, k=4, max_len=128,
                  compile_tracker=None, registry=None):

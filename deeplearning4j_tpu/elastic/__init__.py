@@ -15,7 +15,7 @@ Three coordinated pieces (ROADMAP item 4):
   up warm.
 - `tools/loadgen.py` — the open-loop arrival-process load generator that
   measures the scale claims (fixed offered rate, no coordinated omission,
-  latency SLO report consumable by bench.py).
+  latency SLO report).
 
 Every transition (replica lost, re-shard, scale-up, drain) is visible in
 /fleet/* and the structured logs with trace correlation, and gated through

@@ -1,10 +1,10 @@
 """Device-side ingest: lower a fitted TransformProcess + DataNormalizer into
 the jitted step, so the host ships narrow bytes and XLA does the widening.
 
-BENCH_r05 measured why this module exists: the ResNet-50 train step sits at
-the HBM roofline (`roofline_util≈1.0`) while end-to-end training feeds the
-chip at 7.7% of compute rate — the HOST LINK is the wall (`e2e_binding=
-host_link`), not the chip. The TPU-paper idiom (PAPERS.md: the Julia-to-TPU
+Why this module exists: a float32 image batch is four times the bytes of
+its uint8 source, and every one of them crosses the host link and is cast
+by a host thread the train loop may end up waiting for (the benchmark's
+`input_wait_ms_per_step`). The TPU-paper idiom (PAPERS.md: the Julia-to-TPU
 compiler moving whole programs into XLA, the cross-replica-sharding paper
 moving the update path) is to move work INTO the compiled program: transfer
 raw uint8/int records, and let cast/normalize/one-hot be the first fused ops
@@ -440,8 +440,8 @@ class DeviceIngest:
 
     # ---- accounting --------------------------------------------------------
     def bytes_per_row(self):
-        """Wire bytes per record (features + labels) — the number that
-        bench's `h2d_bytes_per_sample` makes visible per workload."""
+        """Wire bytes per record (features + labels): what a batch of
+        this ingest costs the host link."""
         if self.transform is None:
             return None
         n = len(self._feature_names) * self.wire_dtype.itemsize

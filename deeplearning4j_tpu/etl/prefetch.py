@@ -16,8 +16,8 @@ stages batch N+1's host->device DMA while the device computes batch N:
   does not divide the data axis fall back to an unsharded put (the trainer's
   wrap-padding then handles them).
 
-Ingest mode (BENCH_r05: `e2e_binding=host_link` — the link, not the chip,
-bounds end-to-end training):
+Ingest mode (fewer bytes over the host link, and no widening cast on the
+worker's thread):
 
 - `transfer_dtype=np.uint8` narrows the FEATURE arrays on the host before
   the DMA (4x fewer wire bytes than float32 for image pixels); pair it with

@@ -323,8 +323,8 @@ class MultiLayerNetwork(MultiStepTrainable):
         # tbptt also donates the LSTM carries (arg 8): out_carries aliases
         # the incoming h/c buffers instead of allocating 2*layers fresh
         # [B, H] arrays per window — the non-scanned sibling of the
-        # multi_tbptt carry donation, same HBM-bytes-are-milliseconds
-        # argument (BENCH_r05 roofline_util~1.0). The std step passes
+        # multi_tbptt carry donation: bytes the step need not allocate
+        # and copy are time it need not spend. The std step passes
         # carries=None (zero pytree leaves), so donating it there is a no-op.
         donate = (0, 1, 2, 8) if tbptt else (0, 1, 2)
         return jax.jit(train_step, donate_argnums=donate)
@@ -512,8 +512,8 @@ class MultiLayerNetwork(MultiStepTrainable):
 
                 # final carries ARE an output: the donated carry buffers can
                 # alias them, so donation sticks instead of warning "Some
-                # donated buffers were not usable" (at roofline_util≈1.0,
-                # HBM bytes saved are milliseconds saved — BENCH_r05)
+                # donated buffers were not usable" (and the scan allocates
+                # no second set of [B, H] carries per execution)
                 (params, opt_state, states, carries), scores = jax.lax.scan(
                     body, (params, opt_state, states, carries), stacked)
                 return params, opt_state, states, carries, scores

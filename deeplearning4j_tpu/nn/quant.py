@@ -1,7 +1,7 @@
 """Quantization codecs — the designated quant/dequant module (ROADMAP item 3).
 
-BENCH_r05 put the headline train step AT the HBM roofline
-(`roofline_binding=hbm`, `roofline_util≈1.0`): further speed means moving
+State a step reads and writes is device memory held and bytes moved every
+step, whatever bounds the step: this module is about holding and moving
 fewer bytes. ZeRO-1 (parallel/zero.py) already removed the *redundant*
 optimizer-state pool; this module removes precision from the two pools that
 remain — moment precision for training and weight precision for serving —
@@ -268,8 +268,8 @@ def quantize_model_weights(model, dtype="int8", parity_inputs=None,
     is quantized, and the quantized outputs must pass `gate` — a breach
     restores the f32 weights and raises QuantParityError, so the caller's
     deploy fails with the model unchanged. Without parity inputs the
-    quantization is applied ungated (callers measuring accuracy end-to-end,
-    e.g. bench.py's ucidigits/real32 deltas). Returns the parity report."""
+    quantization is applied ungated (callers measuring accuracy end-to-end
+    on a held-out set of their own). Returns the parity report."""
     gate = gate if gate is not None else QuantGate()
     if parity_inputs is None:
         model.quantize_weights(dtype)

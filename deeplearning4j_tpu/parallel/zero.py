@@ -4,9 +4,9 @@ PAPERS.md, "Automatic Cross-Replica Sharding of Weight Update in Data-Parallel
 Training" (arXiv 2004.13336), applied to this stack (ROADMAP item 4): in plain
 data-parallel SPMD every replica holds the FULL optimizer state
 (momentum/adam moments — for Adam, 2x the parameter bytes) and redundantly
-computes the identical full parameter update. BENCH_r05 puts the headline
-step at the HBM roofline (`roofline_binding=hbm`, `roofline_util≈1.0`), so
-those redundant state bytes are the largest unclaimed HBM pool we hold.
+computes the identical full parameter update. Those redundant state bytes
+are device memory every replica holds, and reads and writes every step, for
+nothing: the largest pool a data-parallel run can give back.
 
 The transform here:
   reduce-scatter(grads) -> per-shard optax update (1/N of the state resident
@@ -97,7 +97,7 @@ def moment_bytes(tree):
     """Per-device bytes of the >= 1-D optimizer-state leaves — the moment
     pool the bytes diet targets (flat shards, q8 codes AND their per-block
     scales); scalar schedule counts are excluded. Reported as the
-    `opt_moment_bytes_per_device` gauge/bench field."""
+    `opt_moment_bytes_per_device` gauge."""
     return int(sum(_leaf_device_bytes(leaf)
                    for leaf in jax.tree_util.tree_leaves(tree)
                    if getattr(leaf, "ndim", 0) >= 1))

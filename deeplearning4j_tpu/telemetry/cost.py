@@ -1,16 +1,16 @@
 """Live cost attribution: per-executable FLOPs / HBM bytes / roofline plane.
 
-bench.py has always been able to say what the headline step COSTS — it asks
-XLA directly (`Compiled.cost_analysis()` → flops + bytes accessed,
-`memory_analysis()` → temp/argument/output buffer bytes) — but only offline,
-in three hand-rolled places. The live system (serving batcher buckets, decode
-step/prefill/verify, mesh dispatch, training jit caches) could not say which
-executable is eating the bandwidth. This module closes that gap:
+XLA can say what an executable COSTS (`Compiled.cost_analysis()` → flops +
+bytes accessed, `memory_analysis()` → temp/argument/output buffer bytes).
+This module asks it for every executable of the live system (serving batcher
+buckets, decode step/prefill/verify, mesh dispatch, training jit caches), so
+a running deployment can say which of them is eating the bandwidth, in bytes
+per sample per deploy, and raise an alarm when that regresses:
 
 - `compiled_costs(compiled)` / `classify(...)` — ONE implementation of the
-  cost-dict extraction and the roofline arithmetic bench.py previously
-  hand-rolled (same legs, same binding rule: hbm leg vs the configured
-  nominal bandwidth, matmul leg vs the measured/configured MXU ceiling).
+  cost-dict extraction and the roofline arithmetic (two legs, one binding
+  rule: hbm leg vs the configured nominal bandwidth, matmul leg vs the
+  configured MXU ceiling).
 - `ExecutableCostRegistry` — hooks every compile site the stack already
   funnels through `CompileTracker`/`timed_first_call`. At compile time it
   re-lowers the jitted callable from `ShapeDtypeStruct` abstractions of the
@@ -32,7 +32,7 @@ executable is eating the bandwidth. This module closes that gap:
 - `install_donation_watch()` — donation failures observable at runtime: a
   chained `warnings.showwarning` hook counts XLA "donated buffers were not
   usable" warnings into `donation_warnings_total{site}` with a
-  trace-correlated structured log record, instead of bench-stderr scraping.
+  trace-correlated structured log record, instead of scraping stderr.
 - `capture_trace(steps)` — the bounded on-demand capture behind
   `GET /profile/trace?steps=N`: flips the in-process Tracer on, waits (hard
   iteration bound, never a jax.profiler session) for N fresh spans, restores
@@ -51,10 +51,10 @@ from .trace import get_tracer
 from ..util.time_source import monotonic_s
 
 # Published peaks of one chip, keyed by `jax.devices()[0].device_kind` — the
-# one table bench.py and the live plane both read. The matmul leg may be
-# overridden with a measured MXU ceiling (bench probes it); the HBM leg stays
-# nominal because cost_analysis byte counts are an upper bound (see bench.py's
-# roofline_note). A device that is not listed has no peaks: its rows carry no
+# one table the live plane reads. A registry may be given other ceilings
+# (`ExecutableCostRegistry(matmul_tflops_ceiling=, hbm_gbps_ceiling=)`);
+# cost_analysis byte counts are an upper bound, so an HBM leg is one too.
+# A device that is not listed has no peaks: its rows carry no
 # roofline legs, binding or util (None) — never another chip's numbers.
 DEVICE_PEAKS = {
     "TPU v5 lite": {
@@ -133,7 +133,7 @@ def _pallas_kernel_count(compiled):
 
 def classify(flops, hbm_bytes, tflops_ceiling=None, hbm_bps_ceiling=None,
              measured_ms=None):
-    """The roofline arithmetic bench.py's headline block uses, shared:
+    """The roofline arithmetic of every row of the cost plane:
     compute leg = flops / matmul ceiling, HBM leg = bytes / bandwidth
     ceiling; binding is whichever leg is longer; util (when a measured wall
     time is supplied) is the longer leg over the measured time — util ≈ 1.0
@@ -173,8 +173,8 @@ class ExecutableCostRegistry:
     def __init__(self, registry=None, matmul_tflops_ceiling=None,
                  hbm_gbps_ceiling=None, sample_every=16):
         self.registry = registry if registry is not None else get_registry()
-        # Ceilings arrive in the bench-report units (TFLOP/s, GB/s) and are
-        # held in base units (FLOP/s, bytes/s) like bench's internals; left
+        # Ceilings arrive in the units people quote (TFLOP/s, GB/s) and are
+        # held in base units (FLOP/s, bytes/s); left
         # out, they are this device's published peaks, or None when
         # DEVICE_PEAKS does not list it.
         peaks = {} if matmul_tflops_ceiling and hbm_gbps_ceiling \
@@ -265,9 +265,9 @@ class ExecutableCostRegistry:
 
     def capture_compiled(self, label, compiled, family=None, samples=1,
                          version=None, capture_ms=None):
-        """Record costs for an already-compiled executable (bench.py's AOT
-        path). Returns the stored row (also the live-vs-offline agreement
-        surface bench asserts against). `capture_ms` is what the shadow
+        """Record costs for an already-compiled executable (what `capture`
+        ends in, and where a caller that compiled ahead of time comes in).
+        Returns the stored row. `capture_ms` is what the shadow
         lower + compile cost — with the persistent compilation cache on
         (util/compile_cache.py) a re-trace and a cache read, not a second
         compile. `pallas_kernels` counts the Pallas custom calls in the
@@ -400,7 +400,7 @@ class ExecutableCostRegistry:
 
 
 # ---- process-default registry ----------------------------------------------
-# None until a stack opts in (bench, smoke tools, ServingServer): the
+# None until a stack opts in (smoke tools, ServingServer): the
 # training jit-cache seam (`timed_first_call`) consults this and pays a
 # single None-check per first call when nobody is attributing costs, so unit
 # tests that merely train never pay the AOT re-lower.
@@ -466,7 +466,7 @@ def install_donation_watch(registry=None, logger=None):
     runs (stderr visibility is kept). Returns an uninstall callable removing
     THIS subscriber (the chain itself stays; it is a no-op with no
     subscribers). Note: `warnings.catch_warnings` blocks that swap
-    showwarning (bench's recording net) bypass the chain while active."""
+    showwarning (a test's recording net) bypass the chain while active."""
     global _donation_installed
     reg = registry if registry is not None else get_registry()
     counter = reg.counter(

@@ -9,9 +9,9 @@ and every compile of the next one, provided the directory does not move: the
 path is part of what a later process has to find again.
 
 Entry points call `enable_compile_cache()` before their first compile
-(`chip_smoke.py`, `bench.py`, `tools/smoke_*.py`, `tools/loadgen.py`,
-`examples/*.py`). Importing the library does not turn the cache on, and
-neither do the tests.
+(`chip_smoke.py`, `benchmarks/run.py`, `tools/smoke_*.py`,
+`tools/loadgen.py`, `examples/*.py`). Importing the library does not turn
+the cache on, and neither do the tests.
 """
 from __future__ import annotations
 

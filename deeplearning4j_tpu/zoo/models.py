@@ -45,8 +45,8 @@ def lenet_mnist(seed=12345, updater=None):
 def cifar_convnet(seed=12345, num_classes=10, updater=None):
     """Small conv net for 32x32x3 CIFAR-format data (mirrors the reference's
     Cifar example scale: two conv/pool blocks + dense head). Gated on the
-    committed real-photo fixture (tests/fixtures/cifar_real) in bench.py as
-    `real32_test_acc`."""
+    committed real-photo fixture (tests/fixtures/cifar_real) by
+    tests/test_real_cifar.py."""
     conf = (NeuralNetConfiguration.builder()
             .seed(seed)
             .updater(updater or Adam(1e-3))

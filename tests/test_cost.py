@@ -1,7 +1,7 @@
 """Live cost attribution tests (telemetry/cost.py, ISSUE 19):
 
 - `compiled_costs` / `classify` are the ONE implementation of the cost
-  extraction + roofline arithmetic bench.py now shares.
+  extraction + roofline arithmetic.
 - `ExecutableCostRegistry.capture` attributes every executable family —
   serve (batcher buckets, with pow2-padding-aware per-sample
   normalization), decode (step/prefill), train (the `timed_first_call`
@@ -16,7 +16,7 @@
   rollback resolves it.
 - Donation failures are live metrics: a seeded unusable donation counts
   into `donation_warnings_total{site}`; the char-RNN TBPTT scan path
-  (BENCH_r05's `float32[64,256]x4` suspect) stays at ZERO.
+  (whose carries once could not be donated) stays at ZERO.
 """
 import json
 import threading
@@ -98,7 +98,7 @@ class StubCompiled:
 # ----------------------------------------------------- extraction helpers
 
 def test_compiled_costs_of_real_executable_nonzero_and_flat_cache():
-    """The AOT read bench.py + the live plane share: nonzero flops/bytes
+    """The cost plane's read of a compiled executable: nonzero flops/bytes
     from a real compiled matmul, and lowering does NOT grow the jitted
     fn's dispatch cache (the zero-added-recompiles invariant)."""
     fn = jax.jit(lambda a, b: a @ b)
@@ -114,7 +114,7 @@ def test_compiled_costs_of_real_executable_nonzero_and_flat_cache():
 
 
 def test_classify_matches_bench_roofline_arithmetic():
-    flops, nbytes = 5.71e12, 85.07e9                 # BENCH_r05 headline
+    flops, nbytes = 5.71e12, 85.07e9
     tf_ceiling, bw = 174.9e12, 820e9
     cls = classify(flops, nbytes, tflops_ceiling=tf_ceiling,
                    hbm_bps_ceiling=bw, measured_ms=103.13)
@@ -147,7 +147,7 @@ def test_capture_normalizes_per_sample_and_labels_gauges():
     assert reg.get("roofline_binding").get(executable="serve:b8") is None
     assert cost.to_dict()["ceilings"] == {"matmul_tflops_ceiling": None,
                                           "hbm_gbps_ceiling": None}
-    # with ceilings given (bench's measured ones) the row is classified
+    # with ceilings given the row is classified
     given = ExecutableCostRegistry(reg, matmul_tflops_ceiling=100.0,
                                    hbm_gbps_ceiling=800.0)
     row = given.capture_compiled("serve:b8", StubCompiled(800.0, 1600.0),
@@ -413,8 +413,8 @@ def test_donation_watch_counts_with_site_label():
 
 
 def test_char_rnn_tbptt_scan_has_zero_donation_warnings():
-    """Regression pin for BENCH_r05's float32[64,256]x4 warning: the
-    scanned TBPTT window path (the suspected carrier) compiles with every
+    """Regression pin for the warning the TBPTT carries once drew
+    (`float32[64,256] x4`): the scanned TBPTT window path compiles with every
     donation usable on this backend — the counter stays at ZERO through
     prepare/fit. If a carry change re-breaks donation, this counts it."""
     from deeplearning4j_tpu.zoo.models import char_rnn_lstm

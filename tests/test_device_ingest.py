@@ -12,7 +12,7 @@ wide path; zero steady-state recompiles), the pipeline's device_ingest
 mode, the serving registry's lowered per-version normalizer, and the
 donation regression (scanned multistep paths must not warn "Some donated
 buffers were not usable" — tools/smoke_ingest.py asserts the same on the
-bench-shaped paths).
+image-shaped paths).
 """
 import warnings
 
@@ -530,8 +530,8 @@ def test_smoke_ingest_tool():
 # -------------------------------------------------------------- donation
 
 def test_scanned_paths_donate_cleanly():
-    """The BENCH_r05 warning — 'Some donated buffers were not usable:
-    float32[64,256] x4' from the scanned TBPTT executable — must stay gone:
+    """The warning 'Some donated buffers were not usable:
+    float32[64,256] x4' from the scanned TBPTT executable must stay gone:
     the final carries are now scan outputs, so the donated carry buffers
     alias them."""
     from deeplearning4j_tpu.zoo.models import char_rnn_lstm

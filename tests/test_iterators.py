@@ -5,9 +5,9 @@ behind device compute (AsyncDataSetIterator.java:38-76: prefetch thread +
 bounded queue + device affinity). The testable form of that claim: with a
 producer that takes `t_link` per batch and a consumer that takes `t_compute`
 per batch, total wall for N batches must track
-startup + N*max(t_link, t_compute), NOT N*(t_link + t_compute). bench.py
-reports the same two legs measured on the real chip (e2e_link_ms /
-e2e_wall_ms_per_batch); the hard assertion lives here where timing is
+startup + N*max(t_link, t_compute), NOT N*(t_link + t_compute). On the
+chip the benchmark's train cell reads the same overlap as
+`input_wait_ms_per_step`; the hard assertion lives here where timing is
 controllable.
 """
 import time

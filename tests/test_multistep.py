@@ -241,7 +241,7 @@ def test_prepare_steps_joins_row_chunks_inside_the_plan(kind, monkeypatch):
 
 
 def test_prepare_steps_reusable_executable():
-    """prepare_steps + fit_prepared: the bench hot path — one prepared stack
+    """prepare_steps + fit_prepared: the train loop's hot path — one prepared stack
     can run repeatedly (inputs are NOT donated) and each run advances
     training by K steps."""
     net = _mk_net()
@@ -313,10 +313,9 @@ def test_lstm_tbptt_carry_donation_no_warnings_both_paths():
     (fixed in PR 6: final carries are scan outputs) and the per-window
     fit_batch path (carries are donate_argnums=8 of the tbptt train step).
     JAX computes donation aliasing platform-independently at lowering, so
-    this CPU test catches a donated-but-unusable carry buffer exactly like
-    the TPU run that put "Some donated buffers were not usable:
-    float32[64,256] x4" in BENCH_r05's tail; bench.py now also counts the
-    warning across every workload (donation_warnings)."""
+    this CPU test catches a donated-but-unusable carry buffer ("Some
+    donated buffers were not usable: float32[64,256] x4") exactly as a TPU
+    run would."""
     import warnings
     from deeplearning4j_tpu.zoo.models import char_rnn_lstm
 
@@ -348,14 +347,12 @@ def test_lstm_tbptt_carry_donation_no_warnings_both_paths():
 
 
 def test_char_rnn_bench_call_sequence_donation_clean():
-    """ISSUE-9 satellite: the EXACT call sequence bench.py's char-RNN
-    workload drives (`_scanned_fit_step_s`: an eligibility-probe
-    prepare_steps, then K- and 2K-deep plans each fit_prepared twice,
-    interleaved) must lower with zero "Some donated buffers were not
-    usable" warnings — the BENCH_r05 tail's float32[64,256]x4 came from
-    this path's carries before they became scan outputs. Donation aliasing
-    is computed platform-independently at lowering, so the CPU run guards
-    the TPU bench."""
+    """ISSUE-9 satellite: a timing loop's call sequence over the char-RNN
+    (an eligibility-probe prepare_steps, then K- and 2K-deep plans each
+    fit_prepared twice, interleaved) must lower with zero "Some donated
+    buffers were not usable" warnings — this path's carries drew one
+    before they became scan outputs. Donation aliasing is computed
+    platform-independently at lowering, so the CPU run guards the TPU."""
     import warnings
     from deeplearning4j_tpu.zoo.models import char_rnn_lstm
 
@@ -367,7 +364,7 @@ def test_char_rnn_bench_call_sequence_donation_clean():
     ds = DataSet(jnp.asarray(x), jnp.asarray(y))
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
-        plan = net.prepare_steps([ds] * 2)         # bench eligibility probe
+        plan = net.prepare_steps([ds] * 2)         # eligibility probe
         assert plan is not None and plan[0] == "tbptt"
         K = 3
         p1 = net.prepare_steps([ds] * K)
@@ -383,17 +380,15 @@ def test_char_rnn_bench_call_sequence_donation_clean():
 
 
 def test_bench_r05_exact_geometry_donation_clean():
-    """ISSUE-15 satellite: the BENCH_r05 tail's warning named EXACTLY
-    `float32[64,256] x4` — the char-RNN bench geometry (batch 64, hidden
-    256, 2 LSTM layers x (h, c) carries). The small-geometry tests above
-    guard the code path; this one pins the literal buffer shapes from the
-    bench record, so a donation regression reproduces the historical
-    warning VERBATIM and can never be dismissed as a different workload.
-    The hunt re-ran every [64,256]-shaped candidate (scanned TBPTT,
-    per-window TBPTT, generate, rnn_time_step) — all lower clean; the
-    original emitter was the pre-PR-6/7 TBPTT carries. bench.py's warning
-    net (donation_warnings + regressions entry) stays the run-time
-    backstop across every workload."""
+    """ISSUE-15 satellite: the warning once named EXACTLY
+    `float32[64,256] x4` — a char-RNN at batch 64, hidden 256, 2 LSTM
+    layers x (h, c) carries. The small-geometry tests above guard the code
+    path; this one pins the literal buffer shapes, so a donation
+    regression reproduces that warning VERBATIM and can never be dismissed
+    as a different workload. Every [64,256]-shaped candidate (scanned
+    TBPTT, per-window TBPTT, generate, rnn_time_step) lowers clean; the
+    original emitter was the TBPTT carries before they were donated as
+    scan outputs."""
     import warnings
     from deeplearning4j_tpu.zoo.models import char_rnn_lstm
 

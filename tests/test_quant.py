@@ -149,8 +149,7 @@ def test_q8_moment_bytes_at_least_3p5x_smaller_and_gauge_reports():
     the gauge carries the dtype attribution."""
     def conf():
         # two hidden-256 layers: weight leaves big enough that the q8
-        # codes' block*n_shards pad granule is noise, like the real models
-        # the bench measures (resnet50: 3.9x)
+        # codes' block*n_shards pad granule is noise, as in a real model
         return (NeuralNetConfiguration.builder()
                 .seed(1).updater(Adam(1e-3)).list()
                 .layer(DenseLayer(n_out=256, activation="relu"))

@@ -69,8 +69,7 @@ def test_iterator_one_hots_to_ten_classes():
 
 
 def test_convnet_gate_on_real_heldout():
-    """The SHARED gate recipe (datasets/fetchers/standard.py — the same
-    function bench.py publishes as real32_test_acc) must reach 82% held-out
+    """The gate recipe (datasets/fetchers/standard.py) must reach 82% held-out
     accuracy on the spatially-split real crops (measured 0.88-0.95 across
     seeds/platforms; the weak class is flag-vs-building — red stripes vs
     the red pagoda at 32 px)."""

@@ -178,8 +178,8 @@ def graded(attend):
 
 
 # [batch, time, heads, head_dim]: the transformer_lm train step of
-# chip_smoke.py / bench_transformer_lm, its kernel check, and the widest
-# shape the issue's author compiled
+# chip_smoke.py, its kernel check, and the widest shape the issue's author
+# compiled
 SHAPES = [(16, 512, 4, 64), (4, 4096, 8, 64), (2, 4096, 16, 128)]
 DTYPES = [jnp.bfloat16, jnp.float32]
 

@@ -18,7 +18,7 @@ In-flight threads are bounded (`max_inflight`, the GL012 spawn guard);
 arrivals past the bound are *counted* as `dropped_inflight` — dropped load
 is reported, never silently reshaped into a lower offered rate.
 
-Report (consumable by bench.py; all ratios over arrivals):
+Report (all ratios over arrivals):
 
     {"offered_rate", "achieved_rate", "duration_s", "arrivals", "ok",
      "shed", "errors_5xx", "transport_errors", "dropped_inflight",
@@ -107,7 +107,7 @@ def run_loadgen(url, body, path="/predict", rate=50.0, duration_s=2.0,
         threads.append(th)
     # the offered window ends when the schedule does; the join below only
     # DRAINS stragglers. Rating completions over schedule+drain would let
-    # one wedged request crater achieved_rate (the guarded bench metric)
+    # one wedged request crater achieved_rate
     # while the server sustained the offered rate the whole window — the
     # straggler's cost belongs in p99/mean, and drain_s reports the wait.
     schedule_s = max(monotonic_s() - start, float(duration_s), 1e-9)

@@ -196,8 +196,8 @@ def _paged_arc(n_requests):
 
 def _spec_arc():
     """Arc 3: greedy speculative parity with a trained-for-agreement
-    draft (cyclic corpus, bench_spec style, far fewer steps — the smoke
-    wants a nonzero acceptance rate, not a speedup claim)."""
+    draft (cyclic corpus, few steps — the smoke wants a nonzero
+    acceptance rate, not a speedup claim)."""
     import numpy as np
     import jax.numpy as jnp
     from deeplearning4j_tpu.datasets.dataset import DataSet

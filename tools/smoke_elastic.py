@@ -7,8 +7,11 @@ AutoscaleController with a declarative JSON policy (shed-ratio scale-up
 through the AlertEngine ratio machinery, queue-depth scale-down, cooldown
 flap damping). The script:
 
-1. offers an open-loop burst (tools/loadgen.py) that overflows the single
-   replica's admission queue — clients see 200s and honest 429
+1. offers an open-loop burst (tools/loadgen.py) to a pool whose every
+   replica is busy — its batcher inside a dispatch, its admission queue
+   full behind it, held there by the script until the burst is over, so
+   the overload is a construction and not a race with the host's speed:
+   every arrival overflows a queue and clients see honest 429
    backpressure, never a 5xx (the frontend forwards a pool-wide shed AS
    429);
 2. the controller's shed-ratio rule fires -> scale-up to 2, then (after
@@ -33,6 +36,7 @@ from __future__ import annotations
 import json
 import sys
 import tempfile
+import threading
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
@@ -49,6 +53,7 @@ POLICY = {
 
 
 def run(burst_rate=2000.0, burst_s=0.05, nin=6, seed=0, scan_dir=None):
+    import numpy as np
     from tools.loadgen import predict_body, run_loadgen
     from tools.smoke_telemetry import _tiny_net
     from deeplearning4j_tpu.elastic import (AutoscaleController,
@@ -56,6 +61,7 @@ def run(burst_rate=2000.0, burst_s=0.05, nin=6, seed=0, scan_dir=None):
                                             InProcessLauncher)
     from deeplearning4j_tpu.resilience import FaultPlan, FaultRule
     from deeplearning4j_tpu.serving import FleetFrontend
+    from deeplearning4j_tpu.serving.admission import Request
     from deeplearning4j_tpu.telemetry.fleet import FleetServer
     from deeplearning4j_tpu.util.model_serializer import ModelSerializer
     from deeplearning4j_tpu.util.time_source import (ManualClock,
@@ -73,20 +79,52 @@ def run(burst_rate=2000.0, burst_s=0.05, nin=6, seed=0, scan_dir=None):
     lock_sanitizer.reset()
     lock_sanitizer.install()
 
+    # an admission queue longer than the failover burst: a ramp's overload
+    # does not depend on the length (`occupy` fills whatever there is), and
+    # the failover burst cannot overflow a live replica however slowly it
+    # dispatches — a shed there, with the dead replica tried last, would
+    # reach the client as a 502
     launcher = InProcessLauncher(
         scan_dir=str(scan_dir), max_replicas=POLICY["max_replicas"],
-        server_opts=dict(max_batch_size=4, queue_capacity=2,
+        server_opts=dict(max_batch_size=4, queue_capacity=32,
                          alert_interval_s=0),
         deploy_event={"kind": "deploy", "version": "v1"})
     fe = None
     fleet = None
     body = predict_body(nin=nin)
+    rows = np.asarray(body["data"], np.float32)
     reports = []
 
-    def burst(tag, rate=None, duration=None):
-        rep = run_loadgen(fe.url, body, rate=rate or burst_rate,
-                          duration_s=duration or burst_s, seed=seed,
-                          timeout_s=60.0, max_inflight=64)
+    def occupy(server, release):
+        """`server` as a replica that is busy until `release`: one request
+        of the script's own is dispatched and the batcher thread held where
+        it completes that request's future (a done-callback runs on the
+        thread that completes), then the admission queue is filled to its
+        capacity behind it. Nothing drains until `release`, so whatever
+        arrives meanwhile is shed — on a fast host as on a slow one."""
+        parked = threading.Event()
+        first = Request(rows)
+        first.future.add_done_callback(
+            lambda _f: (parked.set(), release.wait(60.0)))
+        server.queue.offer(first)
+        assert parked.wait(60.0), "the replica dispatched nothing"
+        return [first.future] + [server.submit(rows)
+                                 for _ in range(server.queue.capacity)]
+
+    def burst(tag, rate=None, duration=None, busy=False):
+        release = threading.Event()
+        held = []
+        try:
+            if busy:
+                for r in fe.replicas:
+                    held += occupy(launcher.server(r.name), release)
+            rep = run_loadgen(fe.url, body, rate=rate or burst_rate,
+                              duration_s=duration or burst_s, seed=seed,
+                              timeout_s=60.0, max_inflight=64)
+        finally:
+            release.set()
+        for fut in held:                   # the pool is idle again
+            fut.result(timeout=60.0)
         rep["phase"] = tag
         reports.append(rep)
         return rep
@@ -110,12 +148,12 @@ def run(burst_rate=2000.0, burst_s=0.05, nin=6, seed=0, scan_dir=None):
         pool_sizes = [len(fe.replicas)]
         ctl.evaluate()                     # tick 1: counter baselines
         # ---- ramp: overload -> shed-ratio fires -> 1 -> 2 -> 3 ----------
-        burst("ramp1")
+        burst("ramp1", busy=True)
         clock.advance(1.0)
         r = ctl.evaluate()                 # tick 2: scale_up -> 2
         pool_sizes.append(len(fe.replicas))
         up1 = r["action"]
-        burst("ramp2")
+        burst("ramp2", busy=True)
         clock.advance(policy.cooldown_s + 1.0)
         r = ctl.evaluate()                 # tick 3: scale_up -> 3
         pool_sizes.append(len(fe.replicas))
@@ -126,6 +164,7 @@ def run(burst_rate=2000.0, burst_s=0.05, nin=6, seed=0, scan_dir=None):
             if ev["action"] == "kill":
                 launcher.kill(ev["target"])
         failover = burst("failover", rate=200.0, duration=0.05)
+        assert failover["arrivals"] <= launcher.server_opts["queue_capacity"]
         clock.advance(1.0)
         r = ctl.evaluate()                 # tick 4: reap the dead replica
         pool_sizes.append(len(fe.replicas))

@@ -9,16 +9,15 @@
 
   image leg: uint8 pixel batches + int class ids on the wire ->
   DeviceIngest(normalizer=min-max, one_hot_labels=N) -> fit — the
-  BENCH-shaped path (pixels widen and labels one-hot on device).
+  train cell's path (pixels widen and labels one-hot on device).
 
 Asserts (a) both models actually learn their synthetic rules, (b) steady
 state trains with ZERO recompiles after the first epoch (the compile
 accounting layer's jit_compiles_total stays flat — one executable covers
 ingest + train step), (c) NO XLA donation warning fires on the scanned
-multistep paths ("Some donated buffers were not usable", the BENCH_r05
-warning this PR fixed), (d) the h2d byte counter saw narrow bytes (uint8
-ids, packed features — not widened float32), and (e) device/host parity on
-a held-out batch.
+multistep paths ("Some donated buffers were not usable"), (d) the h2d byte
+counter saw narrow bytes (uint8 ids, packed features — not widened
+float32), and (e) device/host parity on a held-out batch.
 
 Usage (on the CPU; without JAX_PLATFORMS it runs on the default platform):
     JAX_PLATFORMS=cpu python tools/smoke_ingest.py [-n 384] [-e 6]
