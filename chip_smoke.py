@@ -3,6 +3,7 @@
 
     python3 chip_smoke.py            # one TPU chip: train + serve
     python3 chip_smoke.py --chips 4  # four chips: the sharded paths only
+    python3 chip_smoke.py --only kernels  # one phase: train, kernels, serve
 
 One process, default platform (no JAX_PLATFORMS, no jax_platforms set here),
 the entry points a user calls, at the full width of models the repo supports;
@@ -11,7 +12,10 @@ depth is never cut here because both models fit one 16 GB chip whole.
   train  ResNet-50 (1000 classes, 224x224, bf16 compute, Nesterovs), batch
          256, fed uint8 pixels + int32 ids through DevicePrefetcher and
          net.set_ingest: two scanned executions of 5 steps, one fit_batch.
-  serve  the flash kernels against attention_reference on the chip; two
+  serve  the flash kernels against attention_reference on the chip, and the
+         decode step's attention kernel on a cache in whole tiles (4 K/V
+         heads of 128: a ring of 1,024 and a slab of 6,144, 48 slots, 24
+         steps) against plain jax.numpy, slabs bit for bit; two
          fit_batch steps of the 256-wide transformer_lm at 16 x 512 tokens
          (both backward kernels inside the normal train step); then the same
          net through ModelSerializer -> ServingServer(scan_dir=...,
@@ -221,8 +225,146 @@ def kernel_phase(shape=(4, 4096, 8, 64), seed=0):
         bwd.append(float(np.max(np.abs(g - r))
                          / max(1.0, float(np.max(np.abs(r))))))
     require(max(bwd) <= BF16_BWD_TOL, f"flash backward off by {bwd}")
+    # a sliding window's forward (no backward kernel knows a window)
+    window = min(1024, shape[1] // 2)
+    windowed = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window))
+    require_kernels(
+        {"pallas_kernels": windowed.lower(q, k, v).compile().as_text().count(
+            "tpu_custom_call")}, "flash_attention(window=)")
+    win = float(np.max(np.abs(f32(windowed(q, k, v)) - f32(
+        attention_reference(q, k, v, causal=True, window=window)))))
+    require(win <= BF16_FWD_TOL, f"windowed flash forward off by {win}")
     note(phase="kernels", shape=list(shape), dtype="bfloat16",
-         fwd_max_abs_err=fwd, bwd_max_rel_err=bwd)
+         fwd_max_abs_err=fwd, bwd_max_rel_err=bwd, window=window,
+         windowed_fwd_max_abs_err=win)
+
+
+def decode_kernel_phase(slots=48, ring=1024, capacity=6144, heads=32,
+                        kv_heads=4, head_dim=128, steps=24, seed=0):
+    """The decode step's attention kernel on a cache declared in WHOLE TILES
+    (fewer K/V heads of 128 than a tile's 8 sublanes: a token's rows reach
+    the cache by a read-modify-write of the tile they share with another
+    position), compiled, against plain jax.numpy: a sliding window's RING
+    (`flash_decode_append(ring=True)`) and a full layer's slab, `steps`
+    consecutive steps with the slabs donated from one to the next, as the
+    engine runs them. The ring's slots start before, at and several turns
+    past the wrap. A slot's query heads aim, two each of a K/V head's group,
+    at the OLDEST position the window still holds, at the token's tile
+    NEIGHBOUR (position ^ 1), at the token itself, and nowhere: a ring one
+    position short, a neighbour's rows lost in the tile or a token that did
+    not enter moves an output by ~1, which the phase shows by planting the
+    first two in the reference. Both slabs afterwards bit for bit."""
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.kernels import flash_decode_append
+    from deeplearning4j_tpu.kernels.flash_attention import SUBLANES, tiled_rows
+
+    G, D, S = heads // kv_heads, head_dim, slots
+    scale = float(D) ** -0.5
+    rng = np.random.default_rng(seed)
+    f32 = lambda a: np.asarray(a.astype(jnp.float32))
+
+    def reference(q, k, v, kn, vn, pos, C, is_ring, short=False,
+                  neighbour_lost=False):
+        """(out, k, v) on the plain [S, C, H, D] view, float32 products."""
+        at = pos % C if is_ring else pos
+        s_ = jnp.arange(S)
+        k, v = k.at[s_, at].set(kn[:, 0]), v.at[s_, at].set(vn[:, 0])
+        live = jnp.minimum(pos + 1, C)
+        valid = jnp.arange(C)[None] < live[:, None]
+        if short:       # the oldest position of a full ring left out
+            oldest = jnp.where(live == C, (at + 1) % C, C)
+            valid &= jnp.arange(C)[None] != oldest[:, None]
+        ka, va = k, v
+        if neighbour_lost:
+            ka, va = (x.at[s_, at ^ 1].set(0) for x in (k, v))
+        qf = q[:, 0].astype(jnp.float32).reshape(S, kv_heads, G, D)
+        with jax.default_matmul_precision("highest"):
+            sc = jnp.einsum("shgd,schd->shgc", qf,
+                            ka.astype(jnp.float32)) * scale
+            sc = jnp.where(valid[:, None, None], sc, -jnp.inf)
+            out = jnp.einsum("shgc,schd->shgd", jax.nn.softmax(sc, axis=-1),
+                             va.astype(jnp.float32))
+        return out.reshape(S, 1, heads, D), k, v
+
+    def aimed(k, kn, pos, C, is_ring, noise):
+        """Queries [S, 1, heads, D]: of each K/V head's group two at the
+        oldest live key, two at the tile neighbour (the token itself where
+        that is not live), two at the token, the rest noise."""
+        at = pos % C if is_ring else pos
+        live = jnp.minimum(pos + 1, C)
+        s_ = jnp.arange(S)
+        oldest = jnp.where(is_ring & (live == C), (at + 1) % C, 0)
+        near = jnp.where((at ^ 1) < live, at ^ 1, at)
+        k = k.at[s_, at].set(kn[:, 0])
+        pick = lambda i: k[s_, i].astype(jnp.float32)         # [S, H, D]
+        want = jnp.stack([pick(oldest)] * 2 + [pick(near)] * 2
+                         + [kn[:, 0].astype(jnp.float32)] * 2
+                         + [noise[:, :, j] for j in range(G - 6)], axis=2)
+        return want.reshape(S, 1, heads, D).astype(jnp.bfloat16)
+
+    for is_ring, C, name in ((True, ring, "flash_decode_window"),
+                             (False, capacity, "flash_decode")):
+        tiles = tiled_rows(C, kv_heads, D)
+        require(tiles, f"{kv_heads} K/V heads of {D} are not half-tile rows")
+        leaf = (S, tiles, SUBLANES, D)
+        k, v = (jnp.asarray(rng.normal(size=(S, C, kv_heads, D)),
+                            jnp.bfloat16) for _ in range(2))
+        if is_ring:     # before, at and past the wrap, and many turns on
+            edge = [0, 1, C - steps - 1, C - 2, C - 1, C, C + 1, 2 * C - 1,
+                    2 * C, 5 * C - 3]
+            pos = np.concatenate([edge, rng.integers(0, 6 * C, S)])[:S]
+        else:
+            pos = np.concatenate([[0, 1, C - steps],
+                                  rng.integers(0, C - steps, S)])[:S]
+        pos = jnp.asarray(pos, jnp.int32)
+        step = jax.jit(lambda q, k, v, kn, vn, pos: flash_decode_append(
+            q, k, v, kn, vn, pos, ring=is_ring), donate_argnums=(1, 2))
+        tk, tv = k.reshape(leaf), v.reshape(leaf)   # the kernel's slabs
+        worst, short, lost, program = 0.0, np.inf, np.inf, None
+        for _ in range(steps):
+            kn, vn = (jnp.asarray(rng.normal(size=(S, 1, kv_heads, D)),
+                                  jnp.bfloat16) for _ in range(2))
+            noise = jnp.asarray(rng.normal(size=(S, kv_heads, G, D)),
+                                jnp.float32)
+            q = aimed(k, kn, pos, C, is_ring, noise)
+            if program is None:
+                program = step.lower(q, tk, tv, kn, vn, pos).compile()
+                require_kernels({"pallas_kernels": program.as_text().count(
+                    "tpu_custom_call")}, name)
+            out, tk, tv = step(q, tk, tv, kn, vn, pos)
+            want, k, v = reference(q, k, v, kn, vn, pos, C, is_ring)
+            require(bool(jnp.array_equal(tk.reshape(k.shape), k))
+                    and bool(jnp.array_equal(tv.reshape(v.shape), v)),
+                    f"{name}: a slab is not the reference's, bit for bit")
+            err = np.abs(f32(out) - f32(want))
+            require(np.all(np.isfinite(f32(out))), f"{name} is not finite")
+            worst = max(worst, float(err.max()))
+            # the faults the aimed heads are there to show, planted in the
+            # reference: each must move some output far past the tolerance
+            full = np.asarray(jnp.minimum(pos + 1, C) == C)
+            if is_ring and full.any():
+                off = reference(q, k, v, kn, vn, pos, C, True, short=True)[0]
+                short = min(short, float(np.abs(
+                    f32(off) - f32(want))[full].max(axis=(1, 2, 3)).min()))
+            off = reference(q, k, v, kn, vn, pos, C, is_ring,
+                            neighbour_lost=True)[0]
+            seen = np.asarray((pos % C if is_ring else pos) ^ 1
+                              < jnp.minimum(pos + 1, C))
+            lost = min(lost, float(np.abs(
+                f32(off) - f32(want))[seen].max(axis=(1, 2, 3)).min()))
+            pos = pos + 1
+        require(worst <= BF16_FWD_TOL, f"{name} off by {worst}")
+        require(lost > 10 * BF16_FWD_TOL and (not is_ring
+                                              or short > 10 * BF16_FWD_TOL),
+                f"{name}: a planted fault moves the output by only "
+                f"{min(lost, short)}")
+        note(phase="decode_kernels", kernel=name, leaf=list(leaf),
+             heads=[heads, kv_heads], steps=steps, out_max_abs_err=worst,
+             ring_one_short_moves_a_slot_by_at_least=(
+                 short if is_ring else None),
+             neighbour_lost_moves_a_slot_by_at_least=lost)
 
 
 # --------------------------------------------------------------------- serve
@@ -595,6 +737,8 @@ def main(argv=None):
                     help="4: run only the four-chip paths and what they are "
                          "compared with")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--only", choices=("train", "kernels", "serve"),
+                    help="one chip: that phase alone")
     args = ap.parse_args(argv)
 
     import jax
@@ -624,9 +768,13 @@ def main(argv=None):
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
         if args.chips == 1:
-            train_phase(seed=args.seed)
-            kernel_phase(seed=args.seed)
-            serve_phase(events, seed=args.seed)
+            if args.only in (None, "train"):
+                train_phase(seed=args.seed)
+            if args.only in (None, "kernels"):
+                kernel_phase(seed=args.seed)
+                decode_kernel_phase(seed=args.seed)
+            if args.only in (None, "serve"):
+                serve_phase(events, seed=args.seed)
         else:
             multichip_train(args.chips, seed=args.seed)
             multichip_serve(args.chips, seed=args.seed)
@@ -641,10 +789,11 @@ def main(argv=None):
             f"{fallbacks and fallbacks.series()}")
     errs = reg.get("cost_capture_errors_total").get()
     require(errs == 0, f"cost_capture_errors_total = {errs}")
+    first_calls = reg.get("jit_compile_ms_total")   # none: --only kernels
     note(compile_cache_dir=cache_dir, cache_hits=events.hits,
          cache_misses=events.misses,
          first_call_s_total=round(
-             reg.get("jit_compile_ms_total").get() / 1e3, 1),
+             first_calls.get() / 1e3 if first_calls else 0.0, 1),
          train_seams=seam_rows(cost))
     print(json.dumps({"ok": True, "device": device}), flush=True)
 

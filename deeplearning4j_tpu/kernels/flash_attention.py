@@ -69,6 +69,14 @@ a transposing copy. Narrower heads reach the same kernel PACKED
 // D heads side by side on a row, is stored row-major too — 16 heads of 64 in
 float32 are one (8, 128) tile a position — and a token is then two 4 KB
 copies where the positions-minor cache has it in one lane of 256 tiles.
+Fewer heads of 128 than a tile has sublanes (4: half a tile a position)
+reach it declared in WHOLE TILES (`tiled_rows`: [slots, capacity * H // 8, 8,
+128]); a copy to HBM starts and ends on a tile, so the kernel reads the
+token's tile, replaces its rows and writes the tile back. A sliding window's
+cache is a RING of the window's positions (`flash_decode_append(ring=True)`,
+`flash_decode_window` in a trace): position p at p % ring, every block read
+once the ring has filled. The training forward takes the window as the
+lower end of its causal mask and skips the key blocks wholly below it.
 
 Gives way to a pure-JAX path (see `flash_attention`) when shapes don't tile,
 so callers can use it unconditionally; each such call is counted in
@@ -197,8 +205,23 @@ def _causal_keep(qi, ki, q_off, k_off, block_q, block_k):
     return k_off + ki * block_k <= q_off + (qi + 1) * block_q - 1
 
 
+def _window_fold(s, qi, ki, block_q, block_k, window):
+    """The sliding window's lower end: query i sees keys j with i - window <
+    j (<= i: `_causal_fold`), `window` keys with its own."""
+    qpos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where(kpos <= qpos - window, NEG_INF, s)
+
+
+def _window_keep(qi, ki, block_q, block_k, window):
+    """Whether the key block's last position is inside the window of the
+    query block's first row: the blocks wholly below the window are skipped
+    as those above the diagonal are."""
+    return (ki + 1) * block_k - 1 > qi * block_q - window
+
+
 def _flash_kernel(*refs, scale, causal, block_q, block_k, nk, need_lse,
-                  has_mask, has_offs):
+                  has_mask, has_offs, window=None):
     from jax.experimental import pallas as pl
     it = iter(refs)
     q_ref, k_ref, v_ref = next(it), next(it), next(it)
@@ -226,6 +249,8 @@ def _flash_kernel(*refs, scale, causal, block_q, block_k, nk, need_lse,
                                 preferred_element_type=jnp.float32) * scale
         if causal:
             s = _causal_fold(s, qi, ki, q_off, k_off, block_q, block_k)
+        if window is not None:
+            s = _window_fold(s, qi, ki, block_q, block_k, window)
         if has_mask:
             s = _mask_fold(s, km_ref)
 
@@ -241,7 +266,10 @@ def _flash_kernel(*refs, scale, causal, block_q, block_k, nk, need_lse,
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    if causal:
+    if window is not None:      # causal, no offsets: `flash_attention`
+        pl.when(_causal_keep(qi, ki, 0, 0, block_q, block_k)
+                & _window_keep(qi, ki, block_q, block_k, window))(_accumulate)
+    elif causal:
         pl.when(_causal_keep(qi, ki, q_off, k_off, block_q, block_k))(
             _accumulate)
     else:
@@ -284,7 +312,7 @@ def _offs_smem_spec():
 
 
 def _flash_forward(q, k, v, km, offs, scale, causal, block_q, block_k,
-                   interpret, need_lse=False):
+                   interpret, need_lse=False, window=None):
     """Returns (out [B,Tq,H,D], lse [BH,Tq,LANES] f32 | None).
 
     The kernel's name in the jaxpr and in a device trace is `flash_fwd`; the
@@ -307,7 +335,7 @@ def _flash_forward(q, k, v, km, offs, scale, causal, block_q, block_k,
     kernel = functools.partial(_flash_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k, nk=nk,
                                need_lse=need_lse, has_mask=km is not None,
-                               has_offs=offs is not None)
+                               has_offs=offs is not None, window=window)
     o_spec = pl.BlockSpec((1, block_q, D), lambda b, qi, ki: (b, qi, 0))
     o_shape = jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype)
     lse_spec = pl.BlockSpec((1, block_q, LANES), lambda b, qi, ki: (b, qi, 0))
@@ -634,7 +662,7 @@ def _prep_mask(key_mask, B, Tk):
 
 
 def flash_attention(q, k, v, *, causal=False, scale=None, key_mask=None,
-                    block_q=256, block_k=1024, interpret=None):
+                    window=None, block_q=256, block_k=1024, interpret=None):
     """Pallas flash attention on [batch, time, heads, head_dim] tensors.
 
     Default blocks (256 query x 1024 key) were swept on a real v5e: they run
@@ -648,6 +676,16 @@ def flash_attention(q, k, v, *, causal=False, scale=None, key_mask=None,
     the score tiles of the forward and both backward kernels (packed/ragged
     batches keep the fast path).
 
+    window: with `causal`, query i sees the `window` keys i - window < j <=
+    i (its own among them). The key blocks wholly below a query block's
+    window are skipped like those above the diagonal (their products, not
+    their copies: on the chip, 4,096 positions x 32 heads of 128 under a
+    window of 1,024, the key block of 1,024 takes 2.69 ms where the plain
+    causal call takes 2.89, a block of 512 3.28 and one of 256 4.91 — PERF.md
+    section 6, PR 47 —, so a window keeps the default block). FORWARD ONLY: the two
+    backward kernels know no window, so a windowed call is not
+    differentiable (a layer trains through the pure-JAX path).
+
     Falls back to the pure-JAX blockwise scan (O(T_block) memory) when the
     sequence doesn't tile into the requested blocks but a sane key-block
     divisor exists, and to the materializing reference only as a last
@@ -658,6 +696,8 @@ def flash_attention(q, k, v, *, causal=False, scale=None, key_mask=None,
         scale = float(1.0 / (D ** 0.5))
     if interpret is None:
         interpret = _interpret_default()
+    if window is not None:
+        assert causal, "a window is the lower end of a causal mask"
     plan = _plan(Tq, Tk, D, block_q, block_k, interpret)
     if plan is None:
         # prefer the O(T_block)-memory blockwise scan over the materializing
@@ -672,10 +712,17 @@ def flash_attention(q, k, v, *, causal=False, scale=None, key_mask=None,
                        Tq=Tq, Tk=Tk, D=D, interpret=interpret)
         if blockwise:
             return blockwise_attention(q, k, v, block_size=blk, causal=causal,
-                                       scale=scale, key_mask=key_mask)
+                                       scale=scale, key_mask=key_mask,
+                                       window=window)
         return attention_reference(q, k, v, causal=causal, scale=scale,
-                                   key_mask=key_mask)
+                                   key_mask=key_mask, window=window)
     masks = () if key_mask is None else (_prep_mask(key_mask, B, Tk),)
+    if window is not None:
+        return _per_shard(
+            lambda q, k, v, km=None: _flash_forward(
+                q, k, v, km, None, scale, True, plan[0], plan[1], interpret,
+                window=int(window))[0],
+            (q, k, v) + masks, B, H)
     return _per_shard(
         lambda q, k, v, km=None: _flash(q, k, v, km, None, scale, causal,
                                         plan[0], plan[1], interpret),
@@ -714,18 +761,22 @@ def flash_attention_lse(q, k, v, *, causal=False, scale=None, key_mask=None,
                       interpret)
 
 
-def _decode_reference(q, k, v, lengths, scale):
+def _decode_reference(q, k, v, lengths, scale, window=None):
     """Masked single-query attention, materializing the [S, H, 1, C] score
     row — the fallback (and CPU-test) semantics flash_decode must match.
     A slot with lengths=0 degrades to the uniform average over the cache,
     same contract as the main kernel's fully-masked-row behavior; callers
-    never read those slots."""
+    never read those slots. With a `window` a slot sees its `window` newest
+    positions only."""
     S, C = k.shape[0], k.shape[1]
     G = q.shape[2] // k.shape[2]
     if G > 1:           # grouped heads: K/V head j serves query heads j*G..
         k, v = jnp.repeat(k, G, axis=2), jnp.repeat(v, G, axis=2)
-    valid = jax.lax.broadcasted_iota(jnp.int32, (S, C), 1) \
-        < jnp.asarray(lengths, jnp.int32)[:, None]
+    at = jax.lax.broadcasted_iota(jnp.int32, (S, C), 1)
+    lengths = jnp.asarray(lengths, jnp.int32)[:, None]
+    valid = at < lengths
+    if window is not None:
+        valid &= at >= lengths - window
     s = jnp.einsum("sqhd,schd->shqc", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
@@ -1271,9 +1322,12 @@ def _decode_append_call(q, k, v, k_new, v_new, lengths, scale, block_c,
 # past its length are not read, so a shorter block reads less; a tile is the
 # block's positions x all K/V heads, `_DECODE_TILE_BYTES` at most
 _ROWS_BLOCK_POSITIONS = 256
+# the rows of an (8, 128) tile: what the row-major kernel's copies start and
+# end on in HBM, bfloat16 (stored (8, 128)(2, 1)) and float32 alike
+SUBLANES = 8
 
 
-def _rows_block(C, H, D, itemsize, block_k, interpret):
+def _rows_block(C, H, D, itemsize, block_k, interpret, tiled=False):
     """Key-block length of the row-major decode kernel, or None => the
     cache is not one it reads (`flash_decode_append` then takes the other
     kernel or the two calls). H and D are a position's ROWS as the cache
@@ -1283,9 +1337,14 @@ def _rows_block(C, H, D, itemsize, block_k, interpret):
     position's [H, D] values in whole tiles, and — compiled — H has to fill
     a tile's 8 sublanes (packed or not: a [.., 8, 128] bfloat16 array lies
     in (8, 128)(2, 1) tiles), so that the [S, C * H, D] view the kernel
-    copies from is the buffer itself; the block is then a multiple of 128
-    positions. Interpret mode takes any H and any divisor of the capacity."""
-    if D % LANES or (not interpret and H % 8):
+    copies from is the buffer itself; or, `tiled`, the leaf is DECLARED in
+    whole tiles, [S, C * H // 8, 8, D] with H = 1, 2 or 4 (`tiled_rows`: 8
+    // H positions a tile, and a token is written by reading its tile,
+    replacing its rows and writing the tile back). The block is then a
+    multiple of 128 positions. Interpret mode takes any H and any divisor
+    of the capacity."""
+    if D % LANES or (not interpret and H % SUBLANES
+                     and not (tiled and SUBLANES % H == 0)):
         return None
     target = min(block_k, C, _ROWS_BLOCK_POSITIONS,
                  max(1, _DECODE_TILE_BYTES // (H * D * itemsize)))
@@ -1309,6 +1368,22 @@ def packed_rows(H, D, shards=1):
     return H * D // LANES
 
 
+def tiled_rows(C, H, D, shards=1):
+    """The leading rows `C * H // 8` of a cache of `C` positions of `H` K/V
+    heads of `D` declared in WHOLE TILES, `[S, C * H // 8, 8, D]` — or None
+    where it is not to be: D is not a multiple of the lanes, H fills a tile
+    already or does not divide it, the positions are not whole tiles, or a
+    model axis splits the heads (a tile then mixes the shards' rows). Fewer
+    than 8 heads of 128 are half a tile a position or less, and a `[S, C, 4,
+    128]` array is not the `[S, C * 4, 128]` buffer the row-major kernel
+    copies from; declared so, it is (row (c % 2) * 4 + h of tile c // 2 is
+    head h of position c: a plain reshape of `[B, T, H, D]`)."""
+    if D % LANES or H >= SUBLANES or SUBLANES % H or shards != 1 \
+            or C * H % SUBLANES:
+        return None
+    return C * H // SUBLANES
+
+
 def _rows_dot(small, rows_ref, b, dims):
     """`small [M, .] . rows_ref[b]` of the row-major decode kernel on the
     MXU, float32 out; `dims` the contraction as `lax.dot_general` takes it.
@@ -1325,8 +1400,8 @@ def _rows_dot(small, rows_ref, b, dims):
 
 def _decode_rows_kernel(len_ref, q_ref, kx_ref, vx_ref, kn_ref, vn_ref, k_hbm,
                         v_hbm, o_ref, ko_hbm, vo_hbm, k_buf, v_buf, sem, wsem,
-                        buf_ref, bias_ref, acc_ref, m_ref, l_ref, *, scale,
-                        block_c, slots, heads, group):
+                        buf_ref, bias_ref, acc_ref, m_ref, l_ref, *stage,
+                        scale, block_c, slots, heads, group, ring):
     """One slot of decode attention on a ROW-MAJOR cache (a position's
     values in rows of a multiple of 128 lanes), the step's token appended on
     the way. k_hbm / v_hbm are the whole caches viewed [S, C * H, D] — row
@@ -1367,28 +1442,66 @@ def _decode_rows_kernel(len_ref, q_ref, kx_ref, vx_ref, kn_ref, vn_ref, k_hbm,
     block it masks whole), every column at or past the token's position
     masked; the token itself is the softmax's first term, from `kx_ref` /
     `vx_ref` (the token's rows repeated to the query heads, [1, Hq, D]), on
-    the VPU in float32."""
+    the VPU in float32.
+
+    Fewer rows a position than a tile's 8 (`heads` 1, 2 or 4: `stage` is
+    there): a copy to HBM starts and ends on a tile, so the token's rows
+    reach the cache with their tile — the 8 rows that hold them are read
+    into `stage` (started first, under the blocks), the token's rows
+    (`kn_ref` / `vn_ref` repeated to 8 rows, [1, 8, D]) replace theirs
+    there, and the tile is written back; the write is waited for a slot
+    later (the last slot's at the end), so it rides under that slot's
+    blocks. The other positions of the tile are rewritten as read.
+
+    `ring`: the cache is a RING of C positions (a sliding window's): the
+    token at position length - 1 is written at (length - 1) % C and the slot
+    attends to its min(length, C) newest tokens — every block once the ring
+    has filled, each column but the token's own, whose place still holds
+    the position that has just left the window."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     si = pl.program_id(0)
     rows = block_c * heads
     pos = len_ref[si] - 1
     live = jnp.maximum((pos + block_c - 1) // block_c, 1)
+    at, full = pos, False
+    if ring:
+        C = k_hbm.shape[1] // heads
+        at, full = pos % C, pos >= C
+        live = jnp.where(full, C // block_c, live)
 
     def copies(slot, block, b):
-        at = pl.ds(pl.multiple_of(block * rows, rows), rows)
-        return [pltpu.make_async_copy(hbm.at[slot, at, :], vmem.at[b],
+        span = pl.ds(pl.multiple_of(block * rows, rows), rows)
+        return [pltpu.make_async_copy(hbm.at[slot, span, :], vmem.at[b],
                                       sem.at[i, b])
                 for i, (hbm, vmem) in enumerate(((k_hbm, k_buf),
                                                  (v_hbm, v_buf)))]
 
-    home = pl.ds(pl.multiple_of(pos * heads, heads), heads)
-    writes = [pltpu.make_async_copy(new.at[0], hbm.at[si, home, :],
-                                    wsem.at[i])
-              for i, (new, hbm) in enumerate(((kn_ref, ko_hbm),
-                                              (vn_ref, vo_hbm)))]
-    for write in writes:
-        write.start()
+    news, outs = (kn_ref, vn_ref), (ko_hbm, vo_hbm)
+    sub = bool(stage)
+    if sub:         # the token's rows with their tile, through `stage`
+        stage, rsem = stage
+        par = si % 2
+        first = at * heads // SUBLANES * SUBLANES
+        home = pl.ds(pl.multiple_of(first, SUBLANES), SUBLANES)
+        reads = [pltpu.make_async_copy(hbm.at[si, home, :], stage.at[par, i],
+                                       rsem.at[i])
+                 for i, hbm in enumerate((k_hbm, v_hbm))]
+        for read in reads:
+            read.start()
+
+        def writes(parity):
+            return [pltpu.make_async_copy(stage.at[parity, i],
+                                          hbm.at[si, home, :],
+                                          wsem.at[parity, i])
+                    for i, hbm in enumerate(outs)]
+    else:
+        home = pl.ds(pl.multiple_of(at * heads, heads), heads)
+        direct = [pltpu.make_async_copy(new.at[0], hbm.at[si, home, :],
+                                        wsem.at[i])
+                  for i, (new, hbm) in enumerate(zip(news, outs))]
+        for write in direct:
+            write.start()
 
     @pl.when(si == 0)
     def _first():
@@ -1414,8 +1527,11 @@ def _decode_rows_kernel(len_ref, q_ref, kx_ref, vx_ref, kn_ref, vn_ref, k_hbm,
         for copy in copies(si, j, b):
             copy.wait()
         s = _rows_dot(q, k_buf, b, ((1,), (1,))) * scale
-        cached = jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1) \
-            < (pos - j * block_c) * heads
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
+        own = (at - j * block_c) * heads    # the token's columns: from here
+        cached = col < own
+        if ring:    # and, the ring full, every column after them
+            cached |= full & (col >= own + heads)
         s = jnp.where(cached, s + bias_ref[...], NEG_INF)   # [Hq, rows]
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -1444,41 +1560,70 @@ def _decode_rows_kernel(len_ref, q_ref, kx_ref, vx_ref, kn_ref, vn_ref, k_hbm,
         out = jnp.where(lane // D == row % group // (group // pack), out, 0.0)
         out = sum(out[:, i * D:(i + 1) * D] for i in range(pack))
     o_ref[0] = out.astype(o_ref.dtype)
-    for write in writes:
-        write.wait()
+    if not sub:
+        for write in direct:
+            write.wait()
+        return
+    for read in reads:
+        read.wait()
+    row = jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, 1), 0)
+    mine = (row >= at * heads - first) & (row < at * heads - first + heads)
+    for i, new in enumerate(news):      # exact through float32
+        stage[par, i] = jnp.where(
+            mine, new[0].astype(jnp.float32),
+            stage[par, i].astype(jnp.float32)).astype(stage.dtype)
+    for write in writes(par):
+        write.start()
+
+    @pl.when(si > 0)
+    def _before():      # the slot before's, under this slot's blocks
+        for write in writes(1 - par):
+            write.wait()
+
+    @pl.when(si == slots - 1)
+    def _last():
+        for write in writes(par):
+            write.wait()
 
 
-@functools.partial(jax.jit, static_argnums=(6, 7, 8))
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9))
 def _decode_rows_call(q, k, v, k_new, v_new, lengths, scale, block_c,
-                      interpret):
+                      interpret, ring=False):
     """`_decode_append_call` for a row-major cache: q [S, 1, Hq, D], k/v [S,
     C, H, W] — W = D, or packed (`packed_rows`) H * W = K/V heads * D with W
-    = 128 —, k_new/v_new [S, 1, H, W], lengths [S] (the appended token
-    counted) -> (out [S, 1, Hq, D], k, v). The kernel's view of a cache is
-    [S, C * H, W]: for H = 8 the same tiles in the same order, so the
-    reshape is a bitcast (tests/test_tpu_compile.py holds it to that) and
-    the two slab outputs are aliased onto the caches. In a trace it is
-    `flash_decode`, as the kernel it stands in for."""
+    = 128 —, or, H of 1, 2 or 4 (`tiled_rows`), [S, C * H // 8, 8, W];
+    k_new/v_new [S, 1, H, W], lengths [S] (the appended token counted) ->
+    (out [S, 1, Hq, D], k, v). The kernel's view of a cache is [S, C * H,
+    W]: the same tiles in the same order, so the reshape is a bitcast
+    (tests/test_tpu_compile.py holds it to that) and the two slab outputs
+    are aliased onto the caches. `ring`: a sliding window's cache
+    (`_decode_rows_kernel`). In a trace it is `flash_decode`, as the kernel
+    it stands in for, or — a ring's — `flash_decode_window`."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     S, _, Hq, D = q.shape
-    C, H, W = k.shape[1:]
+    H, W = k_new.shape[2:]
+    total = k.shape[1] * k.shape[2]         # C * H rows a slot
     pack = W // D
     G = Hq // H                     # query heads a row
     rows = block_c * H
+    sub = H < SUBLANES              # a token's rows go with their tile
     row = pl.BlockSpec((1, Hq, W), lambda s, lens: (s, 0, 0))
-    new = pl.BlockSpec((1, H, W), lambda s, lens: (s, 0, 0))
+    new = pl.BlockSpec((1, SUBLANES if sub else H, W),
+                       lambda s, lens: (s, 0, 0))
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
-    slab = jax.ShapeDtypeStruct((S, C * H, W), k.dtype)
+    slab = jax.ShapeDtypeStruct((S, total, W), k.dtype)
     kn, vn = k_new.reshape(S, H, W), v_new.reshape(S, H, W)
     q = q.reshape(S, Hq, D).astype(k.dtype)
     if pack > 1:    # zero outside the lanes of the query head's K/V head
         own = (jnp.arange(Hq) % G // (G // pack))[:, None] == jnp.arange(pack)
         q = jnp.where(own[None, :, :, None], q[:, :, None, :],
                       jnp.zeros((), q.dtype)).reshape(S, Hq, W)
+    tile = (lambda x: jnp.tile(x, (1, SUBLANES // H, 1))) if sub \
+        else (lambda x: x)
     out, nk, nv = pl.pallas_call(
         functools.partial(_decode_rows_kernel, scale=scale, block_c=block_c,
-                          slots=S, heads=H, group=G),
+                          slots=S, heads=H, group=G, ring=ring),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(S,),
@@ -1489,13 +1634,16 @@ def _decode_rows_call(q, k, v, k_new, v_new, lengths, scale, block_c,
                 pltpu.VMEM((2, rows, W), k.dtype),           # K tiles
                 pltpu.VMEM((2, rows, W), k.dtype),           # V tiles
                 pltpu.SemaphoreType.DMA((2, 2)),             # (K | V, buffer)
-                pltpu.SemaphoreType.DMA((2,)),               # K | V written
+                # K | V written (through the stage: of either parity)
+                pltpu.SemaphoreType.DMA((2, 2) if sub else (2,)),
                 pltpu.SMEM((1,), jnp.int32),                 # next buffer
                 pltpu.VMEM((Hq, rows), jnp.float32),         # own-row bias
                 pltpu.VMEM((Hq, W), jnp.float32),            # acc
                 pltpu.VMEM((Hq, 1), jnp.float32),            # running max
                 pltpu.VMEM((Hq, 1), jnp.float32),            # running sum
-            ]),
+            ] + ([pltpu.VMEM((2, 2, SUBLANES, W), k.dtype),  # the token's
+                  pltpu.SemaphoreType.DMA((2,))]             # tile, read
+                 if sub else [])),
         out_shape=[jax.ShapeDtypeStruct((S, Hq, D), q.dtype), slab, slab],
         # operands count from the prefetched scalar: 6 and 7 are the slabs
         input_output_aliases={6: 1, 7: 2},
@@ -1503,27 +1651,37 @@ def _decode_rows_call(q, k, v, k_new, v_new, lengths, scale, block_c,
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_DECODE_APPEND_VMEM_BYTES),
         interpret=interpret,
-        name="flash_decode",
-    )(lengths, q, jnp.repeat(kn, G, axis=1), jnp.repeat(vn, G, axis=1), kn,
-      vn, k.reshape(S, C * H, W), v.reshape(S, C * H, W))
+        name="flash_decode_window" if ring else "flash_decode",
+    )(lengths, q, jnp.repeat(kn, G, axis=1), jnp.repeat(vn, G, axis=1),
+      tile(kn), tile(vn), k.reshape(S, total, W), v.reshape(S, total, W))
     return (out.reshape(S, 1, Hq, D), nk.reshape(k.shape),
             nv.reshape(v.shape))
 
 
 def flash_decode_append(q, k, v, k_new, v_new, pos, *, scale=None,
-                        use_pallas=True, block_k=1024, interpret=None):
+                        use_pallas=True, block_k=1024, interpret=None,
+                        ring=False):
     """A decode step's attention layer in ONE kernel: append the step's
     token to the cache and attend to the cache with it.
 
     q: [slots, 1, heads, head_dim]; k, v: [slots, capacity, kv_heads,
     head_dim] — the cache, in place when donated — or PACKED, [slots,
-    capacity, kv_heads * head_dim // 128, 128] (`packed_rows`: recognised by
-    its shape against the token's); k_new, v_new: [slots, 1, kv_heads,
-    head_dim] — the token, in the cache's dtype; pos: [slots] int32 — where
-    each slot appends, inside [0, capacity): the slot then holds pos + 1
-    tokens, so `flash_decode`'s length-0 contract does not exist here.
+    capacity, kv_heads * head_dim // 128, 128] (`packed_rows`), or in WHOLE
+    TILES, [slots, capacity * kv_heads // 8, 8, head_dim] (`tiled_rows`);
+    either is recognised by its shape against the token's; k_new, v_new:
+    [slots, 1, kv_heads, head_dim] — the token, in the cache's dtype; pos:
+    [slots] int32 — where each slot appends, inside [0, capacity): the slot
+    then holds pos + 1 tokens, so `flash_decode`'s length-0 contract does
+    not exist here.
     Returns (out, k, v): the cache `kv_append` leaves and, on it, the rows
     `flash_decode(q, k, v, pos + 1)` gives, both bit for bit.
+
+    `ring`: the cache is a sliding window's RING of `capacity` positions and
+    `pos` any position >= 0: the token is written at pos % capacity and the
+    slot attends to its min(pos + 1, capacity) newest tokens, itself among
+    them. The row-major kernel is then named `flash_decode_window` in a
+    trace: a window layer's call reads its ring and no more, and a reader
+    that counts a full layer's bytes a `flash_decode` call must not see it.
 
     The kernel is `flash_decode`'s (`_decode_kernel`, and its name in a
     trace) with the append folded in: when a slot's last live block — the
@@ -1541,14 +1699,18 @@ def flash_decode_append(q, k, v, k_new, v_new, pos, *, scale=None,
     shapes (`_append_block`, `_decode_block`, `_rows_block` — under a mesh
     the last is asked about the rows one shard holds, so 8 heads split over
     a model axis give way; a packed cache is unpacked for them and packed
-    again, a copy of both slabs), and is the two references under
-    `use_pallas=False`."""
+    again, a copy of both slabs; a ring that is not row-major gives way
+    too), and is the two references under `use_pallas=False`."""
     S, Tq, Hq, D = q.shape
     assert Tq == 1, f"flash_decode takes one query per slot, got Tq={Tq}"
-    C, H = k.shape[1], k_new.shape[2]
+    H = k_new.shape[2]
     assert Hq % H == 0, f"{Hq} query heads over {H} K/V heads"
-    packed = k.shape[2:] != (H, D)
-    assert not packed or k.shape[2:] == (H * D // LANES, LANES), \
+    W = k.shape[3]
+    R = H * D // W                      # rows a position
+    C = k.shape[1] * k.shape[2] // R
+    plain = k.shape[2:] == (H, D)
+    assert plain or k.shape[2:] == (R, LANES) \
+        or (D == W and k.shape[2] == SUBLANES), \
         f"a cache of {k.shape} for {H} K/V heads of {D}"
     if scale is None:
         scale = float(1.0 / (D ** 0.5))
@@ -1556,37 +1718,44 @@ def flash_decode_append(q, k, v, k_new, v_new, pos, *, scale=None,
         interpret = _interpret_default()
     pos = jnp.asarray(pos, jnp.int32)
     size = k.dtype.itemsize
-    block_c, call = None, _decode_append_call
-    if use_pallas and not packed and _append_block(C, D, size, interpret):
+    block_c, rows = None, False
+    if use_pallas and plain and not ring \
+            and _append_block(C, D, size, interpret):
         block_c = _decode_block(C, Hq, D, size, block_k, interpret)
     elif use_pallas:
-        # the rows kernel's view needs the SHARD's rows to fill a tile
-        block_c, call = _rows_block(C, _heads_per_shard(k.shape[2]),
-                                    k.shape[3], size, block_k,
-                                    interpret), _decode_rows_call
+        # the rows kernel's view needs the SHARD's rows to fill a tile, or
+        # the leaf to be declared in whole tiles
+        block_c, rows = _rows_block(
+            C, _heads_per_shard(R), W, size, block_k, interpret,
+            tiled=D == W and not plain), True
     if block_c is not None:
         _note_decode_block(block_c, C=C, H=H, D=D, itemsize=size)
-        k_new, v_new = (x.reshape(S, 1, *k.shape[2:]) for x in (k_new, v_new))
+        k_new, v_new = (x.reshape(S, 1, R, W) for x in (k_new, v_new))
+
+        def call(*a):
+            if rows:
+                return _decode_rows_call(*a, scale, block_c, interpret, ring)
+            return _decode_append_call(*a, scale, block_c, interpret)
         # split by the cache's rows: packed, they divide where the heads do
-        return _per_shard(
-            lambda *a: call(*a, scale, block_c, interpret),
-            (q, k, v, k_new, v_new, pos + 1), S, k.shape[2])
+        return _per_shard(call, (q, k, v, k_new, v_new, pos + 1), S,
+                          k.shape[2])
     if use_pallas:
         _note_fallback("flash_decode", "kv_append+flash_decode", C=C, D=D,
                        interpret=interpret)
     rows = k.shape
     k, v = (x.reshape(S, C, H, D) for x in (k, v))
     with jax.named_scope("kv_append"):
-        k, v = kv_append(k, v, k_new, v_new, pos, use_pallas=use_pallas,
-                         interpret=interpret)
-    out = flash_decode(q, k, v, pos + 1, scale=scale, use_pallas=use_pallas,
-                       block_k=block_k, interpret=interpret)
+        k, v = kv_append(k, v, k_new, v_new, pos % C if ring else pos,
+                         use_pallas=use_pallas, interpret=interpret)
+    out = flash_decode(q, k, v, jnp.minimum(pos + 1, C), scale=scale,
+                       use_pallas=use_pallas, block_k=block_k,
+                       interpret=interpret)
     return out, k.reshape(rows), v.reshape(rows)
 
 
 def flash_decode_paged(q, k_pool, v_pool, block_table, lengths, *,
                        scale=None, use_pallas=True, block_k=1024,
-                       interpret=None):
+                       interpret=None, window=None):
     """Decode attention through a paged KV pool (decode/paged.py).
 
     q:           [slots, 1, heads, head_dim] — current token's query;
@@ -1594,7 +1763,11 @@ def flash_decode_paged(q, k_pool, v_pool, block_table, lengths, *,
                  block pool (block 0 is the scratch block);
     block_table: [slots, max_blocks] int32 — logical block j of slot s
                  lives in pool block block_table[s, j] (0 = unallocated);
-    lengths:     [slots] int32 — valid tokens per slot.
+    lengths:     [slots] int32 — valid tokens per slot;
+    window:      a slot sees its `window` newest tokens only (a sliding
+                 window's layer keeps every block of the shared table, so
+                 this is the masked row, `_decode_reference`, counted in
+                 `pallas_fallback_total{kernel="flash_decode_paged"}`).
 
     Token t of a slot sits at (table[t // bs], t % bs), so gathering the
     slot's table row reconstructs its contiguous cache:
@@ -1617,6 +1790,13 @@ def flash_decode_paged(q, k_pool, v_pool, block_table, lengths, *,
     table = jnp.asarray(block_table, jnp.int32)
     k = jnp.take(k_pool, table, axis=0).reshape(S, nb * bs, H, D)
     v = jnp.take(v_pool, table, axis=0).reshape(S, nb * bs, H, D)
+    if window is not None:
+        if use_pallas:
+            _note_fallback("flash_decode_paged", "reference_window",
+                           C=nb * bs, D=D, window=window)
+        return _decode_reference(
+            q, k, v, lengths, float(1.0 / (D ** 0.5)) if scale is None
+            else scale, window=window)
     return flash_decode(q, k, v, lengths, scale=scale, use_pallas=use_pallas,
                         block_k=block_k, interpret=interpret,
                         _name="flash_decode_paged")

@@ -369,11 +369,13 @@ class SelfAttentionLayer(BaseRecurrentConf):
     through the hand-tiled Pallas kernel (kernels/flash_attention.py;
     interpret mode on CPU, Mosaic on TPU). Grouped K/V heads (`n_kv_heads`),
     a head width of its own (`head_dim`: H x head_dim need not be n_out) and
-    a sigmoid output gate from the layer's input (`output_gate`) are options;
-    the layer adds no positions. Causal layers decode from a K/V cache
-    [slots, capacity, kv_heads, head_dim]: one kernel a step whichever way
-    the TPU stores it (positions-minor under head_dim 128, row-major from
-    there: nn/layers/recurrent.py)."""
+    a sigmoid output gate from the layer's input (`output_gate`) are options,
+    as are rotary positions on q and k (`rope_theta`, and YaRN's parameters
+    as `rope_yarn`) and a sliding `window`. Causal layers decode from a K/V
+    cache [slots, capacity, kv_heads, head_dim] — a windowed one from a ring
+    of `window` positions —: one kernel a step whichever way the TPU stores
+    it (positions-minor under head_dim 128, row-major from there:
+    nn/layers/recurrent.py)."""
     n_heads: int = 4
     causal: bool = False
     block_size: int = 256
@@ -394,6 +396,19 @@ class SelfAttentionLayer(BaseRecurrentConf):
     # is multiplied elementwise by sigmoid(x Wgate), x the layer's input,
     # before Wo
     output_gate: bool = False
+    # rotary positions on q and k, HF's `rotate_half` pairing (channel j
+    # with j + head_dim / 2), angles, cos and sin in float32 (None: the
+    # layer adds no positions)
+    rope_theta: float | None = None
+    # YaRN (arXiv:2309.00071) as `transformers` computes it, given as data:
+    # {"factor", "original_max_position_embeddings", "beta_fast",
+    # "beta_slow", "attention_factor"} — the frequencies blended between
+    # theta's and theta's / factor over the ramp [low, high], cos and sin
+    # times attention_factor (None: plain rotary)
+    rope_yarn: dict | None = None
+    # sliding window (causal layers): position i sees the `window` keys
+    # i - window < j <= i, its own among them (None: the whole context)
+    window: int | None = None
 
 
 @register_layer_conf

@@ -60,18 +60,23 @@ class CacheLeaf(NamedTuple):
 def note_cache_entry(geom, kind, entry):
     """Count a layer's decode-cache entry in the gauges
     `decode_cache_state_bytes` (kind "state": a fixed size a slot, whatever
-    the sequence) or `decode_cache_kv_bytes` (kind "kv": grows with the
-    capacity). A layer's `decode_entry` calls this with what it returns; the
-    running totals ride on the engine's `geom`, so the gauges read the cache
-    of the engine built last."""
+    the sequence), `decode_cache_kv_bytes` (kind "kv": grows with the
+    capacity) or `decode_cache_window_bytes` (kind "window": a sliding
+    window's ring, its window's size whatever the capacity). A layer's
+    `decode_entry` calls this with what it returns; the running totals ride
+    on the engine's `geom`, so the gauges read the cache of the engine built
+    last."""
     import math
     from ...telemetry.registry import get_registry
-    totals = vars(geom).setdefault("cache_bytes", {"state": 0, "kv": 0})
+    totals = vars(geom).setdefault("cache_bytes",
+                                   {"state": 0, "kv": 0, "window": 0})
     totals[kind] += sum(math.prod(leaf.shape) * jnp.dtype(leaf.dtype).itemsize
                         for leaf in entry.values())
     for k, doc in (("state", "fixed-size per-slot state (recurrent carries, "
                     "state-space states, conv tails)"),
-                   ("kv", "entries with a capacity axis (K/V rows)")):
+                   ("kv", "entries with a capacity axis (K/V rows)"),
+                   ("window", "sliding-window layers' rings (K/V rows of "
+                    "the window's positions)")):
         get_registry().gauge(f"decode_cache_{k}_bytes",
                              "Bytes of the decode cache held as "
                              + doc).set(totals[k])

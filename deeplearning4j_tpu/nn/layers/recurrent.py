@@ -236,6 +236,50 @@ def _paged_append_seq(pool, t, row):
     return pool.at[row[:chunks]].set(tc.astype(pool.dtype))
 
 
+def rope_frequencies(head_dim, theta, yarn=None):
+    """(the head_dim / 2 rotary frequencies as float32 numpy, the factor on
+    cos and sin). Plain: f_j = theta^(-2j / head_dim), factor 1. YaRN
+    (`yarn`: the conf's `rope_yarn`), as `transformers` computes it: e_j =
+    theta^(-2j / head_dim), n_j = e_j / factor; c(r) = head_dim ln(original
+    / (2 pi r)) / (2 ln theta); low = floor(c(beta_fast)), high =
+    ceil(c(beta_slow)), both clipped to [0, head_dim - 1]; ramp_j = clip((j
+    - low) / (high - low), 0, 1); f_j = n_j ramp_j + e_j (1 - ramp_j); the
+    factor is `attention_factor`."""
+    half = head_dim // 2
+    e = float(theta) ** (-np.arange(half, dtype=np.float64) / half)
+    if not yarn:
+        return e.astype(np.float32), 1.0
+    low, high = yarn_ramp(head_dim, theta, yarn)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0, 1)
+    f = e / float(yarn["factor"]) * ramp + e * (1 - ramp)
+    return f.astype(np.float32), float(yarn["attention_factor"])
+
+
+def yarn_ramp(head_dim, theta, yarn):
+    """(low, high): the channels between which YaRN's frequencies go from
+    theta's own to theta's / factor."""
+    def c(rotations):
+        return head_dim * np.log(
+            yarn["original_max_position_embeddings"]
+            / (rotations * 2 * np.pi)) / (2 * np.log(theta))
+    return (max(int(np.floor(c(yarn["beta_fast"]))), 0),
+            min(int(np.ceil(c(yarn["beta_slow"]))), head_dim - 1))
+
+
+def rope_half(x, pos, freq, factor=1.0):
+    """x [b, t, heads, Dh] turned at positions pos [b or 1, t], HF's
+    `rotate_half` pairing: channel j with j + Dh / 2 by pos * freq_j; cos
+    and sin times `factor`. Angles, cos and sin float32; back in x's
+    dtype."""
+    half = x.shape[-1] // 2
+    ang = pos.astype(jnp.float32)[..., None, None] * jnp.asarray(freq)
+    cos, sin = jnp.cos(ang) * factor, jnp.sin(ang) * factor   # [b, t, 1, half]
+    xf = x.astype(jnp.float32)
+    a, b = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
 def _repeat_kv(q, k, v):
     """k, v [b, t, Hkv, Dh] repeated to q's heads (K/V head j serves query
     heads j*G .. j*G + G - 1); as they are when the counts agree."""
@@ -299,7 +343,24 @@ class SelfAttentionLayerModule(BaseLayerModule):
     `head_dim` sets a head's width apart from n_out / n_heads (Wq, Wgate [n_in,
     H Dh], Wk, Wv [n_in, Hkv Dh], Wo [H Dh, n_out]). `output_gate`: the
     context is multiplied by sigmoid(x Wgate), x the layer's input, before Wo
-    (scope `attention_gate`; the sigmoid in float32) in every leg."""
+    (scope `attention_gate`; the sigmoid in float32) in every leg.
+
+    `rope_theta` (with `rope_yarn`): q and k are turned by their positions
+    (`rope_half`, scope `rope`, float32 inside) — 0.. in a sequence and a
+    prefill, `ctx.pos` in a step, `ctx.start`.. in a verify window — so the
+    cache holds turned keys. `window`: position i sees keys i - window < j <=
+    i. A windowed layer's slab entry is a RING of min(window, capacity)
+    positions a slot, position p at p % ring: a prefill writes the prompt's
+    last `ring` real positions there (`ctx.length`: a bucket's pad positions
+    never overwrite a live one), a step writes at pos % ring and attends to
+    the min(pos + 1, ring) newest (`flash_decode_append(ring=True)`, named
+    `flash_decode_window` in a trace; scope `attention_window`). What a ring
+    has overwritten cannot be rewound, so a windowed layer is not
+    `decode_rewindable`. Paged, it keeps the shared table's every block and
+    masks (`flash_decode_paged(window=)`). Fewer than 8 K/V heads of a
+    multiple of 128 are declared in whole (8, 128) tiles
+    (`kernels.flash_attention.tiled_rows`), which is what lets the row-major
+    kernel take them."""
 
     def init(self, rng, input_type, dtype=jnp.float32):
         c = self.conf
@@ -333,12 +394,13 @@ class SelfAttentionLayerModule(BaseLayerModule):
         c = self.conf
         return int(getattr(c, "n_kv_heads", None) or c.n_heads)
 
-    def project_qkv(self, params, x):
+    def project_qkv(self, params, x, pos=None):
         """[b,t,f] -> q [b,t,H,Dh] and k, v [b,t,Hkv,Dh]. Split out of
         forward so the decode legs run the SAME projections when they append
         a token's k/v to a KV-cache slot. An explicit `score_scale` rides on
         q (q * score_scale * sqrt(Dh)), so every kernel behind keeps its
-        1 / sqrt(Dh)."""
+        1 / sqrt(Dh). With `rope_theta`, q and k are turned at `pos` [b or
+        1, t] (default: 0 .. t - 1)."""
         c = self.conf
         B, T, _ = x.shape
         H, Hkv, Dh = int(c.n_heads), self.kv_heads, self.head_dim
@@ -348,7 +410,29 @@ class SelfAttentionLayerModule(BaseLayerModule):
         scale = getattr(c, "score_scale", None)
         if scale is not None:
             q = q * jnp.asarray(float(scale) * float(np.sqrt(Dh)), q.dtype)
+        theta = getattr(c, "rope_theta", None)
+        if theta is not None:
+            with jax.named_scope("rope"):
+                if pos is None:
+                    pos = jnp.arange(T, dtype=jnp.int32)[None]
+                turn = rope_frequencies(Dh, theta,
+                                        getattr(c, "rope_yarn", None))
+                q, k = rope_half(q, pos, *turn), rope_half(k, pos, *turn)
         return q, k, v
+
+    @property
+    def window(self):
+        w = getattr(self.conf, "window", None)
+        return None if w is None else int(w)
+
+    @property
+    def decode_rewindable(self):
+        """A ring cannot be rewound past what it has overwritten."""
+        return self.window is None
+
+    def _attention_scope(self):
+        return jax.named_scope("attention" if self.window is None
+                               else "attention_window")
 
     def attend(self, q, k, v, mask):
         """The kernel dispatch (shared by forward and the decode prefill).
@@ -360,6 +444,7 @@ class SelfAttentionLayerModule(BaseLayerModule):
         c = self.conf
         T = q.shape[1]
         k, v = _repeat_kv(q, k, v)
+        window = {} if self.window is None else {"window": self.window}
         if getattr(c, "use_pallas", False):
             from ...kernels import flash_attention
             # block_size tunes the QUERY tile only; the key tile keeps the
@@ -369,11 +454,14 @@ class SelfAttentionLayerModule(BaseLayerModule):
             # score tiles (fwd + both bwd), so ragged/packed batches keep
             # the fast path; untileable shapes fall back inside the call
             return flash_attention(q, k, v, causal=c.causal,
-                                   block_q=int(c.block_size), key_mask=mask)
+                                   block_q=int(c.block_size), key_mask=mask,
+                                   **window)
         if T % min(int(c.block_size), T) == 0:
             return blockwise_attention(q, k, v, block_size=int(c.block_size),
-                                       causal=c.causal, key_mask=mask)
-        return attention_reference(q, k, v, causal=c.causal, key_mask=mask)
+                                       causal=c.causal, key_mask=mask,
+                                       **window)
+        return attention_reference(q, k, v, causal=c.causal, key_mask=mask,
+                                   **window)
 
     def finish(self, params, out, mask, x):
         """Output gate (from the layer's input x), output projection,
@@ -401,30 +489,64 @@ class SelfAttentionLayerModule(BaseLayerModule):
         return None
 
     def decode_entry(self, geom):
-        from ...kernels.flash_attention import packed_rows
+        from ...kernels.flash_attention import (LANES, SUBLANES,
+                                                packed_rows, tiled_rows)
         H, Dh = self.kv_heads, self.head_dim
         if geom.paged:
             shape = (geom.num_blocks, geom.block_size, H, Dh)
-        else:
-            # a slab the step's kernel reads: heads narrower than a lane row
-            # packed side by side where one shard's then fill whole tiles
-            rows = getattr(self.conf, "use_pallas", False) and packed_rows(
-                H, Dh, geom.model_shards)
-            shape = (geom.slots, geom.capacity,
-                     *((rows, 128) if rows else (H, Dh)))
+            return note_cache_entry(geom, "kv", self._leaves(shape, geom))
+        # a windowed layer keeps a ring, a position at its remainder: of its
+        # window, or of the capacity where a slot cannot outgrow the window
+        # (a ring that never wraps; still the ring's kind, kernel name and
+        # `decode_rewindable`: `self.window is not None` decides all four)
+        ring = self.window is not None
+        C = min(self.window, geom.capacity) if ring else geom.capacity
+        # a slab the step's kernel reads: heads narrower than a lane row
+        # packed side by side where one shard's then fill whole tiles, fewer
+        # heads of whole lane rows than a tile's 8 declared in whole tiles
+        kernels = getattr(self.conf, "use_pallas", False)
+        rows = kernels and packed_rows(H, Dh, geom.model_shards)
+        tiles = kernels and tiled_rows(C, H, Dh, geom.model_shards)
+        shape = (geom.slots, *((C, rows, LANES) if rows else
+                               (tiles, SUBLANES, Dh) if tiles
+                               else (C, H, Dh)))
+        return note_cache_entry(geom, "window" if ring else "kv",
+                                self._leaves(shape, geom))
+
+    @staticmethod
+    def _leaves(shape, geom):
         leaf = CacheLeaf(shape, geom.dtype, 2)
-        return note_cache_entry(geom, "kv", {"k": leaf, "v": leaf})
+        return {"k": leaf, "v": leaf}
+
+    def _positions(self, leaf):
+        """Positions a slot of a slab leaf holds, whatever its rows' shape."""
+        return leaf.shape[1] * leaf.shape[2] * leaf.shape[3] \
+            // (self.kv_heads * self.head_dim)
 
     @staticmethod
     def _as_stored(t, leaf):
         """A sequence's K or V [b, t, H, Dh] in the leaf's dtype and in the
-        shape of its rows (packed: `[b, t, H Dh / 128, 128]`, a plain
-        reshape)."""
-        return t.astype(leaf.dtype).reshape(*t.shape[:2], *leaf.shape[2:])
+        shape of its rows (packed: `[b, t, H Dh / 128, 128]`; in whole tiles:
+        `[b, t H / 8, 8, Dh]`: a plain reshape either way)."""
+        return t.astype(leaf.dtype).reshape(t.shape[0], -1, *leaf.shape[2:])
+
+    def _as_ring(self, t, length, ring):
+        """The [1, L, H, Dh] rows of a prompt as the ring holds them after
+        it: position p at p % ring, of the `length` real positions the last
+        `ring`. A bucket no longer than the ring is its own start; a longer
+        one's last `ring` real rows are cut out and turned to their places
+        (a prompt shorter than the ring in a longer bucket keeps its rows
+        where they are: the cut starts at 0)."""
+        L = t.shape[1]
+        if L <= ring:
+            return t
+        start = jnp.clip(length - ring, 0, L - ring)
+        rows = lax.dynamic_slice_in_dim(t, start, ring, axis=1)
+        return jnp.roll(rows, start % ring, axis=1)
 
     def decode_prefill(self, params, state, x, entry, ctx):
         q, k, v = self.project_qkv(params, x)                 # [1, L, H, Dh]
-        with jax.named_scope("attention"):
+        with self._attention_scope():
             out = self.attend(q, k, v, ctx.mask)
         y = self.finish(params, out, ctx.mask, x)
         with jax.named_scope("kv_append"):
@@ -432,6 +554,9 @@ class SelfAttentionLayerModule(BaseLayerModule):
                 return y, {
                     "k": _paged_append_seq(entry["k"], k[0], ctx.row),
                     "v": _paged_append_seq(entry["v"], v[0], ctx.row)}
+            if self.window is not None:
+                ring = self._positions(entry["k"])
+                k, v = (self._as_ring(t, ctx.length, ring) for t in (k, v))
             # match the traced slot's index dtype under x64
             z = jnp.zeros((), ctx.slot.dtype)
             at = (ctx.slot, z, z, z)
@@ -443,41 +568,56 @@ class SelfAttentionLayerModule(BaseLayerModule):
 
     def decode_step(self, params, state, x, entry, ctx):
         from ...kernels import flash_decode_append, flash_decode_paged
-        q, kt, vt = self.project_qkv(params, x)               # [S, 1, H, Dh]
-        use_pallas = getattr(self.conf, "use_pallas", False)
+        q, kt, vt = self.project_qkv(params, x, ctx.pos[:, None])
+        use_pallas = getattr(self.conf, "use_pallas", False)   # [S, 1, H, Dh]
         if ctx.table is not None:
             with jax.named_scope("kv_append"):
                 nk = entry["k"].at[ctx.blk, ctx.off].set(
                     kt[:, 0].astype(entry["k"].dtype))
                 nv = entry["v"].at[ctx.blk, ctx.off].set(
                     vt[:, 0].astype(entry["v"].dtype))
-            with jax.named_scope("attention"):
+            with self._attention_scope():
                 out = flash_decode_paged(q, nk, nv, ctx.table, ctx.kv_valid,
-                                         use_pallas=use_pallas)
+                                         use_pallas=use_pallas,
+                                         window=self.window)
         else:
-            # the slot then holds ctx.kv_valid = ctx.pos + 1 tokens
-            with jax.named_scope("attention"):
+            # the slot then holds ctx.kv_valid = ctx.pos + 1 tokens, a
+            # window's ring the newest of them
+            with self._attention_scope():
                 out, nk, nv = flash_decode_append(
                     q, entry["k"], entry["v"], kt.astype(entry["k"].dtype),
                     vt.astype(entry["v"].dtype), ctx.pos,
-                    use_pallas=use_pallas)
+                    use_pallas=use_pallas, ring=self.window is not None)
         return self.finish(params, out.astype(x.dtype), None, x), \
             {"k": nk, "v": nv}
 
     def decode_verify(self, params, state, x, entry, ctx):
-        """Slab layout only (the engine's verify() refuses the paged one)."""
-        q, k, v = self.project_qkv(params, x)                 # [1, W, H, Dh]
-        slot = ctx.slot
+        """Slab layout only (the engine's verify() refuses the paged one),
+        and no windowed layer (`decode_rewindable`)."""
+        W = x.shape[1]
+        start = jnp.asarray(ctx.start, ctx.slot.dtype)
+        q, k, v = self.project_qkv(
+            params, x, (start + jnp.arange(W, dtype=start.dtype))[None])
+        slot = ctx.slot                                       # [1, W, H, Dh]
         z = jnp.zeros((), slot.dtype)
-        at = (slot, jnp.asarray(ctx.start, slot.dtype), z, z)
-        nk = lax.dynamic_update_slice(entry["k"],
-                                      self._as_stored(k, entry["k"]), at)
-        nv = lax.dynamic_update_slice(entry["v"],
-                                      self._as_stored(v, entry["v"]), at)
-        # the one slot's row, as heads (a packed row unpacked)
+
+        def write(leaf, t):
+            tiled = leaf.shape[2:] != t.shape[2:] \
+                and leaf.shape[3] == t.shape[3]
+            if not tiled:       # a position an index: plain, or packed
+                return lax.dynamic_update_slice(
+                    leaf, self._as_stored(t, leaf), (slot, start, z, z))
+            # whole tiles: a window may start inside one, so by the rows
+            H = t.shape[2]
+            rows = leaf.reshape(leaf.shape[0], -1, leaf.shape[3])
+            return lax.dynamic_update_slice(
+                rows, t.astype(leaf.dtype).reshape(1, W * H, -1),
+                (slot, start * H, z)).reshape(leaf.shape)
+        nk, nv = write(entry["k"], k), write(entry["v"], v)
+        # the one slot's row, as heads (a packed or tiled row unpacked)
         krow, vrow = (
             lax.dynamic_index_in_dim(n, slot, 0, keepdims=True).reshape(
-                1, n.shape[1], *k.shape[2:]) for n in (nk, nv))
+                1, -1, *k.shape[2:]) for n in (nk, nv))
         out = _verify_attend(q, krow, vrow, ctx.start)
         return self.finish(params, out.astype(x.dtype), None, x), \
             {"k": nk, "v": nv}

@@ -23,9 +23,11 @@ from .sharding import SEQ_AXIS
 NEG_INF = -1e30
 
 
-def attention_reference(q, k, v, *, causal=False, scale=None, key_mask=None):
+def attention_reference(q, k, v, *, causal=False, scale=None, key_mask=None,
+                        window=None):
     """Plain softmax attention (the correctness oracle for the blockwise and
-    ring paths). key_mask: optional [batch, time] validity of key positions."""
+    ring paths). key_mask: optional [batch, time] validity of key positions.
+    window: with `causal`, query i sees keys i - window < j <= i."""
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
     scale = scale if scale is not None else 1.0 / jnp.sqrt(D).astype(q.dtype)
@@ -35,17 +37,23 @@ def attention_reference(q, k, v, *, causal=False, scale=None, key_mask=None):
     if causal:
         qpos = jnp.arange(Tq)[:, None]
         kpos = jnp.arange(Tk)[None, :]
-        s = jnp.where((kpos > qpos)[None, None], NEG_INF, s)
+        bad = kpos > qpos
+        if window is not None:
+            bad = bad | (kpos <= qpos - window)
+        s = jnp.where(bad[None, None], NEG_INF, s)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
 
-def _causal_mask_fn(qpos):
-    """Scores mask: key positions after the query's global position get
-    NEG_INF (shared by the blockwise scan and the ring body)."""
+def _causal_mask_fn(qpos, window=None):
+    """Scores mask: key positions after the query's global position — and,
+    with a `window`, those at or below position - window — get NEG_INF
+    (shared by the blockwise scan and the ring body)."""
     def mask_fn(s, k_off):
         kpos = k_off + jnp.arange(s.shape[-1])
         bad = kpos[None, :] > qpos[:, None]               # Tq, Tb
+        if window is not None:
+            bad = bad | (kpos[None, :] <= qpos[:, None] - window)
         return jnp.where(bad[None, None], NEG_INF, s)
     return mask_fn
 
@@ -76,7 +84,7 @@ def _block_update(carry, kv, q, scale, mask_fn=None):
 
 
 def blockwise_attention(q, k, v, *, block_size=256, causal=False, scale=None,
-                        key_mask=None):
+                        key_mask=None, window=None):
     """Single-device flash-style attention: scan over K/V blocks with online
     softmax — O(T_block) memory instead of O(T^2). Numerically identical to
     attention_reference, including its key_mask ([batch, time] key validity)
@@ -93,7 +101,7 @@ def blockwise_attention(q, k, v, *, block_size=256, causal=False, scale=None,
     vb = v.reshape(B, n_blocks, block_size, H, D).transpose(1, 0, 2, 3, 4)
     offs = jnp.arange(n_blocks) * block_size
 
-    mask_fn = _causal_mask_fn(jnp.arange(Tq)) if causal else None
+    mask_fn = _causal_mask_fn(jnp.arange(Tq), window) if causal else None
 
     o0 = jnp.zeros((B, H, Tq, D), q.dtype)
     m0 = jnp.full((B, H, Tq), NEG_INF, q.dtype)

@@ -496,6 +496,72 @@ def solar_hybrid_lm(vocab_size=256, d_model=128, n_layers=4, n_heads=2,
     return ComputationGraph(gb.build())
 
 
+def mellum_lm(vocab_size=256, d_model=128, n_layers=4, n_heads=4,
+              n_kv_heads=1, head_dim=128, window=1024, full_interval=3,
+              rope_theta=500000.0, yarn=None, n_experts=64,
+              experts_per_token=8, expert_hidden=896, experts_held=None,
+              first_expert=0, rms_norm_eps=1e-6, dtype="float32", seed=12345,
+              use_pallas=False, updater=None):
+    """Sliding-window / full-attention expert decoder of the `mellum` shape
+    (JetBrains/Mellum2-12B-A2.5B-Instruct): pre-norm blocks h +=
+    attention(RMSNorm(h)); h += experts(RMSNorm(h)). Every layer mixes with
+    grouped-query attention — `n_heads` query heads on `n_kv_heads` K/V
+    heads of `head_dim`, rotary positions on q and k, no bias, no q/k norm.
+    Layer i (0-based) is a FULL layer when i % (full_interval + 1) ==
+    full_interval (the period ends with it): the whole context, its rotary
+    frequencies YaRN's (`yarn`: {"factor", "original_max_position_embeddings",
+    "beta_fast", "beta_slow", "attention_factor"}; None: plain); every other
+    layer sees a sliding `window` of positions, its own among them, under
+    plain rotary at `rope_theta`, and decodes from a ring of `window`
+    positions a slot. Its ffn, in every layer, is `experts_per_token` of
+    `n_experts` routed gated-SiLU experts of width `expert_hidden` — softmax
+    over all experts, the largest taken, their gates renormalised — with no
+    shared expert beside them; this model holds `experts_held` of the
+    experts from `first_expert` on (default: all; the rest of the sum is
+    another chip's). h_0 = E[ids]; probabilities = softmax(RMSNorm(h)
+    W_head^T), the head untied. Input one-hot [b, t, vocab]. The default
+    updater is plain SGD: it keeps no state beside the parameters."""
+    from ..nn.conf.layers import (LMHeadLayer, MixtureOfExpertsLayer,
+                                  RMSNormalization, SelfAttentionLayer)
+    gb = (NeuralNetConfiguration.builder()
+          .seed(seed).updater(updater or Sgd(learning_rate=1e-3))
+          .weight_init("xavier").dtype(dtype)
+          .graph_builder()
+          .add_inputs("tokens"))
+    norm = lambda: RMSNormalization(eps=rms_norm_eps)
+
+    def residual(name, prev, branch):
+        gb.add_vertex(name, ElementWiseVertex("add"), prev, branch)
+        return name
+
+    gb.add_layer("embed", DenseLayer(n_out=d_model, activation="identity"),
+                 "tokens")
+    prev = "embed"
+    for i in range(n_layers):
+        full = i % (full_interval + 1) == full_interval
+        gb.add_layer(f"b{i}_norm1", norm(), prev)
+        gb.add_layer(f"b{i}_attn", SelfAttentionLayer(
+            n_out=d_model, n_heads=n_heads, n_kv_heads=n_kv_heads,
+            head_dim=head_dim, causal=True, rope_theta=rope_theta,
+            rope_yarn=dict(yarn) if full and yarn else None,
+            window=None if full else window, use_pallas=use_pallas,
+            activation="identity"), f"b{i}_norm1")
+        prev = residual(f"b{i}_res1", prev, f"b{i}_attn")
+        gb.add_layer(f"b{i}_norm2", norm(), prev)
+        gb.add_layer(f"b{i}_moe", MixtureOfExpertsLayer(
+            n_out=d_model, n_experts=n_experts, top_k=experts_per_token,
+            gated=True, n_hidden=expert_hidden, experts_held=experts_held,
+            first_expert=first_expert, score_function="softmax",
+            use_pallas=use_pallas, activation="identity"), f"b{i}_norm2")
+        prev = residual(f"b{i}_res2", prev, f"b{i}_moe")
+    gb.add_layer("norm", norm(), prev)
+    gb.add_layer("out", LMHeadLayer(n_out=vocab_size, activation="softmax",
+                                    loss="MCXENT"), "norm")
+    gb.set_outputs("out")
+    gb.set_input_types(InputType.recurrent(vocab_size))
+    return ComputationGraph(gb.build())
+
+
 def vgg16(num_classes=1000, image_size=224, seed=12345):
     """VGG16 (reference: trainedmodels/TrainedModels.java VGG16)."""
     b = (NeuralNetConfiguration.builder()

@@ -428,6 +428,62 @@ def test_flash_decode_append_on_a_packed_cache_is_one_kernel_in_place(
                      r"\{2\}: \(7, \{\}\)\}", text)
 
 
+@pytest.mark.parametrize("C,ring", [(1024, True), (6144, False)],
+                         ids=["ring_of_1024", "slab_of_6144"])
+def test_flash_decode_append_on_half_tile_rows_is_one_kernel_in_place(
+        on_chip, C, ring):
+    """`mellum2_code_decode`'s two kinds of layer (PR 47): 32 query heads on
+    4 K/V heads of 128, bfloat16, 48 slots — half an (8, 128) tile a
+    position, so the leaf is declared in whole tiles, [48, C * 4 / 8, 8, 128]
+    (`tiled_rows`): THAT array is the [S, C * 4, 128] buffer the row-major
+    kernel copies from (a bitcast), and the token's 4 rows reach it with
+    their tile (a copy to HBM may not start inside one: `Slice shape along
+    dimension 1 must be aligned to tiling (8), but is 4` is what the plain
+    leaf gave here). A window layer's ring of 1,024 positions is named
+    `flash_decode_window`, a full layer's slab of 6,144 `flash_decode`: ONE
+    kernel either way, its two slab outputs aliased onto the donated caches
+    (operands 6 and 7), no copy, transpose or update of a slab, no loop over
+    the slots, no counted fallback, a 256-position block."""
+    from deeplearning4j_tpu.telemetry.registry import get_registry
+    S, Hq, H, D = 48, 32, 4, 128
+    tiles = fa.tiled_rows(C, H, D)
+    assert tiles == C // 2 \
+        and fa._rows_block(C, H, D, 2, 1024, False, tiled=True) == 256
+    fallbacks = get_registry().counter("pallas_fallback_total", "")
+    before = fallbacks.get()
+    kv = on_chip((S, tiles, 8, D), jnp.bfloat16)
+    comp = compiled_step_layer(on_chip((S, 1, Hq, D), jnp.bfloat16), kv,
+                               on_chip((S, 1, H, D), jnp.bfloat16),
+                               on_chip((S,), jnp.int32), interpret=False,
+                               ring=ring)
+    assert fallbacks.get() == before
+    text = comp.as_text()
+    assert len(re.findall(rf"bf16\[48,{C * H},128\]\S* bitcast\(", text)) == 2
+    assert text.count(KERNEL) == 1
+    name = "flash_decode_window" if ring else "flash_decode"
+    assert len(re.findall(rf"%{name}[.\d]* = ", text)) == 1
+    assert ring or "%flash_decode_window" not in text
+    assert "%kv_append" not in text and "dynamic-update-slice" not in text
+    assert relayouts(text, S * C * H * D) == []
+    assert loops(text) == []
+    mem = comp.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 20
+    assert mem.alias_size_in_bytes == 2 * S * C * H * D * 2
+    assert re.search(r"output_to_operand_aliasing=\{\{1\}: \(6, \{\}\), "
+                     r"\{2\}: \(7, \{\}\)\}", text)
+
+
+def test_windowed_flash_forward_compiles(on_chip):
+    """A window layer's prefill of the longest prompt: 4,096 positions, 32
+    heads of 128, bfloat16, a window of 1,024 in the kernel's default key
+    blocks of 1,024 (swept on the chip: PERF.md section 6, PR 47)."""
+    q = on_chip((1, 4096, 32, 128), jnp.bfloat16)
+    text = compiled_text(
+        lambda q, k, v: flash_attention(q, k, v, causal=True, window=1024,
+                                        interpret=False), q, q, q)
+    assert text.count(KERNEL) == 1
+
+
 def test_flash_decode_append_runs_per_shard_on_a_mesh(topo, chip_config):
     """`ServingServer(mesh=4)`: per shard, 4 of the 16 heads — the three
     outputs (the rows and the two slabs) head-sharded like the operands:
@@ -1044,6 +1100,56 @@ def test_solar_decode_step_compiles_with_its_kernels_and_no_copy_of_a_slab(
     text = _prefill_text(eng, 128, one_chip)
     assert len(re.findall(r"%expert_gmm_1x128[.\d]* = ", text)) == 4
     assert "kda_step" not in text and "%flash_decode" not in text
+
+
+def test_mellum_decode_step_compiles_with_one_kernel_a_layer_and_no_copy(
+        one_chip, chip_config, monkeypatch):
+    """One period of `mellum_lm` (three sliding-window layers and a full one
+    under YaRN, 32 query heads on the configuration's own 4 K/V heads of
+    128; four routed ffns) at d_model 256, bfloat16, 16 slots of 2,048: a
+    window layer is ONE `flash_decode_window` on its ring of 1,024 positions,
+    the full layer ONE `flash_decode` on its slab, both leaves in whole
+    tiles, an expert layer one `expert_gmm_16x1`; no instruction of the step
+    copies a slab or loops over the slots, and no kernel gave way (the
+    counted fallbacks stand still, in the prefill too). (The whole 28-layer
+    step at the cell's size — 48 slots of 6,144 — compiles here in 37 s with
+    13.32 GB of arguments and 0.056 GB of temporaries, 56 kernels, its
+    4,096-token prefill in 53 s with 0.58 GB: PERF.md section 4.)"""
+    from deeplearning4j_tpu.decode.engine import DecodeEngine
+    from deeplearning4j_tpu.telemetry.registry import get_registry
+    from deeplearning4j_tpu.zoo.models import mellum_lm
+    for module in ("flash_attention", "expert_gmm"):
+        monkeypatch.setattr(
+            importlib.import_module("deeplearning4j_tpu.kernels." + module),
+            "_interpret_default", lambda: False)
+    yarn = {"factor": 16, "original_max_position_embeddings": 8192,
+            "beta_fast": 32, "beta_slow": 1,
+            "attention_factor": 1.2772588722239782}
+    net = mellum_lm(vocab_size=512, d_model=256, n_layers=4, n_heads=32,
+                    n_kv_heads=4, yarn=yarn, n_experts=32, experts_held=8,
+                    expert_hidden=128, dtype="bfloat16",
+                    use_pallas=True).init()
+    eng = DecodeEngine(net, slots=16, max_len=2048)
+    assert [e["k"].shape for e in eng._entries.values()] \
+        == [(16, 512, 8, 128)] * 3 + [(16, 1024, 8, 128)]
+    fallbacks = get_registry().counter("pallas_fallback_total", "")
+    before = fallbacks.get()
+    args = _abstract((net.params, net.states, eng.init_cache(),
+                      np.zeros((eng.slots,), np.int32),
+                      eng._greedy_step_ops), one_chip)
+    text = eng._build_step().lower(*args, None).compile().as_text()
+    assert text.count(KERNEL) == 8
+    assert len(re.findall(r"%flash_decode_window[.\d]* = ", text)) == 3
+    assert len(re.findall(r"%flash_decode[.\d]* = ", text)) == 1
+    assert len(re.findall(r"%expert_gmm_16x1[.\d]* = ", text)) == 4
+    assert "%kv_append" not in text
+    assert loops(text) == []
+    assert relayouts(text, 16 * 1024 * 4 * 128) == []    # a ring
+    text = _prefill_text(eng, 2048, one_chip)
+    assert len(re.findall(r"%flash_fwd[.\d]* = ", text)) == 4
+    assert len(re.findall(r"%expert_gmm_1x2048[.\d]* = ", text)) == 4
+    assert "%flash_decode" not in text and loops(text) == []
+    assert fallbacks.get() == before
 
 
 @pytest.mark.slow
