@@ -4,8 +4,10 @@ row tile times ITS expert's matrices, the experts that got no row never read.
 An expert layer (nn/layers/feedforward.py, MixtureOfExpertsLayerModule) lays
 the (token, expert) pairs it has to compute out as rows `[m, k]` sorted by
 expert, every expert's run starting on a multiple of the row tile `tm` and
-padded with zero rows to the next (`group_tiles`), so a row tile belongs to
-ONE expert and nothing has to be masked:
+padded to the next (`group_tiles`), so a row tile belongs to ONE expert and
+nothing has to be masked. A padding row holds ANY finite row (the layer
+leaves some token's there): both products treat rows apart, and the caller
+reads only the rows it laid pairs on:
 
     tile t, of group g = tile_group[t]:
         (a, b) = split(rows[t] @ w1[g])          w1 [g, k, 2 h]
@@ -30,8 +32,9 @@ The plain form (`_gmm_reference`: two `lax.ragged_dot`s) is the semantics,
 the path off the TPU, the backward (`jax.vjp` of it: no backward kernel yet)
 and the fallback when the shapes do not tile or under a serving mesh,
 counted in `pallas_fallback_total{kernel="expert_gmm"}` like the others'.
-Rows of the tiles past `n_tiles` are zero in the plain form and UNDEFINED in
-the kernel's output: the caller reads only the rows it laid out.
+Rows of the tiles past `n_tiles` are never read and come out zero in the
+plain form, UNDEFINED in the kernel's output: the caller reads only the rows
+it laid out.
 """
 from __future__ import annotations
 
@@ -223,7 +226,8 @@ def expert_gmm(rows, w1, w2, tile_group, n_tiles, *, use_pallas=True,
     """Every row tile through its expert's gated feed-forward.
 
     rows: [m, k], m = len(tile_group) * tm, in `group_tiles`'s layout (each
-    group's rows from a tile boundary on, zero rows up to the next);
+    group's rows from a tile boundary on; up to the next boundary, and past
+    tile `n_tiles`, any finite rows: nothing reads their products);
     w1: [g, k, 2 * h] and w2: [g, h, n] — the experts held, no biases;
     tile_group: [tiles] int32, n_tiles: int32 scalar. Returns [m, n] in the
     rows' dtype: (silu(a) * b) @ w2[g] with (a, b) = split(rows @ w1[g]),

@@ -412,14 +412,19 @@ class MixtureOfExpertsLayerModule(BaseLayerModule):
         return experts, float(c.routed_scaling) * chosen \
             / jnp.sum(chosen, axis=-1, keepdims=True)
 
-    def layout(self, params, xt):
+    def layout(self, params, xt, mask=None):
         """xt [T, f] -> where every (token, expert) pair of the experts held
-        goes: `here`, `gates` [T, k] (is the pair's expert held; its gate),
+        goes: `here`, `gates` [T, k] (does the pair take a row — its expert
+        is held and `mask` [T], where given, keeps its token; its gate),
         `row_of_pair` [T * k] (its row in the grouped product's operand),
         and of that operand `token`, `live` [rows] (the token a row holds;
-        is it a pair's), `group` [rows], `tile_group`, `n_tiles`
-        (kernels/expert_gmm.py `group_tiles`) and `tm`. Rows are sized for
-        the worst case, every pair held: T * k // tm + held tiles."""
+        is it a pair's: `live` counts the held pairs of the tokens kept),
+        `group` [rows], `tile_group`, `n_tiles` (kernels/expert_gmm.py
+        `group_tiles`) and `tm`. A token the mask leaves out (a prefill's
+        padding) holds no pair: no row, no tile. Rows are sized for the
+        worst case, every pair held: T * k // tm + held tiles; `n_tiles`,
+        the tiles that hold a row, is data, and the kernel's grid ends
+        there."""
         from ...kernels.expert_gmm import group_tiles, row_tile
         _, held, first, _, k = self._sizes()
         P = xt.shape[0] * k
@@ -427,6 +432,8 @@ class MixtureOfExpertsLayerModule(BaseLayerModule):
         experts, gates = self.route(params, xt)
         local = experts - first
         here = (local >= 0) & (local < held)
+        if mask is not None:
+            here &= mask[:, None]
         key = jnp.where(here, local, held).reshape(P)
         order = jnp.argsort(key, stable=True)        # sorted place -> pair
         onehot = (key[:, None] == jnp.arange(held)[None]).astype(jnp.int32)
@@ -447,6 +454,11 @@ class MixtureOfExpertsLayerModule(BaseLayerModule):
                 "group": group, "tile_group": tile_group, "n_tiles": n_tiles}
 
     def forward(self, params, state, x, *, train=False, rng=None, mask=None):
+        """x [..., f] -> (the gated sum of each position's held experts,
+        state, mask). `mask` [...] (a prefill's validity, a padded batch's)
+        takes the positions it leaves out off the layer: they hold no pair
+        (`layout`) and come out zero; the others are what they are without
+        a mask, bit for bit."""
         from ...kernels.expert_gmm import expert_gmm, tile_rows
         c = self.conf
         x = apply_dropout(x, c.dropout, train, rng)
@@ -455,9 +467,14 @@ class MixtureOfExpertsLayerModule(BaseLayerModule):
         _note_experts(E, held, k, (W1.size + W2.size) * W1.dtype.itemsize)
         xt = x.reshape(-1, x.shape[-1])
         with jax.named_scope("moe_route"):
-            at = self.layout(params, xt)
+            at = self.layout(params, xt, None if mask is None
+                             else mask.reshape(xt.shape[0]) != 0)
         with jax.named_scope("moe_dispatch"):
-            rows = jnp.where(at["live"][:, None], xt[at["token"]], 0)
+            # not zeroed where no pair lies: a padding row of a live tile
+            # holds some token's row, and nothing reads its product
+            # (`moe_combine` takes the rows of pairs, both products treat
+            # rows apart, an unread row's cotangent is zero)
+            rows = xt[at["token"]]
         with jax.named_scope("moe_experts"):
             if c.gated:
                 out = expert_gmm(rows, W1, W2, at["tile_group"], at["n_tiles"],

@@ -3,7 +3,8 @@ the `granite_hybrid_lm` that uses it, at tiny sizes on the CPU, seeded weights:
 
 - the layer against the dense all-experts einsum it once was (kept HERE as
   the oracle): k < E and k = E, [b, f] and [b, t, f], both kinds of expert,
-  one expert starved and one taking every token, and its gradient;
+  one expert starved and one taking every token, and its gradient; under
+  a mask the positions kept bit for bit, the others off the layer;
 - `expert_gmm` in interpret mode against its plain form: ragged groups, empty
   ones, row tiles past the last group, several blocks a product;
 - the share ties to the model: four layers holding a quarter of the experts
@@ -151,6 +152,87 @@ def test_layer_sets_its_gauges():
     assert reg.get("moe_experts").get(routed=E, held=4, top_k=3) == 4
     assert reg.get("moe_expert_weight_bytes").get() \
         == 4 * (F * 12 + 6 * F) * 4
+
+
+# ------------------------------------------------------------------ the mask
+def _steered(mod, params, x, case):
+    """`case` steers the router as the test above does: "starved" (expert 2
+    every token's first choice, expert 5 nobody's), "one_takes_all" (the
+    layer holds expert 2 alone), "all_held" (the layer holds every expert:
+    every pair of a token the mask keeps takes a row)."""
+    if case != "all_held":
+        x = x.at[:, 0].set(1.0)
+        params = dict(params, Wg=params["Wg"].at[0, 2].set(50.0)
+                      .at[0, 5].set(-50.0))
+    return params, x
+
+
+MASK_CASES = {"starved": dict(), "one_takes_all": dict(experts_held=1,
+                                                       first_expert=2),
+              "all_held": dict(), "a_share": dict(experts_held=4,
+                                                  first_expert=2)}
+
+
+@pytest.mark.parametrize("how", ["gated", "relu", "gated_kernel"])
+@pytest.mark.parametrize("case", list(MASK_CASES))
+def test_masked_positions_hold_no_pair(case, how):
+    """A prefill's padding takes no row: with a mask the valid positions
+    are `mask=None`'s bit for bit (rows are independent in both products),
+    the others zero, and `live` / `n_tiles` count the valid tokens' held
+    pairs only — what they would be were the padding not there."""
+    mod, params = layer(jnp.bfloat16, gated=how != "relu",
+                        use_pallas=how == "gated_kernel", **MASK_CASES[case])
+    params, x = _steered(mod, params, inputs((96,)).astype(jnp.bfloat16),
+                         case)
+    x, valid = x[None], 61
+    mask = (jnp.arange(96) < valid).astype(jnp.bfloat16)[None]
+    want = mod.forward(params, {}, x)[0]
+    got, _, out_mask = mod.forward(params, {}, x, mask=mask)
+    assert out_mask is mask and got.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(got[0, :valid], np.float32),
+                                  np.asarray(want[0, :valid], np.float32))
+    assert float(jnp.abs(want[0, valid:].astype(jnp.float32)).max()) > 0
+    assert not np.asarray(got[0, valid:], np.float32).any()
+    at = mod.layout(params, x[0], mask[0] != 0)
+    alone = mod.layout(params, x[0, :valid])
+    held_pairs = int(np.asarray(alone["here"]).sum())
+    assert int(np.asarray(at["here"]).sum()) == held_pairs
+    assert int(np.asarray(at["live"]).sum()) == held_pairs
+    assert not np.asarray(at["here"][valid:]).any()
+    # the tile is sized for all 96 positions' pairs, the tiles counted are
+    # those of the 61's: each held expert's pairs among them, rounded up
+    experts, _ = mod.route(params, x[0, :valid])
+    _, held, first, _, _ = mod._sizes()
+    counts = np.bincount((np.asarray(experts) - first)[
+        np.asarray(alone["here"])], minlength=held)
+    assert at["tm"] >= alone["tm"] and int(at["n_tiles"]) == sum(
+        -(-int(c) // at["tm"]) for c in counts)
+    everything = mod.forward(params, {}, x, mask=jnp.ones_like(mask))[0]
+    np.testing.assert_array_equal(np.asarray(everything, np.float32),
+                                  np.asarray(want, np.float32))
+
+
+@pytest.mark.parametrize("how", ["gated", "relu", "gated_kernel"])
+def test_gradient_under_a_mask_is_the_oracles(how):
+    """A masked position takes and gives no gradient, the others the dense
+    oracle's: the rows the dispatch no longer zeroes (padding rows of live
+    tiles hold some token's row) are read by nothing, so their cotangent is
+    zero and no token's gradient sees them."""
+    mod, params = layer(gated=how != "relu", top_k=3,
+                        use_pallas=how == "gated_kernel")
+    x, w = inputs((2, 6)), inputs((2, 6), seed=2)
+    mask = jnp.asarray([[1, 1, 1, 1, 0, 0], [1, 1, 1, 1, 1, 0]], x.dtype)
+    loss = lambda f: lambda p, x: jnp.sum(f(p, x) * w * mask[:, :, None])
+    got = jax.grad(loss(lambda p, x: mod.forward(p, {}, x, mask=mask)[0]),
+                   argnums=(0, 1))(params, x)
+    want = jax.grad(loss(lambda p, x: dense_oracle(mod, p, x)),
+                    argnums=(0, 1))(params, x)
+    tol = 1e-5 if how == "gated_kernel" else 1e-10
+    for g, o in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(g, o, atol=tol)
+    assert not np.asarray(got[1])[mask == 0].any()
+    assert float(jnp.abs(got[0]["Wg"]).max()) > 0
 
 
 # ---------------------------------------------------------------- the kernel
@@ -385,9 +467,9 @@ def test_one_period_output_matches_the_reference_logits(
                          ids=["padded", "bucket", "longer"])
 def test_prefill_then_decode_is_the_full_forward(paged, n_prompt):
     """Three blocks (Mamba-2, attention, Mamba-2), each with routed experts
-    beside the shared one: a prompt padded to its bucket routes its padded
-    rows like any other and throws them away with their positions; every
-    step's row of probabilities is the full forward's."""
+    beside the shared one: a prompt padded to its bucket hands the layer
+    the prefill's mask, so its padded positions hold no pair; every step's
+    row of probabilities is the full forward's."""
     V = 48
     net = granite_hybrid_lm(
         vocab_size=V, d_model=32, n_layers=3, n_heads=4, n_kv_heads=2,

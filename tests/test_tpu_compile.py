@@ -86,6 +86,10 @@ _CALLS = re.compile(r"calls=%?([\w.\-]+)")
 _COMPUTATION = re.compile(r"^(ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
 
 
+def _elements(dims):
+    return int(np.prod([int(d) for d in dims.split(",") if d]))
+
+
 def relayouts(text, elements):
     """Names of the instructions of an optimized HLO text that rewrite an
     array of at least `elements` elements into another layout: a `copy`, a
@@ -105,8 +109,7 @@ def relayouts(text, elements):
         name, dims, op = m.groups()
         if line.lstrip().startswith("ROOT"):
             roots[computation] = op
-        rows.append((name, int(np.prod([int(d) for d in dims.split(",")
-                                        if d])), op, _CALLS.search(line)))
+        rows.append((name, _elements(dims), op, _CALLS.search(line)))
     moved = ("copy", "transpose")
     return [name for name, n, op, calls in rows if n >= elements and (
         op in moved or (op == "fusion" and calls
@@ -121,6 +124,31 @@ def loops(text):
     """The `op_name` of every `while` loop of an optimized HLO text."""
     return [re.search(r'op_name="([^"]*)"', line).group(1)
             for line in text.splitlines() if re.search(r" while\(", line)]
+
+
+def under_scope(text, scope, op, elements):
+    """Names of the instructions of an optimized HLO text of at least
+    `elements` elements whose `op_name` passes through `scope` and ends in
+    `op` (the jax primitive: `select_n`, `gather`, ...; "": any)."""
+    found = []
+    for line in text.splitlines():
+        m, name = _RESULT.match(line), re.search(r'op_name="([^"]*)"', line)
+        if m and name and f"/{scope}/" in name.group(1) \
+                and name.group(1).endswith(op) \
+                and _elements(m.group(2)) >= elements:
+            found.append(m.group(1))
+    return found
+
+
+def operand_copies(text, elements):
+    """The `relayouts` of an expert layer's operand (`elements` of it): what
+    its dispatch or its grouped product copies or transposes, less the
+    `transpose` of dimensions {0,1} XLA fuses INTO the gather, which moves
+    nothing."""
+    ours = set(under_scope(text, "moe_dispatch", "", elements)
+               + under_scope(text, "moe_experts", "", elements))
+    return sorted(set(relayouts(text, elements)) & ours
+                  - set(under_scope(text, "moe_dispatch", "gather", elements)))
 
 
 _CALLEE = re.compile(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)")
@@ -1012,9 +1040,18 @@ def test_routed_decode_step_compiles_with_one_expert_kernel_a_layer(
     assert len(re.findall(r"%expert_gmm_16x1[.\d]* = ", text)) == 2
     assert len(re.findall(r"%ssm_step[.\d]* = ", text)) == 1
     assert loops(text) == []
+    conditionals = text.count(" conditional(")          # the sampler's one
+    assert conditionals == 1
     text = _prefill_text(eng, 128, one_chip)
     assert len(re.findall(r"%expert_gmm_1x128[.\d]* = ", text)) == 2
     assert loops(text) == []
+    # 128 x 4 pairs in tiles of 64 + 4 experts' spare tiles: 768 rows of
+    # 512 — gathered, not zeroed afterwards, not copied
+    rows = 128 * 4 + 4 * 64
+    assert under_scope(text, "moe_dispatch", "select_n", rows * 512) == []
+    assert len(under_scope(text, "moe_dispatch", "gather", rows * 512)) >= 2
+    assert operand_copies(text, rows * 512) == []
+    assert text.count(" conditional(") == conditionals
 
 
 def test_ling_decode_step_compiles_with_its_three_new_kernels(
@@ -1048,7 +1085,7 @@ def test_ling_decode_step_compiles_with_its_three_new_kernels(
     assert len(re.findall(r"%mla_decode[.\d]* = ", text)) == 1
     assert len(re.findall(r"%latent_append[.\d]* = ", text)) == 1
     assert len(re.findall(r"%expert_gmm_16x1[.\d]* = ", text)) == 4
-    assert loops(text) == []
+    assert loops(text) == [] and text.count(" conditional(") == 1
     assert relayouts(text, 16 * 2 * 128 * 128) == []
     assert relayouts(text, 16 * 256 * 640) == []
     text = _prefill_text(eng, 128, one_chip)
@@ -1094,7 +1131,7 @@ def test_solar_decode_step_compiles_with_its_kernels_and_no_copy_of_a_slab(
     assert len(re.findall(r"%kda_step[.\d]* = ", text)) == 3
     assert len(re.findall(r"%expert_gmm_16x1[.\d]* = ", text)) == 4
     assert "%kv_append" not in text
-    assert loops(text) == []
+    assert loops(text) == [] and text.count(" conditional(") == 1
     assert relayouts(text, 16 * 256 * 8 * 128) == []     # a K or V slab
     assert relayouts(text, 16 * 16 * 128 * 128) == []    # a layer's state
     text = _prefill_text(eng, 128, one_chip)
@@ -1145,11 +1182,20 @@ def test_mellum_decode_step_compiles_with_one_kernel_a_layer_and_no_copy(
     assert "%kv_append" not in text
     assert loops(text) == []
     assert relayouts(text, 16 * 1024 * 4 * 128) == []    # a ring
+    conditionals = text.count(" conditional(")          # the sampler's one
+    assert conditionals == 1
     text = _prefill_text(eng, 2048, one_chip)
     assert len(re.findall(r"%flash_fwd[.\d]* = ", text)) == 4
     assert len(re.findall(r"%expert_gmm_1x2048[.\d]* = ", text)) == 4
     assert "%flash_decode" not in text and loops(text) == []
     assert fallbacks.get() == before
+    # an expert layer's operand, 2,048 x 8 pairs in tiles of 512 + 8 spare
+    # tiles = 20,480 rows of 256: one gather, nothing zeroed after it, no
+    # copy of it, and the program branches no more than the step does
+    rows = 2048 * 8 + 8 * 512
+    assert under_scope(text, "moe_dispatch", "select_n", rows * 256) == []
+    assert operand_copies(text, rows * 256) == []
+    assert text.count(" conditional(") == conditionals
 
 
 @pytest.mark.slow
