@@ -240,101 +240,121 @@ def kernel_phase(shape=(4, 4096, 8, 64), seed=0):
          windowed_fwd_max_abs_err=win)
 
 
-def decode_kernel_phase(slots=48, ring=1024, capacity=6144, heads=32,
-                        kv_heads=4, head_dim=128, steps=24, seed=0):
+# (kernel's name, ring?, slots, positions, K/V heads, head_dim): the two kinds
+# of layer of `mellum2_code_decode` and `granite4hmicro_chat_decode`'s
+DECODE_KERNEL_CASES = (("flash_decode_window", True, 48, 1024, 4, 128),
+                       ("flash_decode", False, 48, 6144, 4, 128),
+                       ("flash_decode", False, 64, 1024, 8, 64))
+
+
+def decode_kernel_phase(cases=DECODE_KERNEL_CASES, heads=32, steps=24, seed=0):
     """The decode step's attention kernel on a cache declared in WHOLE TILES
-    (fewer K/V heads of 128 than a tile's 8 sublanes: a token's rows reach
+    (fewer rows of 128 lanes a position than a tile's 8 sublanes — 4 K/V
+    heads of 128, or 8 of 64 PACKED two to a row —: a token's rows reach
     the cache by a read-modify-write of the tile they share with another
     position), compiled, against plain jax.numpy: a sliding window's RING
     (`flash_decode_append(ring=True)`) and a full layer's slab, `steps`
     consecutive steps with the slabs donated from one to the next, as the
     engine runs them. The ring's slots start before, at and several turns
-    past the wrap. A slot's query heads aim, two each of a K/V head's group,
-    at the OLDEST position the window still holds, at the token's tile
-    NEIGHBOUR (position ^ 1), at the token itself, and nowhere: a ring one
-    position short, a neighbour's rows lost in the tile or a token that did
-    not enter moves an output by ~1, which the phase shows by planting the
-    first two in the reference. Both slabs afterwards bit for bit."""
+    past the wrap, a slab's at a tile's first and second position and on
+    both sides of a 256-position block's edge. A slot's query heads aim, of
+    each K/V head's group, at the OLDEST position the window still holds, at
+    the token's tile NEIGHBOUR (position ^ 1), at the token itself, and
+    nowhere: a ring one position short, a neighbour's rows lost in the tile,
+    a head read from the other half of its lane row or a token that did not
+    enter moves an output by ~1, which the phase shows by planting the first
+    three in the reference. Both slabs afterwards bit for bit."""
     import jax
     import jax.numpy as jnp
     from deeplearning4j_tpu.kernels import flash_decode_append
-    from deeplearning4j_tpu.kernels.flash_attention import SUBLANES, tiled_rows
+    from deeplearning4j_tpu.kernels.flash_attention import (LANES, SUBLANES,
+                                                            tiled_rows)
 
-    G, D, S = heads // kv_heads, head_dim, slots
-    scale = float(D) ** -0.5
     rng = np.random.default_rng(seed)
     f32 = lambda a: np.asarray(a.astype(jnp.float32))
 
-    def reference(q, k, v, kn, vn, pos, C, is_ring, short=False,
-                  neighbour_lost=False):
-        """(out, k, v) on the plain [S, C, H, D] view, float32 products."""
-        at = pos % C if is_ring else pos
-        s_ = jnp.arange(S)
-        k, v = k.at[s_, at].set(kn[:, 0]), v.at[s_, at].set(vn[:, 0])
-        live = jnp.minimum(pos + 1, C)
-        valid = jnp.arange(C)[None] < live[:, None]
-        if short:       # the oldest position of a full ring left out
-            oldest = jnp.where(live == C, (at + 1) % C, C)
-            valid &= jnp.arange(C)[None] != oldest[:, None]
-        ka, va = k, v
-        if neighbour_lost:
-            ka, va = (x.at[s_, at ^ 1].set(0) for x in (k, v))
-        qf = q[:, 0].astype(jnp.float32).reshape(S, kv_heads, G, D)
-        with jax.default_matmul_precision("highest"):
-            sc = jnp.einsum("shgd,schd->shgc", qf,
-                            ka.astype(jnp.float32)) * scale
-            sc = jnp.where(valid[:, None, None], sc, -jnp.inf)
-            out = jnp.einsum("shgc,schd->shgd", jax.nn.softmax(sc, axis=-1),
-                             va.astype(jnp.float32))
-        return out.reshape(S, 1, heads, D), k, v
+    for name, is_ring, S, C, kv_heads, D in cases:
+        G, scale = heads // kv_heads, float(D) ** -0.5
+        aim = 2 if G >= 6 else 1    # query heads a target, of a group
 
-    def aimed(k, kn, pos, C, is_ring, noise):
-        """Queries [S, 1, heads, D]: of each K/V head's group two at the
-        oldest live key, two at the tile neighbour (the token itself where
-        that is not live), two at the token, the rest noise."""
-        at = pos % C if is_ring else pos
-        live = jnp.minimum(pos + 1, C)
-        s_ = jnp.arange(S)
-        oldest = jnp.where(is_ring & (live == C), (at + 1) % C, 0)
-        near = jnp.where((at ^ 1) < live, at ^ 1, at)
-        k = k.at[s_, at].set(kn[:, 0])
-        pick = lambda i: k[s_, i].astype(jnp.float32)         # [S, H, D]
-        want = jnp.stack([pick(oldest)] * 2 + [pick(near)] * 2
-                         + [kn[:, 0].astype(jnp.float32)] * 2
-                         + [noise[:, :, j] for j in range(G - 6)], axis=2)
-        return want.reshape(S, 1, heads, D).astype(jnp.bfloat16)
+        def reference(q, k, v, kn, vn, pos, short=False, neighbour_lost=False,
+                      halves_swapped=False):
+            """(out, k, v) on the plain [S, C, H, D] view, float32 products."""
+            at = pos % C if is_ring else pos
+            s_ = jnp.arange(S)
+            k, v = k.at[s_, at].set(kn[:, 0]), v.at[s_, at].set(vn[:, 0])
+            live = jnp.minimum(pos + 1, C)
+            valid = jnp.arange(C)[None] < live[:, None]
+            if short:       # the oldest position of a full ring left out
+                oldest = jnp.where(live == C, (at + 1) % C, C)
+                valid &= jnp.arange(C)[None] != oldest[:, None]
+            ka, va = k, v
+            if neighbour_lost:
+                ka, va = (x.at[s_, at ^ 1].set(0) for x in (k, v))
+            if halves_swapped:      # the head beside it on the lane row
+                other = jnp.arange(kv_heads) ^ 1
+                ka, va = ka[:, :, other], va[:, :, other]
+            qf = q[:, 0].astype(jnp.float32).reshape(S, kv_heads, G, D)
+            with jax.default_matmul_precision("highest"):
+                sc = jnp.einsum("shgd,schd->shgc", qf,
+                                ka.astype(jnp.float32)) * scale
+                sc = jnp.where(valid[:, None, None], sc, -jnp.inf)
+                out = jnp.einsum("shgc,schd->shgd",
+                                 jax.nn.softmax(sc, axis=-1),
+                                 va.astype(jnp.float32))
+            return out.reshape(S, 1, heads, D), k, v
 
-    for is_ring, C, name in ((True, ring, "flash_decode_window"),
-                             (False, capacity, "flash_decode")):
+        def aimed(k, kn, pos, noise):
+            """Queries [S, 1, heads, D]: of each K/V head's group `aim` at
+            the oldest live key, `aim` at the tile neighbour (the token
+            itself where that is not live), `aim` at the token, the rest
+            noise."""
+            at = pos % C if is_ring else pos
+            live = jnp.minimum(pos + 1, C)
+            s_ = jnp.arange(S)
+            oldest = jnp.where(is_ring & (live == C), (at + 1) % C, 0)
+            near = jnp.where((at ^ 1) < live, at ^ 1, at)
+            k = k.at[s_, at].set(kn[:, 0])
+            pick = lambda i: k[s_, i].astype(jnp.float32)     # [S, H, D]
+            want = jnp.stack([pick(oldest)] * aim + [pick(near)] * aim
+                             + [kn[:, 0].astype(jnp.float32)] * aim
+                             + [noise[:, :, j] for j in range(G - 3 * aim)],
+                             axis=2)
+            return want.reshape(S, 1, heads, D).astype(jnp.bfloat16)
+
         tiles = tiled_rows(C, kv_heads, D)
         require(tiles, f"{kv_heads} K/V heads of {D} are not half-tile rows")
-        leaf = (S, tiles, SUBLANES, D)
+        leaf = (S, tiles, SUBLANES, max(D, LANES))
+        packed = D < LANES
         k, v = (jnp.asarray(rng.normal(size=(S, C, kv_heads, D)),
                             jnp.bfloat16) for _ in range(2))
         if is_ring:     # before, at and past the wrap, and many turns on
             edge = [0, 1, C - steps - 1, C - 2, C - 1, C, C + 1, 2 * C - 1,
                     2 * C, 5 * C - 3]
             pos = np.concatenate([edge, rng.integers(0, 6 * C, S)])[:S]
-        else:
-            pos = np.concatenate([[0, 1, C - steps],
-                                  rng.integers(0, C - steps, S)])[:S]
+        else:           # a tile's two positions, a block's edge, the end
+            edge = [0, 1, C - steps, 255, 256, 256 - steps // 2]
+            pos = np.concatenate([edge, rng.integers(0, C - steps, S)])[:S]
         pos = jnp.asarray(pos, jnp.int32)
         step = jax.jit(lambda q, k, v, kn, vn, pos: flash_decode_append(
             q, k, v, kn, vn, pos, ring=is_ring), donate_argnums=(1, 2))
         tk, tv = k.reshape(leaf), v.reshape(leaf)   # the kernel's slabs
-        worst, short, lost, program = 0.0, np.inf, np.inf, None
+        worst, program = 0.0, None
+        short = lost = swapped = np.inf
+        moved = lambda off, want, who: float(np.abs(
+            f32(off) - f32(want))[who].max(axis=(1, 2, 3)).min())
         for _ in range(steps):
             kn, vn = (jnp.asarray(rng.normal(size=(S, 1, kv_heads, D)),
                                   jnp.bfloat16) for _ in range(2))
             noise = jnp.asarray(rng.normal(size=(S, kv_heads, G, D)),
                                 jnp.float32)
-            q = aimed(k, kn, pos, C, is_ring, noise)
+            q = aimed(k, kn, pos, noise)
             if program is None:
                 program = step.lower(q, tk, tv, kn, vn, pos).compile()
                 require_kernels({"pallas_kernels": program.as_text().count(
                     "tpu_custom_call")}, name)
             out, tk, tv = step(q, tk, tv, kn, vn, pos)
-            want, k, v = reference(q, k, v, kn, vn, pos, C, is_ring)
+            want, k, v = reference(q, k, v, kn, vn, pos)
             require(bool(jnp.array_equal(tk.reshape(k.shape), k))
                     and bool(jnp.array_equal(tv.reshape(v.shape), v)),
                     f"{name}: a slab is not the reference's, bit for bit")
@@ -345,26 +365,27 @@ def decode_kernel_phase(slots=48, ring=1024, capacity=6144, heads=32,
             # reference: each must move some output far past the tolerance
             full = np.asarray(jnp.minimum(pos + 1, C) == C)
             if is_ring and full.any():
-                off = reference(q, k, v, kn, vn, pos, C, True, short=True)[0]
-                short = min(short, float(np.abs(
-                    f32(off) - f32(want))[full].max(axis=(1, 2, 3)).min()))
-            off = reference(q, k, v, kn, vn, pos, C, is_ring,
-                            neighbour_lost=True)[0]
+                off = reference(q, k, v, kn, vn, pos, short=True)[0]
+                short = min(short, moved(off, want, full))
+            off = reference(q, k, v, kn, vn, pos, neighbour_lost=True)[0]
             seen = np.asarray((pos % C if is_ring else pos) ^ 1
                               < jnp.minimum(pos + 1, C))
-            lost = min(lost, float(np.abs(
-                f32(off) - f32(want))[seen].max(axis=(1, 2, 3)).min()))
+            lost = min(lost, moved(off, want, seen))
+            if packed:
+                off = reference(q, k, v, kn, vn, pos, halves_swapped=True)[0]
+                swapped = min(swapped, moved(off, want, np.ones(S, bool)))
             pos = pos + 1
         require(worst <= BF16_FWD_TOL, f"{name} off by {worst}")
-        require(lost > 10 * BF16_FWD_TOL and (not is_ring
-                                              or short > 10 * BF16_FWD_TOL),
+        require(min(lost, short, swapped) > 10 * BF16_FWD_TOL,
                 f"{name}: a planted fault moves the output by only "
-                f"{min(lost, short)}")
+                f"{min(lost, short, swapped)}")
         note(phase="decode_kernels", kernel=name, leaf=list(leaf),
              heads=[heads, kv_heads], steps=steps, out_max_abs_err=worst,
              ring_one_short_moves_a_slot_by_at_least=(
                  short if is_ring else None),
-             neighbour_lost_moves_a_slot_by_at_least=lost)
+             neighbour_lost_moves_a_slot_by_at_least=lost,
+             other_half_of_the_lane_row_moves_a_slot_by_at_least=(
+                 swapped if packed else None))
 
 
 # --------------------------------------------------------------------- serve

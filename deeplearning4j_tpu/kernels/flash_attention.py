@@ -72,7 +72,11 @@ copies where the positions-minor cache has it in one lane of 256 tiles.
 Fewer heads of 128 than a tile has sublanes (4: half a tile a position)
 reach it declared in WHOLE TILES (`tiled_rows`: [slots, capacity * H // 8, 8,
 128]); a copy to HBM starts and ends on a tile, so the kernel reads the
-token's tile, replaces its rows and writes the tile back. A sliding window's
+token's tile, replaces its rows and writes the tile back. The two compose:
+narrow heads whose packed rows are 1, 2 or 4 a position (8 heads of 64: 4
+rows, half a tile) are declared packed AND in whole tiles, [slots, capacity *
+rows // 8, 8, 128] — `tiled_rows` packs them first —, and the kernel runs
+with both halves on. A sliding window's
 cache is a RING of the window's positions (`flash_decode_append(ring=True)`,
 `flash_decode_window` in a trace): position p at p % ring, every block read
 once the ring has filled. The training forward takes the window as the
@@ -1331,18 +1335,19 @@ def _rows_block(C, H, D, itemsize, block_k, interpret, tiled=False):
     """Key-block length of the row-major decode kernel, or None => the
     cache is not one it reads (`flash_decode_append` then takes the other
     kernel or the two calls). H and D are a position's ROWS as the cache
-    holds them — K/V heads of head_dim, or, packed (`packed_rows`), rows of
-    128 lanes with several heads side by side. D has to be a multiple of
-    the lanes: the TPU then stores a [S, C, H, D] cache row-major, a
-    position's [H, D] values in whole tiles, and — compiled — H has to fill
-    a tile's 8 sublanes (packed or not: a [.., 8, 128] bfloat16 array lies
-    in (8, 128)(2, 1) tiles), so that the [S, C * H, D] view the kernel
-    copies from is the buffer itself; or, `tiled`, the leaf is DECLARED in
-    whole tiles, [S, C * H // 8, 8, D] with H = 1, 2 or 4 (`tiled_rows`: 8
-    // H positions a tile, and a token is written by reading its tile,
-    replacing its rows and writing the tile back). The block is then a
-    multiple of 128 positions. Interpret mode takes any H and any divisor
-    of the capacity."""
+    holds them — K/V heads of head_dim, or, packed (`packed_rows`, and
+    `tiled_rows` of narrow heads), rows of 128 lanes with several heads
+    side by side. D has to be a multiple of the lanes: the TPU then stores
+    a [S, C, H, D] cache row-major, a position's [H, D] values in whole
+    tiles, and — compiled — H has to fill a tile's 8 sublanes (packed or
+    not: a [.., 8, 128] bfloat16 array lies in (8, 128)(2, 1) tiles), so
+    that the [S, C * H, D] view the kernel copies from is the buffer
+    itself; or, `tiled`, the leaf is DECLARED in whole tiles, [S, C * H //
+    8, 8, D] with H = 1, 2 or 4 (`tiled_rows`: 8 // H positions a tile, and
+    a token is written by reading its tile, replacing its rows and writing
+    the tile back). The block is then a multiple of 128 positions: 256 for
+    every leaf the layer declares over a capacity of 256 or more. Interpret
+    mode takes any H and any divisor of the capacity."""
     if D % LANES or (not interpret and H % SUBLANES
                      and not (tiled and SUBLANES % H == 0)):
         return None
@@ -1353,15 +1358,17 @@ def _rows_block(C, H, D, itemsize, block_k, interpret, tiled=False):
 
 def packed_rows(H, D, shards=1):
     """Rows of 128 lanes a cache position takes when its `H` K/V heads of
-    `D` are PACKED, 128 // D of them side by side on a row — or None where
-    they are not to be: D does not divide the lanes (or fills them), or the
-    rows ONE shard of a model axis of `shards` holds (`_heads_per_shard`)
-    are not whole (8, 128) tiles. A `[S, C, H, D]` cache with D < 128 is stored positions-minor
-    on the TPU; declared `[S, C, packed_rows, 128]` the same bytes are
-    row-major, one tile a position for 16 heads of 64 in float32, which is
-    what `_decode_rows_kernel` reads and writes a token into by one 4 KB
-    copy. `[B, T, H, D] -> [B, T, H * D // 128, 128]` is the packing: a
-    plain reshape."""
+    `D` are PACKED, 128 // D of them side by side on a row, and the leaf is
+    declared `[S, C, packed_rows, 128]` — or None where it is not to be so:
+    D does not divide the lanes (or fills them), or the rows ONE shard of a
+    model axis of `shards` holds (`_heads_per_shard`) are not whole (8, 128)
+    tiles (8 heads of 64, half a tile, are packed by `tiled_rows`, into
+    whole tiles). A `[S, C, H, D]` cache with D < 128 is stored
+    positions-minor on the TPU; declared `[S, C, packed_rows, 128]` the
+    same bytes are row-major, one tile a position for 16 heads of 64 in
+    float32, which is what `_decode_rows_kernel` reads and writes a token
+    into by one 4 KB copy. `[B, T, H, D] -> [B, T, H * D // 128, 128]` is
+    the packing: a plain reshape."""
     if D >= LANES or LANES % D or \
             _heads_per_shard(H, shards) * D % (8 * LANES):
         return None
@@ -1369,15 +1376,21 @@ def packed_rows(H, D, shards=1):
 
 
 def tiled_rows(C, H, D, shards=1):
-    """The leading rows `C * H // 8` of a cache of `C` positions of `H` K/V
-    heads of `D` declared in WHOLE TILES, `[S, C * H // 8, 8, D]` — or None
-    where it is not to be: D is not a multiple of the lanes, H fills a tile
+    """The leading rows `C * R // 8` of a cache of `C` positions of `H` K/V
+    heads of `D` declared in WHOLE TILES, `[S, C * R // 8, 8, W]`, R rows of
+    W lanes a position: the heads themselves where D is a multiple of the
+    lanes (R = H, W = D); heads narrower than the lanes PACKED first, 128 //
+    D side by side (R = H * D // 128, W = 128) — or None where it is not to
+    be: the heads neither are lane rows nor pack into them, R fills a tile
     already or does not divide it, the positions are not whole tiles, or a
     model axis splits the heads (a tile then mixes the shards' rows). Fewer
-    than 8 heads of 128 are half a tile a position or less, and a `[S, C, 4,
-    128]` array is not the `[S, C * 4, 128]` buffer the row-major kernel
-    copies from; declared so, it is (row (c % 2) * 4 + h of tile c // 2 is
-    head h of position c: a plain reshape of `[B, T, H, D]`)."""
+    than 8 rows are half a tile a position or less, and a `[S, C, 4, 128]`
+    array is not the `[S, C * 4, 128]` buffer the row-major kernel copies
+    from; declared so, it is (row (c % 2) * 4 + r of tile c // 2 is row r of
+    position c: a plain reshape of `[B, T, H, D]`). 4 heads of 128 and 8
+    heads of 64 are the same leaf, `[S, C // 2, 8, 128]`."""
+    if D < LANES and LANES % D == 0 and H * D % LANES == 0:
+        H, D = H * D // LANES, LANES    # narrow heads: packed rows first
     if D % LANES or H >= SUBLANES or SUBLANES % H or shards != 1 \
             or C * H % SUBLANES:
         return None
@@ -1451,7 +1464,9 @@ def _decode_rows_kernel(len_ref, q_ref, kx_ref, vx_ref, kn_ref, vn_ref, k_hbm,
     (`kn_ref` / `vn_ref` repeated to 8 rows, [1, 8, D]) replace theirs
     there, and the tile is written back; the write is waited for a slot
     later (the last slot's at the end), so it rides under that slot's
-    blocks. The other positions of the tile are rewritten as read.
+    blocks. The other positions of the tile are rewritten as read. The rows
+    may be packed ones as well (8 heads of 64: `heads` 4, `pack` 2): the
+    two are independent, one about a row's lanes, one about a tile's rows.
 
     `ring`: the cache is a RING of C positions (a sliding window's): the
     token at position length - 1 is written at (length - 1) % C and the slot
@@ -1591,8 +1606,8 @@ def _decode_rows_call(q, k, v, k_new, v_new, lengths, scale, block_c,
                       interpret, ring=False):
     """`_decode_append_call` for a row-major cache: q [S, 1, Hq, D], k/v [S,
     C, H, W] — W = D, or packed (`packed_rows`) H * W = K/V heads * D with W
-    = 128 —, or, H of 1, 2 or 4 (`tiled_rows`), [S, C * H // 8, 8, W];
-    k_new/v_new [S, 1, H, W], lengths [S] (the appended token counted) ->
+    = 128 —, or, H of 1, 2 or 4 (`tiled_rows`: packed or not), [S, C * H //
+    8, 8, W]; k_new/v_new [S, 1, H, W], lengths [S] (the token counted) ->
     (out [S, 1, Hq, D], k, v). The kernel's view of a cache is [S, C * H,
     W]: the same tiles in the same order, so the reshape is a bitcast
     (tests/test_tpu_compile.py holds it to that) and the two slab outputs
@@ -1667,12 +1682,13 @@ def flash_decode_append(q, k, v, k_new, v_new, pos, *, scale=None,
     q: [slots, 1, heads, head_dim]; k, v: [slots, capacity, kv_heads,
     head_dim] — the cache, in place when donated — or PACKED, [slots,
     capacity, kv_heads * head_dim // 128, 128] (`packed_rows`), or in WHOLE
-    TILES, [slots, capacity * kv_heads // 8, 8, head_dim] (`tiled_rows`);
-    either is recognised by its shape against the token's; k_new, v_new:
-    [slots, 1, kv_heads, head_dim] — the token, in the cache's dtype; pos:
-    [slots] int32 — where each slot appends, inside [0, capacity): the slot
-    then holds pos + 1 tokens, so `flash_decode`'s length-0 contract does
-    not exist here.
+    TILES, [slots, capacity * rows // 8, 8, lanes] (`tiled_rows`: rows x
+    lanes a position are kv_heads x head_dim or, heads narrower than 128
+    packed as well, kv_heads * head_dim // 128 x 128); each is recognised
+    by its shape against the token's; k_new, v_new: [slots, 1, kv_heads,
+    head_dim] — the token, in the cache's dtype; pos: [slots] int32 — where
+    each slot appends, inside [0, capacity): the slot then holds pos + 1
+    tokens, so `flash_decode`'s length-0 contract does not exist here.
     Returns (out, k, v): the cache `kv_append` leaves and, on it, the rows
     `flash_decode(q, k, v, pos + 1)` gives, both bit for bit.
 
@@ -1690,9 +1706,10 @@ def flash_decode_append(q, k, v, k_new, v_new, pos, *, scale=None,
     block's arithmetic. The cache is written exactly where `kv_append`
     writes it; the append's own read of that tile, its launch and its grid
     are gone. A cache that is stored row-major — head_dim a multiple of 128,
-    or packed — takes the kernel that reads it so (`_decode_rows_call`,
-    `_rows_block`: the same contract, the output to float32 rounding — its
-    products run on the MXU in another order — and both slabs bit for bit).
+    packed, or in whole tiles — takes the kernel that reads it so
+    (`_decode_rows_call`, `_rows_block`: the same contract, the output to
+    float32 rounding — its products run on the MXU in another order — and
+    both slabs bit for bit).
     Gives way to the two calls, counted in
     `pallas_fallback_total{kernel="flash_decode",
     path="kv_append+flash_decode"}`, wherever neither kernel takes these
@@ -1709,8 +1726,10 @@ def flash_decode_append(q, k, v, k_new, v_new, pos, *, scale=None,
     R = H * D // W                      # rows a position
     C = k.shape[1] * k.shape[2] // R
     plain = k.shape[2:] == (H, D)
-    assert plain or k.shape[2:] == (R, LANES) \
-        or (D == W and k.shape[2] == SUBLANES), \
+    # fewer rows a position than a tile's, declared in whole tiles
+    tiled = not plain and R < SUBLANES == k.shape[2]
+    assert plain or (W in (D, LANES) and R * W == H * D
+                     and (tiled or k.shape[2] == R)), \
         f"a cache of {k.shape} for {H} K/V heads of {D}"
     if scale is None:
         scale = float(1.0 / (D ** 0.5))
@@ -1727,7 +1746,7 @@ def flash_decode_append(q, k, v, k_new, v_new, pos, *, scale=None,
         # the leaf to be declared in whole tiles
         block_c, rows = _rows_block(
             C, _heads_per_shard(R), W, size, block_k, interpret,
-            tiled=D == W and not plain), True
+            tiled=tiled), True
     if block_c is not None:
         _note_decode_block(block_c, C=C, H=H, D=D, itemsize=size)
         k_new, v_new = (x.reshape(S, 1, R, W) for x in (k_new, v_new))

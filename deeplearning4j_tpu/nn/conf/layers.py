@@ -374,8 +374,9 @@ class SelfAttentionLayer(BaseRecurrentConf):
     as `rope_yarn`) and a sliding `window`. Causal layers decode from a K/V
     cache [slots, capacity, kv_heads, head_dim] — a windowed one from a ring
     of `window` positions —: one kernel a step whichever way the TPU stores
-    it (positions-minor under head_dim 128, row-major from there:
-    nn/layers/recurrent.py)."""
+    it (row-major from head_dim 128 on and, with `use_pallas`, wherever
+    narrower heads pack into whole tiles; positions-minor elsewhere:
+    nn/layers/recurrent.py `decode_entry`)."""
     n_heads: int = 4
     causal: bool = False
     block_size: int = 256

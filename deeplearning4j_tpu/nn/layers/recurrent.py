@@ -358,9 +358,10 @@ class SelfAttentionLayerModule(BaseLayerModule):
     has overwritten cannot be rewound, so a windowed layer is not
     `decode_rewindable`. Paged, it keeps the shared table's every block and
     masks (`flash_decode_paged(window=)`). Fewer than 8 K/V heads of a
-    multiple of 128 are declared in whole (8, 128) tiles
-    (`kernels.flash_attention.tiled_rows`), which is what lets the row-major
-    kernel take them."""
+    multiple of 128 — or narrower heads that pack to fewer than 8 rows of
+    128 lanes a position, 8 heads of 64 — are declared in whole (8, 128)
+    tiles (`kernels.flash_attention.tiled_rows`), which is what lets the
+    row-major kernel take them."""
 
     def init(self, rng, input_type, dtype=jnp.float32):
         c = self.conf
@@ -503,12 +504,13 @@ class SelfAttentionLayerModule(BaseLayerModule):
         C = min(self.window, geom.capacity) if ring else geom.capacity
         # a slab the step's kernel reads: heads narrower than a lane row
         # packed side by side where one shard's then fill whole tiles, fewer
-        # heads of whole lane rows than a tile's 8 declared in whole tiles
+        # lane rows a position than a tile's 8 (heads of whole rows, or
+        # narrower ones packed) declared in whole tiles
         kernels = getattr(self.conf, "use_pallas", False)
         rows = kernels and packed_rows(H, Dh, geom.model_shards)
         tiles = kernels and tiled_rows(C, H, Dh, geom.model_shards)
         shape = (geom.slots, *((C, rows, LANES) if rows else
-                               (tiles, SUBLANES, Dh) if tiles
+                               (tiles, SUBLANES, max(Dh, LANES)) if tiles
                                else (C, H, Dh)))
         return note_cache_entry(geom, "window" if ring else "kv",
                                 self._leaves(shape, geom))
@@ -527,7 +529,8 @@ class SelfAttentionLayerModule(BaseLayerModule):
     def _as_stored(t, leaf):
         """A sequence's K or V [b, t, H, Dh] in the leaf's dtype and in the
         shape of its rows (packed: `[b, t, H Dh / 128, 128]`; in whole tiles:
-        `[b, t H / 8, 8, Dh]`: a plain reshape either way)."""
+        `[b, t H / 8, 8, Dh]`, or, packed as well, `[b, t H Dh / 1024, 8,
+        128]`: a plain reshape every way)."""
         return t.astype(leaf.dtype).reshape(t.shape[0], -1, *leaf.shape[2:])
 
     def _as_ring(self, t, length, ring):
@@ -602,17 +605,17 @@ class SelfAttentionLayerModule(BaseLayerModule):
         z = jnp.zeros((), slot.dtype)
 
         def write(leaf, t):
-            tiled = leaf.shape[2:] != t.shape[2:] \
-                and leaf.shape[3] == t.shape[3]
-            if not tiled:       # a position an index: plain, or packed
+            if leaf.shape[1] == self._positions(leaf):
+                # a position an index: plain, or packed
                 return lax.dynamic_update_slice(
                     leaf, self._as_stored(t, leaf), (slot, start, z, z))
             # whole tiles: a window may start inside one, so by the rows
-            H = t.shape[2]
-            rows = leaf.reshape(leaf.shape[0], -1, leaf.shape[3])
+            lanes = leaf.shape[3]
+            R = t.shape[2] * t.shape[3] // lanes    # rows a position
+            rows = leaf.reshape(leaf.shape[0], -1, lanes)
             return lax.dynamic_update_slice(
-                rows, t.astype(leaf.dtype).reshape(1, W * H, -1),
-                (slot, start * H, z)).reshape(leaf.shape)
+                rows, t.astype(leaf.dtype).reshape(1, W * R, lanes),
+                (slot, start * R, z)).reshape(leaf.shape)
         nk, nv = write(entry["k"], k), write(entry["v"], v)
         # the one slot's row, as heads (a packed or tiled row unpacked)
         krow, vrow = (

@@ -312,13 +312,14 @@ def test_the_engine_names_no_layer_class():
 
 
 # -- the K/V leaf is declared from what the engine observes (PR 44) ----------
-def _packing_net(use_pallas, seed=7):
+def _packing_net(use_pallas, seed=7, **heads):
     """One causal attention layer with `opt350m`'s heads (16 of 64: 8 rows of
-    128 lanes a position) on an 8-wide stream."""
+    128 lanes a position), or `heads`, on an 8-wide stream."""
     from deeplearning4j_tpu.nn.conf.layers import SelfAttentionLayer
-    return _net(SelfAttentionLayer(n_out=F, n_heads=16, head_dim=64,
-                                   causal=True, use_pallas=use_pallas,
-                                   activation="identity"), seed=seed)
+    heads = {"n_heads": 16, **heads}
+    return _net(SelfAttentionLayer(n_out=F, head_dim=64, causal=True,
+                                   use_pallas=use_pallas,
+                                   activation="identity", **heads), seed=seed)
 
 
 def _through_every_leg(eng):
@@ -372,3 +373,57 @@ def test_the_kv_leaf_packs_where_one_shards_heads_fill_whole_tiles(how, leaf):
     want_toks, want_rows = _through_every_leg(want)
     assert toks == want_toks
     np.testing.assert_allclose(rows, want_rows, rtol=1e-4, atol=1e-6)
+
+
+@pytest.mark.parametrize("kv,how,leaf", [
+    (8, {}, (2, 16, 8, 128)),               # packed, two positions a tile
+    (4, {}, (2, 8, 8, 128)),                # 2 rows a position: four
+    (2, {}, (2, 4, 8, 128)),                # 1 row: eight
+    (8, {"mesh": 4}, (2, 32, 8, 64)),       # a tile would mix shards' rows
+    (8, {"paged": True, "block_size": 8}, (9, 8, 8, 64)),
+    (8, {"use_pallas": False}, (2, 32, 8, 64)),
+], ids=["8_heads", "4_heads", "2_heads", "mesh_1x4", "paged", "no_kernel"])
+def test_the_kv_leaf_packs_into_whole_tiles_where_rows_are_half_a_tile(
+        kv, how, leaf):
+    """32 query heads on 8 K/V heads of 64 (`granite4_h_micro`'s layer) are
+    4 rows of 128 lanes a position, half a tile: the slab leaf is declared
+    packed AND in whole tiles, `[slots, capacity * 4 / 8, 8, 128]`, where
+    the step's kernel reads it and no model axis splits the heads — plain
+    under a mesh, paged, or without the kernel. Whatever the leaf, the
+    engine gives what the plain engine gives, token for token and row for
+    row, through prefill, step, rollback and `verify` (whose window starts
+    inside a tile: position 7)."""
+    how = dict(how)
+    heads = dict(n_heads=32, n_kv_heads=kv)
+    net = _packing_net(how.pop("use_pallas", True), **heads)
+    if "mesh" in how:
+        net = MeshContext({"n_data": 1, "n_model": how.pop("mesh")},
+                          devices=jax.devices()[:4]).wrap(net)
+    eng = DecodeEngine(net, slots=2, max_len=32, **how)
+    assert eng._entries["1"]["k"].shape == eng._entries["1"]["v"].shape == leaf
+    assert eng.init_cache()["layers"]["1"]["k"].shape == leaf
+    want = DecodeEngine(_packing_net(False, **heads), slots=2, max_len=32)
+    assert eng.cache_bytes() == want.cache_bytes() or eng.paged
+    if eng.paged:
+        assert eng.generate([3, 1, 4, 1, 5, 9], 8) == \
+            want.generate([3, 1, 4, 1, 5, 9], 8)
+        return
+    toks, rows = _through_every_leg(eng)
+    want_toks, want_rows = _through_every_leg(want)
+    assert toks == want_toks
+    np.testing.assert_allclose(rows, want_rows, rtol=1e-4, atol=1e-6)
+
+
+def test_a_windows_ring_of_half_tile_packed_rows_decodes_as_the_plain_one():
+    """A sliding window over 8 K/V heads of 64: the ring (8 positions) is
+    declared packed in whole tiles too, a prompt longer than the ring is
+    cut and turned into it by a plain reshape, and the tokens are the plain
+    engine's well past the wrap."""
+    heads = dict(n_heads=32, n_kv_heads=8, window=8)
+    eng = DecodeEngine(_packing_net(True, **heads), slots=2, max_len=64)
+    assert eng._entries["1"]["k"].shape == (2, 4, 8, 128)
+    want = DecodeEngine(_packing_net(False, **heads), slots=2, max_len=64)
+    assert want._entries["1"]["k"].shape == (2, 8, 8, 64)
+    for prompt in ([3, 1, 4], list(range(1, 12)), list(range(20))):
+        assert eng.generate(prompt, 14) == want.generate(prompt, 14)
+

@@ -11,8 +11,10 @@ and full slots in one call. Shapes that do not tile take the two calls and
 count in `pallas_fallback_total{kernel="flash_decode"}`. A PACKED cache
 (`packed_rows`: heads of 64 two to a 128-lane row, since PR 44) takes the
 row-major kernel: the slabs bit for bit after unpacking, the rows to float32
-rounding. The compiled kernel — one launch, in place — is in
-tests/test_tpu_compile.py."""
+rounding. Packed rows that are half a tile a position or less (8, 4 or 2
+heads of 64: `tiled_rows`, since PR 49) are declared in whole tiles as well
+and take the same kernel with both of its halves on. The compiled kernel —
+one launch, in place — is in tests/test_tpu_compile.py."""
 import importlib
 
 import numpy as np
@@ -238,7 +240,8 @@ def test_packed_call_is_one_kernel_named_flash_decode():
 
 @pytest.mark.parametrize("H,D,shards,rows", [
     (16, 64, 1, 8), (32, 64, 2, 16), (32, 32, 1, 8), (64, 16, 1, 8),
-    (8, 64, 1, None),       # granite4_h_micro: 4 rows, half a tile
+    (8, 64, 1, None),       # granite4_h_micro: 4 rows, half a tile: not
+                            # this leaf but `tiled_rows`' (below)
     (16, 64, 4, None),      # opt350m on a 1 x 4 mesh: 2 rows a shard
     (16, 64, 2, None),      # ... and on two model shards: 4
     (16, 64, 3, 8),         # an axis that does not divide the heads: whole
@@ -249,10 +252,131 @@ def test_packed_rows_asks_for_whole_tiles_in_the_shard(H, D, shards, rows):
     assert fa.packed_rows(H, D, shards) == rows
 
 
+# ---------------------------------- packed AND in whole tiles: the two compose
+@pytest.mark.parametrize("C_,H,D,shards,tiles", [
+    (1024, 8, 64, 1, 512),      # granite4_h_micro: 4 rows, two positions a tile
+    (1024, 4, 64, 1, 256),      # 2 rows: four positions a tile
+    (1024, 2, 64, 1, 128),      # 1 row: eight
+    (1024, 8, 32, 1, 256),      # four heads to a row
+    (1024, 16, 64, 1, None),    # whole tiles a position: `packed_rows`' leaf
+    (1024, 8, 64, 2, None),     # a model axis: a tile would mix shards' rows
+    (1024, 16, 64, 4, None),    # opt350m on a 1 x 4 mesh stays unpacked
+    (1024, 6, 64, 1, None),     # 3 rows divide no tile
+    (1024, 1, 64, 1, None),     # half a lane row
+    (1024, 8, 96, 1, None),     # does not divide the lanes
+    (1023, 8, 64, 1, None),     # an odd count of positions: half a tile over
+    (1022, 8, 64, 1, 511), (1022, 4, 64, 1, None),
+], ids=str)
+def test_tiled_rows_packs_narrow_heads_first(C_, H, D, shards, tiles):
+    assert fa.tiled_rows(C_, H, D, shards) == tiles
+    # one leaf a cache: never both
+    assert not (tiles and fa.packed_rows(H, D, shards))
+
+
+def composite(x):
+    """[S, T, H, D] -> [S, T * H * D // 1024, 8, 128]: packed, whole tiles."""
+    return x.reshape(x.shape[0], -1, 8, 128)
+
+
+COMPOSITE_POSITIONS = {
+    "length_1": [0] * SLOTS,
+    "a_tiles_two_positions": [6, 7, 300, 301],
+    "a_blocks_edge": [255, 256, 254, 257],
+    "capacity": [C - 1, C - 2, 0, C - 1],
+}
+
+
+@pytest.mark.parametrize("positions", COMPOSITE_POSITIONS)
+@pytest.mark.parametrize("ring", [False, True], ids=["slab", "ring"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=lambda d: jnp.dtype(d).name)
+@pytest.mark.parametrize("H", [8, 4, 2], ids=lambda h: f"32_on_{h}")
+def test_packed_cache_in_whole_tiles_matches_append_then_decode(
+        H, dtype, ring, positions):
+    """32 query heads on 8 K/V heads of 64 (`granite4_h_micro`'s layer; 4
+    and 2 heads: 2 rows and 1 row a position), the leaf packed and declared
+    in whole tiles, `[S, C * rows / 8, 8, 128]`: `flash_decode_append` knows
+    it by its shape against the token's and runs the row-major kernel with
+    `pack` and the staged tile write both on — both slabs bit for bit
+    `kv_append`'s / the plain update's after unpacking, the output against
+    the masked row at the file's tolerance, nothing counted as a fallback.
+    A ring (`ring=True`) two turns on writes at pos % C and reads all of
+    it."""
+    from deeplearning4j_tpu.telemetry.registry import get_registry
+    shape = (SLOTS, CAPACITY, H, 64)
+    q, k, v, k_new, v_new = operands(dtype, 32, seed=6, shape=shape)
+    pos = jnp.asarray(COMPOSITE_POSITIONS[positions], jnp.int32)
+    if ring:        # the same places, a turn or two on for some
+        pos = pos + jnp.asarray([0, C, 2 * C, 0], jnp.int32)
+    at = pos % C
+    size = jnp.dtype(dtype).itemsize
+    assert fa.tiled_rows(C, H, 64) == C * H // 16
+    assert fa._rows_block(C, H // 2, 128, size, BLOCK, True, tiled=True) \
+        == BLOCK
+    fallbacks = get_registry().counter("pallas_fallback_total", "")
+    before = fallbacks.get()
+    got, got_k, got_v = fused(q, composite(k), composite(v), k_new, v_new,
+                              pos, ring=ring)
+    assert fallbacks.get() == before
+    assert got.shape == q.shape and got.dtype == q.dtype
+    assert got_k.shape == got_v.shape == (SLOTS, C * H // 16, 8, 128)
+    want_k, want_v = fa.kv_append(k, v, k_new, v_new, at)
+    ref_k, ref_v = fa._append_reference(k, v, k_new, v_new, at)
+    for g, w, r in ((got_k, want_k, ref_k), (got_v, want_v, ref_v)):
+        np.testing.assert_array_equal(bits(g.reshape(w.shape)), bits(w))
+        np.testing.assert_array_equal(bits(g.reshape(r.shape)), bits(r))
+    ref = fa._decode_reference(q, ref_k, ref_v, jnp.minimum(pos + 1, C),
+                               64 ** -0.5)
+    tol = 2e-6 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(ref, np.float32), rtol=tol,
+                               atol=tol)
+    # the two references on the composite leaf: unpacked, packed again
+    off, off_k, off_v = fused(q, composite(k), composite(v), k_new, v_new,
+                              pos, ring=ring, use_pallas=False)
+    np.testing.assert_array_equal(bits(off_k), bits(got_k))
+    np.testing.assert_array_equal(bits(off_v), bits(got_v))
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(off, np.float32), rtol=tol,
+                               atol=tol)
+
+
+def test_composite_call_is_one_kernel_and_touches_nothing_else():
+    q, k, v, k_new, v_new = operands(jnp.bfloat16, 32, seed=7,
+                                     shape=(SLOTS, CAPACITY, 8, 64))
+    at = np.asarray(POSITIONS["short_and_full"])
+    pos = jnp.asarray(at, jnp.int32)
+    jaxpr = str(jax.make_jaxpr(fa.flash_decode_append)(
+        q, composite(k), composite(v), k_new, v_new, pos))
+    assert jaxpr.count("pallas_call") == 1
+    assert "flash_decode" in jaxpr and "kv_append" not in jaxpr
+    assert "transpose" not in jaxpr
+    _, got_k, got_v = fused(q, composite(k), composite(v), k_new, v_new, pos)
+    untouched = np.ones(k.shape, bool)
+    untouched[np.arange(SLOTS), at] = False
+    for got, old, new in ((got_k, k, k_new), (got_v, v, v_new)):
+        got = bits(got.reshape(old.shape))
+        np.testing.assert_array_equal(got[np.arange(SLOTS), at],
+                                      bits(new)[:, 0])
+        np.testing.assert_array_equal(got[untouched], bits(old)[untouched])
+
+
+def test_a_leaf_that_is_no_view_of_the_tokens_heads_is_refused():
+    q, k, v, k_new, v_new = operands(jnp.float32, 32, seed=8,
+                                     shape=(2, 256, 8, 64))
+    pos = jnp.zeros((2,), jnp.int32)
+    for wrong in ((2, 256, 2, 256), (2, 128, 16, 64), (2, 512, 4, 64)):
+        with pytest.raises(AssertionError, match="a cache of"):
+            fa.flash_decode_append(q, k.reshape(wrong), v.reshape(wrong),
+                                   k_new, v_new, pos)
+
+
 def test_rows_that_do_not_fill_a_tile_take_the_kernel_they_took():
-    """8 K/V heads of 64 (`granite4_h_micro`'s layer) are 4 rows a position:
-    not packed, the positions-minor kernel as before, its block the one it
-    chose before, nothing new counted."""
+    """8 K/V heads of 64 on a PLAIN leaf (what a model shard of a mesh, or a
+    caller that declares nothing, still hands over: the layer itself has
+    declared `granite4_h_micro`'s packed in whole tiles since PR 49): the
+    positions-minor kernel as before, its block the one it chose before,
+    nothing new counted."""
     from deeplearning4j_tpu.telemetry.registry import get_registry
     shape = (2, 512, 8, 64)
     q, k, v, k_new, v_new = operands(jnp.bfloat16, 32, seed=5, shape=shape)

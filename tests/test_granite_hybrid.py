@@ -174,6 +174,34 @@ def test_prefill_then_decode_is_the_full_forward(paged, n_prompt):
     assert eng.executable_counts()["decode_step"] == 1
 
 
+@pytest.mark.parametrize("kv", [2, 4], ids=["1_row", "2_rows"])
+def test_heads_of_64_decode_from_a_packed_leaf_in_whole_tiles(kv):
+    """The attention block as `granite4_h_micro` has it — heads of 64,
+    `use_pallas` — with 2 and 4 K/V heads: 1 and 2 rows of 128 lanes a
+    position, so the slab leaf is declared packed in whole tiles
+    (`tiled_rows`; the cell's 8 heads are 4 rows: tests/
+    test_decode_contract.py) and prefill + steps give the full forward's
+    rows and the plain-leaf engine's tokens."""
+    over = dict(d_model=256, n_kv_heads=kv, attention_multiplier=0.125)
+    net = tiny(use_pallas=True, **over)
+    eng = DecodeEngine(net, slots=2, max_len=32)
+    assert eng._entries["b1_attn"]["k"].shape == (2, 32 * kv // 16, 8, 128)
+    prompt = list(np.random.RandomState(kv).randint(0, V, 11))
+    cache, nid, probs = eng.prefill(eng.init_cache(), 1, prompt)
+    got, rows = [nid], [probs]
+    ids = np.zeros((2,), np.int32)
+    for _ in range(5):
+        ids[1] = got[-1]
+        cache, nxt, probs = eng.step(cache, ids)
+        got.append(int(nxt[1]))
+        rows.append(probs[1])
+    want = full_forward_rows(net, prompt + got[:-1], len(prompt))
+    np.testing.assert_allclose(np.stack(rows), want, rtol=2e-4, atol=1e-6)
+    plain = DecodeEngine(tiny(**over), slots=2, max_len=32)
+    assert plain._entries["b1_attn"]["k"].shape == (2, 32, kv, 64)
+    assert got == plain.generate(prompt, 6)
+
+
 def test_both_kinds_of_entry_live_side_by_side_and_do_not_rewind():
     eng = DecodeEngine(tiny(), slots=2, max_len=32)
     cache = eng.init_cache()

@@ -171,14 +171,17 @@ def test_half_tile_rows_are_the_two_calls_they_replace(Hq, H):
 
 
 def test_which_leaves_are_declared_in_whole_tiles():
-    """4 K/V heads of 128 are two positions a tile; 8 heads fill one, heads
-    of 64 pack instead, 3 heads divide no tile, a model axis splits heads a
-    tile would mix, and an odd count of positions leaves half a tile."""
+    """4 K/V heads of 128 are two positions a tile; 8 heads fill one, 4
+    heads of 64 pack to 2 rows and go four positions a tile (since PR 49;
+    16 of them fill tiles packed and stay `packed_rows`'), 3 heads divide
+    no tile, a model axis splits heads a tile would mix, and an odd count of
+    positions leaves half a tile."""
     assert fa.tiled_rows(1024, 4, 128) == 512
     assert fa.tiled_rows(6144, 4, 128) == 3072
     assert fa.tiled_rows(1024, 1, 256) == 128
     assert fa.tiled_rows(1024, 8, 128) is None
-    assert fa.tiled_rows(1024, 4, 64) is None
+    assert fa.tiled_rows(1024, 4, 64) == 256
+    assert fa.tiled_rows(1024, 16, 64) is None
     assert fa.tiled_rows(1024, 3, 128) is None
     assert fa.tiled_rows(1024, 4, 128, shards=2) is None
     assert fa.tiled_rows(1023, 4, 128) is None

@@ -456,10 +456,13 @@ def test_flash_decode_append_on_a_packed_cache_is_one_kernel_in_place(
                      r"\{2\}: \(7, \{\}\)\}", text)
 
 
-@pytest.mark.parametrize("C,ring", [(1024, True), (6144, False)],
-                         ids=["ring_of_1024", "slab_of_6144"])
+@pytest.mark.parametrize("S,C,H,D,ring", [
+    (48, 1024, 4, 128, True), (48, 6144, 4, 128, False),
+    (64, 1024, 8, 64, False), (64, 1024, 8, 64, True),
+], ids=["ring_of_1024", "slab_of_6144", "packed_slab_of_1024",
+        "packed_ring_of_1024"])
 def test_flash_decode_append_on_half_tile_rows_is_one_kernel_in_place(
-        on_chip, C, ring):
+        on_chip, S, C, H, D, ring):
     """`mellum2_code_decode`'s two kinds of layer (PR 47): 32 query heads on
     4 K/V heads of 128, bfloat16, 48 slots — half an (8, 128) tile a
     position, so the leaf is declared in whole tiles, [48, C * 4 / 8, 8, 128]
@@ -471,22 +474,33 @@ def test_flash_decode_append_on_half_tile_rows_is_one_kernel_in_place(
     `flash_decode_window`, a full layer's slab of 6,144 `flash_decode`: ONE
     kernel either way, its two slab outputs aliased onto the donated caches
     (operands 6 and 7), no copy, transpose or update of a slab, no loop over
-    the slots, no counted fallback, a 256-position block."""
+    the slots, no counted fallback, a 256-position block.
+
+    `granite4hmicro_chat_decode`'s layer (PR 49): 32 query heads on 8 K/V
+    heads of 64, bfloat16, 64 slots of 1,024 — packed two to a row they are
+    the same 4 rows a position, the leaf [64, 512, 8, 128], the view [64,
+    4096, 128], and everything above holds of it (and of a ring of it)."""
     from deeplearning4j_tpu.telemetry.registry import get_registry
-    S, Hq, H, D = 48, 32, 4, 128
+    Hq, R, W = 32, H * D // 128, 128
     tiles = fa.tiled_rows(C, H, D)
-    assert tiles == C // 2 \
-        and fa._rows_block(C, H, D, 2, 1024, False, tiled=True) == 256
+    assert tiles == C // 2 and R == 4 \
+        and fa._rows_block(C, R, W, 2, 1024, False, tiled=True) == 256
     fallbacks = get_registry().counter("pallas_fallback_total", "")
     before = fallbacks.get()
-    kv = on_chip((S, tiles, 8, D), jnp.bfloat16)
+    kv = on_chip((S, tiles, 8, W), jnp.bfloat16)
     comp = compiled_step_layer(on_chip((S, 1, Hq, D), jnp.bfloat16), kv,
                                on_chip((S, 1, H, D), jnp.bfloat16),
                                on_chip((S,), jnp.int32), interpret=False,
                                ring=ring)
     assert fallbacks.get() == before
+    assert get_registry().get("flash_decode_block").get(
+        C=C, H=H, D=D, itemsize=2) == 256
     text = comp.as_text()
-    assert len(re.findall(rf"bf16\[48,{C * H},128\]\S* bitcast\(", text)) == 2
+    stored = re.findall(
+        rf"bf16\[{S},{tiles},8,128\](\{{[^}}]*\}}) parameter\(", text)
+    assert stored == ["{3,2,1,0:T(8,128)(2,1)}"] * 2
+    assert len(re.findall(rf"bf16\[{S},{C * R},128\]\S* bitcast\(",
+                          text)) == 2
     assert text.count(KERNEL) == 1
     name = "flash_decode_window" if ring else "flash_decode"
     assert len(re.findall(rf"%{name}[.\d]* = ", text)) == 1
@@ -499,6 +513,38 @@ def test_flash_decode_append_on_half_tile_rows_is_one_kernel_in_place(
     assert mem.alias_size_in_bytes == 2 * S * C * H * D * 2
     assert re.search(r"output_to_operand_aliasing=\{\{1\}: \(6, \{\}\), "
                      r"\{2\}: \(7, \{\}\)\}", text)
+
+
+@pytest.mark.parametrize("how,leaf", [
+    (dict(n_heads=16), (48, 1024, 8, 128)),     # opt350m: packed
+    (dict(n_heads=32, n_kv_heads=8, head_dim=128),
+     (32, 1024, 8, 128)),                       # granite4_h_small, solar_open2
+    (dict(n_heads=32, n_kv_heads=4, head_dim=128),
+     (48, 512, 8, 128)),                        # mellum2's full layers (slab)
+    (dict(n_heads=32, n_kv_heads=4, head_dim=128, window=256),
+     (48, 128, 8, 128)),                        # ... and a window's ring
+    (dict(n_heads=32, n_kv_heads=8), (64, 512, 8, 128)),  # granite4_h_micro
+    (dict(n_heads=16, shards=4), (48, 1024, 16, 64)),     # opt350m, mesh of 4
+    (dict(n_heads=32, n_kv_heads=8, shards=2), (64, 1024, 8, 64)),
+    (dict(n_heads=32, n_kv_heads=8, use_pallas=False), (64, 1024, 8, 64)),
+], ids=["opt350m", "granite4_h_small", "mellum2_slab", "mellum2_ring",
+        "granite4_h_micro", "opt350m_mesh4", "granite4_h_micro_mesh2",
+        "granite4_h_micro_no_kernel"])
+def test_which_leaf_a_configurations_attention_layer_declares(how, leaf):
+    """`SelfAttentionLayer.decode_entry` at the cells' heads, 1,024 positions:
+    `granite4_h_micro`'s 8 K/V heads of 64 are declared packed in whole
+    tiles since PR 49; every other configuration's leaf is what it was (and
+    `granite4_h_micro`'s own under a model axis or without the kernel)."""
+    from types import SimpleNamespace
+    from deeplearning4j_tpu.nn.conf.layers import SelfAttentionLayer
+    from deeplearning4j_tpu.nn.layers.recurrent import \
+        SelfAttentionLayerModule
+    how = {"head_dim": 64, "use_pallas": True, **how}
+    geom = SimpleNamespace(slots=leaf[0], capacity=1024, dtype=jnp.bfloat16,
+                           paged=False, model_shards=how.pop("shards", 1))
+    entry = SelfAttentionLayerModule(SelfAttentionLayer(
+        n_in=64, n_out=64, causal=True, **how)).decode_entry(geom)
+    assert entry["k"].shape == entry["v"].shape == leaf
 
 
 def test_windowed_flash_forward_compiles(on_chip):
@@ -892,13 +938,14 @@ def _prefill_text(eng, bucket, sharding):
 
 
 @pytest.mark.parametrize("which,leaf", [
-    ("lm_engine", (8, 256, 4, 64)), ("packed_lm_engine", (8, 256, 8, 128))],
+    ("lm_engine", (8, 64, 8, 128)), ("packed_lm_engine", (8, 256, 8, 128))],
     ids=["4_heads_of_64", "16_heads_of_64_packed"])
 def test_decode_step_compiles_with_kernel(request, which, leaf, one_chip,
                                           chip_config, monkeypatch):
-    """At a shape whose K/V leaves stay as they were (4 heads of 64: two
-    rows, the positions-minor kernel) and at one that packs (16 heads of 64:
-    the row-major kernel on a `[8, 256, 8, 128]` leaf)."""
+    """At a shape whose K/V leaves pack to two rows a position and are
+    declared in whole tiles since PR 49 (4 heads of 64: `[8, 256 * 2 / 8, 8,
+    128]`, four positions a tile) and at one that packs to whole tiles (16
+    heads of 64: `[8, 256, 8, 128]`): the row-major kernel on both."""
     monkeypatch.setattr(fa, "_interpret_default", lambda: False)
     eng = request.getfixturevalue(which)
     layers = len(eng._entries)
@@ -993,6 +1040,8 @@ def test_hybrid_decode_step_compiles_with_both_kinds_of_kernel(
         residual_multiplier=0.22, logits_scaling=8, dtype="bfloat16",
         use_pallas=True).init()
     eng = DecodeEngine(net, slots=16, max_len=256)
+    # 2 K/V heads of 64: one row of 128 lanes a position, eight to a tile
+    assert eng._entries["b1_attn"]["k"].shape == (16, 32, 8, 128)
     args = _abstract((net.params, net.states, eng.init_cache(),
                       np.zeros((eng.slots,), np.int32),
                       eng._greedy_step_ops), one_chip)
@@ -1001,7 +1050,7 @@ def test_hybrid_decode_step_compiles_with_both_kinds_of_kernel(
     assert len(re.findall(r"%ssm_step[.\d]* = ", text)) == 2
     assert len(re.findall(r"%flash_decode[.\d]* = ", text)) == 1
     assert "%kv_append" not in text
-    assert relayouts(text, 16 * 128 * 1024) == []
+    assert relayouts(text, 16 * 256 * 2 * 64) == []
     assert loops(text) == []
     assert sorts_only_under_a_conditional(text)
     text = _prefill_text(eng, 128, one_chip)
