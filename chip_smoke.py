@@ -15,7 +15,10 @@ depth is never cut here because both models fit one 16 GB chip whole.
   serve  the flash kernels against attention_reference on the chip, and the
          decode step's attention kernel on a cache in whole tiles (4 K/V
          heads of 128: a ring of 1,024 and a slab of 6,144, 48 slots, 24
-         steps) against plain jax.numpy, slabs bit for bit; two
+         steps) against plain jax.numpy, slabs bit for bit; latent
+         attention's kernels at `kimi_k27_code`'s shapes (`latent_append` +
+         `mla_decode` at 64 heads on a 128 x 6,144 x 640 slab, the blockwise
+         `mla_prefill` at a 4,096 bucket against the plain form); two
          fit_batch steps of the 256-wide transformer_lm at 16 x 512 tokens
          (both backward kernels inside the normal train step); then the same
          net through ModelSerializer -> ServingServer(scan_dir=...,
@@ -30,6 +33,7 @@ last line of stdout is exactly
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import math
 import os
@@ -386,6 +390,153 @@ def decode_kernel_phase(cases=DECODE_KERNEL_CASES, heads=32, steps=24, seed=0):
              neighbour_lost_moves_a_slot_by_at_least=lost,
              other_half_of_the_lane_row_moves_a_slot_by_at_least=(
                  swapped if packed else None))
+
+
+# ------------------------------------------------- latent attention's kernels
+def latent_kernel_phase(slots=128, capacity=6144, heads=64, bucket=4096,
+                        steps=8, seed=0):
+    """`kimi_k27_code`'s two attention paths at its own shapes, compiled,
+    against plain jax.numpy in float32: the decode step's pair — the token's
+    row into a `[slots, capacity, 640]` bfloat16 slab (`latent_append`,
+    donated), then `mla_decode` at 64 query rows a slot over the slot's live
+    rows — for `steps` consecutive steps from lengths on both sides of a
+    256-position block's edge and at the slab's end, the slab afterwards bit
+    for bit; and the blockwise prefill (`mla_prefill`, keys 128 | 64 against
+    values 128) over a `bucket` of positions against the plain form, whose
+    `[heads, T, T]` scores are formed eight heads at a time so that they fit.
+    A query aimed at the oldest key, at the newest and at a key just under
+    the diagonal's block edge moves by ~1 when that key is lost, which the
+    phase shows by planting each loss in the reference."""
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.kernels import (latent_append, mla_decode,
+                                            mla_prefill)
+
+    rng = np.random.default_rng(seed)
+    f32 = lambda a: np.asarray(a.astype(jnp.float32))
+    S, C, H, W, R = slots, capacity, heads, 640, 512
+    scale = 192.0 ** -0.5 * 2.00474
+
+    # ---- the step's pair
+    slab = jnp.asarray(rng.normal(size=(S, C, W)) * 0.5, jnp.bfloat16)
+    slab = slab.at[:, :, 576:].set(0)
+    edge = [0, 1, 255, 256, 256 - steps // 2, C - steps - 1]
+    pos = jnp.asarray(np.concatenate(
+        [edge, rng.integers(0, C - steps, S)])[:S], jnp.int32)
+
+    @functools.partial(jax.jit, donate_argnums=(0,))
+    def step(slab, rows, q, pos):
+        slab = latent_append(slab, rows, pos)
+        return slab, mla_decode(q, slab, pos + 1, rank=R)
+
+    def reference(slab, q, lengths, lost=None):
+        """Sixteen slots at a time, float32 products; `lost` [S]: a position
+        a slot whose row is left out."""
+        outs = []
+        for a in range(0, S, 16):
+            lat = slab[a:a + 16].astype(jnp.float32)
+            with jax.default_matmul_precision("highest"):
+                sc = jnp.einsum("shw,scw->shc",
+                                q[a:a + 16].astype(jnp.float32), lat)
+                valid = jnp.arange(C)[None] < lengths[a:a + 16, None]
+                if lost is not None:
+                    valid &= jnp.arange(C)[None] != lost[a:a + 16, None]
+                p = jax.nn.softmax(jnp.where(valid[:, None], sc, -jnp.inf),
+                                   axis=-1)
+                outs.append(jnp.einsum("shc,scr->shr", p, lat[:, :, :R]))
+        return jnp.concatenate(outs)
+
+    program, worst, moved = None, 0.0, np.inf
+    for _ in range(steps):
+        rows = jnp.asarray(rng.normal(size=(S, W)) * 0.5, jnp.bfloat16)
+        rows = rows.at[:, 576:].set(0)
+        plain = slab.at[jnp.arange(S), pos].set(rows)
+        # a third of a slot's heads at its oldest row, a third at the new
+        # one, the rest noise; 6 x the row: the aimed key takes the softmax
+        aimed = jnp.stack([plain[:, 0], rows], axis=1).astype(jnp.float32)
+        q = jnp.concatenate(
+            [jnp.repeat(aimed * 6, H // 3, axis=1)[:, :2 * (H // 3)],
+             jnp.asarray(rng.normal(size=(S, H - 2 * (H // 3), W)) * 0.1,
+                         jnp.float32)], axis=1).astype(jnp.bfloat16)
+        if program is None:
+            program = step.lower(slab, rows, q, pos).compile()
+            require_kernels({"pallas_kernels": program.as_text().count(
+                "tpu_custom_call")}, "latent_append + mla_decode")
+        slab, out = step(slab, rows, q, pos)
+        require(bool(jnp.array_equal(slab, plain)),
+                "latent_append: the slab is not the reference's, bit for bit")
+        want = reference(plain, q, pos + 1)
+        require(np.all(np.isfinite(f32(out))), "mla_decode is not finite")
+        worst = max(worst, float(np.abs(f32(out) - f32(want)).max()))
+        off = reference(plain, q, pos + 1, lost=pos)      # the new row lost
+        moved = min(moved, float(np.abs(f32(off) - f32(want)).max(
+            axis=(1, 2)).min()))
+        pos = pos + 1
+    require(worst <= BF16_FWD_TOL, f"mla_decode off by {worst}")
+    require(moved > 10 * BF16_FWD_TOL,
+            f"mla_decode: a lost row moves the output by only {moved}")
+    note(phase="latent_kernels", kernel="latent_append+mla_decode",
+         slab=[S, C, W], heads=H, steps=steps, out_max_abs_err=worst,
+         new_row_lost_moves_a_slot_by_at_least=moved)
+
+    # ---- the prefill
+    T = bucket
+    draw = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+    qn, qp, kn, kp, v = (draw(1, T, H, 128), draw(1, T, H, 64),
+                         draw(1, T, H, 128), draw(1, T, 64),
+                         draw(1, T, H, 128))
+    # every 97th query aims at the key 513 positions under it (across a
+    # block's edge) where there is one, else at its own
+    at = np.arange(T)
+    target = np.where((at % 97 == 0) & (at >= 513), at - 513, at)
+    qn = jnp.where((at % 97 == 0)[None, :, None, None],
+                   kn[:, target] * 4, qn * 0.3)
+    valid = jnp.asarray((at < T - 300)[None], jnp.float32)
+    attend = jax.jit(lambda *a: mla_prefill(*a, scale=scale, key_mask=valid))
+    require_kernels({"pallas_kernels": attend.lower(
+        qn, qp, kn, kp, v).compile().as_text().count("tpu_custom_call")},
+        "mla_prefill")
+    out = attend(qn, qp, kn, kp, v)
+
+    @jax.jit
+    def plain_form(qn, qp, kn, kp, v, lost):
+        with jax.default_matmul_precision("highest"):
+            up = lambda a: a.astype(jnp.float32)
+            s_ = (jnp.einsum("bqhn,bkhn->bhqk", up(qn), up(kn))
+                  + jnp.einsum("bqhr,bkr->bhqk", up(qp), up(kp))) * scale
+            keep = (at[None, :] <= at[:, None]) & (valid[0] > 0)[None, :] \
+                & (at[None, :] != lost[:, None])
+            p = jax.nn.softmax(jnp.where(keep[None, None], s_, -jnp.inf),
+                               axis=-1)
+            return jnp.einsum("bhqk,bkhv->bqhv", p, up(v))
+
+    fwd, moved = 0.0, np.inf
+    real = np.asarray(at < T - 300)
+    none = jnp.full((T,), -1)
+    aimed = (at % 97 == 0) & (at >= 513) & real
+    for a in range(0, H, 8):
+        part = lambda x: x[:, :, a:a + 8]
+        want = plain_form(part(qn), part(qp), part(kn), kp, part(v), none)
+        got = f32(out[:, :, a:a + 8])
+        require(np.all(np.isfinite(got[:, real])),
+                "mla_prefill is not finite")
+        fwd = max(fwd, float(np.abs(got - f32(want))[:, real].max()))
+        off = plain_form(part(qn), part(qp), part(kn), kp, part(v),
+                         jnp.asarray(np.where(aimed, target, -1)))
+        moved = min(moved, float(np.abs(f32(off) - f32(want))[0, aimed].max(
+            axis=(1, 2)).min()))
+    require(fwd <= BF16_FWD_TOL, f"mla_prefill off by {fwd}")
+    require(moved > 10 * BF16_FWD_TOL,
+            f"mla_prefill: a lost key moves the output by only {moved}")
+    jax.block_until_ready(attend(qn, qp, kn, kp, v))
+    t0 = time.perf_counter()
+    for _ in range(5):
+        last = attend(qn, qp, kn, kp, v)
+    jax.block_until_ready(last)
+    note(phase="latent_kernels", kernel=f"mla_prefill_{T}", heads=H,
+         fwd_max_abs_err=fwd, aimed_key_lost_moves_a_query_by_at_least=moved,
+         ms_a_call_with_its_folds=round(
+             (time.perf_counter() - t0) / 5 * 1e3, 3))
 
 
 # --------------------------------------------------------------------- serve
@@ -794,6 +945,7 @@ def main(argv=None):
             if args.only in (None, "kernels"):
                 kernel_phase(seed=args.seed)
                 decode_kernel_phase(seed=args.seed)
+                latent_kernel_phase(seed=args.seed)
             if args.only in (None, "serve"):
                 serve_phase(events, seed=args.seed)
         else:

@@ -13,9 +13,10 @@ from .flash_attention import (flash_attention, flash_decode,
                               flash_decode_paged, kv_append)
 from .kda_step import kda_step
 from .mla_decode import latent_append, mla_decode
+from .mla_prefill import mla_prefill
 from .ssm_step import ssm_step
 
 __all__ = ["expert_gmm", "flash_attention", "flash_decode",
            "flash_decode_append",
            "flash_decode_paged", "kda_step", "kv_append", "latent_append",
-           "mla_decode", "ssm_step"]
+           "mla_decode", "mla_prefill", "ssm_step"]

@@ -473,11 +473,14 @@ class LatentAttentionLayer(_NoActivationConf, BaseRecurrentConf):
     keys and values are up-projections of ONE `kv_lora_rank`-wide normed
     latent a token, beside one `qk_rope_head_dim`-wide rotary key shared by
     all heads, so the decode cache holds kv_lora_rank + qk_rope_head_dim
-    values a token whatever the head count. Full-rank queries (no q
-    compression), rotary positions on adjacent pairs, a sigmoid gate a head
-    on the output. Runtime: nn/layers/mla.py — the plain form for sequences
-    and prefill, the absorbed form (scores against the cached row) for a
-    decode step."""
+    values a token whatever the head count. Rotary positions on adjacent
+    pairs. Full-rank queries, or (`q_lora_rank`) queries compressed through
+    a normed latent of their own; plain rotary, or YaRN's frequencies
+    (`rope_yarn`) with the softmax scale times mscale^2; a sigmoid gate a
+    head on the output, or none (`output_gate`). Runtime: nn/layers/mla.py —
+    the plain form for sequences and prefill (blockwise once the scores
+    would be large: kernels/mla_prefill.py), the absorbed form (scores
+    against the cached row) for a decode step."""
     n_heads: int = 4
     kv_lora_rank: int = 64
     qk_nope_head_dim: int = 32
@@ -486,6 +489,18 @@ class LatentAttentionLayer(_NoActivationConf, BaseRecurrentConf):
     rope_theta: float = 10000.0
     eps: float = 1e-6
     use_pallas: bool = False
+    # compressed queries (DeepSeek-V2 section 2.1.2): q = RMSNorm(x Wq_a)
+    # Wq_b, the latent `q_lora_rank` wide (None: one full-rank Wq)
+    q_lora_rank: int | None = None
+    # YaRN (arXiv:2309.00071) as DeepSeek-V3 computes it, given as data:
+    # {"factor", "original_max_position_embeddings", "beta_fast",
+    # "beta_slow", "mscale", "mscale_all_dim"} — the rotary frequencies
+    # blended between theta's and theta's / factor over the ramp [low,
+    # high]; cos and sin times m(mscale) / m(mscale_all_dim) and the scores
+    # times m(mscale_all_dim)^2, m(s) = 0.1 s ln(factor) + 1 (None: plain)
+    rope_yarn: dict | None = None
+    # the head-wise sigmoid gate sigmoid(x Wgate) on the context before Wo
+    output_gate: bool = True
 
 
 @register_layer_conf

@@ -1,23 +1,32 @@
 """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434), causal, with
-rotary positions and a head-wise output gate (conf: nn/conf/layers.py
-LatentAttentionLayer — NEW, no reference counterpart). With x the layer's
-input at position t, H heads, latent R, rotary Dr, nope Dn, value Dv:
+rotary positions and, as options, compressed queries, YaRN and a head-wise
+output gate (conf: nn/conf/layers.py LatentAttentionLayer — NEW, no
+reference counterpart). With x the layer's input at position t, H heads,
+latent R, rotary Dr, nope Dn, value Dv:
 
     [c | k_pe] = x Wkv_a ;  c <- RMSNorm_R(c) ;  k_pe <- RoPE(k_pe)    shared
     [q_nope | q_pe]_h = x Wq ;  q_pe <- RoPE(q_pe)
+        with `q_lora_rank`:  = RMSNorm(x Wq_a) Wq_b     (scope `mla_queries`)
     [k_nope | v]_h = c Wkv_b
     p_h = causal softmax((q_nope_h . k_nope_h + q_pe_h . k_pe) (Dn + Dr)^-1/2)
-    out = [(sum p_h v_h) * sigmoid(x Wgate)_h] Wo
+        with `rope_yarn`: the scores times m(mscale_all_dim)^2
+    out = [(sum p_h v_h) * sigmoid(x Wgate)_h] Wo       (`output_gate`)
 
-RoPE turns adjacent pairs (2i, 2i + 1) by position * theta^(-2i / Dr), in
-float32; the positions are 0.. for a sequence and a prefill, `ctx.pos` for a
-decode step, `ctx.start`.. for a verify window.
+RoPE turns adjacent pairs (2i, 2i + 1) by position * theta^(-2i / Dr) — with
+`rope_yarn` by YaRN's blended frequencies (`recurrent.rope_frequencies`), cos
+and sin times m(mscale) / m(mscale_all_dim) —, in float32; the positions are
+0.. for a sequence and a prefill, `ctx.pos` for a decode step, `ctx.start`..
+for a verify window.
 
 Two formulations in one layer:
 
 - the PLAIN form above (`forward`, the decode prefill, verify): keys and
   values are made from the latent for the whole sequence, 192-wide scores
-  against 128-wide values.
+  against 128-wide values. While the `[H, tq, tk]` float32 scores are small
+  (`PLAIN_SCORE_BYTES`) they are formed whole (`attend_plain`); beyond that
+  the layer attends BLOCKWISE: a sequence from position 0 through
+  kernels.mla_prefill (scope `mla_prefill`), a verify window a query block
+  at a time in `jax.numpy`.
 - the ABSORBED form (`decode_step`): q'_h = q_nope_h W_UK,h^T (R wide), the
   scores are [q'_h | q_pe_h] against the cached row, the mix of the rows'
   latents goes through W_UV,h afterwards — so a token's row is read ONCE for
@@ -34,6 +43,8 @@ engine the rows stay a slab (a block table for latent rows is not written).
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -41,18 +52,41 @@ from jax import lax
 from .base import (BaseLayerModule, CacheLeaf, apply_dropout,
                    note_cache_entry, register_impl)
 from .convolution import rms_norm
+from .recurrent import rope_frequencies
 from ..weights import init_weights
 from ..conf.inputs import InputType
 
+# the plain form's [b, H, tq, tk] float32 scores are formed whole up to this
+# size — 32 heads at 1,024 x 1,024, the largest the `ling3_flash` cell forms,
+# whose programs stay as they were measured — and blockwise beyond
+PLAIN_SCORE_BYTES = 128 << 20
 
-def rope(x, pos, theta):
+
+def yarn_factors(yarn):
+    """(the factor on cos and sin, the factor on the scores) of a conf's
+    `rope_yarn`, as DeepSeek-V3 computes them: m(mscale) / m(mscale_all_dim)
+    and m(mscale_all_dim)^2 — 1 without `mscale_all_dim` —, m(s) = 0.1 s
+    ln(factor) + 1."""
+    factor = float(yarn["factor"])
+    m = lambda s: 0.1 * float(s) * math.log(factor) + 1.0 if factor > 1 \
+        else 1.0
+    all_dim = yarn.get("mscale_all_dim", 0)
+    return m(yarn.get("mscale", 1)) / m(all_dim), \
+        m(all_dim) ** 2 if all_dim else 1.0
+
+
+def rope(x, pos, theta, freq=None, factor=1.0):
     """x [.., t, (heads,) Dr] turned at positions pos [.., t]: adjacent
-    pairs (2i, 2i + 1) by pos * theta^(-2i / Dr). float32 inside."""
+    pairs (2i, 2i + 1) by pos * theta^(-2i / Dr) — or by pos * freq_i, cos
+    and sin times `factor`, where a table is given (YaRN). float32 inside."""
     half = x.shape[-1] // 2
-    freq = float(theta) ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = pos.astype(jnp.float32)[..., None] * freq
+    if freq is None:
+        freq = float(theta) ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = pos.astype(jnp.float32)[..., None] * jnp.asarray(freq)
     ang = ang.reshape(pos.shape + (1,) * (x.ndim - pos.ndim - 1) + (half,))
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (half, 2))
     a, b = pairs[..., 0], pairs[..., 1]
     return jnp.stack([a * cos - b * sin, a * sin + b * cos],
@@ -82,12 +116,20 @@ class LatentAttentionLayerModule(BaseLayerModule):
         mk = lambda k, i, o: init_weights(k, (i, o), c.weight_init, fan_in=i,
                                           fan_out=o, distribution=c.dist,
                                           dtype=dtype)
-        params = {"Wq": mk(k1, n_in, H * (Dn + Dr)),
-                  "Wkv_a": mk(k2, n_in, R + Dr),
+        params = {"Wkv_a": mk(k2, n_in, R + Dr),
                   "kv_norm": jnp.ones((R,), dtype),
                   "Wkv_b": mk(k3, R, H * (Dn + Dv)),
-                  "Wgate": mk(k4, n_in, H),
                   "Wo": mk(k5, H * Dv, n_out)}
+        Rq = getattr(c, "q_lora_rank", None)
+        if Rq:
+            params.update(Wq_a=mk(k1, n_in, int(Rq)),
+                          q_norm=jnp.ones((int(Rq),), dtype),
+                          Wq_b=mk(jax.random.fold_in(k1, 1), int(Rq),
+                                  H * (Dn + Dr)))
+        else:
+            params["Wq"] = mk(k1, n_in, H * (Dn + Dr))
+        if getattr(c, "output_gate", True):
+            params["Wgate"] = mk(k4, n_in, H)
         return params, {}, InputType.recurrent(n_out)
 
     # -- the pieces the legs share ---------------------------------------------
@@ -97,14 +139,29 @@ class LatentAttentionLayerModule(BaseLayerModule):
         c = self.conf
         R = self.dims()[1]
         lat, k_pe = jnp.split(x @ params["Wkv_a"], [R], axis=-1)
-        return rms_norm(lat, params["kv_norm"], c.eps), \
-            rope(k_pe, pos, c.rope_theta)
+        return rms_norm(lat, params["kv_norm"], c.eps), self.turn(k_pe, pos)
+
+    def turn(self, x, pos):
+        """The rotary turn of this layer: plain at `rope_theta`, or YaRN's
+        table and its factor on cos and sin."""
+        c = self.conf
+        yarn = getattr(c, "rope_yarn", None)
+        if not yarn:
+            return rope(x, pos, c.rope_theta)
+        freq, _ = rope_frequencies(x.shape[-1], c.rope_theta, yarn)
+        return rope(x, pos, c.rope_theta, freq, yarn_factors(yarn)[0])
 
     def queries(self, params, x, pos):
         """-> q_nope [.., H, Dn], q_pe [.., H, Dr] turned."""
         H, _, Dn, Dr, _ = self.dims()
-        q = (x @ params["Wq"]).reshape(x.shape[:-1] + (H, Dn + Dr))
-        return q[..., :Dn], rope(q[..., Dn:], pos, self.conf.rope_theta)
+        if "Wq_a" in params:
+            with jax.named_scope("mla_queries"):
+                q = rms_norm(x @ params["Wq_a"], params["q_norm"],
+                             self.conf.eps) @ params["Wq_b"]
+        else:
+            q = x @ params["Wq"]
+        q = q.reshape(x.shape[:-1] + (H, Dn + Dr))
+        return q[..., :Dn], self.turn(q[..., Dn:], pos)
 
     def up(self, params):
         """Wkv_b as (W_UK [R, H, Dn], W_UV [R, H, Dv])."""
@@ -114,7 +171,9 @@ class LatentAttentionLayerModule(BaseLayerModule):
 
     def scale(self):
         _, _, Dn, Dr, _ = self.dims()
-        return float(Dn + Dr) ** -0.5
+        yarn = getattr(self.conf, "rope_yarn", None)
+        return float(Dn + Dr) ** -0.5 * (yarn_factors(yarn)[1] if yarn
+                                         else 1.0)
 
     def attend_plain(self, params, q_nope, q_pe, lat, k_pe, q_pos, valid):
         """Queries [b, tq, H, ..] at positions q_pos [b, tq] against the
@@ -134,10 +193,38 @@ class LatentAttentionLayerModule(BaseLayerModule):
         p = jax.nn.softmax(jnp.where(keep[:, None], s, -1e30), axis=-1)
         return jnp.einsum("bhqk,bkhv->bqhv", p.astype(v.dtype), v)
 
+    def attend(self, params, q_nope, q_pe, lat, k_pe, q_pos, valid,
+               from_zero):
+        """`attend_plain` while its scores are small, else the same
+        attention blockwise: a whole sequence from position 0 (`from_zero`:
+        q_pos is 0 .. tk - 1) through kernels.mla_prefill, any other queries
+        a block at a time in `jax.numpy`."""
+        from ...kernels.mla_prefill import mla_attend_blockwise, mla_prefill
+        H = self.dims()[0]
+        (b, tq), tk = q_pos.shape, lat.shape[1]
+        if 4 * b * H * tq * tk <= PLAIN_SCORE_BYTES:
+            with jax.named_scope("mla_attention"):
+                return self.attend_plain(params, q_nope, q_pe, lat, k_pe,
+                                         q_pos, valid)
+        W_uk, W_uv = self.up(params)
+        with jax.named_scope("mla_prefill"):
+            k_nope = jnp.einsum("bkr,rhn->bkhn", lat, W_uk)
+            v = jnp.einsum("bkr,rhv->bkhv", lat, W_uv)
+            if from_zero:
+                return mla_prefill(
+                    q_nope, q_pe, k_nope, k_pe, v, scale=self.scale(),
+                    key_mask=valid,
+                    use_pallas=getattr(self.conf, "use_pallas", False))
+            return mla_attend_blockwise(q_nope, q_pe, k_nope, k_pe, v, q_pos,
+                                        valid, self.scale())
+
     def finish(self, params, o, x, mask):
-        """Head-wise sigmoid gate, output projection, mask zeroing."""
-        gate = jax.nn.sigmoid((x @ params["Wgate"]).astype(jnp.float32))
-        y = (o * gate[..., None].astype(o.dtype)).reshape(o.shape[:-2] + (-1,))
+        """Head-wise sigmoid gate (where the layer has one), output
+        projection, mask zeroing."""
+        if "Wgate" in params:
+            gate = jax.nn.sigmoid((x @ params["Wgate"]).astype(jnp.float32))
+            o = o * gate[..., None].astype(o.dtype)
+        y = o.reshape(o.shape[:-2] + (-1,))
         y = self.activation_fn()(y.astype(x.dtype) @ params["Wo"])
         return y if mask is None else y * mask[:, :, None].astype(y.dtype)
 
@@ -147,8 +234,7 @@ class LatentAttentionLayerModule(BaseLayerModule):
         pos = jnp.broadcast_to(jnp.arange(x.shape[1]), x.shape[:2])
         lat, k_pe = self.latent(params, x, pos)
         q_nope, q_pe = self.queries(params, x, pos)
-        with jax.named_scope("mla_attention"):
-            o = self.attend_plain(params, q_nope, q_pe, lat, k_pe, pos, mask)
+        o = self.attend(params, q_nope, q_pe, lat, k_pe, pos, mask, True)
         return self.finish(params, o, x, mask), lat, k_pe
 
     def forward(self, params, state, x, *, train=False, rng=None, mask=None):
@@ -214,6 +300,6 @@ class LatentAttentionLayerModule(BaseLayerModule):
             (slot, start, z))
         rows = lax.dynamic_index_in_dim(cache, slot, 0, keepdims=True)
         q_nope, q_pe = self.queries(params, x, pos)
-        o = self.attend_plain(params, q_nope, q_pe, rows[..., :R],
-                              rows[..., R:R + Dr], pos, None)
+        o = self.attend(params, q_nope, q_pe, rows[..., :R],
+                        rows[..., R:R + Dr], pos, None, False)
         return self.finish(params, o, x, None), {"latent": cache}

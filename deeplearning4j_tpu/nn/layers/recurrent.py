@@ -244,7 +244,8 @@ def rope_frequencies(head_dim, theta, yarn=None):
     / (2 pi r)) / (2 ln theta); low = floor(c(beta_fast)), high =
     ceil(c(beta_slow)), both clipped to [0, head_dim - 1]; ramp_j = clip((j
     - low) / (high - low), 0, 1); f_j = n_j ramp_j + e_j (1 - ramp_j); the
-    factor is `attention_factor`."""
+    factor is `attention_factor` (1 where the caller has its own:
+    nn/layers/mla.py)."""
     half = head_dim // 2
     e = float(theta) ** (-np.arange(half, dtype=np.float64) / half)
     if not yarn:
@@ -252,7 +253,7 @@ def rope_frequencies(head_dim, theta, yarn=None):
     low, high = yarn_ramp(head_dim, theta, yarn)
     ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0, 1)
     f = e / float(yarn["factor"]) * ramp + e * (1 - ramp)
-    return f.astype(np.float32), float(yarn["attention_factor"])
+    return f.astype(np.float32), float(yarn.get("attention_factor", 1.0))
 
 
 def yarn_ramp(head_dim, theta, yarn):

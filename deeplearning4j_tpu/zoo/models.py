@@ -562,6 +562,85 @@ def mellum_lm(vocab_size=256, d_model=128, n_layers=4, n_heads=4,
     return ComputationGraph(gb.build())
 
 
+def kimi_k2_lm(vocab_size=256, d_model=128, n_layers=5, n_heads=2,
+               ffn_mult=18432 / 7168, first_k_dense=1, q_lora_rank=1536,
+               kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+               v_head_dim=128, rope_theta=50000.0, yarn=None, n_experts=384,
+               experts_per_token=8, routed_scaling=2.827, expert_hidden=2048,
+               shared_hidden=2048, experts_held=None, first_expert=0,
+               rms_norm_eps=1e-5, dtype="float32", seed=12345,
+               use_pallas=False, updater=None):
+    """Latent-attention expert decoder of the `kimi_k2` shape
+    (moonshotai/Kimi-K2.7-Code; DeepSeek-V3's block): pre-norm blocks h +=
+    attention(RMSNorm(h)); h += ffn(RMSNorm(h)). EVERY layer mixes with a
+    LatentAttentionLayer — `n_heads` heads, queries compressed through a
+    normed latent of `q_lora_rank`, keys and values made from one normed
+    latent of `kv_lora_rank` beside one shared rotary key, rotary
+    frequencies YaRN's (`yarn`: {"factor", "original_max_position_embeddings",
+    "beta_fast", "beta_slow", "mscale", "mscale_all_dim"}; None: plain), no
+    output gate. Layer i (0-based) has a gated SiLU feed-forward of width
+    round(d_model * ffn_mult) for i < first_k_dense, else
+    `experts_per_token` of `n_experts` routed gated experts of width
+    `expert_hidden` — sigmoid scores, a selection-only bias, one group,
+    gates renormalised and times `routed_scaling` — beside a shared expert
+    of width `shared_hidden` on the same norm; this model holds
+    `experts_held` of the routed experts from `first_expert` on (default:
+    all; the rest of the sum is another chip's). h_0 = E[ids]; probabilities
+    = softmax(RMSNorm(h) W_head^T), the head untied. Input one-hot [b, t,
+    vocab]. The default updater is plain SGD: it keeps no state beside the
+    parameters."""
+    from ..nn.conf.layers import (GatedDenseLayer, LatentAttentionLayer,
+                                  LMHeadLayer, MixtureOfExpertsLayer,
+                                  RMSNormalization)
+    gb = (NeuralNetConfiguration.builder()
+          .seed(seed).updater(updater or Sgd(learning_rate=1e-3))
+          .weight_init("xavier").dtype(dtype)
+          .graph_builder()
+          .add_inputs("tokens"))
+    norm = lambda: RMSNormalization(eps=rms_norm_eps)
+
+    def residual(name, prev, branch):
+        gb.add_vertex(name, ElementWiseVertex("add"), prev, branch)
+        return name
+
+    gb.add_layer("embed", DenseLayer(n_out=d_model, activation="identity"),
+                 "tokens")
+    prev = "embed"
+    for i in range(n_layers):
+        gb.add_layer(f"b{i}_norm1", norm(), prev)
+        gb.add_layer(f"b{i}_mla", LatentAttentionLayer(
+            n_out=d_model, n_heads=n_heads, q_lora_rank=q_lora_rank,
+            kv_lora_rank=kv_lora_rank, qk_nope_head_dim=qk_nope_head_dim,
+            qk_rope_head_dim=qk_rope_head_dim, v_head_dim=v_head_dim,
+            rope_theta=rope_theta, rope_yarn=dict(yarn) if yarn else None,
+            output_gate=False, eps=rms_norm_eps, use_pallas=use_pallas),
+            f"b{i}_norm1")
+        prev = residual(f"b{i}_res1", prev, f"b{i}_mla")
+        gb.add_layer(f"b{i}_norm2", norm(), prev)
+        dense = i < first_k_dense
+        ffn = f"b{i}_mlp"
+        gb.add_layer(ffn, GatedDenseLayer(
+            n_out=d_model, n_hidden=int(round(d_model * ffn_mult)) if dense
+            else shared_hidden), f"b{i}_norm2")
+        if not dense:
+            gb.add_layer(f"b{i}_moe", MixtureOfExpertsLayer(
+                n_out=d_model, n_experts=n_experts, top_k=experts_per_token,
+                gated=True, n_hidden=expert_hidden, experts_held=experts_held,
+                first_expert=first_expert, score_function="sigmoid",
+                n_groups=1, routed_scaling=routed_scaling,
+                use_pallas=use_pallas, activation="identity"), f"b{i}_norm2")
+            ffn = f"b{i}_ffn"
+            gb.add_vertex(ffn, ElementWiseVertex("add"), f"b{i}_moe",
+                          f"b{i}_mlp")
+        prev = residual(f"b{i}_res2", prev, ffn)
+    gb.add_layer("norm", norm(), prev)
+    gb.add_layer("out", LMHeadLayer(n_out=vocab_size, activation="softmax",
+                                    loss="MCXENT"), "norm")
+    gb.set_outputs("out")
+    gb.set_input_types(InputType.recurrent(vocab_size))
+    return ComputationGraph(gb.build())
+
+
 def vgg16(num_classes=1000, image_size=224, seed=12345):
     """VGG16 (reference: trainedmodels/TrainedModels.java VGG16)."""
     b = (NeuralNetConfiguration.builder()

@@ -857,6 +857,82 @@ def test_mla_decode_and_latent_append_compile_on_the_padded_row(on_chip):
             on_chip((S, C, 576), jnp.bfloat16), on_chip((S,), jnp.int32))
 
 
+def test_mla_kernels_compile_at_64_heads_and_a_4096_bucket(on_chip):
+    """`kimi_k27_code`'s shapes: `mla_decode` at 64 query rows a slot on a
+    128 x 6,144 x 640 bfloat16 slab it reads where it lies, and the
+    blockwise prefill — 128 | 64-wide keys against 128-wide values, the
+    rotary key one `[1, T, 64]` operand for all 64 heads — over a 4,096
+    bucket with the prefill's mask: one kernel named after its bucket, and
+    no `[64, T, T]` score matrix among its temporaries (4.3 GB; the head
+    folds are 0.2 GB)."""
+    from deeplearning4j_tpu.kernels import mla_decode, mla_prefill
+    S, C, W, H, R, T = 128, 6144, 640, 64, 512, 4096
+    text = compiled_text(
+        lambda q, lat, n: mla_decode(q, lat, n, rank=R, interpret=False),
+        on_chip((S, H, W), jnp.bfloat16), on_chip((S, C, W), jnp.bfloat16),
+        on_chip((S,), jnp.int32))
+    assert text.count(KERNEL) == 1
+    assert relayouts(text, S * C * W) == []
+    head = lambda d: on_chip((1, T, H, d), jnp.bfloat16)
+    comp = jax.jit(lambda qn, qp, kn, kp, v, m: mla_prefill(
+        qn, qp, kn, kp, v, scale=0.1447, key_mask=m, interpret=False)).lower(
+            head(128), head(64), head(128), on_chip((1, T, 64), jnp.bfloat16),
+            head(128), on_chip((1, T), jnp.float32)).compile()
+    text = comp.as_text()
+    assert text.count(KERNEL) == 1
+    assert len(re.findall(rf"%mla_prefill_{T}[.\d]* = ", text)) == 1
+    assert comp.memory_analysis().temp_size_in_bytes < 1 << 28
+
+
+def test_kimi_decode_step_and_prefill_compile_with_their_kernels(
+        one_chip, chip_config, monkeypatch):
+    """`kimi_k2_lm` (the dense layer and two expert layers) at two of the
+    configuration's 64 heads, its own query latent of 1,536, key/value
+    latent of 512, 128 | 64 | 128 heads and 2,048-wide experts (12 of 384
+    held), bfloat16, 16 slots of 256: every layer of the step is one
+    `latent_append` and one `mla_decode`, an expert layer one
+    `expert_gmm_16x1`; no loop, no copy of a latent slab. Its 128-token
+    prefill, made to attend blockwise as the cell's buckets do (64 heads at
+    2,048 positions and more), is one `mla_prefill_128` a layer; no kernel
+    gave way. (The whole step at the cell's size — 128 slots of 6,144, 64
+    heads, five layers — compiles here in 12 s with 12.03 GB of arguments and
+    0.046 GB of temporaries, 14 kernels, its 4,096-token prefill in 22 s
+    with 1.18 GB: PERF.md section 4.)"""
+    from deeplearning4j_tpu.decode.engine import DecodeEngine
+    from deeplearning4j_tpu.telemetry.registry import get_registry
+    from deeplearning4j_tpu.zoo.models import kimi_k2_lm
+    for module in ("mla_decode", "mla_prefill", "expert_gmm"):
+        monkeypatch.setattr(
+            importlib.import_module("deeplearning4j_tpu.kernels." + module),
+            "_interpret_default", lambda: False)
+    yarn = {"factor": 64, "original_max_position_embeddings": 4096,
+            "beta_fast": 32, "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1}
+    net = kimi_k2_lm(vocab_size=512, d_model=256, n_layers=3, n_heads=2,
+                     yarn=yarn, experts_held=12, dtype="bfloat16",
+                     use_pallas=True).init()
+    eng = DecodeEngine(net, slots=16, max_len=256)
+    fallbacks = get_registry().counter("pallas_fallback_total", "")
+    before = fallbacks.get()
+    args = _abstract((net.params, net.states, eng.init_cache(),
+                      np.zeros((eng.slots,), np.int32),
+                      eng._greedy_step_ops), one_chip)
+    text = eng._build_step().lower(*args, None).compile().as_text()
+    assert text.count(KERNEL) == 8
+    assert len(re.findall(r"%mla_decode[.\d]* = ", text)) == 3
+    assert len(re.findall(r"%latent_append[.\d]* = ", text)) == 3
+    assert len(re.findall(r"%expert_gmm_16x1[.\d]* = ", text)) == 2
+    assert loops(text) == [] and text.count(" conditional(") == 1
+    assert relayouts(text, 16 * 256 * 640) == []
+    monkeypatch.setattr(
+        importlib.import_module("deeplearning4j_tpu.nn.layers.mla"),
+        "PLAIN_SCORE_BYTES", 0)
+    text = _prefill_text(eng, 128, one_chip)
+    assert len(re.findall(r"%mla_prefill_128[.\d]* = ", text)) == 3
+    assert len(re.findall(r"%expert_gmm_1x128[.\d]* = ", text)) == 2
+    assert "%mla_decode" not in text and loops(text) == []
+    assert fallbacks.get() == before
+
+
 def test_kda_chunked_forms_no_chunk_square_of_exponents(on_chip):
     """The prefill's delta rule at `solar_open2`'s size, [1, 1024, 64, 128]
     float32 in chunks of 64: the scores are formed by sub-blocks of 16, so
