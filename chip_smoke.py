@@ -475,9 +475,30 @@ def latent_kernel_phase(slots=128, capacity=6144, heads=64, bucket=4096,
     require(worst <= BF16_FWD_TOL, f"mla_decode off by {worst}")
     require(moved > 10 * BF16_FWD_TOL,
             f"mla_decode: a lost row moves the output by only {moved}")
+    # the kernel alone at the lengths the steps ended on: 100 calls in one
+    # program (each call's lengths wait for the call before), best of 3
+    calls = 100
+
+    @jax.jit
+    def alone(q, slab, lengths):
+        def body(_, n):
+            out = mla_decode(q, slab, n, rank=R)
+            return n + (out[0, 0, 0] > 1e30).astype(jnp.int32)
+        return jax.lax.fori_loop(0, calls, body, lengths)
+
+    jax.block_until_ready(alone(q, slab, pos))
+    best = np.inf
+    for _ in range(3):
+        t0 = time.perf_counter()
+        jax.block_until_ready(alone(q, slab, pos))
+        best = min(best, (time.perf_counter() - t0) / calls)
+    live_blocks = int(np.sum(-(-np.asarray(pos) // 256)))
     note(phase="latent_kernels", kernel="latent_append+mla_decode",
          slab=[S, C, W], heads=H, steps=steps, out_max_abs_err=worst,
-         new_row_lost_moves_a_slot_by_at_least=moved)
+         new_row_lost_moves_a_slot_by_at_least=moved,
+         mla_decode_ms_a_call=round(best * 1e3, 4),
+         mla_decode_live_blocks_of_256=live_blocks,
+         mla_decode_us_a_live_block=round(best * 1e6 / live_blocks, 4))
 
     # ---- the prefill
     T = bucket

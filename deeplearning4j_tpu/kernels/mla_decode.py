@@ -12,11 +12,17 @@ ONCE for the 32 heads and both products run on the MXU.
 
 `mla_decode` (kernel `mla_decode`): the grid walks the slots; the kernel
 walks a slot's LIVE key blocks only, copying each `[block, width]` tile from
-the cache (left in HBM) into one of two VMEM buffers itself, the next copy
-started before the current one is waited for — `flash_decode`'s scheme, for
-the reason given there (a clamped index map would still move every byte).
-Scores, the online softmax and the accumulator are float32; the two
-products multiply in the cache's dtype.
+the cache (left in HBM) into VMEM itself, for the reason `flash_decode` gives
+(a clamped index map would still move every byte). The call's (slot, live
+block) pairs are one sequence and `_DEPTH` of its copies are kept in flight
+in a ring of `_DEPTH + 1` buffers: the copy of pair p + `_DEPTH` is started
+before pair p's is waited for, across the slots' boundaries, so a copy
+has the time of `_DEPTH` blocks' arithmetic to arrive in, not of one (with
+one copy ahead the loop waited on every block). Blocks are computed on in
+the order they lie. Scores,
+the online softmax and the accumulator are float32; the two products
+multiply in the cache's dtype. `mla_decode_block{C,W,depth}` says at trace
+time which plan a program runs.
 
 `latent_append` (kernel `latent_append`): every slot's new row into its
 position, in place: the output is aliased onto the cache and of each slot
@@ -36,8 +42,20 @@ import jax.numpy as jnp
 from .flash_attention import (NEG_INF, _fit_block, _interpret_default,
                               _live_blocks, _note_fallback)
 
-_BLOCK_POSITIONS = 256      # a key block; a slot's blocks past its length
-                            # are not read, so shorter blocks read less
+# A key block's positions, and how many block copies the kernel keeps in
+# flight. Alone on a v5e at 64 heads on a 128 x 6,144 x 640 bfloat16 slab
+# with `kimik27code_code_decode`'s lengths (359 k live rows; PR 51), ms a call
+# at depth 1 / 2 / 3 / 4 / 6: blocks of 128 1.72 / 1.63 / 1.63 / 1.63 / 1.63,
+# of 256 1.118 / 0.973 / 0.976 / 0.975 / 0.976, of 512 0.826 / 0.682 / 0.682
+# / 0.682 / 0.683. Depth 2 is all there is to have: past it a block's
+# arithmetic (0.60 us a 256-position block with no copy in the loop, at 32
+# heads as at 64) is the longer, its copy 0.45. A slot's blocks past its
+# length are not read, so a shorter block reads less (4 % past the live rows
+# at 256, 8 % at 512 at that mix) — and yet 512 is the faster by 30 %: the
+# arithmetic costs by the block more than by the position. The block stays
+# 256 here because PR 51 changed one thing, the depth; ROADMAP S13 (d).
+_BLOCK_POSITIONS = 256
+_DEPTH = 2
 
 
 def _mla_reference(q, latent, lengths, rank):
@@ -53,12 +71,32 @@ def _mla_reference(q, latent, lengths, rank):
     return jnp.einsum("shc,scr->shr", p, lat[:, :, :rank])
 
 
-def _mla_kernel(len_ref, q_ref, lat_hbm, o_ref, buf, sem, buf_ref, acc_ref,
-                m_ref, l_ref, *, block_c, nk, slots, rank):
+def _note_mla_block(block_c, **plan):
+    """The plan `mla_decode` runs for these shapes, as the gauge
+    `mla_decode_block{C,W,depth}` beside `pallas_fallback_total`: the key
+    block's positions, and in `depth` how many block copies the kernel keeps
+    in flight. Set at trace time, so once per compiled program."""
+    from ..telemetry.registry import get_registry
+    get_registry().gauge(
+        "mla_decode_block",
+        "Key-block length (cache positions) of the mla_decode kernel, "
+        "labeled with the number of block copies it keeps in flight: a "
+        "slot's blocks wholly past its length are not read").set(
+            block_c, **plan)
+
+
+def _mla_kernel(len_ref, q_ref, lat_hbm, o_ref, buf, sem, ring_ref, acc_ref,
+                m_ref, l_ref, *, block_c, nk, slots, rank, depth):
     """One slot: q_ref [1, H, W], lat_hbm the whole [S, C, W] cache in HBM,
-    o_ref [1, H, rank]. Which of the two buffers the slot starts in crosses
-    the grid step in `buf_ref` (SMEM): the first block of the NEXT slot is
-    copied under this slot's last."""
+    o_ref [1, H, rank]. The call's (slot, live block) pairs are one
+    sequence, pair p copied into buffer p % (depth + 1); when pair p is
+    waited for, the copies of pairs p + 1 .. p + depth are out. Three
+    scalars cross the grid step in `ring_ref` (SMEM): the buffer this slot's
+    first block is in, and the (slot, block) of the next pair to copy —
+    up to `depth` pairs ahead, so in a later slot, several slots later where
+    slots hold fewer live blocks than `depth`. Past the last slot's last
+    block there is no pair and nothing is started, so the last wait of the
+    last slot leaves no copy out."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     si = pl.program_id(0)
@@ -70,20 +108,34 @@ def _mla_kernel(len_ref, q_ref, lat_hbm, o_ref, buf, sem, buf_ref, acc_ref,
         return pltpu.make_async_copy(lat_hbm.at[slot, at, :], buf.at[b],
                                      sem.at[b])
 
+    def start(slot, block, b):
+        """Start the copy of pair (slot, block), if there is one, into
+        buffer b; returns the pair after it (slot == slots: none)."""
+        @pl.when(slot < slots)
+        def _():
+            copy(slot, block, b).start()
+        last = block + 1 >= _live_blocks(
+            len_ref[jnp.minimum(slot, slots - 1)], block_c, nk)
+        return (jnp.minimum(slot + last.astype(jnp.int32), slots),
+                jnp.where(last, 0, block + 1))
+
     @pl.when(si == 0)
     def _first():
-        buf_ref[0] = 0
-        copy(0, 0, 0).start()
+        pair = jnp.int32(0), jnp.int32(0)
+        for b in range(depth):
+            pair = start(*pair, b)
+        ring_ref[0] = 0
+        ring_ref[1], ring_ref[2] = pair
 
     acc_ref[...] = jnp.zeros_like(acc_ref)
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
     q = q_ref[0]                                            # [H, W]
 
-    def block(j, b):
-        more = j + 1 < live
-        copy(jnp.where(more, si, jnp.minimum(si + 1, slots - 1)),
-             jnp.where(more, j + 1, 0), 1 - b).start()
+    def block(j, ring):
+        b, slot, ahead = ring
+        # the buffer before b in the ring: pair j - 1's, computed on already
+        slot, ahead = start(slot, ahead, jnp.where(b == 0, depth, b - 1))
         copy(si, j, b).wait()
         rows = buf[b]                                       # [block_c, W]
         s = jax.lax.dot_general(q, rows, (((1,), (1,)), ((), ())),
@@ -100,21 +152,17 @@ def _mla_kernel(len_ref, q_ref, lat_hbm, o_ref, buf, sem, buf_ref, acc_ref,
             preferred_element_type=jnp.float32)
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
         m_ref[...] = m_new
-        return 1 - b
+        return jnp.where(b == depth, 0, b + 1), slot, ahead
 
-    b = jax.lax.fori_loop(0, live, block, buf_ref[0])
-    buf_ref[0] = b
-
-    @pl.when(si == slots - 1)
-    def _drain():
-        copy(0, 0, b).wait()
+    ring_ref[0], ring_ref[1], ring_ref[2] = jax.lax.fori_loop(
+        0, live, block, (ring_ref[0], ring_ref[1], ring_ref[2]))
 
     # l >= 1 always: a fully masked slot sums exp(0) per position
     o_ref[0] = acc_ref[...] / l_ref[...]
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4, 5))
-def _mla_call(q, latent, lengths, rank, block_c, interpret):
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _mla_call(q, latent, lengths, rank, block_c, interpret, depth):
     """Jitted for the reason `_decode_call` is: one trace, one lowering."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -122,7 +170,7 @@ def _mla_call(q, latent, lengths, rank, block_c, interpret):
     C = latent.shape[1]
     return pl.pallas_call(
         functools.partial(_mla_kernel, block_c=block_c, nk=C // block_c,
-                          slots=S, rank=rank),
+                          slots=S, rank=rank, depth=depth),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(S,),
@@ -130,9 +178,9 @@ def _mla_call(q, latent, lengths, rank, block_c, interpret):
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((1, H, rank), lambda s, lens: (s, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((2, block_c, W), latent.dtype),   # row tiles
-                pltpu.SemaphoreType.DMA((2,)),
-                pltpu.SMEM((1,), jnp.int32),                 # next buffer
+                pltpu.VMEM((depth + 1, block_c, W), latent.dtype),  # tiles
+                pltpu.SemaphoreType.DMA((depth + 1,)),
+                pltpu.SMEM((3,), jnp.int32),     # buffer, next pair to copy
                 pltpu.VMEM((H, rank), jnp.float32),          # acc
                 pltpu.VMEM((H, 1), jnp.float32),             # running max
                 pltpu.VMEM((H, 1), jnp.float32),             # running sum
@@ -170,8 +218,9 @@ def mla_decode(q, latent, lengths, *, rank, use_pallas=True, interpret=None):
                        "reference" if block_c is None else "reference_mesh",
                        C=C, W=W, interpret=interpret)
         return _mla_reference(q, latent, lengths, rank)
+    _note_mla_block(block_c, C=C, W=W, depth=_DEPTH)
     return _mla_call(q.astype(latent.dtype), latent, lengths, rank, block_c,
-                     interpret)
+                     interpret, _DEPTH)
 
 
 def _latent_append_reference(latent, rows, pos):

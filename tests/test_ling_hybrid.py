@@ -248,6 +248,54 @@ def test_mla_decode_and_latent_append_interpreted_are_their_plain_forms():
         md._latent_append_reference(lat, rows, pos))
 
 
+# (lengths of the slots of one call): the edges of the ring of copies that
+# `mla_decode` keeps in flight over the call's (slot, live block) pairs
+PIPELINE_EDGES = {
+    "one_live_block": [100, 900, 30, 600],
+    "fewer_than_depth_in_a_row": [1, 200, 256, 17, 1024, 3, 2, 700],
+    "length_0": [0, 300, 0],                  # every block of the slot live
+    "block_edges": [256, 512, 257, 511, 255],
+    "one_slot": [700],
+    "last_slot_longest": [10, 300, 1024],     # nothing follows its blocks
+}
+
+
+@pytest.mark.parametrize("heads", [32, 64])
+@pytest.mark.parametrize("lengths", list(PIPELINE_EDGES.values()),
+                         ids=list(PIPELINE_EDGES))
+def test_mla_decode_ring_of_copies_is_the_one_ahead_scheme_bit_for_bit(
+        lengths, heads):
+    """At every depth the blocks are consumed in the order of depth 1 — one
+    copy ahead, the kernel's scheme before PR 51 — so the output is that
+    one's bit for bit, and the reference's within rounding; rows in blocks
+    wholly past a slot's length are never waited for nor read (NaN there
+    changes nothing)."""
+    rng = np.random.RandomState(len(lengths) + heads)
+    S, C, W, R, block = len(lengths), 1024, 640, 512, 256
+    lat = jnp.asarray(rng.randn(S, C, W) * 0.5, jnp.float32)
+    q = jnp.asarray(rng.randn(S, heads, W) * 0.1, jnp.float32)
+    lens = jnp.asarray(lengths, jnp.int32)
+    one_ahead = md._mla_call(q, lat, lens, R, block, True, 1)
+    np.testing.assert_allclose(one_ahead, md._mla_reference(q, lat, lens, R),
+                               atol=1e-5)
+    live = np.where(np.asarray(lengths) > 0,
+                    -(-np.asarray(lengths) // block) * block, C)
+    poisoned = jnp.where(jnp.arange(C)[None, :, None] >= live[:, None, None],
+                         jnp.nan, lat)
+    assert md._DEPTH > 1
+    for depth in sorted({2, 3, 4, md._DEPTH}):
+        np.testing.assert_array_equal(
+            md._mla_call(q, lat, lens, R, block, True, depth), one_ahead)
+    np.testing.assert_array_equal(
+        md._mla_call(q, poisoned, lens, R, block, True, md._DEPTH), one_ahead)
+    np.testing.assert_array_equal(
+        md.mla_decode(q, lat, lens, rank=R, interpret=True), one_ahead)
+    # which plan ran, beside `pallas_fallback_total`
+    from deeplearning4j_tpu.telemetry.registry import get_registry
+    assert get_registry().get("mla_decode_block").get(
+        C=C, W=W, depth=md._DEPTH) == block
+
+
 # ------------------------------------------------------------------ router
 def router(E=64, k=8, d=16, seed=0, **over):
     conf = MixtureOfExpertsLayer(

@@ -30,6 +30,7 @@ from deeplearning4j_tpu.kernels.flash_attention import (flash_attention,
 
 # the module, not the function of the same name the package re-exports
 fa = importlib.import_module("deeplearning4j_tpu.kernels.flash_attention")
+mla = importlib.import_module("deeplearning4j_tpu.kernels.mla_decode")
 KERNEL = "tpu_custom_call"
 
 
@@ -78,6 +79,17 @@ def on_chip(one_chip, chip_config):
 
 def compiled_text(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def mla_row_tiles(fn, *args):
+    """How many [256, 640] bfloat16 row tiles `mla_decode` holds in VMEM,
+    each with a DMA semaphore of its own: one more than the block copies it
+    keeps in flight."""
+    traced = str(jax.make_jaxpr(fn)(*args))
+    tiles, = set(re.findall(r"Ref<vmem>\{bf16\[(\d+),256,640\]\}",
+                            traced))
+    assert f"dma_sem[{tiles}]" in traced
+    return int(tiles)
 
 
 _RESULT = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* "
@@ -835,12 +847,13 @@ def test_mla_decode_and_latent_append_compile_on_the_padded_row(on_chip):
     from deeplearning4j_tpu.kernels import latent_append, mla_decode
     S, C, W, H, R = 128, 3072, 640, 32, 512
     slab = on_chip((S, C, W), jnp.bfloat16)
-    text = compiled_text(
-        lambda q, lat, n: mla_decode(q, lat, n, rank=R, interpret=False),
-        on_chip((S, H, W), jnp.bfloat16), slab, on_chip((S,), jnp.int32))
+    attend = lambda q, lat, n: mla_decode(q, lat, n, rank=R, interpret=False)
+    args = on_chip((S, H, W), jnp.bfloat16), slab, on_chip((S,), jnp.int32)
+    text = compiled_text(attend, *args)
     assert text.count(KERNEL) == 1
     assert len(re.findall(r"%mla_decode[.\d]* = ", text)) == 1
     assert relayouts(text, S * C * W) == []
+    assert mla_row_tiles(attend, *args) == mla._DEPTH + 1 > 2
     comp = jax.jit(
         lambda lat, rows, pos: latent_append(lat, rows, pos, interpret=False),
         donate_argnums=(0,)).lower(
@@ -867,12 +880,13 @@ def test_mla_kernels_compile_at_64_heads_and_a_4096_bucket(on_chip):
     folds are 0.2 GB)."""
     from deeplearning4j_tpu.kernels import mla_decode, mla_prefill
     S, C, W, H, R, T = 128, 6144, 640, 64, 512, 4096
-    text = compiled_text(
-        lambda q, lat, n: mla_decode(q, lat, n, rank=R, interpret=False),
-        on_chip((S, H, W), jnp.bfloat16), on_chip((S, C, W), jnp.bfloat16),
-        on_chip((S,), jnp.int32))
+    attend = lambda q, lat, n: mla_decode(q, lat, n, rank=R, interpret=False)
+    args = (on_chip((S, H, W), jnp.bfloat16),
+            on_chip((S, C, W), jnp.bfloat16), on_chip((S,), jnp.int32))
+    text = compiled_text(attend, *args)
     assert text.count(KERNEL) == 1
     assert relayouts(text, S * C * W) == []
+    assert mla_row_tiles(attend, *args) == mla._DEPTH + 1 > 2
     head = lambda d: on_chip((1, T, H, d), jnp.bfloat16)
     comp = jax.jit(lambda qn, qp, kn, kp, v, m: mla_prefill(
         qn, qp, kn, kp, v, scale=0.1447, key_mask=m, interpret=False)).lower(
