@@ -81,9 +81,32 @@ class DecodeUnsupported(TypeError):
     (bidirectional recurrence, non-causal attention, temporal pooling...)."""
 
 
+STALL_FLOOR_MS = 250.0    # a program's wall is a stall over this AND over
+STALL_FACTOR = 8.0        # this many times its own running median
+
 MIN_PREFILL_BUCKET = 16   # floor the prompt buckets: bounds the executable
                           # set at log2(capacity/16)+1 without measurable
                           # padding waste at serving prompt sizes
+
+
+def _ledger_instruments(registry):
+    """The program ledger on `registry` (get-or-create: every engine of a
+    server shares them): the histogram `observe_wall` writes, the same
+    milliseconds as a counter by program (a window's share of a program is
+    the growth of its series over the growth of all), and the prefill's
+    rows counter."""
+    program = registry.histogram(
+        "decode_program_ms", "Wall of every warm execution of a decode "
+        "program as its caller measured it (observe_wall: result to result "
+        "in a loop that keeps the device fed, so the program's device "
+        "time), by program: step, prefill:<bucket>, verify:<W>, ms")
+    total = registry.counter(
+        "decode_program_ms_total", "Sum of decode_program_ms by program, ms")
+    rows = registry.counter(
+        "decode_prefill_rows_total", "Rows the prefill programs computed, "
+        "by kind: \"prompt\" (the context's tokens) or \"padding\" (the "
+        "rest of the bucket)")
+    return program, total, rows
 
 
 def bucket_for_len(n, capacity):
@@ -250,7 +273,12 @@ class DecodeEngine:
         self.tracer = tracer if tracer is not None else get_tracer()
         self._m_dispatch = self._m_sync = self._m_probs_read = None
         self._m_steps = None        # decode_steps_total{sampler}
+        # the program ledger: histogram, its sums as a counter, prefill rows
+        self._m_program = self._m_program_total = self._m_rows = None
+        self.last_sync_ms = 0.0     # the last read_ids' wait for the device
         if registry is not None:
+            self._m_program, self._m_program_total, self._m_rows = \
+                _ledger_instruments(registry)
             self._m_dispatch = registry.histogram(
                 "decode_step_dispatch_ms", "The jitted decode step call "
                 "until it returns (enqueue cost; on a mesh it waits for "
@@ -271,6 +299,7 @@ class DecodeEngine:
         self._prefill_fns = {}              # length bucket -> jitted fn
         self._verify_fns = {}               # window size W -> jitted fn
         self._compiled = set()              # labels whose first call was timed
+        self._cold = set()      # compiled by a call whose wall is still owed
         self._jit_lock = threading.Lock()
         # default (greedy) sampling operands, built once: callers that never
         # sample pay zero per-call operand construction
@@ -502,6 +531,8 @@ class DecodeEngine:
         jax.block_until_ready(out[1])
         ms = (monotonic_s() - t0) * 1000.0
         self._compiled.add(label)
+        if not sample:
+            self._cold.add(label)
         record_jit_compile(label, ms, registry=self.registry)
         if self.compile_tracker is not None:
             self.compile_tracker.record(ms, bucket=bucket, phase="decode")
@@ -516,11 +547,35 @@ class DecodeEngine:
     def observe_wall(self, label, ms):
         """The wall of one execution of `label` as its caller measured it
         (call to result on the host, or result to result in a loop that
-        keeps the device fed): every Nth is the cost plane's dispatch
-        sample."""
+        keeps the device fed). Every warm one is an observation of the
+        program ledger, `decode_program_ms{program}` (the label less its
+        "decode_"); the call that compiled is `jit_compile_ms`'s and is left
+        out. Every Nth is the cost plane's dispatch sample. Returns whether
+        the wall is a STALL by the ledger's own record: over STALL_FLOOR_MS
+        and over STALL_FACTOR times the program's median before it — or its
+        mean where that is the larger: the host reads results in device
+        order, not as they land, so a burst's time can sit in its first
+        reading (48 prefills enqueued into a drained loop: one wall of
+        2.7 s, then 47 of 0.01 ms); the sum is conserved, so the mean stays
+        true where the median does not."""
         cr = self.cost_registry
         if cr is not None and cr.dispatch_due(label):
             cr.observe_dispatch(label, ms)
+        if label in self._cold:
+            self._cold.discard(label)
+            return False
+        if self._m_program is None:
+            return False
+        program = label[len("decode_"):]
+        stalled = False
+        if ms > STALL_FLOOR_MS:     # rare: the median is a sort of <= 4096
+            median = self._m_program.percentile(0.5, program=program)
+            stalled = median is not None and ms > STALL_FACTOR * max(
+                median, self._m_program.sum(program=program)
+                / self._m_program.count(program=program))
+        self._m_program.observe(ms, program=program)
+        self._m_program_total.inc(ms, program=program)
+        return stalled
 
     def _cost_samples(self, label):
         """Tokens one execution of this executable serves — the per-token
@@ -635,6 +690,9 @@ class DecodeEngine:
             fn = self._prefill_fns.get(L)
             if fn is None:
                 fn = self._prefill_fns[L] = self._build_prefill(L)
+        if self._m_rows is not None:
+            self._m_rows.inc(n, kind="prompt")
+            self._m_rows.inc(L - n, kind="padding")
         return self._run(
             fn, f"decode_prefill:{L}", L, self.model.params,
             self.model.states, cache, np.int32(slot), padded, np.int32(n),
@@ -701,8 +759,10 @@ class DecodeEngine:
         """Host copy ([slots] np.int32) of a dispatched step's next ids: the
         one wait for the device of a step, the phase `decode_step_sync`."""
         with self.tracer.phase("decode_step_sync", histogram=self._m_sync,
-                               fold=True):
-            return np.asarray(next_ids)
+                               fold=True) as sync:
+            ids = np.asarray(next_ids)
+        self.last_sync_ms = sync.duration_ms
+        return ids
 
     def step(self, cache, last_ids, sampling=None, table=None):
         """`dispatch_step` and `read_ids` in a row: the synchronous step of
@@ -759,10 +819,14 @@ class DecodeEngine:
             fn = self._verify_fns.get(W)
             if fn is None:
                 fn = self._verify_fns[W] = self._build_verify(W)
+        t0 = monotonic_s()
         cache, probs = self._run(
             fn, f"decode_verify:{W}", W, self.model.params,
-            self.model.states, cache, np.int32(slot), ids, np.int32(start))
-        return cache, np.asarray(probs)
+            self.model.states, cache, np.int32(slot), ids, np.int32(start),
+            sample=False)
+        probs = np.asarray(probs)
+        self.observe_wall(f"decode_verify:{W}", (monotonic_s() - t0) * 1000.0)
+        return cache, probs
 
     def set_length(self, cache, slot, n):
         """Host-side length commit for `slot` (the speculative accept /

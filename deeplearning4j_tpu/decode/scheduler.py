@@ -62,7 +62,18 @@ futures completed — after either read) and `decode_admit` (a pass that
 admitted something; holds the per-request `decode_queue_wait` and
 `decode_prefill`, which now spans the prompt's placement and the enqueue,
 not the program's run). The ring gets one span a pass with its parts'
-durations as attributes. `decode_itl_ms` is, per rider of a step, the wall
+durations as attributes. A request's stages hang under its own trace id
+(`r.trace_ctx`), recorded from clock reads the loop makes anyway:
+`decode_queue_wait`, `decode_first_token` (slot granted -> first token on
+the host; histogram `decode_first_token_ms`) and `decode_generate` (first
+token -> the answer), so queue wait + first token = `ttft_ms`. Every read
+of a result hands its wall to the engine's program ledger
+(`DecodeEngine.observe_wall` -> `decode_program_ms{program}`); a wall the
+ledger calls a stall is counted (`decode_stalls_total{program}`) and
+written up once (`_stall`: log record, `decode_stall` ring span and
+profiler marker) with what would explain it — the host's wait against its
+own phases, the collector's and the compile counts since the pass before.
+`decode_itl_ms` is, per rider of a step, the wall
 from the previous result's arrival on the host (a step's ids or a first
 token; this step's own dispatch when that came later, as into a drained
 loop) to this step's ids: in steady state the interval between two reads,
@@ -108,6 +119,7 @@ gauge can never leak however a request leaves its slot.
 from __future__ import annotations
 
 import collections
+import gc
 import threading
 from typing import Any, NamedTuple
 
@@ -126,7 +138,7 @@ class GenerateRequest:
     __slots__ = ("prompt", "max_new_tokens", "stop_id", "future", "deadline",
                  "enqueued_at", "trace_ctx", "tokens", "slot", "version",
                  "ttft_ms", "queue_wait_ms", "finish_reason", "sampler",
-                 "admit_seq", "scheduled")
+                 "admit_seq", "scheduled", "admitted_at", "first_token_at")
 
     def __init__(self, prompt, max_new_tokens, stop_id=None, deadline=None,
                  sampler=None):
@@ -142,6 +154,10 @@ class GenerateRequest:
         self.version = None
         self.ttft_ms = None
         self.queue_wait_ms = None         # enqueue -> popped with a slot
+        # the request's stages on the loop's own clock reads: the slot
+        # granted (first admission) and the first token on the host
+        self.admitted_at = None
+        self.first_token_at = None
         self.finish_reason = None
         self.sampler = sampler            # SamplerConfig or None (greedy)
         self.admit_seq = None             # admission order; youngest preempts
@@ -165,6 +181,10 @@ class GenerateRequest:
 
     def fail(self, exc):
         safe_set_exception(self.future, exc)
+
+
+# the two phases of a pass in which the loop waits for the device
+_WAITS = ("decode_step_sync_ms", "decode_prefill_sync_ms")
 
 
 class _Flight(NamedTuple):
@@ -232,6 +252,12 @@ class DecodeScheduler:
         self._flight = None                         # _Flight
         self._firsts = []                           # [_First]
         self._last_read = 0.0
+        # for the stall record: the pass's own phase (its folded parts) and
+        # the parts of the pass before, the collector's and the compile
+        # counts at the start of this pass and of the one before
+        self._wave = None
+        self._last_parts = {}
+        self._marks = self._marks_before = None
         # paged-mode allocator state (loop-thread-owned, rebuilt with the
         # cache each generation)
         self._pool = None                           # BlockPool
@@ -270,6 +296,14 @@ class DecodeScheduler:
             "decode_discarded_slot_steps_total", "Slot-steps whose token "
             "was thrown away: the request had ended (stop id, deadline, "
             "abandon) or been preempted while the step was in flight")
+        self.m_first_token = reg.histogram(
+            "decode_first_token_ms", "Slot granted to first token on the "
+            "host, per request: admission behind a running step and the "
+            "prefill itself, without the queue, ms")
+        self.m_stalls = reg.counter(
+            "decode_stalls_total", "Decode programs whose wall was a stall "
+            "(over 250 ms and over 8 x the program's running median or mean), by "
+            "program; each also writes a `decode_stall` log record")
         self.m_tps = reg.gauge("decode_tokens_per_sec",
                                "Decode throughput over the last step wave")
         # one unlabelled histogram per phase of the loop (module docstring);
@@ -314,7 +348,7 @@ class DecodeScheduler:
                   "engine", fn=lambda: self.cache_mb())
         for c in (self.m_requests, self.m_tokens, self.m_shed,
                   self.m_expired, self.m_errors, self.m_preempted,
-                  self.m_discarded):
+                  self.m_discarded, self.m_stalls):
             c.inc(0)
 
     # ------------------------------------------------------------ admission
@@ -533,21 +567,28 @@ class DecodeScheduler:
                 if self._closed and not self._queue and not self._active \
                         and self._flight is None:
                     return
-            with self.tracer.phase("decode_wave",
-                                   histogram=self.m_wave) as wave:
-                try:
-                    worked = self._pass()
-                except Exception as e:      # last resort: the loop survives
-                    self._fail_all(e)
-                    worked = True
-                if not worked:
-                    wave.cancel()
+            self._turn()
+
+    def _turn(self):
+        """One pass as the loop thread makes it: inside its `decode_wave`
+        phase, whose folded parts the stall record reads."""
+        with self.tracer.phase("decode_wave", histogram=self.m_wave) as wave:
+            self._wave = wave
+            try:
+                worked = self._pass()
+            except Exception as e:      # last resort: the loop survives
+                self._fail_all(e)
+                worked = True
+            if not worked:
+                wave.cancel()
+            self._last_parts = wave.attributes
 
     def _pass(self):
         """One turn of the loop (module docstring): dispatch the next step
         from ids that are still on the device, then read what the last pass
         left there, retire and admit while the device runs. Returns whether
         anything was dispatched, read or admitted."""
+        self._marks_before, self._marks = self._marks, self._read_marks()
         prev = self._flight
         cur = self._dispatch_step(ahead=prev is not None)
         worked = prev is not None or cur is not None
@@ -667,7 +708,7 @@ class DecodeScheduler:
                 # (partial result), NOT as a 504 — same retire path either
                 # way, so the accounting cannot diverge
                 if r.tokens:
-                    self._finish(r, "deadline")
+                    self._finish(r, "deadline", now)
                 else:
                     self.m_expired.add(1)
                     r.fail(DeadlineExceeded(
@@ -686,7 +727,7 @@ class DecodeScheduler:
                         # a preempted request outgrew the whole pool: what
                         # it generated is the answer, same as hitting the
                         # slab capacity wall mid-flight
-                        self._finish(r, "capacity")
+                        self._finish(r, "capacity", now)
                     else:
                         self.m_errors.add(1)
                         r.fail(ValueError(
@@ -704,6 +745,7 @@ class DecodeScheduler:
             r.admit_seq = self._admit_seq
             self._admit_seq += 1
             if r.queue_wait_ms is None:  # first admission: `now` is the pop
+                r.admitted_at = now
                 r.queue_wait_ms = (now - r.enqueued_at) * 1000.0
                 self.tracer.record_span(
                     "decode_queue_wait", r.enqueued_at, now,
@@ -767,13 +809,14 @@ class DecodeScheduler:
                 continue        # preempted before its first token was read
             with self.tracer.phase("decode_prefill_sync",
                                    histogram=self.m_prefill_sync,
-                                   fold=True):
+                                   fold=True) as sync:
                 nid = int(f.nid)
             now = monotonic_s()
-            self._engine.observe_wall(
-                f"decode_prefill:{f.bucket}",
-                (now - max(f.dispatched_at, self._last_read)) * 1000.0)
+            wall_ms = (now - max(f.dispatched_at, self._last_read)) * 1000.0
             self._last_read = now
+            if self._engine.observe_wall(f"decode_prefill:{f.bucket}",
+                                         wall_ms):
+                self._stall(f"prefill:{f.bucket}", wall_ms, sync.duration_ms)
             n += 1
             with self.tracer.phase("decode_emit", histogram=self.m_emit,
                                    fold=True):
@@ -782,6 +825,11 @@ class DecodeScheduler:
                     self.m_ttft.observe(        # is not a second "first
                         r.ttft_ms, trace_id=getattr(    # token"
                             r.trace_ctx, "trace_id", None))
+                    r.first_token_at = now
+                    self.tracer.record_span(
+                        "decode_first_token", r.admitted_at, now,
+                        parent=r.trace_ctx, histogram=self.m_first_token,
+                        slot=f.slot, bucket=f.bucket, n_prompt=len(r.prompt))
                 r.tokens.append(nid)
                 self.m_tokens.add(1)
                 self._maybe_retire(f.slot, now)
@@ -905,7 +953,8 @@ class DecodeScheduler:
         # in which the device ran something else for the whole of it
         wall = now - max(flight.dispatched_at, self._last_read)
         self._last_read = now
-        self._engine.observe_wall("decode_step", wall * 1000.0)
+        if self._engine.observe_wall("decode_step", wall * 1000.0):
+            self._stall("step", wall * 1000.0, self._engine.last_sync_ms)
         self.m_ahead.inc(1, ahead="1" if flight.ahead else "0")
         with self.tracer.phase("decode_emit", histogram=self.m_emit,
                                fold=True):
@@ -924,6 +973,46 @@ class DecodeScheduler:
                                                     None))
                 self._maybe_retire(slot, now)
 
+    # ------------------------------------------------------------- stalls
+    def _read_marks(self):
+        """What a stall record gives as a delta: the garbage collector's
+        collections by generation and the server's compiles, read once a
+        pass."""
+        ct = self.compile_tracker
+        return ([g["collections"] for g in gc.get_stats()],
+                0 if ct is None else ct.total())
+
+    def _stall(self, program, wall_ms, waited_ms):
+        """A program's wall was a stall by the engine's rule (`observe_wall`):
+        count it and say which of the suspects it was. `waited_ms` is the
+        host's wait for this result, so wall less it is the time the loop
+        thread spent between the two reads — its own phases (`phases_ms`:
+        the parts of this pass and of the one before, whose tail the wall
+        covers; `host_phases_ms` their sum without the two waits) or
+        whatever else held it: a collection, a compile, another thread."""
+        self.m_stalls.inc(1, program=program)
+        parts = dict(self._last_parts)
+        if self._wave is not None:
+            for k, v in self._wave.attributes.items():
+                parts[k] = parts.get(k, 0.0) + v
+        then = self._marks_before or self._marks     # a loop's first pass
+        now_gc, now_compiles = self._read_marks()
+        record = {
+            "program": program, "wall_ms": round(wall_ms, 3),
+            "waited_ms": round(waited_ms, 3),
+            "host_phases_ms": round(sum(
+                v for k, v in parts.items() if k not in _WAITS), 3),
+            "phases_ms": {k: round(v, 3) for k, v in parts.items()},
+            "gc_collections": [a - b for a, b in zip(now_gc, then[0])],
+            "compiles": now_compiles - then[1],
+            "queue_depth": self.depth(), "active_slots": self.active_count()}
+        # a phase, so that a profiler session shows `dl4j:decode_stall` at
+        # the instant the long result reached the host; its ring span
+        # (tracer on) carries the record
+        with self.tracer.phase("decode_stall", **record):
+            if self.logger is not None:
+                self.logger.warning("decode_stall", **record)
+
     # ----------------------------------------------------------- retiring
     def _release_slot(self, slot):
         """The ONE place a slot id (and, paged, its pool blocks + table
@@ -940,15 +1029,21 @@ class DecodeScheduler:
             if not self._active:
                 self._pool.defrag()
 
-    def _finish(self, r, reason):
+    def _finish(self, r, reason, now):
         r.finish_reason = reason
         self.m_requests.add(1)
+        if r.first_token_at is not None:
+            # the request's last stage, under its trace id: first token on
+            # the host -> the answer (a re-queue after preemption included)
+            self.tracer.record_span(
+                "decode_generate", r.first_token_at, now, parent=r.trace_ctx,
+                n_tokens=len(r.tokens), finish_reason=reason)
         r.complete()
 
-    def _retire(self, slot, r, reason):
+    def _retire(self, slot, r, reason, now):
         self._active.pop(slot, None)
         self._release_slot(slot)
-        self._finish(r, reason)
+        self._finish(r, reason, now)
         if self.logger is not None:
             self.logger.debug("generate_done", slot=slot, reason=reason,
                               n_tokens=len(r.tokens), version=r.version)
@@ -970,4 +1065,4 @@ class DecodeScheduler:
             reason = "deadline"
         if reason is None:
             return
-        self._retire(slot, r, reason)
+        self._retire(slot, r, reason, now)

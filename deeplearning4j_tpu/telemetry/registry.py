@@ -159,12 +159,13 @@ DEFAULT_LATENCY_BUCKETS_MS = (1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0,
 
 
 class _HistState:
-    __slots__ = ("count", "sum", "bucket_counts", "reservoir", "_cap",
+    __slots__ = ("count", "sum", "max", "bucket_counts", "reservoir", "_cap",
                  "exemplars", "_ex_cap")
 
     def __init__(self, n_buckets, reservoir_cap, exemplar_cap):
         self.count = 0
         self.sum = 0.0
+        self.max = None                        # over the series' whole life
         self.bucket_counts = [0] * n_buckets   # non-cumulative, per bound
         self.reservoir = []                    # most-recent cap samples
         self._cap = reservoir_cap
@@ -176,6 +177,8 @@ class _HistState:
     def observe(self, v, bounds, trace_id=None):
         self.count += 1
         self.sum += v
+        if self.max is None or v > self.max:
+            self.max = v
         for i, b in enumerate(bounds):
             if v <= b:
                 self.bucket_counts[i] += 1
@@ -256,6 +259,14 @@ class Histogram(_Instrument):
         with self._lock:
             return sum((st.sum for st in self._series(labels)), 0.0)
 
+    def max(self, **labels):
+        """Largest observation of the series' whole life (the reservoir only
+        holds the recent ones), or None before the first; without labels,
+        over every label-set."""
+        with self._lock:
+            return max((st.max for st in self._series(labels)
+                        if st.max is not None), default=None)
+
     def _reservoir_copy(self, labels):
         with self._lock:
             return [v for st in self._series(labels) for v in st.reservoir]
@@ -270,7 +281,8 @@ class Histogram(_Instrument):
 
     def percentiles(self, qs=(0.50, 0.95, 0.99), **labels):
         """One reservoir copy + one sort for several quantiles; returns
-        {"count", "p50", ..., "max"} (the old ServingMetrics latency shape)."""
+        {"count", "p50", ..., "max"} (the old ServingMetrics latency shape),
+        all over the recent reservoir; the life's maximum is `max()`."""
         vals = self._reservoir_copy(labels)
         vals.sort()
         out = {"count": len(vals)}
@@ -280,8 +292,8 @@ class Histogram(_Instrument):
         return out
 
     def series(self):
-        """[(labels, {"count", "sum", "buckets": [(le, cumulative)...],
-        "exemplars": [...]})]."""
+        """[(labels, {"count", "sum", "max", "buckets": [(le,
+        cumulative)...], "exemplars": [...]})]."""
         with self._lock:
             out = []
             for key, st in sorted(self._states.items()):
@@ -291,7 +303,7 @@ class Histogram(_Instrument):
                     cum += c
                     buckets.append((b, cum))
                 out.append((dict(key), {"count": st.count, "sum": st.sum,
-                                        "buckets": buckets,
+                                        "max": st.max, "buckets": buckets,
                                         "exemplars": [dict(e) for e in
                                                       st.exemplars]}))
             return out

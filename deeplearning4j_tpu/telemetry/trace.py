@@ -213,7 +213,9 @@ class Phase:
 
     `fold=True`: no ring span of its own; the duration adds to the
     `<name>_ms` attribute of the nearest enclosing unfolded phase on this
-    thread (none, as on a worker thread: annotation and histogram only).
+    thread (none, as on a worker thread: annotation and histogram only),
+    with the tracer on or off: whoever holds that phase can read its parts
+    so far from `attributes` (the decode loop's stall record does).
     `labels`: the label values of the histogram's series observed.
     `cancel()`: the interval turned out not to be one worth
     counting (a pass that did nothing, a call that compiled) — nothing is
@@ -280,8 +282,8 @@ class Phase:
             host = self._outer
             while host is not None and host.fold:
                 host = host._outer
-            if host is not None and host.tracer.enabled:
-                key = self.name + "_ms"
+            if host is not None:    # tracer on or off: the host phase's
+                key = self.name + "_ms"     # owner may read its parts
                 host.attributes[key] = host.attributes.get(key, 0.0) + ms
         elif self.tracer.enabled:
             if exc_type is not None:
